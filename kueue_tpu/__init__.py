@@ -10,14 +10,15 @@ that mirrors the reference's cache/queue/event semantics.
 
 __version__ = "0.1.0"
 
-# Loading XLA:CPU AOT compilation-cache entries logs two multi-KB ERROR
-# lines about tuning pseudo-features per load; the env var must be set
-# before jaxlib's static initialization, so it lives here rather than in
-# compilecache.enable().  KUEUE_TPU_COMPILE_CACHE=0 restores full logs.
+# Loading an XLA:CPU AOT compilation-cache entry logs two multi-KB ERROR
+# lines about tuning pseudo-features ("+prefer-no-scatter is not
+# supported"); the env var must be set before jaxlib's static
+# initialization, so it lives here.  Only when the caller chose the CPU:
+# on the chip path nothing is silenced, because level 3 also swallows
+# libtpu's own start-up errors — the lines that say why a chip was not
+# found.
 import os as _os
 
-from .features import env_value as _env_value
-
-if _env_value("KUEUE_TPU_COMPILE_CACHE") != "0":
+if _os.environ.get("JAX_PLATFORMS") == "cpu":
     _os.environ.setdefault("TF_CPP_MIN_LOG_LEVEL", "3")
 del _os

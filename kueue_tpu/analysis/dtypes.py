@@ -50,7 +50,7 @@ PLANE_SCHEMA: dict[str, str] = {
 }
 
 #: planes tighten_arrays() may narrow — must be int32 in the schema
-TIGHTENABLE = ("wl_req", "wl_cycle_rank", "wl_prio", "wl_uidrank",
+TIGHTENABLE = ("wl_cycle_rank", "wl_uidrank",
                "parent", "node_level", "nominal_cq", "slot_fr",
                "forest_of_cq", "members", "cand_rows", "cand_lmem",
                "self_lmem")

@@ -114,13 +114,9 @@ class Driver:
                                                 backend=solver_backend)
             shards = self._env_shards()
             if shards > 1:
-                try:
-                    from ..parallel.sharded import make_mesh
-                    mesh = make_mesh(shards)
-                    if mesh is not None:
-                        self.scheduler.solver.set_mesh(mesh)
-                except Exception:
-                    pass  # fewer devices than asked: stay serial
+                # more shards than devices raises (make_mesh)
+                from ..parallel.sharded import make_mesh
+                self.scheduler.solver.set_mesh(make_mesh(shards))
         self.scheduler.apply_admission = self._apply_admission
         self.scheduler.preemptor.apply_preemption = self._apply_preemption
         if self.wait_for_pods_ready.enable and self.wait_for_pods_ready.block_admission:
@@ -877,7 +873,6 @@ class Driver:
                        external_finishes: Optional[dict] = None,
                        on_cycle: Optional[Callable] = None,
                        on_cycle_start: Optional[Callable] = None,
-                       backend: str = "auto",
                        pipeline: Optional[bool] = None) -> list:
         """Run up to ``max_cycles`` cycles, fusing runs of clean cycles
         into single device dispatches (kueue_tpu.ops.burst) and falling
@@ -922,11 +917,10 @@ class Driver:
             or (self.wait_for_pods_ready.enable
                 and self.wait_for_pods_ready.block_admission))
         if self._burst_solver is None:
-            self._burst_solver = BurstSolver(backend=backend)
+            self._burst_solver = BurstSolver()
             shards = self._env_shards()
             if shards > 1:
                 self._burst_solver.set_shards(shards)
-        self._burst_solver.backend = backend
         solver = self.scheduler.solver
         normal_streak = 0   # cycles to run normally before re-bursting
 

@@ -73,16 +73,28 @@ class ProcessSupervisor:
         mp = self.procs.get(name)
         if mp is None:
             mp = ManagedProcess(name=name, role=role, argv=list(argv),
-                                env=dict(env or os.environ),
+                                env=self._child_env(env or os.environ),
                                 port_file=port_file, pipe_stdio=pipe_stdio)
             self.procs[name] = mp
         else:
             mp.argv = list(argv)
             if env is not None:
-                mp.env = dict(env)
+                mp.env = self._child_env(env)
         self._launch(mp)
         self._bump(role, "spawns")
         return mp
+
+    @staticmethod
+    def _child_env(env) -> dict:
+        """Every child is pinned to the CPU.  A chip belongs to one
+        process: several shard children each building a device Driver
+        on a one-chip host cannot all own it, and a parent that merely
+        inherits JAX_PLATFORMS would hand them the fight.  The day a
+        cell needs a shard on the chip, exactly one child is given it
+        here."""
+        child = dict(env)
+        child["JAX_PLATFORMS"] = "cpu"
+        return child
 
     def _launch(self, mp: ManagedProcess) -> None:
         pipe = subprocess.PIPE if mp.pipe_stdio else None

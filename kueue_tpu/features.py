@@ -119,10 +119,9 @@ ENV_FLAGS: dict[str, EnvFlag] = {f.name: f for f in (
             "CLI state directory (durable store + WAL)."),
     EnvFlag("KUEUE_TPU_SHARDS", "0", "int",
             "Shard count for the (\"cq\",) mesh; 0 = serial path."),
-    EnvFlag("KUEUE_TPU_ACCEL_MIN_HEADS", "512", "int",
-            "Min solver heads before dispatching to the accelerator."),
     EnvFlag("KUEUE_TPU_REQUIRE_ACCEL", "0", "bool",
-            "Die rather than fall back to CPU (perf harness guard)."),
+            "Fail a bench run that finds no accelerator or dispatches "
+            "nothing to it (same as --require-accel)."),
     EnvFlag("KUEUE_TPU_STREAM_PACK", "1", "bool",
             "Streaming delta-pack of the persistent packed universe."),
     EnvFlag("KUEUE_TPU_PACK_TIGHTEN", "1", "bool",
@@ -133,8 +132,10 @@ ENV_FLAGS: dict[str, EnvFlag] = {f.name: f for f in (
             "Cross-check resident planes against host scatter."),
     EnvFlag("KUEUE_TPU_SNAP_INCREMENTAL", "1", "bool",
             "Incremental O(dirty) snapshot maintenance in the cache."),
-    EnvFlag("KUEUE_TPU_COMPILE_CACHE", "", "path",
-            "XLA compile-cache dir; \"0\" disables, empty = default."),
+    EnvFlag("KUEUE_TPU_COMPILE_CACHE", "1", "bool",
+            "Persistent XLA compile cache; 0 disables.  Placed by "
+            "JAX_COMPILATION_CACHE_DIR, else .kueue-tpu/xla-cache in "
+            "the checkout."),
     EnvFlag("KUEUE_TPU_WAL_COMMIT_EVERY", "1", "int",
             "CycleWAL group-commit interval (ops per fsync)."),
     EnvFlag("KUEUE_TPU_CHAOS_SEED", "", "int",

@@ -4,12 +4,16 @@
 compiled core (kueue_tpu/native/cycle_core.cpp) — identical decisions to
 the JAX kernel (ops/cycle.solve_cycle, run_scan=False) and the scalar
 host oracle.  The shared library is built lazily with g++ on first use
-and cached next to the source.
+and cached next to the source under a name that carries the source's
+content hash, so a stale build can never be loaded (mtimes do not
+survive a copy of the tree).  CPU hosts only: with an accelerator as the
+default JAX backend nothing reaches this module (ops/solver.py).
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -18,7 +22,6 @@ import numpy as np
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_HERE, "cycle_core.cpp")
-_LIB = os.path.join(_HERE, "libcyclecore.so")
 
 _lock = threading.Lock()
 _lib = None
@@ -36,12 +39,21 @@ def _u8(a):
     return np.ascontiguousarray(a, dtype=np.uint8)
 
 
-def _build() -> None:
-    cmd = ["g++", "-O2", "-shared", "-fPIC", "-o", _LIB, _SRC]
+def _lib_path() -> str:
+    """Library path keyed on the source's content."""
+    with open(_SRC, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:12]
+    return os.path.join(_HERE, f"libcyclecore-{digest}.so")
+
+
+def _build(lib_path: str) -> None:
+    tmp = f"{lib_path}.{os.getpid()}.tmp"
+    cmd = ["g++", "-O2", "-shared", "-fPIC", "-o", tmp, _SRC]
     proc = subprocess.run(cmd, capture_output=True, text=True)
     if proc.returncode != 0:
         raise NativeBuildError(
             f"building cycle core failed: {proc.stderr[-2000:]}")
+    os.replace(tmp, lib_path)
 
 
 def _load():
@@ -49,10 +61,10 @@ def _load():
     with _lock:
         if _lib is not None:
             return _lib
-        if (not os.path.exists(_LIB)
-                or os.path.getmtime(_LIB) < os.path.getmtime(_SRC)):
-            _build()
-        lib = ctypes.CDLL(_LIB)
+        lib_path = _lib_path()
+        if not os.path.exists(lib_path):
+            _build(lib_path)
+        lib = ctypes.CDLL(lib_path)
         i32p = np.ctypeslib.ndpointer(np.int32, flags="C_CONTIGUOUS")
         u8p = np.ctypeslib.ndpointer(np.uint8, flags="C_CONTIGUOUS")
         lib.classify_cycle.restype = None
@@ -73,7 +85,7 @@ def _load():
 
 def available() -> bool:
     """Whether the native backend can be used (g++ present or prebuilt)."""
-    if os.path.exists(_LIB):
+    if os.path.exists(_lib_path()):
         return True
     from shutil import which
     return which("g++") is not None

@@ -531,16 +531,28 @@ def pack_cycle(snapshot: Snapshot, heads: list[Info], ordering=None,
 # Dtype tightening of packed planes (host→device transfer compression)
 # ---------------------------------------------------------------------------
 
-# Planes the serial burst launch may narrow below int32 when their value
-# range permits.  Only *rank/index/request* planes qualify: sentinel
-# planes (wl_rank's INF_I32, death0's I32_MAX) and the chained scan-state
-# 9-tuple are excluded — a chained window receives the previous window's
-# device outputs, so alternating their dtypes would recompile every
-# boundary.  Quota planes holding _LIMIT-scaled sums stay int32 too.
-TIGHTEN_PLANES = ("wl_req", "wl_cycle_rank", "wl_prio", "wl_uidrank",
+# Planes the serial burst launch may narrow below int32.  A plane's
+# width is part of the fused kernel's jit signature, so it must not move
+# while a cluster runs: a widened plane recompiles the whole kernel in
+# the middle of a run (seconds on XLA:CPU, longer on a chip).  Two kinds
+# of plane qualify:
+# - GRID_PLANES hold dense ranks and row ids bounded by the packed grid
+#   (< C*M): their width comes from that bound, a function of the shapes
+#   the kernel is compiled for anyway;
+# - the rest hold cluster structure, whose values move only with the
+#   structure generation.
+# Workload-valued planes (wl_req, wl_prio) are excluded for the same
+# reason: their range moves with what arrives (one priority-200 wave
+# takes wl_prio from int8 to int16).  So are sentinel planes (wl_rank's
+# INF_I32, death0's I32_MAX), the chained scan-state 9-tuple (a chained
+# window receives the previous window's device outputs, so alternating
+# their dtypes would recompile every boundary) and quota planes holding
+# _LIMIT-scaled sums.
+TIGHTEN_PLANES = ("wl_cycle_rank", "wl_uidrank",
                   "parent", "node_level", "nominal_cq", "slot_fr",
                   "forest_of_cq", "members", "cand_rows", "cand_lmem",
                   "self_lmem")
+GRID_PLANES = ("wl_cycle_rank", "wl_uidrank", "cand_rows")
 
 _WIDTH_DT = {1: np.int8, 2: np.int16, 4: np.int32}
 
@@ -557,15 +569,18 @@ class TightenState:
         self.widen_events = 0
 
 
-def _needed_width(arr: np.ndarray) -> int:
-    if arr.size == 0:
-        return 1
-    lo, hi = int(arr.min()), int(arr.max())
+def _range_width(lo: int, hi: int) -> int:
     if -128 <= lo and hi <= 127:
         return 1
     if -32768 <= lo and hi <= 32767:
         return 2
     return 4
+
+
+def _needed_width(arr: np.ndarray) -> int:
+    if arr.size == 0:
+        return 1
+    return _range_width(int(arr.min()), int(arr.max()))
 
 
 def tighten_arrays(arrays: dict, state: TightenState,
@@ -578,11 +593,17 @@ def tighten_arrays(arrays: dict, state: TightenState,
     resident scatter path."""
     out = dict(arrays)
     saved = 0
+    grid = arrays.get("wl_cycle_rank")
     for name in TIGHTEN_PLANES:
         a = out.get(name)
         if a is None or a.dtype != np.int32:
             continue
         need = _needed_width(a)
+        if name in GRID_PLANES and grid is not None:
+            # width from the grid bound (pad cells hold -1 or 0), not
+            # from this window's values; the measurement stays as the
+            # guard that widens should a value ever exceed the bound
+            need = max(need, _range_width(-1, grid.size - 1))
         prev = state.width.get(name)
         if prev is not None and need > prev:
             state.widen_events += 1

@@ -27,6 +27,16 @@ from ..ops.cycle import solve_cycle
 from ..ops.packing import PackedCycle
 
 
+def _require_devices(asked: int, have: int) -> None:
+    """Asking for N shards and getting fewer is an error, never a
+    quieter run: only the modelled chaos fault (BurstSolver.lose_devices)
+    shrinks a mesh."""
+    if asked > have:
+        raise ValueError(
+            f"{asked} shards requested but the default JAX backend has "
+            f"{have} device(s) ({jax.devices()[0].platform})")
+
+
 def make_mesh(n_devices: int | None = None, devices=None) -> Mesh:
     """A 2-D (wl, cq) mesh over the first ``n_devices`` devices.
 
@@ -36,6 +46,7 @@ def make_mesh(n_devices: int | None = None, devices=None) -> Mesh:
     if devices is None:
         devices = jax.devices()
     if n_devices is not None:
+        _require_devices(n_devices, len(devices))
         devices = devices[:n_devices]
     n = len(devices)
     wl = n
@@ -201,15 +212,11 @@ def fs_scan_fn(mesh: Mesh, depth: int, n_levels: int):
 # Sharded fused-burst dispatch (BurstSolver.set_shards routing)
 # ---------------------------------------------------------------------------
 
-def make_burst_mesh(n_devices: int):
-    """A 1-D ``("cq",)`` mesh for the forest-partitioned burst kernel,
-    or None when fewer than ``n_devices`` devices exist (the caller
-    degrades to the serial path)."""
-    if n_devices is None or n_devices < 2:
-        return None
+def make_burst_mesh(n_devices: int) -> Mesh:
+    """A 1-D ``("cq",)`` mesh over the first ``n_devices`` devices for
+    the forest-partitioned burst kernel."""
     devices = jax.devices()
-    if len(devices) < n_devices:
-        return None
+    _require_devices(n_devices, len(devices))
     return Mesh(np.asarray(devices[:n_devices]), axis_names=("cq",))
 
 
@@ -494,10 +501,6 @@ def sharded_burst_fn(mesh: Mesh, *, K: int, depth: int, L: int, S: int,
     on the CQ axis, the dirty flags replicated (the kernel psums them),
     and the final carry stays sharded on device for window chaining."""
     from functools import partial as _partial
-    try:
-        from jax.experimental.shard_map import shard_map
-    except ImportError:  # pragma: no cover - newer JAX moved it
-        from jax.shard_map import shard_map
     from ..ops.burst import _burst_cycles
 
     row = P("cq")
@@ -508,8 +511,8 @@ def sharded_burst_fn(mesh: Mesh, *, K: int, depth: int, L: int, S: int,
     body = _partial(_burst_cycles, K=K, depth=depth, L=L, S=S, KC=KC,
                     n_levels=n_levels, G=G, runtime=runtime,
                     axis_name="cq")
-    return jax.jit(shard_map(body, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_rep=False))
+    return jax.jit(jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                                 out_specs=out_specs, check_vma=False))
 
 def make_hybrid_mesh(n_hosts: int | None = None, devices=None) -> Mesh:
     """A two-tier (wl, cq) mesh laid out so collective traffic matches

@@ -19,6 +19,7 @@ Run: ``python -m kueue_tpu.perf.harness <generator.yaml> [rangespec.yaml]``
 from __future__ import annotations
 
 import heapq
+import os
 import sys
 import time
 from dataclasses import dataclass, field
@@ -224,22 +225,33 @@ def run_scenario(config: list[dict], driver: Driver | None = None) -> PerfStats:
 
 
 def require_accel_or_die() -> None:
-    """Required-mode chip check for the bench entrypoints: with
-    ``--require-accel`` (or ``KUEUE_TPU_REQUIRE_ACCEL=1``) an
-    unreachable accelerator aborts the run instead of silently
-    producing CPU-only numbers.  Also exports the env var so
-    subprocess-based checks (tests/test_accel_route.py) FAIL rather
-    than skip for the rest of the run."""
-    import os
-    os.environ["KUEUE_TPU_REQUIRE_ACCEL"] = "1"
-    import jax
-    accel = [dev for dev in jax.devices() if dev.platform != "cpu"]
-    if not accel:
+    """``--require-accel`` (or ``KUEUE_TPU_REQUIRE_ACCEL=1``), before
+    the run: the solver device must be an accelerator, or the run
+    aborts instead of producing CPU numbers."""
+    from ..ops.device import solver_device
+    dev = solver_device()
+    if dev.platform == "cpu":
         raise SystemExit(
-            "--require-accel: no accelerator platform reachable "
-            f"(devices: {[dev.platform for dev in jax.devices()]})")
-    print(f"require-accel: {len(accel)} {accel[0].platform} device(s)",
+            "--require-accel: the default JAX backend is the CPU "
+            f"(JAX_PLATFORMS={os.environ.get('JAX_PLATFORMS')!r})")
+    print(f"require-accel: {dev.platform} {dev.device_kind}",
           file=sys.stderr)
+
+
+def require_accel_dispatches(solver_stats: dict,
+                             burst_stats: dict | None = None) -> None:
+    """``--require-accel``, after the run: a device that exists is not
+    a device that was used.  Fails unless admit scans or burst windows
+    were dispatched to the accelerator and none ran anywhere else."""
+    on = (solver_stats.get("accel_dispatches", 0)
+          + (burst_stats or {}).get("burst_accel_dispatches", 0))
+    off = (solver_stats.get("cpu_dispatches", 0)
+           + solver_stats.get("native_dispatches", 0))
+    if on == 0 or off:
+        raise SystemExit(
+            f"--require-accel: {on} dispatches reached the accelerator "
+            f"and {off} ran off it (solver={solver_stats}, "
+            f"burst={burst_stats})")
 
 
 def burst_boundary_report(bstats: dict) -> dict:

@@ -123,7 +123,11 @@ class Preemptor:
         # decision-identical (tests/test_preemption_kernel.py).
         self.device_search: object = "auto"
         self._cycle_pack = None   # (weakref to snapshot, PackedCycle)
-        self.stats = {"device_searches": 0, "host_searches": 0}
+        # accel_searches: the device searches whose output landed on an
+        # accelerator (a subset of device_searches; all of them on a
+        # chip host, none under JAX_PLATFORMS=cpu)
+        self.stats = {"device_searches": 0, "host_searches": 0,
+                      "accel_searches": 0}
 
     def set_cycle_pack(self, snapshot: Snapshot, packed) -> None:
         """Thread the admission solver's cached pack for this cycle's
@@ -243,7 +247,8 @@ class Preemptor:
         if flat_specs:
             from ..ops.preemption_solver import (
                 device_minimal_preemptions_batch)
-            results = device_minimal_preemptions_batch(flat_specs, packed)
+            results = device_minimal_preemptions_batch(
+                flat_specs, packed, stats=self.stats)
             if results is None:
                 # unpackable spec: per-head host path
                 return [self.get_targets(wl, a, snapshot)
@@ -349,7 +354,8 @@ class Preemptor:
             from ..ops.preemption_solver import device_minimal_preemptions
             result = device_minimal_preemptions(
                 ctx, candidates, allow_borrowing,
-                allow_borrowing_below_priority, packed=packed)
+                allow_borrowing_below_priority, packed=packed,
+                stats=self.stats)
             if result is not None:
                 self.stats["device_searches"] += 1
                 return result

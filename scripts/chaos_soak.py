@@ -491,7 +491,7 @@ def scenario_shard_cascade(cfg, seed, wal_path):
     control = run_host(dc, cc, cycles, runtime)
 
     d1, c1 = build(spec)
-    bs = BurstSolver(backend="cpu")
+    bs = BurstSolver()
     bs.set_shards(8)
     d1._burst_solver = bs
     wal = CycleWAL(wal_path)

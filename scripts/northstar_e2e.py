@@ -43,41 +43,14 @@ def _peek_int_flag(argv, flag: str) -> int:
     return n
 
 
-def _peek_arm_list(argv, flag: str) -> int:
-    """Max shard count named in a comma-list flag (e.g. --crossover
-    1,2,4,8) from raw argv — same pre-jax constraint as _peek_int_flag."""
-    n = 0
-    for i, a in enumerate(argv):
-        v = None
-        if a == flag and i + 1 < len(argv):
-            v = argv[i + 1]
-        elif a.startswith(flag + "="):
-            v = a.split("=", 1)[1]
-        if v:
-            for part in v.split(","):
-                try:
-                    n = max(n, int(part))
-                except ValueError:
-                    pass
-    return n
-
-
-# sharding must be configured BEFORE jax initializes its backend (the
-# kueue_tpu import below pulls jax in): on a CPU host the only way to
-# get a multi-device mesh is --xla_force_host_platform_device_count
+# --shards N is KUEUE_TPU_SHARDS=N: the env route is what production
+# uses, and setting it here also exercises the Driver.__init__ wiring.
+# The devices are whatever the default JAX backend has — four chips on
+# a v5e-4 host, or a virtual CPU mesh the CALLER asks for with
+# JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=N
+# — and more shards than devices fails.
 _shards = _peek_int_flag(sys.argv[1:], "--shards")
-_ab_shards = _peek_int_flag(sys.argv[1:], "--ab-shards")
-_xover = _peek_arm_list(sys.argv[1:], "--crossover")
-_n_dev = max(_shards, _ab_shards, _xover)
-if _n_dev > 1:
-    _xf = os.environ.get("XLA_FLAGS", "")
-    if "xla_force_host_platform_device_count" not in _xf:
-        os.environ["XLA_FLAGS"] = (
-            _xf + f" --xla_force_host_platform_device_count={_n_dev}"
-        ).strip()
 if _shards > 1:
-    # the env route is what production uses; setting it here also
-    # exercises the Driver.__init__ KUEUE_TPU_SHARDS wiring
     os.environ.setdefault("KUEUE_TPU_SHARDS", str(_shards))
 
 from kueue_tpu.api.types import (
@@ -97,6 +70,7 @@ from kueue_tpu.api.types import (
     Workload,
 )
 from kueue_tpu.controller.driver import Driver
+from kueue_tpu.ops.device import solver_device
 
 # heterogeneous runs cycle the whenCanBorrow x whenCanPreempt matrix
 # across CQs so the in-kernel fungibility walk sees every policy shape
@@ -233,29 +207,19 @@ def with_trials(trial_fn, args) -> dict:
     return summarize_trials(runs)
 
 
-def run_burst_path(args, backend: str) -> dict:
-    """The fused-burst path (kueue_tpu.ops.burst): runs of clean cycles
-    are decided in single device dispatches on ``backend``; preemption
-    waves fall back to the normal per-cycle path automatically.  Per-
-    cycle wall times are measured between applied-cycle boundaries, so
-    pack + dispatch costs land in the first cycle of each burst (honest
-    p99: the amortization is visible, not hidden)."""
-    os.environ["KUEUE_BURST_DELTA_PACK"] = (
-        "0" if getattr(args, "no_delta_pack", False) else "1")
-    d, clock, total, preemptor_wave = build(
-        args.cqs, args.wl, use_device=True,
-        n_flavors=args.flavors, n_resources=args.resources)
-    t_w = time.perf_counter()
-    d.scheduler.solver.warmup(d.cache.snapshot(), args.cqs)
-    # pre-compile the burst kernel rungs this run can hit (one XLA
-    # compile per (M, K) shape; the persistent compilation cache makes
-    # this one-time per machine)
-    from kueue_tpu.ops.burst import pack_burst, BurstSolver, K_BURST_LADDER
+def warm_burst(d, clock, n_cqs: int, runtime: int, shards: int = 0):
+    """Set-up for the fused-burst path, outside the measured cycles: the
+    per-cycle solver's warmup (truncated windows finish on it), then one
+    dispatch of every burst kernel rung this run can hit (one XLA
+    compile per (M, K) shape; the persistent compilation cache makes
+    this one-time per machine).  Installs and returns the warmed
+    BurstSolver."""
     import numpy as np
+    from kueue_tpu.ops.burst import pack_burst, BurstSolver, K_BURST_LADDER
+    d.scheduler.solver.warmup(d.cache.snapshot(), n_cqs)
     st = d.scheduler.solver._structure_for(d.cache.snapshot(), [])
     plan = pack_burst(st, d.queues, d.cache, d.scheduler, clock)
-    bs = BurstSolver(backend=backend)
-    shards = getattr(args, "shards", 0)
+    bs = BurstSolver()
     if shards > 1:
         bs.set_shards(shards)
     if plan is not None:
@@ -263,7 +227,7 @@ def run_burst_path(args, backend: str) -> dict:
         for K in K_BURST_LADDER:
             extr = np.zeros((K, plan.C, F), np.int32)
             extu = np.zeros((K, plan.G), bool)
-            h = bs.dispatch(plan, K, args.runtime, extr, extu)
+            h = bs.dispatch(plan, K, runtime, extr, extu)
             bs.fetch_flags(h)
             # chain one speculative window so the pipeline's
             # carry-rebase path is compiled here, not at the first
@@ -278,6 +242,24 @@ def run_burst_path(args, backend: str) -> dict:
         bs._resident = None
         d._burst_m = plan.M
     d._burst_solver = bs
+    return bs
+
+
+def run_burst_path(args) -> dict:
+    """The fused-burst path (kueue_tpu.ops.burst): runs of clean cycles
+    are decided in single device dispatches; cycles outside the kernel's
+    envelope fall back to the normal per-cycle path automatically.  Per-
+    cycle wall times are measured between applied-cycle boundaries, so
+    pack + dispatch costs land in the first cycle of each burst (honest
+    p99: the amortization is visible, not hidden)."""
+    os.environ["KUEUE_BURST_DELTA_PACK"] = (
+        "0" if getattr(args, "no_delta_pack", False) else "1")
+    d, clock, total, preemptor_wave = build(
+        args.cqs, args.wl, use_device=True,
+        n_flavors=args.flavors, n_resources=args.resources)
+    t_w = time.perf_counter()
+    bs = warm_burst(d, clock, args.cqs, args.runtime,
+                    shards=getattr(args, "shards", 0))
     warmup_s = time.perf_counter() - t_w
     print(f"solver+burst warmup {warmup_s:.1f}s", file=sys.stderr)
 
@@ -350,7 +332,7 @@ def run_burst_path(args, backend: str) -> dict:
         stats = d.schedule_burst(
             target - base, runtime=args.runtime, external_finishes=ext,
             on_cycle=on_cycle, on_cycle_start=on_cycle_start,
-            backend=backend, pipeline=not args.no_pipeline)
+            pipeline=not args.no_pipeline)
         all_stats.extend(stats)
         if not stats:
             if not injected:
@@ -397,7 +379,7 @@ def run_burst_path(args, backend: str) -> dict:
             stats = d.schedule_burst(
                 16, runtime=10_000, external_finishes={},
                 on_cycle=on_cycle, on_cycle_start=on_cycle_start,
-                backend=backend, pipeline=not args.no_pipeline)
+                pipeline=not args.no_pipeline)
             all_stats.extend(stats)
             if not any(s.admitted or s.preempted_targets for s in stats):
                 break
@@ -424,7 +406,7 @@ def run_burst_path(args, backend: str) -> dict:
             stats = d.schedule_burst(
                 2, runtime=args.runtime, external_finishes={},
                 on_cycle=on_cycle, on_cycle_start=on_cycle_start,
-                backend=backend, pipeline=not args.no_pipeline)
+                pipeline=not args.no_pipeline)
             all_stats.extend(stats)
             t_adm += sum(len(s.admitted) for s in stats)
         bs_now = d._burst_solver.stats
@@ -453,8 +435,9 @@ def run_burst_path(args, backend: str) -> dict:
     suffix = ("" if not args.no_pipeline else "-serial") + (
         "-fullpack" if getattr(args, "no_delta_pack", False) else "") + (
         f"-shard{bs.n_shards}" if bs.n_shards > 1 else "")
+    platform = solver_device().platform
     out = {
-        "path": f"burst-{backend}{suffix}",
+        "path": f"burst-{platform}{suffix}",
         "p50_ms": round(p50 * 1e3, 1),
         "p99_ms": round(p99 * 1e3, 1),
         "admitted": sum(len(s.admitted) for s in all_stats),
@@ -474,7 +457,7 @@ def run_burst_path(args, backend: str) -> dict:
         out["elapsed_s"] = round(time.perf_counter() - t_run0, 1)
     if trickle > 0:
         out["trickle"] = trickle_stats
-    print(f"burst[{backend}] stats: {d._burst_solver.stats}",
+    print(f"burst[{platform}] stats: {d._burst_solver.stats}",
           file=sys.stderr)
     return out
 
@@ -654,8 +637,6 @@ def run_path(args, use_device: bool) -> dict:
         out["elapsed_s"] = round(time.perf_counter() - t_run0, 1)
     if solver is not None:
         out["solver_stats"] = dict(solver.stats)
-        if solver.rtt_s is not None:
-            out["accel_rtt_ms"] = round(solver.rtt_s * 1e3, 1)
         print(f"stats: {solver.stats}", file=sys.stderr)
     return out
 
@@ -671,19 +652,11 @@ def mesh_info(shards: int) -> dict:
         "shards": max(1, shards),
     }
     if shards > 1:
-        try:
-            from kueue_tpu.parallel.sharded import (make_burst_mesh,
-                                                    make_mesh)
-            m = make_mesh(shards)
-            if m is not None:
-                info["cycle_mesh_axes"] = {
-                    k: int(v) for k, v in m.shape.items()}
-            bm = make_burst_mesh(shards)
-            if bm is not None:
-                info["burst_mesh_axes"] = {
-                    k: int(v) for k, v in bm.shape.items()}
-        except Exception:
-            pass
+        from kueue_tpu.parallel.sharded import make_burst_mesh, make_mesh
+        info["cycle_mesh_axes"] = {
+            k: int(v) for k, v in make_mesh(shards).shape.items()}
+        info["burst_mesh_axes"] = {
+            k: int(v) for k, v in make_burst_mesh(shards).shape.items()}
     return info
 
 
@@ -705,8 +678,6 @@ def main():
     ap.add_argument("--burst", action="store_true",
                     help="run the fused multi-cycle burst path in place "
                          "of the per-cycle device path")
-    ap.add_argument("--burst-backend", default="both",
-                    choices=["both", "cpu", "accel"])
     ap.add_argument("--trials", type=int, default=3,
                     help="trials per path; the median (by p99) is "
                          "reported with min/max spread")
@@ -740,8 +711,7 @@ def main():
     ap.add_argument("--shards", type=int, default=0,
                     help="shard the burst window + FS/admit scans "
                          "across N devices (same as KUEUE_TPU_SHARDS=N; "
-                         "on a CPU host this also forces "
-                         "--xla_force_host_platform_device_count=N)")
+                         "more shards than devices fails)")
     ap.add_argument("--ab-hetero", action="store_true",
                     help="heterogeneous A/B: the in-kernel fungibility "
                          "per-cycle arm, the fused burst arm (plus an "
@@ -765,10 +735,9 @@ def main():
                          "exhausts it stops at the next window "
                          "boundary and is recorded completed=false")
     ap.add_argument("--require-accel", action="store_true",
-                    help="abort (exit 1) if no accelerator platform is "
-                         "reachable instead of producing CPU-only "
-                         "numbers; also makes the accel smoke test "
-                         "FAIL rather than skip")
+                    help="abort (exit 1) if the default JAX backend "
+                         "is the CPU, or if the run dispatched nothing "
+                         "to the accelerator or anything off it")
     ap.add_argument("--quick", action="store_true",
                     help="seconds-level smoke sizing (CI wiring check, "
                          "not a perf number): caps cqs/wl/cycles and "
@@ -801,8 +770,6 @@ def main():
         # process; decisions must be bit-identical across every
         # completed arm
         from kueue_tpu.perf.harness import ab_block
-        backend = ("cpu" if args.burst_backend == "both"
-                   else args.burst_backend)
         shard_n = args.ab_shards if args.ab_shards > 1 else 0
         runs = {"in_kernel": [], "burst": [], "host": []}
         if shard_n:
@@ -812,13 +779,12 @@ def main():
             runs["in_kernel"].append(run_path(args, use_device=True))
             gc.unfreeze()
             gc.collect()
-            runs["burst"].append(run_burst_path(args, backend=backend))
+            runs["burst"].append(run_burst_path(args))
             gc.unfreeze()
             gc.collect()
             if shard_n:
                 args.shards = shard_n
-                runs["sharded"].append(run_burst_path(args,
-                                                      backend=backend))
+                runs["sharded"].append(run_burst_path(args))
                 args.shards = 0
                 gc.unfreeze()
                 gc.collect()
@@ -914,15 +880,13 @@ def main():
         # is the median trial, and cross-arm decision identity is
         # required over every run that completed the full cycle count
         from kueue_tpu.perf.harness import shard_imbalance_report
-        backend = ("cpu" if args.burst_backend == "both"
-                   else args.burst_backend)
         arms = sorted({max(1, int(x))
                        for x in args.crossover.split(",") if x.strip()})
         runs = {n: [] for n in arms}
         for _ in range(max(1, args.trials)):
             for n_sh in arms:
                 args.shards = 0 if n_sh == 1 else n_sh
-                runs[n_sh].append(run_burst_path(args, backend=backend))
+                runs[n_sh].append(run_burst_path(args))
                 gc.unfreeze()
                 gc.collect()
         args.shards = 0
@@ -993,13 +957,11 @@ def main():
         # in one process (same rationale as --ab-pipeline) and require
         # cross-arm decision identity — the tentpole's bit-identical
         # claim measured at artifact scale, not just in unit tests
-        backend = ("cpu" if args.burst_backend == "both"
-                   else args.burst_backend)
         runs = {0: [], args.ab_shards: []}
         for _ in range(max(1, args.trials)):
             for n_sh in (args.ab_shards, 0):
                 args.shards = n_sh
-                runs[n_sh].append(run_burst_path(args, backend=backend))
+                runs[n_sh].append(run_burst_path(args))
                 gc.unfreeze()
                 gc.collect()
         args.shards = 0
@@ -1040,8 +1002,6 @@ def main():
         # (same rationale as --ab-pipeline); the boundary pipeline is
         # disabled on both arms so every window pays a measurable host
         # pack instead of hiding it behind the previous apply loop
-        backend = ("cpu" if args.burst_backend == "both"
-                   else args.burst_backend)
         args.no_pipeline = True
         if args.trickle == 0:
             args.trickle = 6
@@ -1050,7 +1010,7 @@ def main():
         for _ in range(max(1, args.trials)):
             for no_delta in (False, True):
                 args.no_delta_pack = no_delta
-                runs[no_delta].append(run_burst_path(args, backend=backend))
+                runs[no_delta].append(run_burst_path(args))
                 gc.unfreeze()
                 gc.collect()
             # the shipping configuration (boundary pipeline + delta
@@ -1058,7 +1018,7 @@ def main():
             # exist to expose the pack cost, not to represent it
             args.no_delta_pack = False
             args.no_pipeline = False
-            piped.append(run_burst_path(args, backend=backend))
+            piped.append(run_burst_path(args))
             args.no_pipeline = True
             gc.unfreeze()
             gc.collect()
@@ -1072,30 +1032,31 @@ def main():
         # machine windows hit both modes equally (a sequential pair of
         # 3-trial runs on this box once showed a 2.3x whole-process
         # skew that had nothing to do with the code under test)
-        backend = ("cpu" if args.burst_backend == "both"
-                   else args.burst_backend)
         runs = {False: [], True: []}
         for _ in range(max(1, args.trials)):
             for no_pipe in (False, True):
                 args.no_pipeline = no_pipe
-                runs[no_pipe].append(run_burst_path(args, backend=backend))
+                runs[no_pipe].append(run_burst_path(args))
                 gc.unfreeze()
                 gc.collect()
         args.no_pipeline = False
         results.append(summarize_trials(runs[False]))
         results.append(summarize_trials(runs[True]))
     elif args.burst:
-        backends = (["cpu", "accel"] if args.burst_backend == "both"
-                    else [args.burst_backend])
-        for b in backends:
-            results.append(with_trials(
-                lambda b=b: run_burst_path(args, backend=b), args))
+        results.append(with_trials(lambda: run_burst_path(args), args))
     if not args.host and not args.burst and not args.fair_sharing:
         results.append(with_trials(
             lambda: run_path(args, use_device=True), args))
     if not args.device and not args.fair_sharing and not args.ab_hetero:
         results.append(with_trials(
             lambda: run_path(args, use_device=False), args))
+    if args.require_accel:
+        # a device that exists is not a device that was used
+        from kueue_tpu.perf.harness import require_accel_dispatches
+        for r in results:
+            if "solver_stats" in r:
+                require_accel_dispatches(r["solver_stats"],
+                                         r.get("burst_stats"))
     mesh_shards = max(args.shards, args.ab_shards,
                       (crossover or {}).get("arms", [0])[-1])
     tail = {
@@ -1246,6 +1207,12 @@ def main():
         with open(args.out, "w") as f:
             json.dump(tail, f, indent=1)
             f.write("\n")
+    cut = [r["path"] for r in results if not r.get("completed", True)]
+    if cut:
+        # the artifact above records how far each arm got; the exit
+        # status says the run did not finish
+        raise SystemExit(f"stalled: {cut} exhausted --budget-s "
+                         f"{args.budget_s:.0f}")
 
 
 if __name__ == "__main__":

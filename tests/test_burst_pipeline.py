@@ -329,7 +329,7 @@ def test_dispatch_next_refuses_seq_overflow():
     """The chained-window path re-checks the same headroom before
     advancing seq_base (no pack_burst gate runs for it)."""
     from kueue_tpu.ops.burst import BurstHandle, BurstSolver
-    bs = BurstSolver(backend="cpu")
+    bs = BurstSolver()
     h = BurstHandle(plan=None, K=32, runtime=0,
                     seq_base=(1 << 20) - 16, dev=None,
                     carry=object())
@@ -344,10 +344,8 @@ def test_calibration_sidecar_schema_and_eager_compile(tmp_path,
     """Satellite: the calibration sidecar carries a schema version; a
     mismatched sidecar is rejected (re-measured, re-written), and a
     valid one still runs the eager-compile walk after loading."""
-    from kueue_tpu import compilecache
     from kueue_tpu.ops import solver as solver_mod
-    monkeypatch.setenv("KUEUE_TPU_COMPILE_CACHE", str(tmp_path))
-    monkeypatch.setattr(compilecache, "_enabled_dir", None)
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
     spec = add_workloads(simple_cluster(n_cohorts=1, cqs=2),
                          [mk("w", "lq-0-0", 1000, t=1.0)])
 
@@ -386,14 +384,3 @@ def test_calibration_sidecar_schema_and_eager_compile(tmp_path,
     s4 = warm()                      # wrong-host sidecar is rejected too
     assert s4.stats.get("calibration_rejected") == 1
 
-
-def test_require_accel_turns_skip_into_fail(monkeypatch):
-    """Satellite: KUEUE_TPU_REQUIRE_ACCEL=1 turns every infrastructure
-    skip in the accel smoke test into a hard failure."""
-    import test_accel_route as tar
-    monkeypatch.setenv("KUEUE_TPU_REQUIRE_ACCEL", "1")
-    with pytest.raises(pytest.fail.Exception):
-        tar._skip_or_fail("no chip reachable")
-    monkeypatch.setenv("KUEUE_TPU_REQUIRE_ACCEL", "0")
-    with pytest.raises(pytest.skip.Exception):
-        tar._skip_or_fail("no chip reachable")

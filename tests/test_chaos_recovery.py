@@ -413,7 +413,7 @@ def test_shard_loss_cascade_8_4_1_keeps_parity():
     control = run_host(dc, cc, 80, 2)
 
     d1, c1 = build(spec)
-    bs = BurstSolver(backend="cpu")
+    bs = BurstSolver()
     bs.set_shards(8)
     d1._burst_solver = bs
     inj = chaos.install(ChaosInjector(seed=11))

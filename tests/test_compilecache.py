@@ -1,6 +1,6 @@
 """Persistent warmup artifacts: the compile-cache sidecar JSON store and
-the router-calibration reload that makes a second cold process skip the
-measurement pass (reference analog: minimalkueue starts in milliseconds,
+the native-vs-XLA calibration reload that makes a second cold process
+skip the measurement pass (reference analog: minimalkueue starts in milliseconds,
 test/performance/scheduler/minimalkueue/main.go — restart cost must be
 one-time per machine)."""
 
@@ -24,19 +24,19 @@ from kueue_tpu.api.types import (
 from kueue_tpu.controller.driver import Driver
 
 
-def test_sidecar_json_round_trip(tmp_path):
-    d = str(tmp_path)
-    obj = {"calibration": [[["cpu", "flat", 8, 8], 0.001]]}
-    assert compilecache.save_json("t.json", obj, cache_dir=d)
-    assert compilecache.load_json("t.json", cache_dir=d) == obj
-    assert compilecache.load_json("missing.json", cache_dir=d) is None
+def test_sidecar_json_round_trip(tmp_path, monkeypatch):
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    obj = {"calibration": [[["xla", "flat", 8, 8], 0.001]]}
+    assert compilecache.save_json("t.json", obj)
+    assert os.listdir(tmp_path) == ["t.json"]
+    assert compilecache.load_json("t.json") == obj
+    assert compilecache.load_json("missing.json") is None
 
 
 def test_warmup_reloads_persisted_calibration(tmp_path, monkeypatch):
-    """A second solver with the same (machine, shape) fingerprint loads
-    the persisted router table and skips the measurement pass."""
-    monkeypatch.setenv("KUEUE_TPU_COMPILE_CACHE", str(tmp_path))
-    monkeypatch.setattr(compilecache, "_enabled_dir", None)
+    """A second solver with the same (build, shape) fingerprint loads
+    the persisted calibration table and skips the measurement pass."""
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
 
     def build():
         d = Driver(clock=lambda: 1000.0, use_device_solver=True)
@@ -60,12 +60,12 @@ def test_warmup_reloads_persisted_calibration(tmp_path, monkeypatch):
     assert d1.scheduler.solver.calibration
     files = [f for f in os.listdir(tmp_path)
              if f.startswith("calibration-")]
-    assert files, "warmup must persist the router table"
+    assert files, "warmup must persist the calibration table"
 
     d2 = build()
     d2.scheduler.solver.warmup(d2.cache.snapshot(), 8)
     assert d2.scheduler.solver.stats["calibration_loaded"] == 1
     assert d2.scheduler.solver.calibration == d1.scheduler.solver.calibration
-    # the reloaded table routes a real cycle without re-measuring
+    # the reloaded table serves a real cycle without re-measuring
     s = d2.schedule_once()
     assert s.admitted == ["default/w"]

@@ -50,7 +50,7 @@ def fixture_driver(use_device, extra_cqs=(), extra_lqs=(), extra_cohorts=(),
     clock = FakeClock()
     d = Driver(clock=clock, namespaces=NAMESPACES,
                use_device_solver=use_device, fair_sharing=fair_sharing,
-               solver_backend="cpu" if use_device else "auto")
+               solver_backend="xla" if use_device else "auto")
     for cohort in extra_cohorts:
         d.apply_cohort(cohort)
     for f in ("default", "on-demand", "spot", "model-a"):
@@ -642,7 +642,7 @@ def tas_driver(use_device, cq_flavors):
     features.set_feature_gates({"TopologyAwareScheduling": True})
     clock = FakeClock()
     d = Driver(clock=clock, use_device_solver=use_device,
-               solver_backend="cpu" if use_device else "auto")
+               solver_backend="xla" if use_device else "auto")
     d.apply_topology(Topology(name="tas-single-level", levels=[HOSTNAME]))
     d.apply_resource_flavor(ResourceFlavor(
         name="tas-default", node_labels={"tas-node": "true"},

@@ -30,7 +30,7 @@ def build_driver(seed, use_device, n_cohorts=2, cqs_per_cohort=3, n_wl=60,
     rng = random.Random(seed)
     clock = FakeClock()
     d = Driver(clock=clock, use_device_solver=use_device,
-               solver_backend="cpu" if use_device else "auto")
+               solver_backend="xla" if use_device else "auto")
     d.apply_resource_flavor(ResourceFlavor(name="default"))
     pre = (PreemptionPolicy(
         reclaim_within_cohort=ReclaimWithinCohort.ANY,
@@ -124,7 +124,7 @@ def build_preemption_heavy(seed, use_device, n_cohorts=3, cqs_per_cohort=3,
     rng = random.Random(seed)
     clock = FakeClock()
     d = Driver(clock=clock, use_device_solver=use_device,
-               solver_backend="cpu" if use_device else "auto")
+               solver_backend="xla" if use_device else "auto")
     d.apply_resource_flavor(ResourceFlavor(name="default"))
     pre = PreemptionPolicy(
         reclaim_within_cohort=ReclaimWithinCohort.ANY,
@@ -228,7 +228,7 @@ def test_reserve_path_runs_on_device():
     preempt-capable with zero candidates → the device cycle reserves
     capacity and stays fully device-decided (no host fallback)."""
     clock = FakeClock()
-    d = Driver(clock=clock, use_device_solver=True, solver_backend="cpu")
+    d = Driver(clock=clock, use_device_solver=True, solver_backend="xla")
     d.apply_resource_flavor(ResourceFlavor(name="default"))
     d.apply_cluster_queue(ClusterQueue(
         name="cq",
@@ -263,7 +263,7 @@ def test_drain_scenario_device_share_gate():
     eligibility shrink).  If a change makes the solver fall back, this
     fails before the bench regresses."""
     clock = FakeClock()
-    d = Driver(clock=clock, use_device_solver=True, solver_backend="cpu")
+    d = Driver(clock=clock, use_device_solver=True, solver_backend="xla")
     d.apply_resource_flavor(ResourceFlavor(name="default"))
     for c in range(2):
         for q in range(3):
@@ -327,7 +327,7 @@ def test_skip_race_matches_host():
     for use_device in (False, True):
         clock = FakeClock()
         d = Driver(clock=clock, use_device_solver=use_device,
-                   solver_backend="cpu" if use_device else "auto")
+                   solver_backend="xla" if use_device else "auto")
         d.apply_resource_flavor(ResourceFlavor(name="default"))
         for i in range(2):
             d.apply_cluster_queue(ClusterQueue(

@@ -35,7 +35,7 @@ def build_fs_driver(seed, *, batched, use_device=False, n_cohorts=2,
     clock = FakeClock()
     d = Driver(clock=clock, fair_sharing=True,
                use_device_solver=use_device,
-               solver_backend="cpu" if use_device else "auto")
+               solver_backend="xla" if use_device else "auto")
     d.scheduler.fs_batched = batched
     d.apply_resource_flavor(ResourceFlavor(name="default"))
     pre = PreemptionPolicy(reclaim_within_cohort=ReclaimWithinCohort.ANY)

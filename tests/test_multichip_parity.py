@@ -12,7 +12,7 @@ import pytest
 
 from kueue_tpu.controller.driver import Driver
 from kueue_tpu.ops.burst import BurstSolver
-from kueue_tpu.parallel.sharded import make_burst_mesh, make_mesh
+from kueue_tpu.parallel.sharded import make_mesh
 
 from test_burst import add_workloads, build, mk, run_host, simple_cluster
 from test_burst_pipeline import (
@@ -32,7 +32,7 @@ needs_8_devices = pytest.mark.skipif(
 
 
 def run_burst_shards(d, clock, cycles, runtime, shards, inject=None):
-    bs = BurstSolver(backend="cpu")
+    bs = BurstSolver()
     if shards > 1:
         bs.set_shards(shards)
         assert bs.n_shards == shards, bs.n_shards
@@ -236,7 +236,7 @@ def test_burst_8shard_cost_rebalance_parity(monkeypatch):
     serial = run_burst_shards(ds, cs, 60, 2, shards=0)
 
     dpp, cpp = dp, cp
-    bs = BurstSolver(backend="cpu")
+    bs = BurstSolver()
     bs.set_shards(8)
     dpp._burst_solver = bs
     # measured-cost seed: as if prior windows decided heads only in
@@ -254,14 +254,14 @@ def test_burst_8shard_cost_rebalance_parity(monkeypatch):
     assert len(st.get("burst_shard_cost", [])) == 8, st
 
 
-def test_burst_mesh_degrades_below_two_devices():
-    """make_burst_mesh(1) is None and set_shards(1) keeps the serial
-    path — graceful degradation on a 1-device mesh."""
-    assert make_burst_mesh(1) is None
-    assert make_burst_mesh(0) is None
-    bs = BurstSolver(backend="cpu")
+def test_burst_shards_one_is_serial_and_too_many_raises():
+    """set_shards(1) keeps the serial path; asking for more shards than
+    there are devices is an error, not a quieter run."""
+    bs = BurstSolver()
     bs.set_shards(1)
     assert bs.n_shards == 1
     assert bs._shard_mesh is None
-    bs.set_shards(10 ** 6)   # more shards than devices: stay serial
-    assert bs.n_shards == 1
+    with pytest.raises(ValueError, match="shards requested"):
+        bs.set_shards(10 ** 6)
+    with pytest.raises(ValueError, match="shards requested"):
+        make_mesh(10 ** 6)

@@ -109,22 +109,22 @@ def test_native_backend_full_cycle_parity(seed):
 
 
 def test_auto_routing_prefers_calibrated_native():
-    """backend='auto' dispatches to the C++ core when warmup measured it
-    fastest for the bucket — with unchanged decisions (weak r3 #5: the
-    native backend competes in the calibration table instead of needing
-    an explicit backend switch)."""
+    """On a CPU host backend='auto' hands the admit loop to the C++ core
+    when warmup measured it faster than the XLA scan for the bucket —
+    with unchanged decisions (weak r3 #5: the native core competes in
+    the calibration table instead of needing an explicit switch)."""
     from tests.test_device_cycle import build_driver, drive_cycles
     host, hclock, hwl = build_driver(33, use_device=False,
                                      preemption=False)
     auto, aclock, awl = build_driver(33, use_device=True,
                                      preemption=False)
     s = auto.scheduler.solver
-    s.backend = "auto"     # build_driver pins cpu; routing under test
+    s.backend = "auto"     # build_driver pins xla; the pick under test
     for W in (8, 16, 32, 64, 128, 256, 512, 1024):
-        s.calibration[("cpu", "flat", W, W)] = 1e-3
+        s.calibration[("xla", "flat", W, W)] = 1e-3
         s.calibration[("native", "flat", W, W)] = 1e-5
         for mfw in (4, 8, 16, 32, 64):
-            s.calibration[("cpu", "forest", W, mfw)] = 1e-3
+            s.calibration[("xla", "forest", W, mfw)] = 1e-3
             s.calibration[("native", "forest", W, mfw)] = 1e-5
     hlog = drive_cycles(host, hclock, hwl)
     alog = drive_cycles(auto, aclock, awl)
@@ -132,20 +132,20 @@ def test_auto_routing_prefers_calibrated_native():
         assert h == a, f"cycle {cyc}:\nhost={h}\nauto={a}"
     assert s.stats["native_dispatches"] > 0, s.stats
     assert s.stats["cpu_dispatches"] == 0, s.stats
-    # flipping the measurement routes the same cycles back to XLA-CPU
+    # flipping the measurement hands the same cycles back to the XLA scan
     auto2, a2clock, a2wl = build_driver(33, use_device=True,
                                         preemption=False)
     s2 = auto2.scheduler.solver
     for key, v in s.calibration.items():
-        s2.calibration[key] = 1e-5 if key[0] == "cpu" else 1e-3
+        s2.calibration[key] = 1e-5 if key[0] == "xla" else 1e-3
     drive_cycles(auto2, a2clock, a2wl)
     assert s2.stats["native_dispatches"] == 0, s2.stats
 
 
 def test_warmup_records_native_calibration():
     """warmup() itself must produce the ('native', ...) calibration
-    entries the router compares — guarding the admit_scan_raw argument
-    wiring (a drift would otherwise silently disable native routing)."""
+    entries dispatch compares — guarding the admit_scan_raw argument
+    wiring (a drift would otherwise silently disable the native core)."""
     from tests.test_device_cycle import build_driver
     d, _, _ = build_driver(34, use_device=True, preemption=False)
     s = d.scheduler.solver
@@ -154,7 +154,7 @@ def test_warmup_records_native_calibration():
     assert s.stats["native_calibration_failures"] == 0, s.stats
     native_keys = [k for k in s.calibration if k[0] == "native"]
     assert native_keys, sorted(s.calibration)
-    # every native entry has an XLA-CPU twin for the same bucket, so the
-    # three-way comparison in dispatch always has both sides
+    # every native entry has an XLA twin for the same bucket, so the
+    # comparison in dispatch always has both sides
     for k in native_keys:
-        assert ("cpu",) + k[1:] in s.calibration, k
+        assert ("xla",) + k[1:] in s.calibration, k

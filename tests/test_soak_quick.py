@@ -56,7 +56,7 @@ def test_northstar_hetero_quick(tmp_path):
     out = str(tmp_path / "NORTHSTAR_r99.json")
     d = _run_quick("northstar_e2e.py", out, extra=(
         "--burst", "--ab-hetero", "--flavors", "4", "--resources", "3",
-        "--ab-shards", "2", "--burst-backend", "cpu"))
+        "--ab-shards", "2"))
     assert d["quick"] is True
     h = d["hetero"]
     assert h["decisions_identical_across_arms"] is True

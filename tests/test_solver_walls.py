@@ -35,7 +35,7 @@ from tests.conftest import FakeClock
 def new_driver(use_device):
     clock = FakeClock()
     d = Driver(clock=clock, use_device_solver=use_device,
-               solver_backend="cpu" if use_device else "auto")
+               solver_backend="xla" if use_device else "auto")
     return d, clock
 
 

@@ -277,29 +277,60 @@ def test_stream_bail_wide_key_falls_back_to_classic():
 def test_tighten_narrows_then_widens_sticky():
     st = TightenState()
     stats = {}
-    small = {"wl_prio": np.arange(8, dtype=np.int32).reshape(2, 4)}
+    small = {"nominal_cq": np.arange(8, dtype=np.int32).reshape(2, 4)}
     out = tighten_arrays(small, st, stats)
-    assert out["wl_prio"].dtype == np.int8
-    assert np.array_equal(out["wl_prio"].astype(np.int32),
-                          small["wl_prio"])
-    assert small["wl_prio"].dtype == np.int32, "input must not mutate"
-    assert st.width["wl_prio"] == 1
+    assert out["nominal_cq"].dtype == np.int8
+    assert np.array_equal(out["nominal_cq"].astype(np.int32),
+                          small["nominal_cq"])
+    assert small["nominal_cq"].dtype == np.int32, "input must not mutate"
+    assert st.width["nominal_cq"] == 1
 
-    mid = {"wl_prio": np.array([[300, -4000]], dtype=np.int32)}
+    mid = {"nominal_cq": np.array([[300, -4000]], dtype=np.int32)}
     out = tighten_arrays(mid, st, stats)
-    assert out["wl_prio"].dtype == np.int16
+    assert out["nominal_cq"].dtype == np.int16
     assert stats["pack_tighten_widened"] == 1
 
-    big = {"wl_prio": np.array([[1 << 19]], dtype=np.int32)}
+    big = {"nominal_cq": np.array([[1 << 19]], dtype=np.int32)}
     out = tighten_arrays(big, st, stats)
-    assert out["wl_prio"].dtype == np.int32
+    assert out["nominal_cq"].dtype == np.int32
     assert stats["pack_tighten_widened"] == 2
 
     # sticky: small values after an overflow stay wide (stable jit sig)
     out = tighten_arrays(small, st, stats)
-    assert out["wl_prio"].dtype == np.int32
+    assert out["nominal_cq"].dtype == np.int32
     assert stats["pack_tighten_widened"] == 2
     assert stats["pack_tighten_bytes_saved"] > 0
+
+
+def test_tighten_widths_do_not_follow_workload_values():
+    """The narrowed widths are part of the fused kernel's jit signature:
+    rank/row-id planes take theirs from the grid bound and the
+    workload-valued planes are never narrowed, so an arriving wave
+    (bigger requests, a higher priority, more rows) cannot widen a
+    plane and recompile the kernel mid-run."""
+    st = TightenState()
+    stats = {}
+    C, M = 4, 64                       # grid of 256 cells: int16 ranks
+    quiet = {
+        "wl_cycle_rank": np.zeros((C, M), np.int32),
+        "wl_uidrank": np.zeros((C, M), np.int32),
+        "cand_rows": np.full((2, 32), -1, np.int32),
+        "wl_prio": np.full((C, M), 50, np.int32),
+        "wl_req": np.full((C, M, 1), 500, np.int32),
+    }
+    out = tighten_arrays(quiet, st, stats)
+    busy = dict(quiet)
+    busy["wl_cycle_rank"] = np.arange(C * M, dtype=np.int32).reshape(C, M)
+    busy["wl_uidrank"] = busy["wl_cycle_rank"][::-1].copy()
+    busy["cand_rows"] = np.full((2, 32), C * M - 1, np.int32)
+    busy["wl_prio"] = np.full((C, M), 200, np.int32)
+    busy["wl_req"] = np.full((C, M, 1), 1 << 20, np.int32)
+    out2 = tighten_arrays(busy, st, stats)
+    for name in quiet:
+        assert out[name].dtype == out2[name].dtype, name
+    assert out["wl_cycle_rank"].dtype == np.int16
+    assert out["wl_prio"].dtype == out["wl_req"].dtype == np.int32
+    assert stats.get("pack_tighten_widened", 0) == 0
 
 
 def test_tighten_skips_sentinel_and_foreign_planes():
