@@ -310,7 +310,10 @@ def cmd_schedule(store: Store, args) -> int:
     from .profiling import trace
     driver = build_driver(store, use_device=getattr(args, "device_solver",
                                                     False))
-    with trace(getattr(args, "profile_dir", None)):
+    profile_dir = getattr(args, "profile_dir", None)
+    if profile_dir:
+        driver.obs.enable_tracing()    # the spans are the trace's host marks
+    with trace(profile_dir):
         driver.run_until_settled(max_cycles=args.cycles)
     save_workloads(store, driver)
     store.save()
@@ -421,6 +424,7 @@ def cmd_serve(store: Store, args) -> int:
     profile_dir = getattr(args, "profile_dir", None)
     if profile_dir:
         from .profiling import start_trace
+        driver.obs.enable_tracing()    # the spans are the trace's host marks
         start_trace(profile_dir)
     worker_server = None
     if getattr(args, "listen", None) is not None:
@@ -544,7 +548,8 @@ def main(argv: list[str] | None = None) -> int:
     p.add_argument("--device-solver", action="store_true",
                    help="decide cycles with the batched device solver")
     p.add_argument("--profile-dir", default=None,
-                   help="write a jax.profiler trace here")
+                   help="write a jax.profiler trace here (turns the span "
+                        "tracer on: its spans are the trace's host marks)")
 
     sub.add_parser("state", help="dump queues/cache state")
 
@@ -554,7 +559,8 @@ def main(argv: list[str] | None = None) -> int:
     p.add_argument("--exit-when-drained", action="store_true",
                    help="exit once no workloads are pending (tests)")
     p.add_argument("--profile-dir", default=None,
-                   help="write a jax.profiler trace here")
+                   help="write a jax.profiler trace here (turns the span "
+                        "tracer on: its spans are the trace's host marks)")
     p.add_argument("--listen", type=int, default=None,
                    help="serve the MultiKueue worker API on this port")
     p.add_argument("--device-solver", action="store_true",

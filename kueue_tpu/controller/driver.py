@@ -1018,6 +1018,7 @@ class Driver:
             assumptions were invalidated; it must never be applied."""
             if h is not None:
                 bstats["burst_spec_cancelled"] += 1
+                bstats["burst_cycles_discarded"] += h.K
                 if os.environ.get("KUEUE_BURST_DEBUG"):
                     import sys as _sys
                     print(f"spec cancel: {why}", file=_sys.stderr)
@@ -1158,6 +1159,7 @@ class Driver:
             forest_of_cq = plan.arrays["forest_of_cq"]
             st_names = st.cq_names
             applied = 0
+            consumed = 0     # window cycles applied or empty, not dropped
             drained = False
             window_complete = False
             for k in range(K):
@@ -1233,6 +1235,7 @@ class Driver:
                 if not modeled:
                     # empty cycle: pending finishes may unpark work
                     normal_cycle(heads=[], advance=False)
+                    consumed += 1
                     continue
                 # one settle per cycle: evict/finish wakeups inside the
                 # block collapse into a single deduped requeue pass at
@@ -1283,12 +1286,14 @@ class Driver:
                     normal_cycle(heads=heads, advance=False)
                     break
                 applied += 1
+                consumed += 1
                 normal_streak = 0
                 dirty_backoff = 0
                 if _chaos.ACTIVE is not None:
                     _chaos.ACTIVE.crashpoint("burst.mid_window")
             else:
                 window_complete = True
+            bstats["burst_cycles_discarded"] += K - consumed
             if spec is not None and not window_complete:
                 # the window was truncated (dirty / divergence / clock):
                 # live state no longer matches the carry the speculative

@@ -4,9 +4,15 @@ The operator-facing observability layer the reference ships as
 ``pkg/metrics`` + ``pkg/visibility`` + ``pkg/debugger`` + Events,
 reproduced for the solver stack:
 
-- :mod:`trace`  — structured span tracer over the admission hot path
-  (schedule phases, burst pack/dispatch/fetch/apply, WAL, federation
-  sync), off by default and zero-allocation when off;
+- :mod:`trace`  — the one structured span tracer over the admission
+  hot path (schedule phases and the sub-phases of nominate, burst
+  pack/dispatch/fetch/apply and the sub-phases of pack and dispatch,
+  WAL, federation sync), off by default and zero-allocation when off.
+  On, every recorded span is also a ``jax.profiler.TraceAnnotation``,
+  so a profiler trace (``profiling.py``) shows it beside the device's
+  operations on one clock; a parent with children reports its self
+  time as ``<name>.self``; spans ``/debug/spans`` had no room for are
+  counted (``spans_dropped`` in the ``obs`` block), never lost silently;
 - :mod:`flight` — ring-buffer flight recorder of the last N cycles
   (decision digests, spans, chaos hits), dumpable on demand, over
   HTTP, and on SIGUSR2;
@@ -33,6 +39,7 @@ from .events import Event, EventStream            # noqa: F401
 from .flight import CycleRecord, FlightRecorder   # noqa: F401
 from .trace import (                               # noqa: F401
     HOT_PATH_PHASES,
+    SELF_SUFFIX,
     SPAN_BUCKETS,
     SpanRecord,
     Tracer,
@@ -139,4 +146,5 @@ class ObsPlane:
         t = self._tracer_view()
         if t is not None:
             out["spans"] = t.roster()
+            out["spans_dropped"] = t.dropped_total
         return out

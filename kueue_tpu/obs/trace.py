@@ -12,10 +12,21 @@ tracer code.  Tracing on, each ``span(name)``:
   runs make bit-identical decisions,
 - feeds the duration into the registry's per-phase exponential-bucket
   histogram (``kueue_span_duration_seconds{phase=...}``),
+- when it had at least one recorded child, feeds its *self time*
+  (duration less the children's) into the same series under the phase
+  ``<name>.self``, so a parent says how much of it no sub-span covers,
+- holds a ``jax.profiler.TraceAnnotation(name)`` open for its lifetime:
+  whenever a profiler session is running, whoever started it
+  (``profiling.start_trace``, ``cli --profile-dir``, a benchmark), the
+  span sits on the trace's ``/host:CPU`` plane, on the trace's own
+  clock, beside the device's operations — one system, not two,
 - appends a finished-span record to the current cycle buffer, which the
   flight recorder drains at each cycle boundary (``counted=True``
-  leaves skip the record and keep histogram-only timing — see
-  :func:`span`).
+  leaves skip the record, the annotation and the self-time bookkeeping
+  and keep histogram-only timing — see :func:`span`).
+
+``Tracer.trace_spans`` keeps the first ``trace_capacity`` records for
+``/debug/spans``; what it refuses is counted in ``dropped_total``.
 
 Spans nest via an explicit stack; ``Span.__exit__`` enforces LIFO
 pairing (a span may close exactly once, and only when it is the
@@ -23,9 +34,13 @@ innermost open span), so malformed instrumentation fails loudly in
 tests instead of producing silently garbled traces.
 
 ``to_chrome_trace`` renders finished spans as Chrome trace-event JSON
-(``ph: "X"`` complete events, microsecond timestamps) so ``/debug/spans``
-output opens in Perfetto next to ``jax.profiler`` traces from
-``profiling.py``.
+(``ph: "X"`` complete events, microsecond ``perf_counter`` timestamps)
+for ``/debug/spans`` when no profiler session ran; a profiler trace
+already holds the same spans as annotations, on its own clock.
+
+``jax`` is imported where a :class:`Tracer` is built, never at the
+import of this module: the WAL and the federation reach :func:`span`
+(a no-op while tracing is off) without it.
 """
 
 from __future__ import annotations
@@ -43,14 +58,30 @@ SPAN_BUCKETS = exponential_buckets(1e-6, 2, 22)
 #: Every phase the hot path is instrumented with, in call order.  The
 #: OBS artifact's span roster and the SIGUSR2 dump are checked against
 #: this list; adding an instrumentation site means adding its name here.
+#: A dotted child is opened only inside its parent; the roster also
+#: carries ``<name>.self`` for every phase that had a child.
 HOT_PATH_PHASES = (
     "cycle",            # one whole scheduling cycle (schedule_once path)
     "cycle.snapshot",   # cache snapshot build / incremental reuse
     "cycle.nominate",   # validation + flavor assignment + preempt targets
+    "cycle.nominate.classify",        # cycle pack + device classification
+    "cycle.nominate.walk",            # host FlavorAssigner walks
+    "cycle.nominate.candidates",      # find/sort candidates, plan searches
+    "cycle.nominate.search_pack",     # numpy fill of the [S, K, F] planes
+    "cycle.nominate.search_launch",   # planes up, the search, results back
+    "cycle.nominate.search_decode",   # masks to Target lists
+    "cycle.nominate.search_fallback",  # the one-launch-a-head route
+    "cycle.nominate.scan_dispatch",   # pack targets + admit-scan dispatch
     "cycle.order",      # classical sort or fair-sharing tournament setup
     "cycle.admit",      # sequential admit loop (assume/apply/requeue)
+    "cycle.admit.fetch",              # blocking wait for the admit scan
     "burst.pack",       # burst-window pack (streaming or classic delta)
+    "burst.pack.drain",               # journal drain + round-trip checks
+    "burst.pack.walk",                # stage A: per-queue row records
+    "burst.pack.grid",                # stage B: the dense [C, M] planes
     "burst.dispatch",   # fused-kernel launch incl. sharded shard launches
+    "burst.dispatch.tighten",         # dtype narrowing on the host
+    "burst.dispatch.launch",          # the fused kernel's (async) jit call
     "burst.fetch",      # decision-plane fetch (flags + full planes)
     "burst.apply",      # host apply of one modeled burst cycle
     "wal.append",       # one journal op append
@@ -61,6 +92,9 @@ HOT_PATH_PHASES = (
     "svc.ingest",       # cycle-boundary drain of the service ingest queue
     "svc.shutdown",     # graceful-drain epilogue (final WAL/journal flush)
 )
+
+#: Roster suffix of a phase's self time (duration less recorded children).
+SELF_SUFFIX = ".self"
 
 
 @dataclass(slots=True)
@@ -83,7 +117,7 @@ class Span:
     allocates no span objects at all."""
 
     __slots__ = ("tracer", "name", "t0", "depth", "parent", "vt",
-                 "_open")
+                 "child_s", "_note", "_open")
 
     def __init__(self, tracer: "Tracer", name: str = ""):
         self.tracer = tracer
@@ -97,8 +131,11 @@ class Span:
         self.depth = len(st)
         self.parent = st[-1].name if st else ""
         self.vt = self.tracer.vclock() if self.tracer.vclock else 0.0
+        self.child_s = None     # summed recorded children; None = none
         st.append(self)
         self._open = True
+        self._note = self.tracer._annotation(self.name)
+        self._note.__enter__()
         self.t0 = time.perf_counter()
         return self
 
@@ -109,8 +146,12 @@ class Span:
                 f"span {self.name!r} closed out of order "
                 f"(stack: {[s.name for s in st]})")
         dur = time.perf_counter() - self.t0
+        self._note.__exit__(exc_type, exc, tb)
         st.pop()
         self._open = False
+        if st:
+            up = st[-1]
+            up.child_s = dur if up.child_s is None else up.child_s + dur
         self.tracer._finish(self, dur)
         return False            # never swallow the exception
 
@@ -118,9 +159,11 @@ class Span:
 class _CountedSpan:
     """Histogram-only leaf span: times every entry into the phase
     histogram but skips the stack, parent/depth bookkeeping, the
-    virtual-clock read, and the retained record.  By contract counted
-    spans are leaves and must not nest inside one another (each tracer
-    reuses a single instance per depth-free site)."""
+    virtual-clock read, the profiler annotation, and the retained
+    record (so it never counts as a child in a parent's self time).
+    By contract counted spans are leaves and must not nest inside one
+    another (each tracer reuses a single instance per depth-free
+    site)."""
 
     __slots__ = ("tracer", "name", "t0")
 
@@ -136,10 +179,7 @@ class _CountedSpan:
         dur = time.perf_counter() - self.t0
         tr = self.tracer
         tr.finished_total += 1
-        h = tr._hists.get(self.name)
-        if h is None:
-            h = tr._hist_for(self.name)
-        h.observe(dur)
+        tr._observe(self.name, dur)
         return False            # never swallow the exception
 
 
@@ -171,6 +211,8 @@ class Tracer:
 
     def __init__(self, registry: Optional[Registry] = None,
                  vclock: Optional[Callable[[], float]] = None):
+        from jax.profiler import TraceAnnotation
+        self._annotation = TraceAnnotation
         self.registry = registry if registry is not None else Registry()
         self.vclock = vclock
         # the tracer's span stack belongs to the thread that built it
@@ -186,9 +228,11 @@ class Tracer:
         self.cycle_spans: list[SpanRecord] = []
         self.finished_total = 0
         self.opened_total = 0
-        # retained finished spans for /debug/spans (bounded)
+        # retained finished spans for /debug/spans: the first
+        # trace_capacity are kept, the rest counted in dropped_total
         self.trace_spans: list[SpanRecord] = []
-        self.trace_capacity = 4096
+        self.trace_capacity = 65536
+        self.dropped_total = 0
 
     def span(self, name: str, counted: bool = False):
         self.opened_total += 1
@@ -226,10 +270,17 @@ class Tracer:
         self.cycle_spans.append(rec)
         if len(self.trace_spans) < self.trace_capacity:
             self.trace_spans.append(rec)
-        h = self._hists.get(s.name)
+        else:
+            self.dropped_total += 1
+        self._observe(s.name, dur)
+        if s.child_s is not None:
+            self._observe(s.name + SELF_SUFFIX, max(0.0, dur - s.child_s))
+
+    def _observe(self, phase: str, seconds: float) -> None:
+        h = self._hists.get(phase)
         if h is None:
-            h = self._hist_for(s.name)
-        h.observe(dur)
+            h = self._hist_for(phase)
+        h.observe(seconds)
 
     def drain_cycle(self) -> list[SpanRecord]:
         out, self.cycle_spans = self.cycle_spans, []

@@ -76,6 +76,7 @@ from .quota_kernel import available_all, available_at
 from .cycle import add_usage_chain_batched
 from ..chaos import injector as _chaos
 from ..features import env_value
+from ..obs.trace import span as _span
 from .device import on_accelerator, output_devices, solver_device
 
 INF_I32 = np.int32(2**31 - 1)
@@ -1729,13 +1730,15 @@ def _pack_burst_cached_classic(structure, queues, cache, scheduler,
     soft: dict = {}
     jranges: list = []
     force_full = False
-    for j in (getattr(queues, "pack_journal", None),
-              getattr(cache, "pack_journal", None)):
-        if j is None:
-            force_full = True
-        else:
-            force_full |= j.drain_into(dirty, soft, row_of=st.cq_index,
-                                       ranges_out=jranges)
+    with _span("burst.pack.drain"):
+        for j in (getattr(queues, "pack_journal", None),
+                  getattr(cache, "pack_journal", None)):
+            if j is None:
+                force_full = True
+            else:
+                force_full |= j.drain_into(dirty, soft,
+                                           row_of=st.cq_index,
+                                           ranges_out=jranges)
     enabled = os.environ.get("KUEUE_BURST_DELTA_PACK", "1") != "0"
     from .aggregate import agg_planes_enabled
     key = (st.generation, st.resource_scale.tobytes(),
@@ -1744,12 +1747,14 @@ def _pack_burst_cached_classic(structure, queues, cache, scheduler,
     def _full():
         if _unknown_active_cq(st, queues):
             return None, None, False
-        records = _walk_records(st, queues, cache, scheduler, window)
+        with _span("burst.pack.walk"):
+            records = _walk_records(st, queues, cache, scheduler, window)
         if records is None:
             return None, None, False
         fields: dict = {}
-        plan = _assemble_plan(st, records, cache, scheduler, min_m,
-                              fields_out=fields if enabled else None)
+        with _span("burst.pack.grid"):
+            plan = _assemble_plan(st, records, cache, scheduler, min_m,
+                                  fields_out=fields if enabled else None)
         if plan is None:
             return None, None, False
         if stats is not None:
@@ -1775,22 +1780,23 @@ def _pack_burst_cached_classic(structure, queues, cache, scheduler,
     # the full walk would (active with pending work); clean unknown CQs
     # were checked at state creation and only change through journaled
     # mutators
-    for name in dirty | set(soft):
-        if name not in index_of:
-            q = queues.queue_for(name)
-            if q is not None and q.active and q.pending_active():
-                return None, None, False
-    # soft-dirty roundtrips: verify the packed dynamic bits still hold;
-    # escalate the CQ to a re-walk when they moved
-    for name, skeys in soft.items():
-        ci = index_of.get(name)
-        if ci is None or name in dirty:
-            continue
-        if not _roundtrips_clean(state.records[ci],
-                                 queues.queue_for(name),
-                                 cache.cluster_queue(name), skeys,
-                                 name in structure.cq_covers_pods):
-            dirty.add(name)
+    with _span("burst.pack.drain"):
+        for name in dirty | set(soft):
+            if name not in index_of:
+                q = queues.queue_for(name)
+                if q is not None and q.active and q.pending_active():
+                    return None, None, False
+        # soft-dirty roundtrips: verify the packed dynamic bits still
+        # hold; escalate the CQ to a re-walk when they moved
+        for name, skeys in soft.items():
+            ci = index_of.get(name)
+            if ci is None or name in dirty:
+                continue
+            if not _roundtrips_clean(state.records[ci],
+                                     queues.queue_for(name),
+                                     cache.cluster_queue(name), skeys,
+                                     name in structure.cq_covers_pods):
+                dirty.add(name)
 
     # at full churn the per-CQ delta walk is a near-complete rebuild
     # plus journal/roundtrip overhead — measurably slower than the
@@ -1806,24 +1812,27 @@ def _pack_burst_cached_classic(structure, queues, cache, scheduler,
     scale_of = {r: int(st.resource_scale[i])
                 for i, r in enumerate(st.resource_names)}
     repacked = 0
-    for name in dirty:
-        ci = index_of.get(name)
-        if ci is None:
-            continue
-        rec = _pack_cq_rows(st, ci, pos_of.get(name, C), queues, cache,
-                            scheduler, assumed, scale_of, window)
-        if rec is _PACK_FAIL:
-            return None, None, False
-        records[ci] = rec
-        repacked += rec.n_rows
+    with _span("burst.pack.walk"):
+        for name in dirty:
+            ci = index_of.get(name)
+            if ci is None:
+                continue
+            rec = _pack_cq_rows(st, ci, pos_of.get(name, C), queues,
+                                cache, scheduler, assumed, scale_of,
+                                window)
+            if rec is _PACK_FAIL:
+                return None, None, False
+            records[ci] = rec
+            repacked += rec.n_rows
     # heads-enumeration positions can shift when CQs leave the queue
     # manager; refresh them on every record (clean ones included)
     for rec in records:
         rec.pos = pos_of.get(st.cq_names[rec.ci], C)
     fields: dict = {}
-    plan = _assemble_plan(st, records, cache, scheduler, min_m,
-                          prev=(state.records, state.fields),
-                          fields_out=fields)
+    with _span("burst.pack.grid"):
+        plan = _assemble_plan(st, records, cache, scheduler, min_m,
+                              prev=(state.records, state.fields),
+                              fields_out=fields)
     if plan is None:
         return None, None, False
     new_state = DeltaPackState(key, records, fields)
@@ -1908,6 +1917,9 @@ class BurstSolver:
         from ..compilecache import enable as _enable_compile_cache
         _enable_compile_cache()
         self.stats = {"burst_dispatches": 0, "burst_cycles_decided": 0,
+                      # decided cycles of windows the driver dropped or
+                      # cancelled before applying them
+                      "burst_cycles_discarded": 0,
                       "burst_accel_dispatches": 0,
                       # most devices one window's decision planes were
                       # spread over (sharded: the shard count)
@@ -1955,6 +1967,9 @@ class BurstSolver:
                       "burst_resident_scatter_s": 0.0,
                       "burst_boundary_bytes_h2d": 0,
                       "burst_boundary_bytes_equiv": 0,
+                      # bytes the serial launch's numpy planes put on
+                      # the bus (after dtype tightening)
+                      "burst_launch_bytes_h2d": 0,
                       # coalesced dirty-row ranges seen by the journal
                       "burst_journal_dirty_ranges": 0,
                       # cost-balanced forest partition (EWMA of decided
@@ -2140,36 +2155,36 @@ class BurstSolver:
             # upcasts on device.  Scan-state planes are never narrowed
             # (a chained window feeds device outputs straight back in).
             from .packing import tighten_arrays
-            a = tighten_arrays(a, self._tighten, self.stats)
+            with _span("burst.dispatch.tighten"):
+                a = tighten_arrays(a, self._tighten, self.stats)
         (elig0, parked0, resume0, adm0, adm_seq0, adm_usage0,
          adm_uses0, death0, u_cq0) = state
-        self.stats["burst_launch_bytes_h2d"] = (
-            self.stats.get("burst_launch_bytes_h2d", 0)
-            + sum(v.nbytes for v in a.values()
-                  if isinstance(v, np.ndarray))
+        self.stats["burst_launch_bytes_h2d"] += (
+            sum(v.nbytes for v in a.values() if isinstance(v, np.ndarray))
             + sum(v.nbytes for v in state if isinstance(v, np.ndarray)))
         t0 = _time.perf_counter()
-        out = burst_cycles(
-            a["wl_req"], a["wl_rank"], a["wl_cycle_rank"],
-            a["wl_prio"], a["wl_uidrank"], a["vec_ok"],
-            elig0, parked0, resume0,
-            adm0, adm_seq0, adm_usage0,
-            adm_uses0, death0, np.int32(seq_base),
-            u_cq0,
-            a["potential0"], a["subtree"], a["guaranteed"],
-            a["borrow_cap"], a["has_blim"], a["parent"],
-            a["node_level"], a["nominal_cq"], a["npb_cq"],
-            a["slot_fr"], a["slot_valid"], a["cq_can_preempt_borrow"],
-            a["cq_wcb_borrow"], a["cq_wcp_preempt"],
-            a["forest_of_cq"], a["strict_cq"],
-            a["wcq_lower"], a["rwc_enabled"], a["rwc_only_lower"],
-            a["preempt_ok"],
-            a["members"], a["cand_rows"], a["cand_lmem"],
-            a["self_lmem"],
-            ext_release, ext_unpark,
-            K=K, depth=st.depth, L=plan.L,
-            S=int(st.slot_fr.shape[1]), KC=plan.KC,
-            n_levels=plan.n_levels, G=plan.G, runtime=max(0, runtime))
+        with _span("burst.dispatch.launch"):
+            out = burst_cycles(
+                a["wl_req"], a["wl_rank"], a["wl_cycle_rank"],
+                a["wl_prio"], a["wl_uidrank"], a["vec_ok"],
+                elig0, parked0, resume0,
+                adm0, adm_seq0, adm_usage0,
+                adm_uses0, death0, np.int32(seq_base),
+                u_cq0,
+                a["potential0"], a["subtree"], a["guaranteed"],
+                a["borrow_cap"], a["has_blim"], a["parent"],
+                a["node_level"], a["nominal_cq"], a["npb_cq"],
+                a["slot_fr"], a["slot_valid"], a["cq_can_preempt_borrow"],
+                a["cq_wcb_borrow"], a["cq_wcp_preempt"],
+                a["forest_of_cq"], a["strict_cq"],
+                a["wcq_lower"], a["rwc_enabled"], a["rwc_only_lower"],
+                a["preempt_ok"],
+                a["members"], a["cand_rows"], a["cand_lmem"],
+                a["self_lmem"],
+                ext_release, ext_unpark,
+                K=K, depth=st.depth, L=plan.L,
+                S=int(st.slot_fr.shape[1]), KC=plan.KC,
+                n_levels=plan.n_levels, G=plan.G, runtime=max(0, runtime))
         self.stats["burst_dispatches"] += 1
         self.stats["burst_cycles_decided"] += K
         if speculative:

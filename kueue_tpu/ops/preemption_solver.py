@@ -18,6 +18,7 @@ from ..api.types import (
     IN_COHORT_RECLAIM_WHILE_BORROWING_REASON,
     IN_COHORT_RECLAMATION_REASON,
 )
+from ..obs.trace import span as _span
 from .device import on_accelerator, output_devices
 from .packing import pack_cycle
 from .preemption_kernel import minimal_preemptions
@@ -91,6 +92,16 @@ def _planes_for(packed) -> Optional[_ForestPlanes]:
     return planes
 
 
+def _refused(stats: Optional[dict], reason: str) -> None:
+    """Count one refusal of the batched search under its one reason
+    (``over_k``, ``over_s`` or ``unpackable``); the caller then runs a
+    launch a head."""
+    if stats is not None:
+        stats["search_batch_refusals"] += 1
+        stats["search_refused_" + reason] += 1
+    return None
+
+
 def device_minimal_preemptions_batch(specs, packed,
                                      stats: Optional[dict] = None):
     """ALL of a cycle's preemption searches in one vmapped dispatch,
@@ -100,16 +111,39 @@ def device_minimal_preemptions_batch(specs, packed,
     per-head search requests the preemptor planned (every search is
     against the same nominate-time snapshot, so they are independent).
     Returns a list of per-spec Target lists ([] = search failed), or
-    None when any spec can't be packed (caller runs the host path).
+    None when the batch is refused: a spec can't be packed, or the
+    batch is over the shape ladders' top rung (the caller runs a launch
+    a head; ``stats`` counts the refusal under its one reason).
     ``stats["accel_searches"]`` counts the searches whose output landed
-    on an accelerator."""
-    from ..scheduler.preemption import Target  # circular-safe import
-
-    if packed is None or not packed.exact or not specs:
+    on an accelerator, ``search_batch_launches`` the launches, and
+    ``search_candidate_slots`` / ``search_padded_slots`` the real
+    candidates against the S x K slots of the bucket launched."""
+    with _span("cycle.nominate.search_pack"):
+        args = _pack_batch(specs, packed, stats)
+    if args is None:
         return None
+    with _span("cycle.nominate.search_launch"):
+        from .preemption_kernel import minimal_preemptions_batch
+        fitted, mask = minimal_preemptions_batch(*args, depth=packed.depth)
+        if stats is not None:
+            stats["search_batch_launches"] += 1
+            if on_accelerator(output_devices(fitted)):
+                stats["accel_searches"] += len(specs)
+        fitted = np.asarray(fitted)
+        mask = np.asarray(mask)
+    with _span("cycle.nominate.search_decode"):
+        return _decode_batch(specs, fitted, mask)
+
+
+def _pack_batch(specs, packed, stats: Optional[dict]):
+    """The numpy planes of one batched launch (the kernel's positional
+    arguments, their real and padded candidate slots counted), or None
+    with the refusal counted."""
+    if packed is None or not packed.exact or not specs:
+        return _refused(stats, "unpackable")
     planes = _planes_for(packed)
     if planes is None:
-        return None
+        return _refused(stats, "unpackable")
     cq_idx = {n: i for i, n in enumerate(packed.cq_names)}
     F = packed.usage0.shape[1]
     scale_of = {r: int(packed.resource_scale[i])
@@ -135,8 +169,10 @@ def device_minimal_preemptions_batch(specs, packed,
     # host path runs (None), never an array overflow.
     from .packing import coarse_bucket
     max_cands = max(1, max(len(c) for _, c, _, _ in specs))
-    if len(specs) > S_LADDER[-1] or max_cands > K_LADDER[-1]:
-        return None
+    if len(specs) > S_LADDER[-1]:
+        return _refused(stats, "over_s")
+    if max_cands > K_LADDER[-1]:
+        return _refused(stats, "over_k")
     S = coarse_bucket(len(specs), S_LADDER)
     K = coarse_bucket(max_cands, K_LADDER)
     NL = planes.NL
@@ -158,52 +194,55 @@ def device_minimal_preemptions_batch(specs, packed,
     for si, (ctx, candidates, allow_borrowing, threshold) in enumerate(specs):
         ci = cq_idx.get(ctx.preemptor_cq.name)
         if ci is None or ci not in planes.local:
-            return None
+            return _refused(stats, "unpackable")
         f, ci_local = planes.local[ci]
         wu = to_f_vec(ctx.workload_usage)
         if wu is None:
-            return None
+            return _refused(stats, "unpackable")
         forest_of[si] = f
         pre_cq[si] = ci_local
         wl_usage[si] = wu
         for fr in ctx.frs_need_preemption:
             fi = packed.fr_index.get(fr)
             if fi is None:
-                return None
+                return _refused(stats, "unpackable")
             frs_mask[si, fi] = True
         allow_b0[si] = allow_borrowing
         thr_en[si] = threshold is not None
         for k, cand in enumerate(candidates):
             cci = cq_idx.get(cand.cluster_queue)
             if cci is None:
-                return None
+                return _refused(stats, "unpackable")
             cf_local = planes.local.get(cci)
             if cf_local is None or cf_local[0] != f:
-                return None   # candidate outside the preemptor's forest
+                # candidate outside the preemptor's forest
+                return _refused(stats, "unpackable")
             delta = vec_cache.get(cand.key)
             if delta is None and cand.key not in vec_cache:
                 delta = to_f_vec(cand.usage())
                 vec_cache[cand.key] = delta
             if delta is None:
-                return None
+                return _refused(stats, "unpackable")
             cand_cq[si, k] = cf_local[1]
             cand_delta[si, k] = delta
             cand_other[si, k] = cand.cluster_queue != ctx.preemptor_cq.name
             cand_above[si, k] = (threshold is not None
                                  and cand.obj.priority >= threshold)
 
-    from .preemption_kernel import minimal_preemptions_batch
-    fitted, mask = minimal_preemptions_batch(
-        usage_planes[forest_of], planes.subtree[forest_of],
-        planes.guaranteed[forest_of], planes.borrow_cap[forest_of],
-        planes.has_blim[forest_of], planes.parent[forest_of],
-        pre_cq, wl_usage, frs_mask, cand_cq, cand_delta, cand_other,
-        cand_above, allow_b0, thr_en, depth=packed.depth)
-    if stats is not None and on_accelerator(output_devices(fitted)):
-        stats["accel_searches"] += len(specs)
-    fitted = np.asarray(fitted)
-    mask = np.asarray(mask)
+    if stats is not None:
+        stats["search_candidate_slots"] += sum(
+            len(c) for _, c, _, _ in specs)
+        stats["search_padded_slots"] += S * K
+    return (usage_planes[forest_of], planes.subtree[forest_of],
+            planes.guaranteed[forest_of], planes.borrow_cap[forest_of],
+            planes.has_blim[forest_of], planes.parent[forest_of],
+            pre_cq, wl_usage, frs_mask, cand_cq, cand_delta, cand_other,
+            cand_above, allow_b0, thr_en)
 
+
+def _decode_batch(specs, fitted, mask):
+    """The launch's masks as per-spec Target lists ([] = no fit)."""
+    from ..scheduler.preemption import Target  # circular-safe import
     out = []
     for si, (ctx, candidates, _, threshold) in enumerate(specs):
         if not fitted[si]:
@@ -297,8 +336,10 @@ def device_minimal_preemptions(ctx, candidates, allow_borrowing: bool,
         pre_cq, wl_usage, frs_mask, cand_cq, cand_delta, cand_other,
         cand_above, allow_borrowing, threshold is not None,
         depth=packed.depth)
-    if stats is not None and on_accelerator(output_devices(fitted)):
-        stats["accel_searches"] += 1
+    if stats is not None:
+        stats["search_single_launches"] += 1
+        if on_accelerator(output_devices(fitted)):
+            stats["accel_searches"] += 1
     if not bool(fitted):
         return []
     mask = np.asarray(target_mask)

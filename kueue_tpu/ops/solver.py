@@ -980,7 +980,6 @@ class CycleSolver:
         preempt = ((pmask, pre_fr, pre_amt, targets.tgt_mat,
                     targets.tu_cq, targets.tu_delta)
                    if has_preempt else None)
-        from ..profiling import annotation
         sharded = self.mesh is not None
         if sharded:
             # production mesh routing takes precedence over the native
@@ -1000,10 +999,8 @@ class CycleSolver:
             handle.route = "native"
             self.stats["native_dispatches"] += 1
             return handle
-        name = "admit_scan_sharded" if sharded else "admit_scan"
-        with annotation(f"{name}:{kernel}"):
-            handle.pending = self._scan(st, args, order, mfw=mfw,
-                                        preempt=preempt)
+        handle.pending = self._scan(st, args, order, mfw=mfw,
+                                    preempt=preempt)
         route = self._count_dispatch(handle.pending)
         handle.route = "sharded" if sharded else route
         return handle
@@ -1069,7 +1066,6 @@ class CycleSolver:
         handle = DispatchHandle(order=np.arange(W, dtype=np.int32),
                                 rmask=np.zeros(W, dtype=bool), n=n)
         handle.fit_mask = fit_mask
-        from ..profiling import annotation
         fs_args = (packed.usage0, st.subtree_quota, statics.sq_mask,
                    st.guaranteed, st.borrow_cap, st.has_borrow_limit,
                    st.parent, statics.node_level, st.fair_weight_milli,
@@ -1088,12 +1084,10 @@ class CycleSolver:
                 self._sharded_fns[key] = fn
             self.stats["sharded_fs_dispatches"] = (
                 self.stats.get("sharded_fs_dispatches", 0) + 1)
-            with annotation("fs_admit_scan"):
-                out = fn(*fs_args)
+            out = fn(*fs_args)
         else:
-            with annotation("fs_admit_scan"):
-                out = fs_admit_scan(*fs_args, depth=st.depth,
-                                    n_levels=statics.n_levels)
+            out = fs_admit_scan(*fs_args, depth=st.depth,
+                                n_levels=statics.n_levels)
         handle.route = self._count_dispatch(out)
         handle.pending = ("fs", out)
         return handle

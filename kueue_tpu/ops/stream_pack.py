@@ -46,6 +46,7 @@ from typing import Optional
 import numpy as np
 
 from ..cache.arena import PlaneArena
+from ..obs.trace import span as _span
 from . import aggregate as _agg
 from . import burst as _b
 from .packing import _bucket
@@ -523,150 +524,152 @@ def _init_full(st, queues, cache, scheduler, key, min_m, window, arena,
     so the first streaming plan equals the reference plan bit for bit."""
     if _b._unknown_active_cq(st, queues):
         return None, None, False
-    records = _b._walk_records(st, queues, cache, scheduler, window)
+    with _span("burst.pack.walk"):
+        records = _b._walk_records(st, queues, cache, scheduler, window)
     if records is None:
         return None, None, False
-    C = len(st.cq_names)
-    R = len(st.resource_names)
-    F = max(1, len(st.fr_index))
-    s = _b._pack_statics(st, cache)
+    with _span("burst.pack.grid"):
+        C = len(st.cq_names)
+        R = len(st.resource_names)
+        F = max(1, len(st.fr_index))
+        s = _b._pack_statics(st, cache)
 
-    state = StreamState(key, arena)
-    state.records = records
-    state.cq_names_list = list(queues.cluster_queue_names())
-    pos_of = {name: i for i, name in enumerate(state.cq_names_list)}
-    state.pos_cq = np.fromiter(
-        (pos_of.get(nm, C) for nm in st.cq_names), np.int32, C)
-    for rec in records:
-        rec.pos = int(state.pos_cq[rec.ci])
-    state.n_rows_cq = np.fromiter((r.n_rows for r in records),
-                                  np.int64, C)
-    state.n_pend_cq = np.fromiter((r.n_pend for r in records),
-                                  np.int64, C)
-    state.bad_cq = np.fromiter((r.bad for r in records), bool, C)
-    state.strict_cq = np.fromiter((r.strict for r in records), bool, C)
-    state.n_comp_cq = np.fromiter((r.n_comp for r in records),
-                                  np.int64, C)
-    state.comp_max_cq = np.fromiter((r.comp_max_ts for r in records),
-                                    np.float64, C)
-    bounds = np.concatenate(([0], np.cumsum(state.n_rows_cq)))
-    n = int(bounds[-1])
+        state = StreamState(key, arena)
+        state.records = records
+        state.cq_names_list = list(queues.cluster_queue_names())
+        pos_of = {name: i for i, name in enumerate(state.cq_names_list)}
+        state.pos_cq = np.fromiter(
+            (pos_of.get(nm, C) for nm in st.cq_names), np.int32, C)
+        for rec in records:
+            rec.pos = int(state.pos_cq[rec.ci])
+        state.n_rows_cq = np.fromiter((r.n_rows for r in records),
+                                      np.int64, C)
+        state.n_pend_cq = np.fromiter((r.n_pend for r in records),
+                                      np.int64, C)
+        state.bad_cq = np.fromiter((r.bad for r in records), bool, C)
+        state.strict_cq = np.fromiter((r.strict for r in records), bool, C)
+        state.n_comp_cq = np.fromiter((r.n_comp for r in records),
+                                      np.int64, C)
+        state.comp_max_cq = np.fromiter((r.comp_max_ts for r in records),
+                                        np.float64, C)
+        bounds = np.concatenate(([0], np.cumsum(state.n_rows_cq)))
+        n = int(bounds[-1])
 
-    nz = [r for r in records if r.n_rows]
-    def cat(attr, empty_dtype):
-        if nz:
-            return np.concatenate([getattr(r, attr) for r in nz])
-        return np.empty(0, dtype=empty_dtype)
-    keys_a = cat("keys", "U1")
-    uids_a = cat("uids", "U1")
-    prio_a = cat("prio", np.int64)
-    ts_a = cat("ts", np.float64)
-    res_ts_a = cat("res_ts", np.float64)
-    adm_a = cat("adm", bool)
-    kb_all = _enc_str(keys_a, _KEY_BYTES)      # may bail -> caller
-    ub_all = _enc_str(uids_a, _UID_BYTES)
-    ci_a = np.repeat(np.arange(C, dtype=np.int32), state.n_rows_cq)
-    pos_a = np.repeat(state.pos_cq, state.n_rows_cq)
+        nz = [r for r in records if r.n_rows]
+        def cat(attr, empty_dtype):
+            if nz:
+                return np.concatenate([getattr(r, attr) for r in nz])
+            return np.empty(0, dtype=empty_dtype)
+        keys_a = cat("keys", "U1")
+        uids_a = cat("uids", "U1")
+        prio_a = cat("prio", np.int64)
+        ts_a = cat("ts", np.float64)
+        res_ts_a = cat("res_ts", np.float64)
+        adm_a = cat("adm", bool)
+        kb_all = _enc_str(keys_a, _KEY_BYTES)      # may bail -> caller
+        ub_all = _enc_str(uids_a, _UID_BYTES)
+        ci_a = np.repeat(np.arange(C, dtype=np.int32), state.n_rows_cq)
+        pos_a = np.repeat(state.pos_cq, state.n_rows_cq)
 
-    # per-CQ |prio| maxima (reduceat; empty segments masked out)
-    state.maxabs_prio_cq = np.zeros(C, np.int64)
-    if n:
-        red = np.maximum.reduceat(
-            np.abs(prio_a), np.minimum(bounds[:-1], n - 1))
-        state.maxabs_prio_cq = np.where(state.n_rows_cq > 0, red, 0)
+        # per-CQ |prio| maxima (reduceat; empty segments masked out)
+        state.maxabs_prio_cq = np.zeros(C, np.int64)
+        if n:
+            red = np.maximum.reduceat(
+                np.abs(prio_a), np.minimum(bounds[:-1], n - 1))
+            state.maxabs_prio_cq = np.where(state.n_rows_cq > 0, red, 0)
 
-    rows_per_cq = int(state.n_rows_cq.max(initial=0))
-    state.M = M = max(_bucket(rows_per_cq, minimum=4), min_m)
-    views = _views(arena, C, M, R, F)
-    _reset_views(views)
+        rows_per_cq = int(state.n_rows_cq.max(initial=0))
+        state.M = M = max(_bucket(rows_per_cq, minimum=4), min_m)
+        views = _views(arena, C, M, R, F)
+        _reset_views(views)
 
-    # per-CQ heap rank: the reference ci-segmented lexsort
-    order = np.lexsort((keys_a, ts_a, -prio_a, ci_a))
-    ci_sorted = ci_a[order]
-    first = np.ones(n, dtype=bool)
-    first[1:] = ci_sorted[1:] != ci_sorted[:-1]
-    idx = np.arange(n, dtype=np.int32)
-    seg_start = np.maximum.accumulate(np.where(first, idx, np.int32(0)))
-    mi_sorted = idx - seg_start
-    mi_a = np.empty(n, dtype=np.int32)
-    mi_a[order] = mi_sorted
+        # per-CQ heap rank: the reference ci-segmented lexsort
+        order = np.lexsort((keys_a, ts_a, -prio_a, ci_a))
+        ci_sorted = ci_a[order]
+        first = np.ones(n, dtype=bool)
+        first[1:] = ci_sorted[1:] != ci_sorted[:-1]
+        idx = np.arange(n, dtype=np.int32)
+        seg_start = np.maximum.accumulate(np.where(first, idx, np.int32(0)))
+        mi_sorted = idx - seg_start
+        mi_a = np.empty(n, dtype=np.int32)
+        mi_a[order] = mi_sorted
 
-    state.mi_of = {}
-    state.kb_of = {}
-    for ci in range(C):
-        lo, hi = int(bounds[ci]), int(bounds[ci + 1])
-        state.mi_of[ci] = mi_a[lo:hi]
-        state.kb_of[ci] = kb_all[lo:hi]
+        state.mi_of = {}
+        state.kb_of = {}
+        for ci in range(C):
+            lo, hi = int(bounds[ci]), int(bounds[ci + 1])
+            state.mi_of[ci] = mi_a[lo:hi]
+            state.kb_of[ci] = kb_all[lo:hi]
 
-    if n:
-        views["wl_req"][ci_a, mi_a] = cat("req", np.int32)
-        views["wl_rank"][ci_a, mi_a] = mi_a
-        views["wl_prio"][ci_a, mi_a] = np.clip(
-            prio_a, -_b.I32_MAX, _b.I32_MAX)
-        parked_a = cat("parked", bool)
-        views["parked0"][ci_a, mi_a] = parked_a
-        views["elig0"][ci_a, mi_a] = ~parked_a & ~adm_a
-        views["vec_ok"][ci_a, mi_a] = cat("ok", bool)
-        views["resume0"][ci_a, mi_a] = cat("resume", np.int32)
-        views["adm0"][ci_a, mi_a] = adm_a
-        views["adm_usage0"][ci_a, mi_a] = cat("usage", np.int32)
-        views["adm_uses0"][ci_a, mi_a] = cat("uses", bool)
-        key_list = keys_a.tolist()
-        views["keys_grid"][ci_a, mi_a] = np.array(key_list, dtype=object)
-        state.row_of_key = dict(zip(
-            key_list, zip(ci_a.tolist(), mi_a.tolist())))
-    else:
-        state.row_of_key = {}
-    for ci, rec in enumerate(records):
-        views["u_cq0"][ci] = rec.u_row
-    _agg.agg_fill(views, records)
+        if n:
+            views["wl_req"][ci_a, mi_a] = cat("req", np.int32)
+            views["wl_rank"][ci_a, mi_a] = mi_a
+            views["wl_prio"][ci_a, mi_a] = np.clip(
+                prio_a, -_b.I32_MAX, _b.I32_MAX)
+            parked_a = cat("parked", bool)
+            views["parked0"][ci_a, mi_a] = parked_a
+            views["elig0"][ci_a, mi_a] = ~parked_a & ~adm_a
+            views["vec_ok"][ci_a, mi_a] = cat("ok", bool)
+            views["resume0"][ci_a, mi_a] = cat("resume", np.int32)
+            views["adm0"][ci_a, mi_a] = adm_a
+            views["adm_usage0"][ci_a, mi_a] = cat("usage", np.int32)
+            views["adm_uses0"][ci_a, mi_a] = cat("uses", bool)
+            key_list = keys_a.tolist()
+            views["keys_grid"][ci_a, mi_a] = np.array(key_list, dtype=object)
+            state.row_of_key = dict(zip(
+                key_list, zip(ci_a.tolist(), mi_a.tolist())))
+        else:
+            state.row_of_key = {}
+        for ci, rec in enumerate(records):
+            views["u_cq0"][ci] = rec.u_row
+        _agg.agg_fill(views, records)
 
-    # maintained global orders + their dense rank planes
-    state.crank = _Order(_SKEY_S)
-    state.crank.set(_crank_skey(prio_a, ts_a, pos_a, kb_all),
-                    ci_a, mi_a)
-    if n:
-        views["wl_cycle_rank"][state.crank.ci, state.crank.mi] = \
-            np.arange(n, dtype=np.int32)
-    # head-pack: the uid order (and so the 19-bit uidrank field) only
-    # tracks budget rows — rows of preempting forests; exempt rows keep
-    # the pad rank 0, which the kernel never reads for them (candidate
-    # eligibility needs the head's wcq_lower/rwc_enabled census bits)
-    state.uord = _Order(f"S{_UID_BYTES}")
-    if _agg.head_pack_enabled() and n:
-        bsel = np.nonzero(~s.comp_cq[ci_a])[0]
-        state.uord.set(ub_all[bsel], ci_a[bsel], mi_a[bsel])
-    else:
-        state.uord.set(ub_all, ci_a, mi_a)
-    n_uord = len(state.uord.ci)
-    if n_uord:
-        views["wl_uidrank"][state.uord.ci, state.uord.mi] = \
-            np.arange(n_uord, dtype=np.int32)
-    am = np.nonzero(adm_a)[0]
-    ats = res_ts_a[am]
-    aord = np.argsort(ats, kind="stable")
-    state.adm_ts = ats[aord]
-    state.adm_ci = ci_a[am][aord]
-    state.adm_mi = mi_a[am][aord]
-    if len(state.adm_ts):
-        uniq = np.unique(state.adm_ts)
-        state.adm_seq_cache = (np.searchsorted(uniq, state.adm_ts)
-                               + 1).astype(np.int32)
-        views["adm_seq0"][state.adm_ci, state.adm_mi] = \
-            state.adm_seq_cache
-    else:
-        state.adm_seq_cache = np.empty(0, np.int32)
+        # maintained global orders + their dense rank planes
+        state.crank = _Order(_SKEY_S)
+        state.crank.set(_crank_skey(prio_a, ts_a, pos_a, kb_all),
+                        ci_a, mi_a)
+        if n:
+            views["wl_cycle_rank"][state.crank.ci, state.crank.mi] = \
+                np.arange(n, dtype=np.int32)
+        # head-pack: the uid order (and so the 19-bit uidrank field) only
+        # tracks budget rows — rows of preempting forests; exempt rows keep
+        # the pad rank 0, which the kernel never reads for them (candidate
+        # eligibility needs the head's wcq_lower/rwc_enabled census bits)
+        state.uord = _Order(f"S{_UID_BYTES}")
+        if _agg.head_pack_enabled() and n:
+            bsel = np.nonzero(~s.comp_cq[ci_a])[0]
+            state.uord.set(ub_all[bsel], ci_a[bsel], mi_a[bsel])
+        else:
+            state.uord.set(ub_all, ci_a, mi_a)
+        n_uord = len(state.uord.ci)
+        if n_uord:
+            views["wl_uidrank"][state.uord.ci, state.uord.mi] = \
+                np.arange(n_uord, dtype=np.int32)
+        am = np.nonzero(adm_a)[0]
+        ats = res_ts_a[am]
+        aord = np.argsort(ats, kind="stable")
+        state.adm_ts = ats[aord]
+        state.adm_ci = ci_a[am][aord]
+        state.adm_mi = mi_a[am][aord]
+        if len(state.adm_ts):
+            uniq = np.unique(state.adm_ts)
+            state.adm_seq_cache = (np.searchsorted(uniq, state.adm_ts)
+                                   + 1).astype(np.int32)
+            views["adm_seq0"][state.adm_ci, state.adm_mi] = \
+                state.adm_seq_cache
+        else:
+            state.adm_seq_cache = np.empty(0, np.int32)
 
-    _bump(stats, "burst_full_packs")
-    _bump(stats, "stream_full_packs")
-    _bump(stats, "rows_repacked", n)
-    if int(state.n_pend_cq.sum()) == 0:
+        _bump(stats, "burst_full_packs")
+        _bump(stats, "stream_full_packs")
+        _bump(stats, "rows_repacked", n)
+        if int(state.n_pend_cq.sum()) == 0:
+            _note_ms(stats, t0)
+            return None, state, False
+        plan = _materialize(st, state, s, views, scheduler, None,
+                            None, 0, stats)
         _note_ms(stats, t0)
-        return None, state, False
-    plan = _materialize(st, state, s, views, scheduler, None,
-                        None, 0, stats)
-    _note_ms(stats, t0)
-    return plan, state, False
+        return plan, state, False
 
 
 def _note_ms(stats, t0, delta=False):
@@ -694,13 +697,15 @@ def pack_burst_streaming(structure, queues, cache, scheduler, clock,
     rows: dict = {}
     jranges: list = []
     force_full = False
-    for j in (getattr(queues, "pack_journal", None),
-              getattr(cache, "pack_journal", None)):
-        if j is None:
-            force_full = True
-        else:
-            force_full |= j.drain_into(dirty, soft, row_of=st.cq_index,
-                                       ranges_out=jranges, rows_out=rows)
+    with _span("burst.pack.drain"):
+        for j in (getattr(queues, "pack_journal", None),
+                  getattr(cache, "pack_journal", None)):
+            if j is None:
+                force_full = True
+            else:
+                force_full |= j.drain_into(
+                    dirty, soft, row_of=st.cq_index, ranges_out=jranges,
+                    rows_out=rows)
     arena = getattr(cache, "_pack_arena", None)
     if arena is None:
         arena = cache._pack_arena = PlaneArena()
@@ -711,261 +716,264 @@ def pack_burst_streaming(structure, queues, cache, scheduler, clock,
             return _init_full(st, queues, cache, scheduler, key, min_m,
                               window, arena, stats, t0)
 
-        index_of = st.cq_index
-        C = len(st.cq_names)
-        for name in set(dirty) | set(soft) | set(rows.values()):
-            if name not in index_of:
-                q = queues.queue_for(name)
-                if q is not None and q.active and q.pending_active():
-                    return None, None, False
-        for name, skeys in soft.items():
-            ci = index_of.get(name)
-            if ci is None or name in dirty:
-                continue
-            if not _b._roundtrips_clean(
-                    state.records[ci], queues.queue_for(name),
-                    cache.cluster_queue(name), skeys,
-                    name in st.cq_covers_pods):
-                dirty.add(name)
-        row_jobs = []
-        rows_verified = 0
-        for wkey, name in rows.items():
-            ci = index_of.get(name)
-            if ci is None or name in dirty:
-                continue
-            job = _row_patch_job(state, st, queues, cache, scheduler,
-                                 ci, wkey)
-            if job is _ESCALATE:
-                dirty.add(name)
-            elif job is not None:
-                row_jobs.append(job)
-            else:
-                rows_verified += 1
-        if rows_verified:
-            _bump(stats, "pack_rows_verified", rows_verified)
+        with _span("burst.pack.drain"):
+            index_of = st.cq_index
+            C = len(st.cq_names)
+            for name in set(dirty) | set(soft) | set(rows.values()):
+                if name not in index_of:
+                    q = queues.queue_for(name)
+                    if q is not None and q.active and q.pending_active():
+                        return None, None, False
+            for name, skeys in soft.items():
+                ci = index_of.get(name)
+                if ci is None or name in dirty:
+                    continue
+                if not _b._roundtrips_clean(
+                        state.records[ci], queues.queue_for(name),
+                        cache.cluster_queue(name), skeys,
+                        name in st.cq_covers_pods):
+                    dirty.add(name)
+            row_jobs = []
+            rows_verified = 0
+            for wkey, name in rows.items():
+                ci = index_of.get(name)
+                if ci is None or name in dirty:
+                    continue
+                job = _row_patch_job(state, st, queues, cache, scheduler,
+                                     ci, wkey)
+                if job is _ESCALATE:
+                    dirty.add(name)
+                elif job is not None:
+                    row_jobs.append(job)
+                else:
+                    rows_verified += 1
+            if rows_verified:
+                _bump(stats, "pack_rows_verified", rows_verified)
 
         if len(dirty) > max(_b._DELTA_MIN_DIRTY_CQS,
                             _b._DELTA_MAX_DIRTY_FRAC * C):
             return _init_full(st, queues, cache, scheduler, key, min_m,
                               window, arena, stats, t0)
 
-        # heads-enumeration position drift (CQs joined/left the queue
-        # manager without a structure change): the crank sort keys of
-        # every row of a moved CQ change, nothing else does
-        pos_dirty_cis: list = []
-        names_now = queues.cluster_queue_names()
-        if state.cq_names_list != names_now:
-            pos_of = {nm: i for i, nm in enumerate(names_now)}
-            newpos = np.fromiter(
-                (pos_of.get(nm, C) for nm in st.cq_names), np.int32, C)
-            for ci in np.nonzero(newpos != state.pos_cq)[0]:
-                ci = int(ci)
-                pos_dirty_cis.append(ci)
-                state.records[ci].pos = int(newpos[ci])
-            state.pos_cq = newpos
-            state.cq_names_list = list(names_now)
+        with _span("burst.pack.walk"):
+            # heads-enumeration position drift (CQs joined/left the queue
+            # manager without a structure change): the crank sort keys of
+            # every row of a moved CQ change, nothing else does
+            pos_dirty_cis: list = []
+            names_now = queues.cluster_queue_names()
+            if state.cq_names_list != names_now:
+                pos_of = {nm: i for i, nm in enumerate(names_now)}
+                newpos = np.fromiter(
+                    (pos_of.get(nm, C) for nm in st.cq_names), np.int32, C)
+                for ci in np.nonzero(newpos != state.pos_cq)[0]:
+                    ci = int(ci)
+                    pos_dirty_cis.append(ci)
+                    state.records[ci].pos = int(newpos[ci])
+                state.pos_cq = newpos
+                state.cq_names_list = list(names_now)
 
-        # stage A over the dirty CQs only; encode before mutating so a
-        # bail leaves the state coherent
-        assumed = cache.assumed_workloads
-        scale_of = {r: int(st.resource_scale[i])
-                    for i, r in enumerate(st.resource_names)}
-        statics = _b._pack_statics(st, cache)
-        comp_cq = (statics.comp_cq if _agg.agg_planes_enabled()
-                   else None)
-        def _walk_one(ci):
-            rec = _b._pack_cq_rows(st, ci, int(state.pos_cq[ci]),
-                                   queues, cache, scheduler, assumed,
-                                   scale_of, window,
-                                   compress=(comp_cq is not None
-                                             and bool(comp_cq[ci])))
-            if rec is _b._PACK_FAIL:
-                return None
-            kb = _enc_str(rec.keys, _KEY_BYTES)
-            ub = _enc_str(rec.uids, _UID_BYTES)
-            return (ci, rec, kb, ub, _cq_mi(rec))
+            # stage A over the dirty CQs only; encode before mutating so a
+            # bail leaves the state coherent
+            assumed = cache.assumed_workloads
+            scale_of = {r: int(st.resource_scale[i])
+                        for i, r in enumerate(st.resource_names)}
+            statics = _b._pack_statics(st, cache)
+            comp_cq = (statics.comp_cq if _agg.agg_planes_enabled()
+                       else None)
+            def _walk_one(ci):
+                rec = _b._pack_cq_rows(st, ci, int(state.pos_cq[ci]),
+                                       queues, cache, scheduler, assumed,
+                                       scale_of, window,
+                                       compress=(comp_cq is not None
+                                                 and bool(comp_cq[ci])))
+                if rec is _b._PACK_FAIL:
+                    return None
+                kb = _enc_str(rec.keys, _KEY_BYTES)
+                ub = _enc_str(rec.uids, _UID_BYTES)
+                return (ci, rec, kb, ub, _cq_mi(rec))
 
-        cis = sorted(ci for name in dirty
-                     if (ci := index_of.get(name)) is not None)
-        # stage A is per-CQ pure (each walk reads shared structure and
-        # writes only its own CQ's rows/memos), so the host pool fans
-        # the dirty walk out by cohort forest; the gather is in
-        # ascending (forest, ci) order, and every downstream merge is
-        # order-insensitive (sorted-order updates, disjoint row writes),
-        # so pooled and serial walks build identical states
-        pool = getattr(cache, "host_pool", None)
-        if pool is not None and pool.active and len(cis) >= 2:
-            fcq = statics.forest_of_cq
-            parts = pool.map_partitions(
-                cis, lambda ci: int(fcq[ci]),
-                lambda g, part: [_walk_one(ci) for ci in part])
-            walked = [w for part in parts for w in part]
-        else:
-            walked = [_walk_one(ci) for ci in cis]
-        if any(w is None for w in walked):
-            return None, None, False
-
-        for ci, rec, kb, ub, mi in walked:
-            state.n_rows_cq[ci] = rec.n_rows
-            state.n_pend_cq[ci] = rec.n_pend
-            state.bad_cq[ci] = rec.bad
-            state.strict_cq[ci] = rec.strict
-            state.n_comp_cq[ci] = rec.n_comp
-            state.comp_max_cq[ci] = rec.comp_max_ts
-            state.maxabs_prio_cq[ci] = int(
-                np.abs(rec.prio).max(initial=0))
-        rows_per_cq = int(state.n_rows_cq.max(initial=0))
-        state.M = M = max(_bucket(rows_per_cq, minimum=4), min_m)
-        R = len(st.resource_names)
-        F = max(1, len(st.fr_index))
-        views = _views(arena, C, M, R, F)
-
-        for ci, rec, kb, ub, mi in walked:
-            _clear_cq(state, views, ci)
-            _write_cq(state, views, ci, rec, mi)
-            state.records[ci] = rec
-            state.mi_of[ci] = mi
-            state.kb_of[ci] = kb
-
-        rank_patches = 0
-        # cycle-order rank: drop dirty + pos-moved CQ entries, merge the
-        # fresh ones back in, rewrite the dense rank suffix
-        walked_cis = [w[0] for w in walked]
-        crank_drop = np.asarray(walked_cis + pos_dirty_cis, np.int32)
-        ins_sk, ins_ci, ins_mi = [], [], []
-        for ci, rec, kb, ub, mi in walked:
-            if rec.n_rows:
-                ins_sk.append(_crank_skey(
-                    rec.prio, rec.ts,
-                    np.full(rec.n_rows, state.pos_cq[ci], np.int64), kb))
-                ins_ci.append(np.full(rec.n_rows, ci, np.int32))
-                ins_mi.append(mi)
-        for ci in pos_dirty_cis:
-            rec = state.records[ci]
-            if rec.n_rows:
-                ins_sk.append(_crank_skey(
-                    rec.prio, rec.ts,
-                    np.full(rec.n_rows, state.pos_cq[ci], np.int64),
-                    state.kb_of[ci]))
-                ins_ci.append(np.full(rec.n_rows, ci, np.int32))
-                ins_mi.append(state.mi_of[ci])
-        sfrom = state.crank.update(
-            crank_drop,
-            np.concatenate(ins_sk) if ins_sk
-            else np.empty(0, _SKEY_S),
-            np.concatenate(ins_ci) if ins_ci else (),
-            np.concatenate(ins_mi) if ins_mi else ())
-        if sfrom is not None:
-            ntot = len(state.crank.skey)
-            views["wl_cycle_rank"][
-                state.crank.ci[sfrom:], state.crank.mi[sfrom:]] = \
-                np.arange(sfrom, ntot, dtype=np.int32)
-            rank_patches += ntot - sfrom
-
-        # uid rank: same mechanism, dirty CQs only; head-pack keeps
-        # exempt (never-candidate) CQs out of the maintained uid order,
-        # mirroring the _init_full budget filter
-        head_pack = _agg.head_pack_enabled()
-        ins_sk, ins_ci, ins_mi = [], [], []
-        for ci, rec, kb, ub, mi in walked:
-            if rec.n_rows and not (head_pack and statics.comp_cq[ci]):
-                ins_sk.append(ub)
-                ins_ci.append(np.full(rec.n_rows, ci, np.int32))
-                ins_mi.append(mi)
-        sfrom = state.uord.update(
-            np.asarray(walked_cis, np.int32),
-            np.concatenate(ins_sk) if ins_sk
-            else np.empty(0, f"S{_UID_BYTES}"),
-            np.concatenate(ins_ci) if ins_ci else (),
-            np.concatenate(ins_mi) if ins_mi else ())
-        if sfrom is not None:
-            ntot = len(state.uord.skey)
-            views["wl_uidrank"][
-                state.uord.ci[sfrom:], state.uord.mi[sfrom:]] = \
-                np.arange(sfrom, ntot, dtype=np.int32)
-            rank_patches += ntot - sfrom
-
-        # admitted reservation-seq: maintain the sorted ts multiset,
-        # recompute dense seqs vectorized, scatter only changed cells
-        if walked:
-            wset = np.asarray(walked_cis, np.int32)
-            keep = ~np.isin(state.adm_ci, wset) \
-                if len(state.adm_ci) else np.empty(0, bool)
-            a_ts = state.adm_ts[keep]
-            a_ci = state.adm_ci[keep]
-            a_mi = state.adm_mi[keep]
-            a_sq = state.adm_seq_cache[keep]
-            nts, nci, nmi = [], [], []
-            for ci, rec, kb, ub, mi in walked:
-                if rec.n_adm:
-                    am = rec.adm
-                    nts.append(rec.res_ts[am])
-                    nci.append(np.full(int(am.sum()), ci, np.int32))
-                    nmi.append(mi[am])
-            if nts:
-                nts = np.concatenate(nts)
-                srt = np.argsort(nts, kind="stable")
-                nts = nts[srt]
-                nci = np.concatenate(nci)[srt]
-                nmi = np.concatenate(nmi)[srt]
-                pos = np.searchsorted(a_ts, nts)
-                a_ts = np.insert(a_ts, pos, nts)
-                a_ci = np.insert(a_ci, pos, nci)
-                a_mi = np.insert(a_mi, pos, nmi)
-                a_sq = np.insert(a_sq, pos,
-                                 np.full(len(nts), -1, np.int32))
-            state.adm_ts, state.adm_ci, state.adm_mi = a_ts, a_ci, a_mi
-            if len(a_ts):
-                uniq = np.unique(a_ts)
-                seq_all = (np.searchsorted(uniq, a_ts)
-                           + 1).astype(np.int32)
-                chg = seq_all != a_sq
-                if chg.any():
-                    views["adm_seq0"][a_ci[chg], a_mi[chg]] = \
-                        seq_all[chg]
-                    rank_patches += int(chg.sum())
-                state.adm_seq_cache = seq_all
+            cis = sorted(ci for name in dirty
+                         if (ci := index_of.get(name)) is not None)
+            # stage A is per-CQ pure (each walk reads shared structure and
+            # writes only its own CQ's rows/memos), so the host pool fans
+            # the dirty walk out by cohort forest; the gather is in
+            # ascending (forest, ci) order, and every downstream merge is
+            # order-insensitive (sorted-order updates, disjoint row writes),
+            # so pooled and serial walks build identical states
+            pool = getattr(cache, "host_pool", None)
+            if pool is not None and pool.active and len(cis) >= 2:
+                fcq = statics.forest_of_cq
+                parts = pool.map_partitions(
+                    cis, lambda ci: int(fcq[ci]),
+                    lambda g, part: [_walk_one(ci) for ci in part])
+                walked = [w for part in parts for w in part]
             else:
-                state.adm_seq_cache = np.empty(0, np.int32)
+                walked = [_walk_one(ci) for ci in cis]
+            if any(w is None for w in walked):
+                return None, None, False
 
-        # row-grade patches (deduped by the journal): single cells.
-        # A job queued before a later row escalated its CQ to dirty is
-        # stale — the re-walk rebuilt the record (and row order), so its
-        # idx no longer addresses the row it was derived from.
-        wset_cis = set(walked_cis)
-        row_jobs = [j for j in row_jobs if j[0] not in wset_cis]
-        for ci, idx, parked_now, resume_now, ok_now in row_jobs:
-            rec = state.records[ci]
-            mi = int(state.mi_of[ci][idx])
-            rec.parked[idx] = parked_now
-            rec.resume[idx] = resume_now
-            rec.ok[idx] = ok_now
-            views["parked0"][ci, mi] = parked_now
-            views["elig0"][ci, mi] = (not parked_now
-                                      and not bool(rec.adm[idx]))
-            views["resume0"][ci, mi] = resume_now
-            views["vec_ok"][ci, mi] = ok_now
-        _bump(stats, "pack_row_patches", len(row_jobs))
+        with _span("burst.pack.grid"):
+            for ci, rec, kb, ub, mi in walked:
+                state.n_rows_cq[ci] = rec.n_rows
+                state.n_pend_cq[ci] = rec.n_pend
+                state.bad_cq[ci] = rec.bad
+                state.strict_cq[ci] = rec.strict
+                state.n_comp_cq[ci] = rec.n_comp
+                state.comp_max_cq[ci] = rec.comp_max_ts
+                state.maxabs_prio_cq[ci] = int(
+                    np.abs(rec.prio).max(initial=0))
+            rows_per_cq = int(state.n_rows_cq.max(initial=0))
+            state.M = M = max(_bucket(rows_per_cq, minimum=4), min_m)
+            R = len(st.resource_names)
+            F = max(1, len(st.fr_index))
+            views = _views(arena, C, M, R, F)
 
-        prev_token = state.token
-        state.token = next(_b.DeltaPackState._next_token)
-        repacked = sum(r.n_rows for _, r, _, _, _ in walked)
-        _bump(stats, "burst_delta_packs")
-        _bump(stats, "stream_packs")
-        _bump(stats, "rows_repacked", repacked)
-        _bump(stats, "rows_reused",
-              int(state.n_rows_cq.sum()) - repacked)
-        _bump(stats, "burst_journal_dirty_ranges", len(jranges))
+            for ci, rec, kb, ub, mi in walked:
+                _clear_cq(state, views, ci)
+                _write_cq(state, views, ci, rec, mi)
+                state.records[ci] = rec
+                state.mi_of[ci] = mi
+                state.kb_of[ci] = kb
 
-        if int(state.n_pend_cq.sum()) == 0:
-            _note_ms(stats, t0)
-            return None, state, False
-        s = _b._pack_statics(st, cache)
-        dirty_cis = set(walked_cis) | {j[0] for j in row_jobs}
-        plan = _materialize(st, state, s, views, scheduler, dirty_cis,
-                            prev_token, rank_patches, stats)
-        _note_ms(stats, t0, delta=True)
-        return plan, state, True
+            rank_patches = 0
+            # cycle-order rank: drop dirty + pos-moved CQ entries, merge the
+            # fresh ones back in, rewrite the dense rank suffix
+            walked_cis = [w[0] for w in walked]
+            crank_drop = np.asarray(walked_cis + pos_dirty_cis, np.int32)
+            ins_sk, ins_ci, ins_mi = [], [], []
+            for ci, rec, kb, ub, mi in walked:
+                if rec.n_rows:
+                    ins_sk.append(_crank_skey(
+                        rec.prio, rec.ts,
+                        np.full(rec.n_rows, state.pos_cq[ci], np.int64), kb))
+                    ins_ci.append(np.full(rec.n_rows, ci, np.int32))
+                    ins_mi.append(mi)
+            for ci in pos_dirty_cis:
+                rec = state.records[ci]
+                if rec.n_rows:
+                    ins_sk.append(_crank_skey(
+                        rec.prio, rec.ts,
+                        np.full(rec.n_rows, state.pos_cq[ci], np.int64),
+                        state.kb_of[ci]))
+                    ins_ci.append(np.full(rec.n_rows, ci, np.int32))
+                    ins_mi.append(state.mi_of[ci])
+            sfrom = state.crank.update(
+                crank_drop,
+                np.concatenate(ins_sk) if ins_sk
+                else np.empty(0, _SKEY_S),
+                np.concatenate(ins_ci) if ins_ci else (),
+                np.concatenate(ins_mi) if ins_mi else ())
+            if sfrom is not None:
+                ntot = len(state.crank.skey)
+                views["wl_cycle_rank"][
+                    state.crank.ci[sfrom:], state.crank.mi[sfrom:]] = \
+                    np.arange(sfrom, ntot, dtype=np.int32)
+                rank_patches += ntot - sfrom
+
+            # uid rank: same mechanism, dirty CQs only; head-pack keeps
+            # exempt (never-candidate) CQs out of the maintained uid order,
+            # mirroring the _init_full budget filter
+            head_pack = _agg.head_pack_enabled()
+            ins_sk, ins_ci, ins_mi = [], [], []
+            for ci, rec, kb, ub, mi in walked:
+                if rec.n_rows and not (head_pack and statics.comp_cq[ci]):
+                    ins_sk.append(ub)
+                    ins_ci.append(np.full(rec.n_rows, ci, np.int32))
+                    ins_mi.append(mi)
+            sfrom = state.uord.update(
+                np.asarray(walked_cis, np.int32),
+                np.concatenate(ins_sk) if ins_sk
+                else np.empty(0, f"S{_UID_BYTES}"),
+                np.concatenate(ins_ci) if ins_ci else (),
+                np.concatenate(ins_mi) if ins_mi else ())
+            if sfrom is not None:
+                ntot = len(state.uord.skey)
+                views["wl_uidrank"][
+                    state.uord.ci[sfrom:], state.uord.mi[sfrom:]] = \
+                    np.arange(sfrom, ntot, dtype=np.int32)
+                rank_patches += ntot - sfrom
+
+            # admitted reservation-seq: maintain the sorted ts multiset,
+            # recompute dense seqs vectorized, scatter only changed cells
+            if walked:
+                wset = np.asarray(walked_cis, np.int32)
+                keep = ~np.isin(state.adm_ci, wset) \
+                    if len(state.adm_ci) else np.empty(0, bool)
+                a_ts = state.adm_ts[keep]
+                a_ci = state.adm_ci[keep]
+                a_mi = state.adm_mi[keep]
+                a_sq = state.adm_seq_cache[keep]
+                nts, nci, nmi = [], [], []
+                for ci, rec, kb, ub, mi in walked:
+                    if rec.n_adm:
+                        am = rec.adm
+                        nts.append(rec.res_ts[am])
+                        nci.append(np.full(int(am.sum()), ci, np.int32))
+                        nmi.append(mi[am])
+                if nts:
+                    nts = np.concatenate(nts)
+                    srt = np.argsort(nts, kind="stable")
+                    nts = nts[srt]
+                    nci = np.concatenate(nci)[srt]
+                    nmi = np.concatenate(nmi)[srt]
+                    pos = np.searchsorted(a_ts, nts)
+                    a_ts = np.insert(a_ts, pos, nts)
+                    a_ci = np.insert(a_ci, pos, nci)
+                    a_mi = np.insert(a_mi, pos, nmi)
+                    a_sq = np.insert(a_sq, pos,
+                                     np.full(len(nts), -1, np.int32))
+                state.adm_ts, state.adm_ci, state.adm_mi = a_ts, a_ci, a_mi
+                if len(a_ts):
+                    uniq = np.unique(a_ts)
+                    seq_all = (np.searchsorted(uniq, a_ts)
+                               + 1).astype(np.int32)
+                    chg = seq_all != a_sq
+                    if chg.any():
+                        views["adm_seq0"][a_ci[chg], a_mi[chg]] = \
+                            seq_all[chg]
+                        rank_patches += int(chg.sum())
+                    state.adm_seq_cache = seq_all
+                else:
+                    state.adm_seq_cache = np.empty(0, np.int32)
+
+            # row-grade patches (deduped by the journal): single cells.
+            # A job queued before a later row escalated its CQ to dirty is
+            # stale — the re-walk rebuilt the record (and row order), so its
+            # idx no longer addresses the row it was derived from.
+            wset_cis = set(walked_cis)
+            row_jobs = [j for j in row_jobs if j[0] not in wset_cis]
+            for ci, idx, parked_now, resume_now, ok_now in row_jobs:
+                rec = state.records[ci]
+                mi = int(state.mi_of[ci][idx])
+                rec.parked[idx] = parked_now
+                rec.resume[idx] = resume_now
+                rec.ok[idx] = ok_now
+                views["parked0"][ci, mi] = parked_now
+                views["elig0"][ci, mi] = (not parked_now
+                                          and not bool(rec.adm[idx]))
+                views["resume0"][ci, mi] = resume_now
+                views["vec_ok"][ci, mi] = ok_now
+            _bump(stats, "pack_row_patches", len(row_jobs))
+
+            prev_token = state.token
+            state.token = next(_b.DeltaPackState._next_token)
+            repacked = sum(r.n_rows for _, r, _, _, _ in walked)
+            _bump(stats, "burst_delta_packs")
+            _bump(stats, "stream_packs")
+            _bump(stats, "rows_repacked", repacked)
+            _bump(stats, "rows_reused",
+                  int(state.n_rows_cq.sum()) - repacked)
+            _bump(stats, "burst_journal_dirty_ranges", len(jranges))
+
+            if int(state.n_pend_cq.sum()) == 0:
+                _note_ms(stats, t0)
+                return None, state, False
+            s = _b._pack_statics(st, cache)
+            dirty_cis = set(walked_cis) | {j[0] for j in row_jobs}
+            plan = _materialize(st, state, s, views, scheduler, dirty_cis,
+                                prev_token, rank_patches, stats)
+            _note_ms(stats, t0, delta=True)
+            return plan, state, True
     except _StreamBail:
         st._stream_poison = True
         _bump(stats, "stream_pack_bails")

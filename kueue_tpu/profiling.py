@@ -1,10 +1,17 @@
-"""jax.profiler integration: per-cycle step markers + on-demand traces.
+"""jax.profiler integration: the operator's entry to an on-demand trace.
 
 SURVEY §5.1: the reference's observability is zap logging + a pprof flag
 on the perf harness; the TPU-native equivalent is a jax.profiler trace
 with one StepTraceAnnotation per scheduling cycle, so device dispatches
 (admit scans, preemption searches) line up under named cycle steps in
 TensorBoard/Perfetto.
+
+Named host phases are not this module's: they are the spans of
+``obs/trace.py``, each of which holds a ``jax.profiler.TraceAnnotation``
+while the tracer is on.  Turn the tracer on (``KUEUE_TPU_OBS_TRACE=1``
+or ``ObsPlane.enable_tracing``) together with a trace and
+``burst.pack``, ``cycle.nominate.search_launch`` and the rest sit on the
+trace's host plane beside the device's operations, on one clock.
 
 Usage: ``start_trace(logdir)`` / ``stop_trace()`` around any driver
 activity, or ``cli schedule --profile-dir`` / ``cli serve
@@ -62,15 +69,4 @@ def cycle_step(cycle: int):
     import jax
     with jax.profiler.StepTraceAnnotation("schedule_cycle",
                                           step_num=cycle):
-        yield
-
-
-@contextlib.contextmanager
-def annotation(name: str):
-    """Named sub-span (nominate / admit-scan / preemption-search)."""
-    if not _active.is_set():
-        yield
-        return
-    import jax
-    with jax.profiler.TraceAnnotation(name):
         yield

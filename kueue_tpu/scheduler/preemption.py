@@ -28,6 +28,7 @@ from ..api.types import (
 )
 from ..cache.snapshot import Snapshot
 from ..cache.state import CQState
+from ..obs.trace import span as _span
 from ..resources import FlavorResource, FlavorResourceQuantities
 from ..workload import Info, Ordering
 from . import fairsharing
@@ -127,7 +128,23 @@ class Preemptor:
         # accelerator (a subset of device_searches; all of them on a
         # chip host, none under JAX_PLATFORMS=cpu)
         self.stats = {"device_searches": 0, "host_searches": 0,
-                      "accel_searches": 0}
+                      "accel_searches": 0,
+                      # the two device routes apart: one batched launch
+                      # for a cycle's heads, or one launch a head
+                      "search_batch_launches": 0,
+                      "search_single_launches": 0,
+                      # a cycle the batched route turned away, and why
+                      # (the sum of the three reasons): a head over the
+                      # K ladder's top rung, more specs than the S
+                      # ladder's, or a spec the planes cannot hold
+                      "search_batch_refusals": 0,
+                      "search_refused_over_k": 0,
+                      "search_refused_over_s": 0,
+                      "search_refused_unpackable": 0,
+                      # real candidates in the batched launches, and the
+                      # S x K slots of the buckets they were padded to
+                      "search_candidate_slots": 0,
+                      "search_padded_slots": 0}
 
     def set_cycle_pack(self, snapshot: Snapshot, packed) -> None:
         """Thread the admission solver's cached pack for this cycle's
@@ -216,32 +233,39 @@ class Preemptor:
         to per-head get_targets for fair sharing, a missing cycle pack,
         or an unpackable spec (decision-identical either way)."""
         packed = self._pack_for(snapshot)
+        def each_head():
+            """One search (and one candidate discovery) a head."""
+            with _span("cycle.nominate.search_fallback"):
+                return [self.get_targets(wl, a, snapshot)
+                        for wl, a in requests]
+
         if (self.enable_fair_sharing or packed is None
                 or self.device_search is False or not requests):
-            return [self.get_targets(wl, a, snapshot) for wl, a in requests]
+            return each_head()
 
         flat_specs: list[tuple] = []
         plans: list[tuple[list[int], bool]] = []
-        for wl, assignment in requests:
-            ctx = _PreemptionCtx(
-                preemptor=wl,
-                preemptor_cq=snapshot.cq(wl.cluster_queue),
-                snapshot=snapshot,
-                frs_need_preemption=flavor_resources_need_preemption(
-                    assignment),
-                workload_usage=assignment.total_requests_for(wl))
-            candidates = self._find_candidates(ctx)
-            if not candidates:
-                plans.append(([], False))
-                continue
-            candidates.sort(key=candidates_ordering_key(
-                ctx.preemptor_cq.name, self.clock()))
-            specs, staged = self.plan_searches(ctx, candidates)
-            idxs = []
-            for cands, ab, thr in specs:
-                idxs.append(len(flat_specs))
-                flat_specs.append((ctx, cands, ab, thr))
-            plans.append((idxs, staged))
+        with _span("cycle.nominate.candidates"):
+            for wl, assignment in requests:
+                ctx = _PreemptionCtx(
+                    preemptor=wl,
+                    preemptor_cq=snapshot.cq(wl.cluster_queue),
+                    snapshot=snapshot,
+                    frs_need_preemption=flavor_resources_need_preemption(
+                        assignment),
+                    workload_usage=assignment.total_requests_for(wl))
+                candidates = self._find_candidates(ctx)
+                if not candidates:
+                    plans.append(([], False))
+                    continue
+                candidates.sort(key=candidates_ordering_key(
+                    ctx.preemptor_cq.name, self.clock()))
+                specs, staged = self.plan_searches(ctx, candidates)
+                idxs = []
+                for cands, ab, thr in specs:
+                    idxs.append(len(flat_specs))
+                    flat_specs.append((ctx, cands, ab, thr))
+                plans.append((idxs, staged))
 
         results = None
         if flat_specs:
@@ -250,9 +274,9 @@ class Preemptor:
             results = device_minimal_preemptions_batch(
                 flat_specs, packed, stats=self.stats)
             if results is None:
-                # unpackable spec: per-head host path
-                return [self.get_targets(wl, a, snapshot)
-                        for wl, a in requests]
+                # refused (stats say why): one launch a head, each
+                # finding and sorting its candidates again
+                return each_head()
             self.stats["device_searches"] += len(flat_specs)
 
         out: list[list[Target]] = []

@@ -28,6 +28,7 @@ from ..api.types import (
 from ..cache.cache import Cache
 from ..cache.snapshot import Snapshot
 from ..cache.state import CQState, dominant_resource_share
+from ..obs.trace import span as _span
 from ..queue.cluster_queue import RequeueReason
 from ..queue.manager import Manager as QueueManager
 from ..resources import FlavorResourceQuantities
@@ -152,14 +153,12 @@ class Scheduler:
             heads = self.queues.heads_nonblocking()
         if not heads:
             return stats
-        from ..obs.trace import span as _span
         from ..profiling import cycle_step
         with cycle_step(self.scheduling_cycle), _span("cycle"):
             return self._run_cycle(heads, stats, start)
 
     def _run_cycle(self, heads: list[Info], stats: CycleStats,
                    start: float) -> CycleStats:
-        from ..obs.trace import span as _span
         self._cycle_blocked = self.admission_blocked()
         with _span("cycle.snapshot"):
             snapshot = self.cache.snapshot()
@@ -365,14 +364,16 @@ class Scheduler:
         if not deferred:
             return None
         solver = self.solver
-        cls = (solver.classify(snapshot, [e.info for e in deferred])
-               if solver is not None else None)
+        with _span("cycle.nominate.classify"):
+            cls = (solver.classify(snapshot, [e.info for e in deferred])
+                   if solver is not None else None)
         if cls is None:
             if solver is not None:
                 solver.stats["host_cycles"] += 1
-            for e in deferred:
-                e.inadmissible_msg = ""
-                self._assign_entry(e, snapshot)
+            with _span("cycle.nominate.walk"):
+                for e in deferred:
+                    e.inadmissible_msg = ""
+                    self._assign_entry(e, snapshot)
             return None
         if self.fair_sharing:
             # fair-sharing cycles: the tournament + admit loop runs as
@@ -412,7 +413,8 @@ class Scheduler:
                         solver.stats["fs_noop_reuses"] = (
                             solver.stats.get("fs_noop_reuses", 0) + 1)
                         return None
-                self._assign_classified(deferred, cls, snapshot, set())
+                with _span("cycle.nominate.walk"):
+                    self._assign_classified(deferred, cls, snapshot, set())
                 if fp is not None and not any(
                         getattr(e.info.last_assignment,
                                 "pending_flavors", False)
@@ -428,10 +430,12 @@ class Scheduler:
             if (not self._cycle_blocked
                     and not cls.scalar_mask[:n].any()
                     and not cls.preempt0[:n].any()):
-                fs_handle = solver.dispatch_fs(cls)
+                with _span("cycle.nominate.scan_dispatch"):
+                    fs_handle = solver.dispatch_fs(cls)
             if fs_handle is None:
                 solver.stats["classify_cycles"] += 1
-                self._assign_classified(deferred, cls, snapshot, set())
+                with _span("cycle.nominate.walk"):
+                    self._assign_classified(deferred, cls, snapshot, set())
                 return None
             solver.stats["full_cycles"] += 1
             solver.stats["fs_full_cycles"] += 1
@@ -471,53 +475,58 @@ class Scheduler:
                     reserve[wi] = True
             return True
 
-        for wi in np.nonzero(cls.scalar_mask[:n])[0]:
-            if not scalar_walk(int(wi)):
-                full_ok = False
-                break
+        batch_reqs: list[tuple[int, Assignment]] = []
+        with _span("cycle.nominate.walk"):
+            for wi in np.nonzero(cls.scalar_mask[:n])[0]:
+                if not scalar_walk(int(wi)):
+                    full_ok = False
+                    break
+            if full_ok:
+                for wi in np.nonzero(cls.preempt0[:n])[0]:
+                    wi = int(wi)
+                    # A policy-stopped preempt choice is final; otherwise
+                    # with several preempt-capable slots the host walk's
+                    # best-mode pick depends on the reclaim oracle
+                    # (flavorassigner.go:692 RECLAIM beats PREEMPT) — run
+                    # the real walk for this head.
+                    if not (cls.preempt_stopped0[wi]
+                            or cls.preempt_slot_count[wi] == 1):
+                        if not scalar_walk(wi):
+                            full_ok = False
+                            break
+                        continue
+                    batch_reqs.append(
+                        (wi, solver.build_preempt_assignment(cls, wi)))
+
+        if full_ok and batch_reqs:
+            # all preempt heads' target searches in ONE batched
+            # dispatch (preemption.go:127-191; candidate discovery
+            # host-side, greedy+fillback searches vmapped)
+            results = self.preemptor.get_targets_batch(
+                [(deferred[wi].info, a) for wi, a in batch_reqs],
+                snapshot)
+            for (wi, assignment), targets in zip(batch_reqs, results):
+                if targets:
+                    targets_by_wi[wi] = targets
+                    assignments_by_wi[wi] = assignment
+                else:
+                    reserve[wi] = True
 
         if full_ok:
-            batch_reqs: list[tuple[int, Assignment]] = []
-            for wi in np.nonzero(cls.preempt0[:n])[0]:
-                wi = int(wi)
-                # A policy-stopped preempt choice is final; otherwise with
-                # several preempt-capable slots the host walk's best-mode
-                # pick depends on the reclaim oracle (flavorassigner.go:692
-                # RECLAIM beats PREEMPT) — run the real walk for this head.
-                if not (cls.preempt_stopped0[wi]
-                        or cls.preempt_slot_count[wi] == 1):
-                    if not scalar_walk(wi):
-                        full_ok = False
-                        break
-                    continue
-                batch_reqs.append(
-                    (wi, solver.build_preempt_assignment(cls, wi)))
-            if full_ok and batch_reqs:
-                # all preempt heads' target searches in ONE batched
-                # dispatch (preemption.go:127-191; candidate discovery
-                # host-side, greedy+fillback searches vmapped)
-                results = self.preemptor.get_targets_batch(
-                    [(deferred[wi].info, a) for wi, a in batch_reqs],
-                    snapshot)
-                for (wi, assignment), targets in zip(batch_reqs, results):
-                    if targets:
-                        targets_by_wi[wi] = targets
-                        assignments_by_wi[wi] = assignment
-                    else:
-                        reserve[wi] = True
-
-        packed_targets = None
-        if full_ok and targets_by_wi:
-            packed_targets = solver.pack_targets(cls, targets_by_wi)
-            if packed_targets is None:
-                full_ok = False
+            with _span("cycle.nominate.scan_dispatch"):
+                packed_targets = None
+                if targets_by_wi:
+                    packed_targets = solver.pack_targets(cls, targets_by_wi)
+                    full_ok = packed_targets is not None
+                if full_ok:
+                    handle = solver.dispatch(cls, reserve, packed_targets)
 
         if not full_ok:
             solver.stats["classify_cycles"] += 1
-            self._assign_classified(deferred, cls, snapshot, walked)
+            with _span("cycle.nominate.walk"):
+                self._assign_classified(deferred, cls, snapshot, walked)
             return None
 
-        handle = solver.dispatch(cls, reserve, packed_targets)
         solver.stats["full_cycles"] += 1
         return (deferred, cls, handle, assignments_by_wi, targets_by_wi,
                 walked)
@@ -588,7 +597,8 @@ class Scheduler:
                     if cq is not None:
                         self._prepare_admit(e, cq)
 
-        final = solver.fetch(handle)
+        with _span("cycle.admit.fetch"):
+            final = solver.fetch(handle)
         for wi in final.order:
             wi = int(wi)
             e = deferred[wi]

@@ -131,6 +131,26 @@ def mk(name, lq, cpu, prio=0, t=0.0, count=1):
                                      requests={"cpu": cpu})])
 
 
+def preempting_cluster(cqs=3):
+    """One cohort of ``cqs`` queues, each filled to its quota with four
+    low-priority workloads (four settled cycles), then one high-priority
+    head a queue that fits only by evicting three of them: the next
+    cycle runs one preemption search a head, four candidates each.
+    Returns (driver, clock) with the preemptors pending."""
+    pre = PreemptionPolicy(
+        reclaim_within_cohort=ReclaimWithinCohort.ANY,
+        within_cluster_queue=WithinClusterQueue.LOWER_PRIORITY)
+    d, clock = build(add_workloads(
+        simple_cluster(n_cohorts=1, cqs=cqs, nominal=4000, preemption=pre),
+        [mk(f"low-{q}-{i}", f"lq-0-{q}", 1000, t=float(4 * q + i + 1))
+         for q in range(cqs) for i in range(4)]))
+    run_host(d, clock, 4, 0)
+    for q in range(cqs):
+        d.create_workload(mk(f"high-{q}", f"lq-0-{q}", 3000, prio=100,
+                             t=50.0 + q))
+    return d, clock
+
+
 def test_burst_simple_drain():
     """More pending than quota: admissions, in-cycle skips, parking,
     finish-driven unparking across several fused cycles."""

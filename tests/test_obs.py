@@ -39,6 +39,7 @@ from kueue_tpu.obs import trace as trace_mod
 from kueue_tpu.obs.flight import decision_digest
 from kueue_tpu.obs.trace import (
     HOT_PATH_PHASES,
+    SELF_SUFFIX,
     Tracer,
     _NOOP,
     span,
@@ -47,7 +48,15 @@ from kueue_tpu.obs.trace import (
 from kueue_tpu.utils.journal import CycleWAL
 from kueue_tpu.visibility import VisibilityServer
 
-from test_burst import add_workloads, build, mk, run_host, simple_cluster
+from test_burst import (
+    add_workloads,
+    build,
+    mk,
+    preempting_cluster,
+    run_burst,
+    run_host,
+    simple_cluster,
+)
 from test_chaos_recovery import (
     drain_spec,
     full_state,
@@ -208,7 +217,8 @@ def test_tracing_on_vs_off_is_bit_identical():
     phases = set(tracer.roster())
     assert {"cycle", "cycle.snapshot", "cycle.nominate",
             "cycle.admit"} <= phases
-    assert phases <= set(HOT_PATH_PHASES)
+    assert {p.removesuffix(SELF_SUFFIX) for p in phases} \
+        <= set(HOT_PATH_PHASES)
     # empty cycles (no queue heads) return before the span opens
     assert 1 <= tracer.roster()["cycle"]["count"] <= 12
 
@@ -230,6 +240,137 @@ def test_traced_wal_spans_and_flight_ring(tmp_path):
         names = {s.name for s in rec.spans}
         assert "cycle" in names, "cycle record missing its own spans"
     assert tracer.cycle_spans == [], "flight recorder must drain the buffer"
+
+
+# ---------------------------------------------------------------------------
+# Sub-phases, self time, the drop counter, one clock with the profiler
+# ---------------------------------------------------------------------------
+
+NEW_PHASES = (
+    "cycle.nominate.classify", "cycle.nominate.walk",
+    "cycle.nominate.candidates", "cycle.nominate.search_pack",
+    "cycle.nominate.search_launch", "cycle.nominate.search_decode",
+    "cycle.nominate.search_fallback", "cycle.nominate.scan_dispatch",
+    "cycle.admit.fetch",
+    "burst.pack.drain", "burst.pack.walk", "burst.pack.grid",
+    "burst.dispatch.tighten", "burst.dispatch.launch",
+)
+
+
+def run_preempting(traced, small_k=False):
+    """One per-cycle cycle in which three heads search for targets,
+    then a fused burst of three; ``small_k`` shrinks the batched
+    search's top rung under the heads' four candidates, so the cycle
+    takes the one-launch-a-head route.  Returns (driver, tracer, the
+    four cycles' stats)."""
+    from kueue_tpu.ops import preemption_solver
+    d, clock = preempting_cluster()
+    tracer = d.obs.enable_tracing() if traced else None
+    with pytest.MonkeyPatch.context() as mp:
+        if small_k:
+            mp.setattr(preemption_solver, "K_LADDER", (2,))
+        out = run_host(d, clock, 1, 0) + run_burst(d, clock, 3, 0)
+    d.obs.disable_tracing()
+    return d, tracer, out
+
+
+@pytest.fixture(scope="module")
+def preempting_spans():
+    """Every span record of the batched and of the fallback arm."""
+    recs = []
+    for small_k in (False, True):
+        recs += run_preempting(True, small_k)[1].trace_spans
+    trace_mod.clear()
+    return recs
+
+
+@pytest.mark.parametrize("phase", NEW_PHASES)
+def test_sub_phase_is_listed_emitted_and_parented(phase, preempting_spans):
+    assert phase in HOT_PATH_PHASES
+    parents = {r.parent for r in preempting_spans if r.name == phase}
+    assert parents == {phase.rsplit(".", 1)[0]}, \
+        f"{phase}: never emitted, or under {parents}"
+
+
+def test_self_time_is_parent_less_recorded_children():
+    t = Tracer()
+    with t.span("cycle"):
+        with t.span("cycle.nominate"):
+            with t.span("wal.append", counted=True):   # not a child
+                pass
+        with t.span("cycle.admit"):
+            pass
+    by = {r.name: r for r in t.trace_spans}
+    roster = t.roster()
+    assert roster["cycle" + SELF_SUFFIX]["total_s"] == pytest.approx(
+        by["cycle"].dur - by["cycle.nominate"].dur - by["cycle.admit"].dur,
+        abs=1e-9)
+    assert roster["cycle" + SELF_SUFFIX]["count"] == 1
+    # no recorded child, no self series; and .self is never a record
+    assert "cycle.nominate" + SELF_SUFFIX not in roster
+    assert "cycle.admit" + SELF_SUFFIX not in roster
+    assert all(not r.name.endswith(SELF_SUFFIX) for r in t.trace_spans)
+    # the pooled span forgets its children when it is handed out again
+    with t.span("cycle"):
+        pass
+    assert t.roster()["cycle" + SELF_SUFFIX]["count"] == 1
+
+
+def test_trace_spans_keeps_the_first_and_counts_the_rest():
+    d, c = build(simple_cluster())
+    t = d.obs.enable_tracing()
+    t.trace_capacity = 3
+    for i in range(5):
+        with span(f"phase.{i}"):
+            pass
+    assert [r.name for r in t.trace_spans] == ["phase.0", "phase.1",
+                                               "phase.2"]
+    assert t.dropped_total == 2 and t.finished_total == 5
+    assert d.obs.report()["spans_dropped"] == 2
+    assert len(t.drain_cycle()) == 5    # the flight recorder loses none
+
+
+def test_tracing_on_vs_off_is_bit_identical_when_preempting():
+    """The same bar on a cycle that searches for targets, by both
+    search routes, and on the burst after it."""
+    for small_k in (False, True):
+        dc, _, control = run_preempting(False, small_k)
+        dt, tracer, traced = run_preempting(True, small_k)
+        assert any(s.preempted_targets for s in control)
+        for k, (x, y) in enumerate(zip(traced, control, strict=True)):
+            assert decision_digest(x) == decision_digest(y), f"cycle {k}"
+        assert full_state(dt) == full_state(dc)
+        assert dt.scheduler.preemptor.stats == dc.scheduler.preemptor.stats
+        assert ("cycle.nominate.search_fallback" in tracer.roster()) \
+            is small_k
+
+
+def test_profiler_trace_holds_the_spans_as_host_events(tmp_path):
+    """One system: with the tracer on, a profiler session that anyone
+    started shows the spans on its host plane, on its own clock."""
+    import glob
+    import jax
+    from jax.profiler import ProfileData
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        _, tracer, _ = run_preempting(True)
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "plugins/profile/*/*.xplane.pb"))
+    host = [p for p in ProfileData.from_file(path).planes
+            if p.name == "/host:CPU"]
+    events = {}
+    for line in host[0].lines:
+        for ev in line.events:
+            events.setdefault(ev.name, []).append(ev.duration_ns)
+    for name in ("cycle.nominate", "cycle.nominate.search_launch",
+                 "burst.pack.grid"):
+        recs = [r for r in tracer.trace_spans if r.name == name]
+        assert len(events.get(name, ())) == len(recs) >= 1, name
+    # the annotation is held for the span's lifetime: same duration
+    rec = next(r for r in tracer.trace_spans if r.name == "cycle.nominate")
+    assert events["cycle.nominate"][0] == pytest.approx(rec.dur * 1e9,
+                                                        abs=1e6)
 
 
 # ---------------------------------------------------------------------------

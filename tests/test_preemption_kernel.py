@@ -126,3 +126,66 @@ def test_device_search_stats_fallback_for_fair_sharing():
     # fair-sharing path never reaches the device search
     assert d.scheduler.preemptor.stats["device_searches"] == 0
     assert "default/reclaimer" in d.admitted_keys()
+
+
+def test_head_over_the_top_rung_is_counted_and_searched_a_head(monkeypatch):
+    """One head with more candidates than ``K_LADDER``'s top rung turns
+    the whole cycle's batch away: the refusal is counted under its one
+    reason, every head that searched gets a launch of its own, and the
+    targets are those of the batched route."""
+    from kueue_tpu.ops import preemption_solver
+    from tests.test_burst import preempting_cluster, run_host
+
+    def cycle(k_ladder=None):
+        d, clock = preempting_cluster()     # 3 heads, 4 candidates each
+        if k_ladder is not None:
+            monkeypatch.setattr(preemption_solver, "K_LADDER", k_ladder)
+        (stats,) = run_host(d, clock, 1, 0)
+        return d.scheduler.preemptor.stats, stats
+
+    batched, b_cycle = cycle()
+    assert batched["search_batch_launches"] == 1
+    assert batched["search_batch_refusals"] == 0
+    assert batched["search_single_launches"] == 0
+    assert batched["search_candidate_slots"] == 12
+    assert batched["search_padded_slots"] == 32 * 16      # S x K rungs
+    assert batched["device_searches"] == 3
+
+    single, s_cycle = cycle(k_ladder=(2,))
+    assert single["search_batch_refusals"] == 1
+    assert single["search_refused_over_k"] == 1
+    assert single["search_refused_over_s"] == 0
+    assert single["search_refused_unpackable"] == 0
+    assert single["search_batch_launches"] == 0
+    assert single["search_single_launches"] == 3          # heads that searched
+    assert single["device_searches"] == 3 and single["host_searches"] == 0
+    assert single["search_padded_slots"] == 0
+
+    assert s_cycle.preempted_targets == b_cycle.preempted_targets
+    assert len(b_cycle.preempted_targets) == 9
+    assert s_cycle.preempting == b_cycle.preempting
+
+
+def test_batch_refusal_reasons_sum_to_the_refusals(monkeypatch):
+    """Every ``return None`` of the batched search lands in exactly one
+    reason: too many specs, and a plane that cannot hold a spec."""
+    from kueue_tpu.ops import preemption_solver
+    from tests.test_burst import preempting_cluster, run_host
+
+    d, clock = preempting_cluster()
+    monkeypatch.setattr(preemption_solver, "S_LADDER", (2,))
+    run_host(d, clock, 1, 0)
+    stats = d.scheduler.preemptor.stats
+    assert stats["search_refused_over_s"] == 1
+
+    d2, clock2 = preempting_cluster()
+    monkeypatch.undo()
+    monkeypatch.setattr(preemption_solver, "_planes_for", lambda packed: None)
+    run_host(d2, clock2, 1, 0)
+    stats2 = d2.scheduler.preemptor.stats
+    assert stats2["search_refused_unpackable"] == 1
+    for s in (stats, stats2):
+        assert s["search_batch_refusals"] == 1 == (
+            s["search_refused_over_k"] + s["search_refused_over_s"]
+            + s["search_refused_unpackable"])
+        assert s["search_single_launches"] == 3

@@ -53,5 +53,12 @@ def test_trace_captures_scheduling_cycles(tmp_path):
 def test_cycle_step_noop_without_trace():
     with profiling.cycle_step(7):
         pass
-    with profiling.annotation("x"):
-        pass
+
+
+def test_named_phases_are_spans_not_a_second_system():
+    """``profiling.annotation`` is gone: a named host phase is a span of
+    obs/trace.py, which holds the profiler annotation itself (the trace
+    side is checked in test_obs.py)."""
+    assert not hasattr(profiling, "annotation")
+    from kueue_tpu.obs.trace import HOT_PATH_PHASES
+    assert "cycle.nominate.scan_dispatch" in HOT_PATH_PHASES
