@@ -94,8 +94,8 @@ def _planes_for(packed) -> Optional[_ForestPlanes]:
 
 def _refused(stats: Optional[dict], reason: str) -> None:
     """Count one refusal of the batched search under its one reason
-    (``over_k``, ``over_s`` or ``unpackable``); the caller then runs a
-    launch a head."""
+    (``over_s`` or ``unpackable``); the caller then runs a launch a
+    head."""
     if stats is not None:
         stats["search_batch_refusals"] += 1
         stats["search_refused_" + reason] += 1
@@ -110,10 +110,13 @@ def device_minimal_preemptions_batch(specs, packed,
     ``specs``: [(ctx, candidates, allow_borrowing, threshold)] — the
     per-head search requests the preemptor planned (every search is
     against the same nominate-time snapshot, so they are independent).
-    Returns a list of per-spec Target lists ([] = search failed), or
-    None when the batch is refused: a spec can't be packed, or the
-    batch is over the shape ladders' top rung (the caller runs a launch
-    a head; ``stats`` counts the refusal under its one reason).
+    No spec has more candidates than ``K_LADDER``'s top rung: the
+    preemptor launches such a search alone (``search_alone_over_k``)
+    and batches the rest.  Returns a list of per-spec Target lists
+    ([] = search failed), or None when the batch is refused: a spec
+    can't be packed, or there are more specs than ``S_LADDER``'s top
+    rung (the caller runs a launch a head; ``stats`` counts the refusal
+    under its one reason).
     ``stats["accel_searches"]`` counts the searches whose output landed
     on an accelerator, ``search_batch_launches`` the launches, and
     ``search_candidate_slots`` / ``search_padded_slots`` the real
@@ -165,14 +168,13 @@ def _pack_batch(specs, packed, stats: Optional[dict]):
 
     # coarse shape ladders: each distinct (S, K) combination is one XLA
     # compilation — a handful of rungs covers every cycle, and warmup
-    # pre-compiles them (CycleSolver.warmup).  Beyond the top rung the
-    # host path runs (None), never an array overflow.
+    # pre-compiles them (CycleSolver.warmup).  Beyond S's top rung the
+    # caller runs a launch a head (None); a spec beyond K's never comes
+    # here (Preemptor.get_targets_batch searches it alone).
     from .packing import coarse_bucket
     max_cands = max(1, max(len(c) for _, c, _, _ in specs))
     if len(specs) > S_LADDER[-1]:
         return _refused(stats, "over_s")
-    if max_cands > K_LADDER[-1]:
-        return _refused(stats, "over_k")
     S = coarse_bucket(len(specs), S_LADDER)
     K = coarse_bucket(max_cands, K_LADDER)
     NL = planes.NL

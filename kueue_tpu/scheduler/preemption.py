@@ -130,17 +130,20 @@ class Preemptor:
         self.stats = {"device_searches": 0, "host_searches": 0,
                       "accel_searches": 0,
                       # the two device routes apart: one batched launch
-                      # for a cycle's heads, or one launch a head
+                      # for a cycle's heads, or one launch a search
                       "search_batch_launches": 0,
                       "search_single_launches": 0,
-                      # a cycle the batched route turned away, and why
-                      # (the sum of the three reasons): a head over the
-                      # K ladder's top rung, more specs than the S
-                      # ladder's, or a spec the planes cannot hold
+                      # a whole batch the batched route turned away, and
+                      # why (the sum of the two reasons): more specs than
+                      # the S ladder's top rung, or a spec the planes
+                      # cannot hold.  Size of one spec never refuses a
+                      # batch: a spec with more candidates than the K
+                      # ladder's top rung is searched alone, counted in
+                      # search_alone_over_k, and the rest stay batched
                       "search_batch_refusals": 0,
-                      "search_refused_over_k": 0,
                       "search_refused_over_s": 0,
                       "search_refused_unpackable": 0,
+                      "search_alone_over_k": 0,
                       # real candidates in the batched launches, and the
                       # S x K slots of the buckets they were padded to
                       "search_candidate_slots": 0,
@@ -229,9 +232,13 @@ class Preemptor:
         """Target searches for ALL of a cycle's preempt heads in one
         batched device dispatch (ops/preemption_kernel
         minimal_preemptions_batch) — candidate discovery and ordering
-        stay host-side, the greedy+fillback searches vmap.  Falls back
-        to per-head get_targets for fair sharing, a missing cycle pack,
-        or an unpackable spec (decision-identical either way)."""
+        stay host-side, the greedy+fillback searches vmap.  A search
+        with more candidates than the batch's K ladder holds is
+        launched alone, over the candidates found and sorted here, and
+        the others stay in the batch.  Falls back to per-head
+        get_targets for fair sharing, a missing cycle pack, or a batch
+        that is refused: too many specs, or an unpackable one
+        (decision-identical either way)."""
         packed = self._pack_for(snapshot)
         def each_head():
             """One search (and one candidate discovery) a head."""
@@ -267,17 +274,37 @@ class Preemptor:
                     flat_specs.append((ctx, cands, ab, thr))
                 plans.append((idxs, staged))
 
-        results = None
-        if flat_specs:
-            from ..ops.preemption_solver import (
-                device_minimal_preemptions_batch)
-            results = device_minimal_preemptions_batch(
-                flat_specs, packed, stats=self.stats)
-            if results is None:
+        # the launch plan follows each spec's size: those the K ladder
+        # holds share the one launch, each of the others gets its own
+        from ..ops import preemption_solver
+        top = preemption_solver.K_LADDER[-1]
+        results: list[Optional[list[Target]]] = [None] * len(flat_specs)
+        batch = [i for i, spec in enumerate(flat_specs)
+                 if len(spec[1]) <= top]
+        if batch:
+            found = preemption_solver.device_minimal_preemptions_batch(
+                [flat_specs[i] for i in batch], packed, stats=self.stats)
+            if found is None:
                 # refused (stats say why): one launch a head, each
                 # finding and sorting its candidates again
                 return each_head()
-            self.stats["device_searches"] += len(flat_specs)
+            self.stats["device_searches"] += len(batch)
+            for i, targets in zip(batch, found):
+                results[i] = targets
+        if len(batch) < len(flat_specs):
+            with _span("cycle.nominate.search_fallback"):
+                for idxs, _ in plans:
+                    for i in idxs:
+                        if results[i] is not None:
+                            continue
+                        # a staged plan's retry is a subset of its first
+                        # spec, so the first was searched just above:
+                        # the retry runs only if that found no fit
+                        if i != idxs[0] and results[idxs[0]]:
+                            continue
+                        self.stats["search_alone_over_k"] += 1
+                        results[i] = self._minimal_preemptions(
+                            *flat_specs[i])
 
         out: list[list[Target]] = []
         for idxs, staged in plans:

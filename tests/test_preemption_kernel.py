@@ -128,47 +128,125 @@ def test_device_search_stats_fallback_for_fair_sharing():
     assert "default/reclaimer" in d.admitted_keys()
 
 
-def test_head_over_the_top_rung_is_counted_and_searched_a_head(monkeypatch):
-    """One head with more candidates than ``K_LADDER``'s top rung turns
-    the whole cycle's batch away: the refusal is counted under its one
-    reason, every head that searched gets a launch of its own, and the
-    targets are those of the batched route."""
-    from kueue_tpu.ops import preemption_solver
-    from tests.test_burst import preempting_cluster, run_host
+def _cohort_then_heads(cqs, lows, heads):
+    """One cohort of ``cqs`` queues (quota 4,000 m each, reclaim Any,
+    LowerPriority within the queue) that admits ``lows``, a cycle a
+    workload so that any split over the queues settles; then ``heads``
+    arrive.  Returns (driver, clock) with the heads pending."""
+    from tests.test_burst import (add_workloads, build, run_host,
+                                  simple_cluster)
+    pre = PreemptionPolicy(
+        reclaim_within_cohort=ReclaimWithinCohort.ANY,
+        within_cluster_queue=WithinClusterQueue.LOWER_PRIORITY)
+    d, clock = build(add_workloads(
+        simple_cluster(n_cohorts=1, cqs=cqs, nominal=4000, preemption=pre),
+        lows))
+    run_host(d, clock, len(lows), 0)
+    for wl in heads:
+        d.create_workload(wl)
+    return d, clock
 
-    def cycle(k_ladder=None):
-        d, clock = preempting_cluster()     # 3 heads, 4 candidates each
+
+def uneven_cluster():
+    """Three full queues holding 8, 4 and 2 low-priority workloads,
+    then one high-priority head a queue: three searches of 8, 4 and 2
+    candidates (no queue borrows, so none crosses queues)."""
+    from tests.test_burst import mk
+    return _cohort_then_heads(
+        3,
+        [mk(f"low-{q}-{i}", f"lq-0-{q}", 4000 // n, t=float(8 * q + i + 1))
+         for q, n in enumerate((8, 4, 2)) for i in range(n)],
+        [mk(f"high-{q}", f"lq-0-{q}", 3000, prio=100, t=50.0 + q)
+         for q in range(3)])
+
+
+def staged_cluster():
+    """Two queues: the second borrows (six workloads of 1,000 m on a
+    quota of 4,000), the first runs one and is under its quota.  Its
+    head of 4,000 m plans a staged search: first all seven candidates
+    without borrowing, then (only if that finds no fit) its own
+    queue's one.  The first fits, with three targets."""
+    from tests.test_burst import mk
+    return _cohort_then_heads(
+        2,
+        [mk("own", "lq-0-0", 1000, t=1.0)]
+        + [mk(f"lent-{i}", "lq-0-1", 1000, t=float(i + 2))
+           for i in range(6)],
+        [mk("high", "lq-0-0", 4000, prio=100, t=50.0)])
+
+
+# (cluster, shrunk K ladder) -> the launches, and the Preemptor.stats
+# that do not follow from them
+LAUNCH_PLANS = {
+    "no_head_over": (uneven_cluster, None, dict(
+        batch=1, single=0, in_batch=3, slots=14, padded=32 * 16)),
+    "one_head_of_three_over": (uneven_cluster, (4,), dict(
+        batch=1, single=1, in_batch=2, slots=6, padded=32 * 4)),
+    "every_head_over": (uneven_cluster, (1,), dict(
+        batch=0, single=3, in_batch=0, slots=0, padded=0)),
+    # the retry (one candidate) rides in the batch; the first is alone
+    "staged_first_over": (staged_cluster, (4,), dict(
+        batch=1, single=1, in_batch=1, slots=1, padded=32 * 4)),
+    # the first fits, so its oversized retry is never launched
+    "staged_both_over": (staged_cluster, (0,), dict(
+        batch=0, single=1, in_batch=0, slots=0, padded=0)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(LAUNCH_PLANS))
+def test_spec_over_the_top_rung_is_searched_alone_and_the_rest_batched(
+        monkeypatch, case):
+    """Size never turns a cycle's batch away: a search with more
+    candidates than ``K_LADDER``'s top rung gets a launch of its own
+    over the candidates already found, the others share the one batched
+    launch, and the targets are those of the unshrunk batched run."""
+    from kueue_tpu.ops import preemption_solver
+    from kueue_tpu.scheduler.preemption import Preemptor
+    from tests.test_burst import run_host
+
+    cluster, k_ladder, want = LAUNCH_PLANS[case]
+    finds = []
+    find = Preemptor._find_candidates
+    monkeypatch.setattr(
+        Preemptor, "_find_candidates",
+        lambda self, ctx: finds.append(ctx.preemptor.key) or find(self, ctx))
+
+    def cycle(k_ladder):
+        d, clock = cluster()
         if k_ladder is not None:
             monkeypatch.setattr(preemption_solver, "K_LADDER", k_ladder)
+        del finds[:]
         (stats,) = run_host(d, clock, 1, 0)
-        return d.scheduler.preemptor.stats, stats
+        return d.scheduler.preemptor.stats, stats, len(finds)
 
-    batched, b_cycle = cycle()
+    batched, b_cycle, b_finds = cycle(None)
     assert batched["search_batch_launches"] == 1
-    assert batched["search_batch_refusals"] == 0
     assert batched["search_single_launches"] == 0
-    assert batched["search_candidate_slots"] == 12
-    assert batched["search_padded_slots"] == 32 * 16      # S x K rungs
-    assert batched["device_searches"] == 3
+    assert batched["search_alone_over_k"] == 0
+    assert len(b_cycle.preempted_targets) == (
+        11 if cluster is uneven_cluster else 3)
 
-    single, s_cycle = cycle(k_ladder=(2,))
-    assert single["search_batch_refusals"] == 1
-    assert single["search_refused_over_k"] == 1
-    assert single["search_refused_over_s"] == 0
-    assert single["search_refused_unpackable"] == 0
-    assert single["search_batch_launches"] == 0
-    assert single["search_single_launches"] == 3          # heads that searched
-    assert single["device_searches"] == 3 and single["host_searches"] == 0
-    assert single["search_padded_slots"] == 0
+    stats, s_cycle, s_finds = cycle(k_ladder)
+    assert stats["search_batch_launches"] == want["batch"]
+    assert stats["search_single_launches"] == want["single"]
+    assert stats["search_alone_over_k"] == want["single"]
+    assert stats["search_batch_refusals"] == 0
+    assert stats["device_searches"] == want["in_batch"] + want["single"]
+    assert stats["host_searches"] == 0
+    assert stats["search_candidate_slots"] == want["slots"]
+    assert stats["search_padded_slots"] == want["padded"]
+    # candidates are found once a head, whatever the launch plan
+    assert s_finds == b_finds == len(b_cycle.preempting)
 
     assert s_cycle.preempted_targets == b_cycle.preempted_targets
-    assert len(b_cycle.preempted_targets) == 9
     assert s_cycle.preempting == b_cycle.preempting
 
 
 def test_batch_refusal_reasons_sum_to_the_refusals(monkeypatch):
     """Every ``return None`` of the batched search lands in exactly one
-    reason: too many specs, and a plane that cannot hold a spec."""
+    reason: too many specs, and a plane that cannot hold a spec.  A
+    spec searched alone for its size is no refusal: the whole-batch
+    count and ``search_alone_over_k`` move apart."""
     from kueue_tpu.ops import preemption_solver
     from tests.test_burst import preempting_cluster, run_host
 
@@ -186,6 +264,19 @@ def test_batch_refusal_reasons_sum_to_the_refusals(monkeypatch):
     assert stats2["search_refused_unpackable"] == 1
     for s in (stats, stats2):
         assert s["search_batch_refusals"] == 1 == (
-            s["search_refused_over_k"] + s["search_refused_over_s"]
-            + s["search_refused_unpackable"])
+            s["search_refused_over_s"] + s["search_refused_unpackable"])
         assert s["search_single_launches"] == 3
+        assert s["search_alone_over_k"] == 0
+
+    # one head over the K rung beside a refused batch: the refusal is
+    # the batch's own, and each_head searches every head once
+    d3, clock3 = uneven_cluster()
+    monkeypatch.setattr(preemption_solver, "_planes_for", lambda packed: None)
+    monkeypatch.setattr(preemption_solver, "K_LADDER", (4,))
+    run_host(d3, clock3, 1, 0)
+    stats3 = d3.scheduler.preemptor.stats
+    assert stats3["search_batch_refusals"] == 1
+    assert stats3["search_refused_unpackable"] == 1
+    assert stats3["search_alone_over_k"] == 0
+    assert stats3["search_single_launches"] == 3
+    assert stats3["device_searches"] == 3
