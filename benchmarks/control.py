@@ -7,9 +7,10 @@ One process, several seeds (set-up is long and the compiled programs
 are shared).  For each seed it runs the cell as ``run.py`` does, at the
 cell's own size and load, for a short window, and prints the numbers
 the comparison read for the program.  Then it puts each control in the
-program's place: the plain reference with one stated guarantee switched
-off (benchmarks/reference.py CONTROLS) decides the same cycles from the
-same inputs, and the same comparison reads it.  Every number compared
+program's place: the plain reference of the configuration's deployment
+kind with one stated guarantee switched off (the kind's ``CONTROLS``)
+decides the same cycles from the same inputs, and the same comparison
+reads it.  Every number compared
 is exact, limit 0: the program has to read 0 on every seed and a
 control above 0.  The benchmark's own runs do not run this.
 """
@@ -29,26 +30,25 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
 
 
-def control_readings(plan, rounds, measured_from) -> dict:
-    """Each control replayed in the program's place: its decisions
-    against the plain reference's, through the same comparison."""
+def control_readings(kind, plan, rounds, measured_from) -> dict:
+    """Each control of the deployment kind replayed in the program's
+    place: its decisions against the plain reference's, through the
+    same comparison."""
     import copy
 
     import correct
-    import reference
     out = {}
-    for broken in reference.CONTROLS:
+    for broken in kind.CONTROLS:
         # the control's answers, shaped like the program's record
-        ctl = reference.Reference(plan, broken=broken)
+        ctl = kind.Reference(plan, broken=broken)
         answers = copy.deepcopy(rounds)
         for rnd in answers:
-            ctl.finish(rnd.finished)
+            ctl.begin_round(rnd)
             for cyc in rnd.cycles:
                 res = ctl.cycle(cyc.clock)
-                cyc.admitted, cyc.evicted = res.admitted, res.evicted
-                cyc.skipped, cyc.preempting = res.skipped, res.preempting
-        verdict = correct.compare(plan, answers, measured_from,
-                                  reference.Reference)
+                for name in kind.COMPARED:
+                    setattr(cyc, name, getattr(res, name))
+        verdict = correct.compare(kind, plan, answers, measured_from)
         out[broken] = {k: v["value"]
                        for k, v in verdict["compared"].items()}
         out[broken]["correct"] = verdict["correct"]

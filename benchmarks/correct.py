@@ -1,13 +1,18 @@
-"""The comparison that decides ``correct``.
+"""The comparison that decides ``correct``: what is common to every
+deployment kind.
 
 Every applied cycle of the run, warm rounds included, is replayed
-through the plain reference (benchmarks/reference.py) from the same
-inputs: the finished workloads of each boundary and the clock of each
-cycle.  A cycle matches when its admitted set, its evicted set, its
-skipped set and its preempting set equal the reference's.  Beside the
-replay a quota ledger, which trusts neither side, adds up what the
+through the plain reference of the configuration's deployment kind
+(``deployment_kinds/<kind>/``; the contract is in benchmarks/harness.py)
+from the same inputs: each round's record as the traffic wrote it (the
+workloads that finished at its boundary, and whatever else a later
+traffic kind records there) and the clock of each cycle.  A cycle
+matches when every field the kind names in ``COMPARED`` (for the first
+kind its admitted, evicted, skipped and preempting workloads) holds the
+same entries as the reference's, in any order.  Beside the replay the
+kind's quota ledger, which trusts neither side, adds up what the
 program says it admitted, evicted and finished, and holds it to the
-configuration's quotas on every resource.
+configuration's quotas.
 
 Each number compared is exact, so each limit is 0.
 """
@@ -23,97 +28,26 @@ def replay(reference, rounds):
     reference still had a head to decide, and the unknown finishes."""
     out, short, unknown = [], 0, 0
     for rnd in rounds:
-        unknown += reference.finish(rnd.finished)
+        unknown += reference.begin_round(rnd)
         out.append([reference.cycle(cyc.clock) for cyc in rnd.cycles])
         if len(rnd.cycles) < rnd.max_cycles and reference.has_heads():
             short += 1
     return out, short, unknown
 
 
-def _same(cyc, ref) -> bool:
-    return (set(cyc.admitted) == set(ref.admitted)
-            and sorted(cyc.evicted) == sorted(ref.evicted)
-            and sorted(cyc.skipped) == sorted(ref.skipped)
-            and sorted(cyc.preempting) == sorted(ref.preempting)
-            and len(cyc.admitted) == len(ref.admitted))
+def _same(cyc, ref, fields) -> bool:
+    return all(sorted(getattr(cyc, name)) == sorted(getattr(ref, name))
+               for name in fields)
 
 
-def ledger(plan, rounds) -> dict:
-    """Adds up the program's own answers.  Counts quota violations
-    (a queue over nominal + borrowing limit, or a cohort over the sum
-    of its nominals, in any resource, after any cycle), admissions of a
-    workload that already holds quota, and evictions or finishes of one
-    that holds none."""
-    res = plan.resources
-    R = len(res)
-    nominal = [[q.nominal[r] for r in res] for q in plan.queues]
-    cap = [[q.nominal[r] + q.borrowing_limit[r] for r in res]
-           for q in plan.queues]
-    cohorts: dict[str, list] = {}
-    for c, q in enumerate(plan.queues):
-        cohorts.setdefault(q.cohort, []).append(c)
-    cohort_of = {c: name for name, ms in cohorts.items() for c in ms}
-    quota = {name: [sum(nominal[c][r] for c in ms) for r in range(R)]
-             for name, ms in cohorts.items()}
-    row = {plan.key(i): i for i in range(len(plan.wl_name))}
-    q_of = plan.wl_queue.tolist()
-    req = plan.wl_request.tolist()
-    holds = {plan.key(i) for i, on in enumerate(plan.wl_running.tolist())
-             if on}
-    usage = [[0] * R for _ in plan.queues]
-    cusage = {name: [0] * R for name in cohorts}
-    for k in holds:
-        i = row[k]
-        for r in range(R):
-            usage[q_of[i]][r] += req[i][r]
-            cusage[cohort_of[q_of[i]]][r] += req[i][r]
-
-    def move(k, sign):
-        i = row[k]
-        c = q_of[i]
-        for r in range(R):
-            usage[c][r] += sign * req[i][r]
-            cusage[cohort_of[c]][r] += sign * req[i][r]
-        return c
-
-    violations = double = unknown = 0
-    for rnd in rounds:
-        for k in rnd.finished:
-            if k in holds:
-                holds.discard(k)
-                move(k, -1)
-            else:
-                unknown += 1
-        for cyc in rnd.cycles:
-            touched = set()
-            for k in cyc.evicted:
-                if k in holds:
-                    holds.discard(k)
-                    move(k, -1)
-                else:
-                    unknown += 1
-            for k in cyc.admitted:
-                if k in holds or k not in row:
-                    double += 1
-                    continue
-                holds.add(k)
-                touched.add(move(k, +1))
-            for c in touched:
-                h = cohort_of[c]
-                if any(usage[c][r] > cap[c][r] or cusage[h][r] > quota[h][r]
-                       for r in range(R)):
-                    violations += 1
-    return {"quota_violations": violations, "double_admissions": double,
-            "unknown_finishes": unknown}
-
-
-def compare(plan, rounds, measured_from: int, reference_cls,
-            broken=None) -> dict:
-    """``rounds``: every round of the run in order; the measured window
-    starts at index ``measured_from``.  Returns the numbers compared,
-    each with its limit, and the facts printed beside them."""
+def compare(kind, plan, rounds, measured_from: int, broken=None) -> dict:
+    """``kind``: the module of the plan's deployment kind.  ``rounds``:
+    every round of the run in order; the measured window starts at index
+    ``measured_from``.  Returns the numbers compared, each with its
+    limit, and the facts printed beside them."""
     t0 = time.perf_counter()
-    ref = reference_cls(plan, broken=broken)
+    fields = kind.COMPARED
+    ref = kind.Reference(plan, broken=broken)
     results, short, unknown_ref = replay(ref, rounds)
     mismatched = compared = 0
     first = None
@@ -121,23 +55,23 @@ def compare(plan, rounds, measured_from: int, reference_cls,
     for ri, (rnd, cycles) in enumerate(zip(rounds, results)):
         for ci, (cyc, r) in enumerate(zip(rnd.cycles, cycles)):
             compared += 1
-            if not _same(cyc, r):
+            if not _same(cyc, r, fields):
                 mismatched += 1
                 if first is None:
-                    first = {
-                        "round": ri, "cycle": ci,
-                        "admitted": [len(cyc.admitted), len(r.admitted)],
-                        "evicted": [len(cyc.evicted), len(r.evicted)],
-                        "skipped": [len(cyc.skipped), len(r.skipped)],
-                        "only_program": sorted(
-                            set(cyc.admitted) - set(r.admitted))[:3],
-                        "only_reference": sorted(
-                            set(r.admitted) - set(cyc.admitted))[:3]}
+                    first = {"round": ri, "cycle": ci}
+                    for name in fields:
+                        mine, theirs = getattr(cyc, name), getattr(r, name)
+                        first[name] = {
+                            "counts": [len(mine), len(theirs)],
+                            "only_program": sorted(
+                                set(mine) - set(theirs))[:3],
+                            "only_reference": sorted(
+                                set(theirs) - set(mine))[:3]}
             if r.evicted:
                 with_evictions += 1
                 evictions += len(r.evicted)
                 cross += r.cross_queue_evictions
-    led = ledger(plan, rounds)
+    led = kind.ledger(plan, rounds)
     window = rounds[measured_from:]
     stalled = int(bool(window) and not any(
         cyc.admitted for rnd in window for cyc in rnd.cycles))

@@ -3,9 +3,9 @@
 Nothing here imports the program.  ``plan_cluster`` turns one file of
 ``benchmarks/configs/`` and a seed into queues (name, cohort, quotas)
 and workloads (queue, class, requests, timestamps, running or pending).
-The program's builder (``benchmarks/program.py``) and the plain
-reference (``benchmarks/reference.py``) both start from this plan, so
-neither takes anything the other has made.
+The program's builder (``program.py`` beside this file) and the plain
+reference (``reference.py``) both start from this plan, so neither
+takes anything the other has made.
 
 The seed draws labels and nothing else: which ClusterQueue name holds
 which Zipf rank, and so the order of the queues and of their rows in
@@ -21,17 +21,11 @@ decided in which cycles one did; PERF.md, section 6.)
 
 from __future__ import annotations
 
-import json
 from dataclasses import dataclass
 
 import numpy as np
 
 GIB = 1 << 30
-
-
-def load_config(path: str) -> dict:
-    with open(path) as f:
-        return json.load(f)
 
 
 def unit_scale(cfg: dict) -> dict[str, int]:
@@ -110,6 +104,19 @@ def queue_rows(cfg: dict) -> dict:
     return {"running": running, "pending": pending,
             "hottest_running": max(running), "deepest_rows": deepest,
             "M": m, "slots": n * m, "preempting_forest_rows": forest_rows}
+
+
+def problem(cfg: dict, plan: ClusterPlan) -> dict:
+    """What ``benchmarks/peaks.py`` counts a decided cycle's bytes from:
+    the rows a cycle can decide about, the queues and the resources."""
+    return {"real_rows": queue_rows(cfg)["preempting_forest_rows"],
+            "queues": len(plan.queues), "resources": len(plan.resources)}
+
+
+def summary(plan: ClusterPlan) -> str:
+    return (f"built {len(plan.queues)} queues, "
+            f"{int(plan.wl_running.sum())} restored, "
+            f"{int((~plan.wl_running).sum())} ingested")
 
 
 def plan_cluster(cfg: dict, seed: int) -> ClusterPlan:

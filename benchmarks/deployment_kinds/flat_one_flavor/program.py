@@ -1,7 +1,7 @@
 """The system under test, built from a ``ClusterPlan``.
 
-The only file of the benchmark that imports the program's cluster
-objects.  Set-up drives ``Driver.restore_workload`` for the workloads
+The only file of this deployment kind that imports the program's
+cluster objects.  Set-up drives ``Driver.restore_workload`` for the workloads
 that hold quota and ``Driver.ingest_workloads`` for the backlog, as a
 manager does when it restarts on a full cluster.
 """
@@ -97,9 +97,10 @@ def build_driver(plan, use_device: bool = True):
     return d, clock
 
 
-def warm_up(driver, n_heads: int, max_candidates: int) -> dict:
+def warm_up(driver, plan) -> dict:
     """Every shape the cell's cycles can reach, compiled or loaded
-    before the window.  ``CycleSolver.warmup`` is the program's own
+    before the window: a head a queue, and candidate sets up to the
+    largest cohort's running rows.  ``CycleSolver.warmup`` is the program's own
     ladder: the admit scans and the batched preemption search up to
     128 candidates a head.  The search shapes it leaves to first use
     are warmed here, with the program's kernels and its own structure
@@ -117,6 +118,11 @@ def warm_up(driver, n_heads: int, max_candidates: int) -> dict:
     from kueue_tpu.ops.preemption_kernel import (
         minimal_preemptions, minimal_preemptions_batch)
     from kueue_tpu.ops.preemption_solver import K_LADDER, S_LADDER
+    n_heads = len(plan.queues)
+    cohort_rows: dict[str, int] = {}
+    for q in plan.queues:
+        cohort_rows[q.cohort] = cohort_rows.get(q.cohort, 0) + q.running
+    max_candidates = max(cohort_rows.values())
     solver = driver.scheduler.solver
     solver.warmup(driver.cache.snapshot(), n_heads)
     done = {"ladder": True, "batch_rungs": 0, "one_head_buckets": 0}

@@ -1,10 +1,10 @@
 """The plain reference: Kueue's admission cycle, written out directly.
 
 It imports nothing of the program and takes nothing the program has
-made: it starts from the ``ClusterPlan`` (benchmarks/cluster.py) and is
-fed only the inputs the program was fed: which workloads finished at
-each boundary and what the clock read at each cycle.  It covers exactly
-what the two configurations use: flat cohorts, one flavor, any number of
+made: it starts from the ``ClusterPlan`` (``cluster.py`` beside this
+file) and is fed only the inputs the program was fed: which workloads
+finished at each boundary and what the clock read at each cycle.  It
+covers exactly what this deployment kind is: flat cohorts, one flavor, any number of
 resources, BestEffortFIFO, no fair sharing, ``borrowWithinCohort:
 Never``.  The semantics are upstream Kueue's (pkg/scheduler/scheduler.go
 schedule(), flavorassigner.go fitsResourceQuota, preemption.go
@@ -35,6 +35,10 @@ from dataclasses import dataclass, field
 FIT, PREEMPT, NOFIT = 2, 1, 0
 
 CONTROLS = ("memory_unenforced",)
+
+# the fields of a cycle's result that the comparison holds the program's
+# record to (benchmarks/correct.py), each without regard to order
+COMPARED = ("admitted", "evicted", "skipped", "preempting")
 
 
 @dataclass
@@ -160,12 +164,14 @@ class Reference:
 
     # -- boundary -------------------------------------------------------------
 
-    def finish(self, keys) -> int:
-        """Release the quota of finished workloads and wake their
-        cohorts.  Returns how many were not running (a finish of a
-        workload the reference does not hold)."""
+    def begin_round(self, rnd) -> int:
+        """What the round fed the program before its cycles; of this
+        kind's traffic, the workloads that finished at the boundary.
+        Releases their quota and wakes their cohorts.  Returns how many
+        were not running (a finish of a workload the reference does not
+        hold)."""
         unknown = 0
-        for k in keys:
+        for k in rnd.finished:
             i = self.id_of.get(k)
             if i is None or i not in self.reserved_at:
                 unknown += 1

@@ -1,11 +1,63 @@
 """One run of one cell: set-up, measured window, comparison, metrics.
 
 Driven by data.  ``BENCHMARK.json`` names the cell's configuration file
-and its traffic; the traffic's data file (``traffic/<traffic>.json``)
-names its kind, found as ``traffic_kinds/<kind>.py``; each per-layer
-metric is ``metrics/<name>.json`` naming a reader
-``metric_kinds/<kind>.py``.  A later PR adds a deployment, a mix or a
-metric as new files and entries, and edits nothing here.
+and its traffic.  The configuration's file names its deployment kind
+(``"kind"``), found as the package ``deployment_kinds/<kind>/``; the
+traffic's data file (``traffic/<traffic>.json``) names its kind, found
+as ``traffic_kinds/<kind>.py``; each per-layer metric is
+``metrics/<name>.json`` naming a reader ``metric_kinds/<kind>.py``.  A
+later PR adds a deployment, a mix or a metric as new files and entries,
+and edits nothing here.
+
+The contract of a deployment kind
+---------------------------------
+Whatever is particular to one shape of cluster (how a configuration
+becomes queues and workloads, how the program's objects are declared,
+the plain reference's semantics, the capacity model of the quota
+ledger) lives in the kind's package.  The harness, ``correct.py`` and
+``control.py`` call of a kind the names of ``KIND_CONTRACT`` and nothing
+else, and import no kind by name
+(benchmarks/tests/test_deployment_kinds.py holds both):
+
+- ``plan_cluster(cfg, seed) -> plan``: the cluster as plain data, from
+  the configuration and the seed alone, importing nothing of the
+  program.  Of a plan the harness itself reads only ``config``,
+  ``clock_start`` and ``cycle_s``.
+- ``summary(plan) -> str``: one line for the run's log.
+- ``problem(cfg, plan) -> dict``: what benchmarks/peaks.py counts a
+  decided cycle's bytes from: ``real_rows``, ``queues``, ``resources``.
+- ``build_driver(plan) -> (driver, clock)``: the system under test,
+  restored and ingested; ``clock.t`` is the virtual time the traffic
+  advances.
+- ``warm_up(driver, plan) -> dict``: every shape the cell's cycles can
+  reach, compiled or loaded; what it returns goes to the log.
+- ``Reference(plan, broken=None)``: the plain reference, which imports
+  nothing of the program, with ``begin_round(round_record) -> int``
+  (takes the round's inputs from the traffic's record, whatever the
+  traffic kind wrote there; returns how many of them it does not know),
+  ``cycle(clock) -> result`` and ``has_heads() -> bool``.  A result
+  holds every field of ``COMPARED``, and ``evicted`` and
+  ``cross_queue_evictions`` for the facts beside the verdict.
+  ``broken`` is one of ``CONTROLS``: a guarantee the configuration
+  states, switched off.
+- ``CONTROLS``: the names ``broken`` takes; ``control.py`` puts each in
+  the program's place and the comparison has to fail it.
+- ``COMPARED``: the per-cycle fields that ``correct.compare`` holds equal,
+  order apart, between the traffic's cycle record and the reference's
+  result.  A kind that decides more (the flavor of each admission, say)
+  names one more field here and its traffic kind fills it.
+- ``ledger(plan, rounds) -> {"quota_violations", "double_admissions",
+  "unknown_finishes"}``: the program's own answers added up against the
+  kind's capacity model, trusting neither side.
+
+What a traffic kind reads of a plan is the kind's to keep or not:
+``burst_rounds`` reads ``queues[i].rank``, ``wl_queue``, ``wl_running``,
+``key(i)`` and ``cycle_s``.  A kind whose plan has them reuses the
+``backlog`` mix as it is; one that has not brings a traffic kind of its
+own.  Of the traffic's records the harness and the comparison read: a
+round's ``cycles``, ``max_cycles``, ``seconds`` and ``boundary_s``; a
+cycle's ``clock``, ``admitted``, ``evicted``, ``heads``, ``seconds`` and
+the fields of ``COMPARED``.
 """
 
 from __future__ import annotations
@@ -21,6 +73,9 @@ import time
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
+
+KIND_CONTRACT = ("plan_cluster", "summary", "problem", "build_driver",
+                 "warm_up", "Reference", "CONTROLS", "COMPARED", "ledger")
 
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 _CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
@@ -78,6 +133,29 @@ def _module(package: str, kind: str):
     return importlib.import_module(f"{package}.{kind}")
 
 
+def load_config(path: str) -> dict:
+    with open(path) as f:
+        return json.load(f)
+
+
+def deployment_kind(cfg: dict, where: str):
+    """The module of the deployment kind a configuration names.  There
+    is no default: a configuration replayed through another kind's
+    reference would be compared with the wrong semantics."""
+    kind = cfg.get("kind")
+    if not isinstance(kind, str) or not kind.isidentifier():
+        raise SystemExit(f"benchmark: configuration {where} names no "
+                         f"deployment kind (\"kind\": {kind!r})")
+    try:
+        return _module("deployment_kinds", kind)
+    except ModuleNotFoundError as e:
+        if e.name != f"deployment_kinds.{kind}":
+            raise
+        raise SystemExit(f"benchmark: configuration {where} names the "
+                         f"deployment kind {kind!r}, and there is no "
+                         f"package deployment_kinds/{kind}/") from None
+
+
 def program_counters(driver) -> dict:
     """The program's integer and float counters, flat."""
     out = {}
@@ -124,7 +202,7 @@ def run_cell(manifest: dict, cell_name: str, seed: int, seconds: float,
              trace: bool, t_start: float, root: str = ROOT,
              require_tpu: bool = True, on_rounds=None) -> dict:
     """Returns the result object of the run (the last line of standard
-    output, before it is serialised).  ``on_rounds(plan, rounds,
+    output, before it is serialised).  ``on_rounds(kind, plan, rounds,
     measured_from)`` sees the run's record before the comparison: the
     control (benchmarks/control.py) replays it through a broken
     reference."""
@@ -132,12 +210,11 @@ def run_cell(manifest: dict, cell_name: str, seed: int, seconds: float,
     gc.unfreeze()            # a second run in one process frees the first
     gc.collect()
 
-    import cluster
     import correct
-    import reference
 
     cell, cfg_entry = find_cell(manifest, cell_name)
-    cfg = cluster.load_config(os.path.join(root, cfg_entry["file"]))
+    cfg = load_config(os.path.join(root, cfg_entry["file"]))
+    kind = deployment_kind(cfg, cfg_entry["file"])
     with open(os.path.join(root, manifest["paths"][0], "traffic",
                            cell["traffic"] + ".json")) as f:
         traffic_params = json.load(f)
@@ -154,23 +231,16 @@ def run_cell(manifest: dict, cell_name: str, seed: int, seconds: float,
             f"chip(s); JAX reports {device}")
     compiles = CompileCounter()
 
-    import program
     from kueue_tpu import compilecache
     say(t_start, f"compile cache: {compilecache.enable()}")
 
     # ---- set-up ----------------------------------------------------
-    plan = cluster.plan_cluster(cfg, seed)
-    driver, clock = program.build_driver(plan, use_device=True)
-    say(t_start, f"built {len(plan.queues)} queues, "
-        f"{int(plan.wl_running.sum())} restored, "
-        f"{int((~plan.wl_running).sum())} ingested")
+    plan = kind.plan_cluster(cfg, seed)
+    driver, clock = kind.build_driver(plan)
+    say(t_start, kind.summary(plan))
     traffic = _module("traffic_kinds", traffic_params["kind"]).Traffic(
         traffic_params, plan, seed)
-    cohort_rows = {}
-    for q in plan.queues:
-        cohort_rows[q.cohort] = cohort_rows.get(q.cohort, 0) + q.running
-    warmed = program.warm_up(driver, len(plan.queues),
-                             max(cohort_rows.values()))
+    warmed = kind.warm_up(driver, plan)
     say(t_start, f"warm-up {warmed}: {compiles.read()}")
     rounds = []
     for _ in range(traffic.warm_rounds):
@@ -192,18 +262,22 @@ def run_cell(manifest: dict, cell_name: str, seed: int, seconds: float,
     # minutes to write, of the six a run may last.  The pause is taken
     # out of the traced run's window, which reports no end-to-end metric.
     marks = []
-    trace_dir = window_mark = None
-    traced_s = pause_s = 0.0
+    trace_dir = window_mark = traced_records = None
+    traced_s = pause_s = t_mark = 0.0
     counters_traced = {}
 
     def stop_trace(k=None):
         nonlocal window_mark, traced_s, pause_s, counters_traced
+        nonlocal traced_records
         if window_mark is None or (k is not None
                                    and k + 1 < traffic.trace_cycles):
             return
         t0 = time.perf_counter()
         traced_s = t0 - t_w0
         counters_traced = _delta(program_counters(driver), counters_setup)
+        if k is not None:      # the finished rounds, and k + 1 of this one
+            traced_records = k + 1 + sum(
+                len(r.cycles) for r in rounds[measured_from:])
         window_mark.__exit__(None, None, None)
         window_mark = None
         jax.profiler.stop_trace()
@@ -222,6 +296,9 @@ def run_cell(manifest: dict, cell_name: str, seed: int, seconds: float,
         jax.profiler.start_trace(trace_dir, profiler_options=opts)
         window_mark = jax.profiler.TraceAnnotation("bench.window")
         window_mark.__enter__()
+        # the instant the annotation opened, for the spans' move onto the
+        # trace's clock; the timed window opens at t_w0 below, as ever
+        t_mark = time.perf_counter()
     spans0 = span_totals(driver)
     mark = (lambda n, a, b: marks.append((n, a, b))) if trace else None
     usage0 = resource.getrusage(resource.RUSAGE_SELF)
@@ -248,12 +325,14 @@ def run_cell(manifest: dict, cell_name: str, seed: int, seconds: float,
     span_records = (list(driver.obs.tracer.trace_spans)
                     if trace and driver.obs.tracer is not None else [])
     window = rounds[measured_from:]
-    admissions = sum(len(c.admitted) for r in window for c in r.cycles)
-    evictions = sum(len(c.evicted) for r in window for c in r.cycles)
-    cycles = sum(1 for r in window for c in r.cycles if c.heads)
-    heads = sum(c.heads for r in window for c in r.cycles)
-    slowest = max((c.seconds for r in window for c in r.cycles),
-                  default=0.0)
+    done = [c for r in window for c in r.cycles]
+    admissions = sum(len(c.admitted) for c in done)
+    evictions = sum(len(c.evicted) for c in done)
+    cycles = sum(1 for c in done if c.heads)
+    # of them, those decided while the profiler was on
+    traced_cycles = sum(1 for c in done[:traced_records] if c.heads)
+    heads = sum(c.heads for c in done)
+    slowest = max((c.seconds for c in done), default=0.0)
     say(t_start, f"window: {len(window)} rounds "
         f"{[round(r.seconds, 3) for r in window]} s, {cycles} cycles, "
         f"{admissions} admissions, {evictions} evictions, slowest "
@@ -277,15 +356,10 @@ def run_cell(manifest: dict, cell_name: str, seed: int, seconds: float,
             # the program's spans and the benchmark's marks, moved onto
             # the trace's clock through the bench.window annotation
             w = trace_reduce.window_of(loaded)
-            host = []
-            if w is not None:
-                shift = w[0] - t_w0 * 1e9
-                host = [(n, a * 1e9 + shift, b * 1e9 + shift)
-                        for n, a, b in marks]
-                host += [(s.name, s.t0 * 1e9 + shift,
-                          (s.t0 + s.dur) * 1e9 + shift)
-                         for s in span_records]
-            reduced = trace_reduce.reduce_trace(loaded, marks=host)
+            on_trace = [] if w is None else trace_reduce.onto_trace_clock(
+                w, t_mark, marks + [(s.name, s.t0, s.t0 + s.dur)
+                                    for s in span_records])
+            reduced = trace_reduce.reduce_trace(loaded, marks=on_trace)
         if path is not None:
             size = os.path.getsize(path)
             say(t_start, f"trace: {size / 1e6:.1f} MB, reduced "
@@ -300,9 +374,8 @@ def run_cell(manifest: dict, cell_name: str, seed: int, seconds: float,
     driver.obs.disable_tracing()
     del driver
     if on_rounds is not None:
-        on_rounds(plan, rounds, measured_from)
-    verdict = correct.compare(plan, rounds, measured_from,
-                              reference.Reference)
+        on_rounds(kind, plan, rounds, measured_from)
+    verdict = correct.compare(kind, plan, rounds, measured_from)
     facts = verdict["facts"]
     say(t_start, f"reference: {facts['reference_s']:.2f} s over "
         f"{facts['cycles_compared']} cycles; cycles with evictions "
@@ -319,9 +392,9 @@ def run_cell(manifest: dict, cell_name: str, seed: int, seconds: float,
     }
     units = {m["name"]: m["unit"] for m in manifest["end_to_end"]}
     if trace:
-        rows = cluster.queue_rows(cfg)
         ctx = {
             "rounds": len(window), "cycles": cycles, "window_s": window_s,
+            "traced_cycles": traced_cycles,
             "clocks": {"boundary_s": sum(r.boundary_s for r in window)},
             "spans": spans, "tracer_on": driver_traced,
             "counters": {"window": dict(counters_window, **window_compiles),
@@ -330,9 +403,7 @@ def run_cell(manifest: dict, cell_name: str, seed: int, seconds: float,
             "memory_peak_bytes": peak,
             "trace": reduced,
             "device_kind": device["kind"],
-            "problem": {"real_rows": rows["preempting_forest_rows"],
-                        "queues": len(plan.queues),
-                        "resources": len(plan.resources)},
+            "problem": kind.problem(cfg, plan),
         }
         metrics = read_per_layer(manifest, cell_name, ctx, root)
     else:
@@ -351,7 +422,7 @@ def run_cell(manifest: dict, cell_name: str, seed: int, seconds: float,
         end_to_end, window_s=window_s, rounds=len(window), cycles=cycles,
         admissions=admissions, evictions=evictions,
         slowest_cycle_ms=slowest * 1e3,
-        cycle_s=[round(c.seconds, 3) for r in window for c in r.cycles],
+        cycle_s=[round(c.seconds, 3) for c in done],
         host=host,
         cycles_compared=facts["cycles_compared"],
         cycles_with_evictions=facts["cycles_with_evictions"],

@@ -153,6 +153,14 @@ def lint(manifest: dict, root: str = ROOT, raw_size: int | None = None
                 for key in ("assumed", "deployment", "guarantees"):
                     if not body.get(key):
                         f.append(f"{what}: the file states no {key}")
+                kind = body.get("kind")
+                if not (isinstance(kind, str) and kind.isidentifier()):
+                    f.append(f"{what}: the file names no deployment kind")
+                elif not any(os.path.isfile(os.path.join(
+                        root, p, "deployment_kinds", kind, "__init__.py"))
+                        for p in paths):
+                    f.append(f"{what}: deployment kind {kind!r} has no "
+                             f"package deployment_kinds/{kind}/")
 
     # workloads
     cells = manifest["workloads"]

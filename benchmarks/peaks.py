@@ -20,8 +20,9 @@ def peak(device_kind: str) -> dict:
     return PEAKS[device_kind]
 
 
-# What one launch of the fused window has to read, counted from the
-# cluster and never from the implementation.  A real row is a workload
+# What one launch of the fused window has to read, and what any program
+# has to read to decide one cycle, counted from the cluster and never
+# from the implementation.  A real row is a workload
 # the window can decide about: every admitted workload of a forest that
 # can preempt, and the pending workloads a queue can reach inside one
 # window.  Deciding needs, a row: its request in each resource, its
@@ -44,7 +45,8 @@ def queue_bytes(n_resources: int) -> int:
 
 
 def burst_launch_bytes(real_rows: int, queues: int, n_resources: int) -> int:
-    """Least bytes one fused-window launch moves through HBM: every real
-    row and every queue's quota state read once."""
+    """Least bytes one fused-window launch, or one decided cycle by any
+    program, moves through HBM: every real row and every queue's quota
+    state read once."""
     return (real_rows * row_bytes(n_resources)
             + queues * queue_bytes(n_resources))

@@ -5,7 +5,7 @@ import os
 
 import numpy as np
 
-import cluster
+from deployment_kinds.flat_one_flavor import cluster
 from traffic_kinds import burst_rounds
 
 from conftest import BENCH
@@ -61,3 +61,25 @@ def drive(driver, clock, plan, rounds, seed=0, **over):
 
 def short(key):
     return key.split("/", 1)[1]
+
+
+def shadow_root(tmp_path):
+    """A root beside the repository's: ``<tmp>/benchmarks`` holds a link
+    to everything ``benchmarks/`` has, and the directories a later PR
+    adds files to are real, with a link to each of their entries, so
+    that a test can put a new file beside the benchmark's and edit none
+    of them.  Returns the root."""
+    bench = tmp_path / "benchmarks"
+    bench.mkdir()
+    for x in os.listdir(BENCH):
+        if x == "__pycache__":
+            continue
+        if x not in ("configs", "traffic", "deployment_kinds",
+                     "traffic_kinds"):
+            os.symlink(os.path.join(BENCH, x), bench / x)
+            continue
+        (bench / x).mkdir()
+        for y in os.listdir(os.path.join(BENCH, x)):
+            if y != "__pycache__":
+                os.symlink(os.path.join(BENCH, x, y), bench / x / y)
+    return str(tmp_path)

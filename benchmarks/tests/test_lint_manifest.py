@@ -9,6 +9,7 @@ import pytest
 import lint_manifest
 
 from conftest import ROOT
+from helpers import shadow_root
 
 MANIFEST = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
 
@@ -52,6 +53,25 @@ def _broken(edit):
 def test_lint_finds(edit, says):
     faults = _broken(edit)
     assert any(says in x for x in faults), faults
+
+
+@pytest.mark.parametrize("edit, says", [
+    (lambda cfg: cfg.pop("kind"), "names no deployment kind"),
+    (lambda cfg: cfg.update(kind="no_such_kind"), "has no package"),
+    (lambda cfg: cfg.update(reduced=["cluster_queues"]), "reduced differs"),
+])
+def test_lint_finds_in_the_configuration_file(tmp_path, edit, says):
+    root = shadow_root(tmp_path)
+    path = os.path.join(root, MANIFEST["configs"][0]["file"])
+    with open(path) as f:
+        cfg = json.load(f)
+    edit(cfg)
+    os.remove(path)                      # the link, not the file behind it
+    with open(path, "w") as f:
+        json.dump(cfg, f)
+    assert lint_manifest.lint(MANIFEST, ROOT) == []
+    faults = lint_manifest.lint(MANIFEST, root)
+    assert len(faults) == 1 and says in faults[0], faults
 
 
 GOOD = {"correct": True, "attempted": 10, "failed": 0,

@@ -80,3 +80,28 @@ def test_ratio_reader():
     assert ratio.read(spec, ctx) is None          # nothing was launched
     del ctx["counters"]["window"]["a"]
     assert ratio.read(spec, ctx) is None          # a program without it
+
+
+def test_decide_roofline_reader():
+    """The least time to decide the traced cycles over the device's busy
+    time, every program counted: 6 cycles x (322,000 rows x 25 B + 1,000
+    queues x 24 B) at 819 GB/s over 5.8 s busy."""
+    from metric_kinds import trace
+    spec = SPECS["decide_roofline"]
+    ctx = {"trace": {"busy_s": 5.8, "window_s": 36.0, "program_s": {}},
+           "traced_cycles": 6, "device_kind": "TPU v5 lite",
+           "problem": {"real_rows": 322_000, "queues": 1000,
+                       "resources": 2},
+           "counters": {"traced": {}}}
+    least_s = 6 * (322_000 * 25 + 1000 * 24) / 819e9
+    assert trace.read(spec, ctx) == pytest.approx(100 * least_s / 5.8)
+    assert 0.0009 < trace.read(spec, ctx) < 0.0011
+    # no launch of any one program is needed: the kernel's own share
+    # falls silent here, this one does not
+    assert trace.read(SPECS["burst_kernel_roofline"], ctx) is None
+    # twice the busy time for the same decisions is half the share
+    ctx["trace"]["busy_s"] = 11.6
+    assert trace.read(spec, ctx) == pytest.approx(50 * least_s / 5.8)
+    for nothing in ({"traced_cycles": 0}, {"trace": None},
+                    {"trace": dict(ctx["trace"], busy_s=0.0)}):
+        assert trace.read(spec, dict(ctx, **nothing)) is None

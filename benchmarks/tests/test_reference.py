@@ -12,10 +12,10 @@ import os
 
 import pytest
 
-import cluster
 import correct
-import program
-import reference
+import harness
+from deployment_kinds import flat_one_flavor as kind
+from deployment_kinds.flat_one_flavor import cluster, program, reference
 
 from conftest import HERE
 from helpers import drive, hand_plan, short
@@ -26,14 +26,14 @@ TOYS = ["toy-zipf.json"]
 def witness(plan, rounds, **over):
     driver, clock = program.build_driver(plan, use_device=False)
     records = drive(driver, clock, plan, rounds, **over)
-    verdict = correct.compare(plan, records, 0, reference.Reference)
+    verdict = correct.compare(kind, plan, records, 0)
     return records, verdict
 
 
 @pytest.mark.parametrize("toy", TOYS)
 @pytest.mark.parametrize("seed", [3, 4, 2_147_483_659])
 def test_reference_equals_scalar_scheduler(toy, seed):
-    cfg = cluster.load_config(os.path.join(HERE, "data", toy))
+    cfg = harness.load_config(os.path.join(HERE, "data", toy))
     plan = cluster.plan_cluster(cfg, seed)
     records, verdict = witness(plan, 6, seed=seed,
                                finish_fraction_per_round=0.05)
@@ -49,16 +49,32 @@ def test_reference_equals_scalar_scheduler(toy, seed):
 def test_control_fails_the_comparison(toy):
     """The control is the reference with one stated guarantee switched
     off, put in the program's place: it has to come out not correct."""
-    cfg = cluster.load_config(os.path.join(HERE, "data", toy))
+    cfg = harness.load_config(os.path.join(HERE, "data", toy))
     plan = cluster.plan_cluster(cfg, 5)
     records, verdict = witness(plan, 4, seed=5,
                                finish_fraction_per_round=0.05)
     assert verdict["correct"]
     for broken in reference.CONTROLS:
-        control = correct.compare(plan, records, 0, reference.Reference,
-                                  broken=broken)
+        control = correct.compare(kind, plan, records, 0, broken=broken)
         assert not control["correct"], broken
         assert control["compared"]["mismatched_cycles"]["value"] >= 3
+
+
+def test_control_readings_put_each_control_in_the_programs_place():
+    """``control.py``'s own loop: the control decides the recorded
+    cycles, its answers take the record's place field by field of
+    ``COMPARED``, and the same comparison reads them; the record itself
+    is left as the program wrote it."""
+    import control
+    cfg = harness.load_config(os.path.join(HERE, "data", TOYS[0]))
+    plan = cluster.plan_cluster(cfg, 6)
+    records, verdict = witness(plan, 4, seed=6,
+                               finish_fraction_per_round=0.05)
+    readings = control.control_readings(kind, plan, records, 0)
+    assert set(readings) == set(kind.CONTROLS)
+    for row in readings.values():
+        assert row["correct"] is False and row["mismatched_cycles"] >= 3
+    assert correct.compare(kind, plan, records, 0)["correct"]
 
 
 ONE_QUEUE = [("cq-0", "cohort-0", {"cpu": 10_000, "memory": 8},

@@ -59,19 +59,77 @@ def test_rehearsal_untraced(cell):
     assert r["facts"]["window_programs"]["programs_built"] == 0
 
 
-def test_rehearsal_traced():
+def cpu_cannot_read():
+    """The per-layer metrics only a chip gives, from the data and not by
+    hand: those whose ``source`` is the device's trace (XLA:CPU writes
+    no device plane) and those of the ``memory`` reader (the CPU backend
+    reports no ``memory_stats``)."""
+    out = set()
+    for m in TOY["per_layer"]:
+        with open(os.path.join(ROOT, TOY["paths"][0], "metrics",
+                               m["name"] + ".json")) as f:
+            reader = json.load(f)["kind"]
+        if m["source"] == "device_trace" or reader == "memory":
+            out.add(m["name"])
+    return out
+
+
+@pytest.mark.parametrize("path", ["windows_applied", "windows_dropped"])
+def test_rehearsal_traced(monkeypatch, path):
+    """The traced run on both of the fused window's paths.  At the
+    cell's size a forest's candidate slots (5 queues x 65,536 rows) are
+    over the fused kernel's cap, so a preempting head makes the window
+    dirty and the per-cycle engine searches; the toy's 5 x 64 are under
+    it and its windows are applied.  With the cap lowered the toy takes
+    the cell's path."""
+    if path == "windows_dropped":
+        from kueue_tpu.ops import burst
+        monkeypatch.setattr(burst, "KC_CAP", 32)
     r = run(CELLS[0], 9, trace=True)
     assert r["correct"] is True
-    got = set(r["metrics"])
     # no TPU here: the trace has no device plane, so its readers and the
     # memory reader find nothing and leave their metrics out
-    assert got == {m["name"] for m in TOY["per_layer"]} - {
-        "burst_kernel_ms", "burst_kernel_roofline", "device_idle_pct",
-        "device_peak_gib"}
+    device_only = cpu_cannot_read()
+    assert {"burst_kernel_ms", "burst_kernel_roofline", "decide_roofline",
+            "device_idle_pct", "device_peak_gib",
+            "search_kernel_ms"} <= device_only
+    assert len(TOY["per_layer"]) >= 31
+    unread = {m["name"] for m in TOY["per_layer"]} - set(r["metrics"])
+    # an applied window keeps the per-cycle engine idle: spans of the
+    # scheduler that were never entered are nothing to read, and nothing
+    # of another layer may be missing
+    bypassed = unread - device_only
+    assert {m["layer"] for m in TOY["per_layer"]
+            if m["name"] in bypassed} <= {"scheduler"}
+    if path == "windows_dropped":
+        assert not bypassed
+    assert unread >= device_only
     assert "busy_s" not in r["device"]
-    assert r["metrics"]["compiles_in_window"]["value"] == 0
-    assert r["metrics"]["launches_per_round"]["value"] >= 1
-    assert r["metrics"]["h2d_mb"]["value"] > 0
+    # the line passes the contract but for what only a chip can give
+    faults = lint_manifest.lint_line(TOY, CELLS[0], 1, json.dumps(r))
+    assert sorted(faults) == sorted(
+        [f"result: metric {n!r} is missing" for n in unread]
+        + [f"result: device.{k} must be above 0 in a traced run"
+           for k in ("busy_s", "window_s")])
+    m = {k: v["value"] for k, v in r["metrics"].items()}
+    assert m["compiles_in_window"] == 0
+    assert m["launches_per_round"] >= 1 and m["h2d_mb"] > 0
+    if path == "windows_applied":
+        return
+    # as the cell: every window dropped, every cycle by the per-cycle
+    # engine, its searches batched
+    assert m["offwindow_cycles_pct"] == 100 and m["apply_ms"] == 0
+    assert m["discarded_window_cycles"] == 32 * m["launches_per_round"]
+    assert m["nominate_ms"] > 0 and m["search_wait_ms"] > 0
+    assert m["single_searches_per_round"] == m["search_fallback_ms"] == 0
+    assert 0 <= m["search_pad_pct"] < 100
+    # children stay inside their parents
+    assert m["percycle_ms"] >= m["nominate_ms"] >= (
+        m["nominate_self_ms"] + m["classify_ms"] + m["search_plan_ms"]
+        + m["search_pack_ms"] + m["search_wait_ms"])
+    assert m["pack_ms"] >= (m["pack_walk_ms"] + m["pack_grid_ms"]
+                            + m["pack_self_ms"]) > 0
+    assert m["dispatch_ms"] >= m["tighten_ms"] >= 0
 
 
 def test_fault_state_left_unchanged(monkeypatch):

@@ -13,7 +13,8 @@ import os
 import numpy as np
 import pytest
 
-import cluster
+import harness
+from deployment_kinds.flat_one_flavor import cluster
 
 from conftest import ROOT
 
@@ -23,7 +24,7 @@ CONFIGS = [c["file"] for c in MANIFEST["configs"]]
 
 @pytest.mark.parametrize("path", CONFIGS)
 def test_grid_from_the_file_alone(path):
-    cfg = cluster.load_config(os.path.join(ROOT, path))
+    cfg = harness.load_config(os.path.join(ROOT, path))
     rows = cluster.queue_rows(cfg)
     assert abs(rows["hottest_running"] - 38_475) <= 1
     assert rows["M"] == 65_536 == cfg["fused_path_limits"]["grid_rows_M"]
@@ -43,7 +44,7 @@ def test_grid_from_the_file_alone(path):
 @pytest.mark.parametrize("path", CONFIGS)
 @pytest.mark.parametrize("seed", [0, 17, 3_000_000_019])
 def test_plan_holds_the_sizes_for_any_seed(path, seed):
-    cfg = cluster.load_config(os.path.join(ROOT, path))
+    cfg = harness.load_config(os.path.join(ROOT, path))
     plan = cluster.plan_cluster(cfg, seed)
     per_queue = np.bincount(plan.wl_queue[plan.wl_running], minlength=1000)
     assert per_queue.max() == cluster.queue_rows(cfg)["hottest_running"]
@@ -74,7 +75,7 @@ def test_plan_holds_the_sizes_for_any_seed(path, seed):
 
 
 def test_seeds_share_sizes_and_differ_in_order():
-    cfg = cluster.load_config(os.path.join(ROOT, CONFIGS[0]))
+    cfg = harness.load_config(os.path.join(ROOT, CONFIGS[0]))
     a, b = cluster.plan_cluster(cfg, 1), cluster.plan_cluster(cfg, 2)
     assert sorted(q.running for q in a.queues) == sorted(
         q.running for q in b.queues)

@@ -70,3 +70,42 @@ def test_no_device_plane_is_nothing_to_read(tmp_path):
     jax.profiler.stop_trace()
     path = trace_reduce.find_xplane(str(tmp_path))
     assert path is not None and trace_reduce.load_trace(path) is None
+
+
+def test_a_span_opened_at_the_mark_lands_on_the_windows_start(loaded):
+    lo, hi = trace_reduce.window_of(loaded)
+    t_mark = 1234.5                      # the host's clock as it opened
+    (name, a, b), (_, c, _) = trace_reduce.onto_trace_clock(
+        (lo, hi), t_mark, [("span", t_mark, t_mark + 0.25),
+                           ("later", t_mark + 0.00118, t_mark + 1)])
+    assert (name, a, b) == ("span", lo, lo + 0.25e9)
+    assert c - lo == pytest.approx(1.18e6)     # ns: 1.18 ms into the window
+
+
+def test_host_clock_and_annotations_agree_through_the_mark(tmp_path):
+    """The harness's one-point move, on a real profile: a probe
+    annotation opened right after the host's clock is read lands, once
+    moved, on its own event of /host:CPU.  The closest of five probes
+    agrees to 0.1 ms (a probe can lose its core between the two)."""
+    import time
+
+    import jax
+    from jax.profiler import ProfileData, TraceAnnotation
+    jax.profiler.start_trace(str(tmp_path))
+    probes = []
+    with TraceAnnotation(trace_reduce.WINDOW_MARK):
+        t_mark = time.perf_counter()
+        for i in range(5):
+            time.sleep(0.002)
+            t = time.perf_counter()
+            with TraceAnnotation(f"bench.probe{i}"):
+                probes.append((f"bench.probe{i}", t, t))
+    jax.profiler.stop_trace()
+    data = ProfileData.from_file(trace_reduce.find_xplane(str(tmp_path)))
+    noted = {ev.name: ev.start_ns for plane in data.planes
+             if plane.name == "/host:CPU" for line in plane.lines
+             for ev in line.events if ev.name.startswith("bench.")}
+    window = (noted[trace_reduce.WINDOW_MARK], None)
+    moved = trace_reduce.onto_trace_clock(window, t_mark, probes)
+    off_ms = [abs(a - noted[name]) / 1e6 for name, a, _ in moved]
+    assert len(off_ms) == 5 and min(off_ms) < 0.1, off_ms

@@ -80,6 +80,15 @@ def window_of(loaded: dict):
     return (w[0][1], w[0][2]) if w else None
 
 
+def onto_trace_clock(window, t_mark: float, marks):
+    """Host marks (name, start, end) in seconds of ``time.perf_counter``
+    -> nanoseconds of the trace's clock, through one point: ``window``
+    is the ``bench.window`` annotation as the trace holds it and
+    ``t_mark`` the host's clock read as it opened."""
+    shift = window[0] - t_mark * 1e9
+    return [(n, a * 1e9 + shift, b * 1e9 + shift) for n, a, b in marks]
+
+
 def reduce_trace(loaded: dict, marks=None, window=None) -> dict:
     """``marks``: [(name, start_ns, end_ns)] on the trace's clock, or
     None to take the host's ``bench.*`` annotations from the trace.
