@@ -17,11 +17,21 @@ filled with the plane's pad value.  Shrink never happens — a smaller
 request just views a prefix of the slab, so transient peaks don't
 cause realloc churn.
 
+A plan owns a copy of the live region, not the slab (the next window
+patches the slab in place).  ``snapshot`` makes that copy in a buffer
+it keeps and writes over for the next plan once nothing else refers to
+the last one: a window of 5 GB in pages the process already holds is
+copied and sent to the device at the bus's rate, where pages mapped
+fresh every window are faulted in one by one first and transfer at a
+rate that changes from window to window.
+
 The arena also keeps the occupancy/growth counters surfaced as
 ``kueue_pack_arena_*`` gauges.
 """
 
 from __future__ import annotations
+
+import sys
 
 import numpy as np
 
@@ -41,13 +51,17 @@ class PlaneArena:
     def __init__(self):
         self._slabs: dict[str, np.ndarray] = {}
         self._fills: dict[str, object] = {}
+        self._snaps: dict[str, np.ndarray] = {}
         self.stats = {"arena_growth_events": 0, "arena_planes": 0,
-                      "arena_bytes": 0, "arena_used_bytes": 0}
+                      "arena_bytes": 0, "arena_used_bytes": 0,
+                      "arena_snapshots_reused": 0,
+                      "arena_snapshots_fresh": 0}
 
     def drop(self) -> None:
         """Forget every slab (structure change with new trailing axes)."""
         self._slabs.clear()
         self._fills.clear()
+        self._snaps.clear()
 
     def ensure(self, name: str, shape: tuple, dtype, fill,
                grow_axes: int = 2) -> np.ndarray:
@@ -79,6 +93,25 @@ class PlaneArena:
 
     def view(self, name: str, shape: tuple) -> np.ndarray:
         return self._slabs[name][tuple(slice(0, int(s)) for s in shape)]
+
+    def snapshot(self, name: str, view: np.ndarray) -> np.ndarray:
+        """A contiguous copy of ``view`` for a plan to own.  The buffer
+        of the last snapshot under ``name`` is written over when the
+        arena holds the only reference to it, that is when the plan it
+        was made for, every view of it and any transfer still reading it
+        are gone; a buffer somebody still holds is left to its holder
+        and a fresh one takes its place."""
+        buf = self._snaps.pop(name, None)
+        # two references: ``buf`` and getrefcount's own argument
+        if (buf is not None and buf.shape == view.shape
+                and buf.dtype == view.dtype and sys.getrefcount(buf) == 2):
+            np.copyto(buf, view)
+            self.stats["arena_snapshots_reused"] += 1
+        else:
+            buf = view.copy()
+            self.stats["arena_snapshots_fresh"] += 1
+        self._snaps[name] = buf
+        return buf
 
     def refresh_stats(self, used_shapes: dict | None = None) -> dict:
         """Recompute the byte counters; ``used_shapes`` maps plane name

@@ -1010,6 +1010,7 @@ class Driver:
         if pipeline is None:
             pipeline = os.environ.get("KUEUE_BURST_PIPELINE", "1") != "0"
         spec = None          # speculative BurstHandle for the next window
+        plan = handle = None
         last_adm_clock = None
         clock_monotone = True
 
@@ -1066,6 +1067,10 @@ class Driver:
             else:
                 K = next((r for r in K_BURST_LADDER if r >= min(
                     remaining, K_BURST_LADDER[-1])), K_BURST_LADDER[-1])
+                # the last window is applied or dropped: let go of it,
+                # so that the pack writes this window's planes over its
+                # snapshot (cache/arena.py) and maps no fresh pages
+                plan = handle = None
                 _t_pack = time.perf_counter()
                 with _span("burst.pack"):
                     plan, self._burst_pack_state, _ = pack_burst_cached(

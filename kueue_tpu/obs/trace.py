@@ -58,14 +58,17 @@ SPAN_BUCKETS = exponential_buckets(1e-6, 2, 22)
 #: Every phase the hot path is instrumented with, in call order.  The
 #: OBS artifact's span roster and the SIGUSR2 dump are checked against
 #: this list; adding an instrumentation site means adding its name here.
-#: A dotted child is opened only inside its parent; the roster also
-#: carries ``<name>.self`` for every phase that had a child.
+#: A dotted child is opened only inside its parent (the reclaim
+#: oracle's searches reuse the search's five phases one level deeper,
+#: inside ``cycle.nominate.oracle``); the roster also carries
+#: ``<name>.self`` for every phase that had a child.
 HOT_PATH_PHASES = (
     "cycle",            # one whole scheduling cycle (schedule_once path)
     "cycle.snapshot",   # cache snapshot build / incremental reuse
     "cycle.nominate",   # validation + flavor assignment + preempt targets
     "cycle.nominate.classify",        # cycle pack + device classification
     "cycle.nominate.walk",            # host FlavorAssigner walks
+    "cycle.nominate.oracle",          # the reclaim oracle's batched searches
     "cycle.nominate.candidates",      # find/sort candidates, plan searches
     "cycle.nominate.search_pack",     # numpy fill of the [S, K, F] planes
     "cycle.nominate.search_launch",   # planes up, the search, results back

@@ -1867,6 +1867,18 @@ def _pack_burst_cached_classic(structure, queues, cache, scheduler,
 # cycles at most ~15ms of kernel time when fewer remain
 K_BURST_LADDER = (32,)
 
+# A launch's host planes go to the device ahead of the call, in batches
+# of at most this many bytes, each waited for before the next is sent.
+# Handed to the call all at once, the 4.9 GiB of an F = 8 launch went up
+# in 1.2 to 3.9 s on a TPU v5e, another time every launch, where 3.1 GiB
+# (F = 2) took 0.6 s; in batches of this size the same planes go up in
+# 0.55 s every time, at the 9 to 10 GB/s that one plane alone reaches
+# (PERF.md, Findings, PR 31).  Whatever the runtime stages transfers
+# through holds this much whole; nothing else is known of it.
+H2D_BATCH_BYTES = 2 << 30
+# planes under this size ride with the call
+H2D_STAGE_MIN_BYTES = 1 << 20
+
 
 class _ResidentRows:
     """Device-resident scatter-tier row planes from the last fresh
@@ -1925,6 +1937,8 @@ class BurstSolver:
                       # spread over (sharded: the shard count)
                       "burst_output_devices": 0,
                       "burst_dispatch_s": 0.0,
+                      # batches a serial launch's host planes went up in
+                      "burst_h2d_batches": 0,
                       # boundary + fallback visibility (VERDICT r4 item 9)
                       "burst_pack_s": 0.0, "burst_packs": 0,
                       "burst_suppressed_cycles": 0,
@@ -2157,13 +2171,13 @@ class BurstSolver:
             from .packing import tighten_arrays
             with _span("burst.dispatch.tighten"):
                 a = tighten_arrays(a, self._tighten, self.stats)
-        (elig0, parked0, resume0, adm0, adm_seq0, adm_usage0,
-         adm_uses0, death0, u_cq0) = state
         self.stats["burst_launch_bytes_h2d"] += (
             sum(v.nbytes for v in a.values() if isinstance(v, np.ndarray))
             + sum(v.nbytes for v in state if isinstance(v, np.ndarray)))
         t0 = _time.perf_counter()
         with _span("burst.dispatch.launch"):
+            a, (elig0, parked0, resume0, adm0, adm_seq0, adm_usage0,
+                adm_uses0, death0, u_cq0) = self._stage(a, state, dev)
             out = burst_cycles(
                 a["wl_req"], a["wl_rank"], a["wl_cycle_rank"],
                 a["wl_prio"], a["wl_uidrank"], a["vec_ok"],
@@ -2195,6 +2209,42 @@ class BurstSolver:
         return BurstHandle(plan=plan, K=K, runtime=runtime,
                            seq_base=seq_base, dev=dev, pending=out,
                            speculative=speculative, t_dispatch=t0)
+
+    def _stage(self, a: dict, state: tuple, dev) -> tuple[dict, tuple]:
+        """Send a serial launch's large host planes (the row planes of
+        ``a`` and the scan state) to ``dev`` in batches of at most
+        ``H2D_BATCH_BYTES``, each waited for.  Returns ``a`` and
+        ``state`` with those planes as device arrays; device arrays (a
+        chained state) and small planes pass through."""
+        a = dict(a)
+        state = list(state)
+        rows = ("wl_req", "wl_rank", "wl_cycle_rank", "wl_prio",
+                "wl_uidrank", "vec_ok")
+        batch: list[tuple] = []
+
+        def flush():
+            if batch:
+                up = jax.device_put([box[k] for box, k in batch], dev)
+                jax.block_until_ready(up)
+                for (box, k), x in zip(batch, up):
+                    box[k] = x
+                self.stats["burst_h2d_batches"] += 1
+                batch.clear()
+
+        size = 0
+        for box, k in ([(a, k) for k in rows]
+                       + [(state, i) for i in range(len(state))]):
+            x = box[k]
+            if (not isinstance(x, np.ndarray)
+                    or x.nbytes < H2D_STAGE_MIN_BYTES):
+                continue
+            if size + x.nbytes > H2D_BATCH_BYTES:
+                flush()
+                size = 0
+            batch.append((box, k))
+            size += x.nbytes
+        flush()
+        return a, tuple(state)
 
     def _sharded_fn(self, plan: BurstPlan, layout, K: int, runtime: int):
         from ..parallel.sharded import sharded_burst_fn

@@ -76,6 +76,17 @@ def classify_np(packed, avail0=None, potential0=None, start_slot=None):
                     (False ⇒ the resource is the one needing preemption)
       preempt_stopped0  the walk STOPPED at the preempt slot (the choice
                     is policy-forced, independent of the reclaim oracle)
+      preempt_slots [W, S] the attempted preempt-capable slots; with
+                    several and no stop, the pick among them is the
+                    reclaim oracle's (``pick_preempt_slot_np``)
+      slot_res_fit  [W, S, R] per-resource Fit flag on every slot
+      slot_borrows  [W, S] an assignment on the slot borrows
+      oracle_ask    [W, S, R] the resources of ``preempt_slots`` on
+                    which the host walk asks the oracle: short of quota,
+                    within nominal, and not borrowing with the request
+                    (flavorassigner.go:692, preemption_oracle.go:40)
+      walk_slots    [W] flavors the walk visited: up to its stop slot,
+                    or the whole list from its start
     """
     st = packed.structure
     usage0 = packed.usage0
@@ -154,8 +165,13 @@ def classify_np(packed, avail0=None, potential0=None, start_slot=None):
     # reorders among preempt-capable flavors — flavorassigner.go:692
     # RECLAIM vs PREEMPT), so the device may fix the slot without running
     # the oracle; a policy STOP at the slot forces it the same way
-    preempt_slot_count = (preempt_s & active_s).sum(axis=1).astype(np.int32)
+    preempt_slots = preempt_s & active_s
+    preempt_slot_count = preempt_slots.sum(axis=1).astype(np.int32)
     preempt_stopped0 = has_preempt & has_stop
+    oracle_ask = (preempt_slots[:, :, None] & relevant & ~fit_r
+                  & (req <= nom) & (use + req <= sq))
+    last = np.where(has_stop, stop_idx + 1, st.slot_count_cq[cqs])
+    walk_slots = np.where(valid, np.maximum(last - start, 0), 0)
 
     return {
         "fit_slot0": fit_slot0,
@@ -166,9 +182,28 @@ def classify_np(packed, avail0=None, potential0=None, start_slot=None):
         "preempt_res_fit": preempt_res_fit,
         "preempt_slot_count": preempt_slot_count,
         "preempt_stopped0": preempt_stopped0,
+        "preempt_slots": preempt_slots,
+        "slot_res_fit": fit_r | ~relevant,
+        "slot_borrows": borrows_s,
+        "oracle_ask": oracle_ask,
+        "walk_slots": walk_slots.astype(np.int32),
         "avail0": avail0,
         "potential0": potential0,
     }
+
+
+def pick_preempt_slot_np(preempt_slots, slot_res_fit, reclaim) -> np.ndarray:
+    """The walk's pick among several preempt-capable slots, once the
+    reclaim oracle has answered (flavorassigner.go:308 granular modes):
+    a resource short of quota is Reclaim where ``reclaim`` [W, S, R]
+    says the quota can be had from other queues' borrowers alone, else
+    Preempt; a slot is as good as its worst resource (Fit > Reclaim >
+    Preempt) and the first slot of the best mode wins.  Returns [W]
+    slots; a row without a preempt-capable slot reads 0."""
+    res_mode = np.where(slot_res_fit, 3, np.where(reclaim, 2, 1))
+    mode = np.where(preempt_slots, res_mode.min(axis=2), 0)
+    return np.argmax(mode == mode.max(axis=1, keepdims=True),
+                     axis=1).astype(np.int32)
 
 
 def cycle_order_np(borrows, priority, timestamp) -> np.ndarray:

@@ -51,7 +51,8 @@ from ..resources import FlavorResource, Requests
 from .packing import (PackedCycle, PackedStructure, _bucket, coarse_bucket,
                       pack_cycle, pack_structure)
 from .cycle import (admit_scan, admit_scan_forests, admit_scan_preempt,
-                    classify_np, cycle_order_np, decision_pairs_from_slots)
+                    classify_np, cycle_order_np, decision_pairs_from_slots,
+                    pick_preempt_slot_np)
 from .device import on_accelerator, output_devices, solver_device
 
 # A flat admit scan is one lax.scan step per head; the forest-parallel
@@ -84,6 +85,13 @@ class ClassifiedCycle:
                                            # policy-stopped ON the preempt
                                            # slot (choice is final — no
                                            # reclaim-oracle dependence)
+    # the walk's per-slot planes (classify_np), from which the pick
+    # among several preempt-capable slots is made once the reclaim
+    # oracle has answered (CycleSolver.pick_preempt_slots)
+    preempt_slots: np.ndarray = None       # [W, S] bool
+    slot_res_fit: np.ndarray = None        # [W, S, R] bool
+    slot_borrows: np.ndarray = None        # [W, S] bool
+    oracle_ask: np.ndarray = None          # [W, S, R] bool
     # heads the vectorized math can't classify: the scheduler runs the
     # host FlavorAssigner walk for these and attaches the assignment
     scalar_mask: np.ndarray = None         # [W] bool
@@ -190,6 +198,8 @@ class CycleSolver:
             "scalar_reasons": {},     # {reason: count} for scalar heads
             "resume_heads": 0,        # heads entering the walk mid-list
             "walk_stop_heads": 0,     # heads whose walk policy-stopped
+            "walk_heads": 0,          # heads classified by the vector walk
+            "walk_slots": 0,          # flavors their walks visited
             "native_ff_fallbacks": 0,  # native classify skipped: the C++
                                        # core is first-fit-only and the
                                        # cycle has non-default fungibility
@@ -480,8 +490,10 @@ class CycleSolver:
                                               preempt=pre))
 
         # batched preemption search: compile the (S, K) rungs a run of
-        # this size can hit (S <= 2 specs per head; K rungs beyond 128
-        # are rare enough to compile on first use)
+        # this size can hit (a launch holds <= 2 specs per head, or with
+        # several flavor slots the reclaim oracle's <= 2 a head, slot
+        # and resource; K rungs beyond 128 are rare enough to compile
+        # on first use)
         from .preemption_kernel import minimal_preemptions_batch
         from .preemption_solver import _ForestPlanes, K_LADDER, S_LADDER
         try:
@@ -491,7 +503,8 @@ class CycleSolver:
         if planes is not None:
             st._preempt_planes = planes
             NL = planes.NL
-            s_top = coarse_bucket(2 * max_heads, S_LADDER)
+            specs = 2 * max_heads * (S * R if S > 1 else 1)
+            s_top = coarse_bucket(min(specs, S_LADDER[-1]), S_LADDER)
             for S in [s for s in S_LADDER if s <= s_top]:
                 for K in K_LADDER[:2]:
                     jax.device_get(minimal_preemptions_batch(
@@ -690,6 +703,11 @@ class CycleSolver:
                 "preempt_res_fit": np.ones((W, R), bool),
                 "preempt_slot_count": np.zeros(W, np.int32),
                 "preempt_stopped0": np.zeros(W, bool),
+                # first fit: the walk stops on its fit slot, or visits
+                # the whole list
+                "walk_slots": np.where(
+                    np.asarray(fit_slot0) >= 0, np.asarray(fit_slot0) + 1,
+                    st.slot_count_cq[np.maximum(packed.wl_cq, 0)]),
             }
             if out["preempt0"][:n].any():
                 # the C++ core covers fit/borrow/preempt-possible; the
@@ -697,7 +715,8 @@ class CycleSolver:
                 det = classify_np(packed, potential0=self._potential0)
                 for k in ("preempt_slot0", "preempt_borrows0",
                           "preempt_res_fit", "preempt_slot_count",
-                          "preempt_stopped0"):
+                          "preempt_stopped0", "preempt_slots",
+                          "slot_res_fit", "slot_borrows", "oracle_ask"):
                     out[k] = det[k]
         else:
             out = classify_np(packed, potential0=self._potential0,
@@ -729,6 +748,8 @@ class CycleSolver:
             sm = np.zeros(W, dtype=bool)
         self.stats["walk_stop_heads"] += int(
             np.count_nonzero(out["preempt_stopped0"][:n]))
+        self.stats["walk_heads"] += int(n - scalar.sum())
+        self.stats["walk_slots"] += int(out["walk_slots"][:n][~scalar].sum())
         return ClassifiedCycle(
             packed=packed, heads=heads, snapshot=snapshot,
             fit_slot0=out["fit_slot0"], borrows0=out["borrows0"],
@@ -737,7 +758,44 @@ class CycleSolver:
             preempt_res_fit=out["preempt_res_fit"],
             preempt_slot_count=out["preempt_slot_count"],
             preempt_stopped0=out["preempt_stopped0"],
+            preempt_slots=out.get("preempt_slots"),
+            slot_res_fit=out.get("slot_res_fit"),
+            slot_borrows=out.get("slot_borrows"),
+            oracle_ask=out.get("oracle_ask"),
             scalar_mask=sm, host_assignments={}, host_pairs={})
+
+    # -- the reclaim oracle's part of the walk --------------------------
+
+    def oracle_queries(self, cls: ClassifiedCycle, wi: int) -> list[tuple]:
+        """What the host walk would ask the reclaim oracle for head
+        ``wi``: one (slot, resource index, FlavorResource, quantity) a
+        resource short of quota on each attempted preempt-capable slot
+        (flavorassigner.go:692)."""
+        st = cls.packed.structure
+        h = cls.heads[wi]
+        cq = cls.snapshot.cq(h.cluster_queue)
+        flavors = cq.spec.resource_groups[0].flavors
+        psr = h.total_requests[0]
+        out = []
+        for s, ri in zip(*np.nonzero(cls.oracle_ask[wi])):
+            res = st.resource_names[ri]
+            qty = psr.count if res == "pods" else psr.requests[res]
+            out.append((int(s), int(ri),
+                        FlavorResource(flavors[s].name, res), qty))
+        return out
+
+    def pick_preempt_slots(self, cls: ClassifiedCycle, heads: np.ndarray,
+                           reclaim: np.ndarray) -> None:
+        """Fix the preempt slot of ``heads`` (walks that met several
+        preempt-capable slots and no stop) from the oracle's answers
+        ``reclaim`` [len(heads), S, R]: the first slot of the best
+        granular mode, and with it the slot's borrow and per-resource
+        facts that the target search and the admit scan read."""
+        slot = pick_preempt_slot_np(cls.preempt_slots[heads],
+                                    cls.slot_res_fit[heads], reclaim)
+        cls.preempt_slot0[heads] = slot
+        cls.preempt_borrows0[heads] = cls.slot_borrows[heads, slot]
+        cls.preempt_res_fit[heads] = cls.slot_res_fit[heads, slot]
 
     # -- scalar-head decisions -----------------------------------------
 

@@ -436,7 +436,8 @@ def _materialize(st, state, s, views, scheduler, dirty_cis, prev_token,
                                            M, KC)
         s.cand_tables[(M, KC)] = tables
     cand_rows, cand_lmem, self_lmem = tables
-    arrays = {name: views[name].copy()
+    arena = state.arena
+    arrays = {name: arena.snapshot(name, views[name])
               for name in _ROW_PLANES}
     arrays["u_cq0"] = views["u_cq0"].copy()
     arrays.update(
@@ -457,7 +458,7 @@ def _materialize(st, state, s, views, scheduler, dirty_cis, prev_token,
         self_lmem=self_lmem)
     plan = _b.BurstPlan(
         structure=st, arrays=arrays,
-        keys=_KeysView(views["keys_grid"].copy()),
+        keys=_KeysView(arena.snapshot("keys_grid", views["keys_grid"])),
         C=C, M=M, L=L, G=G, n_levels=s.n_levels, KC=KC,
         seq_base=seq_base, row_of_key=state.row_of_key,
         max_res_ts=max_res_ts,

@@ -133,17 +133,22 @@ class Preemptor:
                       # for a cycle's heads, or one launch a search
                       "search_batch_launches": 0,
                       "search_single_launches": 0,
-                      # a whole batch the batched route turned away, and
-                      # why (the sum of the two reasons): more specs than
+                      # a launch the batched route turned away, and why
+                      # (the sum of the two reasons): more specs than
                       # the S ladder's top rung, or a spec the planes
-                      # cannot hold.  Size of one spec never refuses a
-                      # batch: a spec with more candidates than the K
+                      # cannot hold.  Size never refuses a cycle: its
+                      # specs go out in launches of at most that rung,
+                      # and a spec with more candidates than the K
                       # ladder's top rung is searched alone, counted in
                       # search_alone_over_k, and the rest stay batched
                       "search_batch_refusals": 0,
                       "search_refused_over_s": 0,
                       "search_refused_unpackable": 0,
                       "search_alone_over_k": 0,
+                      # the reclaim oracle on the batched route: the
+                      # searches it asked for, and those answered Reclaim
+                      "oracle_specs": 0,
+                      "oracle_reclaims": 0,
                       # real candidates in the batched launches, and the
                       # S x K slots of the buckets they were padded to
                       "search_candidate_slots": 0,
@@ -229,38 +234,64 @@ class Preemptor:
 
     def get_targets_batch(self, requests: list[tuple[Info, Assignment]],
                           snapshot: Snapshot) -> list[list[Target]]:
-        """Target searches for ALL of a cycle's preempt heads in one
-        batched device dispatch (ops/preemption_kernel
-        minimal_preemptions_batch) — candidate discovery and ordering
-        stay host-side, the greedy+fillback searches vmap.  A search
-        with more candidates than the batch's K ladder holds is
-        launched alone, over the candidates found and sorted here, and
-        the others stay in the batch.  Falls back to per-head
-        get_targets for fair sharing, a missing cycle pack, or a batch
-        that is refused: too many specs, or an unpackable one
-        (decision-identical either way)."""
+        """Target searches for ALL of a cycle's preempt heads in the
+        batched device dispatch (``_search_batch``)."""
+        return self._search_batch([_PreemptionCtx(
+            preemptor=wl,
+            preemptor_cq=snapshot.cq(wl.cluster_queue),
+            snapshot=snapshot,
+            frs_need_preemption=flavor_resources_need_preemption(
+                assignment),
+            workload_usage=assignment.total_requests_for(wl))
+            for wl, assignment in requests], snapshot)
+
+    def reclaim_possible_batch(self, queries: list[tuple],
+                               snapshot: Snapshot) -> list[bool]:
+        """The reclaim oracle (preemption_oracle.go:40) for a cycle's
+        questions at once: ``queries`` = [(head Info, FlavorResource,
+        quantity)], each a target search of its own for that one
+        flavor-resource, all through the batched device dispatch.
+        Reclaim is possible when the search evicts nobody of the head's
+        own queue."""
+        with _span("cycle.nominate.oracle"):
+            found = self._search_batch(
+                [_oracle_ctx(snapshot.cq(wl.cluster_queue), wl, fr, qty,
+                             snapshot) for wl, fr, qty in queries],
+                snapshot)
+            answers = [
+                all(t.info.cluster_queue != wl.cluster_queue
+                    for t in targets)
+                for (wl, _, _), targets in zip(queries, found)]
+        self.stats["oracle_specs"] += len(queries)
+        self.stats["oracle_reclaims"] += sum(answers)
+        return answers
+
+    def _search_batch(self, ctxs: list[_PreemptionCtx],
+                      snapshot: Snapshot) -> list[list[Target]]:
+        """One target search a context, all in batched device dispatches
+        (ops/preemption_kernel minimal_preemptions_batch) — candidate
+        discovery and ordering stay host-side, the greedy+fillback
+        searches vmap.  The planned specs go out in launches of at most
+        ``S_LADDER``'s top rung each.  A search with more candidates
+        than the batch's K ladder holds is launched alone, over the
+        candidates found and sorted here, and the others stay batched.
+        Falls back to a search a context for fair sharing, a missing
+        cycle pack, or an unpackable spec (decision-identical either
+        way)."""
         packed = self._pack_for(snapshot)
         def each_head():
-            """One search (and one candidate discovery) a head."""
+            """One search (and one candidate discovery) a context."""
             with _span("cycle.nominate.search_fallback"):
-                return [self.get_targets(wl, a, snapshot)
-                        for wl, a in requests]
+                return [self._get_targets(ctx) for ctx in ctxs]
 
         if (self.enable_fair_sharing or packed is None
-                or self.device_search is False or not requests):
+                or self.device_search is False or not ctxs):
             return each_head()
 
         flat_specs: list[tuple] = []
         plans: list[tuple[list[int], bool]] = []
         with _span("cycle.nominate.candidates"):
-            for wl, assignment in requests:
-                ctx = _PreemptionCtx(
-                    preemptor=wl,
-                    preemptor_cq=snapshot.cq(wl.cluster_queue),
-                    snapshot=snapshot,
-                    frs_need_preemption=flavor_resources_need_preemption(
-                        assignment),
-                    workload_usage=assignment.total_requests_for(wl))
+            for ctx in ctxs:
                 candidates = self._find_candidates(ctx)
                 if not candidates:
                     plans.append(([], False))
@@ -275,21 +306,24 @@ class Preemptor:
                 plans.append((idxs, staged))
 
         # the launch plan follows each spec's size: those the K ladder
-        # holds share the one launch, each of the others gets its own
+        # holds share launches of up to the S ladder's top rung, each of
+        # the others gets its own
         from ..ops import preemption_solver
         top = preemption_solver.K_LADDER[-1]
+        per_launch = preemption_solver.S_LADDER[-1]
         results: list[Optional[list[Target]]] = [None] * len(flat_specs)
         batch = [i for i, spec in enumerate(flat_specs)
                  if len(spec[1]) <= top]
-        if batch:
+        for at in range(0, len(batch), per_launch):
+            launch = batch[at:at + per_launch]
             found = preemption_solver.device_minimal_preemptions_batch(
-                [flat_specs[i] for i in batch], packed, stats=self.stats)
+                [flat_specs[i] for i in launch], packed, stats=self.stats)
             if found is None:
-                # refused (stats say why): one launch a head, each
+                # refused (stats say why): one launch a context, each
                 # finding and sorting its candidates again
                 return each_head()
-            self.stats["device_searches"] += len(batch)
-            for i, targets in zip(batch, found):
+            self.stats["device_searches"] += len(launch)
+            for i, targets in zip(launch, found):
                 results[i] = targets
         if len(batch) < len(flat_specs):
             with _span("cycle.nominate.search_fallback"):
@@ -547,6 +581,16 @@ class Preemptor:
         return count
 
 
+def _oracle_ctx(cq: CQState, wl: Info, fr: FlavorResource, quantity: int,
+                snapshot: Snapshot) -> _PreemptionCtx:
+    """The oracle's search (preemption_oracle.go:40): the head asks for
+    ``quantity`` of the one flavor-resource and nothing else."""
+    return _PreemptionCtx(
+        preemptor=wl, preemptor_cq=cq, snapshot=snapshot,
+        frs_need_preemption={fr},
+        workload_usage=FlavorResourceQuantities({fr: quantity}))
+
+
 class PreemptionOracle:
     """reference preemption_oracle.go:40."""
 
@@ -558,13 +602,8 @@ class PreemptionOracle:
                             fr: FlavorResource, quantity: int) -> bool:
         if cq.borrowing_with(fr, quantity):
             return False
-        ctx = _PreemptionCtx(
-            preemptor=wl,
-            preemptor_cq=self.snapshot.cq(wl.cluster_queue) or cq,
-            snapshot=self.snapshot,
-            frs_need_preemption={fr},
-            workload_usage=FlavorResourceQuantities({fr: quantity}),
-        )
+        ctx = _oracle_ctx(self.snapshot.cq(wl.cluster_queue) or cq, wl, fr,
+                          quantity, self.snapshot)
         for target in self.preemptor._get_targets(ctx):
             if target.info.cluster_queue == cq.name:
                 return False

@@ -450,17 +450,13 @@ class Scheduler:
 
         def scalar_walk(wi: int) -> bool:
             """Host FlavorAssigner walk for one head (nominate-time,
-            snapshot state) — multi-RG/multi-PodSet/taints/fungibility/
-            resume-state/partial-admission/TAS heads stay inside the
-            device-decided cycle this way."""
+            snapshot state) — multi-RG/multi-PodSet/taints/
+            partial-admission/TAS heads stay inside the device-decided
+            cycle this way."""
             e = deferred[wi]
             e.inadmissible_msg = ""
             self._assign_entry(e, snapshot)
             walked.add(wi)
-            if not cls.scalar_mask[wi]:
-                # promoted post-classify (multi-preempt-slot head)
-                cls.scalar_mask[wi] = True
-                solver.stats["scalar_heads"] += 1
             a = e.assignment
             mode = a.representative_mode()
             if mode == Mode.NO_FIT:
@@ -481,22 +477,22 @@ class Scheduler:
                 if not scalar_walk(int(wi)):
                     full_ok = False
                     break
-            if full_ok:
-                for wi in np.nonzero(cls.preempt0[:n])[0]:
-                    wi = int(wi)
-                    # A policy-stopped preempt choice is final; otherwise
-                    # with several preempt-capable slots the host walk's
-                    # best-mode pick depends on the reclaim oracle
-                    # (flavorassigner.go:692 RECLAIM beats PREEMPT) — run
-                    # the real walk for this head.
-                    if not (cls.preempt_stopped0[wi]
-                            or cls.preempt_slot_count[wi] == 1):
-                        if not scalar_walk(wi):
-                            full_ok = False
-                            break
-                        continue
-                    batch_reqs.append(
-                        (wi, solver.build_preempt_assignment(cls, wi)))
+        if full_ok:
+            pre = np.nonzero(cls.preempt0[:n])[0]
+            # A policy-stopped preempt choice is final, and so is the
+            # only preempt-capable slot; with several, the walk's
+            # best-mode pick is the reclaim oracle's
+            # (flavorassigner.go:692 RECLAIM beats PREEMPT)
+            self._pick_by_oracle(cls, pre[
+                ~cls.preempt_stopped0[pre]
+                & (cls.preempt_slot_count[pre] > 1)], snapshot)
+            # in the walk's span, where the loop stood before the oracle
+            # came between: the span's total by name is read, and
+            # ``cycle.nominate.self`` is what no child covers
+            with _span("cycle.nominate.walk"):
+                batch_reqs = [
+                    (int(wi), solver.build_preempt_assignment(cls, int(wi)))
+                    for wi in pre]
 
         if full_ok and batch_reqs:
             # all preempt heads' target searches in ONE batched
@@ -530,6 +526,29 @@ class Scheduler:
         solver.stats["full_cycles"] += 1
         return (deferred, cls, handle, assignments_by_wi, targets_by_wi,
                 walked)
+
+    def _pick_by_oracle(self, cls, heads, snapshot: Snapshot) -> None:
+        """The preempt slot of the heads whose walk met several
+        preempt-capable flavors and no stop: every question the host
+        walk would put to the reclaim oracle, answered in the cycle's
+        batched search (one launch ahead of the heads' own), and the
+        Reclaim / Preempt lattice applied to the answers."""
+        if not len(heads):
+            return
+        import numpy as np
+        solver = self.solver
+        reclaim = np.zeros((len(heads),) + cls.oracle_ask.shape[1:],
+                           dtype=bool)
+        queries, at = [], []
+        for hi, wi in enumerate(heads):
+            for s, ri, fr, qty in solver.oracle_queries(cls, int(wi)):
+                queries.append((cls.heads[wi], fr, qty))
+                at.append((hi, s, ri))
+        if queries:
+            answers = self.preemptor.reclaim_possible_batch(queries,
+                                                            snapshot)
+            reclaim[tuple(np.array(at).T)] = answers
+        solver.pick_preempt_slots(cls, heads, reclaim)
 
     def _assign_classified(self, deferred: list[Entry], cls, snapshot,
                            walked: set[int]) -> None:

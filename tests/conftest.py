@@ -59,3 +59,19 @@ class FakeClock:
     def tick(self, dt=1.0):
         self.t += dt
         return self.t
+
+
+@pytest.fixture
+def no_oracle_specs(monkeypatch):
+    """For suites whose queues give a head one preempt-capable flavor at
+    most: the walk has nothing to set against anything, so the batched
+    reclaim oracle is never asked (``oracle_specs`` stays 0)."""
+    from kueue_tpu.scheduler.preemption import Preemptor
+    asked = []
+    ask = Preemptor.reclaim_possible_batch
+    monkeypatch.setattr(
+        Preemptor, "reclaim_possible_batch",
+        lambda self, queries, snapshot: asked.append(len(queries))
+        or ask(self, queries, snapshot))
+    yield
+    assert not asked, f"oracle specs planned: {asked}"

@@ -28,6 +28,9 @@ from kueue_tpu.api.types import (
 )
 from kueue_tpu.controller.driver import Driver
 
+# one flavor a head can preempt in: the reclaim oracle is never asked
+pytestmark = pytest.mark.usefixtures("no_oracle_specs")
+
 
 class Clock:
     def __init__(self, t=1000.0):

@@ -42,6 +42,9 @@ from test_burst import (
     _quota,
 )
 
+# one flavor a head can preempt in: the reclaim oracle is never asked
+pytestmark = pytest.mark.usefixtures("no_oracle_specs")
+
 PRE_ANY = PreemptionPolicy(
     reclaim_within_cohort=ReclaimWithinCohort.ANY,
     within_cluster_queue=WithinClusterQueue.LOWER_PRIORITY)

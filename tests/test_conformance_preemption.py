@@ -32,6 +32,9 @@ from kueue_tpu.controller.driver import Driver
 from kueue_tpu.workload import set_quota_reservation, sync_admitted_condition
 from tests.conftest import FakeClock
 
+# one flavor a head can preempt in: the reclaim oracle is never asked
+pytestmark = pytest.mark.usefixtures("no_oracle_specs")
+
 
 K = 1000          # "1" cpu = 1000 milli
 GI = 1024         # "1Gi" memory = 1024 units

@@ -242,18 +242,48 @@ def test_spec_over_the_top_rung_is_searched_alone_and_the_rest_batched(
     assert s_cycle.preempting == b_cycle.preempting
 
 
+def test_more_specs_than_the_top_rung_go_out_in_launches(monkeypatch):
+    """The count of a cycle's specs never sends it to a launch a head:
+    over ``S_LADDER``'s top rung they go out in launches of at most that
+    many, with the targets of the one-launch run."""
+    from kueue_tpu.ops import preemption_solver
+    from tests.test_burst import preempting_cluster, run_host
+
+    d, clock = preempting_cluster()
+    (whole,) = run_host(d, clock, 1, 0)
+    assert d.scheduler.preemptor.stats["search_batch_launches"] == 1
+
+    d, clock = preempting_cluster()
+    monkeypatch.setattr(preemption_solver, "S_LADDER", (2,))
+    (split,) = run_host(d, clock, 1, 0)
+    stats = d.scheduler.preemptor.stats
+    assert stats["search_batch_launches"] == 2          # 3 specs: 2 + 1
+    assert stats["search_single_launches"] == 0
+    assert stats["search_batch_refusals"] == 0
+    assert stats["device_searches"] == 3 and stats["host_searches"] == 0
+    assert stats["search_padded_slots"] == 2 * (2 * 16)
+    assert len(split.preempted_targets) == 9
+    assert split.preempted_targets == whole.preempted_targets
+    assert split.preempting == whole.preempting
+
+
 def test_batch_refusal_reasons_sum_to_the_refusals(monkeypatch):
     """Every ``return None`` of the batched search lands in exactly one
-    reason: too many specs, and a plane that cannot hold a spec.  A
-    spec searched alone for its size is no refusal: the whole-batch
+    reason: a launch of more specs than the S ladder's top rung (the
+    preemptor never asks for one), and a plane that cannot hold a spec.
+    A spec searched alone for its size is no refusal: the whole-batch
     count and ``search_alone_over_k`` move apart."""
     from kueue_tpu.ops import preemption_solver
     from tests.test_burst import preempting_cluster, run_host
 
     d, clock = preempting_cluster()
     monkeypatch.setattr(preemption_solver, "S_LADDER", (2,))
-    run_host(d, clock, 1, 0)
-    stats = d.scheduler.preemptor.stats
+    stats = dict(d.scheduler.preemptor.stats)
+    assert preemption_solver.device_minimal_preemptions_batch(
+        [(None, [], True, None)] * 3,
+        d.scheduler.solver.classify(d.cache.snapshot(),
+                                    d.queues.heads_nonblocking()).packed,
+        stats=stats) is None
     assert stats["search_refused_over_s"] == 1
 
     d2, clock2 = preempting_cluster()
@@ -262,10 +292,10 @@ def test_batch_refusal_reasons_sum_to_the_refusals(monkeypatch):
     run_host(d2, clock2, 1, 0)
     stats2 = d2.scheduler.preemptor.stats
     assert stats2["search_refused_unpackable"] == 1
+    assert stats2["search_single_launches"] == 3
     for s in (stats, stats2):
         assert s["search_batch_refusals"] == 1 == (
             s["search_refused_over_s"] + s["search_refused_unpackable"])
-        assert s["search_single_launches"] == 3
         assert s["search_alone_over_k"] == 0
 
     # one head over the K rung beside a refused batch: the refusal is

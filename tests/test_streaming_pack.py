@@ -64,6 +64,119 @@ def _chaos_off():
 
 
 # ---------------------------------------------------------------------------
+# A plan's planes: the arena's snapshot, written over once released
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("holder", ["released", "plan", "view", "other_shape"])
+def test_arena_snapshot_is_written_over_only_when_nobody_holds_it(holder):
+    """The buffer of the last snapshot takes the next one when the arena
+    has the only reference to it; a holder of the array or of any view
+    of it keeps its bytes, and the next snapshot is a fresh array."""
+    from kueue_tpu.cache.arena import PlaneArena
+    arena = PlaneArena()
+    live = arena.ensure("p", (3, 5), np.int32, 0)
+    live[...] = np.arange(15, dtype=np.int32).reshape(3, 5)
+    first = arena.snapshot("p", live)
+    assert first.flags.c_contiguous and first.base is None
+    assert np.array_equal(first, live)
+    where = first.ctypes.data
+    kept = first.copy()
+    held = {"released": None, "plan": first, "view": first[1, 2:],
+            "other_shape": None}[holder]
+    del first
+    if holder == "other_shape":
+        live = arena.ensure("p", (4, 5), np.int32, 0)
+    live[...] = 7
+    second = arena.snapshot("p", live)
+    assert np.array_equal(second, live)
+    if holder == "released":
+        assert second.ctypes.data == where
+        assert arena.stats["arena_snapshots_reused"] == 1
+    else:
+        assert arena.stats["arena_snapshots_reused"] == 0
+        assert arena.stats["arena_snapshots_fresh"] == 2
+    if holder == "plan":
+        assert np.array_equal(held, kept)
+    if holder == "view":
+        assert np.array_equal(held, kept[1, 2:])
+
+
+def test_a_plan_still_held_keeps_its_planes_across_the_next_pack():
+    """Two plans of one pack state held side by side do not share
+    memory; released, the next pack takes the newest's buffers, and its
+    plan equals a from-scratch pack."""
+    from kueue_tpu.ops.burst import pack_burst, pack_burst_cached
+    from test_delta_pack import assert_plans_equal, current_structure
+    d, clock = build_cluster()
+    for i in range(6):
+        d.create_workload(mk(f"w{i}", f"lq-{i % 2}-{i % 2}", 1500, t=float(i)))
+    stats = {}
+
+    def pack(state):
+        st = current_structure(d)
+        plan, state, _ = pack_burst_cached(
+            st, d.queues, d.cache, d.scheduler, d.clock,
+            state=state, window=0, stats=stats)
+        return plan, state
+
+    one, state = pack(None)
+    kept = {k: np.array(v, copy=True) for k, v in one.arrays.items()}
+    kept_keys = one.keys.tolist()
+    clock.t += 1.0
+    d.schedule_once()
+    d.create_workload(mk("late", "lq-0-0", 1000, t=9.0))
+    two, state = pack(state)
+    assert stats["pack_arena_snapshots_reused"] == 0
+    from kueue_tpu.ops.stream_pack import _ROW_PLANES
+    for name, was in kept.items():
+        assert np.array_equal(one.arrays[name], was), name
+    for name in _ROW_PLANES:
+        assert not np.shares_memory(one.arrays[name], two.arrays[name]), name
+    assert one.keys.tolist() == kept_keys
+    assert any(not np.array_equal(one.arrays[n], two.arrays[n])
+               for n in ("adm0", "elig0"))
+    fresh = stats["pack_arena_snapshots_fresh"]
+    del one, two
+    d.create_workload(mk("later", "lq-1-1", 1000, t=10.0))
+    three, state = pack(state)
+    assert stats["pack_arena_snapshots_fresh"] == fresh
+    assert stats["pack_arena_snapshots_reused"] > 0
+    st = current_structure(d)
+    assert_plans_equal(three, pack_burst(st, d.queues, d.cache, d.scheduler,
+                                         d.clock, window=0), "third")
+
+
+@pytest.mark.parametrize("calls", [2, 3])
+def test_schedule_burst_packs_every_window_after_the_first_in_place(calls):
+    """Driver.schedule_burst lets go of a window before it packs the
+    next, within a call and from one call to the next, so later packs
+    write over the planes the first one mapped."""
+    d, clock = build_cluster(preempt=True)
+    for c in range(2):
+        for q in range(2):
+            for i in range(8):
+                d.create_workload(mk(
+                    f"w-{c}-{q}-{i}", f"lq-{c}-{q}", 1500,
+                    prio=(i % 3) * 10, t=float(10 * c + 3 * q + i)))
+    for _ in range(calls):
+        d.schedule_burst(
+            6, runtime=0,
+            on_cycle_start=lambda k: setattr(clock, "t", clock.t + 1.0))
+        for key in sorted(d.admitted_keys())[:2]:
+            d.finish_workload(key)
+    bs = d._burst_solver.stats
+    windows = bs["burst_serial_windows"]
+    assert windows >= calls
+    # fourteen row planes and the keys' grid a window; the runtime lets
+    # go of a launch's host arrays at its next call, so a pack that
+    # follows a fetch with no launch between may find some still held
+    # and map those afresh
+    reused = bs["pack_arena_snapshots_reused"]
+    assert reused + bs["pack_arena_snapshots_fresh"] == windows * 15
+    assert reused > 0
+
+
+# ---------------------------------------------------------------------------
 # Streaming parity: row-grade admission-check flips
 # ---------------------------------------------------------------------------
 
@@ -373,6 +486,52 @@ def test_schedule_burst_decisions_identical_tighten_on_off(monkeypatch):
     # tightening must actually shrink the serial-launch transfer
     assert (runs["1"][2]["burst_launch_bytes_h2d"]
             < runs["0"][2]["burst_launch_bytes_h2d"])
+
+
+@pytest.mark.parametrize("batch_bytes,batches_a_launch", [
+    (1 << 40, 1),     # every plane in one batch
+    (256, None),      # the toy's planes are 4 B to 128 B: a few a batch
+    (1, 15),          # a plane over the limit goes up alone: six row
+                      # planes and the nine of the scan state
+])
+def test_a_launch_stages_its_host_planes_in_bounded_batches(
+        monkeypatch, batch_bytes, batches_a_launch):
+    """The serial launch sends its large host planes ahead of the call
+    in batches under ``H2D_BATCH_BYTES``; the decisions are those of a
+    launch that hands the call its host arrays (planes under
+    ``H2D_STAGE_MIN_BYTES``, as every test's are)."""
+    from kueue_tpu.ops import burst as _b
+    runs = {}
+    for staged in (False, True):
+        if staged:
+            monkeypatch.setattr(_b, "H2D_STAGE_MIN_BYTES", 0)
+            monkeypatch.setattr(_b, "H2D_BATCH_BYTES", batch_bytes)
+        d, clock = build_cluster(preempt=True)
+        for c in range(2):
+            for q in range(2):
+                for i in range(5):
+                    d.create_workload(mk(
+                        f"w-{c}-{q}-{i}", f"lq-{c}-{q}", 1500,
+                        prio=(i % 3) * 10, t=float(10 * c + 3 * q + i)))
+        stats = d.schedule_burst(
+            10, runtime=2,
+            on_cycle_start=lambda k: setattr(clock, "t", clock.t + 1.0))
+        runs[staged] = (
+            [(sorted(s.admitted), sorted(s.skipped),
+              sorted(s.preempted_targets)) for s in stats],
+            d.admitted_keys(), dict(d._burst_solver.stats))
+    assert runs[True][0] == runs[False][0]
+    assert runs[True][1] == runs[False][1]
+    assert runs[False][2]["burst_h2d_batches"] == 0
+    bs = runs[True][2]
+    assert bs["burst_serial_windows"] >= 1
+    a_launch = bs["burst_h2d_batches"] / bs["burst_serial_windows"]
+    if batches_a_launch is None:
+        assert 1 < a_launch < 15
+    else:
+        assert a_launch == batches_a_launch
+    assert bs["burst_launch_bytes_h2d"] == \
+        runs[False][2]["burst_launch_bytes_h2d"]
 
 
 # ---------------------------------------------------------------------------
