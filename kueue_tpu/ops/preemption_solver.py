@@ -20,12 +20,17 @@ from ..api.types import (
 )
 from ..obs.trace import span as _span
 from .device import on_accelerator, output_devices
-from .packing import pack_cycle
+from .packing import coarse_bucket, pack_cycle
 from .preemption_kernel import minimal_preemptions
 
 # shape ladders for the batched search (see coarse_bucket)
 S_LADDER = (32, 256, 1024, 4096)
 K_LADDER = (16, 128, 1024)
+# The floor of one scan step of the batched search, in rows: a step over
+# S rows takes about as long as STEP_FLOOR_ROWS + S rows' worth, so a
+# launch costs about K * (STEP_FLOOR_ROWS + S) (plan_launches).  Read
+# off the chip; PERF.md §5 has the twelve (S, K) timings it rests on.
+STEP_FLOOR_ROWS = 50
 
 
 def _bucket(n: int, minimum: int = 8) -> int:
@@ -102,46 +107,112 @@ def _refused(stats: Optional[dict], reason: str) -> None:
     return None
 
 
-def device_minimal_preemptions_batch(specs, packed,
-                                     stats: Optional[dict] = None):
-    """ALL of a cycle's preemption searches in one vmapped dispatch,
-    each over its preemptor's forest-local quota plane.
+def plan_launches(counts: list[int]) -> list[tuple[int, list[int]]]:
+    """The launches of one cycle's batched searches, from each spec's
+    candidate count (every one of 1 to ``K_LADDER``'s top rung):
+    [(K rung, positions in ``counts``)], the longest scan first.
 
-    ``specs``: [(ctx, candidates, allow_borrowing, threshold)] — the
-    per-head search requests the preemptor planned (every search is
-    against the same nominate-time snapshot, so they are independent).
-    No spec has more candidates than ``K_LADDER``'s top rung: the
-    preemptor launches such a search alone (``search_alone_over_k``)
-    and batches the rest.  Returns a list of per-spec Target lists
-    ([] = search failed), or None when the batch is refused: a spec
-    can't be packed, or there are more specs than ``S_LADDER``'s top
-    rung (the caller runs a launch a head; ``stats`` counts the refusal
-    under its one reason).
+    The kernel is two scans of K steps over S rows, so a launch costs
+    its own largest search's K whatever the others hold.  Specs are
+    grouped by the K rung of their own count and neighbouring rungs are
+    merged where that is cheaper: of the ways to cut the occupied rungs
+    into runs of neighbours, the one with the least
+    ``sum(K * (STEP_FLOOR_ROWS + S))`` over its launches wins, the
+    fewest launches on a tie.  A group goes out at its highest rung in
+    launches of at most ``S_LADDER``'s top rung.  Counts that share a
+    rung plan the one launch there was before the plan looked at
+    sizes."""
+    by_rung: dict[int, list[int]] = {}
+    for i, n in enumerate(counts):
+        by_rung.setdefault(coarse_bucket(n, K_LADDER), []).append(i)
+    rungs = sorted(by_rung)
+    if not rungs:
+        return []
+    top = S_LADDER[-1]
+
+    def cost(group: list[int]) -> int:
+        full, rest = divmod(sum(len(by_rung[r]) for r in group), top)
+        rows = full * (STEP_FLOOR_ROWS + top)
+        if rest:
+            rows += STEP_FLOOR_ROWS + coarse_bucket(rest, S_LADDER)
+        return group[-1] * rows
+
+    best = None
+    joints = len(rungs) - 1
+    # bit j of ``cuts`` set: rungs j and j + 1 go out apart
+    for cuts in sorted(range(1 << joints), key=int.bit_count):
+        groups, start = [], 0
+        for j in range(len(rungs)):
+            if j == joints or cuts >> j & 1:
+                groups.append(rungs[start:j + 1])
+                start = j + 1
+        total = sum(cost(g) for g in groups)
+        if best is None or total < best[0]:
+            best = (total, groups)
+    launches = []
+    for group in reversed(best[1]):
+        members = [i for r in group for i in by_rung[r]]
+        launches += [(group[-1], members[at:at + top])
+                     for at in range(0, len(members), top)]
+    return launches
+
+
+def device_minimal_preemptions_batch(launches, packed,
+                                     stats: Optional[dict] = None):
+    """A cycle's preemption searches in the vmapped dispatches the
+    preemptor planned (``plan_launches``), every one dispatched before
+    the first result is fetched: launch n + 1 is packed while launch n
+    is on the device, and the host blocks once for the lot.
+
+    ``launches``: [[(ctx, candidates, allow_borrowing, threshold)]] —
+    a launch holds the specs that share a scan length: it is padded to
+    the S rung of its own count and the K rung of its own longest
+    candidate list, not the cycle's.  Every search is against the same
+    nominate-time snapshot, so they are independent, in a launch and
+    across launches.  No spec is empty (the preemptor answers ``[]``
+    itself) and none has more candidates than ``K_LADDER``'s top rung
+    (it launches such a search alone, ``search_alone_over_k``).
+    Returns a list a launch of per-spec Target lists ([] = search
+    failed), or None when any launch is refused: a spec can't be
+    packed, or a launch has more specs than ``S_LADDER``'s top rung
+    (the caller runs a launch a head; ``stats`` counts the refusal
+    under its one reason; what was dispatched is dropped unread).
     ``stats["accel_searches"]`` counts the searches whose output landed
     on an accelerator, ``search_batch_launches`` the launches, and
     ``search_candidate_slots`` / ``search_padded_slots`` the real
-    candidates against the S x K slots of the bucket launched."""
-    with _span("cycle.nominate.search_pack"):
-        args = _pack_batch(specs, packed, stats)
-    if args is None:
-        return None
-    with _span("cycle.nominate.search_launch"):
-        from .preemption_kernel import minimal_preemptions_batch
-        fitted, mask = minimal_preemptions_batch(*args, depth=packed.depth)
-        if stats is not None:
-            stats["search_batch_launches"] += 1
-            if on_accelerator(output_devices(fitted)):
-                stats["accel_searches"] += len(specs)
-        fitted = np.asarray(fitted)
-        mask = np.asarray(mask)
-    with _span("cycle.nominate.search_decode"):
-        return _decode_batch(specs, fitted, mask)
+    candidates against the S x K slots of the buckets launched."""
+    from .preemption_kernel import minimal_preemptions_batch
+    flying = []
+    for specs in launches:
+        with _span("cycle.nominate.search_pack"):
+            args = _pack_batch(specs, packed, stats)
+        if args is None:
+            return None
+        with _span("cycle.nominate.search_launch"):
+            fitted, mask = minimal_preemptions_batch(*args,
+                                                     depth=packed.depth)
+            if stats is not None:
+                stats["search_batch_launches"] += 1
+                if on_accelerator(output_devices(fitted)):
+                    stats["accel_searches"] += len(specs)
+            flying.append((fitted, mask))
+    out = []
+    for specs, (fitted, mask) in zip(launches, flying):
+        with _span("cycle.nominate.search_launch"):
+            fitted = np.asarray(fitted)
+            mask = np.asarray(mask)
+        with _span("cycle.nominate.search_decode"):
+            out.append(_decode_batch(specs, fitted, mask))
+    return out
 
 
 def _pack_batch(specs, packed, stats: Optional[dict]):
     """The numpy planes of one batched launch (the kernel's positional
     arguments, their real and padded candidate slots counted), or None
-    with the refusal counted."""
+    with the refusal counted.  It packs what it is given: S and K are
+    the rungs of this launch's own spec count and longest candidate
+    list, so the planes are as large as the plan's grouping made
+    them."""
     if packed is None or not packed.exact or not specs:
         return _refused(stats, "unpackable")
     planes = _planes_for(packed)
@@ -170,8 +241,7 @@ def _pack_batch(specs, packed, stats: Optional[dict]):
     # compilation — a handful of rungs covers every cycle, and warmup
     # pre-compiles them (CycleSolver.warmup).  Beyond S's top rung the
     # caller runs a launch a head (None); a spec beyond K's never comes
-    # here (Preemptor.get_targets_batch searches it alone).
-    from .packing import coarse_bucket
+    # here (Preemptor._search_batch searches it alone).
     max_cands = max(1, max(len(c) for _, c, _, _ in specs))
     if len(specs) > S_LADDER[-1]:
         return _refused(stats, "over_s")

@@ -145,6 +145,11 @@ class Preemptor:
                       "search_refused_over_s": 0,
                       "search_refused_unpackable": 0,
                       "search_alone_over_k": 0,
+                      # what the launch plan saw in the specs' sizes:
+                      # specs with no candidate, answered [] unpacked,
+                      # and calls whose launches held several K rungs
+                      "search_empty_specs": 0,
+                      "search_split_plans": 0,
                       # the reclaim oracle on the batched route: the
                       # searches it asked for, and those answered Reclaim
                       "oracle_specs": 0,
@@ -271,13 +276,22 @@ class Preemptor:
         """One target search a context, all in batched device dispatches
         (ops/preemption_kernel minimal_preemptions_batch) — candidate
         discovery and ordering stay host-side, the greedy+fillback
-        searches vmap.  The planned specs go out in launches of at most
-        ``S_LADDER``'s top rung each.  A search with more candidates
-        than the batch's K ladder holds is launched alone, over the
-        candidates found and sorted here, and the others stay batched.
-        Falls back to a search a context for fair sharing, a missing
-        cycle pack, or an unpackable spec (decision-identical either
-        way)."""
+        searches vmap.  The launches follow the planned specs' sizes,
+        which is all the plan looks at: a spec with no candidate is
+        answered ``[]`` here and never packed (``search_empty_specs``);
+        a search with more candidates than the K ladder holds is
+        launched alone, over the candidates found and sorted here; the
+        others are grouped by the K rung of their own candidate count,
+        neighbouring rungs merged where that scans less
+        (``preemption_solver.plan_launches``), and a group is one
+        launch, or several of at most ``S_LADDER``'s top rung.  So a
+        launch scans as long as its own largest search, not the
+        cycle's, and specs that share a rung still make the one launch
+        (``search_split_plans`` counts the calls that made more).  All
+        launches are dispatched before the first is fetched.  Falls
+        back to a search a context for fair sharing, a missing cycle
+        pack, or an unpackable spec in any launch (decision-identical
+        either way)."""
         packed = self._pack_for(snapshot)
         def each_head():
             """One search (and one candidate discovery) a context."""
@@ -305,27 +319,37 @@ class Preemptor:
                     flat_specs.append((ctx, cands, ab, thr))
                 plans.append((idxs, staged))
 
-        # the launch plan follows each spec's size: those the K ladder
-        # holds share launches of up to the S ladder's top rung, each of
-        # the others gets its own
+        # the launch plan follows each spec's size: one with no
+        # candidate is answered here, one over the K ladder's top rung
+        # gets a launch of its own, and the others go out grouped by
+        # the K rung of their own candidate count (plan_launches)
         from ..ops import preemption_solver
         top = preemption_solver.K_LADDER[-1]
-        per_launch = preemption_solver.S_LADDER[-1]
         results: list[Optional[list[Target]]] = [None] * len(flat_specs)
-        batch = [i for i, spec in enumerate(flat_specs)
-                 if len(spec[1]) <= top]
-        for at in range(0, len(batch), per_launch):
-            launch = batch[at:at + per_launch]
-            found = preemption_solver.device_minimal_preemptions_batch(
-                [flat_specs[i] for i in launch], packed, stats=self.stats)
-            if found is None:
-                # refused (stats say why): one launch a context, each
-                # finding and sorting its candidates again
-                return each_head()
-            self.stats["device_searches"] += len(launch)
-            for i, targets in zip(launch, found):
-                results[i] = targets
-        if len(batch) < len(flat_specs):
+        batch = []
+        for i, spec in enumerate(flat_specs):
+            if not spec[1]:
+                results[i] = []     # nobody to evict: the search fails
+                self.stats["search_empty_specs"] += 1
+            elif len(spec[1]) <= top:
+                batch.append(i)
+        plan = preemption_solver.plan_launches(
+            [len(flat_specs[i][1]) for i in batch])
+        if len({k_rung for k_rung, _ in plan}) > 1:
+            self.stats["search_split_plans"] += 1
+        launches = [[batch[j] for j in members] for _, members in plan]
+        found = preemption_solver.device_minimal_preemptions_batch(
+            [[flat_specs[i] for i in launch] for launch in launches],
+            packed, stats=self.stats)
+        if found is None:
+            # refused (stats say why): one launch a context, each
+            # finding and sorting its candidates again
+            return each_head()
+        self.stats["device_searches"] += len(batch)
+        for launch, targets in zip(launches, found):
+            for i, t in zip(launch, targets):
+                results[i] = t
+        if any(r is None for r in results):
             with _span("cycle.nominate.search_fallback"):
                 for idxs, _ in plans:
                     for i in idxs:

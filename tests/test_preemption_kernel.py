@@ -280,7 +280,7 @@ def test_batch_refusal_reasons_sum_to_the_refusals(monkeypatch):
     monkeypatch.setattr(preemption_solver, "S_LADDER", (2,))
     stats = dict(d.scheduler.preemptor.stats)
     assert preemption_solver.device_minimal_preemptions_batch(
-        [(None, [], True, None)] * 3,
+        [[(None, [], True, None)] * 3],
         d.scheduler.solver.classify(d.cache.snapshot(),
                                     d.queues.heads_nonblocking()).packed,
         stats=stats) is None
@@ -310,3 +310,143 @@ def test_batch_refusal_reasons_sum_to_the_refusals(monkeypatch):
     assert stats3["search_alone_over_k"] == 0
     assert stats3["search_single_launches"] == 3
     assert stats3["device_searches"] == 3
+
+
+def empty_retry_cluster():
+    """Two queues: the second borrows (six workloads of 1,000 m on a
+    quota of 4,000), the first runs nothing.  Its head of 4,000 m plans
+    a staged search whose retry, its own queue's candidates, is empty:
+    the first spec (the six lent, no borrowing) fits with two targets."""
+    from tests.test_burst import mk
+    return _cohort_then_heads(
+        2,
+        [mk(f"lent-{i}", "lq-0-1", 1000, t=float(i + 1)) for i in range(6)],
+        [mk("high", "lq-0-0", 4000, prio=100, t=50.0)])
+
+
+# (cluster, K ladder, S ladder, step floor) -> the launches as (specs,
+# longest candidate list) in dispatch order, and the Preemptor.stats
+# that do not follow from them; None leaves a module constant alone
+SIZE_PLANS = {
+    # 8, 4 and 2 candidates, a rung each, and a step costs its rows
+    # alone: three launches without one padded slot, the longest first
+    "a_launch_a_rung": (uneven_cluster, (2, 4, 8), (1, 2, 4), 0, dict(
+        launches=[(1, 8), (1, 4), (1, 2)], padded=14, split=1, empty=0)),
+    # the same under the real floor: a launch costs 50 rows a step
+    # before its first spec, so one launch of three is cheapest
+    "floor_merges_every_rung": (uneven_cluster, (2, 4, 8), (1, 2, 4), None,
+                                dict(launches=[(3, 8)], padded=4 * 8,
+                                     split=0, empty=0)),
+    # a floor of two rows: 2 * 3 + 8 * (2 + 2) = 38 beats 42 (apart),
+    # 40 (the two short ones together) and 48 (one launch)
+    "floor_merges_neighbours": (uneven_cluster, (2, 4, 8), (1, 2, 4), 2,
+                                dict(launches=[(2, 8), (1, 2)],
+                                     padded=2 * 8 + 2, split=1, empty=0)),
+    "one_rung_is_one_launch": (uneven_cluster, (8, 16), (1, 2, 4), 0, dict(
+        launches=[(3, 8)], padded=4 * 8, split=0, empty=0)),
+    # the staged head's two specs (7 and 1 candidates) part
+    "staged_retry_in_its_own_rung": (staged_cluster, (1, 8), (1, 2), 0, dict(
+        launches=[(1, 7), (1, 1)], padded=8 + 1, split=1, empty=0)),
+    "empty_retry_is_never_packed": (empty_retry_cluster, None, None, None,
+                                    dict(launches=[(1, 6)], padded=32 * 16,
+                                         split=0, empty=1)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(SIZE_PLANS))
+def test_batched_search_launches_by_size(monkeypatch, case):
+    """The launch plan reads the specs' candidate counts and nothing
+    else: no candidate, no launch; a launch a group of K rungs, merged
+    where the stated cost says so, all dispatched before one is read;
+    and the targets are the one-launch plan's and the host search's."""
+    from kueue_tpu.ops import preemption_solver
+    from tests.test_burst import run_host
+
+    cluster, k_ladder, s_ladder, floor, want = SIZE_PLANS[case]
+    packs, events = [], []
+    pack = preemption_solver._pack_batch
+    decode = preemption_solver._decode_batch
+
+    def packing(specs, packed, stats):
+        packs.append((len(specs), max(len(s[1]) for s in specs),
+                      min(len(s[1]) for s in specs)))
+        events.append("pack")
+        return pack(specs, packed, stats)
+
+    def decoding(*args):
+        events.append("decode")
+        return decode(*args)
+
+    monkeypatch.setattr(preemption_solver, "_pack_batch", packing)
+    monkeypatch.setattr(preemption_solver, "_decode_batch", decoding)
+
+    as_they_are = {name: getattr(preemption_solver, name)
+                   for name in ("K_LADDER", "S_LADDER", "STEP_FLOOR_ROWS")}
+
+    def cycle(device_search, floor):
+        d, clock = cluster()
+        d.scheduler.preemptor.device_search = device_search
+        for name, value in (("K_LADDER", k_ladder), ("S_LADDER", s_ladder),
+                            ("STEP_FLOOR_ROWS", floor)):
+            monkeypatch.setattr(preemption_solver, name,
+                                as_they_are[name] if value is None else value)
+        del packs[:], events[:]
+        (stats,) = run_host(d, clock, 1, 0)
+        return d.scheduler.preemptor.stats, stats
+
+    host, h_cycle = cycle(False, floor)
+    assert host["host_searches"] >= 1 and host["device_searches"] == 0
+    assert h_cycle.preempted_targets
+
+    # a floor no launch is worth: every rung merged, one launch
+    one, o_cycle = cycle("auto", 10**9)
+    assert one["search_batch_launches"] == 1
+    assert one["search_split_plans"] == 0
+
+    stats, s_cycle = cycle("auto", floor)
+    assert [p[:2] for p in packs] == want["launches"]
+    assert min(p[2] for p in packs) >= 1        # no empty spec is packed
+    assert events == (["pack"] * len(packs) + ["decode"] * len(packs))
+    assert stats["search_batch_launches"] == len(want["launches"])
+    assert stats["search_padded_slots"] == want["padded"]
+    assert stats["search_candidate_slots"] == one["search_candidate_slots"]
+    assert stats["search_split_plans"] == want["split"]
+    assert stats["search_empty_specs"] == want["empty"] == (
+        one["search_empty_specs"])
+    assert stats["device_searches"] == sum(n for n, _ in want["launches"])
+    assert stats["search_batch_refusals"] == 0
+    assert stats["search_single_launches"] == stats["host_searches"] == 0
+
+    for got in (o_cycle, s_cycle):
+        assert got.preempted_targets == h_cycle.preempted_targets
+        assert got.preempting == h_cycle.preempting
+
+
+# candidate counts -> [(K rung, specs)] at the ladders as they are
+REAL_LADDER_PLANS = {
+    "nothing_to_launch": ([], []),
+    # a Zipf cycle: many short lists, some long
+    "short_and_long_part": ([50] * 400 + [500] * 100,
+                            [(1024, 100), (128, 400)]),
+    # three long lists do not take 400 short ones through their scan
+    "a_few_long_ones_part": ([500] * 3 + [50] * 400,
+                             [(1024, 3), (128, 400)]),
+    # 16 * 82 + 128 * 82 apart, 128 * 82 together
+    "a_handful_is_one_launch": ([8] * 3 + [100] * 2, [(128, 5)]),
+    "all_three_rungs": ([5] * 900 + [60] * 300 + [700] * 40,
+                        [(1024, 40), (128, 300), (16, 900)]),
+    # over the S ladder's top rung: launches of at most that many
+    "more_than_the_top_rung": ([10] * 5000, [(16, 4096), (16, 904)]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(REAL_LADDER_PLANS))
+def test_plan_launches_at_the_real_ladders(case):
+    from kueue_tpu.ops.preemption_solver import plan_launches
+    counts, want = REAL_LADDER_PLANS[case]
+    plan = plan_launches(counts)
+    assert [(k, len(members)) for k, members in plan] == want
+    # every spec is in exactly one launch, under its launch's rung
+    assert sorted(i for _, members in plan for i in members) == list(
+        range(len(counts)))
+    assert all(counts[i] <= k for k, members in plan for i in members)
