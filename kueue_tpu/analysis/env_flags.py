@@ -1,4 +1,4 @@
-"""Env-flag registry: every ``KUEUE_TPU_*`` read is declared.
+"""Env-flag registry: every ``KUEUE_*`` read is declared.
 
 The single source of truth is ``features.ENV_FLAGS`` (name, default,
 type, doc).  Reads go through ``features.env_value``/``env_int``; the
@@ -6,7 +6,8 @@ README "Environment flags" table is generated from the same registry
 and checked here, so docs cannot drift from code.
 
 - ``ad-hoc-env-read``     ``os.environ.get/[...]``/``os.getenv`` of a
-                          ``KUEUE_TPU_*`` name outside features.py
+                          ``KUEUE_*`` name, registered prefix or not,
+                          outside features.py
                           (writes — ``environ[...] = ``, ``setdefault``,
                           ``pop`` — are fine: harnesses configure
                           children through the environment)
@@ -26,7 +27,7 @@ from .core import Context, Finding, ParsedFile, dotted
 
 RULE = "env-flags"
 
-_PREFIX = "KUEUE_TPU_"
+_PREFIX = "KUEUE_"
 _FLAG_RE = re.compile(r"^KUEUE_TPU_[A-Z0-9_]+$")
 _README_ROW_RE = re.compile(r"^\|\s*`(KUEUE_TPU_[A-Z0-9_]+)`", re.MULTILINE)
 _REGISTRY_FILE = "kueue_tpu/features.py"

@@ -14,16 +14,15 @@ minimalkueue/main.go), so amortizing ours across restarts is part of
 matching its operational profile (verdict r3 item 7).
 
 One rule places the cache.  If ``JAX_COMPILATION_CACHE_DIR`` is set,
-JAX already uses it: this module sets no directory in code and keeps
-its sidecars there.  If it is not, the cache lives at ``DEFAULT_DIR``,
-a fixed path inside the checkout (``.kueue-tpu/`` is git-ignored).
+JAX already uses it: this module sets no directory in code.  If it is
+not, the cache lives at ``DEFAULT_DIR``, a fixed path inside the
+checkout (``.kueue-tpu/`` is git-ignored).
 ``KUEUE_TPU_COMPILE_CACHE=0`` turns the cache off, and a multi-device
 CPU backend (a virtual mesh) never uses it: see ``enable``.
 """
 
 from __future__ import annotations
 
-import json
 import os
 
 from .features import env_value
@@ -34,8 +33,8 @@ DEFAULT_DIR = os.path.join(
 
 
 def cache_dir() -> str | None:
-    """The directory compiled programs and sidecars live in, or None
-    when the cache is disabled."""
+    """The directory compiled programs live in, or None when the cache
+    is disabled."""
     if env_value("KUEUE_TPU_COMPILE_CACHE") == "0":
         return None
     return os.environ.get("JAX_COMPILATION_CACHE_DIR") or DEFAULT_DIR
@@ -70,30 +69,3 @@ def enable() -> str | None:
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     return d
-
-
-def load_json(name: str):
-    """Read a sidecar JSON artifact (the CPU-host calibration table)
-    from the compile-cache directory; None when absent/disabled."""
-    d = cache_dir()
-    if d is None:
-        return None
-    try:
-        with open(os.path.join(d, name)) as f:
-            return json.load(f)
-    except (OSError, ValueError):
-        return None
-
-
-def save_json(name: str, obj) -> bool:
-    """Write a sidecar JSON artifact next to the compile cache (atomic
-    rename); False when the cache is disabled."""
-    d = cache_dir()
-    if d is None:
-        return False
-    os.makedirs(d, exist_ok=True)
-    tmp = os.path.join(d, f".{name}.tmp.{os.getpid()}")
-    with open(tmp, "w") as f:
-        json.dump(obj, f)
-    os.replace(tmp, os.path.join(d, name))
-    return True

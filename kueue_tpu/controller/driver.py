@@ -88,7 +88,6 @@ class Driver:
                  wait_for_pods_ready: WaitForPodsReadyConfig | None = None,
                  namespaces: Optional[dict[str, dict[str, str]]] = None,
                  use_device_solver: bool = False,
-                 solver_backend: str = "auto",
                  validate: bool = True):
         self.clock = clock
         self.wait_for_pods_ready = wait_for_pods_ready or WaitForPodsReadyConfig()
@@ -110,8 +109,7 @@ class Driver:
             ordering=ordering, clock=clock, namespaces=namespaces)
         if use_device_solver:
             from ..ops.solver import CycleSolver
-            self.scheduler.solver = CycleSolver(ordering,
-                                                backend=solver_backend)
+            self.scheduler.solver = CycleSolver(ordering)
             shards = self._env_shards()
             if shards > 1:
                 # more shards than devices raises (make_mesh)
@@ -873,7 +871,7 @@ class Driver:
                        external_finishes: Optional[dict] = None,
                        on_cycle: Optional[Callable] = None,
                        on_cycle_start: Optional[Callable] = None,
-                       pipeline: Optional[bool] = None) -> list:
+                       pipeline: bool = True) -> list:
         """Run up to ``max_cycles`` cycles, fusing runs of clean cycles
         into single device dispatches (kueue_tpu.ops.burst) and falling
         back to the normal per-cycle path whenever a cycle needs host
@@ -889,9 +887,8 @@ class Driver:
         itself.  ``on_cycle_start(k)`` / ``on_cycle(k, stats)`` bracket
         each applied cycle (clock advancement, bookkeeping).
 
-        ``pipeline`` (default on; KUEUE_BURST_PIPELINE=0 disables)
-        double-buffers the burst boundary: after a window with no
-        modeled-dirty cycle is fetched, the NEXT window is dispatched
+        ``pipeline`` double-buffers the burst boundary: after a window
+        with no modeled-dirty cycle is fetched, the NEXT window is dispatched
         speculatively off the kernel's final carry — device-resident,
         no host re-pack — before this window's apply loop starts, so
         pack+dispatch overlap apply instead of landing serially in one
@@ -904,7 +901,6 @@ class Driver:
         to pipeline-off by construction.
 
         Returns the list of per-cycle CycleStats actually applied."""
-        import os
         import numpy as np
         from ..ops.burst import (BurstSolver, pack_burst_cached,
                                  K_BURST_LADDER)
@@ -1007,22 +1003,17 @@ class Driver:
 
         dirty_backoff = 0
         bstats = self._burst_solver.stats
-        if pipeline is None:
-            pipeline = os.environ.get("KUEUE_BURST_PIPELINE", "1") != "0"
         spec = None          # speculative BurstHandle for the next window
         plan = handle = None
         last_adm_clock = None
         clock_monotone = True
 
-        def cancel_spec(h, why=""):
+        def cancel_spec(h):
             """Discard an in-flight speculative window unfetched — its
             assumptions were invalidated; it must never be applied."""
             if h is not None:
                 bstats["burst_spec_cancelled"] += 1
                 bstats["burst_cycles_discarded"] += h.K
-                if os.environ.get("KUEUE_BURST_DEBUG"):
-                    import sys as _sys
-                    print(f"spec cancel: {why}", file=_sys.stderr)
             return None
 
         while len(out) < max_cycles:
@@ -1036,13 +1027,13 @@ class Driver:
                     # argument as every organic cancel
                     bstats["burst_chaos_divergences"] = (
                         bstats.get("burst_chaos_divergences", 0) + 1)
-                    spec = cancel_spec(spec, "chaos")
+                    spec = cancel_spec(spec)
             if (burst_ineligible or solver is None or normal_streak > 0
                     or self._resume_mask):
                 # a pending resume mask routes the first post-recovery
                 # cycle through schedule_once, which completes the
                 # WAL-interrupted cycle before bursting resumes
-                spec = cancel_spec(spec, "ineligible/streak/resume")
+                spec = cancel_spec(spec)
                 if normal_streak > 0 and not burst_ineligible:
                     bstats["burst_suppressed_cycles"] += 1
                 normal_streak = max(0, normal_streak - 1)
@@ -1055,7 +1046,7 @@ class Driver:
                 # structure drifted: one snapshot rebuilds the cached
                 # tensors; steady-state re-packs skip the snapshot cost
                 st = solver._structure_for(self.cache.snapshot(), [])
-                spec = cancel_spec(spec, "structure-drift")
+                spec = cancel_spec(spec)
             remaining = max_cycles - len(out)
             if spec is not None:
                 # pipelined boundary: this window's pack+dispatch
@@ -1135,13 +1126,6 @@ class Driver:
             # inside or past the next window, or runtime > K (a PRE-pack
             # admission's finish could then land past this window — the
             # carry only models finishes of in-kernel admissions).
-            if os.environ.get("KUEUE_BURST_DEBUG"):
-                import sys as _sys
-                print(f"spec gate @cycle {base}: remaining={remaining} "
-                      f"K={K} runtime={runtime} "
-                      f"dirty={bool(np.asarray(dirty).any())} "
-                      f"ext_late={any(off >= base + K for off in ext)}",
-                      file=_sys.stderr)
             if (pipeline and remaining > K and runtime <= K
                     and not bool(np.asarray(dirty).any())
                     and not any(off >= base + K for off in ext)):
@@ -1192,10 +1176,6 @@ class Driver:
                                     bool(borrows[k, ci]), targets)
                 if not dirty[k] and not modeled and quiescent():
                     drained = True
-                    if os.environ.get("KUEUE_BURST_DEBUG"):
-                        import sys as _sys
-                        print(f"win break @k={k}: drained",
-                              file=_sys.stderr)
                     break
                 # the cycle boundary in schedule_once order: advance the
                 # caller's clock FIRST, then fire deadline/backoff timers
@@ -1223,18 +1203,10 @@ class Driver:
                 if has_pre_kind and not clock_monotone:
                     # modeled candidate order may diverge from the host's
                     # reservation-timestamp order: decide on the host
-                    if os.environ.get("KUEUE_BURST_DEBUG"):
-                        import sys as _sys
-                        print(f"win break @k={k}: clock-monotone",
-                              file=_sys.stderr)
                     normal_cycle(heads=heads, advance=False)
                     break
                 if {h.key for h in heads} != set(modeled):
                     # unmodeled divergence: decide this cycle normally
-                    if os.environ.get("KUEUE_BURST_DEBUG"):
-                        import sys as _sys
-                        print(f"win break @k={k}: heads-mismatch",
-                              file=_sys.stderr)
                     normal_cycle(heads=heads, advance=False)
                     break
                 if not modeled:
@@ -1303,11 +1275,11 @@ class Driver:
                 # the window was truncated (dirty / divergence / clock):
                 # live state no longer matches the carry the speculative
                 # window chained from — it must never be applied
-                spec = cancel_spec(spec, "window-truncated")
+                spec = cancel_spec(spec)
             if drained:
-                spec = cancel_spec(spec, "drained")
+                spec = cancel_spec(spec)
                 break
-        spec = cancel_spec(spec, "end-of-call")
+        spec = cancel_spec(spec)
         return out
 
     def _fill_burst_finishes(self, st, plan, ext: dict, base: int, K: int,
@@ -1485,7 +1457,6 @@ class Driver:
                 "scalar_reasons": dict(ss.get("scalar_reasons", {})),
                 "resume_heads": ss.get("resume_heads", 0),
                 "walk_stop_heads": ss.get("walk_stop_heads", 0),
-                "native_ff_fallbacks": ss.get("native_ff_fallbacks", 0),
             }
         self.metrics.burst_solver_sample(out.get("burst"),
                                          out.get("flavor_walk"))

@@ -122,8 +122,6 @@ ENV_FLAGS: dict[str, EnvFlag] = {f.name: f for f in (
     EnvFlag("KUEUE_TPU_REQUIRE_ACCEL", "0", "bool",
             "Fail a bench run that finds no accelerator or dispatches "
             "nothing to it (same as --require-accel)."),
-    EnvFlag("KUEUE_TPU_STREAM_PACK", "1", "bool",
-            "Streaming delta-pack of the persistent packed universe."),
     EnvFlag("KUEUE_TPU_PACK_TIGHTEN", "1", "bool",
             "Dtype-tighten launch planes (int32 -> int16/int8)."),
     EnvFlag("KUEUE_TPU_RESIDENT", "1", "bool",

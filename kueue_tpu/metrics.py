@@ -244,7 +244,6 @@ class Registry:
             "scalar_heads": "kueue_burst_scalar_heads",
             "resume_heads": "kueue_burst_resume_heads",
             "walk_stop_heads": "kueue_burst_walk_stop_heads",
-            "native_ff_fallbacks": "kueue_burst_native_ff_fallbacks",
         }
         if burst_stats:
             for k, gauge in burst_gauge_of.items():
@@ -595,8 +594,6 @@ _SERIES_DEFS = [
      "Heads resumed mid-walk after a preempting flavor."),
     ("kueue_burst_walk_stop_heads", "gauge", (),
      "Heads whose flavor walk stopped early."),
-    ("kueue_burst_native_ff_fallbacks", "gauge", (),
-     "Flavor-fungibility configs the native kernel could not encode."),
     ("kueue_burst_scalar_heads_by_reason", "gauge", ("reason",),
      "Scalar-path heads broken down by routing reason."),
     # streaming pack + arena + WAL

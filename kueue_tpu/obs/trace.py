@@ -78,7 +78,7 @@ HOT_PATH_PHASES = (
     "cycle.order",      # classical sort or fair-sharing tournament setup
     "cycle.admit",      # sequential admit loop (assume/apply/requeue)
     "cycle.admit.fetch",              # blocking wait for the admit scan
-    "burst.pack",       # burst-window pack (streaming or classic delta)
+    "burst.pack",       # burst-window pack (streaming, or full)
     "burst.pack.drain",               # journal drain + round-trip checks
     "burst.pack.walk",                # stage A: per-queue row records
     "burst.pack.grid",                # stage B: the dense [C, M] planes

@@ -61,10 +61,6 @@ no sequential remove-chain walks.
 
 from __future__ import annotations
 
-import itertools
-import os
-import sys
-
 from dataclasses import dataclass
 from functools import partial
 
@@ -1324,65 +1320,12 @@ _ROW_ATTRS = ("adm", "prio", "ts", "res_ts", "parked", "ok",
               "resume", "req", "usage", "uses", "keys", "uids")
 
 
-def _concat_row_fields(records, nz, prev):
-    """Concatenate the per-record row arrays into flat stage-B fields.
-
-    ``prev`` (previous record list + its concatenated fields, from the
-    delta state) turns the 1000-segment concatenation into a few-chunk
-    splice: runs of reused record objects slice the cached flat arrays
-    (their rows are unchanged by construction), only re-walked records
-    contribute fresh segments.  Returns (fields, bounds) with the same
-    values a plain concatenation would produce."""
-    chunks = None
-    if prev is not None:
-        prev_records, prev_fields = prev
-        if prev_fields is not None and len(prev_records) == len(records):
-            bounds = prev_fields["_bounds"]
-            chunks = []          # (0, lo, hi) = prev slice; (1, i, 0)
-            run = None
-            for i, r in enumerate(records):
-                if r is prev_records[i]:
-                    if run is None:
-                        run = [int(bounds[i]), int(bounds[i + 1])]
-                    else:
-                        run[1] = int(bounds[i + 1])
-                else:
-                    if run is not None:
-                        chunks.append((0, run[0], run[1]))
-                        run = None
-                    if r.n_rows:
-                        chunks.append((1, i, 0))
-            if run is not None:
-                chunks.append((0, run[0], run[1]))
-    fields = {}
-    if chunks is not None:
-        for attr in _ROW_ATTRS:
-            prev_arr = prev_fields[attr]
-            fields[attr] = np.concatenate(
-                [prev_arr[lo:hi] if tag == 0
-                 else getattr(records[lo], attr)
-                 for tag, lo, hi in chunks]) if chunks else prev_arr[:0]
-    else:
-        for attr in _ROW_ATTRS:
-            fields[attr] = np.concatenate(
-                [getattr(r, attr) for r in nz])
-    n_rows_arr = np.fromiter((r.n_rows for r in records),
-                             dtype=np.int64, count=len(records))
-    fields["_bounds"] = np.concatenate(
-        ([0], np.cumsum(n_rows_arr)))
-    return fields
-
-
-def _assemble_plan(st, records, cache, scheduler, min_m,
-                   prev=None, fields_out=None):
+def _assemble_plan(st, records, cache, scheduler, min_m):
     """Stage B: fuse per-CQ row records into the dense [C, M] plan.
 
     Pure vectorized numpy over the concatenated rows; every rank comes
     from a total-order lexsort (key/uid final tiebreaks), so the output
-    is independent of record row order and a plan assembled from
-    delta-refreshed records is bit-identical to a full re-walk of the
-    same live state.  ``prev``/``fields_out`` carry the flat row arrays
-    across windows for the delta path (see ``_concat_row_fields``)."""
+    is independent of record row order."""
     ordering = scheduler.ordering
     C = len(st.cq_names)
     F = max(1, len(st.fr_index))
@@ -1403,10 +1346,10 @@ def _assemble_plan(st, records, cache, scheduler, min_m,
     M = max(_bucket(rows_per_cq, minimum=4), min_m)
 
     nz = [r for r in records if r.n_rows > 0]
-    fields = _concat_row_fields(records, nz, prev)
-    if fields_out is not None:
-        fields_out.update(fields)
-    n_rows_arr = np.diff(fields["_bounds"])
+    fields = {attr: np.concatenate([getattr(r, attr) for r in nz])
+              for attr in _ROW_ATTRS}
+    n_rows_arr = np.fromiter((r.n_rows for r in records),
+                             dtype=np.int64, count=C)
     ci_a = np.repeat(
         np.fromiter((r.ci for r in records), dtype=np.int32, count=C),
         n_rows_arr)
@@ -1425,7 +1368,7 @@ def _assemble_plan(st, records, cache, scheduler, min_m,
     uses_all = fields["uses"]
     key_arr = fields["keys"]
     uid_arr = fields["uids"]
-    n = int(fields["_bounds"][-1])
+    n = int(n_rows_arr.sum())
     strict = np.fromiter((r.strict for r in records), dtype=bool,
                          count=C)
 
@@ -1510,16 +1453,7 @@ def _assemble_plan(st, records, cache, scheduler, min_m,
         key_list, zip(ci_a.tolist(), mi_a.tolist())))
 
     # CQ-level usage, scaled exactly (else no burst) — per-record rows
-    if (prev is not None and prev[1] is not None
-            and "u_cq" in prev[1] and len(prev[0]) == len(records)):
-        u_cq = prev[1]["u_cq"].copy()
-        for i, r in enumerate(records):
-            if r is not prev[0][i]:
-                u_cq[i] = r.u_row
-    else:
-        u_cq = np.stack([r.u_row for r in records])
-    if fields_out is not None:
-        fields_out["u_cq"] = u_cq
+    u_cq = np.stack([r.u_row for r in records])
 
     # preemption policy flags + the in-kernel modeling envelope
     forest_bad = s.deep.copy()
@@ -1615,28 +1549,6 @@ def pack_burst(structure, queues, cache, scheduler, clock,
     return _assemble_plan(st, records, cache, scheduler, min_m)
 
 
-class DeltaPackState:
-    """Persistent per-CQ row records carried across burst windows.
-
-    Valid for one (structure generation, resource scale, CQ set,
-    window) key; ``pack_burst_cached`` re-walks only journaled-dirty
-    CQs against it and re-fuses stage B from the mixed records.
-    ``fields`` holds the flat stage-B row concatenation so the next
-    window splices only the dirty segments.  ``token`` is a process-wide
-    monotone serial: plans record the tokens they consumed/produced so a
-    shard-resident device copy can prove it chains from the same state
-    (object identity is not enough — ids alias after GC)."""
-    __slots__ = ("key", "records", "fields", "token")
-
-    _next_token = itertools.count(1)
-
-    def __init__(self, key, records, fields=None):
-        self.key = key
-        self.records = records
-        self.fields = fields
-        self.token = next(DeltaPackState._next_token)
-
-
 def _roundtrips_clean(rec, q, cq_live, keys, covers_pods) -> bool:
     """Verify that popped-and-requeued heads still match their packed
     rows: same Info object, same parked bit, same flavor-walk start
@@ -1672,194 +1584,33 @@ def _roundtrips_clean(rec, q, cq_live, keys, covers_pods) -> bool:
     return True
 
 
-# above this dirty share a delta walk rebuilds nearly everything anyway
-# and the journal bookkeeping makes it slower than a plain full pack
-_DELTA_MAX_DIRTY_FRAC = 0.5
-_DELTA_MIN_DIRTY_CQS = 8
-
-
 def pack_burst_cached(structure, queues, cache, scheduler, clock,
                       state=None, min_m: int = 0, window: int = 0,
                       stats=None):
-    """Delta-maintained pack_burst; returns ``(plan, state, was_delta)``.
+    """Incrementally maintained pack_burst; returns ``(plan, state,
+    was_delta)``.
 
-    Routing front door: by default the *streaming* delta pack
-    (ops/stream_pack.py) serves the boundary — it patches a persistent
-    packed-universe arena in place, O(arrivals + dirty) per window
-    instead of the classic path's O(total rows) stage-B reassembly.
-    ``KUEUE_TPU_STREAM_PACK=0`` opts back into the classic delta pack,
-    ``KUEUE_BURST_DELTA_PACK=0`` forces a full walk every window
-    (either path), and a structure the streaming encoder cannot model
-    (non-ASCII or oversized workload keys) self-poisons back to the
-    classic path.  Both paths share the return contract and produce
-    bit-identical plans (test-enforced)."""
-    import os
-    if (env_value("KUEUE_TPU_STREAM_PACK") != "0"
-            and os.environ.get("KUEUE_BURST_DELTA_PACK", "1") != "0"
-            and not getattr(structure, "_stream_poison", False)):
+    The streaming pack (ops/stream_pack.py) serves the boundary: it
+    patches a persistent packed-universe arena in place, O(arrivals +
+    dirty) per window, and its plans are bit-identical to ``pack_burst``
+    of the same live state (test-enforced).  A structure its encoder
+    cannot model (non-ASCII or oversized workload keys poison it) is
+    packed in full every window and carries no state."""
+    if not getattr(structure, "_stream_poison", False):
         from .stream_pack import pack_burst_streaming
         return pack_burst_streaming(structure, queues, cache, scheduler,
                                     clock, state=state, min_m=min_m,
                                     window=window, stats=stats)
-    return _pack_burst_cached_classic(structure, queues, cache,
-                                      scheduler, clock, state=state,
-                                      min_m=min_m, window=window,
-                                      stats=stats)
-
-
-def _pack_burst_cached_classic(structure, queues, cache, scheduler,
-                               clock, state=None, min_m: int = 0,
-                               window: int = 0, stats=None):
-    """The classic delta pack: re-walk journaled-dirty CQs, re-fuse
-    stage B from the mixed records.
-
-    Drains the queue-manager and cache PackJournals; when ``state``
-    covers the same (structure generation, resource scale, CQ set,
-    window) key and nothing forced a full walk, only journaled-dirty
-    CQs are re-walked and the surviving records re-fuse through stage B
-    — the boundary pays O(dirty rows) of Python walk instead of O(all
-    rows).  Any miss (key change, dirty-all, roundtrip drift, CQ the
-    delta path can't model) falls back to a full walk, counted in
-    ``stats``.  The returned plan is bit-identical to ``pack_burst`` of
-    the same live state (test-enforced by tests/test_delta_pack.py);
-    ``KUEUE_BURST_DELTA_PACK=0`` forces the full walk every window."""
-    import os
-    import time
-    st = structure
-    dirty: set = set()
-    soft: dict = {}
-    jranges: list = []
-    force_full = False
-    with _span("burst.pack.drain"):
-        for j in (getattr(queues, "pack_journal", None),
-                  getattr(cache, "pack_journal", None)):
-            if j is None:
-                force_full = True
-            else:
-                force_full |= j.drain_into(dirty, soft,
-                                           row_of=st.cq_index,
-                                           ranges_out=jranges)
-    enabled = os.environ.get("KUEUE_BURST_DELTA_PACK", "1") != "0"
-    from .aggregate import agg_planes_enabled
-    key = (st.generation, st.resource_scale.tobytes(),
-           tuple(st.cq_names), window, agg_planes_enabled())
-
-    def _full():
-        if _unknown_active_cq(st, queues):
-            return None, None, False
-        with _span("burst.pack.walk"):
-            records = _walk_records(st, queues, cache, scheduler, window)
-        if records is None:
-            return None, None, False
-        fields: dict = {}
-        with _span("burst.pack.grid"):
-            plan = _assemble_plan(st, records, cache, scheduler, min_m,
-                                  fields_out=fields if enabled else None)
-        if plan is None:
-            return None, None, False
-        if stats is not None:
-            stats["burst_full_packs"] = (
-                stats.get("burst_full_packs", 0) + 1)
-            stats["rows_repacked"] = (
-                stats.get("rows_repacked", 0)
-                + sum(r.n_rows for r in records))
-        new_state = (DeltaPackState(key, records, fields) if enabled
-                     else None)
-        # a full walk cannot chain a resident device copy (dirty set is
-        # unbounded) but it SEEDS one: the next delta pack may scatter
-        plan.pack_token = new_state.token if new_state else None
-        return plan, new_state, False
-
-    if not enabled or state is None or state.key != key or force_full:
-        return _full()
-
-    t0 = time.perf_counter()
-    index_of = st.cq_index
-    C = len(st.cq_names)
-    # a dirty CQ the structure doesn't know fails the pack exactly when
-    # the full walk would (active with pending work); clean unknown CQs
-    # were checked at state creation and only change through journaled
-    # mutators
-    with _span("burst.pack.drain"):
-        for name in dirty | set(soft):
-            if name not in index_of:
-                q = queues.queue_for(name)
-                if q is not None and q.active and q.pending_active():
-                    return None, None, False
-        # soft-dirty roundtrips: verify the packed dynamic bits still
-        # hold; escalate the CQ to a re-walk when they moved
-        for name, skeys in soft.items():
-            ci = index_of.get(name)
-            if ci is None or name in dirty:
-                continue
-            if not _roundtrips_clean(state.records[ci],
-                                     queues.queue_for(name),
-                                     cache.cluster_queue(name), skeys,
-                                     name in structure.cq_covers_pods):
-                dirty.add(name)
-
-    # at full churn the per-CQ delta walk is a near-complete rebuild
-    # plus journal/roundtrip overhead — measurably slower than the
-    # straight full walk at north-star scale.  The floor keeps small
-    # packs on the delta path so its machinery stays exercised.
-    if len(dirty) > max(_DELTA_MIN_DIRTY_CQS, _DELTA_MAX_DIRTY_FRAC * C):
-        return _full()
-
-    records = list(state.records)
-    pos_of = {name: i for i, name in
-              enumerate(queues.cluster_queue_names())}
-    assumed = cache.assumed_workloads
-    scale_of = {r: int(st.resource_scale[i])
-                for i, r in enumerate(st.resource_names)}
-    repacked = 0
-    with _span("burst.pack.walk"):
-        for name in dirty:
-            ci = index_of.get(name)
-            if ci is None:
-                continue
-            rec = _pack_cq_rows(st, ci, pos_of.get(name, C), queues,
-                                cache, scheduler, assumed, scale_of,
-                                window)
-            if rec is _PACK_FAIL:
-                return None, None, False
-            records[ci] = rec
-            repacked += rec.n_rows
-    # heads-enumeration positions can shift when CQs leave the queue
-    # manager; refresh them on every record (clean ones included)
-    for rec in records:
-        rec.pos = pos_of.get(st.cq_names[rec.ci], C)
-    fields: dict = {}
-    with _span("burst.pack.grid"):
-        plan = _assemble_plan(st, records, cache, scheduler, min_m,
-                              prev=(state.records, state.fields),
-                              fields_out=fields)
-    if plan is None:
-        return None, None, False
-    new_state = DeltaPackState(key, records, fields)
-    # resident chaining facts: which state this plan consumed/produced
-    # and exactly which CQ rows differ from the consumed state's plan
-    # (post-escalation; clean rows were spliced verbatim, so a device
-    # copy of the previous rows needs only these scattered)
-    dirty_cis = sorted(index_of[name] for name in dirty
-                       if name in index_of)
-    plan.pack_token = new_state.token
-    plan.prev_token = state.token
-    plan.dirty_cqs = np.asarray(dirty_cis, dtype=np.int64)
-    from ..utils.journal import PackJournal
-    plan.dirty_ranges = PackJournal.coalesce(dirty_cis)
-    if stats is not None:
-        stats["burst_delta_packs"] = (
-            stats.get("burst_delta_packs", 0) + 1)
-        stats["rows_repacked"] = (
-            stats.get("rows_repacked", 0) + repacked)
-        stats["rows_reused"] = (
-            stats.get("rows_reused", 0)
-            + sum(r.n_rows for r in records) - repacked)
-        stats["delta_pack_s"] = (
-            stats.get("delta_pack_s", 0.0) + time.perf_counter() - t0)
-        stats["burst_journal_dirty_ranges"] = (
-            stats.get("burst_journal_dirty_ranges", 0) + len(jranges))
-    return plan, new_state, True
+    # nothing reads the journals on this path: drained to stay bounded
+    for j in (getattr(queues, "pack_journal", None),
+              getattr(cache, "pack_journal", None)):
+        if j is not None:
+            j.drain_into(set(), {})
+    plan = pack_burst(structure, queues, cache, scheduler, clock,
+                      min_m=min_m, window=window)
+    if plan is not None and stats is not None:
+        stats["burst_full_packs"] = stats.get("burst_full_packs", 0) + 1
+    return plan, None, False
 
 
 # one K rung: every distinct K is a full kernel compilation, and a
@@ -1882,9 +1633,9 @@ H2D_STAGE_MIN_BYTES = 1 << 20
 
 class _ResidentRows:
     """Device-resident scatter-tier row planes from the last fresh
-    sharded dispatch, keyed by the DeltaPackState token that produced
+    sharded dispatch, keyed by the StreamState token that produced
     them.  The next fresh pack reuses them when its ``prev_token``
-    matches: the delta pack spliced every clean record verbatim, so
+    matches: the delta pack left every clean record's rows in place, so
     only its ``dirty_cqs`` rows need to re-cross the host boundary."""
     __slots__ = ("layout", "token", "planes")
 
@@ -2081,14 +1832,7 @@ class BurstSolver:
                     and fc["generation"] == plan.structure.generation
                     and fc["windows"] > 0 and len(fc["ewma"]) == plan.G):
                 cost = fc["ewma"]
-            import time as _time
-            t0 = _time.perf_counter()
             lay = BurstShardLayout(plan, self.n_shards, forest_cost=cost)
-            if os.environ.get("KUEUE_BURST_DEBUG"):
-                print(f"layout rebuild: gen={plan.structure.generation} "
-                      f"Cs={lay.Cs} Gs={lay.Gs} cost={cost is not None} "
-                      f"{(_time.perf_counter() - t0)*1e3:.1f}ms",
-                      file=sys.stderr)
             self._shard_layouts = {key: lay}   # one structure at a time
             self.stats["burst_layout_rebuilds"] = (
                 self.stats.get("burst_layout_rebuilds", 0) + 1)
@@ -2254,12 +1998,6 @@ class BurstSolver:
                layout.Gs, runtime)
         fn = self._sharded_fns.get(key)
         if fn is None:
-            if os.environ.get("KUEUE_BURST_DEBUG"):
-                print(f"sharded fn miss: K={K} depth={st.depth} "
-                      f"L={plan.L} S={S} KC={plan.KC} "
-                      f"n_levels={plan.n_levels} Gs={layout.Gs} "
-                      f"runtime={runtime} cached={len(self._sharded_fns)}",
-                      file=sys.stderr)
             fn = sharded_burst_fn(
                 self._shard_mesh, K=K, depth=st.depth, L=plan.L, S=S,
                 KC=plan.KC, n_levels=plan.n_levels, G=layout.Gs,
@@ -2298,7 +2036,6 @@ class BurstSolver:
         bit-identical to a full host permute (test harness switch).
         Returns the merged name→array dict (device arrays for static +
         scatter tiers, host arrays for the global tier)."""
-        import os
         import time as _time
         from ..parallel.sharded import (
             _C_FILLS, _STATE_NAMES, SCATTER_PLANES, GLOBAL_PLANES)
@@ -2309,18 +2046,12 @@ class BurstSolver:
         stats = self.stats
         dev_static = layout._static_dev
         if dev_static is None:
-            t_s = _time.perf_counter()
             host = layout.static_arrays(plan, timers)
             dev_static = {k: jax.device_put(v, sh) for k, v in
                           host.items()}
             layout._static_dev = dev_static
             layout._static_nbytes = sum(v.nbytes for v in host.values())
             stats["burst_boundary_bytes_h2d"] += layout._static_nbytes
-            if os.environ.get("KUEUE_BURST_DEBUG"):
-                print(f"static tier upload: "
-                      f"{layout._static_nbytes/1e6:.1f}MB "
-                      f"{(_time.perf_counter() - t_s)*1e3:.1f}ms",
-                      file=sys.stderr)
         stats["burst_boundary_bytes_equiv"] += layout._static_nbytes
 
         res = self._resident
@@ -2380,10 +2111,6 @@ class BurstSolver:
                 for name in SCATTER_PLANES}
             stats["burst_resident_misses"] += 1
             stats["burst_boundary_bytes_h2d"] += full_bytes
-            if os.environ.get("KUEUE_BURST_DEBUG"):
-                print(f"resident miss: {full_bytes/1e6:.1f}MB "
-                      f"{(_time.perf_counter() - t0)*1e3:.1f}ms",
-                      file=sys.stderr)
         stats["burst_boundary_bytes_equiv"] += full_bytes
 
         glob = {}
@@ -2411,7 +2138,6 @@ class BurstSolver:
         boundary on, the permuted row planes live on the mesh: a fresh
         pack scatters only its dirty rows (``_resident_inputs``) and a
         chained window reuses the cached device dict outright."""
-        import os
         import time as _time
         from ..parallel.sharded import _STATE_NAMES
         layout = self._layout_for(plan)
@@ -2433,13 +2159,8 @@ class BurstSolver:
         (elig0, parked0, resume0, adm0, adm_seq0, adm_usage0,
          adm_uses0, death0, u_cq0) = state
         extr, extu = layout.permute_ext(ext_release, ext_unpark)
-        t_fn = _time.perf_counter()
         fn = self._sharded_fn(plan, layout, K, runtime)
         t0 = _time.perf_counter()
-        if (os.environ.get("KUEUE_BURST_DEBUG")
-                and t0 - t_fn > 0.05):
-            print(f"sharded fn build: {(t0 - t_fn)*1e3:.1f}ms",
-                  file=sys.stderr)
         out = fn(
             a["wl_req"], a["wl_rank"], a["wl_cycle_rank"],
             a["wl_prio"], a["wl_uidrank"], a["vec_ok"],
@@ -2458,11 +2179,6 @@ class BurstSolver:
             a["members"], a["cand_rows"], a["cand_lmem"],
             a["self_lmem"],
             extr, extu)
-        if os.environ.get("KUEUE_BURST_DEBUG"):
-            t1 = _time.perf_counter()
-            if t1 - t0 > 0.1:
-                print(f"sharded dispatch call: {(t1 - t0)*1e3:.1f}ms "
-                      f"(trace+lower on first shapes)", file=sys.stderr)
         self.stats["burst_dispatches"] += 1
         self.stats["burst_cycles_decided"] += K
         self.stats["burst_sharded_dispatches"] = (
@@ -2596,14 +2312,6 @@ class BurstSolver:
         else:
             self.stats["burst_dispatch_s"] += (
                 _time.perf_counter() - handle.t_dispatch)
-        import os
-        if os.environ.get("KUEUE_BURST_DEBUG"):
-            import sys
-            plan = handle.plan
-            print(f"burst fetch K={handle.K} M={plan.M} KC={plan.KC} "
-                  f"C={plan.C} dev={handle.dev.platform} "
-                  f"spec={handle.speculative}: wait {dt*1e3:.1f} ms",
-                  file=sys.stderr)
         return handle.decisions
 
     def run(self, plan: BurstPlan, K: int, runtime: int,

@@ -53,7 +53,7 @@ from .packing import (PackedCycle, PackedStructure, _bucket, coarse_bucket,
 from .cycle import (admit_scan, admit_scan_forests, admit_scan_preempt,
                     classify_np, cycle_order_np, decision_pairs_from_slots,
                     pick_preempt_slot_np)
-from .device import on_accelerator, output_devices, solver_device
+from .device import on_accelerator, output_devices
 
 # A flat admit scan is one lax.scan step per head; the forest-parallel
 # variant processes one head per cohort forest per step.  Below this head
@@ -135,41 +135,20 @@ class DispatchHandle:
     preempting: Optional[np.ndarray] = None
     overlap_skip: Optional[np.ndarray] = None
     fit_mask: Optional[np.ndarray] = None  # [W] bool: vector + scalar fits
-    # "accel" | "cpu" | "native" | "sharded" | "no_fit" | "singleton"
+    # "accel" | "cpu" | "sharded" | "no_fit" | "singleton"
     route: str = ""
-
-
-# Calibration sidecar schema: bump whenever the table's key layout or
-# the measurement protocol changes, so a sidecar written by an older
-# build is rejected (re-measured) instead of mis-routing cycles.
-CALIB_SCHEMA = 3
 
 
 class CycleSolver:
     """Batched solver for the admission cycle.
 
-    Every jitted scan runs on the solver device (ops.device): there is
-    no second XLA back end to route to.  ``backend`` only says whether
-    the C++ core (kueue_tpu/native) may take the admit loop on a CPU
-    host: "auto" lets it compete with the XLA scan in a warm-up
-    calibration table, "xla" pins the jitted scan, "native" runs both
-    the classify AND the admit loop in the C++ core (preempt-target
-    cycles keep the jitted scan).  With an accelerator as the default
-    JAX backend the native core and the table are unreachable: "auto"
-    is "xla" and "native" raises.  Identical decisions either way."""
+    Every jitted scan runs on the solver device (ops.device), or over
+    the mesh when one is set: there is no other engine to route to."""
 
-    BACKENDS = ("auto", "xla", "native")
-
-    def __init__(self, ordering: Ordering | None = None,
-                 backend: str = "auto"):
+    def __init__(self, ordering: Ordering | None = None):
         from ..compilecache import enable as _enable_compile_cache
         _enable_compile_cache()
         self.ordering = ordering or Ordering()
-        if backend not in self.BACKENDS:
-            raise ValueError(
-                f"solver backend {backend!r}: expected one of "
-                f"{self.BACKENDS}")
-        self.backend = backend
         # Disjoint cycle counters: every cycle with heads lands in exactly
         # one of full/classify/host (bench derives shares from these).
         self.stats = {
@@ -185,14 +164,11 @@ class CycleSolver:
             # where each full cycle's admit scan ran (also disjoint):
             "accel_dispatches": 0,    # jitted scan, accelerator platform
             "cpu_dispatches": 0,      # jitted scan, XLA:CPU platform
-            "native_dispatches": 0,   # admit loop ran in the C++ core
             "output_devices": 0,      # most devices one scan's output
                                       # was spread over (mesh: > 1)
-            "native_calibration_failures": 0,
             "skipped_dispatches": 0,  # no fit head -> scan provably no-op
             "singleton_dispatches": 0,  # <=1 entry/forest -> no contention
             "structure_rebuilds": 0,
-            "calibration_loaded": 0,  # native-vs-XLA table reloaded
             "scalar_heads": 0,        # heads classified by the host walk
             # flavor-walk telemetry (heterogeneous fast path):
             "scalar_reasons": {},     # {reason: count} for scalar heads
@@ -200,10 +176,6 @@ class CycleSolver:
             "walk_stop_heads": 0,     # heads whose walk policy-stopped
             "walk_heads": 0,          # heads classified by the vector walk
             "walk_slots": 0,          # flavors their walks visited
-            "native_ff_fallbacks": 0,  # native classify skipped: the C++
-                                       # core is first-fit-only and the
-                                       # cycle has non-default fungibility
-                                       # or a resumed head
         }
         self._structure: Optional[PackedStructure] = None
         self._potential0 = None
@@ -211,27 +183,8 @@ class CycleSolver:
         # mesh-sharded programs (parallel/sharded.py admit_scan_fns)
         self.mesh = None
         self._sharded_fns: dict = {}
-        # CPU hosts only: measured admit-loop wall times, filled by
-        # warmup — {("xla"|"native", kernel, bucket, key_len): seconds}
-        self.calibration: dict[tuple, float] = {}
 
     # -- device --------------------------------------------------------
-
-    @staticmethod
-    def _native():
-        """The C++ core: a CPU host's code path, which refuses to stand
-        in for an accelerator."""
-        dev = solver_device()
-        if dev.platform != "cpu":
-            raise RuntimeError(
-                "solver backend 'native' runs on a CPU host only; "
-                f"the default JAX backend is {dev.platform!r}")
-        from .. import native
-        return native
-
-    def _native_competes(self) -> bool:
-        """Whether the calibrated native-vs-XLA pick applies at all."""
-        return self.backend == "auto" and solver_device().platform == "cpu"
 
     def _count_dispatch(self, pending) -> str:
         """Count one jitted scan by where its output lives."""
@@ -372,49 +325,12 @@ class CycleSolver:
         and preemption-search shape a run of ``max_heads`` heads can
         reach, through the same launch site dispatch() uses (_scan), so
         the programs are built for the solver device — or the mesh —
-        and for nothing else.  On a CPU host with backend="auto" it also
-        times the XLA scan against the C++ core per bucket; that table
-        (persisted beside the compile cache) is the only thing that
-        picks between them.  Shapes only — no scheduling state is
+        and for nothing else.  Shapes only — no scheduling state is
         touched."""
-        import time as _time
         import jax
         st = self._structure_for(snapshot, [])
         N, F = st.subtree_quota.shape
         C, S, R = st.slot_fr.shape
-        # the calibration pass exists for the native core alone; a mesh
-        # takes precedence over it, an accelerator makes it unreachable
-        calibrate = self._native_competes() and self.mesh is None
-        measure = False
-        if calibrate:
-            # a persisted table for this (build, structure shape) skips
-            # the measurement reps; the shape walk below still runs so
-            # every hot kernel shape is eagerly compiled — an evicted
-            # XLA cache entry must cost warmup seconds, never a live
-            # cycle
-            from .. import compilecache
-            import hashlib
-            fp_src = repr((jax.__version__, CALIB_SCHEMA,
-                           N, F, C, S, R, st.depth, st.n_forests,
-                           _bucket(max_heads)))
-            fp = hashlib.sha1(fp_src.encode()).hexdigest()[:16]
-            calib_name = f"calibration-{fp}.json"
-            loaded = compilecache.load_json(calib_name)
-            if loaded is not None and (
-                    loaded.get("schema") != CALIB_SCHEMA
-                    or loaded.get("fingerprint") != fp_src):
-                # a sidecar from another build (or a fingerprint-hash
-                # collision) would route cycles by numbers measured in
-                # a different world: reject it and re-measure
-                self.stats["calibration_rejected"] = 1
-                loaded = None
-            measure = loaded is None
-            if not measure:
-                self.calibration.update(
-                    {tuple(k): v
-                     for k, v in loaded.get("calibration", [])})
-                self.stats["calibration_loaded"] = 1
-        reps = 2 if measure else 1
         W = 8
         buckets = []
         while True:
@@ -448,18 +364,8 @@ class CycleSolver:
                     if mfw >= top:
                         break
                     mfw *= 2
-            kernel = "flat" if mfw_ladder == [None] else "forest"
             for mfw in mfw_ladder:
-                # the LAST rep's dispatch + readback is the sample: the
-                # first includes the compile
-                for _ in range(reps):
-                    t0 = _time.perf_counter()
-                    jax.device_get(self._scan(st, args, order, mfw=mfw))
-                    dt = _time.perf_counter() - t0
-                if measure:
-                    self.calibration[("xla", kernel, W, mfw or W)] = dt
-            if measure:
-                self._time_native(st, args, order, kernel, mfw_ladder)
+                jax.device_get(self._scan(st, args, order, mfw=mfw))
 
             # first padded-K bucket (scalar heads with more decision
             # pairs than R, _build_pair_tensors): compile so a
@@ -522,48 +428,6 @@ class CycleSolver:
                         np.zeros((S, K), bool), np.zeros((S, K), bool),
                         np.zeros(S, bool), np.zeros(S, bool),
                         depth=st.depth))
-
-        if measure:
-            compilecache.save_json(calib_name, {
-                "schema": CALIB_SCHEMA,
-                "fingerprint": fp_src,
-                "calibration": [[list(k), v]
-                                for k, v in self.calibration.items()]})
-
-    def _time_native(self, st: PackedStructure, args: tuple, order,
-                     kernel: str, mfw_ladder: list) -> None:
-        """Calibration sample for the C++ admit loop at one bucket (CPU
-        hosts only; nothing to compile — it is AOT C++)."""
-        import time as _time
-        native = self._native()
-        W, R = args[9].shape
-        F = args[0].shape[1]
-        if not native.available():
-            return
-        # worst-case-shaped sample: every head fits with ALL R decision
-        # pairs valid, so the sequential loop pays its full per-entry
-        # cost — a sparse sample made native look cheaper than real
-        # cycles and mis-routed the drain bench
-        n_cq = len(st.cq_names)
-        busy_cq = (np.arange(W) % max(n_cq, 1)).astype(np.int32)
-        busy_fr = np.tile((np.arange(R) % F).astype(np.int32), (W, 1))
-        busy_amt = np.ones((W, R), np.int32)
-        try:
-            for _ in range(2):
-                t0 = _time.perf_counter()
-                native.admit_scan_raw(
-                    *args[:8], busy_cq, busy_fr, busy_amt,
-                    np.ones(W, bool), args[12], args[13],
-                    np.zeros(W, bool), np.zeros(W, bool), order)
-                dt = _time.perf_counter() - t0
-        except (native.NativeBuildError, OSError):
-            # the XLA scan then takes every bucket; surfaced so a
-            # broken native build can't hide (weak r3 #5)
-            self.stats["native_calibration_failures"] += 1
-            return
-        # the native time is mfw-independent (one sequential loop)
-        for mfw in mfw_ladder:
-            self.calibration[("native", kernel, W, mfw or W)] = dt
 
     # -- structure cache -----------------------------------------------
 
@@ -683,44 +547,8 @@ class CycleSolver:
         W = packed.wl_cq.shape[0]
         start_pad = np.zeros(W, dtype=np.int32)
         start_pad[:len(heads)] = start
-        # the C++ classify core is first-fit-only: any non-default
-        # fungibility policy or mid-list resume routes to classify_np
-        ff_default = (bool(st.cq_wcb_borrow.all())
-                      and not bool(st.cq_wcp_preempt.any()))
-        if self.backend == "native" and (not ff_default or start.any()):
-            self.stats["native_ff_fallbacks"] += 1
-        if self.backend == "native" and ff_default and not start.any():
-            fit_slot0, borrows0, preempt0 = (
-                self._native().classify_cycle(packed))
-            n = packed.wl_count
-            R = len(st.resource_names)
-            out = {
-                "fit_slot0": np.asarray(fit_slot0),
-                "borrows0": np.asarray(borrows0),
-                "preempt0": np.asarray(preempt0),
-                "preempt_slot0": np.full(W, -1, np.int32),
-                "preempt_borrows0": np.zeros(W, bool),
-                "preempt_res_fit": np.ones((W, R), bool),
-                "preempt_slot_count": np.zeros(W, np.int32),
-                "preempt_stopped0": np.zeros(W, bool),
-                # first fit: the walk stops on its fit slot, or visits
-                # the whole list
-                "walk_slots": np.where(
-                    np.asarray(fit_slot0) >= 0, np.asarray(fit_slot0) + 1,
-                    st.slot_count_cq[np.maximum(packed.wl_cq, 0)]),
-            }
-            if out["preempt0"][:n].any():
-                # the C++ core covers fit/borrow/preempt-possible; the
-                # preempt-slot details come from the numpy pass on demand
-                det = classify_np(packed, potential0=self._potential0)
-                for k in ("preempt_slot0", "preempt_borrows0",
-                          "preempt_res_fit", "preempt_slot_count",
-                          "preempt_stopped0", "preempt_slots",
-                          "slot_res_fit", "slot_borrows", "oracle_ask"):
-                    out[k] = det[k]
-        else:
-            out = classify_np(packed, potential0=self._potential0,
-                              start_slot=start_pad)
+        out = classify_np(packed, potential0=self._potential0,
+                          start_slot=start_pad)
         n = packed.wl_count
         # partial admission: a min_count head whose FULL counts fit is
         # decision-identical to a plain head; otherwise the host runs the
@@ -1028,8 +856,6 @@ class CycleSolver:
 
         has_preempt = bool(pmask.any())
         mfw = self._forest_bucket(packed) if not has_preempt else None
-        kernel = ("preempt" if has_preempt
-                  else "flat" if mfw is None else "forest")
         args = (packed.usage0, st.subtree_quota, st.guaranteed,
                 st.borrow_cap, st.has_borrow_limit, st.parent,
                 st.nominal_cq, st.nominal_plus_blimit_cq, packed.wl_cq,
@@ -1040,53 +866,16 @@ class CycleSolver:
                    if has_preempt else None)
         sharded = self.mesh is not None
         if sharded:
-            # production mesh routing takes precedence over the native
-            # core: the scan runs as a sharded program over the (wl, cq)
-            # mesh with XLA collectives
+            # the scan runs as a sharded program over the (wl, cq) mesh
+            # with XLA collectives
             self.stats["sharded_dispatches"] += 1
             if has_preempt:
                 self.stats["sharded_preempt_dispatches"] += 1
-        elif not has_preempt and self._use_native(kernel, W, mfw):
-            # the C++ core runs the admit loop synchronously (preempt
-            # cycles keep the jitted scan — no native twin yet)
-            handle.admitted = self._native().admit_scan(
-                packed, dec_fr, dec_amt, fit_mask, res_fr, res_amt,
-                rmask, res_borrows, order)
-            handle.preempting = zeros
-            handle.overlap_skip = zeros
-            handle.route = "native"
-            self.stats["native_dispatches"] += 1
-            return handle
         handle.pending = self._scan(st, args, order, mfw=mfw,
                                     preempt=preempt)
         route = self._count_dispatch(handle.pending)
         handle.route = "sharded" if sharded else route
         return handle
-
-    def _use_native(self, kernel: str, W: int, mfw: Optional[int]) -> bool:
-        """Whether the C++ core takes this (preempt-free, unsharded)
-        admit loop: always under backend="native"; under "auto" on a CPU
-        host, in the buckets where warmup measured it faster than the
-        XLA scan.  The native time is mfw-independent (one sequential
-        loop), so a forest bucket beyond the warmup ladder falls back to
-        any recorded forest entry at this W — same for the XLA twin,
-        whose ladder has the same cap."""
-        if self.backend == "native":
-            return True
-        if not self._native_competes():
-            return False
-        key_len = mfw if mfw is not None else W
-
-        def _lookup(name):
-            t = self.calibration.get((name, kernel, W, key_len))
-            if t is None and kernel == "forest":
-                t = max((v for k, v in self.calibration.items()
-                         if k[:3] == (name, "forest", W)),
-                        default=None)
-            return t
-
-        t_nat, t_xla = _lookup("native"), _lookup("xla")
-        return t_nat is not None and (t_xla is None or t_nat < t_xla)
 
     def dispatch_fs(self, cls: ClassifiedCycle) -> Optional[DispatchHandle]:
         """Dispatch a fair-sharing cycle's tournament + admit loop as one

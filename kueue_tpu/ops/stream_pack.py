@@ -1,14 +1,14 @@
 """Streaming delta-pack: patch a persistent packed universe in place.
 
-The classic delta pack (ops/burst.py pack_burst_cached) re-walks only
-journal-dirty CQs but still *reassembles* the whole dense ``[C, M]``
-plan every window: a full concatenate of the per-CQ row records, three
-global lexsorts over every row, a fresh grid allocation + scatter, a
-``tolist`` of every key and a rebuilt ``row_of_key`` dict.  All of
-that is O(total rows) per window — the host residue that caps the
-10k-CQ artifacts.
+The full pack (ops/burst.py pack_burst) *reassembles* the whole dense
+``[C, M]`` plan: a walk of every CQ, a full concatenate of the per-CQ
+row records, three global lexsorts over every row, a fresh grid
+allocation + scatter, a ``tolist`` of every key and a rebuilt
+``row_of_key`` dict.  All of that is O(total rows) — too much to pay at
+every window boundary.
 
-This module keeps the packed universe *resident on the host* between
+This module, the one incremental pack behind ``pack_burst_cached``,
+keeps the packed universe *resident on the host* between
 windows (cache/arena.py PlaneArena slabs, slab-doubling growth) and
 patches it from the PackJournal:
 
@@ -29,17 +29,18 @@ The reference sort orders are reproduced bit for bit by encoding each
 lexsort key into a fixed-width big-endian byte string (order-preserving
 integer/float maps + the ASCII workload key), so one memcmp order
 equals the reference ``np.lexsort`` order; non-ASCII or oversized keys
-poison the structure back to the classic path (``_StreamBail``).
+poison the structure (``_StreamBail``), which ``pack_burst_cached``
+then packs in full every window.
 
 The produced plan is bit-identical to ``pack_burst`` of the same live
 state (enforced by tests/test_streaming_pack.py); plans carry snapshot
 *copies* of the live planes, so consumers (pipeline speculation, the
 shard-resident scatter, parity tests) never observe later patches.
-``KUEUE_TPU_STREAM_PACK=0`` opts out back to the classic delta pack.
 """
 
 from __future__ import annotations
 
+import itertools
 import time
 from typing import Optional
 
@@ -57,10 +58,15 @@ _SKEY_DT = np.dtype([("p", ">u8"), ("t", ">u8"), ("o", ">u4"),
                      ("k", f"S{_KEY_BYTES}")])
 _SKEY_S = f"S{_SKEY_DT.itemsize}"
 
+# above this dirty share a delta walk rebuilds nearly everything anyway
+# and the journal bookkeeping makes it slower than a plain full pack
+_DELTA_MAX_DIRTY_FRAC = 0.5
+_DELTA_MIN_DIRTY_CQS = 8
+
 
 class _StreamBail(Exception):
     """This structure can't be streamed (non-ASCII / oversized keys):
-    poison it back to the classic pack path."""
+    poison it, so that every window packs it in full."""
 
 
 def _enc_i64(x: np.ndarray) -> np.ndarray:
@@ -171,10 +177,13 @@ _ROW_PLANES = {
 
 
 class StreamState:
-    """Persistent streaming pack state, duck-compatible with
-    ``DeltaPackState`` (key/records/fields/token) so the classic path
-    can consume it after an opt-out or poison."""
-    __slots__ = ("key", "records", "fields", "token", "arena",
+    """Persistent streaming pack state, valid for one (structure
+    generation, resource scale, CQ set, window) key.  ``token`` is a
+    process-wide monotone serial: plans record the tokens they
+    consumed/produced so a shard-resident device copy can prove it
+    chains from the same state (object identity is not enough — ids
+    alias after GC)."""
+    __slots__ = ("key", "records", "token", "arena",
                  "crank", "uord",
                  "adm_ts", "adm_ci", "adm_mi", "adm_seq_cache",
                  "mi_of", "kb_of",
@@ -183,11 +192,12 @@ class StreamState:
                  "n_comp_cq", "comp_max_cq",
                  "row_of_key", "keys_grid", "M")
 
+    _next_token = itertools.count(1)
+
     def __init__(self, key, arena):
         self.key = key
-        self.fields = None        # classic-path compatibility
         self.arena = arena
-        self.token = next(_b.DeltaPackState._next_token)
+        self.token = next(StreamState._next_token)
 
 
 def _views(arena: PlaneArena, C: int, M: int, R: int, F: int) -> dict:
@@ -487,7 +497,7 @@ class _KeysView:
     """Lazy ``plan.keys``: an object grid supporting the consumers'
     ``plan.keys[ci][mi]`` indexing without materializing C×M Python
     lists every window.  Equality compares against list-of-lists (the
-    classic plan shape) for the parity tests."""
+    full pack's plan shape) for the parity tests."""
     __slots__ = ("_g",)
 
     def __init__(self, grid):
@@ -679,8 +689,7 @@ def _note_ms(stats, t0, delta=False):
         stats["stream_pack_s"] = stats.get("stream_pack_s", 0.0) + dt
         stats["pack_last_ms"] = dt * 1e3
         if delta:
-            # classic-path compat: tooling reads delta_pack_s as "time
-            # spent on incremental (non-full) packs"
+            # time spent on incremental (non-full) packs
             stats["delta_pack_s"] = stats.get("delta_pack_s", 0.0) + dt
 
 
@@ -751,8 +760,8 @@ def pack_burst_streaming(structure, queues, cache, scheduler, clock,
             if rows_verified:
                 _bump(stats, "pack_rows_verified", rows_verified)
 
-        if len(dirty) > max(_b._DELTA_MIN_DIRTY_CQS,
-                            _b._DELTA_MAX_DIRTY_FRAC * C):
+        if len(dirty) > max(_DELTA_MIN_DIRTY_CQS,
+                            _DELTA_MAX_DIRTY_FRAC * C):
             return _init_full(st, queues, cache, scheduler, key, min_m,
                               window, arena, stats, t0)
 
@@ -957,7 +966,7 @@ def pack_burst_streaming(structure, queues, cache, scheduler, clock,
             _bump(stats, "pack_row_patches", len(row_jobs))
 
             prev_token = state.token
-            state.token = next(_b.DeltaPackState._next_token)
+            state.token = next(StreamState._next_token)
             repacked = sum(r.n_rows for _, r, _, _, _ in walked)
             _bump(stats, "burst_delta_packs")
             _bump(stats, "stream_packs")
@@ -978,6 +987,6 @@ def pack_burst_streaming(structure, queues, cache, scheduler, clock,
     except _StreamBail:
         st._stream_poison = True
         _bump(stats, "stream_pack_bails")
-        return _b._pack_burst_cached_classic(
+        return _b.pack_burst_cached(
             structure, queues, cache, scheduler, clock, state=None,
             min_m=min_m, window=window, stats=stats)

@@ -253,8 +253,8 @@ _STATE_NAMES = ("elig0", "parked0", "resume0", "adm0", "adm_seq0",
 #   layout's value-remapped tables plus the quota plane and per-CQ
 #   structure facts.  Permuted + uploaded once per layout lifetime.
 # - SCATTER: per-record row facts.  The delta pack re-walks only
-#   journal-dirty CQs and splices every other record verbatim
-#   (_concat_row_fields), so for a chained delta pack these planes are
+#   journal-dirty CQs and leaves every other record's rows in place
+#   (ops/stream_pack.py), so for a chained delta pack these planes are
 #   bit-identical outside the dirty rows — only those rows scatter.
 # - GLOBAL:  globally recomputed each pack — dense cross-CQ ranks
 #   (cycle/uid), the reservation-seq plane, and the modeling envelope

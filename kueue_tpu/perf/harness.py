@@ -245,8 +245,7 @@ def require_accel_dispatches(solver_stats: dict,
     were dispatched to the accelerator and none ran anywhere else."""
     on = (solver_stats.get("accel_dispatches", 0)
           + (burst_stats or {}).get("burst_accel_dispatches", 0))
-    off = (solver_stats.get("cpu_dispatches", 0)
-           + solver_stats.get("native_dispatches", 0))
+    off = solver_stats.get("cpu_dispatches", 0)
     if on == 0 or off:
         raise SystemExit(
             f"--require-accel: {on} dispatches reached the accelerator "
@@ -359,7 +358,7 @@ class MissingControlArm(ValueError):
 # environment_drift record, so a "device wins" artifact also proves how
 # much of the arm actually ran on the device.
 _FALLBACK_KEYS = ("host_cycles", "scalar_heads", "resume_heads",
-                  "walk_stop_heads", "native_ff_fallbacks",
+                  "walk_stop_heads",
                   "burst_dirty_cycles", "burst_dirty_preempt",
                   "burst_dirty_scalar", "burst_dirty_resume",
                   "burst_suppressed_cycles",

@@ -90,8 +90,8 @@ class PackJournal:
         # Row-grade dirt: workload key -> owning CQ, last-writer-wins.
         # Multiple touches of the same key inside one cycle collapse to
         # a single row patch (dict assignment is the dedupe).  Consumers
-        # that don't understand row grade (the classic delta pack)
-        # escalate each entry to its CQ in drain_into.
+        # that don't understand row grade escalate each entry to its CQ
+        # in drain_into.
         self.rows: dict[str, str] = {}
         self.dirty_all = True
         # chaos: a simulated lost update (journal.drop_touch) taints the

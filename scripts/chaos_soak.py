@@ -193,7 +193,7 @@ def run_host_until_crash(d, clock, cycles, runtime):
     return out, None
 
 
-def run_burst_until_crash(d, clock, cycles, runtime, pipeline=None):
+def run_burst_until_crash(d, clock, cycles, runtime, pipeline=True):
     """schedule_burst that surfaces an injected crash, collecting each
     applied cycle's record through on_cycle (the burst's own return
     value is lost when the exception unwinds)."""
@@ -214,7 +214,7 @@ def run_burst_until_crash(d, clock, cycles, runtime, pipeline=None):
     return recs, None
 
 
-def run_burst(d, clock, cycles, runtime, pipeline=None):
+def run_burst(d, clock, cycles, runtime, pipeline=True):
     def on_cycle_start(_k):
         clock.t += 1.0
     return d.schedule_burst(cycles, runtime=runtime,

@@ -252,8 +252,6 @@ def run_burst_path(args) -> dict:
     cycle wall times are measured between applied-cycle boundaries, so
     pack + dispatch costs land in the first cycle of each burst (honest
     p99: the amortization is visible, not hidden)."""
-    os.environ["KUEUE_BURST_DELTA_PACK"] = (
-        "0" if getattr(args, "no_delta_pack", False) else "1")
     d, clock, total, preemptor_wave = build(
         args.cqs, args.wl, use_device=True,
         n_flavors=args.flavors, n_resources=args.resources)
@@ -693,10 +691,6 @@ def main():
                          "INTERLEAVED in one process (drift-fair A/B) "
                          "and report both paths plus a boundary-cost "
                          "comparison")
-    ap.add_argument("--no-delta-pack", action="store_true",
-                    help="disable the incremental delta pack "
-                         "(KUEUE_BURST_DELTA_PACK=0): every window "
-                         "boundary re-walks all queues")
     ap.add_argument("--ab-pack", action="store_true",
                     help="run delta-pack and full-repack burst trials "
                          "INTERLEAVED in one process (drift-fair A/B) "
@@ -1005,12 +999,26 @@ def main():
         args.no_pipeline = True
         if args.trickle == 0:
             args.trickle = 6
+        from kueue_tpu.ops import burst as _burst
+        delta_pack = _burst.pack_burst_cached
+
+        def full_pack(structure, queues, cache, scheduler, clock,
+                      state=None, min_m=0, window=0, stats=None):
+            """The control arm: every window boundary re-walks all
+            queues (pack_burst in pack_burst_cached's place)."""
+            return _burst.pack_burst(structure, queues, cache, scheduler,
+                                     clock, min_m=min_m,
+                                     window=window), None, False
+
         runs = {False: [], True: []}
         piped = []
         for _ in range(max(1, args.trials)):
             for no_delta in (False, True):
                 args.no_delta_pack = no_delta
+                _burst.pack_burst_cached = (full_pack if no_delta
+                                            else delta_pack)
                 runs[no_delta].append(run_burst_path(args))
+                _burst.pack_burst_cached = delta_pack
                 gc.unfreeze()
                 gc.collect()
             # the shipping configuration (boundary pipeline + delta

@@ -102,6 +102,7 @@ from kueue_tpu.api.types import (
 from kueue_tpu.controller.driver import Driver
 from kueue_tpu.features import env_value
 from kueue_tpu.obs import trace as _trace
+from kueue_tpu.ops import burst as _burst
 from kueue_tpu.ops.burst import pack_burst, pack_burst_cached
 from kueue_tpu.ops.packing import TightenState, tighten_arrays
 from kueue_tpu.perf.harness import ab_block
@@ -374,33 +375,30 @@ def pack_curve_point(n_cqs: int, boundaries: int, n_churn: int,
 _ARM_ENV = {
     # every r19 optimization on: head-only packing (default), aggregate
     # compression, lazy heap, bulk apply, pooled host plane
-    "stream": {"KUEUE_TPU_STREAM_PACK": "1",
-               "KUEUE_TPU_HOST_WORKERS": "4"},
-    "rebuild": {"KUEUE_TPU_STREAM_PACK": "0",
-                "KUEUE_BURST_DELTA_PACK": "0"},
+    "stream": {"KUEUE_TPU_HOST_WORKERS": "4"},
+    # a full pack at every window (_rebuild_pack), every flag at its
+    # default
+    "rebuild": {},
     # the single-flag bulk-apply A/B: identical to "stream" except the
     # one-settle cycle bulk apply is off — the honest denominator for
     # the e2e bulk-apply speedup (classic also flips aggregate
     # compression, whose per-admission fold cost lands in the apply
     # path and would confound the measurement)
-    "nobulk": {"KUEUE_TPU_STREAM_PACK": "1",
-               "KUEUE_TPU_HOST_WORKERS": "4",
+    "nobulk": {"KUEUE_TPU_HOST_WORKERS": "4",
                "KUEUE_TPU_CYCLE_BULK_APPLY": "0"},
     # the r19 bit-identity control: streaming pack on, every scale
     # optimization off — head-only packing, aggregate compression,
     # lazy heap repair, one-settle cycle bulk apply, worker pool.
     # This is the full row-backed serial arm of the head-pack parity
     # claim.
-    "classic": {"KUEUE_TPU_STREAM_PACK": "1",
-                "KUEUE_TPU_AGG_PLANES": "0",
+    "classic": {"KUEUE_TPU_AGG_PLANES": "0",
                 "KUEUE_TPU_HEAD_PACK": "0",
                 "KUEUE_TPU_LAZY_HEAP": "0",
                 "KUEUE_TPU_CYCLE_BULK_APPLY": "0",
                 "KUEUE_TPU_HOST_WORKERS": "0"},
 }
 
-_ARM_KEYS = ("KUEUE_TPU_STREAM_PACK", "KUEUE_BURST_DELTA_PACK",
-             "KUEUE_TPU_AGG_PLANES", "KUEUE_TPU_HEAD_PACK",
+_ARM_KEYS = ("KUEUE_TPU_AGG_PLANES", "KUEUE_TPU_HEAD_PACK",
              "KUEUE_TPU_LAZY_HEAP", "KUEUE_TPU_CYCLE_BULK_APPLY",
              "KUEUE_TPU_HOST_WORKERS")
 
@@ -413,12 +411,22 @@ def _span_totals(tracer) -> dict:
     return {n: tracer._hist_for(n).total for n in _KERNEL_SPANS}
 
 
+def _rebuild_pack(structure, queues, cache, scheduler, clock,
+                  state=None, min_m=0, window=0, stats=None):
+    """The rebuild arm's control: ``pack_burst`` in
+    ``pack_burst_cached``'s place, so every window packs from scratch."""
+    return pack_burst(structure, queues, cache, scheduler, clock,
+                      min_m=min_m, window=window), None, False
+
+
 def e2e_arm(arm: str, n_cqs: int, rounds: int, n_churn: int,
             seed: int, per_cq: int = 1) -> dict:
     old = {k: os.environ.get(k) for k in _ARM_KEYS}
     for k in _ARM_KEYS:
         os.environ.pop(k, None)
     os.environ.update(_ARM_ENV[arm])
+    if arm == "rebuild":
+        _burst.pack_burst_cached = _rebuild_pack
     try:
         d, clock = build(n_cqs)
         preload(d, clock, n_cqs, seed)
@@ -467,6 +475,7 @@ def e2e_arm(arm: str, n_cqs: int, rounds: int, n_churn: int,
         bs = dict(d._burst_solver.stats) if d._burst_solver else {}
         pack_block = d.stats.get("pack", {})
     finally:
+        _burst.pack_burst_cached = pack_burst_cached
         gc.enable()
         _trace.clear()
         for k, v in old.items():

@@ -8,14 +8,10 @@ to the serial burst path (and to the per-cycle host path), and any
 speculative window whose assumptions were invalidated by apply is
 discarded unused — plus regression tests for the satellite fixes that
 rode along (clock-monotonicity within a cycle, vanished preempt
-targets, calibration sidecar schema, seq-headroom gate, required-mode
-accel check).
+targets, seq-headroom gate, required-mode accel check).
 """
 
 from __future__ import annotations
-
-import json
-import os
 
 import pytest
 
@@ -137,11 +133,18 @@ def test_pipeline_parity_and_overlap():
     assert off["burst_overlapped_packs"] == 0, off
 
 
-def test_env_toggle_disables_pipeline(monkeypatch):
-    monkeypatch.setenv("KUEUE_BURST_PIPELINE", "0")
+@pytest.mark.parametrize("env", [None, "0"])
+def test_pipeline_is_the_default_whatever_the_environment(monkeypatch,
+                                                          env):
+    """schedule_burst pipelines unless its caller says otherwise: the
+    argument is the only switch, and no variable stands in for it."""
+    if env is not None:
+        monkeypatch.setenv("KUEUE_BURST_PIPELINE", env)
     d, clock = build(sustained_spec(per_cq=20))
-    run_burst_mode(d, clock, 60, 2, pipeline=None)
-    assert spec_counters(d)["burst_spec_dispatches"] == 0
+    d.schedule_burst(60, runtime=2,
+                     on_cycle_start=lambda k: setattr(clock, "t",
+                                                      clock.t + 1.0))
+    assert spec_counters(d)["burst_spec_dispatches"] > 0
 
 
 def test_midwindow_injection_cancels_speculation():
@@ -337,50 +340,3 @@ def test_dispatch_next_refuses_seq_overflow():
     h2 = BurstHandle(plan=None, K=32, runtime=0, seq_base=1, dev=None,
                      carry=None)    # never fetched: no carry to chain
     assert bs.dispatch_next(h2, None, None) is None
-
-
-def test_calibration_sidecar_schema_and_eager_compile(tmp_path,
-                                                      monkeypatch):
-    """Satellite: the calibration sidecar carries a schema version; a
-    mismatched sidecar is rejected (re-measured, re-written), and a
-    valid one still runs the eager-compile walk after loading."""
-    from kueue_tpu.ops import solver as solver_mod
-    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
-    spec = add_workloads(simple_cluster(n_cohorts=1, cqs=2),
-                         [mk("w", "lq-0-0", 1000, t=1.0)])
-
-    def warm():
-        d, _ = build(spec)
-        s = d.scheduler.solver
-        s.warmup(d.cache.snapshot(), 2)
-        return s
-
-    s1 = warm()                      # cold: measures + writes sidecar
-    files = [f for f in os.listdir(tmp_path)
-             if f.startswith("calibration-")]
-    assert len(files) == 1
-    path = tmp_path / files[0]
-    data = json.loads(path.read_text())
-    assert data["schema"] == solver_mod.CALIB_SCHEMA
-    assert data["fingerprint"]
-    assert s1.stats.get("calibration_loaded", 0) == 0
-
-    data["schema"] = -1              # stale build's sidecar
-    path.write_text(json.dumps(data))
-    s2 = warm()
-    assert s2.stats.get("calibration_rejected") == 1
-    assert s2.stats.get("calibration_loaded", 0) == 0
-    assert json.loads(path.read_text())["schema"] == \
-        solver_mod.CALIB_SCHEMA     # re-measured and re-written
-
-    s3 = warm()                      # valid: loads, still eager-compiles
-    assert s3.stats.get("calibration_loaded") == 1
-    assert s3.stats.get("calibration_rejected", 0) == 0
-    assert set(s3.calibration) == set(s2.calibration)
-
-    data = json.loads(path.read_text())
-    data["fingerprint"] = "someone else's machine"
-    path.write_text(json.dumps(data))
-    s4 = warm()                      # wrong-host sidecar is rejected too
-    assert s4.stats.get("calibration_rejected") == 1
-

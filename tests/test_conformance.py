@@ -49,8 +49,7 @@ def fixture_driver(use_device, extra_cqs=(), extra_lqs=(), extra_cohorts=(),
     """The TestSchedule shared fixture (scheduler_test.go:78-180)."""
     clock = FakeClock()
     d = Driver(clock=clock, namespaces=NAMESPACES,
-               use_device_solver=use_device, fair_sharing=fair_sharing,
-               solver_backend="xla" if use_device else "auto")
+               use_device_solver=use_device, fair_sharing=fair_sharing)
     for cohort in extra_cohorts:
         d.apply_cohort(cohort)
     for f in ("default", "on-demand", "spot", "model-a"):
@@ -641,8 +640,7 @@ def tas_driver(use_device, cq_flavors):
     from kueue_tpu.cache.tas_cache import NodeInfo
     features.set_feature_gates({"TopologyAwareScheduling": True})
     clock = FakeClock()
-    d = Driver(clock=clock, use_device_solver=use_device,
-               solver_backend="xla" if use_device else "auto")
+    d = Driver(clock=clock, use_device_solver=use_device)
     d.apply_topology(Topology(name="tas-single-level", levels=[HOSTNAME]))
     d.apply_resource_flavor(ResourceFlavor(
         name="tas-default", node_labels={"tas-node": "true"},

@@ -31,8 +31,7 @@ K = 1000
 
 def make_driver(use_device):
     clock = FakeClock()
-    d = Driver(clock=clock, use_device_solver=use_device, fair_sharing=True,
-               solver_backend="xla" if use_device else "auto")
+    d = Driver(clock=clock, use_device_solver=use_device, fair_sharing=True)
     d.apply_resource_flavor(ResourceFlavor(name="default"))
     policy = PreemptionPolicy(
         within_cluster_queue=WithinClusterQueue.LOWER_PRIORITY,
@@ -282,8 +281,7 @@ def make_driver_strategies(use_device, strategies):
     preemption-strategy list (reference parseStrategies)."""
     clock = FakeClock()
     d = Driver(clock=clock, use_device_solver=use_device, fair_sharing=True,
-               fs_preemption_strategies=list(strategies),
-               solver_backend="xla" if use_device else "auto")
+               fs_preemption_strategies=list(strategies))
     d.apply_resource_flavor(ResourceFlavor(name="default"))
     policy = PreemptionPolicy(
         within_cluster_queue=WithinClusterQueue.LOWER_PRIORITY,

@@ -63,8 +63,7 @@ def two_flavor_cq(name, f1_cpu, f2_cpu, cohort=None, fungibility=None,
 
 def make_driver(use_device, cqs):
     clock = FakeClock()
-    d = Driver(clock=clock, use_device_solver=use_device,
-               solver_backend="xla" if use_device else "auto")
+    d = Driver(clock=clock, use_device_solver=use_device)
     for f in ("f1", "f2"):
         d.apply_resource_flavor(ResourceFlavor(name=f))
     for c in cqs:

@@ -71,8 +71,7 @@ def cq(name, quotas, cohort=None, preemption=None, groups=None):
 
 def make_driver(use_device, cqs):
     clock = FakeClock()
-    d = Driver(clock=clock, use_device_solver=use_device,
-               solver_backend="xla" if use_device else "auto")
+    d = Driver(clock=clock, use_device_solver=use_device)
     for f in ("default", "alpha", "beta"):
         d.apply_resource_flavor(ResourceFlavor(name=f))
     for c in cqs:

@@ -8,8 +8,7 @@ pop/requeue roundtrips), evictions, finishes, backoff park/unpark,
 activeness flips, LimitRanges — and after EVERY step compare the
 delta-built plan against a from-scratch pack, array by array.  Forced
 structure-generation bumps and quota/scale changes must fall back to a
-counted full repack, and ``KUEUE_BURST_DELTA_PACK=0`` must disable the
-delta path entirely.
+counted full repack.
 """
 
 from __future__ import annotations
@@ -237,28 +236,21 @@ def test_delta_pack_rows_reused_counted():
     assert stats["delta_pack_s"] > 0.0
 
 
-def test_delta_pack_env_kill_switch(monkeypatch):
-    monkeypatch.setenv("KUEUE_BURST_DELTA_PACK", "0")
-    d, clock = build_cluster()
-    for i in range(4):
-        d.create_workload(mk(f"w{i}", "lq-0-0", 1000, t=float(i)))
-    stats = {}
-    st = current_structure(d)
-    plan, state, was_delta = pack_burst_cached(
-        st, d.queues, d.cache, d.scheduler, d.clock, stats=stats)
-    assert plan is not None and state is None and not was_delta
-    d.create_workload(mk("w9", "lq-0-0", 1000, t=9.0))
-    plan, state, was_delta = pack_burst_cached(
-        st, d.queues, d.cache, d.scheduler, d.clock, state=state,
-        stats=stats)
-    assert state is None and not was_delta
-    assert stats["burst_full_packs"] == 2
-    assert stats.get("burst_delta_packs", 0) == 0
+def full_pack_every_window(structure, queues, cache, scheduler, clock,
+                           state=None, min_m=0, window=0, stats=None):
+    """The control: ``pack_burst`` in ``pack_burst_cached``'s place."""
+    return pack_burst(structure, queues, cache, scheduler, clock,
+                      min_m=min_m, window=window), None, False
 
 
-def test_schedule_burst_decisions_identical_delta_on_off(monkeypatch):
+@pytest.mark.parametrize("wide_key", [False, True])
+def test_schedule_burst_decisions_identical_delta_on_off(monkeypatch,
+                                                         wide_key):
     """End-to-end drift-fair check: schedule_burst decisions with the
-    delta pack on vs off are identical, and the delta run reuses rows."""
+    delta pack and with a full pack at every window are identical, and
+    the delta run reuses rows.  With a key the streaming encoder cannot
+    hold (``wide_key``) the program itself packs in full every window:
+    one bail, no delta pack, the same decisions."""
     def spec(d):
         for c in range(2):
             for q in range(2):
@@ -266,15 +258,23 @@ def test_schedule_burst_decisions_identical_delta_on_off(monkeypatch):
                     d.create_workload(mk(
                         f"w-{c}-{q}-{i}", f"lq-{c}-{q}", 1500,
                         prio=(i % 3) * 10, t=float(10 * c + 3 * q + i)))
+        if wide_key:
+            d.create_workload(mk("x" * 80, "lq-0-1", 1500, t=99.0))
 
     runs = {}
     for mode in ("1", "0"):
-        monkeypatch.setenv("KUEUE_BURST_DELTA_PACK", mode)
+        if mode == "0":
+            monkeypatch.setattr("kueue_tpu.ops.burst.pack_burst_cached",
+                                full_pack_every_window)
         d, clock = build_cluster()
         spec(d)
-        stats = d.schedule_burst(
-            12, runtime=2,
-            on_cycle_start=lambda k: setattr(clock, "t", clock.t + 1.0))
+
+        def tick(_k, clock=clock):
+            clock.t += 1.0
+
+        stats = d.schedule_burst(6, runtime=2, on_cycle_start=tick)
+        d.create_workload(mk("late", "lq-1-1", 1500, t=200.0))
+        stats += d.schedule_burst(6, runtime=2, on_cycle_start=tick)
         runs[mode] = (
             [(sorted(s.admitted), sorted(s.skipped),
               sorted(s.inadmissible), sorted(s.preempted_targets))
@@ -286,6 +286,11 @@ def test_schedule_burst_decisions_identical_delta_on_off(monkeypatch):
     assert runs["0"][2]["burst_delta_packs"] == 0
     on = runs["1"][2]
     assert on["burst_full_packs"] >= 1
+    if wide_key:
+        assert on["stream_pack_bails"] == 1
+        assert on["burst_full_packs"] == 2
+        assert on["burst_delta_packs"] == 0
+        return
     # the pipelined boundary may skip host packs entirely; when more
     # than one host pack ran, at least one must have been a delta pack
     if on["burst_full_packs"] + on["burst_delta_packs"] > 1:

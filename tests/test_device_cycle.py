@@ -29,8 +29,7 @@ def build_driver(seed, use_device, n_cohorts=2, cqs_per_cohort=3, n_wl=60,
                  preemption=True):
     rng = random.Random(seed)
     clock = FakeClock()
-    d = Driver(clock=clock, use_device_solver=use_device,
-               solver_backend="xla" if use_device else "auto")
+    d = Driver(clock=clock, use_device_solver=use_device)
     d.apply_resource_flavor(ResourceFlavor(name="default"))
     pre = (PreemptionPolicy(
         reclaim_within_cohort=ReclaimWithinCohort.ANY,
@@ -100,10 +99,14 @@ def drive_cycles(d, clock, workloads, n_cycles=40, runtime=2):
     return log
 
 
-@pytest.mark.parametrize("seed", [11, 12, 13, 14])
-def test_per_cycle_parity_host_vs_device(seed):
-    host, hclock, hwl = build_driver(seed, use_device=False)
-    dev, dclock, dwl = build_driver(seed, use_device=True)
+@pytest.mark.parametrize("seed,preemption", [
+    (11, True), (12, True), (13, True), (14, True),
+    (31, False), (32, False)])
+def test_per_cycle_parity_host_vs_device(seed, preemption):
+    host, hclock, hwl = build_driver(seed, use_device=False,
+                                     preemption=preemption)
+    dev, dclock, dwl = build_driver(seed, use_device=True,
+                                    preemption=preemption)
     hlog = drive_cycles(host, hclock, hwl)
     dlog = drive_cycles(dev, dclock, dwl)
     for cyc, (h, dv) in enumerate(zip(hlog, dlog)):
@@ -123,8 +126,7 @@ def build_preemption_heavy(seed, use_device, n_cohorts=3, cqs_per_cohort=3,
     path), overlapping-target races, and reclaim across borrowing CQs."""
     rng = random.Random(seed)
     clock = FakeClock()
-    d = Driver(clock=clock, use_device_solver=use_device,
-               solver_backend="xla" if use_device else "auto")
+    d = Driver(clock=clock, use_device_solver=use_device)
     d.apply_resource_flavor(ResourceFlavor(name="default"))
     pre = PreemptionPolicy(
         reclaim_within_cohort=ReclaimWithinCohort.ANY,
@@ -228,7 +230,7 @@ def test_reserve_path_runs_on_device():
     preempt-capable with zero candidates → the device cycle reserves
     capacity and stays fully device-decided (no host fallback)."""
     clock = FakeClock()
-    d = Driver(clock=clock, use_device_solver=True, solver_backend="xla")
+    d = Driver(clock=clock, use_device_solver=True)
     d.apply_resource_flavor(ResourceFlavor(name="default"))
     d.apply_cluster_queue(ClusterQueue(
         name="cq",
@@ -263,7 +265,7 @@ def test_drain_scenario_device_share_gate():
     eligibility shrink).  If a change makes the solver fall back, this
     fails before the bench regresses."""
     clock = FakeClock()
-    d = Driver(clock=clock, use_device_solver=True, solver_backend="xla")
+    d = Driver(clock=clock, use_device_solver=True)
     d.apply_resource_flavor(ResourceFlavor(name="default"))
     for c in range(2):
         for q in range(3):
@@ -326,8 +328,7 @@ def test_skip_race_matches_host():
     logs = []
     for use_device in (False, True):
         clock = FakeClock()
-        d = Driver(clock=clock, use_device_solver=use_device,
-                   solver_backend="xla" if use_device else "auto")
+        d = Driver(clock=clock, use_device_solver=use_device)
         d.apply_resource_flavor(ResourceFlavor(name="default"))
         for i in range(2):
             d.apply_cluster_queue(ClusterQueue(
@@ -353,3 +354,63 @@ def test_skip_race_matches_host():
     assert logs[0] == logs[1], logs
     admitted, skipped, _ = logs[1]
     assert len(admitted) == 1 and len(skipped) == 1, logs
+
+
+def test_classify_np_matches_jitted_classify():
+    """The entry point's pack through the host classify (classify_np)
+    and through the jitted cycle's classify half: the same fit slot,
+    borrow and preempt-capable verdict for every head."""
+    import numpy as np
+    import __graft_entry__ as ge
+    from kueue_tpu.ops.cycle import classify_np, solve_cycle
+    from kueue_tpu.parallel import cycle_args
+
+    _, _, _, packed = ge._packed_cycle()
+    out = solve_cycle(*cycle_args(packed), depth=packed.depth,
+                      run_scan=False)
+    dev_preempt, dev_fit, dev_borrow = [np.asarray(o) for o in out[3:6]]
+    ref = classify_np(packed)
+    np.testing.assert_array_equal(ref["fit_slot0"], dev_fit)
+    np.testing.assert_array_equal(ref["borrows0"], dev_borrow)
+    np.testing.assert_array_equal(ref["preempt0"], dev_preempt)
+    assert (dev_fit >= 0).any()
+
+
+def test_contended_pack_admit_scan_matches_host_loop():
+    """A contended pack (decision pairs, borrowing, in-scan skips)
+    through the jitted admit_scan: the admitted heads, in cycle order,
+    are the host admit loop's on the same cluster."""
+    import jax
+    import numpy as np
+    import __graft_entry__ as ge
+    from kueue_tpu.ops.cycle import (admit_scan, classify_np,
+                                     cycle_order_np,
+                                     decision_pairs_from_slots)
+
+    shape = dict(n_cohorts=4, cqs_per_cohort=4, n_workloads=64,
+                 contended=True)
+    _, _, _, packed = ge._packed_cycle(**shape)
+    st = packed.structure
+    out = classify_np(packed)
+    dec_fr, dec_amt, fit_mask = decision_pairs_from_slots(
+        st.slot_fr, packed.wl_cq, packed.wl_requests, out["fit_slot0"])
+    W = packed.wl_cq.shape[0]
+    res_fr = np.full_like(dec_fr, -1)
+    res_amt = np.zeros_like(dec_amt)
+    no_res = np.zeros(W, dtype=bool)
+    order = cycle_order_np(out["borrows0"], packed.wl_priority,
+                           packed.wl_timestamp)
+    admitted = np.asarray(jax.device_get(admit_scan(
+        packed.usage0, st.subtree_quota, st.guaranteed, st.borrow_cap,
+        st.has_borrow_limit, st.parent, st.nominal_cq,
+        st.nominal_plus_blimit_cq, packed.wl_cq, dec_fr, dec_amt,
+        fit_mask, res_fr, res_amt, no_res, no_res, order,
+        depth=st.depth)))
+    n = packed.wl_count
+    assert admitted[:n].any() and not admitted[:n].all(), \
+        "scenario must have both admits and in-scan losers"
+    host_stats = ge._build_scenario(**shape).schedule_once()
+    dev_order = [packed.wl_keys[int(wi)] for wi in order
+                 if wi < n and admitted[int(wi)]]
+    assert dev_order == list(host_stats.admitted)
+    assert host_stats.skipped

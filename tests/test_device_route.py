@@ -23,7 +23,6 @@ from kueue_tpu import compilecache
 from kueue_tpu.controller.driver import Driver
 from kueue_tpu.ops.burst import BurstSolver
 from kueue_tpu.ops.device import solver_device
-from kueue_tpu.ops.solver import CycleSolver
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -75,20 +74,74 @@ def test_logs_are_silenced_only_on_the_cpu_choice():
         assert out.stdout.strip() == want, (platforms, out.stderr[-500:])
 
 
-def test_unknown_backend_and_native_off_cpu_raise(monkeypatch):
-    with pytest.raises(ValueError, match="expected one of"):
-        CycleSolver(backend="cpu")
+def _flat(use_device):
+    from tests.test_device_cycle import build_driver, drive_cycles
+    d, clock, workloads = build_driver(21, use_device)
+    return d, lambda: drive_cycles(d, clock, workloads, n_cycles=12)
 
-    class FakeTpu:
-        platform = "tpu"
-        device_kind = "fake"
 
-    from kueue_tpu.ops import solver as solver_mod
-    monkeypatch.setattr(solver_mod, "solver_device", lambda: FakeTpu())
-    with pytest.raises(RuntimeError, match="CPU host only"):
-        CycleSolver(backend="native")._native()
-    # and "auto" on an accelerator never lets the C++ core compete
-    assert not CycleSolver(backend="auto")._native_competes()
+def _forest(use_device):
+    from tests.test_device_cycle import build_driver, drive_cycles
+    d, clock, workloads = build_driver(22, use_device, n_cohorts=16,
+                                       cqs_per_cohort=4, n_wl=400)
+    return d, lambda: drive_cycles(d, clock, workloads, n_cycles=12)
+
+
+def _preempting(use_device):
+    from tests.test_device_cycle import (build_preemption_heavy,
+                                         drive_two_phase)
+    d, clock, low, high = build_preemption_heavy(23, use_device)
+    return d, lambda: drive_two_phase(d, clock, low, high, n_cycles=12)
+
+
+@pytest.fixture(scope="module")
+def compile_events():
+    """Every executable JAX builds (or loads) from here on, as chip_smoke
+    counts them."""
+    events = []
+
+    def on_duration(event, _secs, **_kw):
+        if event == "/jax/core/compile/backend_compile_duration":
+            events.append(event)
+
+    jax.monitoring.register_event_duration_secs_listener(on_duration)
+    return events
+
+
+@pytest.mark.parametrize("build,kind", [
+    (_flat, "flat"), (_forest, "forest"), (_preempting, "preempt")])
+def test_warmed_cpu_host_takes_the_jitted_scan(build, kind, compile_events):
+    """After warmup on a CPU host every cycle's admit scan is the jitted
+    program on the XLA:CPU device (or one of the two shortcuts that
+    launch nothing), already built: warm-up measures nothing, picks
+    nothing and leaves the cycles nothing to compile."""
+    d, drive = build(True)
+    solver = d.scheduler.solver
+    snapshot = d.cache.snapshot()
+    solver.warmup(snapshot, len(snapshot.cluster_queues))
+    routes, kinds = [], set()
+    dispatch, scan = solver.dispatch, solver._scan
+
+    def record_dispatch(*a, **kw):
+        handle = dispatch(*a, **kw)
+        routes.append(handle.route)
+        return handle
+
+    def record_scan(st, args, order, mfw=None, preempt=None):
+        kinds.add("preempt" if preempt is not None
+                  else "flat" if mfw is None else "forest")
+        return scan(st, args, order, mfw=mfw, preempt=preempt)
+
+    solver.dispatch, solver._scan = record_dispatch, record_scan
+    built = len(compile_events)
+    log = drive()
+    assert len(compile_events) == built, "a warmed cycle built a program"
+    assert log == build(False)[1]()
+    assert "cpu" in routes and kind in kinds, (routes, kinds)
+    assert set(routes) <= {"cpu", "singleton", "no_fit"}, routes
+    assert solver.stats["cpu_dispatches"] == routes.count("cpu")
+    assert not [k for k in solver.stats
+                if k.startswith(("native", "calibration"))]
 
 
 def test_more_shards_than_devices_raises(monkeypatch):
@@ -115,8 +168,8 @@ def _one_cpu_device(code, cwd, **extra):
 
 def test_cache_dir_from_env_is_left_to_jax(tmp_path):
     """With JAX_COMPILATION_CACHE_DIR set the program sets no cache
-    directory in code (JAX read the variable itself) and writes its
-    sidecars there and nowhere else."""
+    directory in code (JAX read the variable itself) and names that
+    one and no other."""
     code = (
         "import jax, os\n"
         "from kueue_tpu import compilecache as c\n"
@@ -127,13 +180,12 @@ def test_cache_dir_from_env_is_left_to_jax(tmp_path):
         "print(c.enable())\n"
         "print(jax.config.jax_compilation_cache_dir)\n"
         "print('jax_compilation_cache_dir' in calls)\n"
-        "print(c.save_json('side.json', {'a': 1}))\n")
+        "print(c.cache_dir())\n")
     cache = tmp_path / "cache"
     assert _one_cpu_device(code, str(tmp_path),
                            JAX_COMPILATION_CACHE_DIR=str(cache)) == [
-        str(cache), str(cache), "False", "True"]
-    assert os.listdir(tmp_path) == ["cache"]
-    assert os.listdir(cache) == ["side.json"]
+        str(cache), str(cache), "False", str(cache)]
+    assert os.listdir(tmp_path) in ([], ["cache"])
 
 
 def test_default_cache_dir_is_one_path_in_the_checkout(tmp_path):
@@ -148,7 +200,7 @@ def test_default_cache_dir_is_one_path_in_the_checkout(tmp_path):
 def test_cache_off_by_flag_and_on_a_virtual_cpu_mesh(monkeypatch):
     """XLA:CPU deadlocks running a multi-device executable loaded from
     the persistent cache, so this process (eight virtual devices) gets
-    none; sidecars still have their directory."""
+    none; the directory is still named."""
     assert len(jax.devices()) > 1
     before = jax.config.jax_compilation_cache_dir
     assert compilecache.enable() is None
@@ -156,7 +208,6 @@ def test_cache_off_by_flag_and_on_a_virtual_cpu_mesh(monkeypatch):
     assert compilecache.cache_dir() == compilecache.DEFAULT_DIR
     monkeypatch.setenv("KUEUE_TPU_COMPILE_CACHE", "0")
     assert compilecache.cache_dir() is None
-    assert not compilecache.save_json("side.json", {})
 
 
 def test_require_accel_checks_the_dispatches_not_the_device():
@@ -166,8 +217,7 @@ def test_require_accel_checks_the_dispatches_not_the_device():
         require_accel_or_die()          # this process is pinned to CPU
     with pytest.raises(SystemExit, match="0 dispatches reached"):
         require_accel_dispatches({"accel_dispatches": 0,
-                                  "cpu_dispatches": 0,
-                                  "native_dispatches": 831})
+                                  "cpu_dispatches": 831})
     with pytest.raises(SystemExit, match="ran off it"):
         require_accel_dispatches({"accel_dispatches": 5,
                                   "cpu_dispatches": 1})

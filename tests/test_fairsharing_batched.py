@@ -34,8 +34,7 @@ def build_fs_driver(seed, *, batched, use_device=False, n_cohorts=2,
     rng = random.Random(seed)
     clock = FakeClock()
     d = Driver(clock=clock, fair_sharing=True,
-               use_device_solver=use_device,
-               solver_backend="xla" if use_device else "auto")
+               use_device_solver=use_device)
     d.scheduler.fs_batched = batched
     d.apply_resource_flavor(ResourceFlavor(name="default"))
     pre = PreemptionPolicy(reclaim_within_cohort=ReclaimWithinCohort.ANY)

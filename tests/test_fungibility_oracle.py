@@ -187,8 +187,7 @@ def run(engine, build, wcp):
     """[(admitted, evicted, {admitted key: flavors})] a cycle, and the
     driver."""
     clock = FakeClock()
-    d = Driver(clock=clock, use_device_solver=engine != "host",
-               solver_backend="auto" if engine == "host" else "xla")
+    d = Driver(clock=clock, use_device_solver=engine != "host")
     between = build(d, wcp)
     out = []
 
@@ -264,7 +263,7 @@ def test_oracle_span_holds_its_searches_and_self_time_adds_up():
     from kueue_tpu.obs import trace as trace_mod
     from kueue_tpu.obs.trace import HOT_PATH_PHASES, SELF_SUFFIX
     clock = FakeClock()
-    d = Driver(clock=clock, use_device_solver=True, solver_backend="xla")
+    d = Driver(clock=clock, use_device_solver=True)
     two_reclaimable(d, TRY_NEXT)
     tracer = d.obs.enable_tracing()
     try:

@@ -90,8 +90,7 @@ def test_ab_block_records_fallback_counters():
     from kueue_tpu.perf.harness import ab_block
 
     treatment = {"arm": "burst", "p99_ms": 12.0,
-                 "solver_stats": {"host_cycles": 0, "scalar_heads": 0,
-                                  "native_ff_fallbacks": 2},
+                 "solver_stats": {"host_cycles": 0, "scalar_heads": 0},
                  "burst_stats": {"burst_dirty_cycles": 0,
                                  "burst_dispatches": 9}}
     control = {"arm": "host", "p99_ms": 40.0, "interleaved": True,
@@ -101,7 +100,6 @@ def test_ab_block_records_fallback_counters():
     assert drift["interleaved"] is True
     fc = drift["fallback_counters"]
     assert fc["treatment"]["host_cycles"] == 0
-    assert fc["treatment"]["native_ff_fallbacks"] == 2
     assert fc["treatment"]["burst_dirty_cycles"] == 0
     # non-fallback counters are not copied
     assert "burst_dispatches" not in fc["treatment"]

@@ -18,7 +18,7 @@ from kueue_tpu.controller.driver import Driver
 
 
 def test_trace_captures_scheduling_cycles(tmp_path):
-    d = Driver(use_device_solver=True, solver_backend="xla")
+    d = Driver(use_device_solver=True)
     d.apply_resource_flavor(ResourceFlavor(name="default"))
     d.apply_cluster_queue(ClusterQueue(
         name="cq", resource_groups=[ResourceGroup(

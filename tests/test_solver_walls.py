@@ -34,8 +34,7 @@ from tests.conftest import FakeClock
 
 def new_driver(use_device):
     clock = FakeClock()
-    d = Driver(clock=clock, use_device_solver=use_device,
-               solver_backend="xla" if use_device else "auto")
+    d = Driver(clock=clock, use_device_solver=use_device)
     return d, clock
 
 

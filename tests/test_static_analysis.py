@@ -16,6 +16,7 @@ Three layers, mirroring the acceptance contract:
 
 from __future__ import annotations
 
+import ast
 import json
 import os
 import subprocess
@@ -23,6 +24,7 @@ import sys
 import textwrap
 
 import numpy as np
+import pytest
 
 from kueue_tpu.analysis import (
     BASELINE_PATH,
@@ -370,6 +372,36 @@ def test_env_flags_flags_adhoc_reads_and_unregistered_names(tmp_path):
     found = env_flags.run(files, c)
     assert sum(f.code == "ad-hoc-env-read" for f in found) == 4
     assert sum(f.code == "unregistered-flag" for f in found) == 1
+
+
+def test_env_flags_flags_reads_outside_the_registered_prefix(tmp_path):
+    files = [pf("kueue_tpu/mod.py", """
+        import os
+
+        def f():
+            return os.environ.get("KUEUE_BURST_X", "1") != "0"
+    """)]
+    c = ctx(tmp_path, env_flags=_FLAGS,
+            extra_sources={"README.md": textwrap.dedent(_README_OK)})
+    found = env_flags.run(files, c)
+    assert [(f.code, f.symbol) for f in found] == [
+        ("ad-hoc-env-read", "KUEUE_BURST_X")]
+
+
+@pytest.mark.parametrize("rel", [
+    "kueue_tpu/controller/driver.py", "kueue_tpu/ops/burst.py",
+    "kueue_tpu/ops/stream_pack.py", "kueue_tpu/ops/solver.py"])
+def test_main_path_reads_no_environment(rel):
+    """From Driver.schedule_burst down to the kernels' launch sites
+    nothing asks the environment what to do, under any name: what
+    varies is an argument or a registered flag read through
+    features.env_value."""
+    with open(os.path.join(ROOT, rel)) as f:
+        parsed = ParsedFile.from_source(rel, f.read())
+    os_names = env_flags._os_aliases(parsed.tree)
+    reads = [read for node in ast.walk(parsed.tree)
+             if (read := env_flags._env_read(node, os_names)) is not None]
+    assert reads == [], (rel, reads)
 
 
 def test_env_flags_checks_readme_table_both_ways(tmp_path):

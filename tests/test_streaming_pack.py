@@ -8,7 +8,7 @@ pin its three contracts:
   from-scratch ``pack_burst`` array by array, dtype included — under
   structural churn, row-grade admission-check flips (the ``touch_row``
   channel), and the escalation/bail fallbacks (over-wide keys poison
-  the streaming path back to the classic delta pack).
+  the structure, which is then packed in full every window).
 - **Tightened launch planes never change decisions.**  The serial
   launch narrows eligible planes to int16/int8; widths are sticky and
   overflow widens (never truncates), so runs with tightening on and
@@ -329,43 +329,12 @@ def test_streaming_parity_row_flip_churn_randomized():
         assert stats.get("stream_packs", 0) >= 1
 
 
-def test_schedule_burst_decisions_identical_stream_on_off(monkeypatch):
-    """End-to-end gate: the streaming arena and the classic record
-    re-fuse must admit, skip, and preempt identically."""
-    def spec(d):
-        for c in range(2):
-            for q in range(2):
-                for i in range(6):
-                    d.create_workload(mk(
-                        f"w-{c}-{q}-{i}", f"lq-{c}-{q}", 1500,
-                        prio=(i % 3) * 10, t=float(10 * c + 3 * q + i)))
-
-    runs = {}
-    for mode in ("1", "0"):
-        monkeypatch.setenv("KUEUE_TPU_STREAM_PACK", mode)
-        d, clock = build_cluster()
-        spec(d)
-        stats = d.schedule_burst(
-            12, runtime=2,
-            on_cycle_start=lambda k: setattr(clock, "t", clock.t + 1.0))
-        runs[mode] = (
-            [(sorted(s.admitted), sorted(s.skipped),
-              sorted(s.inadmissible), sorted(s.preempted_targets))
-             for s in stats],
-            d.admitted_keys(),
-            dict(d._burst_solver.stats))
-    assert runs["1"][0] == runs["0"][0]
-    assert runs["1"][1] == runs["0"][1]
-    on, off = runs["1"][2], runs["0"][2]
-    assert on.get("stream_full_packs", 0) >= 1
-    assert off.get("stream_full_packs", 0) == 0
-    assert off.get("stream_packs", 0) == 0
-
-
-def test_stream_bail_wide_key_falls_back_to_classic():
+@pytest.mark.parametrize("windows_after", [1, 2, 3])
+def test_stream_bail_wide_key_packs_in_full_every_window(windows_after):
     """A key wider than the fixed-width sort encoding bails the
-    streaming path — counted, poisoned for the structure's lifetime,
-    and still bit-identical via the classic delta pack."""
+    streaming path — counted once, poisoned for the structure's
+    lifetime — and every window from then on is a full pack that
+    carries no state, still bit-identical."""
     d, clock = build_cluster()
     for i in range(4):
         d.create_workload(mk(f"w{i}", "lq-0-0", 1000, t=float(i)))
@@ -373,14 +342,17 @@ def test_stream_bail_wide_key_falls_back_to_classic():
     d.create_workload(mk("x" * 80, "lq-0-1", 1000, t=9.0))
     stats = {}
     state = check_step(d, None, stats, 0, "bail")
+    assert state is None
     assert stats.get("stream_pack_bails", 0) == 1
     assert stats.get("burst_full_packs", 0) == 1
-    # poisoned: later boundaries route straight to the classic path
-    d.create_workload(mk("tail", "lq-0-0", 1000, t=10.0))
-    state = check_step(d, state, stats, 0, "post-bail")
-    assert stats.get("stream_pack_bails", 0) == 1
+    for w in range(windows_after):
+        d.create_workload(mk(f"tail{w}", "lq-0-0", 1000, t=10.0 + w))
+        state = check_step(d, state, stats, 0, f"post-bail {w}")
+        assert state is None
+        assert stats["burst_full_packs"] == 2 + w
+    assert stats["stream_pack_bails"] == 1
     assert stats.get("stream_packs", 0) == 0
-    assert stats.get("burst_delta_packs", 0) == 1
+    assert stats.get("burst_delta_packs", 0) == 0
 
 
 # ---------------------------------------------------------------------------
