@@ -27,6 +27,7 @@ from ..api.types import (
 )
 from ..resources import FlavorResourceQuantities
 from ..workload import Info, InfoOptions
+from .candidates import TableTally
 from .snapshot import Snapshot
 from .state import (
     CohortState,
@@ -85,6 +86,9 @@ class Cache:
         # workload key → owning CQ name (O(1) duplicate/ownership lookups;
         # the reference keys cache membership the same way, cache.go:536)
         self._wl_owner: dict[str, str] = {}
+        # rows written into the queues' candidate tables and into their
+        # snapshot clones (cache/candidates.py); the preemptor reports it
+        self.table_tally = TableTally()
         # dirty-CQ journal feeding the incremental burst pack: admitted
         # table / usage / assumed-set mutations mark the owning CQ
         # (utils/journal.py); structure edits need no marks — they bump
@@ -124,7 +128,8 @@ class Cache:
         with self._lock:
             existing = self._mgr.cluster_queues.get(spec.name)
             if existing is None:
-                self._mgr.add_cluster_queue(spec.name, CQState(spec))
+                self._mgr.add_cluster_queue(
+                    spec.name, CQState(spec, self.table_tally))
             else:
                 existing.update_quotas(spec)
             self._mgr.update_cluster_queue_edge(spec.name, spec.cohort)

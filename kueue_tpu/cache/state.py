@@ -23,6 +23,7 @@ from ..api.types import (
 from ..resources import FlavorResource, FlavorResourceQuantities, Requests
 from ..workload import Info
 from . import resource_node as rn
+from .candidates import CandidateTable, TableTally
 
 MAX_DRS = sys.maxsize  # weight-zero sentinel (reference fair_sharing.go:52)
 
@@ -112,11 +113,16 @@ class CohortState:
 class CQState:
     """ClusterQueue cache entry (reference pkg/cache/clusterqueue.go)."""
 
-    def __init__(self, spec: ClusterQueue):
+    def __init__(self, spec: ClusterQueue,
+                 table_tally: Optional[TableTally] = None):
         self.spec = spec
         self.resource_node = rn.ResourceNode()
         self.parent: Optional[CohortState] = None
         self.workloads: dict[str, Info] = {}
+        # the same workloads as columns, a row each, for the preemptor
+        # (cache/candidates.py); add_workload, remove_workload and
+        # clone are all that write either
+        self.candidates = CandidateTable(table_tally)
         self.allocatable_generation = 0
         self.active = True
         self.inactive_reasons: list[str] = []
@@ -167,9 +173,11 @@ class CQState:
         if tag is not None:
             tag.mutated = True
         self.workloads[info.key] = info
-        rn.apply_usage(self, info.usage(), +1)
+        usage = info.usage()
+        self.candidates.add(info, usage)
+        rn.apply_usage(self, usage, +1)
         if info.obj.is_admitted:
-            self.admitted_usage.add(info.usage())
+            self.admitted_usage.add(usage)
         return True
 
     def remove_workload(self, info: Info) -> None:
@@ -178,6 +186,7 @@ class CQState:
         tag = self._snap_tag
         if tag is not None:
             tag.mutated = True
+        self.candidates.remove(info.key)
         rn.apply_usage(self, info.usage(), -1)
         if info.obj.is_admitted:
             self.admitted_usage.sub(info.usage())
@@ -227,6 +236,7 @@ class CQState:
         c.resource_node = self.resource_node.clone()
         c.parent = parent
         c.workloads = dict(self.workloads)
+        c.candidates = self.candidates.clone()
         c.allocatable_generation = self.allocatable_generation
         c.active = self.active
         c.inactive_reasons = list(self.inactive_reasons)

@@ -206,36 +206,127 @@ def device_minimal_preemptions_batch(launches, packed,
     return out
 
 
+class _Layout:
+    """The F axis and the scales of one pack's structure: what turns
+    unscaled usage, a column a flavor-resource, into the kernel's
+    [.., F] int32 planes."""
+
+    def __init__(self, packed):
+        self.fr_index = packed.fr_index
+        self.F = packed.usage0.shape[1]
+        self.scale_of = {r: int(packed.resource_scale[i])
+                         for i, r in enumerate(packed.resource_names)}
+
+    def scaled(self, frs, raw: np.ndarray, has: np.ndarray
+               ) -> tuple[np.ndarray, np.ndarray]:
+        """Rows of usage over the columns ``frs`` (``raw`` [m, len(frs)]
+        int64, ``has`` the keys each row holds) on the F axis: ([m, F]
+        int32, [m] bool).  A row is False, and its vector not to be
+        used, where the planes cannot hold it: a key the F axis lacks,
+        a quantity its resource's scale does not divide, or one past
+        int32."""
+        at = [self.fr_index.get(fr) for fr in frs]
+        known = np.array([fi is not None for fi in at], dtype=bool)
+        ok = ~has[:, ~known].any(axis=1)
+        scale = np.array([self.scale_of[fr.resource] if fi is not None else 1
+                          for fr, fi in zip(frs, at)], dtype=np.int64)
+        quot, rem = np.divmod(raw, scale)
+        ok &= ~(rem != 0)[:, known].any(axis=1)
+        ok &= ~(quot > 2**31 - 1)[:, known].any(axis=1)
+        vec = np.zeros((len(raw), self.F), dtype=np.int32)
+        # fr_index is one to one, so no two columns land on one plane
+        vec[:, [fi for fi in at if fi is not None]] = np.where(
+            ok[:, None], quot[:, known], 0)
+        return vec, ok
+
+    def scaled_usages(self, usages) -> tuple[np.ndarray, np.ndarray]:
+        """``scaled`` for usages given as dicts, a row each."""
+        col_of: dict = {}
+        for usage in usages:
+            for fr in usage:
+                col_of.setdefault(fr, len(col_of))
+        raw = np.zeros((len(usages), len(col_of)), dtype=np.int64)
+        has = np.zeros(raw.shape, dtype=bool)
+        for i, usage in enumerate(usages):
+            for fr, v in usage.items():
+                raw[i, col_of[fr]] = v
+                has[i, col_of[fr]] = True
+        return self.scaled(tuple(col_of), raw, has)
+
+
+def layout_for(packed) -> _Layout:
+    st = packed.structure
+    layout = getattr(st, "_candidate_layout", None)
+    if layout is None:
+        layout = st._candidate_layout = _Layout(packed)
+    return layout
+
+
+def _candidate_planes(searches, K: int, S: int, layout: _Layout, locate):
+    """The candidate planes of ``searches`` = [(candidates, threshold)],
+    a search a row: (cand_cq [S, K], cand_delta [S, K, F], cand_other
+    [S, K], cand_above [S, K]), gathered from the candidates' columns;
+    None where the planes cannot hold a candidate (its usage, or its
+    queue: ``locate(si, queue name)`` is the queue's index for search
+    ``si``, or None)."""
+    counts = np.array([len(c) for c, _ in searches], dtype=np.intp)
+    total = int(counts.sum())
+    # where search si's candidate k lands in the planes taken flat
+    flat = (np.arange(total) + np.repeat(
+        np.arange(len(searches)) * K - (np.cumsum(counts) - counts), counts))
+    cand_cq = np.full(S * K, -1, dtype=np.int32)
+    cand_delta = np.zeros((S * K, layout.F), dtype=np.int32)
+    cand_other = np.zeros(S * K, dtype=bool)
+    cand_above = np.zeros(S * K, dtype=bool)
+
+    queue_at, others, priorities = [], [], []
+    by_frs: dict[tuple, list[int]] = {}
+    for si, (cands, _) in enumerate(searches):
+        at = [locate(si, name) for name in cands.queues]
+        queue_at.append(np.array([-1 if q is None else q for q in at],
+                                 dtype=np.int32)[cands.queue])
+        others.append(cands.own)
+        priorities.append(cands.priority)
+        by_frs.setdefault(cands.frs, []).append(si)
+    cand_cq[flat] = np.concatenate(queue_at)
+    if (cand_cq[flat] < 0).any():
+        return None
+    cand_other[flat] = ~np.concatenate(others)
+    # a search without a threshold has none of its candidates above it
+    cand_above[flat] = np.concatenate(priorities) >= np.repeat(
+        np.array([np.iinfo(np.int64).max if thr is None else thr
+                  for _, thr in searches], dtype=np.int64), counts)
+    # one scaling a set of columns: the searches of a launch are of
+    # queues over the same flavors, so as a rule the launch has one
+    ends = np.cumsum(counts)
+    for frs, members in by_frs.items():
+        vec, ok = layout.scaled(
+            frs, np.concatenate([searches[si][0].raw for si in members]),
+            np.concatenate([searches[si][0].has for si in members]))
+        if not ok.all():
+            return None
+        cand_delta[flat if len(members) == len(searches) else np.concatenate(
+            [flat[ends[si] - counts[si]:ends[si]] for si in members])] = vec
+    return (cand_cq.reshape(S, K), cand_delta.reshape(S, K, layout.F),
+            cand_other.reshape(S, K), cand_above.reshape(S, K))
+
+
 def _pack_batch(specs, packed, stats: Optional[dict]):
     """The numpy planes of one batched launch (the kernel's positional
     arguments, their real and padded candidate slots counted), or None
     with the refusal counted.  It packs what it is given: S and K are
     the rungs of this launch's own spec count and longest candidate
     list, so the planes are as large as the plan's grouping made
-    them."""
+    them.  The candidates' planes are gathers from the columns the
+    specs' ``Candidates`` hold, the whole launch at once."""
     if packed is None or not packed.exact or not specs:
         return _refused(stats, "unpackable")
     planes = _planes_for(packed)
     if planes is None:
         return _refused(stats, "unpackable")
-    cq_idx = {n: i for i, n in enumerate(packed.cq_names)}
-    F = packed.usage0.shape[1]
-    scale_of = {r: int(packed.resource_scale[i])
-                for i, r in enumerate(packed.resource_names)}
-
-    def to_f_vec(frq) -> Optional[np.ndarray]:
-        vec = np.zeros(F, dtype=np.int64)
-        for fr, v in frq.items():
-            fi = packed.fr_index.get(fr)
-            if fi is None:
-                return None
-            s = scale_of[fr.resource]
-            if v % s:
-                return None
-            vec[fi] += v // s
-        if vec.max(initial=0) > 2**31 - 1:
-            return None
-        return vec.astype(np.int32)
+    cq_idx = packed.structure.cq_index
+    layout = layout_for(packed)
+    F = layout.F
 
     # coarse shape ladders: each distinct (S, K) combination is one XLA
     # compilation — a handful of rungs covers every cycle, and warmup
@@ -247,33 +338,20 @@ def _pack_batch(specs, packed, stats: Optional[dict]):
         return _refused(stats, "over_s")
     S = coarse_bucket(len(specs), S_LADDER)
     K = coarse_bucket(max_cands, K_LADDER)
-    NL = planes.NL
+    n = len(specs)
     usage_planes = planes.usage_planes(packed.usage0)     # [G, NL, F]
     forest_of = np.zeros(S, dtype=np.int32)
     pre_cq = np.full(S, -1, dtype=np.int32)
     wl_usage = np.zeros((S, F), dtype=np.int32)
     frs_mask = np.zeros((S, F), dtype=bool)
-    cand_cq = np.full((S, K), -1, dtype=np.int32)
-    cand_delta = np.zeros((S, K, F), dtype=np.int32)
-    cand_other = np.zeros((S, K), dtype=bool)
-    cand_above = np.zeros((S, K), dtype=bool)
     allow_b0 = np.zeros(S, dtype=bool)
     thr_en = np.zeros(S, dtype=bool)
-    # target-usage vectors dedupe across specs (the same admitted
-    # workload is a candidate for many preemptors)
-    vec_cache: dict[str, Optional[np.ndarray]] = {}
 
-    for si, (ctx, candidates, allow_borrowing, threshold) in enumerate(specs):
+    for si, (ctx, _, allow_borrowing, threshold) in enumerate(specs):
         ci = cq_idx.get(ctx.preemptor_cq.name)
         if ci is None or ci not in planes.local:
             return _refused(stats, "unpackable")
-        f, ci_local = planes.local[ci]
-        wu = to_f_vec(ctx.workload_usage)
-        if wu is None:
-            return _refused(stats, "unpackable")
-        forest_of[si] = f
-        pre_cq[si] = ci_local
-        wl_usage[si] = wu
+        forest_of[si], pre_cq[si] = planes.local[ci]
         for fr in ctx.frs_need_preemption:
             fi = packed.fr_index.get(fr)
             if fi is None:
@@ -281,25 +359,21 @@ def _pack_batch(specs, packed, stats: Optional[dict]):
             frs_mask[si, fi] = True
         allow_b0[si] = allow_borrowing
         thr_en[si] = threshold is not None
-        for k, cand in enumerate(candidates):
-            cci = cq_idx.get(cand.cluster_queue)
-            if cci is None:
-                return _refused(stats, "unpackable")
-            cf_local = planes.local.get(cci)
-            if cf_local is None or cf_local[0] != f:
-                # candidate outside the preemptor's forest
-                return _refused(stats, "unpackable")
-            delta = vec_cache.get(cand.key)
-            if delta is None and cand.key not in vec_cache:
-                delta = to_f_vec(cand.usage())
-                vec_cache[cand.key] = delta
-            if delta is None:
-                return _refused(stats, "unpackable")
-            cand_cq[si, k] = cf_local[1]
-            cand_delta[si, k] = delta
-            cand_other[si, k] = cand.cluster_queue != ctx.preemptor_cq.name
-            cand_above[si, k] = (threshold is not None
-                                 and cand.obj.priority >= threshold)
+    wl_usage[:n], ok = layout.scaled_usages(
+        [ctx.workload_usage for ctx, _, _, _ in specs])
+    if not ok.all():
+        return _refused(stats, "unpackable")
+
+    def locate(si: int, name: str) -> Optional[int]:
+        """The queue's place in the head's forest; outside it, None."""
+        f, local = planes.local.get(cq_idx.get(name), (-1, None))
+        return local if f == forest_of[si] else None
+
+    cand = _candidate_planes([(c, thr) for _, c, _, thr in specs],
+                             K, S, layout, locate)
+    if cand is None:
+        return _refused(stats, "unpackable")
+    cand_cq, cand_delta, cand_other, cand_above = cand
 
     if stats is not None:
         stats["search_candidate_slots"] += sum(
@@ -312,27 +386,25 @@ def _pack_batch(specs, packed, stats: Optional[dict]):
             cand_above, allow_b0, thr_en)
 
 
+def _targets(candidates, mask: np.ndarray, threshold: Optional[int]):
+    """The Targets a search's mask picks of its candidates."""
+    from ..scheduler.preemption import Target  # circular-safe import
+    targets = []
+    for k in np.flatnonzero(mask[:len(candidates)]).tolist():
+        if candidates.own[k]:
+            reason = IN_CLUSTER_QUEUE_REASON
+        elif threshold is not None and candidates.priority[k] < threshold:
+            reason = IN_COHORT_RECLAIM_WHILE_BORROWING_REASON
+        else:
+            reason = IN_COHORT_RECLAMATION_REASON
+        targets.append(Target(info=candidates[k], reason=reason))
+    return targets
+
+
 def _decode_batch(specs, fitted, mask):
     """The launch's masks as per-spec Target lists ([] = no fit)."""
-    from ..scheduler.preemption import Target  # circular-safe import
-    out = []
-    for si, (ctx, candidates, _, threshold) in enumerate(specs):
-        if not fitted[si]:
-            out.append([])
-            continue
-        targets = []
-        for k, cand in enumerate(candidates):
-            if not mask[si, k]:
-                continue
-            if cand.cluster_queue == ctx.preemptor_cq.name:
-                reason = IN_CLUSTER_QUEUE_REASON
-            elif threshold is not None and cand.obj.priority < threshold:
-                reason = IN_COHORT_RECLAIM_WHILE_BORROWING_REASON
-            else:
-                reason = IN_COHORT_RECLAMATION_REASON
-            targets.append(Target(info=cand, reason=reason))
-        out.append(targets)
-    return out
+    return [_targets(candidates, mask[si], threshold) if fitted[si] else []
+            for si, (_, candidates, _, threshold) in enumerate(specs)]
 
 
 def device_minimal_preemptions(ctx, candidates, allow_borrowing: bool,
@@ -344,63 +416,33 @@ def device_minimal_preemptions(ctx, candidates, allow_borrowing: bool,
     the admission solver's cached-structure pack) avoids re-packing per
     search.  Returns a list of Targets, [] (search failed), or None
     (unsupported — run the host path)."""
-    from ..scheduler.preemption import Target  # circular-safe import
-
-    if not candidates:
+    if not len(candidates):
         return []
     if packed is None:
         packed = pack_cycle(ctx.snapshot, [])
     if packed is None or not packed.exact:
         return None
-    cq_idx = {n: i for i, n in enumerate(packed.cq_names)}
+    cq_idx = packed.structure.cq_index
     pre_cq = cq_idx.get(ctx.preemptor_cq.name)
     if pre_cq is None:
         return None
-    F = packed.usage0.shape[1]
-    scale_of = {r: int(packed.resource_scale[i])
-                for i, r in enumerate(packed.resource_names)}
-
-    def to_f_vec(frq) -> Optional[np.ndarray]:
-        vec = np.zeros(F, dtype=np.int64)
-        for fr, v in frq.items():
-            fi = packed.fr_index.get(fr)
-            if fi is None:
-                return None
-            s = scale_of[fr.resource]
-            if v % s:
-                return None
-            vec[fi] += v // s
-        if vec.max(initial=0) > 2**31 - 1:
-            return None
-        return vec.astype(np.int32)
-
-    wl_usage = to_f_vec(ctx.workload_usage)
-    if wl_usage is None:
+    layout = layout_for(packed)
+    (wl_usage,), ok = layout.scaled_usages([ctx.workload_usage])
+    if not ok.all():
         return None
-    frs_mask = np.zeros(F, dtype=bool)
+    frs_mask = np.zeros(layout.F, dtype=bool)
     for fr in ctx.frs_need_preemption:
         fi = packed.fr_index.get(fr)
         if fi is None:
             return None
         frs_mask[fi] = True
 
-    K = _bucket(len(candidates))
-    cand_cq = np.full(K, -1, dtype=np.int32)
-    cand_delta = np.zeros((K, F), dtype=np.int32)
-    cand_other = np.zeros(K, dtype=bool)
-    cand_above = np.zeros(K, dtype=bool)
-    for i, cand in enumerate(candidates):
-        ci = cq_idx.get(cand.cluster_queue)
-        if ci is None:
-            return None
-        delta = to_f_vec(cand.usage())
-        if delta is None:
-            return None
-        cand_cq[i] = ci
-        cand_delta[i] = delta
-        cand_other[i] = cand.cluster_queue != ctx.preemptor_cq.name
-        cand_above[i] = (threshold is not None
-                         and cand.obj.priority >= threshold)
+    cand = _candidate_planes([(candidates, threshold)],
+                             _bucket(len(candidates)), 1, layout,
+                             lambda si, name: cq_idx.get(name))
+    if cand is None:
+        return None
+    cand_cq, cand_delta, cand_other, cand_above = (p[0] for p in cand)
 
     fitted, target_mask = minimal_preemptions(
         packed.usage0, packed.subtree_quota, packed.guaranteed,
@@ -414,16 +456,4 @@ def device_minimal_preemptions(ctx, candidates, allow_borrowing: bool,
             stats["accel_searches"] += 1
     if not bool(fitted):
         return []
-    mask = np.asarray(target_mask)
-    targets = []
-    for i, cand in enumerate(candidates):
-        if not mask[i]:
-            continue
-        if not cand_other[i]:
-            reason = IN_CLUSTER_QUEUE_REASON
-        elif threshold is not None and cand.obj.priority < threshold:
-            reason = IN_COHORT_RECLAIM_WHILE_BORROWING_REASON
-        else:
-            reason = IN_COHORT_RECLAMATION_REASON
-        targets.append(Target(info=cand, reason=reason))
-    return targets
+    return _targets(candidates, np.asarray(target_mask), threshold)

@@ -739,24 +739,6 @@ class CycleSolver:
         F = packed.usage0.shape[1]
         universe: list = []
         uni_idx: dict[str, int] = {}
-        scale_of = {r: int(st.resource_scale[i])
-                    for i, r in enumerate(st.resource_names)}
-
-        def to_f_vec(frq) -> Optional[np.ndarray]:
-            vec = np.zeros(F, dtype=np.int64)
-            for fr, v in frq.items():
-                fi = st.fr_index.get(fr)
-                if fi is None:
-                    return None
-                s = scale_of[fr.resource]
-                if v % s:
-                    return None
-                vec[fi] += v // s
-            if vec.max(initial=0) > 2**31 - 1:
-                return None
-            return vec.astype(np.int32)
-
-        deltas: list[np.ndarray] = []
         cqs: list[int] = []
         per_wi: dict[int, list[int]] = {}
         for wi, targets in targets_by_wi.items():
@@ -768,17 +750,18 @@ class CycleSolver:
                     ci = st.cq_index.get(t.info.cluster_queue)
                     if ci is None:
                         return None
-                    delta = to_f_vec(t.info.usage())
-                    if delta is None:
-                        return None
                     ti = len(universe)
                     uni_idx[key] = ti
                     universe.append(t.info)
-                    deltas.append(delta)
                     cqs.append(ci)
                 idxs.append(ti)
             per_wi[wi] = idxs
 
+        from .preemption_solver import layout_for
+        deltas, exact = layout_for(packed).scaled_usages(
+            [info.usage() for info in universe])
+        if not exact.all():
+            return None
         n_universe = max(1, len(universe))
         n_per_head = max(1, max(len(v) for v in per_wi.values()))
         if n_universe > T_LADDER[-1] or n_per_head > MT_LADDER[-1]:
@@ -788,8 +771,7 @@ class CycleSolver:
         tu_cq = np.zeros(T, dtype=np.int32)
         tu_delta = np.zeros((T, F), dtype=np.int32)
         tu_cq[:len(cqs)] = cqs
-        if deltas:
-            tu_delta[:len(deltas)] = np.stack(deltas)
+        tu_delta[:len(universe)] = deltas
         tgt_mat = np.full((W, MT), -1, dtype=np.int32)
         preempt_mask = np.zeros(W, dtype=bool)
         for wi, idxs in per_wi.items():

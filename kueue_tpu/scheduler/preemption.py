@@ -12,7 +12,9 @@ from __future__ import annotations
 
 import time
 from dataclasses import dataclass, field
-from typing import Callable, Optional
+from typing import Callable, Iterator, Optional
+
+import numpy as np
 
 from ..api.types import (
     BorrowWithinCohortPolicy,
@@ -26,6 +28,7 @@ from ..api.types import (
     WL_EVICTED,
     WL_QUOTA_RESERVED,
 )
+from ..cache.candidates import TableTally
 from ..cache.snapshot import Snapshot
 from ..cache.state import CQState
 from ..obs.trace import span as _span
@@ -60,22 +63,127 @@ HUMAN_READABLE_REASONS = {
 }
 
 
-def _quota_reservation_time(info: Info, now: float) -> float:
-    c = info.obj.conditions.get(WL_QUOTA_RESERVED)
-    if c is None or c.status != ConditionStatus.TRUE:
-        return now
-    return c.last_transition_time
+class Candidates:
+    """A head's preemption candidates in candidatesOrdering's order, as
+    columns gathered from the candidate tables of its own queue and of
+    its cohort's borrowing queues (cache/candidates.py).  A sequence of
+    ``Info``: ``len`` is what the launch plan reads, iteration what the
+    host search and fair sharing walk; the device search takes its
+    planes from the columns."""
+
+    __slots__ = ("infos", "priority", "own", "queue", "queues", "frs",
+                 "raw", "has")
+
+    def __init__(self, infos, priority, own, queue, queues, frs, raw, has):
+        self.infos = infos          # [k] object, the rows' Infos
+        self.priority = priority    # [k] int64
+        self.own = own              # [k] bool: of the head's own queue
+        self.queue = queue          # [k] index into ``queues``
+        self.queues = queues        # names of the queues the rows are of
+        self.frs = frs              # the columns of raw and has
+        self.raw = raw              # [k, len(frs)] int64 usage, unscaled
+        self.has = has              # [k, len(frs)] bool: usage() has the key
+
+    def __len__(self) -> int:
+        return self.infos.shape[0]
+
+    def __iter__(self) -> Iterator[Info]:
+        return iter(self.infos.tolist())
+
+    def __getitem__(self, k: int) -> Info:
+        return self.infos[k]
+
+    def take(self, keep: np.ndarray) -> "Candidates":
+        """The candidates ``keep`` ([k] bool) picks, in their order."""
+        return Candidates(self.infos[keep], self.priority[keep],
+                          self.own[keep], self.queue[keep], self.queues,
+                          self.frs, self.raw[keep], self.has[keep])
 
 
-def candidates_ordering_key(cq_name: str, now: float):
-    """reference preemption.go:591 candidatesOrdering: evicted first, then
-    other-CQ borrowers, then lower priority, then later admission."""
-    def key(info: Info):
-        evicted = 0 if info.obj.condition_true(WL_EVICTED) else 1
-        in_cq = 1 if info.cluster_queue == cq_name else 0
-        return (evicted, in_cq, info.obj.priority,
-                -_quota_reservation_time(info, now), info.obj.uid)
-    return key
+NO_CANDIDATES = Candidates(
+    np.zeros(0, dtype=object), np.zeros(0, dtype=np.int64),
+    np.zeros(0, dtype=bool), np.zeros(0, dtype=np.intp), [], (),
+    np.zeros((0, 0), dtype=np.int64), np.zeros((0, 0), dtype=bool))
+
+
+class _Part:
+    """The rows one queue's candidate table gives a search, in
+    candidatesOrdering's order among themselves, with the two keys of
+    that order that are read off the ``Info``s."""
+
+    __slots__ = ("queue", "frs", "infos", "uid", "priority", "seq", "raw",
+                 "has", "not_evicted", "reserved")
+
+    def __init__(self, cq: CQState, rows: np.ndarray, now: float):
+        """``rows`` of ``cq``'s table.  The Evicted condition and the
+        quota reservation time are read here, off the chosen rows'
+        ``Info``s: both change under a workload while it stays in its
+        queue, so no column could hold them."""
+        table = cq.candidates
+        infos = table.infos[rows]
+        conditions = [info.obj.conditions for info in infos.tolist()]
+        reserved = np.array([
+            now if (c := conds.get(WL_QUOTA_RESERVED)) is None
+            or c.status != ConditionStatus.TRUE else c.last_transition_time
+            for conds in conditions], dtype=np.float64)
+        not_evicted = np.array([
+            (c := conds.get(WL_EVICTED)) is None
+            or c.status != ConditionStatus.TRUE for conds in conditions],
+            dtype=bool)
+        order = np.lexsort((table.seq[rows], table.uid[rows], -reserved,
+                            table.priority[rows], not_evicted))
+        rows = rows[order]
+        self.queue = cq.name
+        self.frs = table.frs
+        self.infos = infos[order]
+        self.not_evicted = not_evicted[order]
+        self.reserved = reserved[order]
+        for name in ("uid", "priority", "seq", "raw", "has"):
+            setattr(self, name, getattr(table, name)[rows])
+
+    def over(self, frs: tuple, name: str) -> np.ndarray:
+        """``raw`` or ``has`` over the columns ``frs``, a superset."""
+        plane = getattr(self, name)
+        if self.frs == frs:
+            return plane
+        wide = np.zeros((len(plane), len(frs)), dtype=plane.dtype)
+        wide[:, [frs.index(fr) for fr in self.frs]] = plane
+        return wide
+
+
+def _ordered(parts: list[_Part], own: Optional[_Part]) -> Candidates:
+    """The candidates of ``parts``, a part a queue and ``own`` the one
+    of the head's own, in the order of reference preemption.go:591
+    candidatesOrdering: evicted first, then other queues' before the
+    head's own, then lower priority, then later quota reservation, then
+    uid; what is left equal stays in the order the queues and their
+    dicts gave it.  A part is in that order already, so one part alone
+    is the answer as it stands."""
+    queues = [part.queue for part in parts]
+    if len(parts) == 1:
+        (part,) = parts
+        k = len(part.infos)
+        return Candidates(part.infos, part.priority,
+                          np.full(k, part is own, dtype=bool),
+                          np.zeros(k, dtype=np.intp), queues, part.frs,
+                          part.raw, part.has)
+    frs = parts[0].frs
+    if any(part.frs != frs for part in parts):
+        frs = tuple(sorted({fr for part in parts for fr in part.frs}))
+    infos, uid, priority, seq, not_evicted, reserved = (
+        np.concatenate([getattr(part, name) for part in parts])
+        for name in ("infos", "uid", "priority", "seq", "not_evicted",
+                     "reserved"))
+    queue = np.repeat(np.arange(len(parts)),
+                      [len(part.infos) for part in parts])
+    is_own = queue == next(
+        (qi for qi, part in enumerate(parts) if part is own), -1)
+    order = np.lexsort((seq, queue, uid, -reserved, priority, is_own,
+                        not_evicted))
+    return Candidates(
+        infos[order], priority[order], is_own[order], queue[order], queues,
+        frs, np.concatenate([p.over(frs, "raw") for p in parts])[order],
+        np.concatenate([p.over(frs, "has") for p in parts])[order])
 
 
 def flavor_resources_need_preemption(assignment: Assignment) -> set[FlavorResource]:
@@ -86,14 +194,6 @@ def flavor_resources_need_preemption(assignment: Assignment) -> set[FlavorResour
             if fa.mode == Mode.PREEMPT:
                 out.add(FlavorResource(fa.name, res))
     return out
-
-
-def _workload_uses_resources(info: Info, frs: set[FlavorResource]) -> bool:
-    for psr in info.total_requests:
-        for res, flavor in psr.flavors.items():
-            if FlavorResource(flavor, res) in frs:
-                return True
-    return False
 
 
 def _cq_is_borrowing(cq: CQState, frs: set[FlavorResource]) -> bool:
@@ -157,7 +257,20 @@ class Preemptor:
                       # real candidates in the batched launches, and the
                       # S x K slots of the buckets they were padded to
                       "search_candidate_slots": 0,
-                      "search_padded_slots": 0}
+                      "search_padded_slots": 0,
+                      # the candidate tables (cache/candidates.py): rows
+                      # the candidate queries read, and rows written
+                      # into the tables since (appends; a queue's rows
+                      # are built once, as its workloads are added)
+                      "search_table_rows_scanned": 0,
+                      "search_table_rows_built": 0}
+        # the tally of the tables queried, their cache's, and the rows
+        # it had counted when search_table_rows_built last took them
+        self._tally: Optional[TableTally] = None
+        self._tally_seen = 0
+        # while a batch's candidates are found: (queue, flavor-resources,
+        # priority bound) -> the part it gave (_queue_part)
+        self._parts_memo: Optional[dict] = None
 
     def set_cycle_pack(self, snapshot: Snapshot, packed) -> None:
         """Thread the admission solver's cached pack for this cycle's
@@ -192,8 +305,6 @@ class Preemptor:
         candidates = self._find_candidates(ctx)
         if not candidates:
             return []
-        candidates.sort(key=candidates_ordering_key(ctx.preemptor_cq.name,
-                                                    self.clock()))
         if self.enable_fair_sharing:
             return self._fair_preemptions(ctx, candidates)
 
@@ -205,8 +316,8 @@ class Preemptor:
         cands, ab, thr = specs[1]  # queue-under-nominal retry
         return self._minimal_preemptions(ctx, cands, ab, thr)
 
-    def plan_searches(self, ctx: _PreemptionCtx, candidates: list[Info]
-                      ) -> tuple[list[tuple[list[Info], bool, Optional[int]]],
+    def plan_searches(self, ctx: _PreemptionCtx, candidates: Candidates
+                      ) -> tuple[list[tuple[Candidates, bool, Optional[int]]],
                                  bool]:
         """The minimalPreemptions calls _get_targets will issue, computed
         UPFRONT (every branch condition is snapshot-state only) so a
@@ -216,19 +327,16 @@ class Preemptor:
         threshold)]; staged=True → use spec 0's result if it fitted,
         else spec 1's (the queue-under-nominal retry,
         preemption.go:144-191)."""
-        same_queue = [c for c in candidates
-                      if c.cluster_queue == ctx.preemptor_cq.name]
-
-        if len(same_queue) == len(candidates):
+        if candidates.own.all():
             # no cross-queue candidates: try borrowing
             return [(candidates, True, None)], False
+        same_queue = candidates.take(candidates.own)
 
         borrow_ok, threshold = self._can_borrow_within_cohort(ctx)
         if borrow_ok:
             if not self._queue_under_nominal(ctx):
-                candidates = [c for c in candidates
-                              if c.cluster_queue == ctx.preemptor_cq.name
-                              or c.obj.priority < threshold]
+                candidates = candidates.take(
+                    candidates.own | (candidates.priority < threshold))
             return [(candidates, True, threshold)], False
 
         if self._queue_under_nominal(ctx):
@@ -293,6 +401,7 @@ class Preemptor:
         pack, or an unpackable spec in any launch (decision-identical
         either way)."""
         packed = self._pack_for(snapshot)
+
         def each_head():
             """One search (and one candidate discovery) a context."""
             with _span("cycle.nominate.search_fallback"):
@@ -305,19 +414,21 @@ class Preemptor:
         flat_specs: list[tuple] = []
         plans: list[tuple[list[int], bool]] = []
         with _span("cycle.nominate.candidates"):
-            for ctx in ctxs:
-                candidates = self._find_candidates(ctx)
-                if not candidates:
-                    plans.append(([], False))
-                    continue
-                candidates.sort(key=candidates_ordering_key(
-                    ctx.preemptor_cq.name, self.clock()))
-                specs, staged = self.plan_searches(ctx, candidates)
-                idxs = []
-                for cands, ab, thr in specs:
-                    idxs.append(len(flat_specs))
-                    flat_specs.append((ctx, cands, ab, thr))
-                plans.append((idxs, staged))
+            self._parts_memo = {}
+            try:
+                for ctx in ctxs:
+                    candidates = self._find_candidates(ctx)
+                    if not candidates:
+                        plans.append(([], False))
+                        continue
+                    specs, staged = self.plan_searches(ctx, candidates)
+                    idxs = []
+                    for cands, ab, thr in specs:
+                        idxs.append(len(flat_specs))
+                        flat_specs.append((ctx, cands, ab, thr))
+                    plans.append((idxs, staged))
+            finally:
+                self._parts_memo = None
 
         # the launch plan follows each spec's size: one with no
         # candidate is answered here, one over the K ladder's top rung
@@ -400,39 +511,88 @@ class Preemptor:
     # Candidates — reference preemption.go:480 findCandidates
     # ------------------------------------------------------------------
 
-    def _find_candidates(self, ctx: _PreemptionCtx) -> list[Info]:
+    def _find_candidates(self, ctx: _PreemptionCtx) -> Candidates:
+        """The head's candidates, in order: a mask over its own queue's
+        candidate table and over those of its cohort's borrowing
+        queues, a part each (``_queue_part``), then one sort
+        (``_ordered``)."""
         cq = ctx.preemptor_cq
         wl = ctx.preemptor
-        candidates: list[Info] = []
+        frs = ctx.frs_need_preemption
         wl_priority = wl.obj.priority
+        parts: list[_Part] = []
+        own = None
 
-        if cq.preemption.within_cluster_queue != WithinClusterQueue.NEVER:
-            consider_same_prio = (cq.preemption.within_cluster_queue
-                                  == WithinClusterQueue.LOWER_OR_NEWER_EQUAL_PRIORITY)
-            preemptor_ts = self.ordering.queue_order_timestamp(wl.obj)
-            for cand in cq.workloads.values():
-                if cand.obj.priority > wl_priority:
-                    continue
-                if cand.obj.priority == wl_priority and not (
-                        consider_same_prio and preemptor_ts
-                        < self.ordering.queue_order_timestamp(cand.obj)):
-                    continue
-                if not _workload_uses_resources(cand, ctx.frs_need_preemption):
-                    continue
-                candidates.append(cand)
+        within = cq.preemption.within_cluster_queue
+        if within != WithinClusterQueue.NEVER:
+            own = self._queue_part(
+                cq, frs, wl_priority, equal_if_older=wl.obj if within
+                == WithinClusterQueue.LOWER_OR_NEWER_EQUAL_PRIORITY else None)
+            if own is not None:
+                parts.append(own)
 
         if cq.has_parent() and cq.preemption.reclaim_within_cohort != ReclaimWithinCohort.NEVER:
-            only_lower = cq.preemption.reclaim_within_cohort != ReclaimWithinCohort.ANY
+            below = (None if cq.preemption.reclaim_within_cohort
+                     == ReclaimWithinCohort.ANY else wl_priority)
             for cohort_cq in cq.parent.root().subtree_cqs():
-                if cohort_cq is cq or not _cq_is_borrowing(cohort_cq, ctx.frs_need_preemption):
+                if cohort_cq is cq or not _cq_is_borrowing(cohort_cq, frs):
                     continue
-                for cand in cohort_cq.workloads.values():
-                    if only_lower and cand.obj.priority >= wl_priority:
-                        continue
-                    if not _workload_uses_resources(cand, ctx.frs_need_preemption):
-                        continue
-                    candidates.append(cand)
-        return candidates
+                part = self._queue_part(cohort_cq, frs, below)
+                if part is not None:
+                    parts.append(part)
+
+        self._count_rows_built(cq.candidates.tally)
+        if not parts:
+            return NO_CANDIDATES
+        return _ordered(parts, own)
+
+    def _queue_part(self, cq: CQState, frs: set[FlavorResource],
+                    below: Optional[int], equal_if_older=None
+                    ) -> Optional[_Part]:
+        """The rows of ``cq``'s table that use one of ``frs`` and, with
+        ``below``, have a lower priority; None where there is none.
+        With ``equal_if_older``, the head's Workload under
+        LowerOrNewerEqualPriority, an equal priority falls to the head
+        too if the head queued first: the queue-order timestamp is a
+        condition's to change, so it is read off the ``Info``s.
+
+        A batch of searches is against one snapshot at one time, so
+        there the answer is kept for the next head that asks the same:
+        the heads of a cohort ask it of each borrowing queue."""
+        memo = self._parts_memo if equal_if_older is None else None
+        if memo is not None:
+            key = (cq.name, frozenset(frs), below)
+            if key in memo:
+                return memo[key]
+        table = cq.candidates
+        self.stats["search_table_rows_scanned"] += table.n
+        part = None
+        keep = table.using(frs)
+        if keep is not None:
+            if below is not None:
+                priority = table.priority[:table.n]
+                same = (np.flatnonzero(keep & (priority == below))
+                        if equal_if_older is not None else ())
+                keep = keep & (priority < below)
+                if len(same):
+                    ts = self.ordering.queue_order_timestamp
+                    head_ts = ts(equal_if_older)
+                    keep[same[[head_ts < ts(info.obj) for info
+                               in table.infos[same].tolist()]]] = True
+            rows = np.flatnonzero(keep)
+            if rows.size:
+                part = _Part(cq, rows, self.clock())
+        if memo is not None:
+            memo[key] = part
+        return part
+
+    def _count_rows_built(self, tally: TableTally) -> None:
+        """Bring ``search_table_rows_built`` up to what the tables'
+        tally, their cache's, has counted."""
+        if tally is not self._tally:
+            self._tally, self._tally_seen = tally, 0
+        self.stats["search_table_rows_built"] += tally.built - self._tally_seen
+        self._tally_seen = tally.built
 
     # ------------------------------------------------------------------
     # Minimal preemptions — reference preemption.go:275-342
@@ -453,7 +613,7 @@ class Preemptor:
         revert()
         return res
 
-    def _minimal_preemptions(self, ctx: _PreemptionCtx, candidates: list[Info],
+    def _minimal_preemptions(self, ctx: _PreemptionCtx, candidates: Candidates,
                              allow_borrowing: bool,
                              allow_borrowing_below_priority: Optional[int]
                              ) -> list[Target]:

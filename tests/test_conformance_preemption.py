@@ -365,10 +365,15 @@ def test_reclaim_quota_from_lender(use_device):
 def test_candidates_ordering():
     """Transliterates the reference's ordering table exactly: evicted
     first, then other-CQ, then lower priority, then later admission,
-    then uid."""
-    from kueue_tpu.scheduler.preemption import candidates_ordering_key
+    then uid.  The key written out (tests/candidate_reference.py) and
+    the preemptor's one sort over the queues' candidate tables give
+    the same order."""
+    import numpy as np
+    from kueue_tpu.cache.state import CQState
+    from kueue_tpu.scheduler.preemption import _ordered, _Part
     from kueue_tpu.workload import (WL_EVICTED, Condition, ConditionStatus,
                                     Info)
+    from tests.candidate_reference import candidates_ordering_key
 
     now = 1000.0
 
@@ -393,7 +398,15 @@ def test_candidates_ordering():
         info("old-b"),
         info("current", at=now + 1.0),
     ]
+    queues = {name: CQState(ClusterQueue(name=name))
+              for name in ("self", "other")}
+    for c in candidates:
+        queues[c.cluster_queue or "self"].add_workload(c)
+    parts = [_Part(q, np.arange(q.candidates.n), now)
+             for q in queues.values()]
+    from_tables = _ordered(parts, parts[0])
     candidates.sort(key=candidates_ordering_key("self", now))
     got = [c.obj.name for c in candidates]
     assert got == ["evicted", "other", "low", "current", "old-a",
                    "old-b", "high"], got
+    assert [c.obj.name for c in from_tables] == got
