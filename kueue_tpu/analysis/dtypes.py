@@ -34,7 +34,8 @@ PLANE_SCHEMA: dict[str, str] = {
     # streamed row planes (_ROW_PLANES)
     "wl_req": "int32", "wl_rank": "int32", "wl_cycle_rank": "int32",
     "wl_prio": "int32", "wl_uidrank": "int32",
-    "vec_ok": "bool", "elig0": "bool", "parked0": "bool",
+    "vec_ok": "bool", "wl_flavor_skip": "uint8",
+    "elig0": "bool", "parked0": "bool",
     "resume0": "int32", "adm0": "bool", "adm_seq0": "int32",
     "adm_usage0": "int32", "adm_uses0": "bool", "death0": "int32",
     # arena extras

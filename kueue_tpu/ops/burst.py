@@ -39,7 +39,9 @@ Anything the fused math can't decide bit-identically makes the cycle
 walk neither policy-stopped on the preempt slot nor left it as the only
 preempt-capable choice — the host's pick then depends on the reclaim
 oracle), or a head outside the vectorized classify's coverage (multi-RG
-/ multi-PodSet / taints / TAS / partial admission — ``vec_ok`` False).
+/ multi-PodSet / TAS / partial admission — ``vec_ok`` False).  Node
+labels, taints, selectors and tolerations stay inside it: each row
+carries the flavors its PodSet may not take (``wl_flavor_skip``).
 FlavorFungibility itself runs in-kernel: the classify step walks each
 head's flavor list from its carried resume start slot with the
 whenCanBorrow/whenCanPreempt stop rules and records the next start slot
@@ -74,6 +76,8 @@ from ..chaos import injector as _chaos
 from ..features import env_value
 from ..obs.trace import span as _span
 from .device import on_accelerator, output_devices, solver_device
+from .eligibility import (declares, mask_plane_width, skip_mask,
+                          slots_of_mask)
 
 INF_I32 = np.int32(2**31 - 1)
 I32_MAX = 2**31 - 1
@@ -110,6 +114,9 @@ def _burst_cycles(
     wl_prio,         # [C, M] int32 priority
     wl_uidrank,      # [C, M] int32 global uid rank (candidate tiebreak)
     vec_ok,          # [C, M] bool  vectorized-classify coverage
+    wl_flavor_skip,  # [C, M] uint8 bit s: the row's PodSet may not take
+                     #              slot s of its queue (ops/eligibility.py);
+                     #              [C, 1] zeros where every flavor is plain
     elig0,           # [C, M] bool  in the heap at burst start
     parked0,         # [C, M] bool  in the inadmissible lot at burst start
     resume0,         # [C, M] int32 flavor-walk start slot (fungibility
@@ -202,7 +209,7 @@ def _burst_cycles(
     sq_root = subtree[root_of_cq]            # [C, F]
     bit_w = (jnp.uint32(1) << jnp.arange(32, dtype=jnp.uint32))
     # per-CQ flavor-list length: vector-ok CQs have every rg flavor
-    # materialized as a valid slot (solver._cq_vector_ok), so the valid
+    # materialized as a valid slot (eligibility.bind_flavor_lists), so the valid
     # count IS len(rg.flavors) — the host walk's n_slots
     slot_cnt = jnp.sum(slot_valid, axis=1).astype(jnp.int32)   # [C]
 
@@ -285,9 +292,14 @@ def _burst_cycles(
         preempt_capable_r = ((req[:, None, :] <= nom)
                              | cq_can_preempt_borrow[:, None, None])
         res_nofit = relevant & (nofit_r | (~fit_r & ~preempt_capable_r))
+        # a slot the head may not take for a taint or a selector is
+        # visited and passed over, like one whose flavor does not exist
+        skip = wl_flavor_skip[
+            cidx, jnp.minimum(row, wl_flavor_skip.shape[1] - 1)]
+        slot_ok = slot_valid & slots_of_mask(skip, S, jnp)     # [C,S]
         fit_s = (jnp.all(jnp.where(relevant, fit_r, True), axis=2)
-                 & ~missing & slot_valid)                      # [C,S]
-        nofit_s = jnp.any(res_nofit, axis=2) | missing | ~slot_valid
+                 & ~missing & slot_ok)                         # [C,S]
+        nofit_s = jnp.any(res_nofit, axis=2) | missing | ~slot_ok
         preempt_s = ~fit_s & ~nofit_s
         borrow_r = jnp.where(relevant, use + req[:, None, :] > sq, False)
         borrows_s = jnp.any(borrow_r, axis=2) & has_parent_cq[:, None]
@@ -971,7 +983,7 @@ class _CQRows:
                  "n_pend", "n_adm", "n_comp", "comp_max_ts",
                  "keys", "uids", "prio", "ts",
                  "res_ts", "parked", "ok", "resume", "adm", "req",
-                 "usage", "uses", "u_row", "index_of_key", "infos")
+                 "skip", "usage", "uses", "u_row", "index_of_key", "infos")
 
     @property
     def n_rows(self) -> int:
@@ -1283,6 +1295,11 @@ def _pack_cq_rows(st, ci, pos, queues, cache, scheduler, assumed,
     adm[rec.n_pend:] = True
     rec.adm = adm
     rec.req = req_mat[:i]
+    # the flavors a row's PodSet may not take: all zero, and nothing to
+    # ask a row, unless a flavor of this queue carries labels or taints
+    rec.skip = (np.fromiter((skip_mask(info, st, ci) for info in infos),
+                            dtype=np.uint8, count=i)
+                if declares(st, ci) else np.zeros(i, dtype=np.uint8))
     rec.usage = usage_mat[:i]
     rec.uses = uses_mat[:i]
     rec.u_row = u_row
@@ -1317,7 +1334,7 @@ def _walk_records(st, queues, cache, scheduler, window):
 
 
 _ROW_ATTRS = ("adm", "prio", "ts", "res_ts", "parked", "ok",
-              "resume", "req", "usage", "uses", "keys", "uids")
+              "resume", "req", "skip", "usage", "uses", "keys", "uids")
 
 
 def _assemble_plan(st, records, cache, scheduler, min_m):
@@ -1378,6 +1395,7 @@ def _assemble_plan(st, records, cache, scheduler, min_m):
     wl_prio = np.zeros((C, M), dtype=np.int32)
     wl_uidrank = np.zeros((C, M), dtype=np.int32)
     vec_ok = np.zeros((C, M), dtype=bool)
+    wl_flavor_skip = np.zeros((C, mask_plane_width(st, M)), dtype=np.uint8)
     elig = np.zeros((C, M), dtype=bool)
     parked = np.zeros((C, M), dtype=bool)
     resume = np.zeros((C, M), dtype=np.int32)
@@ -1441,6 +1459,8 @@ def _assemble_plan(st, records, cache, scheduler, min_m):
     vec_ok[ci_a, mi_a] = ok_a
     resume[ci_a, mi_a] = resume_a
     wl_req[ci_a, mi_a] = req_all
+    if st.flavors_declared:
+        wl_flavor_skip[ci_a, mi_a] = fields["skip"]
     adm[ci_a, mi_a] = adm_a
     adm_seq[ci_a, mi_a] = seq_a
     adm_usage[ci_a, mi_a] = usage_all
@@ -1489,7 +1509,8 @@ def _assemble_plan(st, records, cache, scheduler, min_m):
     arrays = dict(
         wl_req=wl_req, wl_rank=wl_rank, wl_cycle_rank=wl_cycle_rank,
         wl_prio=wl_prio, wl_uidrank=wl_uidrank,
-        vec_ok=vec_ok, elig0=elig, parked0=parked, resume0=resume,
+        vec_ok=vec_ok, wl_flavor_skip=wl_flavor_skip,
+        elig0=elig, parked0=parked, resume0=resume,
         adm0=adm, adm_seq0=adm_seq, adm_usage0=adm_usage,
         adm_uses0=adm_uses, death0=death,
         u_cq0=u_cq, potential0=s.potential0,
@@ -1925,7 +1946,7 @@ class BurstSolver:
             out = burst_cycles(
                 a["wl_req"], a["wl_rank"], a["wl_cycle_rank"],
                 a["wl_prio"], a["wl_uidrank"], a["vec_ok"],
-                elig0, parked0, resume0,
+                a["wl_flavor_skip"], elig0, parked0, resume0,
                 adm0, adm_seq0, adm_usage0,
                 adm_uses0, death0, np.int32(seq_base),
                 u_cq0,
@@ -1963,7 +1984,7 @@ class BurstSolver:
         a = dict(a)
         state = list(state)
         rows = ("wl_req", "wl_rank", "wl_cycle_rank", "wl_prio",
-                "wl_uidrank", "vec_ok")
+                "wl_uidrank", "vec_ok", "wl_flavor_skip")
         batch: list[tuple] = []
 
         def flush():
@@ -2164,7 +2185,7 @@ class BurstSolver:
         out = fn(
             a["wl_req"], a["wl_rank"], a["wl_cycle_rank"],
             a["wl_prio"], a["wl_uidrank"], a["vec_ok"],
-            elig0, parked0, resume0,
+            a["wl_flavor_skip"], elig0, parked0, resume0,
             adm0, adm_seq0, adm_usage0,
             adm_uses0, death0, np.int32(seq_base),
             u_cq0,

@@ -53,7 +53,8 @@ def available_all_np(usage, subtree, guaranteed, borrow_cap, has_blim,
     return avail
 
 
-def classify_np(packed, avail0=None, potential0=None, start_slot=None):
+def classify_np(packed, avail0=None, potential0=None, start_slot=None,
+                eligible=None):
     """Vectorized nominate on the host: per-head slot classification.
 
     The per-head flavor walk (flavorassigner.go:499) is evaluated dense
@@ -65,6 +66,11 @@ def classify_np(packed, avail0=None, potential0=None, start_slot=None):
     occurrence wins), with a stop slot overriding any earlier best.
     ``start_slot`` [W] carries the fungibility resume index
     (last_tried_flavor_idx + 1); slots below it are never attempted.
+    ``eligible`` [W, S] is False where the head's PodSet may not take
+    the flavor for a taint or a selector (ops/eligibility.py): the walk
+    visits such a slot and passes on, as over a flavor that does not
+    exist; it is NoFit for that head, no stop, no preempt-capable slot
+    and nothing to ask the oracle about.
 
     Returns a dict of [W]-shaped arrays:
       fit_slot0     the walk's chosen Fit slot or -1
@@ -87,6 +93,7 @@ def classify_np(packed, avail0=None, potential0=None, start_slot=None):
                     (flavorassigner.go:692, preemption_oracle.go:40)
       walk_slots    [W] flavors the walk visited: up to its stop slot,
                     or the whole list from its start
+      walk_ineligible  [W] of them, the flavors the head may not take
     """
     st = packed.structure
     usage0 = packed.usage0
@@ -120,6 +127,10 @@ def classify_np(packed, avail0=None, potential0=None, start_slot=None):
     res_nofit = relevant & (nofit_r | (~fit_r & ~preempt_capable_r))
 
     slot_ok = st.slot_valid[cqs]
+    barred = np.zeros_like(slot_ok)
+    if eligible is not None:
+        barred = slot_ok & ~eligible
+        slot_ok = slot_ok & eligible
     fit_s = (np.all(np.where(relevant, fit_r, True), axis=2)
              & ~missing & slot_ok)                          # [W,S]
     nofit_s = np.any(res_nofit, axis=2) | missing | ~slot_ok
@@ -172,6 +183,8 @@ def classify_np(packed, avail0=None, potential0=None, start_slot=None):
                   & (req <= nom) & (use + req <= sq))
     last = np.where(has_stop, stop_idx + 1, st.slot_count_cq[cqs])
     walk_slots = np.where(valid, np.maximum(last - start, 0), 0)
+    visited = active_s & (np.arange(S)[None, :] < last[:, None])
+    walk_ineligible = np.where(valid, (barred & visited).sum(axis=1), 0)
 
     return {
         "fit_slot0": fit_slot0,
@@ -187,6 +200,7 @@ def classify_np(packed, avail0=None, potential0=None, start_slot=None):
         "slot_borrows": borrows_s,
         "oracle_ask": oracle_ask,
         "walk_slots": walk_slots.astype(np.int32),
+        "walk_ineligible": walk_ineligible.astype(np.int32),
         "avail0": avail0,
         "potential0": potential0,
     }
@@ -466,7 +480,7 @@ def solve_cycle(usage0, subtree, guaranteed, borrow_cap, has_blim, parent,
                 nominal_cq, slot_fr, slot_valid, cq_can_preempt_borrow,
                 wl_cq, wl_requests, wl_priority, wl_timestamp,
                 cq_wcb_borrow=None, cq_wcp_preempt=None, start_slot=None,
-                *, depth: int, run_scan: bool = True):
+                eligible=None, *, depth: int, run_scan: bool = True):
     """Returns (admitted[W] bool, slot[W] int32, borrows[W] bool,
     preempt_possible[W] bool, fit_slot0[W] int32, borrows0[W] bool).
 
@@ -479,7 +493,8 @@ def solve_cycle(usage0, subtree, guaranteed, borrow_cap, has_blim, parent,
     policy per CQ and ``start_slot`` [W] the fungibility resume index;
     omitted, the default policy (whenCanBorrow=Borrow,
     whenCanPreempt=TryNextFlavor) walks every slot from 0 — the legacy
-    classify surface."""
+    classify surface.  ``eligible`` [W, S] bars a head from the flavors
+    its PodSet may not take (classify_np); omitted, none is barred."""
     C = slot_fr.shape[0]
     W = wl_cq.shape[0]
     S = slot_fr.shape[1]
@@ -489,14 +504,17 @@ def solve_cycle(usage0, subtree, guaranteed, borrow_cap, has_blim, parent,
         cq_wcp_preempt = jnp.zeros(C, dtype=bool)
     if start_slot is None:
         start_slot = jnp.zeros(W, dtype=jnp.int32)
+    if eligible is None:
+        eligible = jnp.ones((W, S), dtype=bool)
 
     avail0 = available_all(usage0, subtree, guaranteed, borrow_cap, has_blim,
                            parent, depth)
     potential0 = available_all(jnp.zeros_like(usage0), subtree, guaranteed,
                                borrow_cap, has_blim, parent, depth)
 
-    def classify(wl_cq_i, req, start_i):
+    def classify(wl_cq_i, req, start_i, eligible_i):
         cq = jnp.maximum(wl_cq_i, 0)
+        slot_ok = slot_valid[cq] & eligible_i   # [S]
         frs = slot_fr[cq]                       # [S, R]
         frs_safe = jnp.maximum(frs, 0)
         covered = frs >= 0
@@ -515,8 +533,8 @@ def solve_cycle(usage0, subtree, guaranteed, borrow_cap, has_blim, parent,
         res_nofit = relevant & (nofit_r | (~fit_r & ~preempt_capable_r))
 
         fit = (jnp.all(jnp.where(relevant, fit_r, True), axis=1)
-               & ~missing & slot_valid[cq])     # [S]
-        nofit = jnp.any(res_nofit, axis=1) | missing | ~slot_valid[cq]
+               & ~missing & slot_ok)            # [S]
+        nofit = jnp.any(res_nofit, axis=1) | missing | ~slot_ok
         preempt = ~fit & ~nofit
         has_parent = parent[cq] >= 0
         borrow_r = jnp.where(relevant, use + req[None, :] > sq, False)
@@ -546,7 +564,7 @@ def solve_cycle(usage0, subtree, guaranteed, borrow_cap, has_blim, parent,
                 preempt_possible & valid)
 
     fit_slot0, borrows0, preempt0 = jax.vmap(classify)(
-        wl_cq, wl_requests, start_slot)
+        wl_cq, wl_requests, start_slot, eligible)
 
     if not run_scan:
         zeros_b = jnp.zeros(W, dtype=bool)
@@ -686,6 +704,7 @@ def solve_cycle_forests(usage0, subtree, guaranteed, borrow_cap, has_blim,
                         parent, nominal_cq, slot_fr, slot_valid,
                         cq_can_preempt_borrow, wl_cq, wl_requests,
                         wl_priority, wl_timestamp, forest_of_node,
+                        eligible=None,
                         *, depth: int, n_forests: int, max_forest_wl: int):
     """One-call phase 1 + forest-parallel admit scan (probe surface)."""
     W = wl_cq.shape[0]
@@ -693,7 +712,7 @@ def solve_cycle_forests(usage0, subtree, guaranteed, borrow_cap, has_blim,
         usage0, subtree, guaranteed, borrow_cap, has_blim, parent,
         nominal_cq, slot_fr, slot_valid, cq_can_preempt_borrow,
         wl_cq, wl_requests, wl_priority, wl_timestamp,
-        depth=depth, run_scan=False)
+        eligible=eligible, depth=depth, run_scan=False)
     order = jnp.lexsort((jnp.arange(W), wl_timestamp, -wl_priority,
                          borrows0.astype(jnp.int32))).astype(jnp.int32)
     no_reserve = jnp.zeros(W, dtype=bool)

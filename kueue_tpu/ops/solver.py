@@ -7,12 +7,14 @@ Per cycle the solver:
    generation changes, so the per-cycle cost is O(usage + heads);
 2. runs the vectorized nominate (``ops.cycle.classify_np``) on the host
    for heads whose shape the batched math covers (single resource group,
-   single PodSet, plain flavors, default fungibility); the remaining
-   heads are marked SCALAR — the scheduler runs the real host
-   FlavorAssigner walk for those few and attaches the resulting
-   assignment, so multi-resource-group CQs, multi-PodSet workloads,
-   taints/affinity, fungibility policies, resume state, partial
-   admission, and TAS all stay inside a device-decided cycle;
+   single PodSet, flavors without topology; node labels, taints,
+   selectors and tolerations ride in as each head's eligibility mask,
+   ``ops.eligibility``, and any fungibility policy and the resume state
+   run in the vector walk); the remaining heads are marked SCALAR — the
+   scheduler runs the real host FlavorAssigner walk for those few and
+   attaches the resulting assignment, so multi-resource-group CQs,
+   multi-PodSet workloads, partial admission, and TAS all stay inside a
+   device-decided cycle;
 3. dispatches the sequential admit scan (``ops.cycle.admit_scan``) as ONE
    jitted program on the solver device (``ops.device.solver_device``: the
    default JAX backend's device, so the TPU on a chip host).  The scan
@@ -39,6 +41,7 @@ import numpy as np
 
 from ..api.types import FlavorFungibility, FlavorFungibilityPolicy
 from ..cache.snapshot import Snapshot
+from ..obs.trace import span as _span
 from ..workload import Info, Ordering
 from ..scheduler.flavorassigner import (
     Assignment,
@@ -54,6 +57,7 @@ from .cycle import (admit_scan, admit_scan_forests, admit_scan_preempt,
                     classify_np, cycle_order_np, decision_pairs_from_slots,
                     pick_preempt_slot_np)
 from .device import on_accelerator, output_devices
+from .eligibility import bind_flavor_lists, skip_mask, slots_of_mask
 
 # A flat admit scan is one lax.scan step per head; the forest-parallel
 # variant processes one head per cohort forest per step.  Below this head
@@ -176,6 +180,13 @@ class CycleSolver:
             "walk_stop_heads": 0,     # heads whose walk policy-stopped
             "walk_heads": 0,          # heads classified by the vector walk
             "walk_slots": 0,          # flavors their walks visited
+            # per-workload flavor eligibility (ops/eligibility.py):
+            "walk_ineligible_slots": 0,   # of them, skipped for a taint
+                                          # or a selector
+            "constrained_heads": 0,   # vector heads that may not take
+                                      # every flavor of their queue
+            "eligibility_masks_built": 0,  # signatures evaluated, as
+                                           # against read from a cache
         }
         self._structure: Optional[PackedStructure] = None
         self._potential0 = None
@@ -437,38 +448,13 @@ class CycleSolver:
         st = self._structure
         if st is None or st.generation != gen or gen < 0:
             st = pack_structure(snapshot, heads, generation=gen)
-            st.cq_vector_ok = self._cq_vector_ok(snapshot, st)
+            bind_flavor_lists(snapshot, st)
             self._structure = st
             self._potential0 = None
             self.stats["structure_rebuilds"] += 1
         return st
 
     # -- eligibility ---------------------------------------------------
-
-    def _cq_vector_ok(self, snapshot: Snapshot,
-                      st: PackedStructure) -> np.ndarray:
-        """Per-CQ: can the vectorized classify reproduce the host flavor
-        walk for heads of this CQ?  Requires a single resource group and
-        plain flavors (existing, no taints, no node labels, no topology)
-        — everything else routes the head to the scalar host walk instead
-        (flavorassigner.go:499-640).  Any FlavorFungibility policy is
-        fine: the walk (stop rules + resume index) runs in the vector
-        math itself (classify_np / the fused burst kernel)."""
-        ok = np.zeros(len(st.cq_names), dtype=bool)
-        for ci, name in enumerate(st.cq_names):
-            cq = snapshot.cluster_queues[name]
-            if len(cq.spec.resource_groups) != 1:
-                continue
-            plain = True
-            for rg in cq.spec.resource_groups:
-                for fq in rg.flavors:
-                    flavor = snapshot.resource_flavors.get(fq.name)
-                    if (flavor is None or flavor.node_taints
-                            or flavor.node_labels or flavor.topology_name):
-                        plain = False
-                        break
-            ok[ci] = plain
-        return ok
 
     def _scalar_mask(self, snapshot: Snapshot, heads: list[Info],
                      st: PackedStructure) -> np.ndarray:
@@ -512,6 +498,20 @@ class CycleSolver:
                 self.stats["resume_heads"] += 1
         return start
 
+    def _eligible_slots(self, heads: list[Info], st: PackedStructure,
+                        W: int) -> np.ndarray:
+        """The cycle's [W, S] eligibility plane: False where a head's
+        PodSet may not take the flavor (ops/eligibility.py).  Rows of
+        pads and of queues the vector walk does not decide are True."""
+        skip = np.zeros(W, dtype=np.int32)
+        if st.flavors_declared:
+            cq_index = st.cq_index
+            for wi, h in enumerate(heads):
+                ci = cq_index.get(h.cluster_queue, -1)
+                if ci >= 0:
+                    skip[wi] = skip_mask(h, st, ci, self.stats)
+        return slots_of_mask(skip, st.slot_fr.shape[1])
+
     # -- phase 1 -------------------------------------------------------
 
     def classify(self, snapshot: Snapshot,
@@ -547,8 +547,10 @@ class CycleSolver:
         W = packed.wl_cq.shape[0]
         start_pad = np.zeros(W, dtype=np.int32)
         start_pad[:len(heads)] = start
+        with _span("cycle.nominate.classify.eligibility"):
+            eligible = self._eligible_slots(heads, st, W)
         out = classify_np(packed, potential0=self._potential0,
-                          start_slot=start_pad)
+                          start_slot=start_pad, eligible=eligible)
         n = packed.wl_count
         # partial admission: a min_count head whose FULL counts fit is
         # decision-identical to a plain head; otherwise the host runs the
@@ -578,6 +580,11 @@ class CycleSolver:
             np.count_nonzero(out["preempt_stopped0"][:n]))
         self.stats["walk_heads"] += int(n - scalar.sum())
         self.stats["walk_slots"] += int(out["walk_slots"][:n][~scalar].sum())
+        self.stats["walk_ineligible_slots"] += int(
+            out["walk_ineligible"][:n][~scalar].sum())
+        self.stats["constrained_heads"] += int(np.count_nonzero(
+            (st.slot_valid[np.maximum(packed.wl_cq[:n], 0)]
+             & ~eligible[:n]).any(axis=1) & ~scalar))
         return ClassifiedCycle(
             packed=packed, heads=heads, snapshot=snapshot,
             fit_slot0=out["fit_slot0"], borrows0=out["borrows0"],

@@ -50,6 +50,7 @@ from ..cache.arena import PlaneArena
 from ..obs.trace import span as _span
 from . import aggregate as _agg
 from . import burst as _b
+from .eligibility import mask_plane_width
 from .packing import _bucket
 
 _KEY_BYTES = 48          # workload key width in the encoded sort keys
@@ -165,6 +166,7 @@ _ROW_PLANES = {
     "wl_prio": (0, np.int32, None),
     "wl_uidrank": (0, np.int32, None),
     "vec_ok": (False, bool, None),
+    "wl_flavor_skip": (0, np.uint8, None),
     "elig0": (False, bool, None),
     "parked0": (False, bool, None),
     "resume0": (0, np.int32, None),
@@ -200,11 +202,15 @@ class StreamState:
         self.token = next(StreamState._next_token)
 
 
-def _views(arena: PlaneArena, C: int, M: int, R: int, F: int) -> dict:
+def _views(arena: PlaneArena, C: int, M: int, R: int, F: int,
+           skip_width: int) -> dict:
     out = {}
     for name, (pad, dt, extra) in _ROW_PLANES.items():
         shape = (C, M) if extra is None else \
             (C, M, R) if extra == "R" else (C, M, F)
+        if name == "wl_flavor_skip":
+            # every flavor plain: one column of zeros stands for the grid
+            shape = (C, skip_width)
         out[name] = arena.ensure(name, shape, dt, pad)
     out["u_cq0"] = arena.ensure("u_cq0", (C, F), np.int32, 0, grow_axes=1)
     out["keys_grid"] = arena.ensure("keys_grid", (C, M), object, None)
@@ -270,6 +276,8 @@ def _write_cq(state: "StreamState", views: dict, ci: int, rec,
         views["wl_prio"][ci, mi] = np.clip(
             rec.prio, -_b.I32_MAX, _b.I32_MAX)
         views["vec_ok"][ci, mi] = rec.ok
+        if views["wl_flavor_skip"].shape[1] > 1:   # else: a column of 0s
+            views["wl_flavor_skip"][ci, mi] = rec.skip
         views["parked0"][ci, mi] = rec.parked
         views["elig0"][ci, mi] = ~rec.parked & ~rec.adm
         views["resume0"][ci, mi] = rec.resume
@@ -591,7 +599,7 @@ def _init_full(st, queues, cache, scheduler, key, min_m, window, arena,
 
         rows_per_cq = int(state.n_rows_cq.max(initial=0))
         state.M = M = max(_bucket(rows_per_cq, minimum=4), min_m)
-        views = _views(arena, C, M, R, F)
+        views = _views(arena, C, M, R, F, mask_plane_width(st, M))
         _reset_views(views)
 
         # per-CQ heap rank: the reference ci-segmented lexsort
@@ -621,6 +629,8 @@ def _init_full(st, queues, cache, scheduler, key, min_m, window, arena,
             views["parked0"][ci_a, mi_a] = parked_a
             views["elig0"][ci_a, mi_a] = ~parked_a & ~adm_a
             views["vec_ok"][ci_a, mi_a] = cat("ok", bool)
+            if views["wl_flavor_skip"].shape[1] > 1:
+                views["wl_flavor_skip"][ci_a, mi_a] = cat("skip", np.uint8)
             views["resume0"][ci_a, mi_a] = cat("resume", np.int32)
             views["adm0"][ci_a, mi_a] = adm_a
             views["adm_usage0"][ci_a, mi_a] = cat("usage", np.int32)
@@ -836,7 +846,7 @@ def pack_burst_streaming(structure, queues, cache, scheduler, clock,
             state.M = M = max(_bucket(rows_per_cq, minimum=4), min_m)
             R = len(st.resource_names)
             F = max(1, len(st.fr_index))
-            views = _views(arena, C, M, R, F)
+            views = _views(arena, C, M, R, F, mask_plane_width(st, M))
 
             for ci, rec, kb, ub, mi in walked:
                 _clear_cq(state, views, ci)

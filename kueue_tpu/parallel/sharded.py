@@ -227,7 +227,7 @@ _I32_MAX = np.int32(2**31 - 1)
 # or candidate tables — everything else about it is then inert
 _C_FILLS = {
     "wl_req": 0, "wl_rank": _I32_MAX, "wl_cycle_rank": 0, "wl_prio": 0,
-    "wl_uidrank": 0, "vec_ok": False,
+    "wl_uidrank": 0, "vec_ok": False, "wl_flavor_skip": 0,
     "elig0": False, "parked0": False, "resume0": 0, "adm0": False,
     "adm_seq0": 0, "adm_usage0": 0, "adm_uses0": False,
     "death0": _I32_MAX, "u_cq0": 0,
@@ -264,7 +264,8 @@ _ROW_STATIC = ("nominal_cq", "npb_cq", "slot_fr", "slot_valid",
                "cq_can_preempt_borrow", "cq_wcb_borrow",
                "cq_wcp_preempt", "wcq_lower", "rwc_enabled",
                "rwc_only_lower", "self_lmem")
-SCATTER_PLANES = ("wl_req", "wl_rank", "wl_prio", "vec_ok", "strict_cq",
+SCATTER_PLANES = ("wl_req", "wl_rank", "wl_prio", "vec_ok",
+                  "wl_flavor_skip", "strict_cq",
                   "elig0", "parked0", "resume0", "adm0", "adm_usage0",
                   "adm_uses0", "death0", "u_cq0")
 GLOBAL_PLANES = ("wl_cycle_rank", "wl_uidrank", "adm_seq0", "preempt_ok")
@@ -506,7 +507,7 @@ def sharded_burst_fn(mesh: Mesh, *, K: int, depth: int, L: int, S: int,
     row = P("cq")
     rep = P()
     kc = P(None, "cq")
-    in_specs = (row,) * 14 + (rep,) + (row,) * 25 + (kc, kc)
+    in_specs = (row,) * 15 + (rep,) + (row,) * 25 + (kc, kc)
     out_specs = (kc, kc, kc, kc, kc, rep, rep, (row,) * 9)
     body = _partial(_burst_cycles, K=K, depth=depth, L=L, S=S, KC=KC,
                     n_levels=n_levels, G=G, runtime=runtime,

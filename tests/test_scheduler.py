@@ -254,9 +254,10 @@ def test_flavor_fungibility_try_next_flavor():
     assert w2.admission.pod_set_assignments[0].flavors["cpu"] == "on-demand"
 
 
-def test_taints_block_flavor():
+@pytest.mark.parametrize("use_device_solver", [False, True])
+def test_taints_block_flavor(use_device_solver):
     clock = FakeClock()
-    d = make_driver(clock)
+    d = make_driver(clock, use_device_solver=use_device_solver)
     from kueue_tpu.api.types import Taint, Toleration
     d.apply_resource_flavor(ResourceFlavor(
         name="tainted", node_taints=[Taint(key="gpu", value="true")]))
@@ -274,6 +275,13 @@ def test_taints_block_flavor():
     d.create_workload(tol)
     d.run_until_settled()
     assert d.admitted_keys() == {"default/tolerant"}
+    if use_device_solver:
+        # a taint is no reason for the host walk: the vector classify
+        # decided both heads, the first under a mask that bars the flavor
+        stats = d.scheduler.solver.stats
+        assert stats["scalar_heads"] == 0 and stats["host_cycles"] == 0
+        assert "cq_shape" not in stats["scalar_reasons"]
+        assert stats["constrained_heads"] >= 1
 
 
 def test_borrow_within_cohort_preemption():

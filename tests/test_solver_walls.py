@@ -216,11 +216,64 @@ def test_taints_tolerations_affinity():
                 pod_sets=[ps]))
         return out
 
-    hlog, _ = assert_parity(build)
+    hlog, stats = assert_parity(build, expect_scalar=False)
     # both flavors must actually be used for the scenario to mean anything
     used = {f for c in hlog for _, fl in c["admitted"]
             for _, _, pairs in fl for _, f in pairs}
     assert used == {"spot", "ondemand"}, used
+    # ... and every head was decided by the vector walk, under its own
+    # eligibility mask: labels and taints are no reason for the host walk
+    assert stats["scalar_heads"] == 0, stats
+    assert "cq_shape" not in stats["scalar_reasons"], stats
+    assert stats["constrained_heads"] > 0, stats
+    assert stats["walk_ineligible_slots"] > 0, stats
+    # four signatures (tolerates or not, pinned or not), one flavor list
+    assert stats["eligibility_masks_built"] == 4, stats
+
+
+def test_two_resource_groups_with_declared_flavors_stay_scalar():
+    """The sibling: the same labelled, tainted flavors under a queue with
+    two resource groups.  That shape is still the host walk's, and is
+    counted as such."""
+    def build(d):
+        d.apply_resource_flavor(ResourceFlavor(
+            name="spot", node_labels={"tier": "spot"},
+            node_taints=[Taint(key="spot", value="true",
+                               effect="NoSchedule")]))
+        d.apply_resource_flavor(ResourceFlavor(
+            name="ondemand", node_labels={"tier": "ondemand"}))
+        d.apply_resource_flavor(ResourceFlavor(name="gpu-x"))
+        d.apply_cluster_queue(ClusterQueue(
+            name="cq",
+            resource_groups=[
+                ResourceGroup(covered_resources=["cpu"], flavors=[
+                    FlavorQuotas(name="spot", resources={
+                        "cpu": ResourceQuota(nominal=4000)}),
+                    FlavorQuotas(name="ondemand", resources={
+                        "cpu": ResourceQuota(nominal=2000)})]),
+                ResourceGroup(covered_resources=["gpu"], flavors=[
+                    FlavorQuotas(name="gpu-x", resources={
+                        "gpu": ResourceQuota(nominal=4)})])]))
+        d.apply_local_queue(LocalQueue(name="lq", cluster_queue="cq"))
+        rng = random.Random(19)
+        out = []
+        for i in range(12):
+            reqs = {"cpu": rng.choice([1000, 2000])}
+            if i % 2:
+                reqs["gpu"] = 1
+            out.append(Workload(
+                name=f"wl-{i}", queue_name="lq",
+                priority=rng.choice([10, 50]), creation_time=float(i + 1),
+                pod_sets=[PodSet(
+                    name="main", count=1, requests=reqs,
+                    tolerations=([Toleration(key="spot", operator="Exists")]
+                                 if i % 3 else []))]))
+        return out
+
+    _, stats = assert_parity(build)
+    assert stats["scalar_reasons"].get("cq_shape", 0) == stats[
+        "scalar_heads"] > 0, stats
+    assert stats["constrained_heads"] == stats["walk_heads"] == 0, stats
 
 
 # ---------------------------------------------------------------------------

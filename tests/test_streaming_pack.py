@@ -167,12 +167,12 @@ def test_schedule_burst_packs_every_window_after_the_first_in_place(calls):
     bs = d._burst_solver.stats
     windows = bs["burst_serial_windows"]
     assert windows >= calls
-    # fourteen row planes and the keys' grid a window; the runtime lets
+    # fifteen row planes and the keys' grid a window; the runtime lets
     # go of a launch's host arrays at its next call, so a pack that
     # follows a fetch with no launch between may find some still held
     # and map those afresh
     reused = bs["pack_arena_snapshots_reused"]
-    assert reused + bs["pack_arena_snapshots_fresh"] == windows * 15
+    assert reused + bs["pack_arena_snapshots_fresh"] == windows * 16
     assert reused > 0
 
 
@@ -463,7 +463,7 @@ def test_schedule_burst_decisions_identical_tighten_on_off(monkeypatch):
 @pytest.mark.parametrize("batch_bytes,batches_a_launch", [
     (1 << 40, 1),     # every plane in one batch
     (256, None),      # the toy's planes are 4 B to 128 B: a few a batch
-    (1, 15),          # a plane over the limit goes up alone: six row
+    (1, 16),          # a plane over the limit goes up alone: seven row
                       # planes and the nine of the scan state
 ])
 def test_a_launch_stages_its_host_planes_in_bounded_batches(
@@ -499,7 +499,7 @@ def test_a_launch_stages_its_host_planes_in_bounded_batches(
     assert bs["burst_serial_windows"] >= 1
     a_launch = bs["burst_h2d_batches"] / bs["burst_serial_windows"]
     if batches_a_launch is None:
-        assert 1 < a_launch < 15
+        assert 1 < a_launch < 16
     else:
         assert a_launch == batches_a_launch
     assert bs["burst_launch_bytes_h2d"] == \
