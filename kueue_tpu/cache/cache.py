@@ -90,9 +90,11 @@ class Cache:
         # snapshot clones (cache/candidates.py); the preemptor reports it
         self.table_tally = TableTally()
         # dirty-CQ journal feeding the incremental burst pack: admitted
-        # table / usage / assumed-set mutations mark the owning CQ
-        # (utils/journal.py); structure edits need no marks — they bump
-        # structure_generation, which forces a full repack by key
+        # table / usage / assumed-set mutations mark the owning CQ, and
+        # a workload that joins or leaves an admitted table is named
+        # with its queue (utils/journal.py touch_admitted); structure
+        # edits need no marks — they bump structure_generation, which
+        # forces a full repack by key
         from ..utils.journal import PackJournal
         self.pack_journal = PackJournal()
         # Parallel host plane (utils/parallel_host.py): the driver hands
@@ -255,12 +257,13 @@ class Cache:
                 self._tas_apply(owner.workloads[info.key], -1)
                 owner.remove_workload(owner.workloads[info.key])
                 self._wl_owner.pop(info.key, None)
-                self.pack_journal.touch(owner.name)
+                self.pack_journal.touch_admitted(owner.name, info.key, False)
             cq = self._mgr.cluster_queues.get(info.obj.admission.cluster_queue)
-            self.pack_journal.touch(info.obj.admission.cluster_queue)
             if cq is None:
+                self.pack_journal.touch(info.obj.admission.cluster_queue)
                 self.assumed_workloads.discard(info.key)
                 return False
+            self.pack_journal.touch_admitted(cq.name, info.key, True)
             info.cluster_queue = cq.name
             cq.add_workload(info)
             self._tas_apply(info, +1)
@@ -275,7 +278,7 @@ class Cache:
                 self._tas_apply(cq.workloads[info.key], -1)
                 cq.remove_workload(cq.workloads[info.key])
                 self._wl_owner.pop(info.key, None)
-                self.pack_journal.touch(cq.name)
+                self.pack_journal.touch_admitted(cq.name, info.key, False)
             elif info.key in self.assumed_workloads:
                 # the assumed set gates the owner CQ's pending rows
                 owned = getattr(info, "cluster_queue", None)
@@ -301,7 +304,7 @@ class Cache:
             self._tas_apply(info, +1)
             self._wl_owner[info.key] = cq.name
             self.assumed_workloads.add(info.key)
-            self.pack_journal.touch(cq.name)
+            self.pack_journal.touch_admitted(cq.name, info.key, True)
             return True
 
     def forget_workload(self, info: Info) -> bool:
@@ -314,7 +317,7 @@ class Cache:
                 self._tas_apply(cq.workloads[info.key], -1)
                 cq.remove_workload(cq.workloads[info.key])
                 self._wl_owner.pop(info.key, None)
-                self.pack_journal.touch(cq.name)
+                self.pack_journal.touch_admitted(cq.name, info.key, False)
             else:
                 owned = getattr(info, "cluster_queue", None)
                 if owned:

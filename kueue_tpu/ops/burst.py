@@ -63,6 +63,7 @@ no sequential remove-chain walks.
 
 from __future__ import annotations
 
+import operator
 from dataclasses import dataclass
 from functools import partial
 
@@ -969,25 +970,58 @@ _PACK_FAIL = object()   # sentinel: this CQ fails the whole pack
 class _CQRows:
     """One CQ's packed rows (pending then admitted) plus the per-CQ
     facts stage B needs.  Records are the unit of delta reuse: a clean
-    record re-enters ``_assemble_plan`` untouched while a dirty CQ
-    re-walks into a fresh record.  Row order within a record never
+    record re-enters the next window untouched; a dirty CQ's record is
+    built from the old one (pending side walked afresh, admitted side
+    kept but for the rows its journal events name) or, where the events
+    cannot settle it, walked in full.  Row order within a record never
     reaches the plan — every stage-B rank comes from a total-order
     lexsort with a unique final tiebreak — so reuse stays bit-identical
     even though a re-walk may enumerate members differently.
 
-    ``n_comp`` / ``comp_max_ts`` account for admitted rows of
-    compressible forests (ops/aggregate.py) that were walked but NOT
-    packed: their count and max reservation time are all the plan
-    needs from them (usage is already in ``u_row``)."""
-    __slots__ = ("ci", "pos", "strict", "bad", "truncated",
-                 "n_pend", "n_adm", "n_comp", "comp_max_ts",
+    ``bad_keys`` are the admitted workloads left out of the rows because
+    they break the modeled candidate ordering (Evicted, no reservation,
+    no exact usage vector); ``comp_ts`` holds, by key, the reservation
+    time of each admitted row of a compressible forest
+    (ops/aggregate.py) that was walked but NOT packed: their count and
+    max reservation time are all the plan needs from them (usage is
+    already in ``u_row``).  ``infos`` are the pending rows' (an admitted
+    row's is the admitted table's).  ``kept_idx`` is set on a record
+    built from an old one: the old record's indices of the rows it kept
+    (the pending rows whose ``Info`` stands, then the admitted rows no
+    event names), which follow the ``kept_at`` pending rows derived."""
+    __slots__ = ("ci", "pos", "strict", "bad_keys", "truncated",
+                 "n_pend", "n_adm", "comp_ts", "comp_max_ts",
                  "keys", "uids", "prio", "ts",
                  "res_ts", "parked", "ok", "resume", "adm", "req",
-                 "skip", "usage", "uses", "u_row", "index_of_key", "infos")
+                 "skip", "usage", "uses", "u_row", "infos", "kept_idx",
+                 "kept_at", "_pend_index", "_adm_index")
 
     @property
     def n_rows(self) -> int:
         return self.n_pend + self.n_adm
+
+    @property
+    def bad(self) -> bool:
+        return bool(self.bad_keys)
+
+    @property
+    def n_comp(self) -> int:
+        return len(self.comp_ts)
+
+    def find(self, key) -> Optional[int]:
+        """The record index of ``key``'s row.  Pending keys are indexed
+        when the record is built; the admitted side's index is made on
+        the first question about an admitted row, which few records
+        ever get."""
+        idx = self._pend_index.get(key)
+        if idx is None:
+            index = self._adm_index
+            if index is None:
+                index = self._adm_index = {
+                    k: j for j, k in enumerate(
+                        self.keys[self.n_pend:].tolist(), self.n_pend)}
+            idx = index.get(key)
+        return idx
 
 
 class _PackStatics:
@@ -1082,8 +1116,256 @@ def _unknown_active_cq(st, queues) -> bool:
     return False
 
 
+_BURST_SEL = operator.attrgetter("_burst_sel")
+
+
+def _pending_members(q, window, gen, qts):
+    """An active queue's pending side: heap + parking lot, less the
+    backoff-parked, cut to the ``window + 2`` best when ``window`` > 0.
+    Returns (members, parked keys, truncated)."""
+    members = q.heap.items()
+    parked_keys = set()
+    for key, info in q.inadmissible.items():
+        rs = info.obj.requeue_state
+        if rs is not None and rs.requeue_at is not None:
+            # backoff-parked: excluded; a mid-burst expiry diverges
+            # the heads and the application validator truncates
+            continue
+        members.append(info)
+        parked_keys.add(info.key)
+    if window <= 0 or len(members) <= window + 2:
+        return members, parked_keys, False
+    # (generation, -priority, queue-order ts, key) a row, kept on the
+    # Info: the tuple is asked for ~rows×windows times at scale
+    for info in members:
+        sel = getattr(info, "_burst_sel", None)
+        if sel is not None and sel[0] == gen:
+            continue
+        row = getattr(info, "_burst_row", None)
+        if row is not None and row[0] == gen:
+            info._burst_sel = (gen, -row[5], row[4], info.key)
+        else:
+            obj = info.obj
+            info._burst_sel = (gen, -obj.priority, qts(obj), info.key)
+    return sorted(members, key=_BURST_SEL)[:window + 2], parked_keys, True
+
+
+_DERIVED_ATTRS = ("keys", "uids", "prio", "ts", "res_ts", "parked", "ok",
+                  "resume", "req", "skip", "usage", "uses")
+
+
+class _RowWalk:
+    """Derives one ClusterQueue's rows from their ``Info``s, one call a
+    row, for the full walk of a queue and for the rows a dirty queue's
+    record takes in; ``fill`` turns what was derived into a record's
+    arrays."""
+    __slots__ = ("st", "ci", "cq_live", "covers_pods", "cq_vec",
+                 "lr_summaries", "assumed", "scale_of", "compress", "qts",
+                 "resume_start", "failed_check",
+                 "bad_keys", "comp_ts", "comp_max_ts", "n",
+                 "key_l", "uid_l", "prio_l", "ts_l", "res_ts_l",
+                 "parked_l", "ok_l", "resume_l", "infos",
+                 "req_mat", "usage_mat", "uses_mat")
+
+    def __init__(self, st, ci, cq_live, scheduler, assumed, scale_of,
+                 compress, n_upper, bad_keys, comp_ts, comp_max_ts):
+        self.st = st
+        self.ci = ci
+        self.cq_live = cq_live
+        cq_name = st.cq_names[ci]
+        self.covers_pods = cq_name in st.cq_covers_pods
+        cq_ok = st.cq_vector_ok
+        cq_vec = bool(cq_ok[ci]) if cq_ok is not None else False
+        if cq_vec and cq_live.spec.namespace_selector:
+            cq_vec = False   # selector evaluation stays on the host path
+        self.cq_vec = cq_vec
+        self.lr_summaries = scheduler.limit_range_summaries
+        self.assumed = assumed
+        self.scale_of = scale_of
+        self.compress = compress
+        self.qts = scheduler.ordering.queue_order_timestamp
+        from ..api.types import AdmissionCheckState
+        from .solver import resume_start
+        self.resume_start = resume_start
+        self.failed_check = (AdmissionCheckState.RETRY,
+                             AdmissionCheckState.REJECTED)
+        self.bad_keys = bad_keys
+        self.comp_ts = comp_ts
+        self.comp_max_ts = comp_max_ts
+        self.n = 0
+        self.key_l: list[str] = []
+        self.uid_l: list[str] = []
+        self.prio_l: list[int] = []
+        self.ts_l: list[float] = []
+        self.res_ts_l: list[float] = []
+        self.parked_l: list[bool] = []
+        self.ok_l: list[bool] = []
+        self.resume_l: list[int] = []    # flavor-walk start slot (0 = full)
+        self.infos: list = []
+        F = max(1, len(st.fr_index))
+        self.req_mat = np.zeros((n_upper, len(st.resource_names)),
+                                dtype=np.int32)
+        self.usage_mat = np.zeros((n_upper, F), dtype=np.int32)
+        self.uses_mat = np.zeros((n_upper, F), dtype=bool)
+
+    def _static(self, info):
+        row = getattr(info, "_burst_row", None)
+        if (row is None or row[0] != self.st.generation
+                or row[1] != self.covers_pods):
+            row = (self.st.generation,
+                   *_static_row(info, self.st, self.covers_pods, self.qts))
+            info._burst_row = row
+        return row
+
+    def _gated(self, obj) -> bool:
+        """The dynamic gates a row's ``vec_ok`` shares between pending
+        and admitted rows: LimitRange bounds stay host-side, and so
+        does a workload with a failed admission check."""
+        lr = self.lr_summaries
+        if lr and lr.get(obj.namespace):
+            return True
+        return bool(obj.admission_check_states) and any(
+            stt.state in self.failed_check
+            for stt in obj.admission_check_states.values())
+
+    def moving(self, info, static_ok: bool) -> tuple:
+        """A pending row's facts that move while its ``Info`` stands:
+        (vec_ok, the flavor walk's start slot)."""
+        ok = self.cq_vec and static_ok
+        if ok:
+            obj = info.obj
+            if (info.key in self.assumed or obj.admission is not None
+                    or self._gated(obj)):
+                ok = False
+        return ok, self.resume_start(info, self.cq_live, self.covers_pods)
+
+    def pending(self, info, parked: bool) -> None:
+        _, _, req_vec, static_ok, ts, prio, uid = self._static(info)
+        self.key_l.append(info.key)
+        self.uid_l.append(uid)
+        self.prio_l.append(prio)
+        self.ts_l.append(ts)
+        self.res_ts_l.append(0.0)
+        self.parked_l.append(parked)
+        self.req_mat[self.n] = req_vec
+        ok, resume = self.moving(info, static_ok)
+        self.ok_l.append(ok)
+        self.resume_l.append(resume)
+        self.infos.append(info)
+        self.n += 1
+
+    def admitted(self, key, info) -> None:
+        """One workload of the queue's admitted table: a packed row, a
+        count in the compressed aggregates, or a ``bad`` key."""
+        from ..api.types import WL_EVICTED, WL_QUOTA_RESERVED
+        obj = info.obj
+        # assumed-but-applied workloads are normal candidates (the
+        # apply hook is synchronous here; a failed apply forgets the
+        # assumption before the cycle returns) — only a live evicted
+        # condition or a missing reservation breaks the modeled
+        # candidate ordering
+        cond = obj.conditions.get(WL_QUOTA_RESERVED)
+        if cond is None or obj.condition_true(WL_EVICTED):
+            self.bad_keys.add(key)
+            return
+        uv = admitted_usage_vec(info, self.st, self.scale_of,
+                                self.usage_mat.shape[1])
+        if uv is None:
+            # not representable as a target/release row: the host
+            # handles its cycles (forest out of the envelope) and
+            # its finish via the ext_release path
+            self.bad_keys.add(key)
+            return
+        if self.compress:
+            # never candidate-gathered (no preempting CQ in this
+            # forest): fold into the aggregates; a mid-burst finish
+            # reaches the kernel via the ext_release fallback exactly
+            # as an unpacked key does today
+            ts_r = cond.last_transition_time
+            self.comp_ts[key] = ts_r
+            if ts_r > self.comp_max_ts:
+                self.comp_max_ts = ts_r
+            return
+        _, _, req_vec, static_ok, ts, prio, uid = self._static(info)
+        self.key_l.append(key)
+        self.uid_l.append(uid)
+        self.prio_l.append(prio)
+        self.ts_l.append(ts)
+        self.res_ts_l.append(cond.last_transition_time)
+        self.parked_l.append(False)
+        i = self.n
+        self.req_mat[i] = req_vec
+        self.usage_mat[i], self.uses_mat[i] = uv
+        # post-eviction afterlife: the same dynamic gates pending
+        # rows get (LimitRange bounds, failed admission checks) —
+        # an in-burst-evicted row the kernel re-admits must honor
+        # everything the host nominate would; gating extra is safe
+        # (the cycle goes dirty), gating less diverges decisions
+        self.ok_l.append(self.cq_vec and static_ok
+                         and not self._gated(obj))
+        self.resume_l.append(0)
+        self.infos.append(info)
+        self.n += 1
+
+    def fill(self, rec, n_pend: int, old=None, keep=None,
+             kept_pending=()) -> None:
+        """Set ``rec``'s row arrays: the ``n_pend`` pending rows derived
+        first, then (building on ``old``) the rows ``keep`` of the old
+        record, its pending rows ahead of its admitted ones, then the
+        admitted rows derived.  ``kept_pending`` = (info, parked,
+        vec_ok, resume) of each pending row kept: what can move while
+        the ``Info`` stands, as it is now."""
+        i = self.n
+        st = self.st
+        if old is not None and not i:
+            for attr in _DERIVED_ATTRS:
+                setattr(rec, attr, getattr(old, attr)[keep])
+        else:
+            # the flavors a row's PodSet may not take: all zero, and
+            # nothing to ask a row, unless a flavor of this queue carries
+            # labels or taints
+            skip = (np.fromiter((skip_mask(info, st, self.ci)
+                                 for info in self.infos),
+                                dtype=np.uint8, count=i)
+                    if declares(st, self.ci)
+                    else np.zeros(i, dtype=np.uint8))
+            derived = (
+                np.asarray(self.key_l) if i else np.empty(0, dtype="U1"),
+                np.asarray(self.uid_l) if i else np.empty(0, dtype="U1"),
+                np.array(self.prio_l, dtype=np.int64),
+                np.array(self.ts_l, dtype=np.float64),
+                np.array(self.res_ts_l, dtype=np.float64),
+                np.array(self.parked_l, dtype=bool),
+                np.array(self.ok_l, dtype=bool),
+                np.array(self.resume_l, dtype=np.int32),
+                self.req_mat[:i], skip, self.usage_mat[:i],
+                self.uses_mat[:i])
+            for attr, new in zip(_DERIVED_ATTRS, derived):
+                if old is not None:
+                    new = np.concatenate((new[:n_pend],
+                                          getattr(old, attr)[keep],
+                                          new[n_pend:]))
+                setattr(rec, attr, new)
+        rec.infos = self.infos[:n_pend]
+        rec._pend_index = {k: j for j, k in enumerate(self.key_l[:n_pend])}
+        if kept_pending:
+            at = slice(n_pend, n_pend + len(kept_pending))
+            infos, rec.parked[at], rec.ok[at], rec.resume[at] = \
+                zip(*kept_pending)
+            rec.infos.extend(infos)
+            rec._pend_index.update(
+                (info.key, j) for j, info in enumerate(infos, n_pend))
+            n_pend = at.stop
+        rec.n_pend = n_pend
+        rec.n_adm = len(rec.keys) - n_pend
+        rec.adm = np.zeros(len(rec.keys), dtype=bool)
+        rec.adm[n_pend:] = True
+        rec._adm_index = None
+
+
 def _pack_cq_rows(st, ci, pos, queues, cache, scheduler, assumed,
-                  scale_of, window, compress=False):
+                  scale_of, window, compress=False, old=None,
+                  events=None, old_idx=None):
     """Stage A for ONE ClusterQueue: walk its heap + parking lot and
     its admitted table into a _CQRows record, or _PACK_FAIL when the CQ
     can't be represented (missing from the cache, inexact usage
@@ -1093,15 +1375,22 @@ def _pack_cq_rows(st, ci, pos, queues, cache, scheduler, assumed,
     on) the admitted walk runs identically — same bad-detection, same
     usage-vector check, so ``rec.bad`` matches the uncompressed arm
     byte for byte — but representable admitted rows are folded into
-    ``n_comp`` / ``comp_max_ts`` aggregates instead of packed rows."""
-    from ..api.types import (QueueingStrategy, AdmissionCheckState,
-                             WL_EVICTED, WL_QUOTA_RESERVED)
+    ``n_comp`` / ``comp_max_ts`` aggregates instead of packed rows.
+
+    With ``old`` (the queue's record of the last window) the pending
+    side's members are found afresh, a member whose ``Info`` the old
+    record holds keeping its row but for what can move under the
+    ``Info`` (parked, ``vec_ok``, the resume slot), and the admitted
+    side is the old record's,
+    less the rows that went and with the rows that came, as ``events``
+    (the cache journal's ``{key: came?}`` for this queue; None = look)
+    name them; ``old_idx`` gives the old record's index of each event
+    key that has a row there.  The live admitted table settles every
+    event; one that disagrees with it sends the queue to the full
+    walk."""
+    from ..api.types import QueueingStrategy
     from .packing import scaled_usage_row
-    ordering = scheduler.ordering
-    qts = ordering.queue_order_timestamp
-    F = max(1, len(st.fr_index))
-    R = len(st.resource_names)
-    gen = st.generation
+    qts = scheduler.ordering.queue_order_timestamp
     cq_name = st.cq_names[ci]
     cq_live = cache.cluster_queue(cq_name)
     if cq_live is None:
@@ -1109,202 +1398,79 @@ def _pack_cq_rows(st, ci, pos, queues, cache, scheduler, assumed,
     u_row = scaled_usage_row(st, cq_live)
     if u_row is None:
         return _PACK_FAIL
+    live = cq_live.workloads
+    if old is not None and any(
+            came is not None and came != (key in live)
+            for key, came in events.items()):
+        old = None    # the journal and the table disagree: never guess
 
     rec = _CQRows()
     rec.ci = ci
     rec.pos = pos
-    rec.bad = False
-    rec.truncated = False
-    rec.n_comp = 0
-    rec.comp_max_ts = -np.inf
-
+    rec.u_row = u_row
     q = queues.queue_for(cq_name)
     active = q is not None and q.active
     rec.strict = bool(
         active and q.queueing_strategy == QueueingStrategy.STRICT_FIFO)
-    members = []
-    parked_keys = set()
-    if active:
-        members.extend(q.heap.items())
-        for key, info in q.inadmissible.items():
-            rs = info.obj.requeue_state
-            if rs is not None and rs.requeue_at is not None:
-                # backoff-parked: excluded; a mid-burst expiry diverges
-                # the heads and the application validator truncates
+    members, parked_keys, rec.truncated = (
+        _pending_members(q, window, st.generation, qts) if active
+        else ([], (), False))
+
+    keep = None
+    if old is None:
+        came = live.items()
+        rec.bad_keys, rec.comp_ts = set(), {}
+        comp_max_ts = -np.inf
+    else:
+        # the old record's sets go on with the new one
+        rec.bad_keys, rec.comp_ts = old.bad_keys, old.comp_ts
+        comp_max_ts = old.comp_max_ts
+        came = []
+        gone = []
+        remax = False
+        for key in events:
+            rec.bad_keys.discard(key)
+            if rec.comp_ts.pop(key, -np.inf) >= comp_max_ts:
+                remax = True
+            idx = old_idx.get(key)
+            if idx is not None and idx >= old.n_pend:
+                gone.append(idx)
+            info = live.get(key)
+            if info is not None:
+                came.append((key, info))
+        if remax:
+            comp_max_ts = max(rec.comp_ts.values(), default=-np.inf)
+        kept = np.ones(old.n_rows, dtype=bool)
+        kept[:old.n_pend] = False
+        kept[gone] = False
+        keep = np.nonzero(kept)[0]
+    walk = _RowWalk(st, ci, cq_live, scheduler, assumed, scale_of,
+                    compress, len(members) + len(came), rec.bad_keys,
+                    rec.comp_ts, comp_max_ts)
+    kept_pending = []
+    if old is None:
+        for info in members:
+            walk.pending(info, info.key in parked_keys)
+    else:
+        # a pending row whose Info stands keeps what the Info fixes;
+        # what can move under it is looked at again
+        index, infos, keep_pend = old._pend_index, old.infos, []
+        for info in members:
+            j = index.get(info.key)
+            if j is None or infos[j] is not info:
+                walk.pending(info, info.key in parked_keys)
                 continue
-            members.append(info)
-            parked_keys.add(info.key)
-
-    if window > 0:
-        cap = window + 2
-        if len(members) > cap:
-            import heapq
-
-            def sel_key(info):
-                # the tuple is rebuilt ~rows×windows times at scale;
-                # cache it per structure generation alongside the row
-                sel = getattr(info, "_burst_sel", None)
-                if sel is not None and sel[0] == gen:
-                    return sel[1]
-                row = getattr(info, "_burst_row", None)
-                if row is not None and row[0] == gen:
-                    t = (-row[5], row[4], info.key)
-                else:
-                    obj = info.obj
-                    t = (-obj.priority, qts(obj), info.key)
-                info._burst_sel = (gen, t)
-                return t
-
-            members = heapq.nsmallest(cap, members, key=sel_key)
-            rec.truncated = True
-
-    admitted = []
-    for key, info in cq_live.workloads.items():
-        obj = info.obj
-        # assumed-but-applied workloads are normal candidates (the
-        # apply hook is synchronous here; a failed apply forgets the
-        # assumption before the cycle returns) — only a live evicted
-        # condition or a missing reservation breaks the modeled
-        # candidate ordering
-        if (obj.condition_true(WL_EVICTED)
-                or obj.conditions.get(WL_QUOTA_RESERVED) is None):
-            rec.bad = True
-            continue
-        admitted.append(info)
-
-    covers_pods = cq_name in st.cq_covers_pods
-    cq_ok = st.cq_vector_ok
-    cq_vec = bool(cq_ok[ci]) if cq_ok is not None else False
-    if cq_vec and cq_live.spec.namespace_selector:
-        cq_vec = False   # selector evaluation stays on the host path
-    lr_summaries = scheduler.limit_range_summaries
-
-    n_upper = len(members) + len(admitted)
-    prio_l: list[int] = []
-    ts_l: list[float] = []
-    res_ts_l: list[float] = []
-    parked_l: list[bool] = []
-    ok_l: list[bool] = []
-    resume_l: list[int] = []      # flavor-walk start slot (0 = full)
-    key_l: list[str] = []
-    uid_l: list[str] = []
-    infos: list = []
-    req_mat = np.zeros((n_upper, R), dtype=np.int32)
-    usage_mat = np.zeros((n_upper, F), dtype=np.int32)
-    uses_mat = np.zeros((n_upper, F), dtype=bool)
-
-    i = 0
-    for info in members:
-        row = getattr(info, "_burst_row", None)
-        if row is None or row[0] != gen or row[1] != covers_pods:
-            row = (gen, *_static_row(info, st, covers_pods, qts))
-            info._burst_row = row
-        _, _, req_vec, static_ok, ts, prio, uid = row
-        key = info.key
-        key_l.append(key)
-        uid_l.append(uid)
-        prio_l.append(prio)
-        ts_l.append(ts)
-        res_ts_l.append(0.0)
-        parked_l.append(key in parked_keys)
-        req_mat[i] = req_vec
-        ok = cq_vec and static_ok
-        if ok:
-            obj = info.obj
-            if lr_summaries and lr_summaries.get(obj.namespace):
-                ok = False   # LimitRange bounds stay host-side
-            elif key in assumed or obj.admission is not None:
-                ok = False
-            elif obj.admission_check_states and any(
-                    stt.state in (AdmissionCheckState.RETRY,
-                                  AdmissionCheckState.REJECTED)
-                    for stt in obj.admission_check_states.values()):
-                ok = False
-        ok_l.append(ok)
-        from .solver import resume_start
-        resume_l.append(resume_start(info, cq_live, covers_pods))
-        infos.append(info)
-        i += 1
-    rec.n_pend = i
-
-    for info in admitted:
-        uv = admitted_usage_vec(info, st, scale_of, F)
-        if uv is None:
-            # not representable as a target/release row: the host
-            # handles its cycles (forest out of the envelope) and
-            # its finish via the ext_release path
-            rec.bad = True
-            continue
-        if compress:
-            # never candidate-gathered (no preempting CQ in this
-            # forest): fold into the aggregates; a mid-burst finish
-            # reaches the kernel via the ext_release fallback exactly
-            # as an unpacked key does today
-            rec.n_comp += 1
-            ts_r = info.obj.conditions[WL_QUOTA_RESERVED] \
-                .last_transition_time
-            if ts_r > rec.comp_max_ts:
-                rec.comp_max_ts = ts_r
-            continue
-        row = getattr(info, "_burst_row", None)
-        if row is None or row[0] != gen or row[1] != covers_pods:
-            row = (gen, *_static_row(info, st, covers_pods, qts))
-            info._burst_row = row
-        _, _, req_vec, static_ok, ts, prio, uid = row
-        key_l.append(info.key)
-        uid_l.append(uid)
-        prio_l.append(prio)
-        ts_l.append(ts)
-        parked_l.append(False)
-        obj = info.obj
-        cond = obj.conditions.get(WL_QUOTA_RESERVED)
-        res_ts_l.append(cond.last_transition_time)
-        req_mat[i] = req_vec
-        usage_mat[i], uses_mat[i] = uv
-        # post-eviction afterlife: the same dynamic gates pending
-        # rows get (LimitRange bounds, failed admission checks) —
-        # an in-burst-evicted row the kernel re-admits must honor
-        # everything the host nominate would; gating extra is safe
-        # (the cycle goes dirty), gating less diverges decisions
-        ok = cq_vec and static_ok
-        if ok:
-            if lr_summaries and lr_summaries.get(obj.namespace):
-                ok = False
-            elif obj.admission_check_states and any(
-                    stt.state in (AdmissionCheckState.RETRY,
-                                  AdmissionCheckState.REJECTED)
-                    for stt in obj.admission_check_states.values()):
-                ok = False
-        ok_l.append(ok)
-        resume_l.append(0)
-        infos.append(info)
-        i += 1
-    rec.n_adm = i - rec.n_pend
-
-    rec.keys = (np.asarray(key_l) if key_l
-                else np.empty(0, dtype="U1"))
-    rec.uids = (np.asarray(uid_l) if uid_l
-                else np.empty(0, dtype="U1"))
-    rec.prio = np.array(prio_l, dtype=np.int64)
-    rec.ts = np.array(ts_l, dtype=np.float64)
-    rec.res_ts = np.array(res_ts_l, dtype=np.float64)
-    rec.parked = np.array(parked_l, dtype=bool)
-    rec.ok = np.array(ok_l, dtype=bool)
-    rec.resume = np.array(resume_l, dtype=np.int32)
-    adm = np.zeros(i, dtype=bool)
-    adm[rec.n_pend:] = True
-    rec.adm = adm
-    rec.req = req_mat[:i]
-    # the flavors a row's PodSet may not take: all zero, and nothing to
-    # ask a row, unless a flavor of this queue carries labels or taints
-    rec.skip = (np.fromiter((skip_mask(info, st, ci) for info in infos),
-                            dtype=np.uint8, count=i)
-                if declares(st, ci) else np.zeros(i, dtype=np.uint8))
-    rec.usage = usage_mat[:i]
-    rec.uses = uses_mat[:i]
-    rec.u_row = u_row
-    rec.index_of_key = {k: j for j, k in enumerate(key_l)}
-    rec.infos = infos
+            keep_pend.append(j)
+            kept_pending.append((info, info.key in parked_keys,
+                                 *walk.moving(info, walk._static(info)[3])))
+        keep = np.concatenate((np.array(keep_pend, dtype=np.int64), keep))
+    n_pend = walk.n
+    for key, info in came:
+        walk.admitted(key, info)
+    walk.fill(rec, n_pend, old, keep, kept_pending)
+    rec.comp_max_ts = walk.comp_max_ts
+    rec.kept_idx = keep
+    rec.kept_at = n_pend
     return rec
 
 
@@ -1589,13 +1755,13 @@ def _roundtrips_clean(rec, q, cq_live, keys, covers_pods) -> bool:
             if rs is not None and rs.requeue_at is not None:
                 return False   # now backoff-parked: membership changed
             parked_now = True
-        idx = rec.index_of_key.get(key)
+        idx = rec._pend_index.get(key)
         if idx is None:
             # below the window cutoff is the only legitimate absence
             if not rec.truncated:
                 return False
             continue
-        if rec.infos[idx] is not info or idx >= rec.n_pend:
+        if rec.infos[idx] is not info:
             return False
         if bool(rec.parked[idx]) != parked_now:
             return False

@@ -10,39 +10,69 @@ every window boundary.
 This module, the one incremental pack behind ``pack_burst_cached``,
 keeps the packed universe *resident on the host* between
 windows (cache/arena.py PlaneArena slabs, slab-doubling growth) and
-patches it from the PackJournal:
+patches it from the PackJournal.  The first window of a structure, a
+forced-full drain (a lost or spurious journal touch, a global input
+changed) and a changed ``window`` pack in full (``_init_full``); every
+other window is a delta pack, at any share of dirty CQs, and costs
+what changed:
 
-- **dirty CQs** are re-walked (same stage-A ``_pack_cq_rows``) and only
-  their grid rows are cleared + rescattered;
+- **a dirty CQ's pending side** is found afresh (its heap and parking
+  lot cut to ``window + 2`` rows): a member whose ``Info`` the old
+  record holds keeps its row, and only what can move under an ``Info``
+  (parked, ``vec_ok``, the resume slot) is looked at again; its
+  **admitted side is kept by row events**: the cache journals which workload
+  joined or left a CQ's admitted table (``PackJournal.touch_admitted``),
+  the new record is the old one less the rows that went, with the rows
+  that came derived from their ``Info`` (``_pack_cq_rows`` with
+  ``old``).  A CQ dirty on its admitted side with no key (the cache's
+  plain ``touch``), one a row-grade check escalated, and one whose
+  heads position moved is walked whole; the table as it stands settles
+  every event, and one that disagrees with it sends its CQ to the
+  whole walk;
+- **the grid takes row-grade updates**: the rows that went are cleared
+  where the CQ shrank, the rows that came and the CQ's kept rows from
+  the first changed position on are written (``_write_rows``, one
+  scatter a plane for the whole window); ``row_of_key`` is patched;
 - **row-grade touches** (``PackJournal.touch_row``, deduped
   last-writer-wins by ``drain_into``) patch single cells — the dynamic
   bits a check-state flip can move (``vec_ok``, parked, resume) — with
   verify-and-escalate when anything structural moved;
 - **global ranks** (``wl_cycle_rank``, ``wl_uidrank``, ``adm_seq0``)
-  are maintained as order-statistic updates over sorted key arrays:
-  the dirty CQs' entries are deleted and merge-inserted (vectorized
-  ``searchsorted`` + ``insert``), and the dense rank planes are
-  rewritten only from the first shifted position onward — the
-  ``kueue_pack_rank_patches`` gauge counts exactly those rewrites.
+  are maintained as order-statistic updates over sorted key arrays
+  (``_Order``): the entries of the rows that went are found by key and
+  dropped, those of the rows that came merge-inserted (vectorized
+  ``searchsorted`` + ``insert``), the ``(ci, mi)`` locators of a CQ
+  whose rows changed place remapped in one pass, and the dense rank
+  planes are rewritten only from the first shifted position onward and
+  for the CQs that moved — the ``kueue_pack_rank_patches`` gauge counts
+  exactly those rewrites.
+
+``rows_repacked`` counts the rows derived from an ``Info`` (every row in
+a full pack; in a delta window the pending rows whose ``Info`` is new
+and the admitted rows that came), ``rows_reused`` the rest of the grid's
+rows.
 
 The reference sort orders are reproduced bit for bit by encoding each
 lexsort key into a fixed-width big-endian byte string (order-preserving
 integer/float maps + the ASCII workload key), so one memcmp order
 equals the reference ``np.lexsort`` order; non-ASCII or oversized keys
 poison the structure (``_StreamBail``), which ``pack_burst_cached``
-then packs in full every window.
+then packs in full every window.  An order that does not hold an entry
+the records say it holds (``_StreamDesync``: a duplicate uid, say)
+drops the state, and the window packs in full.
 
 The produced plan is bit-identical to ``pack_burst`` of the same live
-state (enforced by tests/test_streaming_pack.py); plans carry snapshot
-*copies* of the live planes, so consumers (pipeline speculation, the
-shard-resident scatter, parity tests) never observe later patches.
+state (enforced by tests/test_streaming_pack.py and
+tests/test_delta_pack.py); plans carry snapshot *copies* of the live
+planes, so consumers (pipeline speculation, the shard-resident scatter,
+parity tests) never observe later patches.
 """
 
 from __future__ import annotations
 
 import itertools
 import time
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import numpy as np
 
@@ -59,15 +89,19 @@ _SKEY_DT = np.dtype([("p", ">u8"), ("t", ">u8"), ("o", ">u4"),
                      ("k", f"S{_KEY_BYTES}")])
 _SKEY_S = f"S{_SKEY_DT.itemsize}"
 
-# above this dirty share a delta walk rebuilds nearly everything anyway
-# and the journal bookkeeping makes it slower than a plain full pack
-_DELTA_MAX_DIRTY_FRAC = 0.5
-_DELTA_MIN_DIRTY_CQS = 8
+# the admitted reservation-time order: memcmp order == (ts, key)
+_AKEY_DT = np.dtype([("t", ">u8"), ("k", f"S{_KEY_BYTES}")])
+_AKEY_S = f"S{_AKEY_DT.itemsize}"
 
 
 class _StreamBail(Exception):
     """This structure can't be streamed (non-ASCII / oversized keys):
     poison it, so that every window packs it in full."""
+
+
+class _StreamDesync(Exception):
+    """A maintained order does not hold an entry the records say it
+    holds: the state is dropped and this window packs in full."""
 
 
 def _enc_i64(x: np.ndarray) -> np.ndarray:
@@ -115,47 +149,92 @@ def _crank_skey(prio, ts, pos, kbytes) -> np.ndarray:
     return out.view(_SKEY_S).reshape(n)
 
 
+def _adm_skey(res_ts, kbytes) -> np.ndarray:
+    """Encoded key for the admitted reservation-time order: memcmp
+    order == (reservation ts, key); the key only tells entries of one
+    timestamp apart, so that one can be found and dropped."""
+    n = len(kbytes)
+    out = np.empty(n, dtype=_AKEY_DT)
+    out["t"] = _enc_f64(res_ts)
+    out["k"] = kbytes
+    return out.view(_AKEY_S).reshape(n)
+
+
 class _Order:
     """A maintained sorted total order: encoded sort keys plus the
-    parallel (ci, mi) grid locators of each entry."""
-    __slots__ = ("skey", "ci", "mi")
+    parallel (ci, mi) grid locators of each entry, and any further
+    parallel columns in ``aux``."""
+    __slots__ = ("skey", "ci", "mi", "aux")
 
-    def __init__(self, dtype):
+    def __init__(self, dtype, **aux):
         self.skey = np.empty(0, dtype=dtype)
         self.ci = np.empty(0, dtype=np.int32)
         self.mi = np.empty(0, dtype=np.int32)
+        self.aux = aux
 
-    def set(self, skey, ci, mi):
+    def set(self, skey, ci, mi, **aux):
         srt = np.argsort(skey, kind="stable")
         self.skey = skey[srt]
         self.ci = np.asarray(ci, np.int32)[srt]
         self.mi = np.asarray(mi, np.int32)[srt]
+        self.aux = {name: col[srt] for name, col in aux.items()}
 
-    def update(self, drop_cis, nskey, nci, nmi) -> Optional[int]:
-        """Delete every entry of the ``drop_cis`` CQs, merge-insert the
-        new entries; returns the first final position whose dense rank
-        may have changed (None = order untouched)."""
+    def update(self, drop, moved, new) -> Optional[int]:
+        """Row-grade update, in three steps.  ``drop`` = (skey, ci, mi)
+        of the entries that leave: each is found by its key and must
+        sit at the locator given.  ``moved`` = (mask [C], offset [C],
+        table): the entries of the CQs in ``mask`` get their ``mi``
+        remapped in place through ``table[offset[ci] + mi]``.  ``new``
+        = (skey, ci, mi, aux) is merge-inserted.  Returns the first
+        final position whose dense rank may have changed (None = the
+        ranks stand)."""
         first = None
-        if len(self.skey) and len(drop_cis):
-            dm = np.isin(self.ci, drop_cis)
-            if dm.any():
-                first = int(np.argmax(dm))
-                keep = ~dm
-                self.skey = self.skey[keep]
-                self.ci = self.ci[keep]
-                self.mi = self.mi[keep]
+        dsk, dci, dmi = drop
+        n = len(self.skey)
+        if len(dsk):
+            pos = np.searchsorted(self.skey, dsk)
+            at = np.minimum(pos, max(n - 1, 0))
+            if (n == 0 or (self.skey[at] != dsk).any()
+                    or (self.ci[at] != dci).any()
+                    or (self.mi[at] != dmi).any()):
+                raise _StreamDesync("dropped entry not in the order")
+            keep = np.ones(n, dtype=bool)
+            keep[pos] = False
+            first = int(pos.min())
+            self.skey = self.skey[keep]
+            self.ci = self.ci[keep]
+            self.mi = self.mi[keep]
+            self.aux = {name: col[keep] for name, col in self.aux.items()}
+        mask, offset, table = moved
+        if len(table) and len(self.ci):
+            sel = np.nonzero(mask[self.ci])[0]
+            if len(sel):
+                to = table[offset[self.ci[sel]] + self.mi[sel]]
+                if (to < 0).any():
+                    raise _StreamDesync("a kept entry points at a row "
+                                        "that went")
+                self.mi[sel] = to
+        nskey, nci, nmi, naux = new
         if len(nskey):
             srt = np.argsort(nskey, kind="stable")
             nskey = nskey[srt]
-            nci = np.asarray(nci, np.int32)[srt]
-            nmi = np.asarray(nmi, np.int32)[srt]
             pos = np.searchsorted(self.skey, nskey)
             fi = int(pos[0])
             first = fi if first is None else min(first, fi)
             self.skey = np.insert(self.skey, pos, nskey)
-            self.ci = np.insert(self.ci, pos, nci)
-            self.mi = np.insert(self.mi, pos, nmi)
+            self.ci = np.insert(self.ci, pos, np.asarray(nci, np.int32)[srt])
+            self.mi = np.insert(self.mi, pos, np.asarray(nmi, np.int32)[srt])
+            self.aux = {name: np.insert(col, pos, naux[name][srt])
+                        for name, col in self.aux.items()}
         return first
+
+    def rewrite_from(self, first, mask) -> np.ndarray:
+        """Positions whose rank cell has to be written again: from
+        ``first`` on, and every entry of a CQ whose rows moved."""
+        todo = mask[self.ci]
+        if first is not None:
+            todo[first:] = True
+        return np.nonzero(todo)[0]
 
 
 # row-plane layout: name -> (pad value, dtype, extra axis: None | "R" | "F")
@@ -186,9 +265,8 @@ class StreamState:
     chains from the same state (object identity is not enough — ids
     alias after GC)."""
     __slots__ = ("key", "records", "token", "arena",
-                 "crank", "uord",
-                 "adm_ts", "adm_ci", "adm_mi", "adm_seq_cache",
-                 "mi_of", "kb_of",
+                 "crank", "uord", "aord", "seq_base",
+                 "mi_of", "sk_of",
                  "n_rows_cq", "n_pend_cq", "maxabs_prio_cq", "bad_cq",
                  "strict_cq", "pos_cq", "cq_names_list",
                  "n_comp_cq", "comp_max_cq",
@@ -226,6 +304,12 @@ def _views(arena: PlaneArena, C: int, M: int, R: int, F: int,
     return out
 
 
+def _slab(view: np.ndarray) -> np.ndarray:
+    while view.base is not None:
+        view = view.base
+    return view
+
+
 def _reset_views(views: dict) -> None:
     for name, v in views.items():
         if name == "keys_grid":
@@ -236,61 +320,67 @@ def _reset_views(views: dict) -> None:
             pad = _agg.AGG_PLANES[name][0]
         else:
             pad = _ROW_PLANES[name][0]
-        base = v
-        while base.base is not None:
-            base = base.base
-        base[...] = pad
+        _slab(v)[...] = pad
 
 
-def _clear_cq(state: "StreamState", views: dict, ci: int) -> None:
-    """Reset one CQ's grid rows to pad across the FULL slab width, so
-    later M growth exposes pads, and unindex its keys."""
-    for name, (pad, _, _) in _ROW_PLANES.items():
-        if name == "death0":
+def _row_slabs(views: dict) -> list:
+    """(slab, pad) of every plane that holds a value a row: what a
+    CQ's cells past its last row are reset in, in the slabs, so that
+    later M growth exposes pads."""
+    out = [(_slab(views[name]), pad)
+           for name, (pad, _, _) in _ROW_PLANES.items()
+           if name != "death0" and not (
+               # every flavor plain: the one column stays 0
+               name == "wl_flavor_skip" and views[name].shape[1] == 1)]
+    out.append((_slab(views["keys_grid"]), None))
+    return out
+
+
+def _write_rows(state: "StreamState", views: dict, batch: list) -> None:
+    """Scatter records' rows into the grid planes (the per-row half;
+    global rank planes are patched separately), one write a plane for
+    the whole batch.  ``batch`` holds (ci, record, grid positions,
+    rows): every row of the record (rows None) or, of a record built
+    from the last window's, the rows named, the others lying in the
+    grid as they are.  The one writer of a delta window's rows."""
+    ci_l, mi_l, part = [], [], {a: [] for a in (
+        "req", "prio", "ok", "skip", "parked", "adm", "resume", "usage",
+        "uses", "keys")}
+    for ci, rec, mi, sel in batch:
+        views["u_cq0"][ci] = rec.u_row
+        _agg.agg_write_cq(views, ci, rec)
+        if sel is not None:
+            mi = mi[sel]
+        if not len(mi):
             continue
-        slab = views[name]
-        base = slab
-        while base.base is not None:
-            base = base.base
-        base[ci] = pad
-    views["u_cq0"][ci] = 0
-    _agg.agg_clear_cq(views, ci)
-    kg = views["keys_grid"]
-    base = kg
-    while base.base is not None:
-        base = base.base
-    base[ci] = None
-    old = state.records[ci]
-    if old is not None:
-        for k in old.index_of_key:
-            state.row_of_key.pop(k, None)
-
-
-def _write_cq(state: "StreamState", views: dict, ci: int, rec,
-              mi: np.ndarray) -> None:
-    """Scatter one CQ's freshly walked record into the grid planes
-    (the per-row half; global rank planes are patched separately)."""
-    if rec.n_rows:
-        views["wl_req"][ci, mi] = rec.req
-        views["wl_rank"][ci, mi] = mi
-        views["wl_prio"][ci, mi] = np.clip(
-            rec.prio, -_b.I32_MAX, _b.I32_MAX)
-        views["vec_ok"][ci, mi] = rec.ok
-        if views["wl_flavor_skip"].shape[1] > 1:   # else: a column of 0s
-            views["wl_flavor_skip"][ci, mi] = rec.skip
-        views["parked0"][ci, mi] = rec.parked
-        views["elig0"][ci, mi] = ~rec.parked & ~rec.adm
-        views["resume0"][ci, mi] = rec.resume
-        views["adm0"][ci, mi] = rec.adm
-        views["adm_usage0"][ci, mi] = rec.usage
-        views["adm_uses0"][ci, mi] = rec.uses
-        keys = rec.keys.tolist()
-        views["keys_grid"][ci, mi] = np.array(keys, dtype=object)
-        row_of = state.row_of_key
-        for k, m in zip(keys, mi.tolist()):
-            row_of[k] = (ci, int(m))
-    views["u_cq0"][ci] = rec.u_row
-    _agg.agg_write_cq(views, ci, rec)
+        ci_l.append(np.full(len(mi), ci, np.int32))
+        mi_l.append(mi)
+        for attr, acc in part.items():
+            col = getattr(rec, attr)
+            acc.append(col if sel is None else col[sel])
+    if not ci_l:
+        return
+    ci = np.concatenate(ci_l)
+    mi = np.concatenate(mi_l)
+    col = {attr: np.concatenate(acc) for attr, acc in part.items()}
+    views["wl_req"][ci, mi] = col["req"]
+    views["wl_rank"][ci, mi] = mi
+    views["wl_prio"][ci, mi] = np.clip(col["prio"], -_b.I32_MAX, _b.I32_MAX)
+    views["vec_ok"][ci, mi] = col["ok"]
+    if views["wl_flavor_skip"].shape[1] > 1:   # else: a column of 0s
+        views["wl_flavor_skip"][ci, mi] = col["skip"]
+    views["parked0"][ci, mi] = col["parked"]
+    views["elig0"][ci, mi] = ~col["parked"] & ~col["adm"]
+    views["resume0"][ci, mi] = col["resume"]
+    views["adm0"][ci, mi] = col["adm"]
+    # a cell's reservation rank is its last tenant's until the admitted
+    # order writes the row's own
+    views["adm_seq0"][ci, mi] = 0
+    views["adm_usage0"][ci, mi] = col["usage"]
+    views["adm_uses0"][ci, mi] = col["uses"]
+    keys = col["keys"].tolist()
+    views["keys_grid"][ci, mi] = np.array(keys, dtype=object)
+    state.row_of_key.update(zip(keys, zip(ci.tolist(), mi.tolist())))
 
 
 def _cq_mi(rec) -> np.ndarray:
@@ -314,14 +404,17 @@ def _row_patch_job(state, st, queues, cache, scheduler, ci, key):
     from ..api.types import AdmissionCheckState
     from .solver import resume_start
     rec = state.records[ci]
-    idx = rec.index_of_key.get(key)
+    idx = rec.find(key)
     if idx is None:
         # benign absences: below a window-truncation cutoff, or an
         # aggregate-compressed admitted row (its only row-grade bit,
         # vec_ok, never reaches the kernel — no candidates are drawn
         # from a compressible forest).  Membership changes always come
         # through hard journal touches, which dirty the CQ before row
-        # jobs run, so an unknown key here can't be a new workload.
+        # jobs run, so an unknown key here can't be a new workload; a
+        # workload left out as ``bad`` may have stopped being so.
+        if key in rec.bad_keys:
+            return _ESCALATE
         return None if (rec.truncated or rec.n_comp) else _ESCALATE
     cq_name = st.cq_names[ci]
     q = queues.queue_for(cq_name)
@@ -334,9 +427,10 @@ def _row_patch_job(state, st, queues, cache, scheduler, ci, key):
     if cq_vec and cq_live.spec.namespace_selector:
         cq_vec = False
     if idx >= rec.n_pend:
-        # admitted row: only the vec_ok gate can move at row grade
-        info = rec.infos[idx]
-        if cq_live.workloads.get(key) is not info:
+        # admitted row: only the vec_ok gate can move at row grade; a
+        # row's Info is the admitted table's, which an event keeps
+        info = cq_live.workloads.get(key)
+        if info is None:
             return _ESCALATE
         obj = info.obj
         from ..api.types import WL_EVICTED, WL_QUOTA_RESERVED
@@ -414,13 +508,9 @@ def _materialize(st, state, s, views, scheduler, dirty_cis, prev_token,
     # max_res_ts (the driver's admission clock) must also cover
     # aggregate-compressed admitted rows, whose reservation times live
     # only in the per-CQ comp_max_cq aggregate
-    if len(state.adm_ts):
-        uniq = np.unique(state.adm_ts)
-        seq_base = int(len(uniq)) + 2
-        max_res_ts = float(state.adm_ts[-1])
-    else:
-        seq_base = 2
-        max_res_ts = None
+    seq_base = state.seq_base
+    adm_ts = state.aord.aux["ts"]
+    max_res_ts = float(adm_ts[-1]) if len(adm_ts) else None
     comp_max = float(state.comp_max_cq.max(initial=-np.inf))
     if np.isfinite(comp_max):
         max_res_ts = (comp_max if max_res_ts is None
@@ -535,6 +625,27 @@ class _KeysView:
         return eq if eq is NotImplemented else not eq
 
 
+def _reseq(state, views, moved_cq) -> int:
+    """Dense reservation-time ranks over the admitted order (entries of
+    one timestamp share a rank); writes the cells whose rank changed and
+    those of the CQs in ``moved_cq``, whose rows changed place.  Returns
+    the cells written."""
+    aord = state.aord
+    ts = aord.aux["ts"]
+    if not len(ts):
+        state.seq_base = 2
+        return 0
+    first = np.ones(len(ts), dtype=bool)
+    first[1:] = ts[1:] != ts[:-1]
+    seq = np.cumsum(first, dtype=np.int32)
+    state.seq_base = int(seq[-1]) + 2
+    todo = np.nonzero((seq != aord.aux["seq"]) | moved_cq[aord.ci])[0]
+    aord.aux["seq"] = seq
+    if len(todo):
+        views["adm_seq0"][aord.ci[todo], aord.mi[todo]] = seq[todo]
+    return len(todo)
+
+
 def _init_full(st, queues, cache, scheduler, key, min_m, window, arena,
                stats, t0):
     """Full streaming (re)build: walk every CQ, reset + refill the
@@ -613,12 +724,16 @@ def _init_full(st, queues, cache, scheduler, key, min_m, window, arena,
         mi_a = np.empty(n, dtype=np.int32)
         mi_a[order] = mi_sorted
 
+        # a record row's grid position and its key in the crank order:
+        # where a later window finds the row, in the grid and among its
+        # CQ's rows
+        sk_all = _crank_skey(prio_a, ts_a, pos_a, kb_all)
         state.mi_of = {}
-        state.kb_of = {}
+        state.sk_of = {}
         for ci in range(C):
             lo, hi = int(bounds[ci]), int(bounds[ci + 1])
             state.mi_of[ci] = mi_a[lo:hi]
-            state.kb_of[ci] = kb_all[lo:hi]
+            state.sk_of[ci] = sk_all[lo:hi]
 
         if n:
             views["wl_req"][ci_a, mi_a] = cat("req", np.int32)
@@ -647,8 +762,7 @@ def _init_full(st, queues, cache, scheduler, key, min_m, window, arena,
 
         # maintained global orders + their dense rank planes
         state.crank = _Order(_SKEY_S)
-        state.crank.set(_crank_skey(prio_a, ts_a, pos_a, kb_all),
-                        ci_a, mi_a)
+        state.crank.set(sk_all, ci_a, mi_a)
         if n:
             views["wl_cycle_rank"][state.crank.ci, state.crank.mi] = \
                 np.arange(n, dtype=np.int32)
@@ -667,19 +781,11 @@ def _init_full(st, queues, cache, scheduler, key, min_m, window, arena,
             views["wl_uidrank"][state.uord.ci, state.uord.mi] = \
                 np.arange(n_uord, dtype=np.int32)
         am = np.nonzero(adm_a)[0]
-        ats = res_ts_a[am]
-        aord = np.argsort(ats, kind="stable")
-        state.adm_ts = ats[aord]
-        state.adm_ci = ci_a[am][aord]
-        state.adm_mi = mi_a[am][aord]
-        if len(state.adm_ts):
-            uniq = np.unique(state.adm_ts)
-            state.adm_seq_cache = (np.searchsorted(uniq, state.adm_ts)
-                                   + 1).astype(np.int32)
-            views["adm_seq0"][state.adm_ci, state.adm_mi] = \
-                state.adm_seq_cache
-        else:
-            state.adm_seq_cache = np.empty(0, np.int32)
+        state.aord = _Order(_AKEY_S)
+        state.aord.set(_adm_skey(res_ts_a[am], kb_all[am]),
+                       ci_a[am], mi_a[am], ts=res_ts_a[am],
+                       seq=np.zeros(len(am), np.int32))
+        _reseq(state, views, np.zeros(C, dtype=bool))
 
         _bump(stats, "burst_full_packs")
         _bump(stats, "stream_full_packs")
@@ -703,6 +809,265 @@ def _note_ms(stats, t0, delta=False):
             stats["delta_pack_s"] = stats.get("delta_pack_s", 0.0) + dt
 
 
+def _walk_dirty(state, st, ci, whole, events, walk_args):
+    """Stage A for one dirty CQ: its new record, built on the old one
+    unless ``whole``."""
+    queues, cache, scheduler, assumed, scale_of, window, comp_cq = walk_args
+    old = None if whole else state.records[ci]
+    old_idx = {}
+    if old is not None and events:
+        # a key's row in the old record, by way of its grid position
+        old_mi = state.mi_of[ci]
+        at = np.empty(len(old_mi), dtype=np.int32)
+        at[old_mi] = np.arange(len(old_mi), dtype=np.int32)
+        row_of = state.row_of_key
+        for key in events:
+            loc = row_of.get(key)
+            if loc is not None and loc[0] == ci:
+                old_idx[key] = int(at[loc[1]])
+    rec = _b._pack_cq_rows(st, ci, int(state.pos_cq[ci]), queues, cache,
+                           scheduler, assumed, scale_of, window,
+                           compress=(comp_cq is not None
+                                     and bool(comp_cq[ci])),
+                           old=old, events=events, old_idx=old_idx)
+    return None if rec is _b._PACK_FAIL else rec
+
+
+class _Placed(NamedTuple):
+    """Where one walked CQ's rows go (``_place_rows``)."""
+    ci: int
+    rec: object
+    sk: np.ndarray        # crank key a row of the record
+    ub_came: np.ndarray   # uid key a row derived
+    mi: np.ndarray        # grid position a row of the record
+    gone: np.ndarray      # the old record's rows that went
+    came: np.ndarray      # the record's rows derived this window
+    to: Optional[np.ndarray]   # old grid position -> new, -1 = went;
+    #                            None: no kept row changed place
+
+
+def _place_rows(state, recs) -> list:
+    """Where the walked records' rows go.  The rows derived this window
+    (every row of a record walked whole) get their order keys in one
+    encoding for all the CQs; a record built on the old one then keeps
+    its kept rows' relative places: they close up over the rows that
+    went and open up for the rows that came, each found its place among
+    them by its key in the CQ's (-prio, ts, key) order, which is the
+    crank order of one CQ.  Returns a ``_Placed`` a CQ."""
+    cis = np.fromiter((r.ci for r in recs), np.int32, len(recs))
+    came_of = []
+    for rec in recs:
+        if rec.kept_idx is None:
+            came_of.append(np.arange(rec.n_rows, dtype=np.int64))
+        else:
+            came_of.append(np.concatenate((
+                np.arange(rec.kept_at, dtype=np.int64),
+                np.arange(rec.kept_at + len(rec.kept_idx), rec.n_rows,
+                          dtype=np.int64))))
+    counts = np.fromiter((len(c) for c in came_of), np.int64, len(recs))
+    ends = np.cumsum(counts)
+
+    def derived(attr, dtype):
+        parts = [getattr(r, attr)[c] for r, c in zip(recs, came_of)
+                 if len(c)]
+        return np.concatenate(parts) if parts else np.empty(0, dtype)
+
+    ub_all = _enc_str(derived("uids", "U1"), _UID_BYTES)
+    sk_all = _crank_skey(
+        derived("prio", np.int64), derived("ts", np.float64),
+        np.repeat(state.pos_cq[cis], counts),
+        _enc_str(derived("keys", "U1"), _KEY_BYTES))
+    out = []
+    for rec, came, end in zip(recs, came_of, ends.tolist()):
+        ci = rec.ci
+        sk_came = sk_all[end - len(came):end]
+        ub_came = ub_all[end - len(came):end]
+        old_mi = state.mi_of[ci]
+        n_old, n = len(old_mi), rec.n_rows
+        kept, rec.kept_idx = rec.kept_idx, None
+        if kept is None:
+            out.append(_Placed(ci, rec, sk_came, ub_came, _cq_mi(rec),
+                               np.arange(n_old, dtype=np.int64), came,
+                               None))
+            continue
+        P, K = rec.kept_at, len(kept)
+        if K == n_old == n:
+            # every row kept and none derived (``came`` is empty, and
+            # so is what went): the rows lie where they lay
+            out.append(_Placed(ci, rec, state.sk_of[ci][kept], ub_came,
+                               old_mi[kept], gone=came, came=came, to=None))
+            continue
+        gone = np.ones(n_old, dtype=bool)
+        gone[kept] = False
+        gone = np.nonzero(gone)[0]
+        mi_kept = old_mi[kept]
+        if len(gone):
+            mi_kept = mi_kept - np.searchsorted(
+                np.sort(old_mi[gone]), mi_kept).astype(np.int32)
+        sk = np.empty(n, dtype=_SKEY_S)
+        mi = np.empty(n, dtype=np.int32)
+        sk[P:P + K] = state.sk_of[ci][kept]
+        if len(came):
+            sk[came] = sk_came
+            srt = np.argsort(sk_came, kind="stable")
+            in_order = np.empty(K, dtype=_SKEY_S)
+            in_order[mi_kept] = sk[P:P + K]
+            at = np.searchsorted(in_order, sk_came[srt]).astype(np.int32)
+            mi[came[srt]] = at + np.arange(len(came), dtype=np.int32)
+            mi_kept = mi_kept + np.searchsorted(
+                at, mi_kept, side="right").astype(np.int32)
+        mi[P:P + K] = mi_kept
+        to = np.full(n_old, -1, dtype=np.int32)
+        to[old_mi[kept]] = mi_kept
+        out.append(_Placed(ci, rec, sk, ub_came, mi, gone, came, to))
+    return out
+
+
+def _patch_grid(st, state, statics, arena, placed, pos_dirty_cis, min_m):
+    """Stage B of a delta window, row grade: the rows that went leave
+    the grid, ``row_of_key`` and the three maintained orders; the rows
+    that came are written and merge-inserted; a CQ's kept rows that
+    changed place are written again at their new cells (``_write_rows``,
+    the one writer of a window's rows) and their locators in the orders
+    are remapped in place.  Returns (the grid's views, rank cells
+    rewritten, rows derived)."""
+    C = len(st.cq_names)
+    for p in placed:
+        ci, rec = p.ci, p.rec
+        state.n_rows_cq[ci] = rec.n_rows
+        state.n_pend_cq[ci] = rec.n_pend
+        state.bad_cq[ci] = rec.bad
+        state.strict_cq[ci] = rec.strict
+        state.n_comp_cq[ci] = rec.n_comp
+        state.comp_max_cq[ci] = rec.comp_max_ts
+        state.maxabs_prio_cq[ci] = int(np.abs(rec.prio).max(initial=0))
+    rows_per_cq = int(state.n_rows_cq.max(initial=0))
+    state.M = M = max(_bucket(rows_per_cq, minimum=4), min_m)
+    views = _views(arena, C, M, len(st.resource_names),
+                   max(1, len(st.fr_index)), mask_plane_width(st, M))
+    slabs = _row_slabs(views)
+    row_of = state.row_of_key
+
+    # what leaves and what joins each order, and the CQs whose kept
+    # rows changed place with the map of their old positions to the new
+    moved = np.zeros(C, dtype=bool)
+    offset = np.zeros(C, dtype=np.int64)
+    tables, n_table = [], 0
+    gone_cols = {c: [] for c in ("ci", "mi", "sk", "uids", "adm", "ts")}
+    came_cols = {c: [] for c in ("ci", "mi", "sk", "ub", "adm", "ts")}
+    batch = []
+    repacked = 0
+    for ci, rec, sk, ub_came, mi, gone, came, to in placed:
+        old = state.records[ci]
+        old_mi = state.mi_of[ci]
+        n_old = len(old_mi)
+        first = n_old    # the first cell of the CQ that changes
+        if len(gone):
+            mi_gone = old_mi[gone]
+            first = int(mi_gone.min())
+            for c, col in (("ci", np.full(len(gone), ci, np.int32)),
+                           ("mi", mi_gone), ("sk", state.sk_of[ci][gone]),
+                           ("uids", old.uids[gone]), ("adm", old.adm[gone]),
+                           ("ts", old.res_ts[gone])):
+                gone_cols[c].append(col)
+            for k in old.keys[gone].tolist():
+                row_of.pop(k, None)
+        if len(came):
+            mi_came = mi[came]
+            first = min(first, int(mi_came.min()))
+            for c, col in (("ci", np.full(len(came), ci, np.int32)),
+                           ("mi", mi_came),
+                           ("sk", sk if to is None else sk[came]),
+                           ("ub", ub_came), ("adm", rec.adm[came]),
+                           ("ts", rec.res_ts[came])):
+                came_cols[c].append(col)
+            repacked += len(came)
+        if to is not None and first < n_old:
+            moved[ci] = True
+            offset[ci] = n_table
+            tables.append(to)
+            n_table += n_old
+        if rec.n_rows < n_old:
+            # the cells of a CQ that shrank, past its last row: every
+            # other cell from ``first`` on is written over below ...
+            for slab, pad in slabs:
+                slab[ci, rec.n_rows:n_old] = pad
+        # ... and a pending row kept may have moved under its Info
+        batch.append((ci, rec, mi, None if first == 0 else np.nonzero(
+            (mi >= first) | ~rec.adm)[0]))
+    # the writes follow every CQ's removals: a key that left one CQ for
+    # another keeps the locator of the row it has now
+    _write_rows(state, views, batch)
+    for p in placed:
+        state.records[p.ci] = p.rec
+        state.mi_of[p.ci] = p.mi
+        state.sk_of[p.ci] = p.sk
+    walked_cis = {p.ci for p in placed}
+
+    def cat(cols, c, dtype):
+        return (np.concatenate(cols[c]) if cols[c]
+                else np.empty(0, dtype=dtype))
+
+    g = {c: cat(gone_cols, c, d) for c, d in (
+        ("ci", np.int32), ("mi", np.int32), ("sk", _SKEY_S), ("uids", "U1"),
+        ("adm", bool), ("ts", np.float64))}
+    j = {c: cat(came_cols, c, d) for c, d in (
+        ("ci", np.int32), ("mi", np.int32), ("sk", _SKEY_S),
+        ("ub", f"S{_UID_BYTES}"), ("adm", bool), ("ts", np.float64))}
+    table = (np.concatenate(tables) if tables
+             else np.empty(0, dtype=np.int32))
+    remap = (moved, offset, table)
+
+    # cycle-order rank; a CQ whose heads position moved takes new keys
+    drop = [g["sk"], g["ci"], g["mi"]]
+    join = [j["sk"], j["ci"], j["mi"]]
+    for ci in pos_dirty_cis:
+        n = state.records[ci].n_rows
+        if ci in walked_cis or not n:
+            continue
+        where = (np.full(n, ci, np.int32), state.mi_of[ci])
+        sk = state.sk_of[ci]
+        drop = [np.concatenate(p) for p in zip(drop, (sk, *where))]
+        sk = sk.copy()
+        sk.view(_SKEY_DT)["o"] = state.pos_cq[ci]
+        state.sk_of[ci] = sk
+        join = [np.concatenate(p) for p in zip(join, (sk, *where))]
+    rank_patches = 0
+    first = state.crank.update(tuple(drop), remap, (*join, {}))
+    todo = state.crank.rewrite_from(first, moved)
+    if len(todo):
+        views["wl_cycle_rank"][state.crank.ci[todo], state.crank.mi[todo]] \
+            = todo.astype(np.int32)
+        rank_patches += len(todo)
+
+    # uid rank: head-pack keeps exempt (never-candidate) CQs out of the
+    # maintained uid order, mirroring the _init_full budget filter
+    in_uord = (~statics.comp_cq if _agg.head_pack_enabled()
+               else np.ones(C, dtype=bool))
+    gu, ju = in_uord[g["ci"]], in_uord[j["ci"]]
+    first = state.uord.update(
+        (_enc_str(g["uids"][gu], _UID_BYTES), g["ci"][gu], g["mi"][gu]),
+        remap, (j["ub"][ju], j["ci"][ju], j["mi"][ju], {}))
+    todo = state.uord.rewrite_from(first, moved)
+    if len(todo):
+        views["wl_uidrank"][state.uord.ci[todo], state.uord.mi[todo]] = \
+            todo.astype(np.int32)
+        rank_patches += len(todo)
+
+    # admitted reservation-seq: ranks shared by equal timestamps
+    ga, ja = g["adm"], j["adm"]
+    state.aord.update(
+        (_adm_skey(g["ts"][ga], g["sk"][ga].view(_SKEY_DT)["k"]),
+         g["ci"][ga], g["mi"][ga]),
+        remap,
+        (_adm_skey(j["ts"][ja], j["sk"][ja].view(_SKEY_DT)["k"]),
+         j["ci"][ja], j["mi"][ja],
+         {"ts": j["ts"][ja],
+          "seq": np.full(int(ja.sum()), -1, np.int32)}))
+    rank_patches += _reseq(state, views, moved)
+    return views, rank_patches, repacked
+
+
 def pack_burst_streaming(structure, queues, cache, scheduler, clock,
                          state=None, min_m: int = 0, window: int = 0,
                          stats=None):
@@ -712,20 +1077,26 @@ def pack_burst_streaming(structure, queues, cache, scheduler, clock,
     t0 = time.perf_counter()
     key = (st.generation, st.resource_scale.tobytes(),
            tuple(st.cq_names), window, _agg.agg_planes_enabled())
+    # hard dirt by journal: the queue manager's is the pending side's;
+    # the cache's, where it names no workload, is a whole queue's
     dirty: set = set()
+    whole: set = set()
     soft: dict = {}
     rows: dict = {}
+    events: dict = {}
     jranges: list = []
     force_full = False
     with _span("burst.pack.drain"):
-        for j in (getattr(queues, "pack_journal", None),
-                  getattr(cache, "pack_journal", None)):
+        for j, into in ((getattr(queues, "pack_journal", None), dirty),
+                        (getattr(cache, "pack_journal", None), whole)):
             if j is None:
                 force_full = True
             else:
                 force_full |= j.drain_into(
-                    dirty, soft, row_of=st.cq_index, ranges_out=jranges,
-                    rows_out=rows)
+                    into, soft, row_of=st.cq_index, ranges_out=jranges,
+                    rows_out=rows, admitted_out=events)
+        dirty |= whole
+        dirty.update(events)
     arena = getattr(cache, "_pack_arena", None)
     if arena is None:
         arena = cache._pack_arena = PlaneArena()
@@ -752,28 +1123,29 @@ def pack_burst_streaming(structure, queues, cache, scheduler, clock,
                         state.records[ci], queues.queue_for(name),
                         cache.cluster_queue(name), skeys,
                         name in st.cq_covers_pods):
-                    dirty.add(name)
+                    dirty.add(name)   # the pending side's own facts
             row_jobs = []
             rows_verified = 0
             for wkey, name in rows.items():
                 ci = index_of.get(name)
-                if ci is None or name in dirty:
+                if ci is None or name in whole:
+                    continue
+                if name in dirty:
+                    # the pending side is walked anyway; an admitted
+                    # row is derived again from the table as it stands
+                    events.setdefault(name, {}).setdefault(wkey, None)
                     continue
                 job = _row_patch_job(state, st, queues, cache, scheduler,
                                      ci, wkey)
                 if job is _ESCALATE:
                     dirty.add(name)
+                    whole.add(name)
                 elif job is not None:
                     row_jobs.append(job)
                 else:
                     rows_verified += 1
             if rows_verified:
                 _bump(stats, "pack_rows_verified", rows_verified)
-
-        if len(dirty) > max(_DELTA_MIN_DIRTY_CQS,
-                            _DELTA_MAX_DIRTY_FRAC * C):
-            return _init_full(st, queues, cache, scheduler, key, min_m,
-                              window, arena, stats, t0)
 
         with _span("burst.pack.walk"):
             # heads-enumeration position drift (CQs joined/left the queue
@@ -794,23 +1166,21 @@ def pack_burst_streaming(structure, queues, cache, scheduler, clock,
 
             # stage A over the dirty CQs only; encode before mutating so a
             # bail leaves the state coherent
-            assumed = cache.assumed_workloads
             scale_of = {r: int(st.resource_scale[i])
                         for i, r in enumerate(st.resource_names)}
             statics = _b._pack_statics(st, cache)
-            comp_cq = (statics.comp_cq if _agg.agg_planes_enabled()
-                       else None)
+            walk_args = (queues, cache, scheduler, cache.assumed_workloads,
+                         scale_of, window,
+                         statics.comp_cq if _agg.agg_planes_enabled()
+                         else None)
+            # a CQ whose rows all take a new crank key is walked whole
+            whole_cis = {index_of[name] for name in whole
+                         if name in index_of} | set(pos_dirty_cis)
+
             def _walk_one(ci):
-                rec = _b._pack_cq_rows(st, ci, int(state.pos_cq[ci]),
-                                       queues, cache, scheduler, assumed,
-                                       scale_of, window,
-                                       compress=(comp_cq is not None
-                                                 and bool(comp_cq[ci])))
-                if rec is _b._PACK_FAIL:
-                    return None
-                kb = _enc_str(rec.keys, _KEY_BYTES)
-                ub = _enc_str(rec.uids, _UID_BYTES)
-                return (ci, rec, kb, ub, _cq_mi(rec))
+                return _walk_dirty(state, st, ci, ci in whole_cis,
+                                   events.get(st.cq_names[ci], {}),
+                                   walk_args)
 
             cis = sorted(ci for name in dirty
                          if (ci := index_of.get(name)) is not None)
@@ -829,138 +1199,19 @@ def pack_burst_streaming(structure, queues, cache, scheduler, clock,
                 walked = [w for part in parts for w in part]
             else:
                 walked = [_walk_one(ci) for ci in cis]
-            if any(w is None for w in walked):
+            if any(rec is None for rec in walked):
                 return None, None, False
+            placed = _place_rows(state, walked)
 
         with _span("burst.pack.grid"):
-            for ci, rec, kb, ub, mi in walked:
-                state.n_rows_cq[ci] = rec.n_rows
-                state.n_pend_cq[ci] = rec.n_pend
-                state.bad_cq[ci] = rec.bad
-                state.strict_cq[ci] = rec.strict
-                state.n_comp_cq[ci] = rec.n_comp
-                state.comp_max_cq[ci] = rec.comp_max_ts
-                state.maxabs_prio_cq[ci] = int(
-                    np.abs(rec.prio).max(initial=0))
-            rows_per_cq = int(state.n_rows_cq.max(initial=0))
-            state.M = M = max(_bucket(rows_per_cq, minimum=4), min_m)
-            R = len(st.resource_names)
-            F = max(1, len(st.fr_index))
-            views = _views(arena, C, M, R, F, mask_plane_width(st, M))
-
-            for ci, rec, kb, ub, mi in walked:
-                _clear_cq(state, views, ci)
-                _write_cq(state, views, ci, rec, mi)
-                state.records[ci] = rec
-                state.mi_of[ci] = mi
-                state.kb_of[ci] = kb
-
-            rank_patches = 0
-            # cycle-order rank: drop dirty + pos-moved CQ entries, merge the
-            # fresh ones back in, rewrite the dense rank suffix
-            walked_cis = [w[0] for w in walked]
-            crank_drop = np.asarray(walked_cis + pos_dirty_cis, np.int32)
-            ins_sk, ins_ci, ins_mi = [], [], []
-            for ci, rec, kb, ub, mi in walked:
-                if rec.n_rows:
-                    ins_sk.append(_crank_skey(
-                        rec.prio, rec.ts,
-                        np.full(rec.n_rows, state.pos_cq[ci], np.int64), kb))
-                    ins_ci.append(np.full(rec.n_rows, ci, np.int32))
-                    ins_mi.append(mi)
-            for ci in pos_dirty_cis:
-                rec = state.records[ci]
-                if rec.n_rows:
-                    ins_sk.append(_crank_skey(
-                        rec.prio, rec.ts,
-                        np.full(rec.n_rows, state.pos_cq[ci], np.int64),
-                        state.kb_of[ci]))
-                    ins_ci.append(np.full(rec.n_rows, ci, np.int32))
-                    ins_mi.append(state.mi_of[ci])
-            sfrom = state.crank.update(
-                crank_drop,
-                np.concatenate(ins_sk) if ins_sk
-                else np.empty(0, _SKEY_S),
-                np.concatenate(ins_ci) if ins_ci else (),
-                np.concatenate(ins_mi) if ins_mi else ())
-            if sfrom is not None:
-                ntot = len(state.crank.skey)
-                views["wl_cycle_rank"][
-                    state.crank.ci[sfrom:], state.crank.mi[sfrom:]] = \
-                    np.arange(sfrom, ntot, dtype=np.int32)
-                rank_patches += ntot - sfrom
-
-            # uid rank: same mechanism, dirty CQs only; head-pack keeps
-            # exempt (never-candidate) CQs out of the maintained uid order,
-            # mirroring the _init_full budget filter
-            head_pack = _agg.head_pack_enabled()
-            ins_sk, ins_ci, ins_mi = [], [], []
-            for ci, rec, kb, ub, mi in walked:
-                if rec.n_rows and not (head_pack and statics.comp_cq[ci]):
-                    ins_sk.append(ub)
-                    ins_ci.append(np.full(rec.n_rows, ci, np.int32))
-                    ins_mi.append(mi)
-            sfrom = state.uord.update(
-                np.asarray(walked_cis, np.int32),
-                np.concatenate(ins_sk) if ins_sk
-                else np.empty(0, f"S{_UID_BYTES}"),
-                np.concatenate(ins_ci) if ins_ci else (),
-                np.concatenate(ins_mi) if ins_mi else ())
-            if sfrom is not None:
-                ntot = len(state.uord.skey)
-                views["wl_uidrank"][
-                    state.uord.ci[sfrom:], state.uord.mi[sfrom:]] = \
-                    np.arange(sfrom, ntot, dtype=np.int32)
-                rank_patches += ntot - sfrom
-
-            # admitted reservation-seq: maintain the sorted ts multiset,
-            # recompute dense seqs vectorized, scatter only changed cells
-            if walked:
-                wset = np.asarray(walked_cis, np.int32)
-                keep = ~np.isin(state.adm_ci, wset) \
-                    if len(state.adm_ci) else np.empty(0, bool)
-                a_ts = state.adm_ts[keep]
-                a_ci = state.adm_ci[keep]
-                a_mi = state.adm_mi[keep]
-                a_sq = state.adm_seq_cache[keep]
-                nts, nci, nmi = [], [], []
-                for ci, rec, kb, ub, mi in walked:
-                    if rec.n_adm:
-                        am = rec.adm
-                        nts.append(rec.res_ts[am])
-                        nci.append(np.full(int(am.sum()), ci, np.int32))
-                        nmi.append(mi[am])
-                if nts:
-                    nts = np.concatenate(nts)
-                    srt = np.argsort(nts, kind="stable")
-                    nts = nts[srt]
-                    nci = np.concatenate(nci)[srt]
-                    nmi = np.concatenate(nmi)[srt]
-                    pos = np.searchsorted(a_ts, nts)
-                    a_ts = np.insert(a_ts, pos, nts)
-                    a_ci = np.insert(a_ci, pos, nci)
-                    a_mi = np.insert(a_mi, pos, nmi)
-                    a_sq = np.insert(a_sq, pos,
-                                     np.full(len(nts), -1, np.int32))
-                state.adm_ts, state.adm_ci, state.adm_mi = a_ts, a_ci, a_mi
-                if len(a_ts):
-                    uniq = np.unique(a_ts)
-                    seq_all = (np.searchsorted(uniq, a_ts)
-                               + 1).astype(np.int32)
-                    chg = seq_all != a_sq
-                    if chg.any():
-                        views["adm_seq0"][a_ci[chg], a_mi[chg]] = \
-                            seq_all[chg]
-                        rank_patches += int(chg.sum())
-                    state.adm_seq_cache = seq_all
-                else:
-                    state.adm_seq_cache = np.empty(0, np.int32)
+            views, rank_patches, repacked = _patch_grid(
+                st, state, statics, arena, placed, pos_dirty_cis, min_m)
 
             # row-grade patches (deduped by the journal): single cells.
             # A job queued before a later row escalated its CQ to dirty is
             # stale — the re-walk rebuilt the record (and row order), so its
             # idx no longer addresses the row it was derived from.
-            wset_cis = set(walked_cis)
+            wset_cis = {rec.ci for rec in walked}
             row_jobs = [j for j in row_jobs if j[0] not in wset_cis]
             for ci, idx, parked_now, resume_now, ok_now in row_jobs:
                 rec = state.records[ci]
@@ -977,7 +1228,6 @@ def pack_burst_streaming(structure, queues, cache, scheduler, clock,
 
             prev_token = state.token
             state.token = next(StreamState._next_token)
-            repacked = sum(r.n_rows for _, r, _, _, _ in walked)
             _bump(stats, "burst_delta_packs")
             _bump(stats, "stream_packs")
             _bump(stats, "rows_repacked", repacked)
@@ -988,12 +1238,15 @@ def pack_burst_streaming(structure, queues, cache, scheduler, clock,
             if int(state.n_pend_cq.sum()) == 0:
                 _note_ms(stats, t0)
                 return None, state, False
-            s = _b._pack_statics(st, cache)
-            dirty_cis = set(walked_cis) | {j[0] for j in row_jobs}
-            plan = _materialize(st, state, s, views, scheduler, dirty_cis,
-                                prev_token, rank_patches, stats)
+            dirty_cis = wset_cis | {j[0] for j in row_jobs}
+            plan = _materialize(st, state, statics, views, scheduler,
+                                dirty_cis, prev_token, rank_patches, stats)
             _note_ms(stats, t0, delta=True)
             return plan, state, True
+    except _StreamDesync:
+        _bump(stats, "stream_pack_desyncs")
+        return _init_full(st, queues, cache, scheduler, key, min_m,
+                          window, arena, stats, t0)
     except _StreamBail:
         st._stream_poison = True
         _bump(stats, "stream_pack_bails")

@@ -4,13 +4,23 @@
 incremental burst pack.  The queue manager and the cache each own one;
 their mutators mark the ClusterQueues whose packed rows may have
 changed.  The burst pack (ops/burst.py pack_burst_cached) drains both
-journals at every window boundary and re-walks only the dirty CQs,
-reusing the persistent per-CQ row records for everything else.
+journals at every window boundary and re-walks only what changed of
+the dirty CQs, reusing the persistent per-CQ row records for everything
+else.
 
-Two dirt grades keep the hot path clean:
+Three dirt grades keep the hot path clean:
 
 - ``touch``: the CQ's row set or row facts changed (arrival, deletion,
-  park/unpark, admission accounting) — the CQ must be re-walked.
+  park/unpark, admission accounting) — the CQ must be re-walked: its
+  pending side where the queue manager's journal says so, the whole CQ
+  where the cache's does.
+- ``touch_admitted``: one workload joined or left the CQ's admitted
+  table (the cache's add, assume, forget and delete).  The CQ is dirty
+  as under ``touch``, and the channel also keeps which key, and whether
+  it came or went: the streaming pack re-walks the pending side and
+  drops or derives the rows named, keeping the CQ's other admitted
+  rows.  ``drain_into`` hands the channel to the consumer that asks for
+  it (``admitted_out``) and gives every other the queue-grade mark.
 - ``note_roundtrip``: a head was popped and requeued straight back
   (every scheduled head, every cycle).  The row set is unchanged; only
   per-row dynamic facts (the flavor-resume bit, the parked bit) could
@@ -81,8 +91,8 @@ from ..features import env_int
 
 
 class PackJournal:
-    __slots__ = ("dirty", "dirty_all", "soft", "rows", "tainted",
-                 "snap_dirty", "snap_all")
+    __slots__ = ("dirty", "dirty_all", "soft", "rows", "admitted",
+                 "tainted", "snap_dirty", "snap_all")
 
     def __init__(self):
         self.dirty: set[str] = set()
@@ -93,6 +103,13 @@ class PackJournal:
         # that don't understand row grade escalate each entry to its CQ
         # in drain_into.
         self.rows: dict[str, str] = {}
+        # Admitted-side row events: CQ -> {workload key: came (True) or
+        # went (False)}, the last event of a key standing for it.  The
+        # queue is dirty as under ``touch``, and the consumer that asks
+        # for this channel also learns which rows of its admitted table
+        # to drop and which to derive; one that does not gets the
+        # queue-grade mark in drain_into.
+        self.admitted: dict[str, dict[str, bool]] = {}
         self.dirty_all = True
         # chaos: a simulated lost update (journal.drop_touch) taints the
         # journal; the next drain reports dirty-all so the pack falls
@@ -158,8 +175,28 @@ class PackJournal:
         self.rows[key] = cq_name
         self.snap_dirty.add(cq_name)
 
+    def touch_admitted(self, cq_name: str, key: str, came: bool) -> None:
+        """One workload joined (``came``) or left the CQ's admitted
+        table: a hard touch of the queue that also names the row, so
+        the streaming pack keeps the queue's other admitted rows as
+        they are."""
+        if _chaos.ACTIVE is not None:
+            if _chaos.ACTIVE.hit("journal.drop_touch") is not None:
+                self.tainted = True
+                self.snap_all = True
+                return
+            if _chaos.ACTIVE.hit("journal.spurious_dirty_all") is not None:
+                self.dirty_all = True
+                self.snap_all = True
+        events = self.admitted.get(cq_name)
+        if events is None:
+            events = self.admitted[cq_name] = {}
+        events[key] = came
+        self.snap_dirty.add(cq_name)
+
     def drain_into(self, dirty: set, soft: dict, row_of: dict = None,
-                   ranges_out: list = None, rows_out: dict = None) -> bool:
+                   ranges_out: list = None, rows_out: dict = None,
+                   admitted_out: dict = None) -> bool:
         """Merge this journal's content into the caller's accumulators
         and reset it; returns the dirty-all flag that was set.  Soft
         roundtrip keys for CQs in the hard dirty set are dropped — those
@@ -176,8 +213,30 @@ class PackJournal:
         (``{workload key: cq name}``, last-writer-wins) minus keys whose
         CQ is hard-dirty (the re-walk covers them).  Callers that don't
         pass it get the legacy escalation: each row touch dirties its
-        CQ, so consumers unaware of row grade stay correct."""
+        CQ, so consumers unaware of row grade stay correct.
+
+        ``admitted_out`` receives the admitted-side row events
+        (``{cq name: {workload key: came}}``) of the CQs that are not
+        hard-dirty by a touch without a key, here or in ``dirty``; such
+        a CQ is dirty for its pending side and for the rows named, and
+        is not added to ``dirty``.  A row-grade touch of a CQ that is
+        hard-dirty joins them as an event that says neither (None): the
+        hard touch may be of the pending side alone.  Callers that don't
+        pass it get the queue-grade mark: each event's CQ joins
+        ``dirty``."""
         was_all = self.dirty_all or self.tainted
+        if self.admitted:
+            if admitted_out is None:
+                self.dirty.update(self.admitted)
+            else:
+                for cq, events in self.admitted.items():
+                    if cq in self.dirty or cq in dirty:
+                        continue
+                    acc = admitted_out.get(cq)
+                    if acc is None:
+                        admitted_out[cq] = events
+                    else:
+                        acc.update(events)
         if self.rows:
             if rows_out is None:
                 # legacy consumer: escalate row dirt to CQ dirt
@@ -186,8 +245,14 @@ class PackJournal:
                 for key, cq in self.rows.items():
                     if cq not in self.dirty and cq not in dirty:
                         rows_out[key] = cq
-        if row_of is not None and ranges_out is not None and self.dirty:
-            rows = sorted(row_of[n] for n in self.dirty if n in row_of)
+                    elif admitted_out is not None:
+                        # the hard touch may be of the pending side
+                        # alone: the row is looked at again
+                        admitted_out.setdefault(cq, {}).setdefault(key, None)
+        if row_of is not None and ranges_out is not None and (
+                self.dirty or self.admitted):
+            rows = sorted(row_of[n] for n in self.dirty | set(self.admitted)
+                          if n in row_of)
             ranges_out.extend(self.coalesce(rows))
         dirty |= self.dirty
         for name, keys in self.soft.items():
@@ -202,10 +267,13 @@ class PackJournal:
             soft.pop(name, None)
         if rows_out is not None:
             for key in [k for k, cq in rows_out.items() if cq in dirty]:
-                del rows_out[key]
+                cq = rows_out.pop(key)
+                if admitted_out is not None:
+                    admitted_out.setdefault(cq, {}).setdefault(key, None)
         self.dirty.clear()
         self.soft.clear()
         self.rows.clear()
+        self.admitted = {}
         self.dirty_all = False
         self.tainted = False
         return was_all

@@ -315,23 +315,400 @@ def build_wide_cluster(n_cqs=24):
     return d, clock
 
 
-def test_delta_pack_full_fallback_at_high_dirty_share():
-    """Above the dirty-share threshold a delta walk rebuilds nearly
-    everything plus bookkeeping, so the boundary takes (and counts) a
-    plain full pack; a sparse boundary goes back to the delta path."""
+def fresh_records(d, window):
+    """The full walk's records of the live state, CQ by CQ."""
+    from kueue_tpu.ops.burst import _walk_records
+    return _walk_records(current_structure(d), d.queues, d.cache,
+                         d.scheduler, window)
+
+
+def assert_records_match(d, state, window, ctx=""):
+    """What a record carries beside its rows comes out as a full walk
+    gives it: the rows' keys, the pending count, ``bad`` and the
+    compressed rows' count and newest reservation."""
+    for old, new in zip(state.records, fresh_records(d, window)):
+        at = f"{ctx}: cq {new.ci}"
+        assert sorted(old.keys.tolist()) == sorted(new.keys.tolist()), at
+        assert (old.n_pend, old.n_adm) == (new.n_pend, new.n_adm), at
+        assert old.bad == new.bad and old.bad_keys == new.bad_keys, at
+        assert old.n_comp == new.n_comp, at
+        assert old.comp_max_ts == new.comp_max_ts, at
+        assert old.truncated == new.truncated, at
+
+
+def evict(d, key):
+    """An eviction that requeues, as a preemption applies it."""
+    d._evict(d.workloads[key], "Preempted", "test eviction")
+
+
+def admitted_obj(d, key):
+    """The object the cache's admitted table holds for ``key``."""
+    owner = d.workloads[key].admission.cluster_queue
+    return owner, d.cache.cluster_queue(owner).workloads[key].obj
+
+
+def _case_every_queue_dirty(d, clock, step):
+    """Every queue dirty at once: the cells' case, far above the share
+    at which the pack used to fall back to the full walk."""
+    for c in range(2):
+        for q in range(2):
+            for i in range(5):
+                d.create_workload(mk(f"w-{c}-{q}-{i}", f"lq-{c}-{q}", 1500,
+                                     prio=(i % 3) * 10, t=float(i)))
+    step("init")
+    for rnd in range(3):
+        clock.t += 1.0
+        d.schedule_once()
+        for key in sorted(d.admitted_keys())[:2]:
+            d.finish_workload(key)
+        for c in range(2):
+            for q in range(2):
+                d.create_workload(mk(f"late-{rnd}-{c}-{q}", f"lq-{c}-{q}",
+                                     500, t=100.0 + rnd))
+        step(f"round {rnd}")
+    return {"full": 1, "delta": 3}
+
+
+def _case_same_key_comes_and_goes(d, clock, step):
+    """In one interval: a finish, an admission, an eviction and a
+    re-admission of one key; then the key evicted and left pending."""
+    for i in range(4):
+        d.create_workload(mk(f"w{i}", "lq-0-0", 1500, prio=10 * (i % 2),
+                             t=float(i)))
+    for _ in range(3):
+        clock.t += 1.0
+        d.schedule_once()
+    step("init")
+    held = sorted(d.admitted_keys())
+    assert len(held) >= 2
+    d.finish_workload(held[0])
+    evict(d, held[1])
+    clock.t += 1.0
+    d.schedule_once()                  # held[1] (or a peer) admitted again
+    evict(d, held[1]) if held[1] in d.admitted_keys() else None
+    clock.t += 1.0
+    d.schedule_once()
+    step("finish+evict+readmit")
+    for key in sorted(d.admitted_keys()):
+        evict(d, key)                  # went, and now pending again
+    step("all evicted")
+    clock.t += 1.0
+    d.schedule_once()
+    step("readmitted")
+    return {"full": 1, "delta": 3}
+
+
+def _case_truncated_head_admitted(d, clock, step, window=2):
+    """A record cut to window + 2 pending rows loses its head to an
+    admission: the next row below the cut comes in by itself."""
+    for i in range(9):
+        d.create_workload(mk(f"w{i}", "lq-0-0", 1000, t=float(i)))
+    state = step("init")
+    assert state.records[0].truncated
+    assert state.records[0].n_pend == window + 2
+    for _ in range(3):
+        clock.t += 1.0
+        d.schedule_once()
+        state = step("head admitted")
+        assert state.records[0].n_pend == min(
+            window + 2, len(d.queues.queue_for("cq-0-0").heap.items())
+            + len(d.queues.queue_for("cq-0-0").inadmissible))
+    return {"full": 1, "delta": 3}
+
+
+def _case_admitted_side_empties_and_starts(d, clock, step):
+    """One queue loses its last admitted row while another gains its
+    first."""
+    d.create_workload(mk("only", "lq-0-0", 3000, t=0.0))
+    clock.t += 1.0
+    d.schedule_once()
+    d.create_workload(mk("first", "lq-1-1", 3000, t=1.0))
+    d.create_workload(mk("waits", "lq-0-1", 1000, t=2.0))
+    state = step("init")
+    assert [r.n_adm for r in state.records] == [1, 0, 0, 0]
+    d.finish_workload("default/only")
+    clock.t += 1.0
+    d.schedule_once()
+    d.create_workload(mk("more", "lq-0-0", 1000, t=3.0))
+    state = step("swap")
+    assert state.records[0].n_adm == 0 and state.records[3].n_adm == 1
+    return {"full": 1, "delta": 1}
+
+
+def _case_bad_comes_and_goes(d, clock, step, pending_dirt=False):
+    """An admitted workload turns ``bad`` (its Evicted condition set
+    with the quota still held) and stops being so; the change reaches
+    the journal as a row touch, alone or beside pending-side dirt of
+    the same queue."""
+    from kueue_tpu.api.types import WL_EVICTED
+    from kueue_tpu.workload import set_evicted_condition
+    for i in range(3):
+        d.create_workload(mk(f"w{i}", "lq-0-0", 1500, t=float(i)))
+    d.create_workload(mk("peer", "lq-0-1", 1500, t=5.0))
+    clock.t += 1.0
+    d.schedule_once()
+    state = step("init")
+    key = sorted(d.admitted_keys())[0]
+    owner, obj = admitted_obj(d, key)
+    ci = current_structure(d).cq_index[owner]
+    assert not state.records[ci].bad
+
+    def touch(n):
+        d.queues.pack_journal.touch_row(owner, key)
+        if pending_dirt:
+            d.create_workload(mk(f"dirt{n}", "lq-" + owner[3:], 500,
+                                 t=10.0 + n))
+
+    set_evicted_condition(obj, "Preempted", "set ahead of the release",
+                          clock.t)
+    touch(0)
+    state = step("turned bad")
+    assert state.records[ci].bad_keys == {key}
+    assert not state.plan_preempt_ok[ci]
+    del obj.conditions[WL_EVICTED]
+    touch(1)
+    state = step("good again")
+    assert not state.records[ci].bad
+    assert key in state.records[ci].keys.tolist()
+    return {"full": 1, "delta": 2}
+
+
+def _case_compressed_counts(d, clock, step):
+    """The compress arm (no queue of the forest preempts): admitted rows
+    are a count and a newest reservation, kept by the events: the newest
+    leaves, an older one leaves, new ones come."""
+    for i in range(6):
+        d.create_workload(mk(f"w{i}", f"lq-0-{i % 2}", 1500, t=float(i)))
+    clock.t += 1.0
+    d.schedule_once()
+    clock.t += 1.0
+    d.schedule_once()
+    state = step("init")
+    assert sum(r.n_comp for r in state.records) == len(d.admitted_keys()) > 2
+    by_res = sorted(d.admitted_keys(), key=lambda k: (
+        d.workloads[k].conditions["QuotaReserved"].last_transition_time, k))
+    d.finish_workload(by_res[-1])      # the newest reservation goes
+    state = step("newest gone")
+    d.finish_workload(by_res[0])
+    clock.t += 1.0
+    d.schedule_once()                  # and newer ones come
+    state = step("older gone, new came")
+    assert sum(r.n_adm for r in state.records) == 0
+    return {"full": 1, "delta": 2}
+
+
+def _case_labelled_rows_that_came(d, clock, step):
+    """A labelled queue: the rows that come carry their own masks."""
+    from tests.test_flavor_eligibility import (
+        TOLERATES_SPOT, head, heads_of_one_cohort_search_different_columns,
+        pod_set)
+    heads_of_one_cohort_search_different_columns(d)
+    state = step("init")
+    head(d, "late", "a", pod_set(selector={"instance-type": "on-demand"}),
+         priority=0, created=1000.0)
+    clock.t += 1.0
+    d.schedule_once()
+    for n in range(2):
+        head(d, f"more-{n}", "b", pod_set(tolerations=[TOLERATES_SPOT]),
+             created=2000.0 + n)
+        clock.t += 1.0
+        d.schedule_once()
+        state = step(f"came {n}")
+    skip = state.last_plan.arrays["wl_flavor_skip"]
+    at = {k.split("/")[1]: int(skip[c, m])
+          for k, (c, m) in state.last_plan.row_of_key.items()}
+    assert at["late"] == 0b1101 and at["more-1"] == 0b0000
+    assert at["own-a-reserved"] == 0b1100
+    return {"full": 1, "delta": 2}
+
+
+def _case_m_grows_a_bucket(d, clock, step):
+    """The widest queue outgrows the grid's M inside a delta window."""
+    for i in range(4):
+        d.create_workload(mk(f"w{i}", "lq-0-0", 1000, t=float(i)))
+    clock.t += 1.0
+    d.schedule_once()
+    state = step("init")
+    assert state.M == 4
+    for i in range(4, 9):
+        d.create_workload(mk(f"w{i}", "lq-0-0", 1000, t=float(i)))
+    d.finish_workload(sorted(d.admitted_keys())[0])
+    state = step("grown")
+    assert state.M == 8
+    for key in sorted(d.admitted_keys()):
+        d.finish_workload(key)
+    state = step("drained")            # M is sticky only through min_m
+    return {"full": 1, "delta": 2}
+
+
+def _case_dropped_touch_forces_the_full_pack(d, clock, step):
+    """A lost journal update (chaos ``journal.drop_touch``) taints the
+    journal: the next window packs in full, the one after is a delta."""
+    from kueue_tpu.chaos import injector as chaos
+    from kueue_tpu.chaos.injector import ChaosInjector
+    for i in range(4):
+        d.create_workload(mk(f"w{i}", f"lq-0-{i % 2}", 1500, t=float(i)))
+    clock.t += 1.0
+    d.schedule_once()
+    step("init")
+    try:
+        chaos.install(ChaosInjector(seed=3)).arm("journal.drop_touch", at=1)
+        d.finish_workload(sorted(d.admitted_keys())[0])   # its event is lost
+    finally:
+        chaos.clear()
+    assert d.cache.pack_journal.tainted
+    step("tainted")
+    d.finish_workload(sorted(d.admitted_keys())[0])
+    step("after")
+    return {"full": 2, "delta": 1}
+
+
+ROW_GRADE_CASES = {
+    "every_queue_dirty": (_case_every_queue_dirty, {"preempt": True}, 0),
+    "same_key_comes_and_goes": (
+        _case_same_key_comes_and_goes, {"preempt": True}, 0),
+    "truncated_head_admitted": (
+        _case_truncated_head_admitted, {"preempt": True}, 2),
+    "admitted_side_empties_and_starts": (
+        _case_admitted_side_empties_and_starts, {"preempt": True}, 0),
+    "bad_comes_and_goes": (_case_bad_comes_and_goes, {"preempt": True}, 0),
+    "bad_comes_and_goes_beside_pending_dirt": (
+        lambda d, clock, step: _case_bad_comes_and_goes(
+            d, clock, step, pending_dirt=True), {"preempt": True}, 0),
+    "compressed_counts": (_case_compressed_counts, {"preempt": False}, 0),
+    "labelled_rows_that_came": (_case_labelled_rows_that_came, None, 0),
+    "m_grows_a_bucket": (_case_m_grows_a_bucket, {"preempt": True}, 0),
+    "dropped_touch_forces_the_full_pack": (
+        _case_dropped_touch_forces_the_full_pack, {"preempt": True}, 0),
+}
+
+
+class _Packed:
+    """What a case's ``step`` hands back: the pack state, with the
+    window's plan and its ``preempt_ok`` beside it."""
+
+    def __init__(self, state, plan):
+        self._state = state
+        self.last_plan = plan
+        self.plan_preempt_ok = (None if plan is None
+                                else plan.arrays["preempt_ok"])
+
+    def __getattr__(self, name):
+        return getattr(self._state, name)
+
+
+@pytest.mark.parametrize("case", sorted(ROW_GRADE_CASES))
+def test_row_grade_delta_parity(case):
+    """Every window after the first is a delta pack, whatever share of
+    the queues is dirty, and its plan equals the full pack's of the
+    same live state plane for plane; what the records carry beside
+    their rows equals a full walk's."""
+    fn, cluster, window = ROW_GRADE_CASES[case]
+    if cluster is None:
+        clock = Clock()
+        d = Driver(clock=clock, use_device_solver=True)
+    else:
+        d, clock = build_cluster(**cluster)
+    stats = {}
+    held = {"state": None}
+
+    def step(ctx):
+        st = current_structure(d)
+        plan, state, _ = pack_burst_cached(
+            st, d.queues, d.cache, d.scheduler, d.clock,
+            state=held["state"], window=window, stats=stats)
+        assert_plans_equal(
+            plan, pack_burst(st, d.queues, d.cache, d.scheduler, d.clock,
+                             window=window), f"{case}: {ctx}")
+        assert_records_match(d, state, window, f"{case}: {ctx}")
+        held["state"] = state
+        return _Packed(state, plan)
+
+    want = fn(d, clock, step)
+    assert stats.get("burst_full_packs", 0) == want["full"]
+    assert stats.get("burst_delta_packs", 0) == want["delta"]
+    assert stats.get("stream_pack_desyncs", 0) == 0
+
+
+def test_a_delta_window_counts_the_rows_it_derived():
+    """``rows_repacked`` in a delta window is the rows derived from an
+    ``Info``: the admitted rows that came and the pending rows whose
+    ``Info`` is new, not every row of every dirty queue."""
+    d, clock = build_cluster(preempt=True)
+    for i in range(12):
+        d.create_workload(mk(f"w{i}", "lq-0-0", 400, t=float(i)))
+    for _ in range(3):
+        clock.t += 1.0
+        d.schedule_once()
+    stats = {}
+    state = check_step(d, None, stats, 0, "full")
+    n_adm = len(d.admitted_keys())
+    assert n_adm >= 3 and stats["rows_repacked"] == 12
+    clock.t += 1.0
+    d.schedule_once()                   # one more row admitted
+    assert len(d.admitted_keys()) == n_adm + 1
+    state = check_step(d, state, stats, 0, "delta")
+    pending = 12 - n_adm - 1
+    assert stats["rows_repacked"] == 12 + 1
+    assert stats["rows_reused"] == n_adm + pending
+    # an update swaps a pending workload's Info: that row is derived
+    # again, with the one that arrives
+    wl = mk("w11", "lq-0-0", 500, t=11.0)
+    d.workloads[wl.key] = wl
+    d.queues.add_or_update_workload(wl)
+    d.create_workload(mk("w12", "lq-0-0", 400, t=12.0))
+    state = check_step(d, state, stats, 0, "update")
+    assert stats["rows_repacked"] == 12 + 1 + 2
+    assert stats["rows_reused"] == 2 * (n_adm + pending) + 1 - 1
+
+
+@pytest.mark.parametrize("asks", [False, True])
+def test_journal_admitted_events_drain_by_row_or_by_queue(asks):
+    """``touch_admitted`` names the row beside the queue: a consumer
+    that asks for the channel gets ``{cq: {key: came}}``, the last
+    event of a key standing, less the queues hard-dirty without a key;
+    one that does not gets each event's queue as hard dirt."""
+    from kueue_tpu.utils.journal import PackJournal
+    j = PackJournal()
+    j.drain_into(set(), {})            # clear the fresh journal's dirty-all
+    j.touch_admitted("cq-a", "k1", True)
+    j.touch_admitted("cq-a", "k1", False)
+    j.touch_admitted("cq-a", "k2", True)
+    j.touch_admitted("cq-b", "k3", True)
+    j.touch("cq-b")                    # keyless: the whole queue
+    j.touch_row("cq-a", "k9")
+    dirty, rows, events, ranges = set(), {}, {}, []
+    was_all = j.drain_into(
+        dirty, {}, row_of={"cq-a": 0, "cq-b": 1}, ranges_out=ranges,
+        rows_out=rows, admitted_out=events if asks else None)
+    assert was_all is False and not j.admitted
+    assert ranges == [(0, 2)]
+    if asks:
+        assert dirty == {"cq-b"}
+        assert events == {"cq-a": {"k1": False, "k2": True}}
+        assert rows == {"k9": "cq-a"}
+    else:
+        assert dirty == {"cq-a", "cq-b"} and rows == {} and events == {}
+
+
+def test_delta_pack_runs_at_any_dirty_share():
+    """Every queue dirty, and then two of twenty-four: both windows are
+    delta packs (the pack no longer gives up at a dirty share)."""
     d, clock = build_wide_cluster(24)
     for i in range(24):
         d.create_workload(mk(f"init-{i}", f"wlq-{i}", 1000, t=float(i)))
     stats = {}
     state = check_step(d, None, stats, 0, "initial")
     assert stats.get("burst_full_packs", 0) == 1
-    for i in range(24):   # dirty every CQ: 24 > max(8, 0.5 * 24)
+    for i in range(24):   # dirty every CQ
         d.create_workload(mk(f"burst-{i}", f"wlq-{i}", 500,
                              t=100.0 + i))
     state = check_step(d, state, stats, 0, "all-dirty")
-    assert stats.get("burst_full_packs", 0) == 2
-    assert stats.get("burst_delta_packs", 0) == 0
+    assert stats.get("burst_full_packs", 0) == 1
+    assert stats.get("burst_delta_packs", 0) == 1
     d.create_workload(mk("tail-0", "wlq-0", 500, t=200.0))
     d.create_workload(mk("tail-1", "wlq-1", 500, t=201.0))
     state = check_step(d, state, stats, 0, "sparse")
-    assert stats.get("burst_delta_packs", 0) == 1
+    assert stats.get("burst_delta_packs", 0) == 2
+    assert not hasattr(__import__("kueue_tpu.ops.stream_pack",
+                                  fromlist=["x"]), "_DELTA_MAX_DIRTY_FRAC")
