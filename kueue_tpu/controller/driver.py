@@ -1075,7 +1075,7 @@ class Driver:
                         break
                     continue
                 self._burst_m = max(self._burst_m, plan.M)
-                F = max(1, len(st.fr_index))
+                F = st.n_frs
                 ext_release = np.zeros((K, plan.C, F), dtype=np.int32)
                 ext_unpark = np.zeros((K, plan.G), dtype=bool)
                 # the kernel must model EVERY release during its window:
@@ -1129,14 +1129,14 @@ class Driver:
             if (pipeline and remaining > K and runtime <= K
                     and not bool(np.asarray(dirty).any())
                     and not any(off >= base + K for off in ext)):
-                F = max(1, len(st.fr_index))
+                F = st.n_frs
                 with _span("burst.dispatch"):
                     spec = self._burst_solver.dispatch_next(
                         handle,
                         np.zeros((K, plan.C, F), dtype=np.int32),
                         np.zeros((K, plan.G), dtype=bool))
             with _span("burst.fetch"):
-                (head_row, kind, slot, borrows, tgt_words, dirty,
+                (head_row, kind, slot, tried, borrows, tgt_words, dirty,
                  dirty_reason) = self._burst_solver.fetch(handle)
             from ..ops import burst as _b
             kind_name = {_b.KIND_ADMIT: "admit", _b.KIND_SKIP: "skip",
@@ -1172,7 +1172,7 @@ class Driver:
                     if kd in ("preempt", "reserve", "overlap_skip",
                               "pre_nofit"):
                         has_pre_kind = True
-                    modeled[key] = (kd, int(slot[k, ci]),
+                    modeled[key] = (kd, slot[k, ci], tried[k, ci],
                                     bool(borrows[k, ci]), targets)
                 if not dirty[k] and not modeled and quiescent():
                     drained = True

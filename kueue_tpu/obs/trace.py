@@ -67,7 +67,8 @@ HOT_PATH_PHASES = (
     "cycle.snapshot",   # cache snapshot build / incremental reuse
     "cycle.nominate",   # validation + flavor assignment + preempt targets
     "cycle.nominate.classify",        # cycle pack + device classification
-    "cycle.nominate.classify.eligibility",  # the heads' [W, S] flavor plane
+    "cycle.nominate.classify.eligibility",  # the heads' [W, G, S] flavor plane
+    "cycle.nominate.classify.groups",  # one flavor walk a group, joined
     "cycle.nominate.walk",            # host FlavorAssigner walks
     "cycle.nominate.oracle",          # the reclaim oracle's batched searches
     "cycle.nominate.candidates",      # find/sort candidates, plan searches

@@ -16,7 +16,8 @@ Semantics reproduced per fused cycle, bit-matching the host scheduler:
    static within a burst because priorities/timestamps never change
    without an external event, and external events end the burst.
 2. **Classify** (flavorassigner.go:499): the vectorized nominate of
-   ops.cycle.classify_np, evaluated dense over [C, S, R].
+   ops.cycle.classify_np (``walk_groups``: one flavor walk a resource
+   group of the head's queue, joined), evaluated dense over [C, S, R].
 3. **Cycle order** (scheduler.go:567 entryOrdering): borrows asc, then a
    host-precomputed (priority desc, timestamp asc, heads-position) rank.
 4. **Admit scan** (scheduler.go:211-284): forest-parallel — one head per
@@ -38,8 +39,8 @@ Anything the fused math can't decide bit-identically makes the cycle
 **dirty**: a preempt-capable head outside the modeled envelope (the
 walk neither policy-stopped on the preempt slot nor left it as the only
 preempt-capable choice — the host's pick then depends on the reclaim
-oracle), or a head outside the vectorized classify's coverage (multi-RG
-/ multi-PodSet / TAS / partial admission — ``vec_ok`` False).  Node
+oracle), or a head outside the vectorized classify's coverage
+(multi-PodSet / TAS / partial admission — ``vec_ok`` False).  Node
 labels, taints, selectors and tolerations stay inside it: each row
 carries the flavors its PodSet may not take (``wl_flavor_skip``).
 FlavorFungibility itself runs in-kernel: the classify step walks each
@@ -72,7 +73,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from .quota_kernel import available_all, available_at
-from .cycle import add_usage_chain_batched
+from .cycle import add_usage_chain_batched, walk_groups
 from ..chaos import injector as _chaos
 from ..features import env_value
 from ..obs.trace import span as _span
@@ -115,13 +116,14 @@ def _burst_cycles(
     wl_prio,         # [C, M] int32 priority
     wl_uidrank,      # [C, M] int32 global uid rank (candidate tiebreak)
     vec_ok,          # [C, M] bool  vectorized-classify coverage
-    wl_flavor_skip,  # [C, M] uint8 bit s: the row's PodSet may not take
-                     #              slot s of its queue (ops/eligibility.py);
-                     #              [C, 1] zeros where every flavor is plain
+    wl_flavor_skip,  # [C, M, RG] uint8 bit s: the row's PodSet may not
+                     #              take slot s of resource group g of its
+                     #              queue (ops/eligibility.py); [C, 1, RG]
+                     #              zeros where every flavor is plain
     elig0,           # [C, M] bool  in the heap at burst start
     parked0,         # [C, M] bool  in the inadmissible lot at burst start
-    resume0,         # [C, M] int32 flavor-walk start slot (fungibility
-                     #              resume state; 0 = full walk)
+    resume0,         # [C, M, RG] int32 flavor-walk start slot a group
+                     #              (fungibility resume state; 0 = full walk)
     # admitted-row state (rows holding quota at burst start)
     adm0,            # [C, M] bool
     adm_seq0,        # [C, M] int32 reservation-time dense rank (ties ==)
@@ -138,8 +140,10 @@ def _burst_cycles(
     node_level,      # [N] int32 (roots = 0)
     nominal_cq,      # [C, F]
     npb_cq,          # [C, F] nominal+borrowingLimit (reserve cap)
-    slot_fr,         # [C, S, R] int32 F-index or -1
-    slot_valid,      # [C, S] bool
+    slot_fr,         # [C, S, R] int32 F-index or -1: flavor s of the
+                     #              group that covers r
+    slot_valid,      # [C, RG, S] bool
+    res_group,       # [C, R] int32 the group that covers r, -1 none
     cq_can_preempt_borrow,                       # [C] bool
     cq_wcb,          # [C] bool whenCanBorrow == Borrow
     cq_wcp,          # [C] bool whenCanPreempt == Preempt
@@ -162,10 +166,13 @@ def _burst_cycles(
 ):
     """Run K fused admission cycles with in-kernel preemption.
 
-    Returns per-cycle (head_row[K,C], kind[K,C], slot[K,C], borrows[K,C],
-    tgt_words[K,C,KC//32] uint32, dirty[K], dirty_reason[K]) plus the
-    final u_cq.  ``slot`` is the fit slot for admit/skip kinds and the
-    preempt slot for preempt kinds.  ``tgt_words`` is the bit-packed
+    Returns per-cycle (head_row[K,C], kind[K,C], slot[K,C,RG],
+    tried[K,C,RG], borrows[K,C], tgt_words[K,C,KC//32] uint32, dirty[K],
+    dirty_reason[K]) plus the final carry.  ``slot`` is the slot each
+    resource group's walk chose (fit slots for admit/skip kinds; for
+    preempt kinds the preempt slots of the groups short of quota beside
+    the fit slots of the others) and ``tried`` the resume state each
+    walk records.  ``tgt_words`` is the bit-packed
     candidate-slot mask of each preempting head's targets (indices into
     cand_rows[forest_of_cq[c]]).
 
@@ -212,7 +219,8 @@ def _burst_cycles(
     # per-CQ flavor-list length: vector-ok CQs have every rg flavor
     # materialized as a valid slot (eligibility.bind_flavor_lists), so the valid
     # count IS len(rg.flavors) — the host walk's n_slots
-    slot_cnt = jnp.sum(slot_valid, axis=1).astype(jnp.int32)   # [C]
+    slot_cnt = jnp.sum(slot_valid, axis=2).astype(jnp.int32)   # [C, RG]
+    slot_at = (cidx[:, None, None], jnp.maximum(slot_fr, 0))    # [C,S,R]
 
     # per-CQ static candidate tables (gathered per forest)
     crows = cand_rows[forest_of_cq]                    # [C, KC]
@@ -275,84 +283,45 @@ def _burst_cycles(
         req = wl_req[cidx, row]                                # [C, R]
         prio_head = wl_prio[cidx, row]
 
-        # -- classify (classify_np dense twin) ------------------------
-        frs = slot_fr                                          # [C,S,R]
-        frs_safe = jnp.maximum(frs, 0)
-        covered = frs >= 0
-        needed = req[:, None, :] > 0
-        missing = jnp.any(needed & ~covered, axis=2)           # [C,S]
-        av = avail[:C][cidx[:, None, None], frs_safe]          # [C,S,R]
-        pot = potential0[:C][cidx[:, None, None], frs_safe]
-        nom = nominal_cq[cidx[:, None, None], frs_safe]
-        use = usage[:C][cidx[:, None, None], frs_safe]
-        sq = subtree[:C][cidx[:, None, None], frs_safe]
-
-        relevant = covered & needed
-        fit_r = req[:, None, :] <= av
-        nofit_r = req[:, None, :] > pot
-        preempt_capable_r = ((req[:, None, :] <= nom)
-                             | cq_can_preempt_borrow[:, None, None])
-        res_nofit = relevant & (nofit_r | (~fit_r & ~preempt_capable_r))
+        # -- classify: one flavor walk a resource group, joined -------
         # a slot the head may not take for a taint or a selector is
-        # visited and passed over, like one whose flavor does not exist
+        # visited and passed over, like one whose flavor does not exist;
+        # each walk scans its group's list from the carried resume
+        # start under the whenCanBorrow/whenCanPreempt stop rules
         skip = wl_flavor_skip[
-            cidx, jnp.minimum(row, wl_flavor_skip.shape[1] - 1)]
-        slot_ok = slot_valid & slots_of_mask(skip, S, jnp)     # [C,S]
-        fit_s = (jnp.all(jnp.where(relevant, fit_r, True), axis=2)
-                 & ~missing & slot_ok)                         # [C,S]
-        nofit_s = jnp.any(res_nofit, axis=2) | missing | ~slot_ok
-        preempt_s = ~fit_s & ~nofit_s
-        borrow_r = jnp.where(relevant, use + req[:, None, :] > sq, False)
-        borrows_s = jnp.any(borrow_r, axis=2) & has_parent_cq[:, None]
+            cidx, jnp.minimum(row, wl_flavor_skip.shape[1] - 1)]  # [C,RG]
+        w = walk_groups(
+            jnp, req=req, frs=slot_fr, grp=res_group, slot_ok=slot_valid,
+            eligible=slots_of_mask(skip, S, jnp), slot_count=slot_cnt,
+            start=resume[cidx, row], av=avail[:C][slot_at],
+            pot=potential0[:C][slot_at], nom=nominal_cq[slot_at],
+            use=usage[:C][slot_at], sq=subtree[:C][slot_at],
+            can_preempt_borrow=cq_can_preempt_borrow,
+            has_parent=has_parent_cq, wcb=cq_wcb, wcp=cq_wcp,
+            valid=has_head)
+        has_fit = w["has_fit"]
+        has_preempt = w["has_preempt"]
+        borrows = w["borrows"] & has_fit
+        res_fr = w["res_fr"]       # [C, R] each resource on its group's slot
+        # the resume state the host records for these walks: a group's
+        # stop slot when it stopped mid-list, else -1
+        tried_c = w["tried"]                                   # [C, RG]
+        pending_c = jnp.any(tried_c >= 0, axis=1)
 
-        # -- fungibility walk (flavorassigner.go:326-391 dense twin) --
-        # scan the flavor list from the carried resume start; STOP on a
-        # slot per whenCanBorrow/whenCanPreempt, else keep the best mode
-        # (first occurrence of max: FIT=2 > PREEMPT=1 > NO_FIT=0)
-        start = resume[cidx, row]                              # [C]
-        active_s = (jnp.arange(S, dtype=jnp.int32)[None, :]
-                    >= start[:, None])                         # [C,S]
-        stop_s = (active_s & (fit_s | (preempt_s & cq_wcp[:, None]))
-                  & (~borrows_s | cq_wcb[:, None]))
-        has_stop = jnp.any(stop_s, axis=1)
-        act_mode = jnp.where(active_s,
-                             jnp.where(fit_s, 2,
-                                       jnp.where(preempt_s, 1, 0)), 0)
-        best_mode = act_mode.max(axis=1)
-        best_idx = jnp.argmax((act_mode == best_mode[:, None]) & active_s,
-                              axis=1).astype(jnp.int32)
-        chosen = jnp.where(has_stop,
-                           jnp.argmax(stop_s, axis=1).astype(jnp.int32),
-                           best_idx)
-        chosen_mode = act_mode[cidx, chosen]
-        has_fit = (chosen_mode == 2) & has_head
-        fit_slot = jnp.where(has_fit, chosen, -1)
-        borrows = borrows_s[cidx, chosen] & has_fit
-        has_preempt = (chosen_mode == 1) & has_head
-        # the resume state the host records for this walk: the stop slot
-        # when it stopped mid-list, else -1 (whole list attempted)
-        tried_c = jnp.where(has_stop & (chosen < slot_cnt - 1),
-                            chosen, -1)
-        pending_c = tried_c >= 0
-
-        # -- preempt head facts on the chosen preempt slot ------------
-        p_idx = chosen
-        p_count = (preempt_s & active_s).sum(axis=1)
-        p_borrows = borrows_s[cidx, p_idx] & has_preempt
-        pfrs = slot_fr[cidx, p_idx]                            # [C, R]
-        prel = (pfrs >= 0) & (req > 0)
-        pfrs_s = jnp.maximum(pfrs, 0)
-        pfit_r = fit_r[cidx, p_idx]                            # [C, R]
+        # -- preempt head facts on the chosen slots -------------------
+        p_borrows = w["borrows"] & has_preempt
+        prel = (res_fr >= 0) & (req > 0)
+        pfrs_s = jnp.maximum(res_fr, 0)
         frs_need = jnp.zeros((C, F), dtype=bool).at[
-            cidx[:, None], pfrs_s].max(prel & ~pfit_r)         # [C, F]
+            cidx[:, None], pfrs_s].max(prel & ~w["res_fit"])   # [C, F]
         wu = jnp.zeros((C, F), dtype=jnp.int32).at[
             cidx[:, None], pfrs_s].add(jnp.where(prel, req, 0))
-        # the modeled envelope: the preempt choice must not depend on
-        # the reclaim oracle (cycle.py:122-126) — a policy-stopped walk
-        # is final, and a single preempt-capable slot leaves the
-        # best-mode pick no freedom either
+        # the modeled envelope: no group's preempt choice may depend on
+        # the reclaim oracle (ops/cycle.py walk_groups) — a
+        # policy-stopped walk is final, and a single preempt-capable
+        # slot leaves the best-mode pick no freedom either
         pre_model = (has_preempt & preempt_ok
-                     & (has_stop | (p_count == 1)))
+                     & ~jnp.any(w["oracle_groups"], axis=1))
 
         dirty_c = has_head & ((has_preempt & ~pre_model)
                               | ~vec_ok[cidx, row])
@@ -612,8 +581,7 @@ def _burst_cycles(
                                              has_blim, parent, cq_s,
                                              depth)
                     # fit entry: fixed-slot re-check
-                    slot = jnp.maximum(fit_slot[cq_s], 0)
-                    frs_l = slot_fr[cq_s, slot]                # [R]
+                    frs_l = res_fr[cq_s]                       # [R]
                     amt_l = req[cq_s]
                     frs_ls = jnp.maximum(frs_l, 0)
                     rel_l = (frs_l >= 0) & (amt_l > 0)
@@ -690,10 +658,8 @@ def _burst_cycles(
 
         # -- end-of-cycle state transitions ---------------------------
         # admit delta per admitted head (committed usage)
-        fslot_s = jnp.maximum(fit_slot, 0)
-        afrs = slot_fr[cidx, fslot_s]                          # [C, R]
-        arel = (afrs >= 0) & (req > 0) & admitted_c[:, None]
-        afrs_s = jnp.maximum(afrs, 0)
+        arel = prel & admitted_c[:, None]
+        afrs_s = pfrs_s                                        # [C, R]
         adm_delta = jnp.zeros((C, F), dtype=jnp.int32).at[
             cidx[:, None], afrs_s].add(jnp.where(arel, req, 0))
         adm_uses_new = jnp.zeros((C, F), dtype=bool).at[
@@ -717,9 +683,9 @@ def _burst_cycles(
         # Info), park, preempt issued, strict NoFit — resets to 0
         keep_resume = (skipped | (reserve_c & pending_c) | overlap_c
                        | pre_nofit_c)
-        head_start = jnp.where(keep_resume & pending_c, tried_c + 1, 0)
+        head_start = jnp.where(keep_resume[:, None], tried_c + 1, 0)
         resume = resume.at[cidx, row].set(
-            jnp.where(has_head, head_start, resume[cidx, row]))
+            jnp.where(has_head[:, None], head_start, resume[cidx, row]))
         # admitted rows join the quota-holding table
         adm = adm.at[cidx, row].set(admitted_c | adm[cidx, row])
         adm_seq = adm_seq.at[cidx, row].set(
@@ -773,15 +739,15 @@ def _burst_cycles(
         kind = jnp.where(preempting_c, KIND_PREEMPT, kind)
         kind = jnp.where(overlap_c, KIND_OVERLAP_SKIP, kind)
         kind = jnp.where(pre_nofit_c, KIND_PRE_NOFIT, kind)
-        slot_out = jnp.where(has_fit, fit_slot,
-                             jnp.where(pre_model, p_idx, -1))
+        slot_out = jnp.where((has_fit | pre_model)[:, None],
+                             w["chosen"], -1)                  # [C, RG]
         borrows_out = jnp.where(has_fit, borrows, p_borrows)
         tgt_commit = tgt0 & preempting_c[:, None]              # [C, KC]
         tgt_words = jnp.sum(
             tgt_commit.reshape(C, KCW, 32).astype(jnp.uint32)
             * bit_w[None, None, :], axis=-1)                   # [C,KCW]
 
-        out = (jnp.where(has_head, row, -1), kind, slot_out,
+        out = (jnp.where(has_head, row, -1), kind, slot_out, tried_c,
                borrows_out, tgt_words, dflags)
         carry = (elig, parked, resume, adm, adm_seq, adm_usage,
                  adm_uses, death, u_cq_next)
@@ -791,7 +757,7 @@ def _burst_cycles(
               adm_uses0, death0, u_cq0)
     carry, outs = jax.lax.scan(cycle, carry0,
                                jnp.arange(K, dtype=jnp.int32))
-    head_row, kind, slot, borrows, tgt_words, dflags = outs
+    head_row, kind, slot, tried, borrows, tgt_words, dflags = outs
     if axis_name is not None:
         dflags = jax.lax.psum(dflags, axis_name)           # [K, 4]
     dirty = dflags[:, 0] > 0
@@ -802,7 +768,7 @@ def _burst_cycles(
     # the full final carry is returned so a pipelined caller can chain
     # the NEXT window's dispatch off the device-resident state (death
     # rebased by -K, seq_base advanced) without a host re-pack
-    return (head_row, kind, slot, borrows, tgt_words, dirty,
+    return (head_row, kind, slot, tried, borrows, tgt_words, dirty,
             dirty_reason, carry)
 
 
@@ -1044,7 +1010,7 @@ def _pack_statics(st, cache) -> _PackStatics:
                              ReclaimWithinCohort, WithinClusterQueue)
     from .cycle import available_all_np
     C = len(st.cq_names)
-    F = max(1, len(st.fr_index))
+    F = st.n_frs
     G = st.n_forests
     N = st.node_count
     parent = st.parent
@@ -1185,8 +1151,8 @@ class _RowWalk:
         self.compress = compress
         self.qts = scheduler.ordering.queue_order_timestamp
         from ..api.types import AdmissionCheckState
-        from .solver import resume_start
-        self.resume_start = resume_start
+        from .solver import resume_starts
+        self.resume_start = resume_starts
         self.failed_check = (AdmissionCheckState.RETRY,
                              AdmissionCheckState.REJECTED)
         self.bad_keys = bad_keys
@@ -1200,9 +1166,10 @@ class _RowWalk:
         self.res_ts_l: list[float] = []
         self.parked_l: list[bool] = []
         self.ok_l: list[bool] = []
-        self.resume_l: list[int] = []    # flavor-walk start slot (0 = full)
+        self.resume_l: list[tuple] = []  # flavor-walk start slot a group
+                                         # (0 = full)
         self.infos: list = []
-        F = max(1, len(st.fr_index))
+        F = st.n_frs
         self.req_mat = np.zeros((n_upper, len(st.resource_names)),
                                 dtype=np.int32)
         self.usage_mat = np.zeros((n_upper, F), dtype=np.int32)
@@ -1230,14 +1197,15 @@ class _RowWalk:
 
     def moving(self, info, static_ok: bool) -> tuple:
         """A pending row's facts that move while its ``Info`` stands:
-        (vec_ok, the flavor walk's start slot)."""
+        (vec_ok, the flavor walks' start slots, one a group)."""
         ok = self.cq_vec and static_ok
         if ok:
             obj = info.obj
             if (info.key in self.assumed or obj.admission is not None
                     or self._gated(obj)):
                 ok = False
-        return ok, self.resume_start(info, self.cq_live, self.covers_pods)
+        return ok, self.resume_start(info, self.cq_live, self.covers_pods,
+                                     self.st.n_groups)
 
     def pending(self, info, parked: bool) -> None:
         _, _, req_vec, static_ok, ts, prio, uid = self._static(info)
@@ -1303,7 +1271,7 @@ class _RowWalk:
         # (the cycle goes dirty), gating less diverges decisions
         self.ok_l.append(self.cq_vec and static_ok
                          and not self._gated(obj))
-        self.resume_l.append(0)
+        self.resume_l.append((0,) * self.st.n_groups)
         self.infos.append(info)
         self.n += 1
 
@@ -1321,14 +1289,15 @@ class _RowWalk:
             for attr in _DERIVED_ATTRS:
                 setattr(rec, attr, getattr(old, attr)[keep])
         else:
-            # the flavors a row's PodSet may not take: all zero, and
-            # nothing to ask a row, unless a flavor of this queue carries
-            # labels or taints
-            skip = (np.fromiter((skip_mask(info, st, self.ci)
-                                 for info in self.infos),
-                                dtype=np.uint8, count=i)
+            # the flavors a row's PodSet may not take, a group: all
+            # zero, and nothing to ask a row, unless a flavor of this
+            # queue carries labels or taints
+            G = st.n_groups
+            skip = (np.array([skip_mask(info, st, self.ci)
+                              for info in self.infos],
+                             dtype=np.uint8).reshape(i, G)
                     if declares(st, self.ci)
-                    else np.zeros(i, dtype=np.uint8))
+                    else np.zeros((i, G), dtype=np.uint8))
             derived = (
                 np.asarray(self.key_l) if i else np.empty(0, dtype="U1"),
                 np.asarray(self.uid_l) if i else np.empty(0, dtype="U1"),
@@ -1337,7 +1306,7 @@ class _RowWalk:
                 np.array(self.res_ts_l, dtype=np.float64),
                 np.array(self.parked_l, dtype=bool),
                 np.array(self.ok_l, dtype=bool),
-                np.array(self.resume_l, dtype=np.int32),
+                np.array(self.resume_l, dtype=np.int32).reshape(i, G),
                 self.req_mat[:i], skip, self.usage_mat[:i],
                 self.uses_mat[:i])
             for attr, new in zip(_DERIVED_ATTRS, derived):
@@ -1511,7 +1480,7 @@ def _assemble_plan(st, records, cache, scheduler, min_m):
     is independent of record row order."""
     ordering = scheduler.ordering
     C = len(st.cq_names)
-    F = max(1, len(st.fr_index))
+    F = st.n_frs
     R = len(st.resource_names)
     n_pending = sum(r.n_pend for r in records)
     if n_pending == 0:
@@ -1561,10 +1530,12 @@ def _assemble_plan(st, records, cache, scheduler, min_m):
     wl_prio = np.zeros((C, M), dtype=np.int32)
     wl_uidrank = np.zeros((C, M), dtype=np.int32)
     vec_ok = np.zeros((C, M), dtype=bool)
-    wl_flavor_skip = np.zeros((C, mask_plane_width(st, M)), dtype=np.uint8)
+    RG = st.n_groups
+    wl_flavor_skip = np.zeros((C, mask_plane_width(st, M), RG),
+                              dtype=np.uint8)
     elig = np.zeros((C, M), dtype=bool)
     parked = np.zeros((C, M), dtype=bool)
-    resume = np.zeros((C, M), dtype=np.int32)
+    resume = np.zeros((C, M, RG), dtype=np.int32)
     adm = np.zeros((C, M), dtype=bool)
     adm_seq = np.zeros((C, M), dtype=np.int32)
     adm_usage = np.zeros((C, M, F), dtype=np.int32)
@@ -1685,6 +1656,7 @@ def _assemble_plan(st, records, cache, scheduler, min_m):
         parent=st.parent, node_level=node_level,
         nominal_cq=st.nominal_cq, npb_cq=st.nominal_plus_blimit_cq,
         slot_fr=st.slot_fr, slot_valid=st.slot_valid,
+        res_group=st.res_group,
         cq_can_preempt_borrow=st.cq_can_preempt_borrow,
         cq_wcb_borrow=st.cq_wcb_borrow, cq_wcp_preempt=st.cq_wcp_preempt,
         forest_of_cq=forest_of_cq, strict_cq=strict,
@@ -1739,9 +1711,9 @@ def pack_burst(structure, queues, cache, scheduler, clock,
 def _roundtrips_clean(rec, q, cq_live, keys, covers_pods) -> bool:
     """Verify that popped-and-requeued heads still match their packed
     rows: same Info object, same parked bit, same flavor-walk start
-    slot.  These are the only row facts a pop/requeue roundtrip can
+    slots.  These are the only row facts a pop/requeue roundtrip can
     move without hitting a hard journal touch."""
-    from .solver import resume_start
+    from .solver import resume_starts
     if q is None or not q.active or cq_live is None:
         return False
     for key in keys:
@@ -1765,8 +1737,8 @@ def _roundtrips_clean(rec, q, cq_live, keys, covers_pods) -> bool:
             return False
         if bool(rec.parked[idx]) != parked_now:
             return False
-        if int(rec.resume[idx]) != resume_start(info, cq_live,
-                                                covers_pods):
+        if tuple(rec.resume[idx].tolist()) != resume_starts(
+                info, cq_live, covers_pods, rec.resume.shape[1]):
             return False
     return True
 
@@ -2119,7 +2091,8 @@ class BurstSolver:
                 a["potential0"], a["subtree"], a["guaranteed"],
                 a["borrow_cap"], a["has_blim"], a["parent"],
                 a["node_level"], a["nominal_cq"], a["npb_cq"],
-                a["slot_fr"], a["slot_valid"], a["cq_can_preempt_borrow"],
+                a["slot_fr"], a["slot_valid"], a["res_group"],
+                a["cq_can_preempt_borrow"],
                 a["cq_wcb_borrow"], a["cq_wcp_preempt"],
                 a["forest_of_cq"], a["strict_cq"],
                 a["wcq_lower"], a["rwc_enabled"], a["rwc_only_lower"],
@@ -2358,7 +2331,8 @@ class BurstSolver:
             a["potential0"], a["subtree"], a["guaranteed"],
             a["borrow_cap"], a["has_blim"], a["parent"],
             a["node_level"], a["nominal_cq"], a["npb_cq"],
-            a["slot_fr"], a["slot_valid"], a["cq_can_preempt_borrow"],
+            a["slot_fr"], a["slot_valid"], a["res_group"],
+            a["cq_can_preempt_borrow"],
             a["cq_wcb_borrow"], a["cq_wcp_preempt"],
             a["forest_of_cq"], a["strict_cq"],
             a["wcq_lower"], a["rwc_enabled"], a["rwc_only_lower"],
@@ -2441,21 +2415,21 @@ class BurstSolver:
         serializing ahead of them."""
         import jax
         if handle.decisions is not None:
-            return handle.decisions[5], handle.decisions[6]
+            return handle.decisions[6], handle.decisions[7]
         if handle.flags is not None:
             return handle.flags
         out = handle.pending
         handle.carry = out[-1]
-        dirty = jax.device_get(out[5])
-        dirty_reason = jax.device_get(out[6])
-        for arr in out[:5]:
+        dirty = jax.device_get(out[6])
+        dirty_reason = jax.device_get(out[7])
+        for arr in out[:6]:
             arr.copy_to_host_async()    # overlap; fetch still blocks
         handle.flags = (dirty, dirty_reason)
         return handle.flags
 
     def fetch(self, handle: BurstHandle):
         """Block for a dispatched window's decisions.  Returns the numpy
-        tuple (head_row, kind, slot, borrows, tgt_words, dirty,
+        tuple (head_row, kind, slot, tried, borrows, tgt_words, dirty,
         dirty_reason) and parks the final carry on the handle for
         ``dispatch_next``."""
         import jax
@@ -2484,8 +2458,8 @@ class BurstSolver:
             # candidate slot j, and the local tables were value-remapped
             # at identical slot positions.
             handle.decisions = tuple(
-                [np.ascontiguousarray(d[:, cp]) for d in dec[:5]]
-                + [dec[5], dec[6]])
+                [np.ascontiguousarray(d[:, cp]) for d in dec[:6]]
+                + [dec[6], dec[7]])
         else:
             handle.decisions = tuple(jax.device_get(out[:-1]))
         handle.pending = None
@@ -2504,8 +2478,8 @@ class BurstSolver:
     def run(self, plan: BurstPlan, K: int, runtime: int,
             ext_release: np.ndarray, ext_unpark: np.ndarray):
         """One fused dispatch of K cycles, synchronously.  Returns numpy
-        decision arrays (head_row, kind, slot, borrows, tgt_words,
-        dirty, dirty_reason, u_cq)."""
+        decision arrays (head_row, kind, slot, tried, borrows,
+        tgt_words, dirty, dirty_reason, u_cq)."""
         import jax
         handle = self.dispatch(plan, K, runtime, ext_release, ext_unpark)
         decisions = self.fetch(handle)

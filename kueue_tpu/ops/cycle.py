@@ -28,6 +28,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..obs.trace import span as _span
 from .quota_kernel import available_all, available_at, add_usage_chain
 
 
@@ -53,47 +54,186 @@ def available_all_np(usage, subtree, guaranteed, borrow_cap, has_blim,
     return avail
 
 
+def walk_groups(xp, *, req, frs, grp, slot_ok, eligible, slot_count, start,
+                av, pot, nom, use, sq, can_preempt_borrow, has_parent,
+                wcb, wcp, valid):
+    """The flavor walk of B heads, one walk a resource group, and the
+    join of a head's groups: the one statement of
+    findFlavorForPodSetResource (flavorassigner.go:499) for the device
+    path, in numpy (``classify_np``) or ``xp=jax.numpy`` (the jitted
+    ``solve_cycle`` and the fused window, ops/burst.py).
+
+    A resource belongs to one group of its queue (``grp`` [B, R], -1:
+    none covers it), and ``frs`` [B, S, R] names flavor s *of that
+    group* for resource r, so every plane a (slot, resource) is a
+    group's already; the walk reduces it over each group's own
+    resources to planes [B, G, S] and runs G walks side by side: from
+    the group's own start slot (``start`` [B, G]), over the group's own
+    flavors (``slot_ok``, ``slot_count``), passing over what the head
+    may not take in that group (``eligible`` [B, G, S]), under the
+    queue's stop rules.  A group none of whose resources the head
+    requests is not walked.  The head is as good as its worst walked
+    group (NoFit in one is NoFit; a requested resource no group covers
+    is NoFit), borrows if any does, and takes one slot a group.
+
+    ``av`` / ``pot`` / ``nom`` / ``use`` / ``sq`` [B, S, R] are the
+    head's queue's available, potential, nominal, usage and subtree
+    quota at ``frs``.  Returns a dict; see ``classify_np``."""
+    B, S, R = frs.shape
+    G = slot_ok.shape[1]
+    req = req[:, None, :]                                   # [B,1,R]
+    covered = frs >= 0
+    needed = req > 0
+    in_g = grp[:, None, :] == xp.arange(G, dtype=grp.dtype)[None, :, None]
+    sel = in_g[:, :, None, :]                               # [B,G,1,R]
+    uncovered = xp.any(needed[:, 0, :] & (grp < 0), axis=1)
+    walked = xp.any(needed & in_g, axis=2)                  # [B,G]
+
+    relevant = covered & needed
+    fit_r = req <= av
+    nofit_r = req > pot
+    preempt_capable_r = (req <= nom) | can_preempt_borrow[:, None, None]
+    res_nofit = relevant & (nofit_r | (~fit_r & ~preempt_capable_r))
+    borrow_r = relevant & (use + req > sq)
+
+    def any_g(x):           # [B,S,R] -> [B,G,S] over the group's own
+        return xp.any(x[:, None] & sel, axis=3)
+
+    missing = any_g(needed & ~covered)
+    barred = slot_ok & ~eligible
+    slot_ok = slot_ok & eligible
+    fit_s = ~any_g(relevant & ~fit_r) & ~missing & slot_ok   # [B,G,S]
+    nofit_s = any_g(res_nofit) | missing | ~slot_ok
+    preempt_s = ~fit_s & ~nofit_s
+    borrows_s = any_g(borrow_r) & has_parent[:, None, None]
+
+    # the fungibility walk: a slot STOPS it when it fits without
+    # borrowing, fits borrowing under whenCanBorrow=Borrow, or is
+    # preempt-capable under whenCanPreempt=Preempt (shouldTryNextFlavor,
+    # :620); else it keeps the best-mode slot seen (Fit > Preempt >
+    # NoFit, first occurrence), a stop overriding any earlier best
+    sidx = xp.arange(S, dtype=xp.int32)[None, None, :]
+    active_s = sidx >= start[:, :, None]
+    stop_s = (active_s & (fit_s | (preempt_s & wcp[:, None, None]))
+              & (~borrows_s | wcb[:, None, None]))
+    has_stop = xp.any(stop_s, axis=2)                       # [B,G]
+    stop_idx = xp.argmax(stop_s, axis=2).astype(xp.int32)
+    act_mode = xp.where(active_s,
+                        xp.where(fit_s, 2, xp.where(preempt_s, 1, 0)), 0)
+    best_mode = act_mode.max(axis=2)
+    best_idx = xp.argmax((act_mode == best_mode[:, :, None]) & active_s,
+                         axis=2).astype(xp.int32)
+    chosen = xp.where(has_stop, stop_idx, best_idx)         # [B,G]
+    at = chosen[:, :, None]
+    chosen_mode = xp.take_along_axis(act_mode, at, axis=2)[:, :, 0]
+    chosen_borrows = (xp.take_along_axis(borrows_s, at, axis=2)[:, :, 0]
+                      & walked)
+    # the resume state the host records for a walk: the stop slot when
+    # it stopped mid-list, else -1 (whole list attempted)
+    tried = xp.where(walked & has_stop & (chosen < slot_count - 1),
+                     chosen, -1)
+
+    # -- the join ------------------------------------------------------
+    mode_g = xp.where(walked, chosen_mode, 2)
+    head_mode = xp.where(valid & ~uncovered, mode_g.min(axis=1), 0)
+    has_fit = head_mode == 2
+    has_preempt = head_mode == 1
+    borrows = xp.any(chosen_borrows, axis=1)
+    pre_g = walked & (chosen_mode == 1) & has_preempt[:, None]
+    preempt_slots = preempt_s & active_s                    # [B,G,S]
+    preempt_count = preempt_slots.sum(axis=2)
+    # a policy-stopped preempt choice is final, and so is the only
+    # preempt-capable slot; with several, the group's pick is the
+    # reclaim oracle's (flavorassigner.go:692 RECLAIM beats PREEMPT)
+    oracle_groups = pre_g & ~has_stop & (preempt_count > 1)
+
+    # each resource reads its own group's slot
+    grp_safe = xp.maximum(grp, 0)
+    res_slot = xp.take_along_axis(chosen, grp_safe, axis=1)  # [B,R]
+    rs = res_slot[:, None, :]
+    res_fr = xp.where(grp >= 0,
+                      xp.take_along_axis(frs, rs, axis=1)[:, 0, :], -1)
+    slot_res_fit = fit_r | ~relevant                        # [B,S,R]
+    res_fit = xp.take_along_axis(slot_res_fit, rs, axis=1)[:, 0, :]
+    ps_r = xp.take_along_axis(                              # [B,S,R]
+        xp.swapaxes(preempt_slots, 1, 2), grp_safe[:, None, :]
+        + xp.zeros((1, S, 1), dtype=grp_safe.dtype), axis=2)
+    oracle_ask = (ps_r & (grp >= 0)[:, None, :] & relevant & ~fit_r
+                  & (req <= nom) & (use + req <= sq))
+
+    last = xp.where(has_stop, stop_idx + 1, slot_count)
+    counted = walked & valid[:, None]
+    walk_slots = xp.where(counted, xp.maximum(last - start, 0), 0)
+    visited = active_s & (sidx < last[:, :, None])
+    walk_ineligible = xp.where(
+        counted, (barred & visited).sum(axis=2), 0)
+    lo = xp.where(walked, chosen_mode, 2).min(axis=1)
+    hi = xp.where(walked, chosen_mode, 0).max(axis=1)
+    return {
+        "has_fit": has_fit, "has_preempt": has_preempt,
+        "borrows": borrows, "chosen": chosen, "walked": walked,
+        "tried": tried, "has_stop": has_stop, "pre_g": pre_g,
+        "oracle_groups": oracle_groups, "preempt_slots": preempt_slots,
+        "res_fr": res_fr, "res_fit": res_fit,
+        "slot_res_fit": slot_res_fit, "slot_borrows": borrows_s,
+        "oracle_ask": oracle_ask,
+        "walk_slots": walk_slots.sum(axis=1),
+        "walk_ineligible": walk_ineligible.sum(axis=1),
+        "group_walks": counted.sum(axis=1),
+        "split_mode": valid & xp.any(walked, axis=1) & (lo != hi),
+    }
+
+
 def classify_np(packed, avail0=None, potential0=None, start_slot=None,
                 eligible=None):
-    """Vectorized nominate on the host: per-head slot classification.
+    """Vectorized nominate on the host: per-head, per-group slot
+    classification (``walk_groups`` over the cycle's heads).
 
-    The per-head flavor walk (flavorassigner.go:499) is evaluated dense
-    over all slots and then resolved under the CQ's FlavorFungibility
-    policy: a slot STOPS the walk when it fits without borrowing, fits
-    borrowing under whenCanBorrow=Borrow, or is preempt-capable under
-    whenCanPreempt=Preempt (shouldTryNextFlavor, :620); otherwise the
-    walk keeps the best-mode slot seen (Fit > Preempt > NoFit, first
-    occurrence wins), with a stop slot overriding any earlier best.
-    ``start_slot`` [W] carries the fungibility resume index
-    (last_tried_flavor_idx + 1); slots below it are never attempted.
-    ``eligible`` [W, S] is False where the head's PodSet may not take
-    the flavor for a taint or a selector (ops/eligibility.py): the walk
-    visits such a slot and passes on, as over a flavor that does not
-    exist; it is NoFit for that head, no stop, no preempt-capable slot
-    and nothing to ask the oracle about.
+    ``start_slot`` [W, G] carries the fungibility resume index a group
+    (last_tried_flavor_idx + 1 of the group's first requested resource);
+    slots below it are never attempted.  ``eligible`` [W, G, S] is False
+    where the head's PodSet may not take the flavor for a taint or a
+    selector (ops/eligibility.py): the walk visits such a slot and
+    passes on, as over a flavor that does not exist; it is NoFit for
+    that head, no stop, no preempt-capable slot and nothing to ask the
+    oracle about.
 
-    Returns a dict of [W]-shaped arrays:
-      fit_slot0     the walk's chosen Fit slot or -1
-      borrows0      the fit assignment borrows
-      preempt0      no fit chosen, the walk chose a preempt-capable slot
-      preempt_slot0 that slot
-      preempt_borrows0  that preempt assignment borrows
-      preempt_res_fit   [W, R] per-resource Fit flag on the preempt slot
-                    (False ⇒ the resource is the one needing preemption)
-      preempt_stopped0  the walk STOPPED at the preempt slot (the choice
-                    is policy-forced, independent of the reclaim oracle)
-      preempt_slots [W, S] the attempted preempt-capable slots; with
-                    several and no stop, the pick among them is the
-                    reclaim oracle's (``pick_preempt_slot_np``)
+    Returns a dict of [W]-shaped arrays unless noted:
+      fit0          the head fits: every walked group chose a Fit slot
+      slots0        [W, G] the slot each group's walk chose (-1: the
+                    head is NoFit); Fit slots of a fit head, and of a
+                    preempt head the Fit slots of its fitting groups
+                    beside the preempt slots of the others; no resource
+                    reads the slot of a group that was not walked
+      fit_slot0     the one-group reading: group 0's slot of a fit head,
+                    else -1
+      borrows0      the fit assignment borrows (in any group)
+      preempt0      no NoFit group, and some group chose a
+                    preempt-capable slot
+      preempt_borrows0  that preempt assignment borrows (in any group)
+      preempt_res_fit   [W, R] per-resource Fit flag on the slot of the
+                    resource's group (False ⇒ the resource is one
+                    needing preemption)
+      preempt_stopped0  every preempt-choosing group's walk STOPPED at
+                    its slot (the choice is policy-forced, independent
+                    of the reclaim oracle)
+      oracle_groups [W, G] the groups whose walk met several
+                    preempt-capable slots and no stop: the pick among
+                    them is the reclaim oracle's (``pick_preempt_slot_np``)
+      preempt_slots [W, G, S] the attempted preempt-capable slots
       slot_res_fit  [W, S, R] per-resource Fit flag on every slot
-      slot_borrows  [W, S] an assignment on the slot borrows
+      slot_borrows  [W, G, S] an assignment on the slot borrows
       oracle_ask    [W, S, R] the resources of ``preempt_slots`` on
                     which the host walk asks the oracle: short of quota,
                     within nominal, and not borrowing with the request
                     (flavorassigner.go:692, preemption_oracle.go:40)
-      walk_slots    [W] flavors the walk visited: up to its stop slot,
-                    or the whole list from its start
-      walk_ineligible  [W] of them, the flavors the head may not take
+      tried         [W, G] the resume state the host records a group:
+                    the stop slot when the walk stopped mid-list, else -1
+      walk_slots    flavors the head's walks visited: in each group up
+                    to its stop slot, or the whole list from its start
+      walk_ineligible  of them, the flavors the head may not take
+      group_walks   the groups walked
+      split_mode    the head's groups ended in different modes
     """
     st = packed.structure
     usage0 = packed.usage0
@@ -107,113 +247,67 @@ def classify_np(packed, avail0=None, potential0=None, start_slot=None,
             st.borrow_cap, st.has_borrow_limit, st.parent, st.depth)
 
     wl_cq = packed.wl_cq
-    req = packed.wl_requests.astype(np.int64)[:, None, :]   # [W,1,R]
     cqs = np.maximum(wl_cq, 0)
     frs = st.slot_fr[cqs]                                   # [W,S,R]
-    frs_safe = np.maximum(frs, 0)
-    covered = frs >= 0
-    needed = req > 0
-    missing = np.any(needed & ~covered, axis=2)             # [W,S]
-    av = avail0[cqs[:, None, None], frs_safe]               # [W,S,R]
-    pot = potential0[cqs[:, None, None], frs_safe]
-    nom = st.nominal_cq[cqs[:, None, None], frs_safe]
-    use = usage0[cqs[:, None, None], frs_safe]
-    sq = st.subtree_quota[cqs[:, None, None], frs_safe]
-
-    relevant = covered & needed
-    fit_r = req <= av
-    nofit_r = req > pot
-    preempt_capable_r = (req <= nom) | st.cq_can_preempt_borrow[cqs][:, None, None]
-    res_nofit = relevant & (nofit_r | (~fit_r & ~preempt_capable_r))
-
-    slot_ok = st.slot_valid[cqs]
-    barred = np.zeros_like(slot_ok)
-    if eligible is not None:
-        barred = slot_ok & ~eligible
-        slot_ok = slot_ok & eligible
-    fit_s = (np.all(np.where(relevant, fit_r, True), axis=2)
-             & ~missing & slot_ok)                          # [W,S]
-    nofit_s = np.any(res_nofit, axis=2) | missing | ~slot_ok
-    preempt_s = ~fit_s & ~nofit_s
-    has_parent = st.parent[cqs] >= 0
-    borrow_r = np.where(relevant, use + req > sq, False)
-    borrows_s = np.any(borrow_r, axis=2) & has_parent[:, None]
-
+    at = (cqs[:, None, None], np.maximum(frs, 0))
+    W, G = len(cqs), st.n_groups
+    S = frs.shape[1]
     valid = wl_cq >= 0
-    W = len(cqs)
-    S = fit_s.shape[1]
-    w = np.arange(W)
-    wcb = st.cq_wcb_borrow[cqs]
-    wcp = st.cq_wcp_preempt[cqs]
-    if start_slot is None:
-        start = np.zeros(W, dtype=np.int32)
-    else:
-        start = np.asarray(start_slot, dtype=np.int32)
-    active_s = np.arange(S)[None, :] >= start[:, None]      # [W, S]
-    stop_s = (active_s & (fit_s | (preempt_s & wcp[:, None]))
-              & (~borrows_s | wcb[:, None]))
-    has_stop = np.any(stop_s, axis=1)
-    stop_idx = np.argmax(stop_s, axis=1)
-    act_mode = np.where(active_s,
-                        np.where(fit_s, 2, np.where(preempt_s, 1, 0)), 0)
-    best_mode = act_mode.max(axis=1)
-    best_idx = np.argmax((act_mode == best_mode[:, None]) & active_s,
-                         axis=1)
-    chosen = np.where(has_stop, stop_idx, best_idx)
-    chosen_mode = act_mode[w, chosen]
-
-    has_fit = (chosen_mode == 2) & valid
-    fit_slot0 = np.where(has_fit, chosen, -1).astype(np.int32)
-    borrows0 = borrows_s[w, chosen] & has_fit
-
-    has_preempt = (chosen_mode == 1) & valid
-    preempt_slot0 = np.where(has_preempt, chosen, -1).astype(np.int32)
-    preempt_borrows0 = borrows_s[w, chosen] & has_preempt
-    # per-resource fit on the preempt slot (for frs_need_preemption)
-    preempt_res_fit = fit_r[w, chosen] | ~relevant[w, chosen]
-    # how many attempted slots are preempt-capable: with exactly one, the
-    # host walk picks it regardless of the reclaim oracle (the oracle only
-    # reorders among preempt-capable flavors — flavorassigner.go:692
-    # RECLAIM vs PREEMPT), so the device may fix the slot without running
-    # the oracle; a policy STOP at the slot forces it the same way
-    preempt_slots = preempt_s & active_s
-    preempt_slot_count = preempt_slots.sum(axis=1).astype(np.int32)
-    preempt_stopped0 = has_preempt & has_stop
-    oracle_ask = (preempt_slots[:, :, None] & relevant & ~fit_r
-                  & (req <= nom) & (use + req <= sq))
-    last = np.where(has_stop, stop_idx + 1, st.slot_count_cq[cqs])
-    walk_slots = np.where(valid, np.maximum(last - start, 0), 0)
-    visited = active_s & (np.arange(S)[None, :] < last[:, None])
-    walk_ineligible = np.where(valid, (barred & visited).sum(axis=1), 0)
-
+    with _span("cycle.nominate.classify.groups"):
+        out = walk_groups(
+            np, req=packed.wl_requests.astype(np.int64), frs=frs,
+            grp=st.res_group[cqs], slot_ok=st.slot_valid[cqs],
+            eligible=(np.ones((W, G, S), dtype=bool) if eligible is None
+                      else np.asarray(eligible).reshape(W, G, S)),
+            slot_count=st.slot_count_cq[cqs],
+            start=(np.zeros((W, G), dtype=np.int32) if start_slot is None
+                   else np.asarray(start_slot, dtype=np.int32).reshape(W, G)),
+            av=avail0[at], pot=potential0[at], nom=st.nominal_cq[at],
+            use=usage0[at], sq=st.subtree_quota[at],
+            can_preempt_borrow=st.cq_can_preempt_borrow[cqs],
+            has_parent=st.parent[cqs] >= 0, wcb=st.cq_wcb_borrow[cqs],
+            wcp=st.cq_wcp_preempt[cqs], valid=valid)
+    has_fit, has_preempt = out["has_fit"], out["has_preempt"]
+    chosen = out["chosen"]
+    decided = (has_fit | has_preempt)[:, None]
     return {
-        "fit_slot0": fit_slot0,
-        "borrows0": borrows0,
+        "fit0": has_fit,
+        "slots0": np.where(decided, chosen, -1).astype(np.int32),
+        "fit_slot0": np.where(has_fit, chosen[:, 0], -1).astype(np.int32),
+        "borrows0": out["borrows"] & has_fit,
         "preempt0": has_preempt,
-        "preempt_slot0": preempt_slot0,
-        "preempt_borrows0": preempt_borrows0,
-        "preempt_res_fit": preempt_res_fit,
-        "preempt_slot_count": preempt_slot_count,
-        "preempt_stopped0": preempt_stopped0,
-        "preempt_slots": preempt_slots,
-        "slot_res_fit": fit_r | ~relevant,
-        "slot_borrows": borrows_s,
-        "oracle_ask": oracle_ask,
-        "walk_slots": walk_slots.astype(np.int32),
-        "walk_ineligible": walk_ineligible.astype(np.int32),
+        "preempt_borrows0": out["borrows"] & has_preempt,
+        "preempt_res_fit": out["res_fit"],
+        "preempt_stopped0": has_preempt & ~np.any(
+            out["pre_g"] & ~out["has_stop"], axis=1),
+        "oracle_groups": out["oracle_groups"],
+        "preempt_slots": out["preempt_slots"],
+        "slot_res_fit": out["slot_res_fit"],
+        "slot_borrows": out["slot_borrows"],
+        "oracle_ask": out["oracle_ask"],
+        "tried": out["tried"].astype(np.int32),
+        "walk_slots": out["walk_slots"].astype(np.int32),
+        "walk_ineligible": out["walk_ineligible"].astype(np.int32),
+        "group_walks": out["group_walks"].astype(np.int32),
+        "split_mode": out["split_mode"],
         "avail0": avail0,
         "potential0": potential0,
     }
 
 
-def pick_preempt_slot_np(preempt_slots, slot_res_fit, reclaim) -> np.ndarray:
-    """The walk's pick among several preempt-capable slots, once the
+def pick_preempt_slot_np(preempt_slots, slot_res_fit, reclaim,
+                         in_group=None) -> np.ndarray:
+    """One group's pick among several preempt-capable slots, once the
     reclaim oracle has answered (flavorassigner.go:308 granular modes):
-    a resource short of quota is Reclaim where ``reclaim`` [W, S, R]
-    says the quota can be had from other queues' borrowers alone, else
-    Preempt; a slot is as good as its worst resource (Fit > Reclaim >
-    Preempt) and the first slot of the best mode wins.  Returns [W]
-    slots; a row without a preempt-capable slot reads 0."""
+    a resource of the group (``in_group`` [W, R]; omitted, every
+    resource) short of quota is
+    Reclaim where ``reclaim`` [W, S, R] says the quota can be had from
+    other queues' borrowers alone, else Preempt; a slot is as good as
+    its worst resource (Fit > Reclaim > Preempt) and the first slot of
+    the best mode wins.  ``preempt_slots`` [W, S] are the group's.
+    Returns [W] slots; a row without a preempt-capable slot reads 0."""
+    if in_group is not None:
+        slot_res_fit = slot_res_fit | ~in_group[:, None, :]
     res_mode = np.where(slot_res_fit, 3, np.where(reclaim, 2, 1))
     mode = np.where(preempt_slots, res_mode.min(axis=2), 0)
     return np.argmax(mode == mode.max(axis=1, keepdims=True),
@@ -475,96 +569,81 @@ def admit_scan_preempt(usage0, subtree, guaranteed, borrow_cap, has_blim,
 # One-call solvers (probe / parity-test surface)
 # ----------------------------------------------------------------------
 
-@partial(jax.jit, static_argnames=("depth", "run_scan"))
-def solve_cycle(usage0, subtree, guaranteed, borrow_cap, has_blim, parent,
-                nominal_cq, slot_fr, slot_valid, cq_can_preempt_borrow,
-                wl_cq, wl_requests, wl_priority, wl_timestamp,
-                cq_wcb_borrow=None, cq_wcp_preempt=None, start_slot=None,
-                eligible=None, *, depth: int, run_scan: bool = True):
-    """Returns (admitted[W] bool, slot[W] int32, borrows[W] bool,
-    preempt_possible[W] bool, fit_slot0[W] int32, borrows0[W] bool).
-
-    Phase 1 classifies each head once against the snapshot usage; the scan
-    then admits in cycle order with a fits re-check on the FIXED slot —
-    the reference admit-loop semantics (assignments are never recomputed
-    within a cycle).  With ``run_scan=False`` only phase 1 runs.
-
-    ``cq_wcb_borrow``/``cq_wcp_preempt`` [C] carry the FlavorFungibility
-    policy per CQ and ``start_slot`` [W] the fungibility resume index;
-    omitted, the default policy (whenCanBorrow=Borrow,
-    whenCanPreempt=TryNextFlavor) walks every slot from 0 — the legacy
-    classify surface.  ``eligible`` [W, S] bars a head from the flavors
-    its PodSet may not take (classify_np); omitted, none is barred."""
-    C = slot_fr.shape[0]
+def _phase1(usage0, subtree, guaranteed, borrow_cap, has_blim, parent,
+            nominal_cq, slot_fr, slot_valid, cq_can_preempt_borrow, wl_cq,
+            wl_requests, cq_wcb_borrow, cq_wcp_preempt, start_slot,
+            eligible, res_group, depth):
+    """``solve_cycle``'s phase 1, traced into its caller's program:
+    (has_fit, fit_slot0, borrows0, preempt0, res_fr [W, R])."""
+    C, S, R = slot_fr.shape
     W = wl_cq.shape[0]
-    S = slot_fr.shape[1]
+    G = slot_valid.shape[1]
+    if res_group is None:
+        res_group = jnp.zeros((C, R), dtype=jnp.int32)
     if cq_wcb_borrow is None:
         cq_wcb_borrow = jnp.ones(C, dtype=bool)
     if cq_wcp_preempt is None:
         cq_wcp_preempt = jnp.zeros(C, dtype=bool)
-    if start_slot is None:
-        start_slot = jnp.zeros(W, dtype=jnp.int32)
-    if eligible is None:
-        eligible = jnp.ones((W, S), dtype=bool)
+    start_slot = (jnp.zeros((W, G), dtype=jnp.int32) if start_slot is None
+                  else start_slot.reshape(W, G))
+    eligible = (jnp.ones((W, G, S), dtype=bool) if eligible is None
+                else eligible.reshape(W, G, S))
 
     avail0 = available_all(usage0, subtree, guaranteed, borrow_cap, has_blim,
                            parent, depth)
     potential0 = available_all(jnp.zeros_like(usage0), subtree, guaranteed,
                                borrow_cap, has_blim, parent, depth)
+    cqs = jnp.maximum(wl_cq, 0)
+    frs = slot_fr[cqs]                                      # [W,S,R]
+    at = (cqs[:, None, None], jnp.maximum(frs, 0))
+    out = walk_groups(
+        jnp, req=wl_requests, frs=frs, grp=res_group[cqs],
+        slot_ok=slot_valid[cqs], eligible=eligible,
+        slot_count=jnp.sum(slot_valid, axis=2).astype(jnp.int32)[cqs],
+        start=start_slot, av=avail0[at], pot=potential0[at],
+        nom=nominal_cq[at], use=usage0[at], sq=subtree[at],
+        can_preempt_borrow=cq_can_preempt_borrow[cqs],
+        has_parent=parent[cqs] >= 0, wcb=cq_wcb_borrow[cqs],
+        wcp=cq_wcp_preempt[cqs], valid=wl_cq >= 0)
+    has_fit = out["has_fit"]
+    fit_slot0 = jnp.where(has_fit, out["chosen"][:, 0], -1).astype(jnp.int32)
+    borrows0 = out["borrows"] & has_fit
+    preempt0 = out["has_preempt"]
 
-    def classify(wl_cq_i, req, start_i, eligible_i):
-        cq = jnp.maximum(wl_cq_i, 0)
-        slot_ok = slot_valid[cq] & eligible_i   # [S]
-        frs = slot_fr[cq]                       # [S, R]
-        frs_safe = jnp.maximum(frs, 0)
-        covered = frs >= 0
-        needed = req[None, :] > 0
-        missing = jnp.any(needed & ~covered, axis=1)        # [S]
-        av = avail0[cq][frs_safe]               # [S, R]
-        pot = potential0[cq][frs_safe]
-        nom = nominal_cq[cq][frs_safe]
-        use = usage0[cq][frs_safe]
-        sq = subtree[cq][frs_safe]
+    return has_fit, fit_slot0, borrows0, preempt0, out["res_fr"]
 
-        relevant = covered & needed
-        fit_r = req[None, :] <= av
-        nofit_r = req[None, :] > pot
-        preempt_capable_r = (req[None, :] <= nom) | cq_can_preempt_borrow[cq]
-        res_nofit = relevant & (nofit_r | (~fit_r & ~preempt_capable_r))
 
-        fit = (jnp.all(jnp.where(relevant, fit_r, True), axis=1)
-               & ~missing & slot_ok)            # [S]
-        nofit = jnp.any(res_nofit, axis=1) | missing | ~slot_ok
-        preempt = ~fit & ~nofit
-        has_parent = parent[cq] >= 0
-        borrow_r = jnp.where(relevant, use + req[None, :] > sq, False)
-        borrows_s = jnp.any(borrow_r, axis=1) & has_parent   # [S]
+@partial(jax.jit, static_argnames=("depth", "run_scan"))
+def solve_cycle(usage0, subtree, guaranteed, borrow_cap, has_blim, parent,
+                nominal_cq, slot_fr, slot_valid, cq_can_preempt_borrow,
+                wl_cq, wl_requests, wl_priority, wl_timestamp,
+                cq_wcb_borrow=None, cq_wcp_preempt=None, start_slot=None,
+                eligible=None, res_group=None,
+                *, depth: int, run_scan: bool = True):
+    """Returns (admitted[W] bool, slot[W] int32, borrows[W] bool,
+    preempt_possible[W] bool, fit_slot0[W] int32, borrows0[W] bool).
 
-        # fungibility walk (classify_np twin): stop slots override the
-        # best-mode slot; slots below the resume index are not attempted
-        wcb = cq_wcb_borrow[cq]
-        wcp = cq_wcp_preempt[cq]
-        active = jnp.arange(S) >= start_i                    # [S]
-        stop = active & (fit | (preempt & wcp)) & (~borrows_s | wcb)
-        has_stop = jnp.any(stop)
-        act_mode = jnp.where(active,
-                             jnp.where(fit, 2, jnp.where(preempt, 1, 0)),
-                             0)
-        best_idx = jnp.argmax(act_mode == act_mode.max())
-        chosen = jnp.where(has_stop, jnp.argmax(stop), best_idx)
-        chosen_mode = act_mode[chosen]
+    Phase 1 classifies each head once against the snapshot usage; the scan
+    then admits in cycle order with a fits re-check on the FIXED slots —
+    the reference admit-loop semantics (assignments are never recomputed
+    within a cycle).  With ``run_scan=False`` only phase 1 runs.
 
-        has_fit = chosen_mode == 2
-        fit_slot = jnp.where(has_fit, chosen, -1)
-        borrows = jnp.where(has_fit, borrows_s[chosen], False)
-        preempt_possible = chosen_mode == 1
-        valid = wl_cq_i >= 0
-        return (jnp.where(valid, fit_slot, -1),
-                borrows & valid,
-                preempt_possible & valid)
-
-    fit_slot0, borrows0, preempt0 = jax.vmap(classify)(
-        wl_cq, wl_requests, start_slot, eligible)
+    ``cq_wcb_borrow``/``cq_wcp_preempt`` [C] carry the FlavorFungibility
+    policy per CQ and ``start_slot`` [W, G] the fungibility resume index
+    a group; omitted, the default policy (whenCanBorrow=Borrow,
+    whenCanPreempt=TryNextFlavor) walks every slot from 0 — the legacy
+    classify surface.  ``eligible`` [W, G, S] bars a head from the
+    flavors its PodSet may not take (classify_np); omitted, none is
+    barred.  ``slot_valid`` is [C, G, S] and ``res_group`` [C, R] names
+    each resource's group; omitted, every resource is in group 0.  The slots returned are
+    group 0's (``classify_np``'s ``fit_slot0``); the admit scan charges
+    every group's."""
+    W = wl_cq.shape[0]
+    has_fit, fit_slot0, borrows0, preempt0, res_fr = _phase1(
+        usage0, subtree, guaranteed, borrow_cap, has_blim, parent,
+        nominal_cq, slot_fr, slot_valid, cq_can_preempt_borrow, wl_cq,
+        wl_requests, cq_wcb_borrow, cq_wcp_preempt, start_slot, eligible,
+        res_group, depth)
 
     if not run_scan:
         zeros_b = jnp.zeros(W, dtype=bool)
@@ -574,33 +653,47 @@ def solve_cycle(usage0, subtree, guaranteed, borrow_cap, has_blim, parent,
     order = jnp.lexsort((jnp.arange(W), wl_timestamp, -wl_priority,
                          borrows0.astype(jnp.int32)))
     no_reserve = jnp.zeros(W, dtype=bool)
-    dec_fr, dec_amt, fit_mask = decision_pairs_from_slots(
-        slot_fr, wl_cq, wl_requests, fit_slot0)
+    dec_fr, dec_amt = decision_pairs(res_fr, wl_requests, has_fit)
     zero_pairs = jnp.full_like(dec_fr, -1)
     admitted = admit_scan(
         usage0, subtree, guaranteed, borrow_cap, has_blim, parent,
         nominal_cq, jnp.zeros_like(nominal_cq), wl_cq, dec_fr, dec_amt,
-        fit_mask, zero_pairs, jnp.zeros_like(dec_amt), no_reserve,
+        has_fit, zero_pairs, jnp.zeros_like(dec_amt), no_reserve,
         no_reserve, order, depth=depth)
     slots = jnp.where(admitted, fit_slot0, -1).astype(jnp.int32)
     borrows = borrows0 & admitted
     return admitted, slots, borrows, preempt0, fit_slot0, borrows0
 
 
-def decision_pairs_from_slots(slot_fr, wl_cq, wl_requests, fit_slot0):
-    """Single-slot classifications → decision pairs (jax or numpy).
+def decision_pairs(res_fr, wl_requests, on):
+    """Per-resource flavor-resources -> decision pairs (jax or numpy).
 
-    dec_fr/dec_amt [W, R]: the chosen slot's flavor-resource per requested
-    resource (-1 where not requested or not fit); fit_mask [W]."""
-    xp = jnp if isinstance(wl_cq, jnp.ndarray) else np
-    cqs = xp.maximum(wl_cq, 0)
-    slots = xp.maximum(fit_slot0, 0)
-    frs = slot_fr[cqs, slots]                               # [W, R]
-    fit_mask = (fit_slot0 >= 0) & (wl_cq >= 0)
-    relevant = (frs >= 0) & (wl_requests > 0) & fit_mask[:, None]
-    dec_fr = xp.where(relevant, frs, -1).astype(xp.int32)
+    ``res_fr`` [W, R]: the flavor-resource each resource takes on the
+    slot its own group chose.  Returns dec_fr/dec_amt [W, R]: that, per
+    requested resource, for the heads ``on`` [W] (-1 / 0 elsewhere)."""
+    xp = jnp if isinstance(res_fr, jnp.ndarray) else np
+    relevant = (res_fr >= 0) & (wl_requests > 0) & on[:, None]
+    dec_fr = xp.where(relevant, res_fr, -1).astype(xp.int32)
     dec_amt = xp.where(relevant, wl_requests, 0).astype(xp.int32)
-    return dec_fr, dec_amt, fit_mask
+    return dec_fr, dec_amt
+
+
+def res_slots(grp, slots):
+    """[W, R] the slot each resource reads when each group takes
+    ``slots`` [W, G] (numpy): ``slots[w, grp[w, r]]``, of its own group
+    (``grp`` [W, R], -1: no group covers the resource; reads group 0)."""
+    return np.take_along_axis(np.maximum(slots, 0), np.maximum(grp, 0),
+                              axis=1)
+
+
+def slot_frs(slot_fr, res_group, wl_cq, slots):
+    """[W, R] the flavor-resource each resource takes when each group of
+    the head's queue takes ``slots`` [W, G] (numpy)."""
+    cqs = np.maximum(wl_cq, 0)
+    grp = res_group[cqs]                                    # [W,R]
+    frs = slot_fr[cqs[:, None], res_slots(grp, slots),
+                  np.arange(slot_fr.shape[2])]
+    return np.where(grp >= 0, frs, -1)
 
 
 def add_usage_chain_batched(usage, nodes, deltas, guaranteed, parent,
@@ -704,24 +797,22 @@ def solve_cycle_forests(usage0, subtree, guaranteed, borrow_cap, has_blim,
                         parent, nominal_cq, slot_fr, slot_valid,
                         cq_can_preempt_borrow, wl_cq, wl_requests,
                         wl_priority, wl_timestamp, forest_of_node,
-                        eligible=None,
+                        eligible=None, res_group=None,
                         *, depth: int, n_forests: int, max_forest_wl: int):
     """One-call phase 1 + forest-parallel admit scan (probe surface)."""
     W = wl_cq.shape[0]
-    _, _, _, preempt0, fit_slot0, borrows0 = solve_cycle(
+    has_fit, fit_slot0, borrows0, preempt0, res_fr = _phase1(
         usage0, subtree, guaranteed, borrow_cap, has_blim, parent,
-        nominal_cq, slot_fr, slot_valid, cq_can_preempt_borrow,
-        wl_cq, wl_requests, wl_priority, wl_timestamp,
-        eligible=eligible, depth=depth, run_scan=False)
+        nominal_cq, slot_fr, slot_valid, cq_can_preempt_borrow, wl_cq,
+        wl_requests, None, None, None, eligible, res_group, depth)
     order = jnp.lexsort((jnp.arange(W), wl_timestamp, -wl_priority,
                          borrows0.astype(jnp.int32))).astype(jnp.int32)
     no_reserve = jnp.zeros(W, dtype=bool)
-    dec_fr, dec_amt, fit_mask = decision_pairs_from_slots(
-        slot_fr, wl_cq, wl_requests, fit_slot0)
+    dec_fr, dec_amt = decision_pairs(res_fr, wl_requests, has_fit)
     admitted = admit_scan_forests(
         usage0, subtree, guaranteed, borrow_cap, has_blim, parent,
         nominal_cq, jnp.zeros_like(nominal_cq), wl_cq, dec_fr, dec_amt,
-        fit_mask, jnp.full_like(dec_fr, -1), jnp.zeros_like(dec_amt),
+        has_fit, jnp.full_like(dec_fr, -1), jnp.zeros_like(dec_amt),
         no_reserve, no_reserve, order, forest_of_node, depth=depth,
         n_forests=n_forests, max_forest_wl=max_forest_wl)
     slots = jnp.where(admitted, fit_slot0, -1).astype(jnp.int32)

@@ -1,4 +1,4 @@
-"""Which flavors of its queue's resource group a PodSet may take.
+"""Which flavors of a resource group of its queue a PodSet may take.
 
 The host walk skips a flavor before it looks at quota when the PodSet
 does not tolerate one of the flavor's NoSchedule/NoExecute taints
@@ -7,18 +7,21 @@ does not tolerate one of the flavor's NoSchedule/NoExecute taints
 flavor's ``node_labels`` on the label keys that some flavor of the
 group carries (flavorassigner.go:553-575, flavorSelector :640;
 scheduler/flavorassigner.py ``_find_flavor_for_podset_resource``).  A
-skipped flavor is visited, is no stop and no candidate.
+skipped flavor is visited, is no stop and no candidate.  The rule is a
+group's: a selector key that only the flavors of one group carry is
+matched in that group's walk and ignored in every other's.
 
 This module states that rule once for the device path: a head's
-answer is a *skip mask*, bit s set when the PodSet may not take slot s
-of its queue's flavor list.  The per-cycle classify expands it into
-the ``[W, S]`` eligibility plane (ops/cycle.py ``classify_np``), the
-fused window carries it a row (``wl_flavor_skip``, ops/burst.py), or
-one column of zeros where every flavor of the structure is plain.  A
-mask is a function of the PodSet's selector, affinity and tolerations
-and of the flavor list alone, so it is evaluated once a distinct
-signature and flavor list, and cached on the ``Info`` under the
-structure generation (a flavor or queue edit bumps it).
+answer is one *skip mask* a resource group of its queue, bit s set when
+the PodSet may not take slot s of that group's flavor list.  The
+per-cycle classify expands them into the ``[W, G, S]`` eligibility
+plane (ops/cycle.py ``classify_np``), the fused window carries them a
+row (``wl_flavor_skip``, ops/burst.py), or one column of zeros where
+every flavor of the structure is plain.  A mask is a function of the
+PodSet's selector, affinity and tolerations and of the flavor list
+alone, so it is evaluated once a distinct signature and flavor list,
+and cached on the ``Info`` under the structure generation (a flavor or
+queue edit bumps it).
 """
 
 from __future__ import annotations
@@ -34,9 +37,10 @@ MASK_BITS = 8
 
 
 class FlavorList:
-    """One distinct flavor list of the structure's vector-decided
-    queues: the flavors in the queue's order, the label keys a selector
-    is matched on, and the masks evaluated so far, by signature."""
+    """One distinct flavor list (a resource group's) of the structure's
+    vector-decided queues: the flavors in the group's order, the label
+    keys a selector is matched on in this group, and the masks evaluated
+    so far, by signature."""
     __slots__ = ("flavors", "allowed_keys", "declared", "masks")
 
     def __init__(self, flavors: list):
@@ -69,37 +73,38 @@ class FlavorList:
 def bind_flavor_lists(snapshot, st) -> None:
     """Sets on a packed structure: ``cq_vector_ok`` [C] bool, the
     distinct ``flavor_lists`` of its vector-decided queues,
-    ``flavor_list_of_cq`` [C] (-1 where the queue is not vector-decided)
-    and ``flavors_declared`` (some list is).  The vector classify
-    reproduces the host flavor walk for a queue with a single resource
-    group whose flavors all exist and bind no topology; labels, taints
-    and any FlavorFungibility policy run in the vector math itself.
-    Anything else routes the queue's heads to the scalar host walk."""
-    C = len(st.cq_names)
+    ``flavor_list_of_cq`` [C, G] (-1 where the queue has no such group
+    or is not vector-decided) and ``flavors_declared`` (some list is).
+    The vector classify reproduces the host flavor walk, one walk a
+    resource group, for a queue whose flavors all exist and bind no
+    topology; labels, taints and any FlavorFungibility policy run in
+    the vector math itself.  Anything else routes the queue's heads to
+    the scalar host walk."""
+    C, G = len(st.cq_names), st.n_groups
     ok = np.zeros(C, dtype=bool)
-    of_cq = np.full(C, -1, dtype=np.int32)
+    of_cq = np.full((C, G), -1, dtype=np.int32)
     lists: list[FlavorList] = []
     index: dict[tuple, int] = {}
     for ci, name in enumerate(st.cq_names):
-        groups = snapshot.cluster_queues[name].spec.resource_groups
-        if len(groups) != 1:
-            continue
-        names = tuple(fq.name for fq in groups[0].flavors)
-        li = index.get(names)
-        if li is None:
-            flavors = [snapshot.resource_flavors.get(n) for n in names]
-            if any(f is None or f.topology_name for f in flavors):
-                li = -1
-            else:
-                fl = FlavorList(flavors)
-                li = -1 if (fl.declared and len(names) > MASK_BITS) \
-                    else len(lists)
-                if li >= 0:
-                    lists.append(fl)
-            index[names] = li
-        if li >= 0:
+        row = []
+        for rg in snapshot.cluster_queues[name].spec.resource_groups:
+            names = tuple(fq.name for fq in rg.flavors)
+            li = index.get(names)
+            if li is None:
+                flavors = [snapshot.resource_flavors.get(n) for n in names]
+                if any(f is None or f.topology_name for f in flavors):
+                    li = -1
+                else:
+                    fl = FlavorList(flavors)
+                    li = -1 if (fl.declared and len(names) > MASK_BITS) \
+                        else len(lists)
+                    if li >= 0:
+                        lists.append(fl)
+                index[names] = li
+            row.append(li)
+        if row and min(row) >= 0:
             ok[ci] = True
-            of_cq[ci] = li
+            of_cq[ci, :len(row)] = row
     st.cq_vector_ok = ok
     st.flavor_list_of_cq = of_cq
     st.flavor_lists = lists
@@ -107,10 +112,10 @@ def bind_flavor_lists(snapshot, st) -> None:
 
 
 def mask_plane_width(st, M: int) -> int:
-    """The second extent of the fused window's ``wl_flavor_skip`` plane:
-    a mask a row of the [C, M] grid where some flavor of the structure
-    is declared, one column of zeros (every row reads it) where all are
-    plain and no row can skip any."""
+    """The second extent of the fused window's ``wl_flavor_skip`` plane
+    ([C, width, G]): a mask a row of the [C, M] grid where some flavor
+    of the structure is declared, one column of zeros (every row reads
+    it) where all are plain and no row can skip any."""
     return M if st.flavors_declared else 1
 
 
@@ -125,8 +130,8 @@ def slots_of_mask(mask, S: int, xp=np):
 def declares(st, ci: int) -> bool:
     """Does a flavor of vector-decided queue ``ci`` carry labels or
     taints, so that its heads' masks can differ from 0?"""
-    li = int(st.flavor_list_of_cq[ci])
-    return li >= 0 and st.flavor_lists[li].declared
+    return any(li >= 0 and st.flavor_lists[li].declared
+               for li in st.flavor_list_of_cq[ci].tolist())
 
 
 def _signature(pod_set) -> tuple:
@@ -136,24 +141,32 @@ def _signature(pod_set) -> tuple:
             tuple(pod_set.tolerations))
 
 
-def skip_mask(info, st, ci: int, tally: dict | None = None) -> int:
-    """The skip mask of ``info``'s first PodSet in queue ``ci`` of
-    structure ``st`` (0 for a queue the vector path does not decide, or
-    whose flavors are all plain).  ``tally["eligibility_masks_built"]``
-    counts the signatures evaluated, as against read from a cache."""
+def skip_mask(info, st, ci: int, tally: dict | None = None) -> tuple:
+    """The skip masks of ``info``'s first PodSet in queue ``ci`` of
+    structure ``st``, one a resource group (all 0 for a queue the vector
+    path does not decide, or whose flavors are all plain).
+    ``tally["eligibility_masks_built"]`` counts the signatures
+    evaluated, as against read from a cache."""
+    G = st.n_groups
     if not declares(st, ci) or not info.obj.pod_sets:
-        return 0
-    fl = st.flavor_lists[int(st.flavor_list_of_cq[ci])]
+        return (0,) * G
     gen = st.generation           # < 0: a structure nothing vouches for
     hit = getattr(info, "_flavor_skip", None)
     if hit is not None and hit[0] == gen >= 0 and hit[1] == ci:
         return hit[2]
     pod_set = info.obj.pod_sets[0]
     sig = _signature(pod_set)
-    mask = fl.masks.get(sig)
-    if mask is None:
-        mask = fl.masks[sig] = fl.skip_mask(pod_set)
-        if tally is not None:
-            tally["eligibility_masks_built"] += 1
-    info._flavor_skip = (gen, ci, mask)
-    return mask
+    masks = []
+    for li in st.flavor_list_of_cq[ci].tolist():
+        mask = 0
+        if li >= 0 and st.flavor_lists[li].declared:
+            fl = st.flavor_lists[li]
+            mask = fl.masks.get(sig)
+            if mask is None:
+                mask = fl.masks[sig] = fl.skip_mask(pod_set)
+                if tally is not None:
+                    tally["eligibility_masks_built"] += 1
+        masks.append(mask)
+    masks = tuple(masks)
+    info._flavor_skip = (gen, ci, masks)
+    return masks

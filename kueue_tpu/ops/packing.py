@@ -8,7 +8,22 @@ shaped integer tensors (SURVEY §7 stage 1).  Axes:
 - W: cycle heads, padded to a bucket size (power of two) to bound
   recompilation
 - S: flavor slots per resource group (max flavor-list length)
+- G: resource groups a ClusterQueue (max over the queues)
 - R: distinct resource names
+
+F and G are rounded up to a power of two (``_plane_extent``): they are
+the minor extent of the fused window's row planes (``adm_usage0
+[C, M, F]``, ``resume0 [C, M, G]``), which the runtime lays out plane by
+plane on the device and so transposes on the host at every launch, and
+an extent of 3 takes it 2.1 s a plane where 2 takes 0.3 s and none at
+all 0.03 s (one TPU v5e, a 1,000 x 65,536 int32 plane; PERF.md §6, PR
+37).  The device tiles an extent of 3 as 4 already; a padded column
+holds no quota and a padded group no flavor, and no head names either.
+
+A resource belongs to one group of its queue, so the slot axis of
+``slot_fr`` is read a resource: ``slot_fr[c, s, r]`` is flavor ``s`` of
+the group that covers ``r`` (``res_group[c, r]``), and a head's
+assignment is one slot a group (ops/cycle.py ``classify_np``).
 
 The codec is split in two so the per-cycle cost is O(usage + heads), not
 O(cluster):
@@ -67,9 +82,11 @@ class PackedStructure:
     has_borrow_limit: np.ndarray         # [N, F] bool
     nominal_cq: np.ndarray               # [C, F] int32
     nominal_plus_blimit_cq: np.ndarray   # [C, F] int32 (INT "inf" when unlimited)
-    slot_fr: np.ndarray                  # [C, S, R] int32 F-index or -1
-    slot_valid: np.ndarray               # [C, S] bool
-    slot_count_cq: np.ndarray            # [C] int32: len(rg.flavors)
+    slot_fr: np.ndarray                  # [C, S, R] int32 F-index or -1:
+                                         # flavor s of r's own group
+    res_group: np.ndarray                # [C, R] int32 group of r, -1 none
+    slot_valid: np.ndarray               # [C, G, S] bool
+    slot_count_cq: np.ndarray            # [C, G] int32: len(rg.flavors)
     cq_can_preempt_borrow: np.ndarray    # [C] bool
     cq_wcb_borrow: np.ndarray            # [C] bool: whenCanBorrow == Borrow
     cq_wcp_preempt: np.ndarray           # [C] bool: whenCanPreempt == Preempt
@@ -78,6 +95,18 @@ class PackedStructure:
     n_forests: int
     cq_index: dict[str, int] = field(default_factory=dict)
     cq_covers_pods: set = field(default_factory=set)
+
+    @property
+    def n_groups(self) -> int:
+        """G: the most resource groups a ClusterQueue declares, rounded
+        up to a power of two."""
+        return self.slot_count_cq.shape[1]
+
+    @property
+    def n_frs(self) -> int:
+        """F: the flavor-resource axis, ``len(fr_index)`` rounded up to
+        a power of two."""
+        return self.subtree_quota.shape[1]
 
 
 @dataclass
@@ -124,6 +153,8 @@ class PackedCycle:
     @property
     def slot_valid(self): return self.structure.slot_valid
     @property
+    def res_group(self): return self.structure.res_group
+    @property
     def cq_can_preempt_borrow(self): return self.structure.cq_can_preempt_borrow
     @property
     def cq_wcb_borrow(self): return self.structure.cq_wcb_borrow
@@ -135,6 +166,12 @@ class PackedCycle:
     def forest_of_node(self): return self.structure.forest_of_node
     @property
     def n_forests(self): return self.structure.n_forests
+
+
+def _plane_extent(n: int) -> int:
+    """The minor extent of a row plane that holds ``n`` columns: the
+    next power of two (module docstring)."""
+    return 1 << max(0, n - 1).bit_length()
 
 
 def _bucket(n: int, minimum: int = 8) -> int:
@@ -154,8 +191,7 @@ def scaled_usage_row(st: PackedStructure, cq_live) -> Optional[np.ndarray]:
     [F] int32, or None when not exactly representable (unknown
     flavor-resource, a remainder under the scale, or int32 overflow) —
     any None fails the whole burst pack, matching the host path."""
-    F = max(1, len(st.fr_index))
-    row = np.zeros(F, dtype=np.int32)
+    row = np.zeros(st.n_frs, dtype=np.int32)
     scale = st.resource_scale
     for fr, v in cq_live.resource_node.usage.items():
         fi = st.fr_index.get(fr)
@@ -281,7 +317,7 @@ def pack_structure(snapshot: Snapshot, heads: list[Info] = (),
         frs.update(node.resource_node.usage)
     fr_list = sorted(frs)
     fr_index = {fr: i for i, fr in enumerate(fr_list)}
-    F = max(1, len(fr_list))
+    F = _plane_extent(len(fr_list))
 
     cq_covers_pods = {
         name for name in cq_names
@@ -381,14 +417,18 @@ def pack_structure(snapshot: Snapshot, heads: list[Info] = (),
         forest_of_node[ni] = root_forest.setdefault(cur, len(root_forest))
     n_forests = max(1, len(root_forest))
 
-    # flavor slots per CQ
-    S = 1
+    # flavor slots per CQ and resource group
+    S = G = 1
     for name in cq_names:
-        for rg in snapshot.cluster_queues[name].spec.resource_groups:
+        groups = snapshot.cluster_queues[name].spec.resource_groups
+        G = max(G, len(groups))
+        for rg in groups:
             S = max(S, len(rg.flavors))
+    G = _plane_extent(G)
     slot_fr = np.full((C, S, R), -1, dtype=np.int32)
-    slot_valid = np.zeros((C, S), dtype=bool)
-    slot_count = np.zeros(C, dtype=np.int32)
+    res_group = np.full((C, R), -1, dtype=np.int32)
+    slot_valid = np.zeros((C, G, S), dtype=bool)
+    slot_count = np.zeros((C, G), dtype=np.int32)
     cq_can_preempt_borrow = np.zeros(C, dtype=bool)
     cq_wcb_borrow = np.zeros(C, dtype=bool)
     cq_wcp_preempt = np.zeros(C, dtype=bool)
@@ -408,11 +448,14 @@ def pack_structure(snapshot: Snapshot, heads: list[Info] = (),
             ff.when_can_preempt == FlavorFungibilityPolicy.PREEMPT)
     for ci, name in enumerate(cq_names):
         cq = snapshot.cluster_queues[name]
-        for rg in cq.spec.resource_groups:
-            slot_count[ci] = max(slot_count[ci], len(rg.flavors))
+        for gi, rg in enumerate(cq.spec.resource_groups):
+            slot_count[ci, gi] = len(rg.flavors)
+            for rname in rg.covered_resources:
+                if rname in r_index:
+                    res_group[ci, r_index[rname]] = gi
             for si, fq in enumerate(rg.flavors):
                 exists = fq.name in snapshot.resource_flavors
-                slot_valid[ci, si] = slot_valid[ci, si] or exists
+                slot_valid[ci, gi, si] = exists
                 for rname in rg.covered_resources:
                     if rname in r_index:
                         fr = FlavorResource(fq.name, rname)
@@ -428,7 +471,8 @@ def pack_structure(snapshot: Snapshot, heads: list[Info] = (),
         subtree_quota=subtree, guaranteed=guaranteed, borrow_cap=borrow_cap,
         has_borrow_limit=has_blim, nominal_cq=nominal_cq,
         nominal_plus_blimit_cq=nominal_plus_blimit,
-        slot_fr=slot_fr, slot_valid=slot_valid, slot_count_cq=slot_count,
+        slot_fr=slot_fr, res_group=res_group, slot_valid=slot_valid,
+        slot_count_cq=slot_count,
         cq_can_preempt_borrow=cq_can_preempt_borrow,
         cq_wcb_borrow=cq_wcb_borrow, cq_wcp_preempt=cq_wcp_preempt,
         fair_weight_milli=fair_weight, forest_of_node=forest_of_node,
@@ -454,7 +498,7 @@ def pack_cycle(snapshot: Snapshot, heads: list[Info], ordering=None,
     if nodes is None:
         return None
 
-    N, F = st.node_count, max(1, len(st.fr_index))
+    N, F = st.node_count, st.n_frs
     R = len(st.resource_names)
     scale = st.resource_scale
     exact = st.exact_static

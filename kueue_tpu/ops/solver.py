@@ -6,15 +6,15 @@ Per cycle the solver:
    static cluster tensors are rebuilt only when the cache structure
    generation changes, so the per-cycle cost is O(usage + heads);
 2. runs the vectorized nominate (``ops.cycle.classify_np``) on the host
-   for heads whose shape the batched math covers (single resource group,
-   single PodSet, flavors without topology; node labels, taints,
-   selectors and tolerations ride in as each head's eligibility mask,
-   ``ops.eligibility``, and any fungibility policy and the resume state
-   run in the vector walk); the remaining heads are marked SCALAR — the
-   scheduler runs the real host FlavorAssigner walk for those few and
-   attaches the resulting assignment, so multi-resource-group CQs,
-   multi-PodSet workloads, partial admission, and TAS all stay inside a
-   device-decided cycle;
+   for heads whose shape the batched math covers (any number of
+   resource groups, one flavor walk a group; single PodSet, flavors
+   without topology; node labels, taints, selectors and tolerations
+   ride in as each head's eligibility masks, ``ops.eligibility``, and
+   any fungibility policy and the resume state run in the vector walk);
+   the remaining heads are marked SCALAR — the scheduler runs the real
+   host FlavorAssigner walk for those few and attaches the resulting
+   assignment, so multi-PodSet workloads, partial admission, and TAS
+   all stay inside a device-decided cycle;
 3. dispatches the sequential admit scan (``ops.cycle.admit_scan``) as ONE
    jitted program on the solver device (``ops.device.solver_device``: the
    default JAX backend's device, so the TPU on a chip host).  The scan
@@ -54,8 +54,8 @@ from ..resources import FlavorResource, Requests
 from .packing import (PackedCycle, PackedStructure, _bucket, coarse_bucket,
                       pack_cycle, pack_structure)
 from .cycle import (admit_scan, admit_scan_forests, admit_scan_preempt,
-                    classify_np, cycle_order_np, decision_pairs_from_slots,
-                    pick_preempt_slot_np)
+                    classify_np, cycle_order_np, decision_pairs,
+                    pick_preempt_slot_np, res_slots, slot_frs)
 from .device import on_accelerator, output_devices
 from .eligibility import bind_flavor_lists, skip_mask, slots_of_mask
 
@@ -78,13 +78,10 @@ class ClassifiedCycle:
     packed: PackedCycle
     heads: list[Info]
     snapshot: Snapshot
-    fit_slot0: np.ndarray        # [W] int32, -1 = no fit
     borrows0: np.ndarray         # [W] bool
     preempt0: np.ndarray         # [W] bool (no fit, preempt-capable)
-    preempt_slot0: np.ndarray    # [W] int32
     preempt_borrows0: np.ndarray  # [W] bool
     preempt_res_fit: np.ndarray  # [W, R] bool
-    preempt_slot_count: np.ndarray = None  # [W] int32 preempt-capable slots
     preempt_stopped0: np.ndarray = None    # [W] bool: the fungibility walk
                                            # policy-stopped ON the preempt
                                            # slot (choice is final — no
@@ -92,10 +89,17 @@ class ClassifiedCycle:
     # the walk's per-slot planes (classify_np), from which the pick
     # among several preempt-capable slots is made once the reclaim
     # oracle has answered (CycleSolver.pick_preempt_slots)
-    preempt_slots: np.ndarray = None       # [W, S] bool
+    preempt_slots: np.ndarray = None       # [W, G, S] bool
     slot_res_fit: np.ndarray = None        # [W, S, R] bool
-    slot_borrows: np.ndarray = None        # [W, S] bool
+    slot_borrows: np.ndarray = None        # [W, G, S] bool
     oracle_ask: np.ndarray = None          # [W, S, R] bool
+    # one walk a resource group of the head's queue (classify_np): the
+    # slot each chose (-1: the head is NoFit), the resume
+    # state each records, and the groups whose pick is the oracle's
+    fit0: np.ndarray = None                # [W] bool: every group fits
+    slots0: np.ndarray = None              # [W, G] int32
+    tried: np.ndarray = None               # [W, G] int32
+    oracle_groups: np.ndarray = None       # [W, G] bool
     # heads the vectorized math can't classify: the scheduler runs the
     # host FlavorAssigner walk for these and attaches the assignment
     scalar_mask: np.ndarray = None         # [W] bool
@@ -180,6 +184,13 @@ class CycleSolver:
             "walk_stop_heads": 0,     # heads whose walk policy-stopped
             "walk_heads": 0,          # heads classified by the vector walk
             "walk_slots": 0,          # flavors their walks visited
+            # one walk a resource group (ops/cycle.py walk_groups):
+            "group_walks": 0,         # (head, group) walks the vector
+                                      # classify did
+            "split_mode_heads": 0,    # heads whose groups ended in
+                                      # different modes: the join, not
+                                      # one walk, set the head's mode
+            "cq_shape_heads": 0,      # scalar_reasons["cq_shape"], flat
             # per-workload flavor eligibility (ops/eligibility.py):
             "walk_ineligible_slots": 0,   # of them, skipped for a taint
                                           # or a selector
@@ -470,6 +481,7 @@ class CycleSolver:
             if ci < 0 or not cq_ok[ci]:
                 mask[wi] = True
                 reasons["cq_shape"] = reasons.get("cq_shape", 0) + 1
+                self.stats["cq_shape_heads"] += 1
                 continue
             if len(h.obj.pod_sets) != 1:
                 # the host can split flavors across pod sets and accounts
@@ -485,25 +497,28 @@ class CycleSolver:
 
     def _start_slots(self, snapshot: Snapshot, heads: list[Info],
                      st: PackedStructure) -> np.ndarray:
-        """Per-head flavor-walk start slot from the fungibility resume
-        state (flavorassigner.go:359-366): a head whose last attempt
-        stopped mid-list resumes at last_tried_flavor_idx + 1, unless the
-        CQ's quota changed since (allocatable_generation moved on)."""
-        start = np.zeros(len(heads), dtype=np.int32)
+        """Per-head, per-group flavor-walk start slot from the
+        fungibility resume state (flavorassigner.go:359-366): a head
+        whose last attempt stopped mid-list in a group resumes that
+        group at last_tried_flavor_idx + 1, unless the CQ's quota
+        changed since (allocatable_generation moved on)."""
+        G = st.n_groups
+        start = np.zeros((len(heads), G), dtype=np.int32)
         for wi, h in enumerate(heads):
-            s = resume_start(h, snapshot.cq(h.cluster_queue),
-                             h.cluster_queue in st.cq_covers_pods)
-            if s:
+            s = resume_starts(h, snapshot.cq(h.cluster_queue),
+                              h.cluster_queue in st.cq_covers_pods, G)
+            if any(s):
                 start[wi] = s
                 self.stats["resume_heads"] += 1
         return start
 
     def _eligible_slots(self, heads: list[Info], st: PackedStructure,
                         W: int) -> np.ndarray:
-        """The cycle's [W, S] eligibility plane: False where a head's
-        PodSet may not take the flavor (ops/eligibility.py).  Rows of
-        pads and of queues the vector walk does not decide are True."""
-        skip = np.zeros(W, dtype=np.int32)
+        """The cycle's [W, G, S] eligibility plane: False where a head's
+        PodSet may not take the flavor of the group (ops/eligibility.py).
+        Rows of pads and of queues the vector walk does not decide are
+        True."""
+        skip = np.zeros((W, st.n_groups), dtype=np.int32)
         if st.flavors_declared:
             cq_index = st.cq_index
             for wi, h in enumerate(heads):
@@ -545,7 +560,7 @@ class CycleSolver:
                 st.borrow_cap, st.has_borrow_limit, st.parent, st.depth)
 
         W = packed.wl_cq.shape[0]
-        start_pad = np.zeros(W, dtype=np.int32)
+        start_pad = np.zeros((W,) + start.shape[1:], dtype=np.int32)
         start_pad[:len(heads)] = start
         with _span("cycle.nominate.classify.eligibility"):
             eligible = self._eligible_slots(heads, st, W)
@@ -556,7 +571,7 @@ class CycleSolver:
         # decision-identical to a plain head; otherwise the host runs the
         # PodSetReducer binary search (podset_reducer.go) — scalar walk
         for wi in range(n):
-            if scalar[wi] or out["fit_slot0"][wi] >= 0:
+            if scalar[wi] or out["fit0"][wi]:
                 continue
             if any(ps.min_count is not None and ps.min_count < ps.count
                    for ps in heads[wi].obj.pod_sets):
@@ -567,10 +582,11 @@ class CycleSolver:
             sm = np.zeros(W, dtype=bool)
             sm[:n] = scalar
             out = dict(out)
-            out["fit_slot0"] = np.where(sm, -1, out["fit_slot0"]).astype(np.int32)
+            out["fit0"] = out["fit0"] & ~sm
+            out["slots0"] = np.where(sm[:, None], -1, out["slots0"])
+            out["oracle_groups"] = out["oracle_groups"] & ~sm[:, None]
             out["borrows0"] = out["borrows0"] & ~sm
             out["preempt0"] = out["preempt0"] & ~sm
-            out["preempt_slot0"] = np.where(sm, -1, out["preempt_slot0"]).astype(np.int32)
             out["preempt_borrows0"] = out["preempt_borrows0"] & ~sm
             out["preempt_stopped0"] = out["preempt_stopped0"] & ~sm
             self.stats["scalar_heads"] += int(scalar.sum())
@@ -582,21 +598,26 @@ class CycleSolver:
         self.stats["walk_slots"] += int(out["walk_slots"][:n][~scalar].sum())
         self.stats["walk_ineligible_slots"] += int(
             out["walk_ineligible"][:n][~scalar].sum())
+        self.stats["group_walks"] += int(
+            out["group_walks"][:n][~scalar].sum())
+        self.stats["split_mode_heads"] += int(np.count_nonzero(
+            out["split_mode"][:n] & ~scalar))
         self.stats["constrained_heads"] += int(np.count_nonzero(
             (st.slot_valid[np.maximum(packed.wl_cq[:n], 0)]
-             & ~eligible[:n]).any(axis=1) & ~scalar))
+             & ~eligible[:n]).any(axis=(1, 2)) & ~scalar))
         return ClassifiedCycle(
             packed=packed, heads=heads, snapshot=snapshot,
-            fit_slot0=out["fit_slot0"], borrows0=out["borrows0"],
-            preempt0=out["preempt0"], preempt_slot0=out["preempt_slot0"],
+            borrows0=out["borrows0"],
+            preempt0=out["preempt0"],
             preempt_borrows0=out["preempt_borrows0"],
             preempt_res_fit=out["preempt_res_fit"],
-            preempt_slot_count=out["preempt_slot_count"],
             preempt_stopped0=out["preempt_stopped0"],
             preempt_slots=out.get("preempt_slots"),
             slot_res_fit=out.get("slot_res_fit"),
             slot_borrows=out.get("slot_borrows"),
             oracle_ask=out.get("oracle_ask"),
+            fit0=out["fit0"], slots0=out["slots0"], tried=out["tried"],
+            oracle_groups=out["oracle_groups"],
             scalar_mask=sm, host_assignments={}, host_pairs={})
 
     # -- the reclaim oracle's part of the walk --------------------------
@@ -605,32 +626,50 @@ class CycleSolver:
         """What the host walk would ask the reclaim oracle for head
         ``wi``: one (slot, resource index, FlavorResource, quantity) a
         resource short of quota on each attempted preempt-capable slot
-        (flavorassigner.go:692)."""
+        of each group whose pick is the oracle's (flavorassigner.go:692);
+        the slot is one of the resource's own group."""
         st = cls.packed.structure
         h = cls.heads[wi]
         cq = cls.snapshot.cq(h.cluster_queue)
-        flavors = cq.spec.resource_groups[0].flavors
+        groups = cq.spec.resource_groups
+        grp = st.res_group[st.cq_index[h.cluster_queue]]
         psr = h.total_requests[0]
         out = []
         for s, ri in zip(*np.nonzero(cls.oracle_ask[wi])):
+            g = int(grp[ri])
+            if not cls.oracle_groups[wi, g]:
+                continue
             res = st.resource_names[ri]
             qty = psr.count if res == "pods" else psr.requests[res]
             out.append((int(s), int(ri),
-                        FlavorResource(flavors[s].name, res), qty))
+                        FlavorResource(groups[g].flavors[s].name, res), qty))
         return out
 
     def pick_preempt_slots(self, cls: ClassifiedCycle, heads: np.ndarray,
                            reclaim: np.ndarray) -> None:
-        """Fix the preempt slot of ``heads`` (walks that met several
-        preempt-capable slots and no stop) from the oracle's answers
-        ``reclaim`` [len(heads), S, R]: the first slot of the best
-        granular mode, and with it the slot's borrow and per-resource
-        facts that the target search and the admit scan read."""
-        slot = pick_preempt_slot_np(cls.preempt_slots[heads],
-                                    cls.slot_res_fit[heads], reclaim)
-        cls.preempt_slot0[heads] = slot
-        cls.preempt_borrows0[heads] = cls.slot_borrows[heads, slot]
-        cls.preempt_res_fit[heads] = cls.slot_res_fit[heads, slot]
+        """Fix, for ``heads``, the preempt slot of each group whose walk
+        met several preempt-capable slots and no stop
+        (``oracle_groups``), from the oracle's answers ``reclaim``
+        [len(heads), S, R]: the first slot of the group's best granular
+        mode, and with it the head's borrow and per-resource facts that
+        the target search and the admit scan read."""
+        st = cls.packed.structure
+        grp = st.res_group[np.maximum(cls.packed.wl_cq[heads], 0)]
+        for g in range(st.n_groups):
+            on = cls.oracle_groups[heads, g]
+            if not on.any():
+                continue
+            slot = pick_preempt_slot_np(
+                cls.preempt_slots[heads, g], cls.slot_res_fit[heads],
+                reclaim, grp == g)
+            cls.slots0[heads[on], g] = slot[on]
+        slots = cls.slots0[heads]                           # [n, G]
+        cls.preempt_borrows0[heads] = np.take_along_axis(
+            cls.slot_borrows[heads], np.maximum(slots, 0)[:, :, None],
+            axis=2).any(axis=(1, 2))
+        cls.preempt_res_fit[heads] = np.take_along_axis(
+            cls.slot_res_fit[heads], res_slots(grp, slots)[:, None, :],
+            axis=1)[:, 0, :]
 
     # -- scalar-head decisions -----------------------------------------
 
@@ -681,15 +720,14 @@ class CycleSolver:
         W = packed.wl_cq.shape[0]
         R = len(st.resource_names)
 
-        # vector fit heads: pairs from the chosen slot (batched)
-        dec_fr, dec_amt, fit_mask = decision_pairs_from_slots(
-            st.slot_fr, packed.wl_cq, packed.wl_requests, cls.fit_slot0)
-        # vector reserve/preempt entries: pairs from the preempt slot
+        # vector heads: each resource's pair from its own group's slot
+        # (batched); fit heads', then reserve/preempt entries'
+        frs = slot_frs(st.slot_fr, st.res_group, packed.wl_cq, cls.slots0)
+        fit_mask = cls.fit0.copy()
+        dec_fr, dec_amt = decision_pairs(frs, packed.wl_requests, fit_mask)
         pre_on = rmask | pmask
-        pslot = np.where(pre_on & (cls.preempt_slot0 >= 0),
-                         cls.preempt_slot0, -1).astype(np.int32)
-        res_fr, res_amt, _ = decision_pairs_from_slots(
-            st.slot_fr, packed.wl_cq, packed.wl_requests, pslot)
+        res_fr, res_amt = decision_pairs(
+            frs, packed.wl_requests, pre_on & cls.preempt0)
         res_borrows = cls.preempt_borrows0 & pre_on
         borrows = cls.borrows0.copy()
         borrows |= res_borrows
@@ -886,8 +924,10 @@ class CycleSolver:
         W = packed.wl_cq.shape[0]
         F = packed.usage0.shape[1]
         n = cls.n
-        dec_fr, dec_amt, fit_mask = decision_pairs_from_slots(
-            st.slot_fr, packed.wl_cq, packed.wl_requests, cls.fit_slot0)
+        fit_mask = cls.fit0
+        dec_fr, dec_amt = decision_pairs(
+            slot_frs(st.slot_fr, st.res_group, packed.wl_cq, cls.slots0),
+            packed.wl_requests, fit_mask)
         u_e = np.zeros((W, F), dtype=np.int32)
         rows, cols = np.nonzero(dec_fr >= 0)
         np.add.at(u_e, (rows, dec_fr[rows, cols]), dec_amt[rows, cols])
@@ -999,17 +1039,16 @@ class CycleSolver:
                              wi) -> Assignment:
         """Host Assignment for a device-classified Fit head, including the
         fungibility resume state the host walk would record."""
-        slot = int(cls.fit_slot0[wi])
-        borrow = bool(cls.borrows0[wi])
-        return self._build_assignment(cls, wi, slot, Mode.FIT, borrow)
+        return self._build_assignment(cls, wi, Mode.FIT,
+                                      bool(cls.borrows0[wi]))
 
-    def _build_assignment(self, cls: ClassifiedCycle, wi: int, slot: int,
+    def _build_assignment(self, cls: ClassifiedCycle, wi: int,
                           mode: Mode, borrow: bool,
                           res_modes: Optional[dict] = None) -> Assignment:
         h = cls.heads[wi]
         cq = cls.snapshot.cq(h.cluster_queue)
-        return build_slot_assignment(h, cq, slot, mode, borrow,
-                                     res_modes=res_modes)
+        return build_slot_assignment(h, cq, cls.slots0[wi], cls.tried[wi],
+                                     mode, borrow, res_modes=res_modes)
 
     def build_preempt_assignment(self, cls: ClassifiedCycle,
                                  wi: int) -> Assignment:
@@ -1017,13 +1056,12 @@ class CycleSolver:
         modes (resources fitting on the preempt slot are FIT, the
         shortfall resources PREEMPT — flavorassigner.go:692), as the
         preemptor's target search expects (preemption.go:466)."""
-        slot = int(cls.preempt_slot0[wi])
         borrow = bool(cls.preempt_borrows0[wi])
         st = cls.packed.structure
         res_modes = {res: (Mode.FIT if cls.preempt_res_fit[wi][ri]
                            else Mode.PREEMPT)
                      for res, ri in st.r_index.items()}
-        return self._build_assignment(cls, wi, slot, Mode.PREEMPT, borrow,
+        return self._build_assignment(cls, wi, Mode.PREEMPT, borrow,
                                       res_modes=res_modes)
 
     def reserve_details(self, cls: ClassifiedCycle, wi: int
@@ -1066,36 +1104,25 @@ class CycleSolver:
         self.stats["classify_cycles"] += 1
         out: dict[str, Assignment] = {}
         for wi in range(cls.n):
-            if cls.fit_slot0[wi] >= 0:
+            if cls.fit0[wi]:
                 out[cls.heads[wi].key] = self.build_fit_assignment(cls, wi)
         return out
 
 
-def build_slot_assignment(info: Info, cq, slot: int, mode: Mode,
+def build_slot_assignment(info: Info, cq, slots, tried, mode: Mode,
                           borrow: bool,
                           res_modes: Optional[dict] = None) -> Assignment:
     """Reconstruct the host Assignment a device-classified head would get
-    from the flavor walk: single resource group, slot = flavor index,
-    including the fungibility resume state (flavorassigner.go:499).
-    ``cq`` is any CQState (snapshot or live cache) carrying .spec and
-    .allocatable_generation."""
-    slot = int(slot)
-    rg = cq.spec.resource_groups[0]
-    covers_pods = "pods" in rg.covered_resources
-    flavor_name = rg.flavors[slot].name
-    n_slots = len(rg.flavors)
-    # the host records attempted_idx = the slot the walk STOPPED on, or
-    # the last slot when it scanned to the end and kept the best
-    # (flavorassigner.go:386-390 + shouldTryNextFlavor); tried = -1 when
-    # the whole list was attempted
-    ff = cq.spec.flavor_fungibility
-    wcb = ff.when_can_borrow == FlavorFungibilityPolicy.BORROW
-    wcp = ff.when_can_preempt == FlavorFungibilityPolicy.PREEMPT
-    stopped = ((not borrow or wcb)
-               and (mode == Mode.FIT
-                    or (mode == Mode.PREEMPT and wcp)))
-    attempted = slot if stopped else n_slots - 1
-    tried = -1 if attempted == n_slots - 1 else attempted
+    from the flavor walks: ``slots[g]`` = the flavor index group g's walk
+    chose, ``tried[g]`` the resume state it recorded (the slot the walk
+    STOPPED on mid-list, -1 when the whole list was attempted —
+    flavorassigner.go:386-390 + shouldTryNextFlavor), written a resource
+    as ``FlavorAssigner._append`` writes it.  ``cq`` is any CQState
+    (snapshot or live cache) carrying .spec and .allocatable_generation."""
+    groups = cq.spec.resource_groups
+    covers_pods = any("pods" in rg.covered_resources for rg in groups)
+    group_of = {res: g for g, rg in enumerate(groups)
+                for res in rg.covered_resources}
 
     assignment = Assignment()
     assignment.borrowing = borrow
@@ -1113,12 +1140,14 @@ def build_slot_assignment(info: Info, cq, slot: int, mode: Mode,
             name=psr.name, requests=Requests(reqs), count=psr.count)
         flavor_idx: dict[str, int] = {}
         for res in reqs:
+            g = group_of[res]
+            flavor_name = groups[g].flavors[int(slots[g])].name
             res_mode = mode if res_modes is None else res_modes.get(
                 res, mode)
             ps_res.flavors[res] = FlavorAssignmentDecision(
                 name=flavor_name, mode=res_mode, borrow=borrow,
-                tried_flavor_idx=tried)
-            flavor_idx[res] = tried
+                tried_flavor_idx=int(tried[g]))
+            flavor_idx[res] = int(tried[g])
             fr = FlavorResource(flavor_name, res)
             assignment.usage[fr] = (assignment.usage.get(fr, 0)
                                     + reqs[res])
@@ -1127,27 +1156,33 @@ def build_slot_assignment(info: Info, cq, slot: int, mode: Mode,
     return assignment
 
 
-def resume_start(info: Info, cq, covers_pods: bool) -> int:
-    """Flavor-walk start slot for a head with fungibility resume state.
+def resume_starts(info: Info, cq, covers_pods: bool, n_groups: int) -> tuple:
+    """Flavor-walk start slot a resource group for a head with
+    fungibility resume state, padded with 0 to ``n_groups``.
 
-    Mirrors the host's entry into the walk (flavorassigner.go:359-366 via
-    next_flavor_to_try of the first resource in sorted request order): 0
-    when there is no usable resume state, last_tried + 1 otherwise.  The
-    state is void when the CQ's quota changed since it was recorded
-    (assign() clears it on allocatable_generation advance)."""
+    Mirrors the host's entry into each group's walk
+    (flavorassigner.go:359-366 via next_flavor_to_try of the group's
+    first resource in sorted request order): 0 when there is no usable
+    resume state, last_tried + 1 otherwise.  The state is void when the
+    CQ's quota changed since it was recorded (assign() clears it on
+    allocatable_generation advance)."""
+    none = (0,) * n_groups
     last = info.last_assignment
     if last is None or cq is None:
-        return 0
+        return none
     if cq.allocatable_generation > last.cluster_queue_generation:
-        return 0
+        return none
     if not info.total_requests:
-        return 0
+        return none
     psr = info.total_requests[0]
     reqs = set(psr.requests)
     if covers_pods:
         reqs.add("pods")
     else:
         reqs.discard("pods")
-    if not reqs:
-        return 0
-    return max(0, int(last.next_flavor_to_try(0, sorted(reqs)[0])))
+    out = list(none)
+    for g, rg in enumerate(cq.spec.resource_groups[:n_groups]):
+        mine = reqs.intersection(rg.covered_resources)
+        if mine:
+            out[g] = max(0, int(last.next_flavor_to_try(0, min(mine))))
+    return tuple(out)

@@ -237,7 +237,8 @@ class _Order:
         return np.nonzero(todo)[0]
 
 
-# row-plane layout: name -> (pad value, dtype, extra axis: None | "R" | "F")
+# row-plane layout: name -> (pad value, dtype, extra axis: None | "R" | "F"
+# | "G", the structure's resource groups a queue)
 _ROW_PLANES = {
     "wl_req": (0, np.int32, "R"),
     "wl_rank": (_b.INF_I32, np.int32, None),
@@ -245,10 +246,10 @@ _ROW_PLANES = {
     "wl_prio": (0, np.int32, None),
     "wl_uidrank": (0, np.int32, None),
     "vec_ok": (False, bool, None),
-    "wl_flavor_skip": (0, np.uint8, None),
+    "wl_flavor_skip": (0, np.uint8, "G"),
     "elig0": (False, bool, None),
     "parked0": (False, bool, None),
-    "resume0": (0, np.int32, None),
+    "resume0": (0, np.int32, "G"),
     "adm0": (False, bool, None),
     "adm_seq0": (0, np.int32, None),
     "adm_usage0": (0, np.int32, "F"),
@@ -280,15 +281,16 @@ class StreamState:
         self.token = next(StreamState._next_token)
 
 
-def _views(arena: PlaneArena, C: int, M: int, R: int, F: int,
-           skip_width: int) -> dict:
+def _views(arena: PlaneArena, C: int, M: int, st) -> dict:
+    extent = {"R": len(st.resource_names), "F": st.n_frs,
+              "G": st.n_groups}
+    F = extent["F"]
     out = {}
     for name, (pad, dt, extra) in _ROW_PLANES.items():
-        shape = (C, M) if extra is None else \
-            (C, M, R) if extra == "R" else (C, M, F)
+        shape = (C, M) if extra is None else (C, M, extent[extra])
         if name == "wl_flavor_skip":
             # every flavor plain: one column of zeros stands for the grid
-            shape = (C, skip_width)
+            shape = (C, mask_plane_width(st, M), extent["G"])
         out[name] = arena.ensure(name, shape, dt, pad)
     out["u_cq0"] = arena.ensure("u_cq0", (C, F), np.int32, 0, grow_axes=1)
     out["keys_grid"] = arena.ensure("keys_grid", (C, M), object, None)
@@ -402,7 +404,7 @@ def _row_patch_job(state, st, queues, cache, scheduler, ci, key):
     ``(ci, idx, parked, resume, ok)`` patch, or ``_ESCALATE`` when the
     change is beyond row grade (membership, identity, admission)."""
     from ..api.types import AdmissionCheckState
-    from .solver import resume_start
+    from .solver import resume_starts
     rec = state.records[ci]
     idx = rec.find(key)
     if idx is None:
@@ -452,7 +454,8 @@ def _row_patch_job(state, st, queues, cache, scheduler, ci, key):
                 ok = False
         if ok == bool(rec.ok[idx]):
             return None
-        return (ci, idx, bool(rec.parked[idx]), int(rec.resume[idx]), ok)
+        return (ci, idx, bool(rec.parked[idx]),
+                tuple(rec.resume[idx].tolist()), ok)
     if q is None or not q.active:
         return _ESCALATE
     parked_now = False
@@ -483,9 +486,10 @@ def _row_patch_job(state, st, queues, cache, scheduler, ci, key):
                             AdmissionCheckState.REJECTED)
                 for s in obj.admission_check_states.values()):
             ok = False
-    resume_now = resume_start(info, cq_live, covers_pods)
+    resume_now = resume_starts(info, cq_live, covers_pods,
+                               rec.resume.shape[1])
     if (parked_now == bool(rec.parked[idx])
-            and resume_now == int(rec.resume[idx])
+            and resume_now == tuple(rec.resume[idx].tolist())
             and ok == bool(rec.ok[idx])):
         return None
     return (ci, idx, parked_now, resume_now, ok)
@@ -554,7 +558,7 @@ def _materialize(st, state, s, views, scheduler, dirty_cis, prev_token,
         has_blim=st.has_borrow_limit, parent=st.parent,
         node_level=s.node_level, nominal_cq=st.nominal_cq,
         npb_cq=st.nominal_plus_blimit_cq, slot_fr=st.slot_fr,
-        slot_valid=st.slot_valid,
+        slot_valid=st.slot_valid, res_group=st.res_group,
         cq_can_preempt_borrow=st.cq_can_preempt_borrow,
         cq_wcb_borrow=st.cq_wcb_borrow,
         cq_wcp_preempt=st.cq_wcp_preempt,
@@ -661,7 +665,7 @@ def _init_full(st, queues, cache, scheduler, key, min_m, window, arena,
     with _span("burst.pack.grid"):
         C = len(st.cq_names)
         R = len(st.resource_names)
-        F = max(1, len(st.fr_index))
+        F = st.n_frs
         s = _b._pack_statics(st, cache)
 
         state = StreamState(key, arena)
@@ -710,7 +714,7 @@ def _init_full(st, queues, cache, scheduler, key, min_m, window, arena,
 
         rows_per_cq = int(state.n_rows_cq.max(initial=0))
         state.M = M = max(_bucket(rows_per_cq, minimum=4), min_m)
-        views = _views(arena, C, M, R, F, mask_plane_width(st, M))
+        views = _views(arena, C, M, st)
         _reset_views(views)
 
         # per-CQ heap rank: the reference ci-segmented lexsort
@@ -943,8 +947,7 @@ def _patch_grid(st, state, statics, arena, placed, pos_dirty_cis, min_m):
         state.maxabs_prio_cq[ci] = int(np.abs(rec.prio).max(initial=0))
     rows_per_cq = int(state.n_rows_cq.max(initial=0))
     state.M = M = max(_bucket(rows_per_cq, minimum=4), min_m)
-    views = _views(arena, C, M, len(st.resource_names),
-                   max(1, len(st.fr_index)), mask_plane_width(st, M))
+    views = _views(arena, C, M, st)
     slabs = _row_slabs(views)
     row_of = state.row_of_key
 

@@ -232,7 +232,7 @@ _C_FILLS = {
     "adm_seq0": 0, "adm_usage0": 0, "adm_uses0": False,
     "death0": _I32_MAX, "u_cq0": 0,
     "nominal_cq": 0, "npb_cq": 0, "slot_fr": -1, "slot_valid": False,
-    "cq_can_preempt_borrow": False, "strict_cq": False,
+    "res_group": -1, "cq_can_preempt_borrow": False, "strict_cq": False,
     "cq_wcb_borrow": True, "cq_wcp_preempt": False,
     "wcq_lower": False, "rwc_enabled": False, "rwc_only_lower": False,
     "preempt_ok": False, "self_lmem": 0,
@@ -260,7 +260,7 @@ _STATE_NAMES = ("elig0", "parked0", "resume0", "adm0", "adm_seq0",
 #   (cycle/uid), the reservation-seq plane, and the modeling envelope
 #   (preempt_ok depends on global scalars).  Always re-uploaded; all
 #   are small relative to the row tier.
-_ROW_STATIC = ("nominal_cq", "npb_cq", "slot_fr", "slot_valid",
+_ROW_STATIC = ("nominal_cq", "npb_cq", "slot_fr", "slot_valid", "res_group",
                "cq_can_preempt_borrow", "cq_wcb_borrow",
                "cq_wcp_preempt", "wcq_lower", "rwc_enabled",
                "rwc_only_lower", "self_lmem")
@@ -507,8 +507,8 @@ def sharded_burst_fn(mesh: Mesh, *, K: int, depth: int, L: int, S: int,
     row = P("cq")
     rep = P()
     kc = P(None, "cq")
-    in_specs = (row,) * 15 + (rep,) + (row,) * 25 + (kc, kc)
-    out_specs = (kc, kc, kc, kc, kc, rep, rep, (row,) * 9)
+    in_specs = (row,) * 15 + (rep,) + (row,) * 26 + (kc, kc)
+    out_specs = (kc, kc, kc, kc, kc, kc, rep, rep, (row,) * 9)
     body = _partial(_burst_cycles, K=K, depth=depth, L=L, S=S, KC=KC,
                     n_levels=n_levels, G=G, runtime=runtime,
                     axis_name="cq")

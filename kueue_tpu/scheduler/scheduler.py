@@ -382,7 +382,7 @@ class Scheduler:
             # otherwise device classification still replaces the
             # per-head flavor walk and the host tournament decides
             n = cls.n
-            if not (cls.fit_slot0[:n] >= 0).any():
+            if not cls.fit0[:n].any():
                 # nothing can admit: fs_admit_scan's can_admit requires
                 # a fit slot, and the dispatch gate below already
                 # excludes preempt-capable heads — the tournament would
@@ -479,13 +479,12 @@ class Scheduler:
                     break
         if full_ok:
             pre = np.nonzero(cls.preempt0[:n])[0]
-            # A policy-stopped preempt choice is final, and so is the
-            # only preempt-capable slot; with several, the walk's
-            # best-mode pick is the reclaim oracle's
+            # A group's policy-stopped preempt choice is final, and so
+            # is its only preempt-capable slot; with several, the
+            # group's best-mode pick is the reclaim oracle's
             # (flavorassigner.go:692 RECLAIM beats PREEMPT)
-            self._pick_by_oracle(cls, pre[
-                ~cls.preempt_stopped0[pre]
-                & (cls.preempt_slot_count[pre] > 1)], snapshot)
+            self._pick_by_oracle(
+                cls, pre[cls.oracle_groups[pre].any(axis=1)], snapshot)
             # in the walk's span, where the loop stood before the oracle
             # came between: the span's total by name is read, and
             # ``cycle.nominate.self`` is what no child covers
@@ -528,11 +527,12 @@ class Scheduler:
                 walked)
 
     def _pick_by_oracle(self, cls, heads, snapshot: Snapshot) -> None:
-        """The preempt slot of the heads whose walk met several
+        """The preempt slot of the heads' groups whose walk met several
         preempt-capable flavors and no stop: every question the host
-        walk would put to the reclaim oracle, answered in the cycle's
-        batched search (one launch ahead of the heads' own), and the
-        Reclaim / Preempt lattice applied to the answers."""
+        walk would put to the reclaim oracle in those groups, answered
+        in the cycle's batched search (one launch ahead of the heads'
+        own), and the Reclaim / Preempt lattice applied to the answers
+        a group."""
         if not len(heads):
             return
         import numpy as np
@@ -560,7 +560,7 @@ class Scheduler:
             if wi in walked:
                 continue  # the host walk already ran for this head
             e.inadmissible_msg = ""
-            if not cls.scalar_mask[wi] and cls.fit_slot0[wi] >= 0:
+            if not cls.scalar_mask[wi] and cls.fit0[wi]:
                 e.assignment = solver.build_fit_assignment(cls, wi)
                 e.info.last_assignment = e.assignment.last_state
             else:
@@ -588,7 +588,7 @@ class Scheduler:
                 # scalar head: the host walk already produced the
                 # assignment, message, resume state, and targets
                 continue
-            if cls.fit_slot0[wi] >= 0:
+            if cls.fit0[wi]:
                 e.assignment = solver.build_fit_assignment(cls, wi)
                 e.info.last_assignment = e.assignment.last_state
                 e.inadmissible_msg = ""
@@ -672,8 +672,9 @@ class Scheduler:
                           modeled: dict) -> Optional[CycleStats]:
         """Apply one fused-burst cycle's decisions to the real state.
 
-        ``modeled``: {workload key: (kind, slot, borrows, targets)} from
-        the burst kernel, where kind ∈ "admit"|"skip"|"park"|"preempt"|
+        ``modeled``: {workload key: (kind, slots, tried, borrows,
+        targets)} from the burst kernel (``slots`` and ``tried`` one a
+        resource group of the head's queue), where kind ∈ "admit"|"skip"|"park"|"preempt"|
         "reserve"|"overlap_skip"|"pre_nofit" and ``targets`` (preempt
         only) is [(target key, target cq name), ...].  The caller has
         already validated that ``heads`` matches the modeled head set
@@ -696,7 +697,7 @@ class Scheduler:
         # pre-resolve every modeled eviction target BEFORE mutating
         # anything: a missing target means the modeled admitted set is
         # stale, which taints the whole cycle, not just one eviction
-        for _kind, _slot, _borrows, _targets in modeled.values():
+        for _kind, _slots, _tried, _borrows, _targets in modeled.values():
             if _kind == "preempt":
                 for tkey, tcq_name in _targets:
                     if self._live_admitted_info(tcq_name, tkey) is None:
@@ -709,11 +710,11 @@ class Scheduler:
                 f"{info.obj.namespace}/{info.obj.queue_name}")
             info.cluster_queue = lq.cluster_queue if lq else ""
             e = Entry(info=info)
-            kind, slot, borrows, targets = modeled[info.key]
+            kind, slot, tried, borrows, targets = modeled[info.key]
             cq = self.cache.cluster_queue(info.cluster_queue)
             if kind == "admit":
                 e.assignment = build_slot_assignment(
-                    info, cq, slot, Mode.FIT, borrows)
+                    info, cq, slot, tried, Mode.FIT, borrows)
                 e.info.last_assignment = e.assignment.last_state
                 e.status = EntryStatus.NOMINATED
                 if self._admit(e, cq):
@@ -729,7 +730,7 @@ class Scheduler:
                 continue
             if kind == "skip":
                 e.assignment = build_slot_assignment(
-                    info, cq, slot, Mode.FIT, borrows)
+                    info, cq, slot, tried, Mode.FIT, borrows)
                 e.info.last_assignment = e.assignment.last_state
                 self._set_skipped(e, "Workload no longer fits after "
                                      "processing another workload")
@@ -739,7 +740,7 @@ class Scheduler:
                 # (scheduler.go:176-284 preempt branch; targets were
                 # selected by the kernel's greedy+fillback search)
                 e.assignment = build_slot_assignment(
-                    info, cq, slot, Mode.PREEMPT, borrows)
+                    info, cq, slot, tried, Mode.PREEMPT, borrows)
                 e.inadmissible_msg = e.assignment.message()
                 e.info.last_assignment = None
                 tgt_objs = []
@@ -768,13 +769,13 @@ class Scheduler:
                 # preempt-classified, no targets: capacity was reserved
                 # in-kernel; the entry requeues not-nominated
                 e.assignment = build_slot_assignment(
-                    info, cq, slot, Mode.PREEMPT, borrows)
+                    info, cq, slot, tried, Mode.PREEMPT, borrows)
                 e.info.last_assignment = e.assignment.last_state
                 e.inadmissible_msg = e.assignment.message()
                 stats.inadmissible.append(info.key)
             elif kind == "overlap_skip":
                 e.assignment = build_slot_assignment(
-                    info, cq, slot, Mode.PREEMPT, borrows)
+                    info, cq, slot, tried, Mode.PREEMPT, borrows)
                 e.info.last_assignment = e.assignment.last_state
                 self._set_skipped(e, "Workload has overlapping "
                                      "preemption targets with another "
@@ -784,7 +785,7 @@ class Scheduler:
                 stats.skipped.append(info.key)
             elif kind == "pre_nofit":
                 e.assignment = build_slot_assignment(
-                    info, cq, slot, Mode.PREEMPT, borrows)
+                    info, cq, slot, tried, Mode.PREEMPT, borrows)
                 e.info.last_assignment = e.assignment.last_state
                 self._set_skipped(e, "Workload no longer fits after "
                                      "processing another workload")

@@ -223,7 +223,7 @@ def warm_burst(d, clock, n_cqs: int, runtime: int, shards: int = 0):
     if shards > 1:
         bs.set_shards(shards)
     if plan is not None:
-        F = max(1, len(st.fr_index))
+        F = st.n_frs
         for K in K_BURST_LADDER:
             extr = np.zeros((K, plan.C, F), np.int32)
             extu = np.zeros((K, plan.G), bool)

@@ -42,7 +42,7 @@ def synth(W=100_000, C=1_000, S=4, R=3, cohorts=64, seed=0):
     for s in range(S):
         for r in range(R):
             slot_fr[:, s, r] = s * R + r
-    slot_valid = np.ones((C, S), dtype=bool)
+    slot_valid = np.ones((C, 1, S), dtype=bool)
     can_preempt = np.zeros(C, dtype=bool)
     wl_cq = rng.integers(0, C, W).astype(np.int32)
     wl_requests = rng.integers(1, 16, (W, R)).astype(np.int32) * 500

@@ -260,7 +260,7 @@ def test_vanished_preempt_target_aborts_cycle_unmutated():
     clock.t += 1.0
     heads = d.queues.heads_nonblocking()
     assert heads
-    modeled = {heads[0].key: ("preempt", 0, False,
+    modeled = {heads[0].key: ("preempt", (0,), (-1,), False,
                               [("default/ghost", "cq-0-0")])}
     cycle_before = d.scheduler.scheduling_cycle
     assert d.scheduler.apply_burst_cycle(heads, modeled) is None

@@ -496,11 +496,11 @@ def test_fs_lowest_share_first(use_device):
     heap, parked = queue_state(d, "eng-beta")
     assert "eng-beta/older-new" in heap | parked
     if use_device:
-        # eng-beta is a 2-resource-group CQ: its head is legitimately
-        # scalar, so this FS cycle runs the host tournament with device
-        # classification (the FULL-mode assertion lives in the
-        # hierarchical-tournament case, whose CQs are all vector-ok)
-        assert d.scheduler.solver.stats["classify_cycles"] > 0
+        # eng-beta is a 2-resource-group CQ: since PR 37 its head is
+        # walked a group by the vector classify like any other, so this
+        # FS cycle is decided in the device tournament
+        stats = d.scheduler.solver.stats
+        assert stats["scalar_heads"] == 0 and stats["fs_full_cycles"] > 0
 
 
 # --- :1569 "hierarchical fair sharing ... wins tournament" ---------------

@@ -515,7 +515,7 @@ def _case_labelled_rows_that_came(d, clock, step):
         d.schedule_once()
         state = step(f"came {n}")
     skip = state.last_plan.arrays["wl_flavor_skip"]
-    at = {k.split("/")[1]: int(skip[c, m])
+    at = {k.split("/")[1]: int(skip[c, m, 0])
           for k, (c, m) in state.last_plan.row_of_key.items()}
     assert at["late"] == 0b1101 and at["more-1"] == 0b0000
     assert at["own-a-reserved"] == 0b1100

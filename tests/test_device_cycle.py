@@ -384,16 +384,18 @@ def test_contended_pack_admit_scan_matches_host_loop():
     import numpy as np
     import __graft_entry__ as ge
     from kueue_tpu.ops.cycle import (admit_scan, classify_np,
-                                     cycle_order_np,
-                                     decision_pairs_from_slots)
+                                     cycle_order_np, decision_pairs,
+                                     slot_frs)
 
     shape = dict(n_cohorts=4, cqs_per_cohort=4, n_workloads=64,
                  contended=True)
     _, _, _, packed = ge._packed_cycle(**shape)
     st = packed.structure
     out = classify_np(packed)
-    dec_fr, dec_amt, fit_mask = decision_pairs_from_slots(
-        st.slot_fr, packed.wl_cq, packed.wl_requests, out["fit_slot0"])
+    fit_mask = out["fit0"]
+    dec_fr, dec_amt = decision_pairs(
+        slot_frs(st.slot_fr, st.res_group, packed.wl_cq, out["slots0"]),
+        packed.wl_requests, fit_mask)
     W = packed.wl_cq.shape[0]
     res_fr = np.full_like(dec_fr, -1)
     res_amt = np.zeros_like(dec_amt)

@@ -317,7 +317,7 @@ def flavors_of(d, keys):
 def run(engine, build):
     """[(admitted, evicted, {admitted key: flavors}, {pending key: the
     slot its next walk starts on})] a cycle, and the driver."""
-    from kueue_tpu.ops.solver import resume_start
+    from kueue_tpu.ops.solver import resume_starts
     clock = FakeClock()
     d = Driver(clock=clock, use_device_solver=engine != "host")
     build(d)
@@ -329,8 +329,8 @@ def run(engine, build):
             cq = d.queues.queue_for(q)
             for info in list(cq.heap.items()) + list(
                     cq.inadmissible.values()):
-                resume[info.key] = resume_start(
-                    info, d.cache.cluster_queue(q), False)
+                resume[info.key] = resume_starts(
+                    info, d.cache.cluster_queue(q), False, 1)[0]
         out.append((sorted(stats.admitted), sorted(stats.preempted_targets),
                     flavors_of(d, stats.admitted), resume))
 
@@ -422,7 +422,7 @@ def test_walk_counters_read_the_masks():
     # the next cycle reads every mask off its Info
     for info in [i for q in heads for i in
                  d.queues.queue_for(q).inadmissible.values()]:
-        assert info._flavor_skip[2] in (0b1100, 0, 0b0011)
+        assert info._flavor_skip[2] in ((0b1100,), (0,), (0b0011,))
     d.queues.queue_inadmissible_workloads(set(heads))
     clock.t += 1.0
     d.schedule_once()
@@ -488,7 +488,7 @@ def test_classify_np_and_the_jitted_classify_agree_under_a_plane():
         assert np.array_equal(np.asarray(got[4]), want["fit_slot0"])
         assert np.array_equal(np.asarray(got[3]), want["preempt0"])
         # nothing the pick or the oracle reads names a barred slot
-        assert not (want["preempt_slots"] & ~eligible).any()
+        assert not (want["preempt_slots"][:, 0] & ~eligible).any()
         assert not (want["oracle_ask"] & ~eligible[:, :, None]).any()
         n = packed.wl_count
         assert (want["walk_ineligible"][:n] <= want["walk_slots"][:n]).all()
@@ -518,7 +518,7 @@ def test_both_packs_write_the_rows_masks():
         assert_plans_equal(plan, full, f"step {step}")
         skip = plan.arrays["wl_flavor_skip"]
         assert skip.dtype == np.uint8
-        at = {k.split("/")[1]: int(skip[c, m])
+        at = {k.split("/")[1]: int(skip[c, m, 0])
               for k, (c, m) in plan.row_of_key.items()}
         assert at["head"] == 0b0111 and at["late"] == 0b1101
         if "other" in at:

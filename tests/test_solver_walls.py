@@ -136,7 +136,13 @@ def test_multi_resource_group_cq():
                 pod_sets=[PodSet(name="main", count=1, requests=reqs)]))
         return out
 
-    assert_parity(build)
+    _, stats = assert_parity(build, expect_scalar=False)
+    # the wall PR 37 took down: every head is walked a group on the
+    # vector path, none on the host
+    assert stats["scalar_heads"] == 0, stats
+    assert "cq_shape" not in stats["scalar_reasons"], stats
+    assert stats["cq_shape_heads"] == 0, stats
+    assert stats["group_walks"] > stats["walk_heads"] > 0, stats
 
 
 # ---------------------------------------------------------------------------
@@ -231,48 +237,65 @@ def test_taints_tolerations_affinity():
     assert stats["eligibility_masks_built"] == 4, stats
 
 
-def test_two_resource_groups_with_declared_flavors_stay_scalar():
-    """The sibling: the same labelled, tainted flavors under a queue with
-    two resource groups.  That shape is still the host walk's, and is
-    counted as such."""
-    def build(d):
-        d.apply_resource_flavor(ResourceFlavor(
-            name="spot", node_labels={"tier": "spot"},
-            node_taints=[Taint(key="spot", value="true",
-                               effect="NoSchedule")]))
-        d.apply_resource_flavor(ResourceFlavor(
-            name="ondemand", node_labels={"tier": "ondemand"}))
-        d.apply_resource_flavor(ResourceFlavor(name="gpu-x"))
-        d.apply_cluster_queue(ClusterQueue(
-            name="cq",
-            resource_groups=[
-                ResourceGroup(covered_resources=["cpu"], flavors=[
-                    FlavorQuotas(name="spot", resources={
-                        "cpu": ResourceQuota(nominal=4000)}),
-                    FlavorQuotas(name="ondemand", resources={
-                        "cpu": ResourceQuota(nominal=2000)})]),
-                ResourceGroup(covered_resources=["gpu"], flavors=[
-                    FlavorQuotas(name="gpu-x", resources={
-                        "gpu": ResourceQuota(nominal=4)})])]))
-        d.apply_local_queue(LocalQueue(name="lq", cluster_queue="cq"))
-        rng = random.Random(19)
-        out = []
-        for i in range(12):
-            reqs = {"cpu": rng.choice([1000, 2000])}
-            if i % 2:
-                reqs["gpu"] = 1
-            out.append(Workload(
-                name=f"wl-{i}", queue_name="lq",
-                priority=rng.choice([10, 50]), creation_time=float(i + 1),
-                pod_sets=[PodSet(
-                    name="main", count=1, requests=reqs,
-                    tolerations=([Toleration(key="spot", operator="Exists")]
-                                 if i % 3 else []))]))
-        return out
+def _two_groups_declared(d, gpu_flavor):
+    d.apply_resource_flavor(ResourceFlavor(
+        name="spot", node_labels={"tier": "spot"},
+        node_taints=[Taint(key="spot", value="true",
+                           effect="NoSchedule")]))
+    d.apply_resource_flavor(ResourceFlavor(
+        name="ondemand", node_labels={"tier": "ondemand"}))
+    d.apply_resource_flavor(gpu_flavor)
+    d.apply_cluster_queue(ClusterQueue(
+        name="cq",
+        resource_groups=[
+            ResourceGroup(covered_resources=["cpu"], flavors=[
+                FlavorQuotas(name="spot", resources={
+                    "cpu": ResourceQuota(nominal=4000)}),
+                FlavorQuotas(name="ondemand", resources={
+                    "cpu": ResourceQuota(nominal=2000)})]),
+            ResourceGroup(covered_resources=["gpu"], flavors=[
+                FlavorQuotas(name="gpu-x", resources={
+                    "gpu": ResourceQuota(nominal=4)})])]))
+    d.apply_local_queue(LocalQueue(name="lq", cluster_queue="cq"))
+    rng = random.Random(19)
+    out = []
+    for i in range(12):
+        reqs = {"cpu": rng.choice([1000, 2000])}
+        if i % 2:
+            reqs["gpu"] = 1
+        out.append(Workload(
+            name=f"wl-{i}", queue_name="lq",
+            priority=rng.choice([10, 50]), creation_time=float(i + 1),
+            pod_sets=[PodSet(
+                name="main", count=1, requests=reqs,
+                tolerations=([Toleration(key="spot", operator="Exists")]
+                             if i % 3 else []))]))
+    return out
 
-    _, stats = assert_parity(build)
+
+def test_two_resource_groups_with_declared_flavors_walk_a_group():
+    """The sibling: the same labelled, tainted flavors under a queue with
+    two resource groups.  Until PR 37 that shape was the host walk's;
+    now each head is walked a group on the vector path, its mask matched
+    in the cpu group alone (the gpu group's flavor carries no label)."""
+    _, stats = assert_parity(
+        lambda d: _two_groups_declared(d, ResourceFlavor(name="gpu-x")),
+        expect_scalar=False)
+    assert stats["scalar_heads"] == 0, stats
+    assert "cq_shape" not in stats["scalar_reasons"], stats
+    assert stats["cq_shape_heads"] == 0, stats
+    assert stats["constrained_heads"] > 0, stats
+    assert stats["group_walks"] > stats["walk_heads"] > 0, stats
+
+
+def test_two_resource_groups_with_a_topology_flavor_stay_scalar():
+    """What is still refused: a flavor that binds a topology in one of
+    the two groups sends every head of the queue to the host walk, and
+    ``cq_shape`` counts them."""
+    _, stats = assert_parity(lambda d: _two_groups_declared(
+        d, ResourceFlavor(name="gpu-x", topology_name="rack")))
     assert stats["scalar_reasons"].get("cq_shape", 0) == stats[
-        "scalar_heads"] > 0, stats
+        "scalar_heads"] == stats["cq_shape_heads"] > 0, stats
     assert stats["constrained_heads"] == stats["walk_heads"] == 0, stats
 
 
@@ -417,7 +440,8 @@ def test_mixed_vector_and_scalar_heads():
                 FlavorQuotas(name="default", resources={
                     "cpu": ResourceQuota(nominal=4000,
                                          borrowing_limit=4000)})])]))
-        # cq-1: multi-RG (scalar heads)
+        # cq-1: two resource groups (vector heads since PR 37; its
+        # two-PodSet workloads below are the scalar heads)
         d.apply_cluster_queue(ClusterQueue(
             name="cq-1", cohort="team",
             resource_groups=[
@@ -439,10 +463,14 @@ def test_mixed_vector_and_scalar_heads():
             reqs = {"cpu": rng.choice([1000, 2000, 3000])}
             if q == 1 and i % 2 == 0:
                 reqs["gpu"] = 1
+            pod_sets = [PodSet(name="main", count=1, requests=reqs)]
+            if q == 1 and i % 4 == 0:
+                pod_sets.append(PodSet(name="aux", count=1,
+                                       requests={"cpu": 1000}))
             out.append(Workload(
                 name=f"wl-{i}", queue_name=f"lq-{q}",
                 priority=rng.choice([10, 50]), creation_time=float(i + 1),
-                pod_sets=[PodSet(name="main", count=1, requests=reqs)]))
+                pod_sets=pod_sets))
         return out
 
     assert_parity(build)
