@@ -1299,6 +1299,7 @@ class Driver:
         scale_of = {r: int(st.resource_scale[i])
                     for i, r in enumerate(st.resource_names)}
         F = ext_release.shape[2]
+        plan.finite_deaths = False
         for off, keys in ext.items():
             k = off - base
             if k < 0 or k >= K:
@@ -1310,6 +1311,7 @@ class Driver:
                 loc = row_of_key.get(key)
                 if loc is not None and plan.arrays["adm0"][loc]:
                     death[loc] = min(int(death[loc]), k)
+                    plan.finite_deaths = True
                     continue
                 ci = st.cq_index.get(wl.admission.cluster_queue)
                 if ci is None:

@@ -125,9 +125,10 @@ ENV_FLAGS: dict[str, EnvFlag] = {f.name: f for f in (
     EnvFlag("KUEUE_TPU_PACK_TIGHTEN", "1", "bool",
             "Dtype-tighten launch planes (int32 -> int16/int8)."),
     EnvFlag("KUEUE_TPU_RESIDENT", "1", "bool",
-            "Shard-resident burst state planes on the device mesh."),
+            "Burst row and state planes stay on the device (one chip "
+            "or the mesh) between windows; 0 sends them whole."),
     EnvFlag("KUEUE_TPU_RESIDENT_VERIFY", "", "bool",
-            "Cross-check resident planes against host scatter."),
+            "Cross-check resident planes against the host's."),
     EnvFlag("KUEUE_TPU_SNAP_INCREMENTAL", "1", "bool",
             "Incremental O(dirty) snapshot maintenance in the cache."),
     EnvFlag("KUEUE_TPU_COMPILE_CACHE", "1", "bool",

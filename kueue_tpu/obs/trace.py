@@ -86,6 +86,7 @@ HOT_PATH_PHASES = (
     "burst.pack.grid",                # stage B: the dense [C, M] planes
     "burst.dispatch",   # fused-kernel launch incl. sharded shard launches
     "burst.dispatch.tighten",         # dtype narrowing on the host
+    "burst.dispatch.scatter",         # resident rows: gather, send, update
     "burst.dispatch.launch",          # the fused kernel's (async) jit call
     "burst.fetch",      # decision-plane fetch (flags + full planes)
     "burst.apply",      # host apply of one modeled burst cycle

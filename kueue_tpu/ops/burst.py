@@ -821,6 +821,13 @@ class BurstPlan:
     prev_token: Optional[int] = None
     dirty_cqs: Optional[np.ndarray] = None   # None = full walk
     dirty_ranges: Optional[list] = None      # coalesced [lo, hi) rows
+    # [C] rows a CQ: outside the cells [0, row_extent[ci]) of each CQ
+    # the row planes are the plan's of prev_token, cell for cell (the
+    # one-chip launch sends those cells alone); None = full walk
+    row_extent: Optional[np.ndarray] = None
+    # whether ``death0`` holds a finish: set by who writes them
+    # (Driver._fill_burst_finishes); None = not known, the launch looks
+    finite_deaths: Optional[bool] = None
     # head-pack accounting: rows charged against the kernel's 2^19
     # composite-key budget vs total rows packed into the [C, M] grid
     # (budget_rows == grid_rows when KUEUE_TPU_HEAD_PACK=0)
@@ -1790,12 +1797,57 @@ H2D_BATCH_BYTES = 2 << 30
 H2D_STAGE_MIN_BYTES = 1 << 20
 
 
+# The fused kernel's [C, M(, k)] row inputs; with the scan state's planes
+# (``_STATE_INPUTS`` less the small ``u_cq0``) they are what a one-chip
+# launch keeps on the device between windows.
+_ROW_INPUTS = ("wl_req", "wl_rank", "wl_cycle_rank", "wl_prio",
+               "wl_uidrank", "vec_ok", "wl_flavor_skip")
+_STATE_INPUTS = ("elig0", "parked0", "resume0", "adm0", "adm_seq0",
+                 "adm_usage0", "adm_uses0", "death0", "u_cq0")
+# The one-chip resident update writes runs of this many slots along M
+# (a whole row where M is shorter), one after another in a loop on the
+# device: a run costs it about as much whatever its length, a few runs a
+# CQ cover its rows, and an element scatter of the same cells takes six
+# times as long (scripts/resident_scatter_times.py).
+RESIDENT_RUN = 1024
+# The update is built for a short ladder of run counts, fixed at the
+# first full upload of a grid: a bound on the runs the rows then take,
+# rounded up by at most a quarter (the update stops at the runs a
+# window has; what pads the rung still crosses the bus), times these,
+# at most the grid's.  A window with more runs than the top rung goes
+# up whole.
+RESIDENT_RUNGS = (1, 4)
+
+
+def _row_runs(extent: np.ndarray, W: int, n: int) -> tuple:
+    """``([n, 2] (ci, start), count)``: the runs of ``W`` slots that
+    cover the cells ``[0, extent[ci])`` of every CQ, in order, the last
+    one again up to ``n`` rows (the update stops at ``count``)."""
+    per = -(-extent // W)
+    ci = np.repeat(np.arange(len(extent), dtype=np.int32), per)
+    first = np.repeat((np.cumsum(per) - per).astype(np.int32), per)
+    start = (np.arange(len(ci), dtype=np.int32) - first) * np.int32(W)
+    at = np.stack((ci, start), axis=1)
+    return np.pad(at, ((0, n - len(at)), (0, 0)), mode="edge"), len(at)
+
+
+def _host_nbytes(arrays) -> int:
+    """Bytes of the numpy arrays among ``arrays``: what a launch that is
+    handed them sends to the device."""
+    return sum(x.nbytes for x in arrays if isinstance(x, np.ndarray))
+
+
 class _ResidentRows:
-    """Device-resident scatter-tier row planes from the last fresh
-    sharded dispatch, keyed by the StreamState token that produced
-    them.  The next fresh pack reuses them when its ``prev_token``
-    matches: the delta pack left every clean record's rows in place, so
-    only its ``dirty_cqs`` rows need to re-cross the host boundary."""
+    """Device-resident row planes of the last fresh dispatch, keyed by
+    the StreamState token that produced them: on the mesh the permuted
+    scatter tier, on one chip a mirror of the pack's arena (the row
+    inputs and the scan state's planes as the pack left them, in the
+    dtypes the launch narrowed them to).  The next fresh pack reuses
+    them when its ``prev_token`` matches: the delta pack left everything
+    else in place, so only its ``dirty_cqs`` rows (the mesh) or the
+    cells of its ``row_extent`` (one chip) re-cross the host boundary.
+    ``layout`` is what the planes are laid out for: the mesh's
+    BurstShardLayout, or on one chip their shapes."""
     __slots__ = ("layout", "token", "planes")
 
     def __init__(self, layout, token, planes):
@@ -1879,11 +1931,12 @@ class BurstSolver:
                       "burst_shard_serial_fallbacks": 0,
                       # speculative windows discarded by injected faults
                       "burst_chaos_divergences": 0,
-                      # shard-resident boundary: fresh packs whose row
-                      # planes stayed on the mesh (only dirty rows
-                      # scattered from host) vs full re-uploads, and the
-                      # host→device bytes actually paid vs what the
-                      # upload-everything boundary would have paid
+                      # resident boundary: fresh packs whose row planes
+                      # stayed on the device (only dirty rows, on one
+                      # chip cells, scattered from host) vs full
+                      # re-uploads, and (the mesh) the host→device bytes
+                      # actually paid vs what the upload-everything
+                      # boundary would have paid
                       "burst_resident_hits": 0,
                       "burst_resident_misses": 0,
                       "burst_resident_scatter_rows": 0,
@@ -1891,8 +1944,9 @@ class BurstSolver:
                       "burst_resident_scatter_s": 0.0,
                       "burst_boundary_bytes_h2d": 0,
                       "burst_boundary_bytes_equiv": 0,
-                      # bytes the serial launch's numpy planes put on
-                      # the bus (after dtype tightening)
+                      # bytes the serial launch put on the bus: its
+                      # numpy planes (after dtype tightening), or the
+                      # cells and their indices where the rows stayed
                       "burst_launch_bytes_h2d": 0,
                       # coalesced dirty-row ranges seen by the journal
                       "burst_journal_dirty_ranges": 0,
@@ -1907,10 +1961,15 @@ class BurstSolver:
         self._shard_mesh = None
         self._shard_layouts: dict = {}
         self._sharded_fns: dict = {}
-        # shard-resident device copy of the last fresh pack's row planes
+        # device-resident copy of the last fresh pack's row planes (on
+        # the mesh or on one chip), the program that updates it on
+        # either, and the rungs the one chip's is built for ({shapes:
+        # rungs});
         # + the per-forest cycle-cost EWMA feeding the next layout
         self._resident = None
         self._scatter_jit = None
+        self._update_jit = None
+        self._resident_rungs: dict = {}
         self._forest_cost: dict | None = None
         # dtype tightening of the serial launch's packed planes (sticky
         # per-plane widths; KUEUE_TPU_PACK_TIGHTEN=0 disables)
@@ -2064,23 +2123,27 @@ class BurstSolver:
                                         speculative, permuted)
         st = plan.structure
         dev = solver_device()
-        a = plan.arrays
-        if env_value("KUEUE_TPU_PACK_TIGHTEN") != "0":
-            # narrow the rank/index/request planes at the serial
-            # transfer boundary only — plan.arrays keeps the reference
-            # int32 dtypes (parity tests, resident scatter); the kernel
-            # upcasts on device.  Scan-state planes are never narrowed
-            # (a chained window feeds device outputs straight back in).
-            from .packing import tighten_arrays
-            with _span("burst.dispatch.tighten"):
-                a = tighten_arrays(a, self._tighten, self.stats)
-        self.stats["burst_launch_bytes_h2d"] += (
-            sum(v.nbytes for v in a.values() if isinstance(v, np.ndarray))
-            + sum(v.nbytes for v in state if isinstance(v, np.ndarray)))
         t0 = _time.perf_counter()
+        a = None
+        if env_value("KUEUE_TPU_RESIDENT") != "0":
+            if isinstance(state[0], np.ndarray):
+                # a packed window: the rows stay on the device, or go up
+                # whole and stay from now on
+                a, state = self._resident_rows(plan, state, dev)
+            else:
+                # a chained one: its rows are the mirror's where the
+                # mirror is still this plan's, its state the carry's
+                a = self._mirror_rows(plan)
+        if a is None:
+            a = self._tightened(plan.arrays)
         with _span("burst.dispatch.launch"):
-            a, (elig0, parked0, resume0, adm0, adm_seq0, adm_usage0,
-                adm_uses0, death0, u_cq0) = self._stage(a, state, dev)
+            a, state = self._stage(a, state, dev)
+            # what is still the host's rides with the call
+            self.stats["burst_launch_bytes_h2d"] += _host_nbytes(
+                [v for k, v in a.items() if k not in _STATE_INPUTS]
+                + list(state))
+            (elig0, parked0, resume0, adm0, adm_seq0, adm_usage0,
+             adm_uses0, death0, u_cq0) = state
             out = burst_cycles(
                 a["wl_req"], a["wl_rank"], a["wl_cycle_rank"],
                 a["wl_prio"], a["wl_uidrank"], a["vec_ok"],
@@ -2114,33 +2177,49 @@ class BurstSolver:
                            seq_base=seq_base, dev=dev, pending=out,
                            speculative=speculative, t_dispatch=t0)
 
-    def _stage(self, a: dict, state: tuple, dev) -> tuple[dict, tuple]:
+    def _tightened(self, a: dict) -> dict:
+        """A copy of ``a`` with the rank, index and request planes
+        narrowed at the serial transfer boundary only — plan.arrays
+        keeps the reference int32 dtypes (parity tests, resident
+        scatter); the kernel upcasts on device.  Scan-state planes are
+        never narrowed (a chained window feeds device outputs straight
+        back in)."""
+        if env_value("KUEUE_TPU_PACK_TIGHTEN") == "0":
+            return dict(a)
+        from .packing import tighten_arrays
+        with _span("burst.dispatch.tighten"):
+            return tighten_arrays(a, self._tighten, self.stats)
+
+    def _stage(self, a: dict, state: tuple, dev,
+               always: tuple = ()) -> tuple[dict, tuple]:
         """Send a serial launch's large host planes (the row planes of
         ``a`` and the scan state) to ``dev`` in batches of at most
         ``H2D_BATCH_BYTES``, each waited for.  Returns ``a`` and
         ``state`` with those planes as device arrays; device arrays (a
-        chained state) and small planes pass through."""
+        chained state, a resident plane) pass through, and so do planes
+        under ``H2D_STAGE_MIN_BYTES`` unless named in ``always``."""
         a = dict(a)
         state = list(state)
-        rows = ("wl_req", "wl_rank", "wl_cycle_rank", "wl_prio",
-                "wl_uidrank", "vec_ok", "wl_flavor_skip")
         batch: list[tuple] = []
 
         def flush():
             if batch:
-                up = jax.device_put([box[k] for box, k in batch], dev)
+                host = [box[k] for box, k in batch]
+                up = jax.device_put(host, dev)
                 jax.block_until_ready(up)
                 for (box, k), x in zip(batch, up):
                     box[k] = x
                 self.stats["burst_h2d_batches"] += 1
+                self.stats["burst_launch_bytes_h2d"] += _host_nbytes(host)
                 batch.clear()
 
         size = 0
-        for box, k in ([(a, k) for k in rows]
-                       + [(state, i) for i in range(len(state))]):
+        for box, k, name in ([(a, n, n) for n in _ROW_INPUTS]
+                             + [(state, i, n)
+                                for i, n in enumerate(_STATE_INPUTS)]):
             x = box[k]
-            if (not isinstance(x, np.ndarray)
-                    or x.nbytes < H2D_STAGE_MIN_BYTES):
+            if not isinstance(x, np.ndarray) or (
+                    x.nbytes < H2D_STAGE_MIN_BYTES and name not in always):
                 continue
             if size + x.nbytes > H2D_BATCH_BYTES:
                 flush()
@@ -2179,6 +2258,173 @@ class BurstSolver:
                     a.at[rows].set(v) for a, v in zip(planes, vals)))
         return self._scatter_jit
 
+    def _update_runs_fn(self):
+        """The one-chip mirror's update: ``planes`` with the first
+        ``count`` runs ``vals[i]`` (``[W(, k)]`` a plane) written along
+        M at ``(ci, start) = at[i]``, every plane's run in one step of
+        one loop.  The planes' buffers are given to the result: the
+        mirror is their only holder, so the update costs no second copy
+        of the grid."""
+        if self._update_jit is None:
+            def update(planes, at, count, vals):
+                def run(i, planes):
+                    return tuple(
+                        jax.lax.dynamic_update_slice(
+                            p, jax.lax.dynamic_slice_in_dim(v, i, 1),
+                            (at[i, 0], at[i, 1]) + (0,) * (p.ndim - 2))
+                        for p, v in zip(planes, vals))
+                return jax.lax.fori_loop(0, count, run, planes)
+            self._update_jit = jax.jit(update, donate_argnums=0)
+        return self._update_jit
+
+    def _chains(self, plan: BurstPlan, layout) -> bool:
+        """Whether the resident copy is laid out as ``layout`` and holds
+        the pack state ``plan``'s delta pack built on."""
+        res = self._resident
+        return (res is not None and res.layout == layout
+                and plan.prev_token is not None
+                and res.token == plan.prev_token)
+
+    @staticmethod
+    def _mirror_shapes(plan: BurstPlan) -> tuple:
+        """(name, shape) of the planes the one-chip mirror holds: the
+        row inputs and the scan state's planes that have a cell a grid
+        slot.  Where every flavor is plain ``wl_flavor_skip`` is one
+        column of zeros and goes with the call."""
+        a = plan.arrays
+        return tuple((n, a[n].shape) for n in _ROW_INPUTS + _STATE_INPUTS[:-1]
+                     if n != "wl_flavor_skip" or a[n].shape[1] == plan.M)
+
+    def _mirror_rows(self, plan: BurstPlan) -> Optional[dict]:
+        """A chained launch's inputs where the mirror still holds this
+        plan's rows: the row inputs from the device, the small planes
+        from the host; None when the mirror has moved on."""
+        res = self._resident
+        if (res is None or plan.pack_token is None
+                or res.token != plan.pack_token
+                or res.layout != self._mirror_shapes(plan)):
+            return None
+        a = self._tightened({k: v for k, v in plan.arrays.items()
+                             if k not in res.planes})
+        a.update((n, res.planes[n]) for n in _ROW_INPUTS if n in res.planes)
+        return a
+
+    def _send_runs(self, plan: BurstPlan, sent: tuple,
+                   rungs: tuple) -> Optional[dict]:
+        """The mirror's planes after ``plan``'s cells went into them:
+        the runs that cover ``[0, row_extent[ci])`` of each CQ, made up
+        to a rung the update was built for, their values in the mirror's
+        dtypes.  None, and nothing sent, where the runs pass the top
+        rung or a value needs a wider plane than the mirror holds."""
+        from .packing import narrow_values
+        a, res = plan.arrays, self._resident
+        W = min(RESIDENT_RUN, plan.M)
+        n_runs = int((-(-plan.row_extent // W)).sum())
+        pad = next((r for r in rungs if r >= n_runs > 0), None)
+        if pad is None:
+            return None
+        with _span("burst.dispatch.scatter"):
+            at, count = _row_runs(plan.row_extent, W, pad)
+            where = (at[:, 0], at[:, 1] // W)
+            vals = [a[n].reshape((plan.C, plan.M // W, W)
+                                 + a[n].shape[2:])[where] for n in sent]
+        with _span("burst.dispatch.tighten"):
+            vals = [narrow_values(v, res.planes[n].dtype)
+                    for n, v in zip(sent, vals)]
+        if any(v is None for v in vals):
+            return None     # the whole upload widens the plane
+        planes = dict(res.planes)
+        with _span("burst.dispatch.scatter"):
+            planes.update(zip(sent, self._update_runs_fn()(
+                tuple(planes[n] for n in sent), at, np.int32(count),
+                tuple(vals))))
+        self.stats["burst_launch_bytes_h2d"] += _host_nbytes([at, *vals])
+        self.stats["burst_resident_scatter_rows"] += count * W
+        return planes
+
+    def _resident_rows(self, plan: BurstPlan, state: tuple,
+                       dev) -> tuple[dict, tuple]:
+        """A freshly packed one-chip window's inputs under the resident
+        boundary (``KUEUE_TPU_RESIDENT``, default on).  The row inputs
+        and the scan state's planes live on the device as a mirror of
+        the pack's arena at the pack token that produced them.  A plan
+        that chains that token, with the same shapes, sends the runs of
+        its CQs' rows and one donated update puts them in place (a hit,
+        ``_send_runs``); any other plan goes up whole through ``_stage``
+        and becomes the mirror (a miss), and the first of a grid builds
+        the update at its rungs (``RESIDENT_RUNGS``).  The
+        mirror's ``death0`` is the arena's, which holds no finish: a
+        plan with finishes sends its own plane for this launch.
+        ``KUEUE_TPU_RESIDENT_VERIFY=1`` asserts every mirrored plane
+        equals the plan's.  Returns the name→array dict (device arrays
+        for the mirrored planes, host arrays for the rest) and the state
+        tuple."""
+        from .packing import scatter_pad
+        a = plan.arrays
+        stats = self.stats
+        if plan.pack_token is None:
+            # a full pack outside the stream: nothing can chain it
+            self._resident = None
+            stats["burst_resident_misses"] += 1
+            return self._tightened(a), state
+        shapes = self._mirror_shapes(plan)
+        names = tuple(n for n, _ in shapes)
+        sent = tuple(n for n in names if n != "death0")
+        finite = plan.finite_deaths
+        if finite is None:
+            finite = bool((a["death0"] != I32_MAX).any())
+        rungs = self._resident_rungs.get(shapes, ())
+        planes = None
+        if self._chains(plan, shapes) and plan.row_extent is not None:
+            planes = self._send_runs(plan, sent, rungs)
+        if planes is not None:
+            stats["burst_resident_hits"] += 1
+            small = self._tightened(
+                {k: v for k, v in a.items() if k not in names})
+            death, u_cq = planes["death0"], a["u_cq0"]
+            if finite:
+                death = jax.device_put(a["death0"], dev)
+                stats["burst_launch_bytes_h2d"] += death.nbytes
+        else:
+            stats["burst_resident_misses"] += 1
+            # let go of the old mirror before the new one goes up
+            self._resident = None
+            host = self._tightened(a)
+            up, state = self._stage(host, state, dev, always=names)
+            small = {k: v for k, v in up.items() if k not in names}
+            planes = {n: up[n] for n in names if n in _ROW_INPUTS}
+            planes.update((n, x) for n, x in zip(_STATE_INPUTS, state)
+                          if n in names)
+            death, u_cq = planes["death0"], state[-1]
+            if finite:
+                planes["death0"] = jax.device_put(
+                    np.full_like(a["death0"], I32_MAX), dev)
+            if not rungs:
+                W = min(RESIDENT_RUN, plan.M)
+                most = plan.grid_rows // W + plan.C
+                step = max(1, scatter_pad(most) // 8)
+                most = -(-most // step) * step
+                rungs = tuple(sorted({min(plan.C * (plan.M // W), most * m)
+                                      for m in RESIDENT_RUNGS}))
+                self._resident_rungs = {shapes: rungs}
+                with _span("burst.dispatch.scatter"):
+                    # build the update at every rung now, not in a later
+                    # window: no run of it is written
+                    for r in rungs:
+                        planes.update(zip(sent, self._update_runs_fn()(
+                            tuple(planes[n] for n in sent),
+                            np.zeros((r, 2), np.int32), np.int32(0),
+                            tuple(np.zeros((r, W) + host[n].shape[2:],
+                                           host[n].dtype) for n in sent))))
+        self._resident = _ResidentRows(shapes, plan.pack_token, planes)
+        launch = {**planes, "death0": death, "u_cq0": u_cq}
+        if env_value("KUEUE_TPU_RESIDENT_VERIFY"):
+            for n in names:
+                if not np.array_equal(np.asarray(launch[n]), a[n]):
+                    raise AssertionError(f"resident scatter drift in {n}")
+        small.update((n, launch[n]) for n in _ROW_INPUTS if n in launch)
+        return small, tuple(launch[n] for n in _STATE_INPUTS)
+
     def _resident_inputs(self, plan: BurstPlan, layout, timers) -> dict:
         """Sharded kernel inputs for a FRESH pack under the
         shard-resident boundary (``KUEUE_TPU_RESIDENT``, default on):
@@ -2198,7 +2444,7 @@ class BurstSolver:
         scatter tiers, host arrays for the global tier)."""
         import time as _time
         from ..parallel.sharded import (
-            _C_FILLS, _STATE_NAMES, SCATTER_PLANES, GLOBAL_PLANES)
+            _C_FILLS, SCATTER_PLANES, GLOBAL_PLANES)
         from ..utils.journal import PackJournal
         from .packing import scatter_pad
         a = plan.arrays
@@ -2215,10 +2461,7 @@ class BurstSolver:
         stats["burst_boundary_bytes_equiv"] += layout._static_nbytes
 
         res = self._resident
-        hit = (res is not None and res.layout is layout
-               and plan.prev_token is not None
-               and res.token == plan.prev_token
-               and plan.dirty_cqs is not None)
+        hit = self._chains(plan, layout) and plan.dirty_cqs is not None
         SCs = layout.n_shards * layout.Cs
         full_bytes = sum((a[n].nbytes // max(1, plan.C)) * SCs
                         for n in SCATTER_PLANES)
@@ -2299,7 +2542,6 @@ class BurstSolver:
         pack scatters only its dirty rows (``_resident_inputs``) and a
         chained window reuses the cached device dict outright."""
         import time as _time
-        from ..parallel.sharded import _STATE_NAMES
         layout = self._layout_for(plan)
         timers = self.stats.get("burst_shard_pack_s")
         a = None
@@ -2311,7 +2553,7 @@ class BurstSolver:
                 a = self._resident_inputs(plan, layout, timers)
                 plan._resident_args = (layout, a)
             if a is not None and not permuted:
-                state = tuple(a[n] for n in _STATE_NAMES)
+                state = tuple(a[n] for n in _STATE_INPUTS)
         if a is None:
             a = layout.plan_arrays(plan, timers)
             if not permuted:
@@ -2359,10 +2601,7 @@ class BurstSolver:
                  ext_release: np.ndarray,
                  ext_unpark: np.ndarray) -> BurstHandle:
         """Async dispatch of a freshly packed window."""
-        a = plan.arrays
-        state = (a["elig0"], a["parked0"], a["resume0"], a["adm0"],
-                 a["adm_seq0"], a["adm_usage0"], a["adm_uses0"],
-                 a["death0"], a["u_cq0"])
+        state = tuple(plan.arrays[n] for n in _STATE_INPUTS)
         return self._launch(plan, K, runtime, ext_release, ext_unpark,
                             state, plan.seq_base, speculative=False)
 

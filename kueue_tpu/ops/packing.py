@@ -627,6 +627,19 @@ def _needed_width(arr: np.ndarray) -> int:
     return _range_width(int(arr.min()), int(arr.max()))
 
 
+def narrow_values(vals: np.ndarray, dtype) -> Optional[np.ndarray]:
+    """``vals`` in ``dtype``, the dtype a device-resident plane was
+    narrowed to when it went up, or None when one of them does not fit
+    it: the caller sends the plane again, which widens; never a
+    truncation."""
+    dtype = np.dtype(dtype)
+    if vals.dtype == dtype:
+        return vals
+    if dtype.kind != "i" or _needed_width(vals) > dtype.itemsize:
+        return None
+    return vals.astype(dtype)
+
+
 def tighten_arrays(arrays: dict, state: TightenState,
                    stats: dict = None) -> dict:
     """Return a shallow copy of ``arrays`` with the TIGHTEN_PLANES

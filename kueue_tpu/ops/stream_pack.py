@@ -64,8 +64,13 @@ drops the state, and the window packs in full.
 The produced plan is bit-identical to ``pack_burst`` of the same live
 state (enforced by tests/test_streaming_pack.py and
 tests/test_delta_pack.py); plans carry snapshot *copies* of the live
-planes, so consumers (pipeline speculation, the shard-resident scatter,
-parity tests) never observe later patches.
+planes, so consumers (pipeline speculation, the resident scatter,
+parity tests) never observe later patches.  A delta plan also says
+where it differs from the plan before it: ``dirty_cqs`` (the CQs walked
+or patched) and ``row_extent`` (a CQ's rows as they were or as they
+are, whichever reach further: every cell a delta window writes lies
+under it), which the launch's device-resident copies update from
+(ops/burst.py ``_resident_inputs``, ``_resident_rows``).
 """
 
 from __future__ import annotations
@@ -501,7 +506,7 @@ def _bump(stats, key, n=1):
 
 
 def _materialize(st, state, s, views, scheduler, dirty_cis, prev_token,
-                 rank_patches, stats):
+                 rank_patches, stats, row_extent=None):
     """Build the BurstPlan snapshot from the patched arena state."""
     C = len(st.cq_names)
     M = state.M
@@ -577,6 +582,7 @@ def _materialize(st, state, s, views, scheduler, dirty_cis, prev_token,
         budget_rows=n_budget, grid_rows=n)
     plan.pack_token = state.token
     plan.prev_token = prev_token
+    plan.row_extent = row_extent
     if dirty_cis is not None:
         plan.dirty_cqs = np.asarray(sorted(dirty_cis), dtype=np.int64)
         from ..utils.journal import PackJournal
@@ -1207,8 +1213,14 @@ def pack_burst_streaming(structure, queues, cache, scheduler, clock,
             placed = _place_rows(state, walked)
 
         with _span("burst.pack.grid"):
+            # every cell this window writes lies in a CQ's rows as they
+            # were or as they come to be: the rows that came, went or
+            # changed place, the pads of a CQ that shrank, the rank
+            # cells, the row-grade patches
+            rows_before = state.n_rows_cq.copy()
             views, rank_patches, repacked = _patch_grid(
                 st, state, statics, arena, placed, pos_dirty_cis, min_m)
+            row_extent = np.maximum(rows_before, state.n_rows_cq)
 
             # row-grade patches (deduped by the journal): single cells.
             # A job queued before a later row escalated its CQ to dirty is
@@ -1243,7 +1255,8 @@ def pack_burst_streaming(structure, queues, cache, scheduler, clock,
                 return None, state, False
             dirty_cis = wset_cis | {j[0] for j in row_jobs}
             plan = _materialize(st, state, statics, views, scheduler,
-                                dirty_cis, prev_token, rank_patches, stats)
+                                dirty_cis, prev_token, rank_patches, stats,
+                                row_extent)
             _note_ms(stats, t0, delta=True)
             return plan, state, True
     except _StreamDesync:

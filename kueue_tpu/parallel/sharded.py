@@ -23,6 +23,7 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ..ops.burst import _STATE_INPUTS as _STATE_NAMES
 from ..ops.cycle import solve_cycle
 from ..ops.packing import PackedCycle
 
@@ -242,8 +243,6 @@ _N_FILLS = {
     "has_blim": False,
 }
 _STATE_FILLS = (False, False, 0, False, 0, 0, False, _I32_MAX, 0)
-_STATE_NAMES = ("elig0", "parked0", "resume0", "adm0", "adm_seq0",
-                "adm_usage0", "adm_uses0", "death0", "u_cq0")
 
 # Residency tiers for the shard-resident boundary (BurstSolver keeps the
 # permuted kernel inputs on the mesh between windows; only the tier that

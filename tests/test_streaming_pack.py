@@ -468,11 +468,14 @@ def test_schedule_burst_decisions_identical_tighten_on_off(monkeypatch):
 ])
 def test_a_launch_stages_its_host_planes_in_bounded_batches(
         monkeypatch, batch_bytes, batches_a_launch):
-    """The serial launch sends its large host planes ahead of the call
-    in batches under ``H2D_BATCH_BYTES``; the decisions are those of a
-    launch that hands the call its host arrays (planes under
-    ``H2D_STAGE_MIN_BYTES``, as every test's are)."""
+    """The serial launch that sends its planes whole (every launch with
+    ``KUEUE_TPU_RESIDENT=0``; with it on, the one that installs the
+    device's copy: tests/test_burst_resident.py) sends the large ones
+    ahead of the call in batches under ``H2D_BATCH_BYTES``; the
+    decisions are those of a launch that hands the call its host arrays
+    (planes under ``H2D_STAGE_MIN_BYTES``, as every test's are)."""
     from kueue_tpu.ops import burst as _b
+    monkeypatch.setenv("KUEUE_TPU_RESIDENT", "0")
     runs = {}
     for staged in (False, True):
         if staged:
