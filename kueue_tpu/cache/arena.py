@@ -55,7 +55,8 @@ class PlaneArena:
         self.stats = {"arena_growth_events": 0, "arena_planes": 0,
                       "arena_bytes": 0, "arena_used_bytes": 0,
                       "arena_snapshots_reused": 0,
-                      "arena_snapshots_fresh": 0}
+                      "arena_snapshots_fresh": 0,
+                      "arena_snapshot_bytes": 0}
 
     def drop(self) -> None:
         """Forget every slab (structure change with new trailing axes)."""
@@ -111,6 +112,7 @@ class PlaneArena:
             buf = view.copy()
             self.stats["arena_snapshots_fresh"] += 1
         self._snaps[name] = buf
+        self.stats["arena_snapshot_bytes"] += buf.nbytes
         return buf
 
     def refresh_stats(self, used_shapes: dict | None = None) -> dict:

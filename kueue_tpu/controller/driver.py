@@ -461,45 +461,46 @@ class Driver:
         subtree walk per workload (manager.go:490 semantics are
         idempotent within a batch — the wakeup sees the post-release
         state either way)."""
-        touched: list[str] = []
-        seen: set[str] = set()
-        any_done = False
-        now = self.clock()
-        if self._wal is not None:
-            live = [k for k in keys
-                    if (w := self.workloads.get(k)) is not None
-                    and not w.is_finished]
-            if live:
-                self._wal.log(_journal.finish_op(live, message, now))
-        if _chaos.ACTIVE is not None:
-            _chaos.ACTIVE.crashpoint("wal.finish")
-        for key in keys:
-            wl = self.workloads.get(key)
-            if wl is None or wl.is_finished:
-                continue
-            set_finished_condition(wl, "JobFinished", message, now)
-            if wl.admission is not None:
-                cq_name = wl.admission.cluster_queue
-                was_admitted = wl.is_admitted
-                self.cache.delete_workload(Info(wl))
-                self.metrics.release_reservation(cq_name)
-                if was_admitted:
-                    self.metrics.release_admitted(cq_name)
-                if cq_name not in seen:
-                    seen.add(cq_name)
-                    touched.append(cq_name)
-            self.queues.delete_workload(wl)
-            self.events.append(("Finished", key, message))
-            any_done = True
-        if touched:
-            if self._cycle_touched is not None:
-                self._cycle_touched.extend(touched)
-            else:
-                self.queues.queue_inadmissible_workloads(touched)
-        if any_done:
-            self.wake_gate_blocked()
-        if self._wal is not None:
-            self.host_pool.commit_wal(self._wal)
+        with _span("boundary"):
+            touched: list[str] = []
+            seen: set[str] = set()
+            any_done = False
+            now = self.clock()
+            if self._wal is not None:
+                live = [k for k in keys
+                        if (w := self.workloads.get(k)) is not None
+                        and not w.is_finished]
+                if live:
+                    self._wal.log(_journal.finish_op(live, message, now))
+            if _chaos.ACTIVE is not None:
+                _chaos.ACTIVE.crashpoint("wal.finish")
+            for key in keys:
+                wl = self.workloads.get(key)
+                if wl is None or wl.is_finished:
+                    continue
+                set_finished_condition(wl, "JobFinished", message, now)
+                if wl.admission is not None:
+                    cq_name = wl.admission.cluster_queue
+                    was_admitted = wl.is_admitted
+                    self.cache.delete_workload(Info(wl))
+                    self.metrics.release_reservation(cq_name)
+                    if was_admitted:
+                        self.metrics.release_admitted(cq_name)
+                    if cq_name not in seen:
+                        seen.add(cq_name)
+                        touched.append(cq_name)
+                self.queues.delete_workload(wl)
+                self.events.append(("Finished", key, message))
+                any_done = True
+            if touched:
+                if self._cycle_touched is not None:
+                    self._cycle_touched.extend(touched)
+                else:
+                    self.queues.queue_inadmissible_workloads(touched)
+            if any_done:
+                self.wake_gate_blocked()
+            if self._wal is not None:
+                self.host_pool.commit_wal(self._wal)
 
     def update_reclaimable_pods(self, key: str, counts: dict[str, int]) -> None:
         """reference workload.UpdateReclaimablePods (KEP 78): shrink the
@@ -901,6 +902,18 @@ class Driver:
         to pipeline-off by construction.
 
         Returns the list of per-cycle CycleStats actually applied."""
+        with _span("burst"):
+            return self._schedule_burst(max_cycles, runtime,
+                                        external_finishes, on_cycle,
+                                        on_cycle_start, pipeline)
+
+    def _schedule_burst(self, max_cycles: int, runtime: int,
+                        external_finishes: Optional[dict],
+                        on_cycle: Optional[Callable],
+                        on_cycle_start: Optional[Callable],
+                        pipeline: bool) -> list:
+        """``schedule_burst``'s loop, inside its ``burst`` span: what no
+        child span covers is the span's self time."""
         import numpy as np
         from ..ops.burst import (BurstSolver, pack_burst_cached,
                                  K_BURST_LADDER)
@@ -963,7 +976,10 @@ class Driver:
                 self.host_pool.commit_wal(self._wal)
             self.obs.record_cycle(stats)
             if on_cycle is not None:
-                on_cycle(k, stats)
+                # the caller's code, not this loop's own: a benchmark
+                # stops its profiler in here
+                with _span("burst.callbacks"):
+                    on_cycle(k, stats)
 
         def quiescent() -> bool:
             """Nothing can make further cycles non-empty: no eligible
@@ -990,7 +1006,8 @@ class Driver:
         def normal_cycle(heads=None, advance=True) -> bool:
             """One normal-path cycle; False when the queues were empty."""
             if advance and on_cycle_start is not None:
-                on_cycle_start(len(out))
+                with _span("burst.callbacks"):
+                    on_cycle_start(len(out))
             if heads is None:
                 stats = self.schedule_once()
             else:
@@ -1181,11 +1198,13 @@ class Driver:
                 # caller's clock FIRST, then fire deadline/backoff timers
                 # at the new time, then pop heads
                 if on_cycle_start is not None:
-                    on_cycle_start(len(out))
-                if self.wait_for_pods_ready.enable:
-                    self.enforce_wait_for_pods_ready()
-                self.queues.wake_expired_backoffs()
-                heads = self.queues.heads_nonblocking()
+                    with _span("burst.callbacks"):
+                        on_cycle_start(len(out))
+                with _span("queue.heads"):
+                    if self.wait_for_pods_ready.enable:
+                        self.enforce_wait_for_pods_ready()
+                    self.queues.wake_expired_backoffs()
+                    heads = self.queues.heads_nonblocking()
                 if dirty[k]:
                     bstats["burst_dirty_cycles"] += 1
                     r = int(dirty_reason[k])

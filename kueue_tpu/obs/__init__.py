@@ -11,8 +11,10 @@ reproduced for the solver stack:
   On, every recorded span is also a ``jax.profiler.TraceAnnotation``,
   so a profiler trace (``profiling.py``) shows it beside the device's
   operations on one clock; a parent with children reports its self
-  time as ``<name>.self``; spans ``/debug/spans`` had no room for are
-  counted (``spans_dropped`` in the ``obs`` block), never lost silently;
+  time as ``<name>.self``; the cyclic collector's runs are ``host.gc``
+  spans (a ``gc.callbacks`` entry that is there only while tracing is
+  on); spans ``/debug/spans`` had no room for are counted
+  (``spans_dropped`` in the ``obs`` block), never lost silently;
 - :mod:`flight` — ring-buffer flight recorder of the last N cycles
   (decision digests, spans, chaos hits), dumpable on demand, over
   HTTP, and on SIGUSR2;
@@ -76,7 +78,8 @@ class ObsPlane:
 
     def enable_tracing(self) -> Tracer:
         """Install the process tracer bound to this driver's registry
-        and (virtual) clock.  Idempotent per driver."""
+        and (virtual) clock, and with it the collector's callback
+        (``trace.install``).  Idempotent per driver."""
         t = _trace.ACTIVE
         if t is None or t.registry is not self.driver.metrics:
             t = _trace.install(Tracer(registry=self.driver.metrics,

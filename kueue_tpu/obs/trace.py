@@ -33,6 +33,11 @@ pairing (a span may close exactly once, and only when it is the
 innermost open span), so malformed instrumentation fails loudly in
 tests instead of producing silently garbled traces.
 
+While a tracer is installed the cyclic collector's runs are spans too
+(``host.gc``, through ``gc.callbacks``: :func:`_on_gc`), nested in
+whatever span was open: a collection in the middle of a phase is the
+collector's time, not that phase's self time.
+
 ``to_chrome_trace`` renders finished spans as Chrome trace-event JSON
 (``ph: "X"`` complete events, microsecond ``perf_counter`` timestamps)
 for ``/debug/spans`` when no profiler session ran; a profiler trace
@@ -45,6 +50,7 @@ import of this module: the WAL and the federation reach :func:`span`
 
 from __future__ import annotations
 
+import gc
 import threading
 import time
 from dataclasses import dataclass
@@ -63,9 +69,12 @@ SPAN_BUCKETS = exponential_buckets(1e-6, 2, 22)
 #: inside ``cycle.nominate.oracle``); the roster also carries
 #: ``<name>.self`` for every phase that had a child.
 HOT_PATH_PHASES = (
+    "queue.heads",      # a cycle's heads popped off the queues (and, in
+                        # schedule_burst, the timers fired before them)
     "cycle",            # one whole scheduling cycle (schedule_once path)
     "cycle.snapshot",   # cache snapshot build / incremental reuse
     "cycle.nominate",   # validation + flavor assignment + preempt targets
+    "cycle.nominate.validate",        # the per-head loop that builds Entries
     "cycle.nominate.classify",        # cycle pack + device classification
     "cycle.nominate.classify.eligibility",  # the heads' [W, G, S] flavor plane
     "cycle.nominate.classify.groups",  # one flavor walk a group, joined
@@ -79,17 +88,29 @@ HOT_PATH_PHASES = (
     "cycle.nominate.scan_dispatch",   # pack targets + admit-scan dispatch
     "cycle.order",      # classical sort or fair-sharing tournament setup
     "cycle.admit",      # sequential admit loop (assume/apply/requeue)
+    "cycle.admit.prepare",            # per-head assignments, speculative
+                                      # admit objects: overlaps the scan
     "cycle.admit.fetch",              # blocking wait for the admit scan
+    "cycle.admit.apply",              # admit, issue preemptions, skip
+    "cycle.admit.requeue",            # requeue of every head not assumed
+    "burst",            # one whole schedule_burst call; parent of the
+                        # burst.* phases and of its per-cycle cycles
     "burst.pack",       # burst-window pack (streaming, or full)
     "burst.pack.drain",               # journal drain + round-trip checks
     "burst.pack.walk",                # stage A: per-queue row records
     "burst.pack.grid",                # stage B: the dense [C, M] planes
+    "burst.pack.grid.patch",          # delta: row write, orders, cells
+    "burst.pack.grid.snapshot",       # host copy of the planes a plan owns
     "burst.dispatch",   # fused-kernel launch incl. sharded shard launches
     "burst.dispatch.tighten",         # dtype narrowing on the host
     "burst.dispatch.scatter",         # resident rows: gather, send, update
     "burst.dispatch.launch",          # the fused kernel's (async) jit call
     "burst.fetch",      # decision-plane fetch (flags + full planes)
     "burst.apply",      # host apply of one modeled burst cycle
+    "burst.callbacks",  # the caller's on_cycle_start / on_cycle hooks
+    "boundary",         # finish_workloads: quota release, removals, wake-up
+    "host.gc",          # one collection of the cyclic collector, nested
+                        # in whatever span was open (gc.callbacks)
     "wal.append",       # one journal op append
     "wal.commit",       # cycle-boundary commit (group commit included)
     "wal.compact",      # checkpoint + tail rewrite
@@ -231,6 +252,14 @@ class Tracer:
         self._pool: list[Span] = []      # one reusable span per depth
         self._counted = _CountedSpan(self)   # shared histogram-only leaf
         self._hists: dict[str, Histogram] = {}   # phase -> registry hist
+        # the collector's span (``_on_gc``) has a slot of its own: a
+        # collection can start between any two bytecodes, those of
+        # ``span()`` and ``Span.__exit__`` included, where the pooled
+        # span of that depth is still somebody's.  Its series is made
+        # here and not at a first observation, which would insert into
+        # the registry from inside whatever the collection interrupted
+        self._gc_span = Span(self, "host.gc")
+        self._hist_for("host.gc")
         self.cycle_spans: list[SpanRecord] = []
         self.finished_total = 0
         self.opened_total = 0
@@ -318,9 +347,39 @@ class Tracer:
 ACTIVE: Optional[Tracer] = None
 
 
+def _on_gc(phase: str, info: dict) -> None:
+    """The ``gc.callbacks`` entry, there while a tracer is installed:
+    one collection of the cyclic collector is one ``host.gc`` span,
+    opened at ``"start"`` and closed at ``"stop"`` inside whatever span
+    was open, so a parent's self time is free of the collector's
+    pauses.  The callback runs on the thread that triggered the
+    collection; on any thread but the tracer's owner it does nothing,
+    as ``span()``.  Collections do not nest and between the two calls
+    run only finalizers, so the pair is LIFO on the owner's stack."""
+    t = ACTIVE
+    if t is None or threading.get_ident() != t._owner:
+        return
+    s = t._gc_span
+    if phase == "start":
+        if not s._open:
+            t.opened_total += 1
+            s.__enter__()
+    elif s._open:
+        s.__exit__(None, None, None)
+
+
 def install(tracer: Optional[Tracer]) -> Optional[Tracer]:
+    """Make ``tracer`` the process's tracer (None: tracing off).  The
+    collector's callback is in ``gc.callbacks`` exactly while one is
+    installed: with tracing off the list is as the interpreter left
+    it."""
     global ACTIVE
     ACTIVE = tracer
+    hooked = _on_gc in gc.callbacks
+    if tracer is not None and not hooked:
+        gc.callbacks.append(_on_gc)
+    elif tracer is None and hooked:
+        gc.callbacks.remove(_on_gc)
     return tracer
 
 

@@ -1924,6 +1924,10 @@ class BurstSolver:
                       "burst_delta_packs": 0, "burst_full_packs": 0,
                       "rows_reused": 0, "rows_repacked": 0,
                       "delta_pack_s": 0.0,
+                      # bytes PlaneArena.snapshot copied for the plans
+                      # to own (the arena's count, carried over by the
+                      # streaming pack's _materialize)
+                      "pack_arena_snapshot_bytes": 0,
                       # graceful degradation (chaos shard.device_loss or
                       # lose_devices): mesh rebuilt over the survivors,
                       # serial fallback when fewer than two remain

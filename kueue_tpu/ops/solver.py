@@ -177,6 +177,9 @@ class CycleSolver:
             "skipped_dispatches": 0,  # no fit head -> scan provably no-op
             "singleton_dispatches": 0,  # <=1 entry/forest -> no contention
             "structure_rebuilds": 0,
+            "snapshot_cqs_recloned": 0,  # queues the cycles' cache
+                                         # snapshots cloned again (the
+                                         # scheduler counts them)
             "scalar_heads": 0,        # heads classified by the host walk
             # flavor-walk telemetry (heterogeneous fast path):
             "scalar_reasons": {},     # {reason: count} for scalar heads

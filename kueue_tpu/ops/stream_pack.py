@@ -554,8 +554,10 @@ def _materialize(st, state, s, views, scheduler, dirty_cis, prev_token,
         s.cand_tables[(M, KC)] = tables
     cand_rows, cand_lmem, self_lmem = tables
     arena = state.arena
-    arrays = {name: arena.snapshot(name, views[name])
-              for name in _ROW_PLANES}
+    with _span("burst.pack.grid.snapshot"):
+        arrays = {name: arena.snapshot(name, views[name])
+                  for name in _ROW_PLANES}
+        keys_grid = arena.snapshot("keys_grid", views["keys_grid"])
     arrays["u_cq0"] = views["u_cq0"].copy()
     arrays.update(
         potential0=s.potential0, subtree=st.subtree_quota,
@@ -575,7 +577,7 @@ def _materialize(st, state, s, views, scheduler, dirty_cis, prev_token,
         self_lmem=self_lmem)
     plan = _b.BurstPlan(
         structure=st, arrays=arrays,
-        keys=_KeysView(arena.snapshot("keys_grid", views["keys_grid"])),
+        keys=_KeysView(keys_grid),
         C=C, M=M, L=L, G=G, n_levels=s.n_levels, KC=KC,
         seq_base=seq_base, row_of_key=state.row_of_key,
         max_res_ts=max_res_ts,
@@ -1218,27 +1220,30 @@ def pack_burst_streaming(structure, queues, cache, scheduler, clock,
             # changed place, the pads of a CQ that shrank, the rank
             # cells, the row-grade patches
             rows_before = state.n_rows_cq.copy()
-            views, rank_patches, repacked = _patch_grid(
-                st, state, statics, arena, placed, pos_dirty_cis, min_m)
-            row_extent = np.maximum(rows_before, state.n_rows_cq)
+            with _span("burst.pack.grid.patch"):
+                views, rank_patches, repacked = _patch_grid(
+                    st, state, statics, arena, placed, pos_dirty_cis,
+                    min_m)
+                row_extent = np.maximum(rows_before, state.n_rows_cq)
 
-            # row-grade patches (deduped by the journal): single cells.
-            # A job queued before a later row escalated its CQ to dirty is
-            # stale — the re-walk rebuilt the record (and row order), so its
-            # idx no longer addresses the row it was derived from.
-            wset_cis = {rec.ci for rec in walked}
-            row_jobs = [j for j in row_jobs if j[0] not in wset_cis]
-            for ci, idx, parked_now, resume_now, ok_now in row_jobs:
-                rec = state.records[ci]
-                mi = int(state.mi_of[ci][idx])
-                rec.parked[idx] = parked_now
-                rec.resume[idx] = resume_now
-                rec.ok[idx] = ok_now
-                views["parked0"][ci, mi] = parked_now
-                views["elig0"][ci, mi] = (not parked_now
-                                          and not bool(rec.adm[idx]))
-                views["resume0"][ci, mi] = resume_now
-                views["vec_ok"][ci, mi] = ok_now
+                # row-grade patches (deduped by the journal): single
+                # cells.  A job queued before a later row escalated its
+                # CQ to dirty is stale — the re-walk rebuilt the record
+                # (and row order), so its idx no longer addresses the
+                # row it was derived from.
+                wset_cis = {rec.ci for rec in walked}
+                row_jobs = [j for j in row_jobs if j[0] not in wset_cis]
+                for ci, idx, parked_now, resume_now, ok_now in row_jobs:
+                    rec = state.records[ci]
+                    mi = int(state.mi_of[ci][idx])
+                    rec.parked[idx] = parked_now
+                    rec.resume[idx] = resume_now
+                    rec.ok[idx] = ok_now
+                    views["parked0"][ci, mi] = parked_now
+                    views["elig0"][ci, mi] = (not parked_now
+                                              and not bool(rec.adm[idx]))
+                    views["resume0"][ci, mi] = resume_now
+                    views["vec_ok"][ci, mi] = ok_now
             _bump(stats, "pack_row_patches", len(row_jobs))
 
             prev_token = state.token

@@ -150,7 +150,8 @@ class Scheduler:
         start = self.clock()
 
         if heads is None:
-            heads = self.queues.heads_nonblocking()
+            with _span("queue.heads"):
+                heads = self.queues.heads_nonblocking()
         if not heads:
             return stats
         from ..profiling import cycle_step
@@ -160,94 +161,112 @@ class Scheduler:
     def _run_cycle(self, heads: list[Info], stats: CycleStats,
                    start: float) -> CycleStats:
         self._cycle_blocked = self.admission_blocked()
+        recloned = self.cache.snapshot_stats["snap_cqs_recloned"]
         with _span("cycle.snapshot"):
             snapshot = self.cache.snapshot()
+        if self.solver is not None:
+            # counted where the work happens: the queues this cycle's
+            # snapshot cloned again, on a refresh or a full rebuild
+            self.solver.stats["snapshot_cqs_recloned"] += (
+                self.cache.snapshot_stats["snap_cqs_recloned"] - recloned)
         with _span("cycle.nominate"):
-            entries = self.nominate(heads, snapshot)
+            with _span("cycle.nominate.validate"):
+                entries = self.nominate(heads, snapshot)
             device_final = self._maybe_solve_on_device(entries, snapshot)
         if device_final is not None:
             with _span("cycle.admit"):
                 self._admit_device_cycle(device_final, snapshot, stats)
-                for e in entries:
-                    if e.status != EntryStatus.ASSUMED:
-                        self._requeue_and_update(e)
-                        if e.status == EntryStatus.SKIPPED:
-                            stats.skipped.append(e.info.key)
-                        else:
-                            stats.inadmissible.append(e.info.key)
+                self._requeue_unassumed(entries, stats)
             self._rewake_if_gate_opened()
             stats.duration_s = self.clock() - start
             return stats
         with _span("cycle.order"):
             iterator = self._make_iterator(entries, snapshot)
 
-        preempted_workloads: dict[str, Info] = {}
         with _span("cycle.admit"):
-            for e in iterator:
-                cq = snapshot.cq(e.info.cluster_queue)
-                mode = e.assignment.representative_mode()
-                if mode == Mode.NO_FIT:
-                    continue
+            with _span("cycle.admit.apply"):
+                self._admit_host_cycle(iterator, snapshot, stats)
+            self._requeue_unassumed(entries, stats)
+        self._rewake_if_gate_opened()
+        stats.duration_s = self.clock() - start
+        return stats
 
-                if mode == Mode.PREEMPT and not e.preemption_targets:
-                    # reserve capacity so lower-priority entries can't jump ahead
-                    if cq is not None:
-                        usage = self._resources_to_reserve(e, cq)
-                        cq.simulate_usage_addition(usage)  # revert discarded: snapshot-local
-                        self._note_fs_usage(e.info.cluster_queue, usage)
-                    continue
+    def _admit_host_cycle(self, iterator, snapshot: Snapshot,
+                          stats: CycleStats) -> None:
+        """The sequential admit loop over the cycle's order (reference
+        scheduler.go:211-284): reserve, skip, issue preemptions or
+        admit, one entry at a time against the snapshot."""
+        preempted_workloads: dict[str, Info] = {}
+        for e in iterator:
+            cq = snapshot.cq(e.info.cluster_queue)
+            mode = e.assignment.representative_mode()
+            if mode == Mode.NO_FIT:
+                continue
 
-                if any(t.info.key in preempted_workloads
-                       for t in e.preemption_targets):
-                    self._set_skipped(e, "Workload has overlapping preemption "
-                                         "targets with another workload")
-                    if self.metrics is not None:
-                        self.metrics.cycle_preemption_skip()
-                    continue
+            if mode == Mode.PREEMPT and not e.preemption_targets:
+                # reserve capacity so lower-priority entries can't jump ahead
+                if cq is not None:
+                    usage = self._resources_to_reserve(e, cq)
+                    cq.simulate_usage_addition(usage)  # revert discarded: snapshot-local
+                    self._note_fs_usage(e.info.cluster_queue, usage)
+                continue
 
-                usage = e.assignment.usage
-                if not self._fits(cq, usage, preempted_workloads,
-                                  e.preemption_targets):
-                    self._set_skipped(e, "Workload no longer fits after "
-                                         "processing another workload")
-                    continue
-                for t in e.preemption_targets:
-                    preempted_workloads[t.info.key] = t.info
-                cq.simulate_usage_addition(usage)
-                self._note_fs_usage(e.info.cluster_queue, usage)
+            if any(t.info.key in preempted_workloads
+                   for t in e.preemption_targets):
+                self._set_skipped(e, "Workload has overlapping preemption "
+                                     "targets with another workload")
+                if self.metrics is not None:
+                    self.metrics.cycle_preemption_skip()
+                continue
 
-                if e.assignment.representative_mode() == Mode.PREEMPT:
-                    e.info.last_assignment = None  # retry all flavors next time
-                    preempted = self.preemptor.issue_preemptions(
-                        e.info, e.preemption_targets)
-                    if preempted:
-                        e.inadmissible_msg += (f". Pending the preemption of "
-                                               f"{preempted} workload(s)")
-                        e.requeue_reason = RequeueReason.PENDING_PREEMPTION
-                    stats.preempting.append(e.info.key)
-                    stats.preempted_targets.extend(
-                        t.info.key for t in e.preemption_targets)
-                    continue
+            usage = e.assignment.usage
+            if not self._fits(cq, usage, preempted_workloads,
+                              e.preemption_targets):
+                self._set_skipped(e, "Workload no longer fits after "
+                                     "processing another workload")
+                continue
+            for t in e.preemption_targets:
+                preempted_workloads[t.info.key] = t.info
+            cq.simulate_usage_addition(usage)
+            self._note_fs_usage(e.info.cluster_queue, usage)
 
-                if self._cycle_blocked:
-                    # blockAdmission: usage stays consumed for this cycle
-                    # (the reference would wait-then-admit here); the entry
-                    # requeues and the PodsReady transition wakes it
-                    e.inadmissible_msg = ("Waiting for all admitted workloads "
-                                          "to be in the PodsReady condition")
-                    self.gate_parked = True
-                    continue
-                e.status = EntryStatus.NOMINATED
-                if self._admit(e, cq):
-                    stats.admitted.append(e.info.key)
-                    # re-check per admission: the workload just admitted is
-                    # itself not PodsReady yet, so with blockAdmission at
-                    # most one admission lands per cycle (scheduler.go:268
-                    # checks PodsReadyForAllAdmittedWorkloads per entry)
-                    self._cycle_blocked = self.admission_blocked()
-                else:
-                    e.inadmissible_msg = "Failed to admit workload"
+            if e.assignment.representative_mode() == Mode.PREEMPT:
+                e.info.last_assignment = None  # retry all flavors next time
+                preempted = self.preemptor.issue_preemptions(
+                    e.info, e.preemption_targets)
+                if preempted:
+                    e.inadmissible_msg += (f". Pending the preemption of "
+                                           f"{preempted} workload(s)")
+                    e.requeue_reason = RequeueReason.PENDING_PREEMPTION
+                stats.preempting.append(e.info.key)
+                stats.preempted_targets.extend(
+                    t.info.key for t in e.preemption_targets)
+                continue
 
+            if self._cycle_blocked:
+                # blockAdmission: usage stays consumed for this cycle
+                # (the reference would wait-then-admit here); the entry
+                # requeues and the PodsReady transition wakes it
+                e.inadmissible_msg = ("Waiting for all admitted workloads "
+                                      "to be in the PodsReady condition")
+                self.gate_parked = True
+                continue
+            e.status = EntryStatus.NOMINATED
+            if self._admit(e, cq):
+                stats.admitted.append(e.info.key)
+                # re-check per admission: the workload just admitted is
+                # itself not PodsReady yet, so with blockAdmission at
+                # most one admission lands per cycle (scheduler.go:268
+                # checks PodsReadyForAllAdmittedWorkloads per entry)
+                self._cycle_blocked = self.admission_blocked()
+            else:
+                e.inadmissible_msg = "Failed to admit workload"
+
+    def _requeue_unassumed(self, entries: list[Entry],
+                           stats: CycleStats) -> None:
+        """Requeue every head the cycle did not assume and note it as
+        skipped or inadmissible."""
+        with _span("cycle.admit.requeue"):
             for e in entries:
                 if e.status != EntryStatus.ASSUMED:
                     self._requeue_and_update(e)
@@ -255,9 +274,6 @@ class Scheduler:
                         stats.skipped.append(e.info.key)
                     else:
                         stats.inadmissible.append(e.info.key)
-        self._rewake_if_gate_opened()
-        stats.duration_s = self.clock() - start
-        return stats
 
     def _rewake_if_gate_opened(self) -> None:
         """Close the missed-wakeup race on the blockAdmission gate: the
@@ -582,87 +598,89 @@ class Scheduler:
         deferred, cls, handle, assignments_by_wi, targets_by_wi, walked = device
         solver = self.solver
         n = cls.n
-        for wi in range(n):
-            e = deferred[wi]
-            if wi in walked:
-                # scalar head: the host walk already produced the
-                # assignment, message, resume state, and targets
-                continue
-            if cls.fit0[wi]:
-                e.assignment = solver.build_fit_assignment(cls, wi)
-                e.info.last_assignment = e.assignment.last_state
-                e.inadmissible_msg = ""
-            elif wi in assignments_by_wi:
-                e.assignment = assignments_by_wi[wi]
-                e.inadmissible_msg = e.assignment.message()
-                e.info.last_assignment = e.assignment.last_state
-                e.preemption_targets = targets_by_wi[wi]
-            elif handle.rmask[wi]:
-                e.assignment, e.inadmissible_msg = solver.reserve_details(
-                    cls, wi)
-                e.info.last_assignment = e.assignment.last_state
-            else:
-                # NoFit: the host walk produces the exact reasons and
-                # resume state
-                e.inadmissible_msg = ""
-                self._assign_entry(e, snapshot)
-        if handle.route == "accel":
-            # the round trip dwarfs per-head prep: speculatively build the
-            # admission objects for every fit head while the chip works
+        with _span("cycle.admit.prepare"):
             for wi in range(n):
                 e = deferred[wi]
-                if handle.fit_mask[wi]:
-                    cq = snapshot.cq(e.info.cluster_queue)
-                    if cq is not None:
-                        self._prepare_admit(e, cq)
+                if wi in walked:
+                    # scalar head: the host walk already produced the
+                    # assignment, message, resume state, and targets
+                    continue
+                if cls.fit0[wi]:
+                    e.assignment = solver.build_fit_assignment(cls, wi)
+                    e.info.last_assignment = e.assignment.last_state
+                    e.inadmissible_msg = ""
+                elif wi in assignments_by_wi:
+                    e.assignment = assignments_by_wi[wi]
+                    e.inadmissible_msg = e.assignment.message()
+                    e.info.last_assignment = e.assignment.last_state
+                    e.preemption_targets = targets_by_wi[wi]
+                elif handle.rmask[wi]:
+                    e.assignment, e.inadmissible_msg = solver.reserve_details(
+                        cls, wi)
+                    e.info.last_assignment = e.assignment.last_state
+                else:
+                    # NoFit: the host walk produces the exact reasons and
+                    # resume state
+                    e.inadmissible_msg = ""
+                    self._assign_entry(e, snapshot)
+            if handle.route == "accel":
+                # the round trip dwarfs per-head prep: speculatively build the
+                # admission objects for every fit head while the chip works
+                for wi in range(n):
+                    e = deferred[wi]
+                    if handle.fit_mask[wi]:
+                        cq = snapshot.cq(e.info.cluster_queue)
+                        if cq is not None:
+                            self._prepare_admit(e, cq)
 
         with _span("cycle.admit.fetch"):
             final = solver.fetch(handle)
-        for wi in final.order:
-            wi = int(wi)
-            e = deferred[wi]
-            cq = snapshot.cq(e.info.cluster_queue)
-            if final.admitted[wi]:
-                if self._cycle_blocked:
-                    e.inadmissible_msg = (
-                        "Waiting for all admitted workloads to be in the "
-                        "PodsReady condition")
-                    self.gate_parked = True
-                    continue
-                e.status = EntryStatus.NOMINATED
-                if self._admit(e, cq):
-                    stats.admitted.append(e.info.key)
-                    # per-admission re-check (see host loop): at most one
-                    # not-yet-ready admission per cycle under the gate
-                    self._cycle_blocked = self.admission_blocked()
-                else:
-                    e.inadmissible_msg = "Failed to admit workload"
-            elif final.preempting is not None and final.preempting[wi]:
-                # in-scan preemption winner: issue the evictions
-                # (scheduler.go:176-284 preempt branch)
-                e.info.last_assignment = None
-                preempted = self.preemptor.issue_preemptions(
-                    e.info, e.preemption_targets)
-                if preempted:
-                    e.inadmissible_msg += (f". Pending the preemption of "
-                                           f"{preempted} workload(s)")
-                    e.requeue_reason = RequeueReason.PENDING_PREEMPTION
-                stats.preempting.append(e.info.key)
-                stats.preempted_targets.extend(
-                    t.info.key for t in e.preemption_targets)
-            elif final.overlap_skip is not None and final.overlap_skip[wi]:
-                self._set_skipped(e, "Workload has overlapping preemption "
-                                     "targets with another workload")
-                if self.metrics is not None:
-                    self.metrics.cycle_preemption_skip()
-            elif wi in assignments_by_wi:
-                # preempt entry that no longer fits after earlier entries
-                self._set_skipped(e, "Workload no longer fits after "
-                                     "processing another workload")
-            elif handle.fit_mask[wi]:
-                # fit at nominate, lost capacity in-scan (scheduler.go:245)
-                self._set_skipped(e, "Workload no longer fits after "
-                                     "processing another workload")
+        with _span("cycle.admit.apply"):
+            for wi in final.order:
+                wi = int(wi)
+                e = deferred[wi]
+                cq = snapshot.cq(e.info.cluster_queue)
+                if final.admitted[wi]:
+                    if self._cycle_blocked:
+                        e.inadmissible_msg = (
+                            "Waiting for all admitted workloads to be in the "
+                            "PodsReady condition")
+                        self.gate_parked = True
+                        continue
+                    e.status = EntryStatus.NOMINATED
+                    if self._admit(e, cq):
+                        stats.admitted.append(e.info.key)
+                        # per-admission re-check (see host loop): at most one
+                        # not-yet-ready admission per cycle under the gate
+                        self._cycle_blocked = self.admission_blocked()
+                    else:
+                        e.inadmissible_msg = "Failed to admit workload"
+                elif final.preempting is not None and final.preempting[wi]:
+                    # in-scan preemption winner: issue the evictions
+                    # (scheduler.go:176-284 preempt branch)
+                    e.info.last_assignment = None
+                    preempted = self.preemptor.issue_preemptions(
+                        e.info, e.preemption_targets)
+                    if preempted:
+                        e.inadmissible_msg += (f". Pending the preemption of "
+                                               f"{preempted} workload(s)")
+                        e.requeue_reason = RequeueReason.PENDING_PREEMPTION
+                    stats.preempting.append(e.info.key)
+                    stats.preempted_targets.extend(
+                        t.info.key for t in e.preemption_targets)
+                elif final.overlap_skip is not None and final.overlap_skip[wi]:
+                    self._set_skipped(e, "Workload has overlapping preemption "
+                                         "targets with another workload")
+                    if self.metrics is not None:
+                        self.metrics.cycle_preemption_skip()
+                elif wi in assignments_by_wi:
+                    # preempt entry that no longer fits after earlier entries
+                    self._set_skipped(e, "Workload no longer fits after "
+                                         "processing another workload")
+                elif handle.fit_mask[wi]:
+                    # fit at nominate, lost capacity in-scan (scheduler.go:245)
+                    self._set_skipped(e, "Workload no longer fits after "
+                                         "processing another workload")
 
     # ------------------------------------------------------------------
     # Burst application — fused multi-cycle decisions (ops/burst.py)
