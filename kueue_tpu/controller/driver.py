@@ -12,7 +12,9 @@ pure function of (snapshot, heads); everything durable lives here.
 
 from __future__ import annotations
 
+import gc
 import time
+from contextlib import contextmanager
 from dataclasses import dataclass, field
 from typing import Callable, Optional
 
@@ -55,6 +57,12 @@ from ..workload import (
     update_requeue_state,
 )
 from .. import metrics
+
+
+#: What the driver collects itself where a scheduling section closes:
+#: the two young generations.  Their survivors are promoted to the
+#: oldest, which is left to the interpreter's own rule.
+_YOUNG_GENERATION = 1
 
 
 def _unpack_target_rows(words, cand_rows_g):
@@ -838,35 +846,82 @@ class Driver:
     # Run loop
     # ------------------------------------------------------------------
 
+    @contextmanager
+    def _scheduling_section(self):
+        """Hold the cyclic collector off for the length of a scheduling
+        call and collect the young generations once at its end.
+
+        The program's objects die by reference count; an automatic
+        collection in the middle of a cycle finds next to nothing, and a
+        full one walks the whole long-lived heap with the device idle.
+        From the entry of ``schedule_burst`` or ``schedule_once`` to the
+        call's return no automatic collection starts.  At the return the
+        driver reads the young generation's count (the container
+        allocations, less the deallocations, that the section held
+        back), collects the young generations inside a ``host.collect``
+        span and hands the collector back enabled, as it found it.  Full
+        collections stay the interpreter's, by its own rule, and so fall
+        between two calls.
+
+        Sections nest (``schedule_burst`` falls back to
+        ``schedule_once``): an inner one finds the collector held and
+        does nothing.  So does every section of a caller that has
+        disabled the collector itself: that caller owns it, and finds
+        it disabled and uncollected after the call.
+
+        The effect is process-wide.  While a section is open no thread
+        triggers a collection (a service's ingest, the WAL writer, the
+        host pool): cyclic garbage they make goes at the section's
+        exit.  The caller's ``on_cycle_start`` / ``on_cycle`` hooks run
+        inside the section."""
+        if not gc.isenabled():
+            yield
+            return
+        gc.disable()
+        try:
+            yield
+        finally:
+            try:
+                solver = self.scheduler.solver
+                if solver is not None:
+                    solver.stats["collector_deferred_allocations"] += (
+                        gc.get_count()[0])
+                with _span("host.collect"):
+                    gc.collect(_YOUNG_GENERATION)
+            finally:
+                gc.enable()
+
     def schedule_once(self):
-        if _chaos.ACTIVE is not None:
-            _chaos.ACTIVE.crashpoint("cycle.start")
-        if self.wait_for_pods_ready.enable:
-            self.enforce_wait_for_pods_ready()
-        self.queues.wake_expired_backoffs()
-        if self._resume_mask:
-            # complete the WAL-recovered interrupted cycle: CQs whose
-            # decision already replayed are held back (their popped
-            # heads go straight back into the queues), so this cycle's
-            # decisions land exactly where the uncrashed run put them
-            mask, self._resume_mask = self._resume_mask, set()
-            kept = []
-            for info in self.queues.heads_nonblocking():
-                wl = info.obj
-                lq = self.queues.local_queues.get(
-                    f"{wl.namespace}/{wl.queue_name}")
-                if lq is not None and lq.cluster_queue in mask:
-                    self.queues.add_or_update_workload(wl)
-                else:
-                    kept.append(info)
-            stats = self.scheduler.schedule(heads=kept)
-        else:
-            stats = self.scheduler.schedule()
-        self.metrics.admission_attempt(bool(stats.admitted), stats.duration_s)
-        if self._wal is not None:
-            self.host_pool.commit_wal(self._wal)
-        self.obs.record_cycle(stats)
-        return stats
+        with self._scheduling_section():
+            if _chaos.ACTIVE is not None:
+                _chaos.ACTIVE.crashpoint("cycle.start")
+            if self.wait_for_pods_ready.enable:
+                self.enforce_wait_for_pods_ready()
+            self.queues.wake_expired_backoffs()
+            if self._resume_mask:
+                # complete the WAL-recovered interrupted cycle: CQs whose
+                # decision already replayed are held back (their popped
+                # heads go straight back into the queues), so this cycle's
+                # decisions land exactly where the uncrashed run put them
+                mask, self._resume_mask = self._resume_mask, set()
+                kept = []
+                for info in self.queues.heads_nonblocking():
+                    wl = info.obj
+                    lq = self.queues.local_queues.get(
+                        f"{wl.namespace}/{wl.queue_name}")
+                    if lq is not None and lq.cluster_queue in mask:
+                        self.queues.add_or_update_workload(wl)
+                    else:
+                        kept.append(info)
+                stats = self.scheduler.schedule(heads=kept)
+            else:
+                stats = self.scheduler.schedule()
+            self.metrics.admission_attempt(bool(stats.admitted),
+                                           stats.duration_s)
+            if self._wal is not None:
+                self.host_pool.commit_wal(self._wal)
+            self.obs.record_cycle(stats)
+            return stats
 
     def schedule_burst(self, max_cycles: int, runtime: int = 0,
                        external_finishes: Optional[dict] = None,
@@ -902,7 +957,7 @@ class Driver:
         to pipeline-off by construction.
 
         Returns the list of per-cycle CycleStats actually applied."""
-        with _span("burst"):
+        with self._scheduling_section(), _span("burst"):
             return self._schedule_burst(max_cycles, runtime,
                                         external_finishes, on_cycle,
                                         on_cycle_start, pipeline)

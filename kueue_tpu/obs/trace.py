@@ -109,6 +109,8 @@ HOT_PATH_PHASES = (
     "burst.apply",      # host apply of one modeled burst cycle
     "burst.callbacks",  # the caller's on_cycle_start / on_cycle hooks
     "boundary",         # finish_workloads: quota release, removals, wake-up
+    "host.collect",     # the driver's own collection of the young
+                        # generations where a scheduling section closes
     "host.gc",          # one collection of the cyclic collector, nested
                         # in whatever span was open (gc.callbacks)
     "wal.append",       # one journal op append

@@ -1928,6 +1928,9 @@ class BurstSolver:
                       # to own (the arena's count, carried over by the
                       # streaming pack's _materialize)
                       "pack_arena_snapshot_bytes": 0,
+                      # of those copies, the ones that had to allocate:
+                      # the kept buffer was still somebody's
+                      "pack_arena_snapshots_fresh": 0,
                       # graceful degradation (chaos shard.device_loss or
                       # lose_devices): mesh rebuilt over the survivors,
                       # serial fallback when fewer than two remain

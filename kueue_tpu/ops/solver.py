@@ -180,6 +180,10 @@ class CycleSolver:
             "snapshot_cqs_recloned": 0,  # queues the cycles' cache
                                          # snapshots cloned again (the
                                          # scheduler counts them)
+            "collector_deferred_allocations": 0,  # the young generation's
+                                         # count where the driver's
+                                         # scheduling sections closed,
+                                         # summed (controller/driver.py)
             "scalar_heads": 0,        # heads classified by the host walk
             # flavor-walk telemetry (heterogeneous fast path):
             "scalar_reasons": {},     # {reason: count} for scalar heads

@@ -43,7 +43,7 @@ BOOK_PHASES = (
     "cycle.admit.prepare", "cycle.admit.fetch", "cycle.admit.apply",
     "cycle.admit.requeue",
     "burst.pack.grid.patch", "burst.pack.grid.snapshot",
-    "burst", "burst.callbacks", "boundary", "host.gc",
+    "burst", "burst.callbacks", "boundary", "host.collect", "host.gc",
 )
 
 
@@ -113,7 +113,7 @@ def test_book_phase_is_listed_entered_and_parented(phase, traced_toy):
     assert parents, f"{phase}: never entered"
     if phase == "host.gc":
         return                      # nested in whatever was open
-    if phase in ("burst", "boundary"):
+    if phase in ("burst", "boundary", "host.collect"):
         assert parents == {""}
     elif phase == "queue.heads":
         assert parents == {"burst"}     # schedule_once: top level
@@ -157,6 +157,30 @@ def test_burst_is_the_parent_of_its_cycles(traced_toy):
                 if r.name == name} == {"burst"}
 
 
+def test_the_drivers_collection_sits_beside_burst_and_not_in_it(traced_toy):
+    """One ``host.collect`` a ``schedule_burst`` call, opened after the
+    call's ``burst`` span closed, so ``burst.self`` holds none of it;
+    and while a ``burst`` is open the collector does not run at all."""
+    _, tracer, _, _ = traced_toy
+    recs = tracer.trace_spans
+    bursts = [r for r in recs if r.name == "burst"]
+    collects = [r for r in recs if r.name == "host.collect"]
+    assert len(bursts) == len(collects) == 3
+    for b, c, nxt in zip(bursts, collects, bursts[1:] + [None]):
+        assert (c.parent, c.depth) == ("", 0)
+        assert b.t0 + b.dur <= c.t0
+        assert nxt is None or c.t0 + c.dur <= nxt.t0
+    gcs = [r for r in recs if r.name == "host.gc"]
+    assert [r for r in gcs if any(b.t0 <= r.t0 < b.t0 + b.dur
+                                  for b in bursts)] == []
+    assert sum(1 for r in gcs if r.parent == "host.collect") == 3
+    roster = tracer.roster()
+    assert roster["host.collect"]["count"] == 3
+    # the driver's collection had a child, so its own code is a series
+    assert roster["host.collect" + SELF_SUFFIX]["total_s"] <= (
+        roster["host.collect"]["total_s"])
+
+
 def test_flight_recorder_finds_the_burst_span_a_cycle_later(traced_toy):
     """``burst`` closes after the call's last cycle was recorded, so
     its record is drained with the first cycle of the next call (or
@@ -190,10 +214,12 @@ def test_tracing_changes_no_decision_and_no_stats(traced_toy):
     def counts(stats):
         # not the hand timers, and not whether a snapshot's buffer could
         # be written over: that hangs on when the collector freed the
-        # last plan, which is the allocations' timing and no decision
+        # last plan, which is the allocations' timing and no decision,
+        # as is the young generation's count where a section closed
         return {k: v for k, v in stats.items()
                 if not k.endswith(("_s", "_ms"))
-                and not k.startswith("pack_arena_snapshots_")}
+                and not k.startswith("pack_arena_snapshots_")
+                and k != "collector_deferred_allocations"}
     assert counts(dt.scheduler.solver.stats) == \
         counts(dc.scheduler.solver.stats)
     assert counts(rounds_t[-1]) == counts(rounds_c[-1])
