@@ -77,6 +77,7 @@ HOT_PATH_PHASES = (
     "cycle.nominate.validate",        # the per-head loop that builds Entries
     "cycle.nominate.classify",        # cycle pack + device classification
     "cycle.nominate.classify.eligibility",  # the heads' [W, G, S] flavor plane
+    "cycle.nominate.classify.podsets",  # a pass a PodSet of every head
     "cycle.nominate.classify.groups",  # one flavor walk a group, joined
     "cycle.nominate.walk",            # host FlavorAssigner walks
     "cycle.nominate.oracle",          # the reclaim oracle's batched searches

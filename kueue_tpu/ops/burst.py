@@ -16,8 +16,10 @@ Semantics reproduced per fused cycle, bit-matching the host scheduler:
    static within a burst because priorities/timestamps never change
    without an external event, and external events end the burst.
 2. **Classify** (flavorassigner.go:499): the vectorized nominate of
-   ops.cycle.classify_np (``walk_groups``: one flavor walk a resource
-   group of the head's queue, joined), evaluated dense over [C, S, R].
+   ops.cycle.classify_np (``walk_groups``: one pass a PodSet of the
+   head, each charged with the earlier ones' choices, in each pass one
+   flavor walk a resource group of the head's queue, joined), evaluated
+   dense over [C, S, R].
 3. **Cycle order** (scheduler.go:567 entryOrdering): borrows asc, then a
    host-precomputed (priority desc, timestamp asc, heads-position) rank.
 4. **Admit scan** (scheduler.go:211-284): forest-parallel — one head per
@@ -40,9 +42,14 @@ Anything the fused math can't decide bit-identically makes the cycle
 walk neither policy-stopped on the preempt slot nor left it as the only
 preempt-capable choice — the host's pick then depends on the reclaim
 oracle), or a head outside the vectorized classify's coverage
-(multi-PodSet / TAS / partial admission — ``vec_ok`` False).  Node
+(more PodSets than the planes hold, a topology request, partial
+admission — ``vec_ok`` False).  A Workload of several PodSets stays
+inside it: a row carries a request, a resume slot and a mask a PodSet
+(``wl_req [C, M, P*R]``, ``resume0`` and ``wl_flavor_skip
+[C, M, P*G]``, PodSet-major; P is ``PackedStructure.pod_sets``).  Node
 labels, taints, selectors and tolerations stay inside it: each row
-carries the flavors its PodSet may not take (``wl_flavor_skip``).
+carries the flavors each of its PodSets may not take
+(``wl_flavor_skip``).
 FlavorFungibility itself runs in-kernel: the classify step walks each
 head's flavor list from its carried resume start slot with the
 whenCanBorrow/whenCanPreempt stop rules and records the next start slot
@@ -80,6 +87,7 @@ from ..obs.trace import span as _span
 from .device import on_accelerator, output_devices, solver_device
 from .eligibility import (declares, mask_plane_width, skip_mask,
                           slots_of_mask)
+from .packing import MAX_POD_SETS
 
 INF_I32 = np.int32(2**31 - 1)
 I32_MAX = 2**31 - 1
@@ -110,20 +118,23 @@ DIRTY_RESUME = 4       # head with fungibility resume state
 
 def _burst_cycles(
     # dense workload state [C, M, ...] — pending AND admitted rows
-    wl_req,          # [C, M, R] int32 scaled requests
+    wl_req,          # [C, M, P*R] int32 scaled requests a PodSet,
+                     #              PodSet-major (P = 1: [C, M, R])
     wl_rank,         # [C, M] int32 heap rank (INF_I32 = empty slot)
     wl_cycle_rank,   # [C, M] int32 global (priority, ts, pos) rank
     wl_prio,         # [C, M] int32 priority
     wl_uidrank,      # [C, M] int32 global uid rank (candidate tiebreak)
     vec_ok,          # [C, M] bool  vectorized-classify coverage
-    wl_flavor_skip,  # [C, M, RG] uint8 bit s: the row's PodSet may not
-                     #              take slot s of resource group g of its
-                     #              queue (ops/eligibility.py); [C, 1, RG]
-                     #              zeros where every flavor is plain
+    wl_flavor_skip,  # [C, M, P*RG] uint8 bit s: the row's PodSet p may
+                     #              not take slot s of resource group g of
+                     #              its queue (ops/eligibility.py);
+                     #              [C, 1, P*RG] zeros where every flavor is
+                     #              plain
     elig0,           # [C, M] bool  in the heap at burst start
     parked0,         # [C, M] bool  in the inadmissible lot at burst start
-    resume0,         # [C, M, RG] int32 flavor-walk start slot a group
-                     #              (fungibility resume state; 0 = full walk)
+    resume0,         # [C, M, P*RG] int32 flavor-walk start slot a
+                     #              (PodSet, group) (fungibility resume
+                     #              state; 0 = full walk)
     # admitted-row state (rows holding quota at burst start)
     adm0,            # [C, M] bool
     adm_seq0,        # [C, M] int32 reservation-time dense rank (ties ==)
@@ -166,13 +177,13 @@ def _burst_cycles(
 ):
     """Run K fused admission cycles with in-kernel preemption.
 
-    Returns per-cycle (head_row[K,C], kind[K,C], slot[K,C,RG],
-    tried[K,C,RG], borrows[K,C], tgt_words[K,C,KC//32] uint32, dirty[K],
+    Returns per-cycle (head_row[K,C], kind[K,C], slot[K,C,P*RG],
+    tried[K,C,P*RG], borrows[K,C], tgt_words[K,C,KC//32] uint32, dirty[K],
     dirty_reason[K]) plus the final carry.  ``slot`` is the slot each
-    resource group's walk chose (fit slots for admit/skip kinds; for
-    preempt kinds the preempt slots of the groups short of quota beside
-    the fit slots of the others) and ``tried`` the resume state each
-    walk records.  ``tgt_words`` is the bit-packed
+    walk (one a PodSet and resource group, PodSet-major) chose (fit
+    slots for admit/skip kinds; for preempt kinds the preempt slots of
+    the walks short of quota beside the fit slots of the others) and
+    ``tried`` the resume state each walk records.  ``tgt_words`` is the bit-packed
     candidate-slot mask of each preempting head's targets (indices into
     cand_rows[forest_of_cq[c]]).
 
@@ -205,7 +216,10 @@ def _burst_cycles(
     cand_rows = cand_rows.astype(jnp.int32)
     cand_lmem = cand_lmem.astype(jnp.int32)
     self_lmem = self_lmem.astype(jnp.int32)
-    C, M, R = wl_req.shape
+    C, M, PR = wl_req.shape
+    R = slot_fr.shape[2]
+    P = PR // R
+    RG = slot_valid.shape[1]
     N, F = subtree.shape
     CM = C * M
     KCW = KC // 32
@@ -280,20 +294,25 @@ def _burst_cycles(
         key = jnp.where(elig, wl_rank, INF_I32)
         row = jnp.argmin(key, axis=1).astype(jnp.int32)        # [C]
         has_head = key[cidx, row] < INF_I32
-        req = wl_req[cidx, row]                                # [C, R]
+        req = wl_req[cidx, row]                                # [C, P*R]
         prio_head = wl_prio[cidx, row]
 
-        # -- classify: one flavor walk a resource group, joined -------
-        # a slot the head may not take for a taint or a selector is
+        # -- classify: one pass a PodSet, one flavor walk a resource
+        # group in each, joined --------------------------------------
+        # a slot the PodSet may not take for a taint or a selector is
         # visited and passed over, like one whose flavor does not exist;
         # each walk scans its group's list from the carried resume
-        # start under the whenCanBorrow/whenCanPreempt stop rules
+        # start under the whenCanBorrow/whenCanPreempt stop rules, at
+        # the PodSet's request and what the earlier PodSets chose
         skip = wl_flavor_skip[
-            cidx, jnp.minimum(row, wl_flavor_skip.shape[1] - 1)]  # [C,RG]
+            cidx, jnp.minimum(row, wl_flavor_skip.shape[1] - 1)]
         w = walk_groups(
-            jnp, req=req, frs=slot_fr, grp=res_group, slot_ok=slot_valid,
-            eligible=slots_of_mask(skip, S, jnp), slot_count=slot_cnt,
-            start=resume[cidx, row], av=avail[:C][slot_at],
+            jnp, req=req.reshape(C, P, R), frs=slot_fr, grp=res_group,
+            slot_ok=slot_valid,
+            eligible=slots_of_mask(skip.reshape(C, P, RG), S, jnp),
+            slot_count=slot_cnt,
+            start=resume[cidx, row].reshape(C, P, RG),
+            av=avail[:C][slot_at],
             pot=potential0[:C][slot_at], nom=nominal_cq[slot_at],
             use=usage[:C][slot_at], sq=subtree[:C][slot_at],
             can_preempt_borrow=cq_can_preempt_borrow,
@@ -302,26 +321,36 @@ def _burst_cycles(
         has_fit = w["has_fit"]
         has_preempt = w["has_preempt"]
         borrows = w["borrows"] & has_fit
-        res_fr = w["res_fr"]       # [C, R] each resource on its group's slot
-        # the resume state the host records for these walks: a group's
-        # stop slot when it stopped mid-list, else -1
-        tried_c = w["tried"]                                   # [C, RG]
+        # each (PodSet, resource) on the slot its group's walk chose
+        res_fr = w["res_fr"].reshape(C, PR)
+        # the resume state the host records for these walks: a walk's
+        # stop slot when it stopped mid-list, else -1.  Of a NoFit head
+        # it holds the PodSets before the one that found no flavor
+        # (assignFlavors returns there, with what it had appended):
+        # nothing of a one-PodSet head
+        nofit_c = has_head & ~has_fit & ~has_preempt
+        tried_c = jnp.where(
+            nofit_c[:, None, None] & ~w["recorded"][:, :, None], -1,
+            w["tried"]).reshape(C, P * RG)
         pending_c = jnp.any(tried_c >= 0, axis=1)
 
-        # -- preempt head facts on the chosen slots -------------------
+        # -- the head's facts on the chosen slots ---------------------
+        # ``wu``: the assignment's usage, a flavor-resource the sum of
+        # the PodSets on it; ``frs_need``: the pairs short of quota
         p_borrows = w["borrows"] & has_preempt
         prel = (res_fr >= 0) & (req > 0)
         pfrs_s = jnp.maximum(res_fr, 0)
         frs_need = jnp.zeros((C, F), dtype=bool).at[
-            cidx[:, None], pfrs_s].max(prel & ~w["res_fit"])   # [C, F]
+            cidx[:, None], pfrs_s].max(
+            prel & ~w["res_fit"].reshape(C, PR))               # [C, F]
         wu = jnp.zeros((C, F), dtype=jnp.int32).at[
             cidx[:, None], pfrs_s].add(jnp.where(prel, req, 0))
-        # the modeled envelope: no group's preempt choice may depend on
+        # the modeled envelope: no walk's preempt choice may depend on
         # the reclaim oracle (ops/cycle.py walk_groups) — a
         # policy-stopped walk is final, and a single preempt-capable
         # slot leaves the best-mode pick no freedom either
         pre_model = (has_preempt & preempt_ok
-                     & ~jnp.any(w["oracle_groups"], axis=1))
+                     & ~jnp.any(w["oracle_groups"], axis=(1, 2)))
 
         dirty_c = has_head & ((has_preempt & ~pre_model)
                               | ~vec_ok[cidx, row])
@@ -580,20 +609,14 @@ def _burst_cycles(
                                              guaranteed, borrow_cap,
                                              has_blim, parent, cq_s,
                                              depth)
-                    # fit entry: fixed-slot re-check
-                    frs_l = res_fr[cq_s]                       # [R]
-                    amt_l = req[cq_s]
-                    frs_ls = jnp.maximum(frs_l, 0)
-                    rel_l = (frs_l >= 0) & (amt_l > 0)
-                    fit_ok = jnp.all(jnp.where(
-                        rel_l, amt_l <= avail_row[frs_ls], True))
-                    admit = (cq >= 0) & has_fit[cq_s] & fit_ok
-                    delta = jnp.zeros(F, dtype=jnp.int32).at[frs_ls].add(
-                        jnp.where(rel_l & admit, amt_l, 0))
-                    # preempting entry: fits after its targets removed
+                    # fit entry: the fixed slots' usage re-checked (Fits
+                    # over assignment.Usage, scheduler.go:372); a
+                    # preempting entry: the same after its targets went
                     wuc = wu[cq_s]
                     pre_ok = jnp.all(jnp.where(wuc > 0,
                                                wuc <= avail_row, True))
+                    admit = (cq >= 0) & has_fit[cq_s] & pre_ok
+                    delta = jnp.where(admit, wuc, 0)
                     pre_now = is_act & pre_ok
                     delta = delta + jnp.where(pre_now, wuc, 0)
                     # reserve entry (resourcesToReserve, scheduler:383)
@@ -658,19 +681,16 @@ def _burst_cycles(
 
         # -- end-of-cycle state transitions ---------------------------
         # admit delta per admitted head (committed usage)
-        arel = prel & admitted_c[:, None]
-        afrs_s = pfrs_s                                        # [C, R]
-        adm_delta = jnp.zeros((C, F), dtype=jnp.int32).at[
-            cidx[:, None], afrs_s].add(jnp.where(arel, req, 0))
-        adm_uses_new = jnp.zeros((C, F), dtype=bool).at[
-            cidx[:, None], afrs_s].max(arel)
+        adm_delta = jnp.where(admitted_c[:, None], wu, 0)
+        adm_uses_new = adm_delta > 0
 
         skipped = has_fit & ~admitted_c            # stays eligible
         # a reserve head whose walk stopped mid-list keeps pending
         # flavors: the host requeues it immediately (cluster_queue.py
         # _requeue_if_not_present) so it stays eligible, not parked
-        park_new = ((has_head & ~has_fit & ~has_preempt & ~dirty_c)
-                    | (reserve_c & ~pending_c)) & ~strict_cq
+        # ... and so does a NoFit gang whose earlier PodSet did
+        park_new = ((nofit_c & ~dirty_c) | reserve_c) & ~pending_c \
+            & ~strict_cq
         gone = admitted_c | park_new
         elig = elig.at[cidx, row].set(
             jnp.where(gone, False, elig[cidx, row]))
@@ -682,7 +702,7 @@ def _burst_cycles(
         # everything else — admit (a later eviction requeues a FRESH
         # Info), park, preempt issued, strict NoFit — resets to 0
         keep_resume = (skipped | (reserve_c & pending_c) | overlap_c
-                       | pre_nofit_c)
+                       | pre_nofit_c | (nofit_c & pending_c & ~dirty_c))
         head_start = jnp.where(keep_resume[:, None], tried_c + 1, 0)
         resume = resume.at[cidx, row].set(
             jnp.where(has_head[:, None], head_start, resume[cidx, row]))
@@ -740,7 +760,7 @@ def _burst_cycles(
         kind = jnp.where(overlap_c, KIND_OVERLAP_SKIP, kind)
         kind = jnp.where(pre_nofit_c, KIND_PRE_NOFIT, kind)
         slot_out = jnp.where((has_fit | pre_model)[:, None],
-                             w["chosen"], -1)                  # [C, RG]
+                             w["chosen"].reshape(C, P * RG), -1)
         borrows_out = jnp.where(has_fit, borrows, p_borrows)
         tgt_commit = tgt0 & preempting_c[:, None]              # [C, KC]
         tgt_words = jnp.sum(
@@ -861,21 +881,26 @@ def build_candidate_tables(forest_of_cq: np.ndarray, members: np.ndarray,
 
 
 def _static_row(info, st, covers_pods: bool, qts):
-    """Per-Info static pack facts: (covers_pods, scaled request vector,
-    static vectorized-eligibility, queue-order ts, priority, uid).
+    """Per-Info static pack facts: (covers_pods, scaled request vectors
+    [PodSets, R], static vectorized-eligibility, queue-order ts,
+    priority, uid).  A multi-PodSet row is ``vec_ok`` up to
+    ``MAX_POD_SETS`` PodSets; what is not: more PodSets than that, a
+    PodSet with a topology request, a partial admission (``min_count``
+    under ``count``), a request that does not scale exactly.
     Cached on the Info keyed by the structure generation — requests,
     conditions, and priority are immutable per Info instance (updates
     build a fresh Info — queue/manager.py add_or_update_workload)."""
     R = len(st.resource_names)
     scale = st.resource_scale
     obj = info.obj
-    ok = (len(obj.pod_sets) == 1
-          and obj.pod_sets[0].topology_request is None
-          and not any(ps.min_count is not None and ps.min_count < ps.count
+    ok = (1 <= len(obj.pod_sets) <= MAX_POD_SETS
+          and not any(ps.topology_request is not None
+                      or (ps.min_count is not None
+                          and ps.min_count < ps.count)
                       for ps in obj.pod_sets))
     exact = True
-    acc = np.zeros(R, dtype=np.int64)
-    for psr in info.total_requests:
+    acc = np.zeros((max(1, len(info.total_requests)), R), dtype=np.int64)
+    for pi, psr in enumerate(info.total_requests):
         for r, v in psr.requests.items():
             if r == "pods" and not covers_pods:
                 continue
@@ -887,17 +912,17 @@ def _static_row(info, st, covers_pods: bool, qts):
                 exact = False
                 v = 0
             if st.scale_is_one:
-                acc[ri] += int(v)
+                acc[pi, ri] += int(v)
             else:
                 s = int(scale[ri])
                 q_, rem = divmod(int(v), s)
                 if rem:
                     exact = False
                     q_ += 1
-                acc[ri] += q_
-    if acc.max(initial=0) > I32_MAX:
+                acc[pi, ri] += q_
+    if acc.sum(axis=0).max(initial=0) > I32_MAX:
         exact = False
-        np.clip(acc, None, I32_MAX, out=acc)
+        np.clip(acc, None, I32_MAX // len(acc), out=acc)
     return (covers_pods, acc.astype(np.int32), ok and exact,
             qts(obj), obj.priority, obj.uid)
 
@@ -1132,7 +1157,7 @@ class _RowWalk:
     row, for the full walk of a queue and for the rows a dirty queue's
     record takes in; ``fill`` turns what was derived into a record's
     arrays."""
-    __slots__ = ("st", "ci", "cq_live", "covers_pods", "cq_vec",
+    __slots__ = ("st", "ci", "P", "cq_live", "covers_pods", "cq_vec",
                  "lr_summaries", "assumed", "scale_of", "compress", "qts",
                  "resume_start", "failed_check",
                  "bad_keys", "comp_ts", "comp_max_ts", "n",
@@ -1144,6 +1169,10 @@ class _RowWalk:
                  compress, n_upper, bad_keys, comp_ts, comp_max_ts):
         self.st = st
         self.ci = ci
+        # the planes' PodSet extent as this walk found it: a row with
+        # more PodSets raises the structure's, and whoever walks packs
+        # again at the new extent (_walk_records, pack_burst_streaming)
+        self.P = st.pod_sets
         self.cq_live = cq_live
         cq_name = st.cq_names[ci]
         self.covers_pods = cq_name in st.cq_covers_pods
@@ -1177,7 +1206,8 @@ class _RowWalk:
                                          # (0 = full)
         self.infos: list = []
         F = st.n_frs
-        self.req_mat = np.zeros((n_upper, len(st.resource_names)),
+        self.req_mat = np.zeros((n_upper,
+                                 self.P * len(st.resource_names)),
                                 dtype=np.int32)
         self.usage_mat = np.zeros((n_upper, F), dtype=np.int32)
         self.uses_mat = np.zeros((n_upper, F), dtype=bool)
@@ -1190,6 +1220,17 @@ class _RowWalk:
                    *_static_row(info, self.st, self.covers_pods, self.qts))
             info._burst_row = row
         return row
+
+    def _lay(self, req) -> bool:
+        """Row ``self.n``'s requests, a PodSet after another; False,
+        and their sum in the first PodSet's place, where the row has
+        more PodSets than the planes hold (the row is not ``vec_ok``
+        then, and its request is read by nobody)."""
+        self.st.note_pod_sets(len(req))
+        fits = len(req) <= self.P
+        flat = (req if fits else req.sum(axis=0)).reshape(-1)
+        self.req_mat[self.n, :len(flat)] = flat
+        return fits
 
     def _gated(self, obj) -> bool:
         """The dynamic gates a row's ``vec_ok`` shares between pending
@@ -1212,7 +1253,7 @@ class _RowWalk:
                     or self._gated(obj)):
                 ok = False
         return ok, self.resume_start(info, self.cq_live, self.covers_pods,
-                                     self.st.n_groups)
+                                     self.st.n_groups, self.P)
 
     def pending(self, info, parked: bool) -> None:
         _, _, req_vec, static_ok, ts, prio, uid = self._static(info)
@@ -1222,7 +1263,7 @@ class _RowWalk:
         self.ts_l.append(ts)
         self.res_ts_l.append(0.0)
         self.parked_l.append(parked)
-        self.req_mat[self.n] = req_vec
+        static_ok = self._lay(req_vec) and static_ok
         ok, resume = self.moving(info, static_ok)
         self.ok_l.append(ok)
         self.resume_l.append(resume)
@@ -1269,7 +1310,7 @@ class _RowWalk:
         self.res_ts_l.append(cond.last_transition_time)
         self.parked_l.append(False)
         i = self.n
-        self.req_mat[i] = req_vec
+        static_ok = self._lay(req_vec) and static_ok
         self.usage_mat[i], self.uses_mat[i] = uv
         # post-eviction afterlife: the same dynamic gates pending
         # rows get (LimitRange bounds, failed admission checks) —
@@ -1278,7 +1319,7 @@ class _RowWalk:
         # (the cycle goes dirty), gating less diverges decisions
         self.ok_l.append(self.cq_vec and static_ok
                          and not self._gated(obj))
-        self.resume_l.append((0,) * self.st.n_groups)
+        self.resume_l.append((0,) * (self.P * self.st.n_groups))
         self.infos.append(info)
         self.n += 1
 
@@ -1299,11 +1340,11 @@ class _RowWalk:
             # the flavors a row's PodSet may not take, a group: all
             # zero, and nothing to ask a row, unless a flavor of this
             # queue carries labels or taints
-            G = st.n_groups
+            G = self.P * st.n_groups
             skip = (np.array([skip_mask(info, st, self.ci)
                               for info in self.infos],
                              dtype=np.uint8).reshape(i, G)
-                    if declares(st, self.ci)
+                    if declares(st, self.ci) and st.pod_sets == self.P
                     else np.zeros((i, G), dtype=np.uint8))
             derived = (
                 np.asarray(self.key_l) if i else np.empty(0, dtype="U1"),
@@ -1462,17 +1503,23 @@ def _walk_records(st, queues, cache, scheduler, window):
     from .aggregate import agg_planes_enabled
     s = _pack_statics(st, cache)
     comp_cq = s.comp_cq if agg_planes_enabled() else None
-    records = []
-    for ci in range(C):
-        rec = _pack_cq_rows(st, ci, pos_of.get(st.cq_names[ci], C),
-                            queues, cache, scheduler, assumed,
-                            scale_of, window,
-                            compress=(comp_cq is not None
-                                      and bool(comp_cq[ci])))
-        if rec is _PACK_FAIL:
-            return None
-        records.append(rec)
-    return records
+    while True:
+        pod_sets = st.pod_sets
+        records = []
+        for ci in range(C):
+            rec = _pack_cq_rows(st, ci, pos_of.get(st.cq_names[ci], C),
+                                queues, cache, scheduler, assumed,
+                                scale_of, window,
+                                compress=(comp_cq is not None
+                                          and bool(comp_cq[ci])))
+            if rec is _PACK_FAIL:
+                return None
+            records.append(rec)
+        if st.pod_sets == pod_sets:
+            return records
+        # a row had more PodSets than the planes held: they hold more
+        # now (PackedStructure.note_pod_sets), and the rows are laid out
+        # again at that extent
 
 
 _ROW_ATTRS = ("adm", "prio", "ts", "res_ts", "parked", "ok",
@@ -1531,13 +1578,14 @@ def _assemble_plan(st, records, cache, scheduler, min_m):
     strict = np.fromiter((r.strict for r in records), dtype=bool,
                          count=C)
 
-    wl_req = np.zeros((C, M, R), dtype=np.int32)
+    P = st.pod_sets
+    wl_req = np.zeros((C, M, P * R), dtype=np.int32)
     wl_rank = np.full((C, M), INF_I32, dtype=np.int32)
     wl_cycle_rank = np.zeros((C, M), dtype=np.int32)
     wl_prio = np.zeros((C, M), dtype=np.int32)
     wl_uidrank = np.zeros((C, M), dtype=np.int32)
     vec_ok = np.zeros((C, M), dtype=bool)
-    RG = st.n_groups
+    RG = P * st.n_groups
     wl_flavor_skip = np.zeros((C, mask_plane_width(st, M), RG),
                               dtype=np.uint8)
     elig = np.zeros((C, M), dtype=bool)
@@ -1715,7 +1763,7 @@ def pack_burst(structure, queues, cache, scheduler, clock,
     return _assemble_plan(st, records, cache, scheduler, min_m)
 
 
-def _roundtrips_clean(rec, q, cq_live, keys, covers_pods) -> bool:
+def _roundtrips_clean(rec, q, cq_live, keys, covers_pods, st) -> bool:
     """Verify that popped-and-requeued heads still match their packed
     rows: same Info object, same parked bit, same flavor-walk start
     slots.  These are the only row facts a pop/requeue roundtrip can
@@ -1745,7 +1793,7 @@ def _roundtrips_clean(rec, q, cq_live, keys, covers_pods) -> bool:
         if bool(rec.parked[idx]) != parked_now:
             return False
         if tuple(rec.resume[idx].tolist()) != resume_starts(
-                info, cq_live, covers_pods, rec.resume.shape[1]):
+                info, cq_live, covers_pods, st.n_groups, st.pod_sets):
             return False
     return True
 
@@ -2676,8 +2724,8 @@ class BurstSolver:
     def fetch(self, handle: BurstHandle):
         """Block for a dispatched window's decisions.  Returns the numpy
         tuple (head_row, kind, slot, tried, borrows, tgt_words, dirty,
-        dirty_reason) and parks the final carry on the handle for
-        ``dispatch_next``."""
+        dirty_reason), ``slot`` and ``tried`` [K, C, P, RG], and parks
+        the final carry on the handle for ``dispatch_next``."""
         import jax
         import time as _time
         if handle.decisions is not None:
@@ -2708,6 +2756,12 @@ class BurstSolver:
                 + [dec[6], dec[7]])
         else:
             handle.decisions = tuple(jax.device_get(out[:-1]))
+        # slot and tried come back flat, PodSet-major: [K, C, P, RG]
+        dec = list(handle.decisions)
+        for i in (2, 3):
+            dec[i] = dec[i].reshape(
+                dec[i].shape[:2] + (-1, handle.plan.structure.n_groups))
+        handle.decisions = tuple(dec)
         handle.pending = None
         # per-forest cycle-cost sample for the next layout's LPT
         self._note_forest_activity(handle.plan, handle.decisions[0])

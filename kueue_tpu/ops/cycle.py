@@ -57,8 +57,9 @@ def available_all_np(usage, subtree, guaranteed, borrow_cap, has_blim,
 def walk_groups(xp, *, req, frs, grp, slot_ok, eligible, slot_count, start,
                 av, pot, nom, use, sq, can_preempt_borrow, has_parent,
                 wcb, wcp, valid):
-    """The flavor walk of B heads, one walk a resource group, and the
-    join of a head's groups: the one statement of
+    """The flavor walk of B heads, one pass a PodSet in the Workload's
+    order, in each pass one walk a resource group, and the join of a
+    head's PodSets and groups: the one statement of assignFlavors and
     findFlavorForPodSetResource (flavorassigner.go:499) for the device
     path, in numpy (``classify_np``) or ``xp=jax.numpy`` (the jitted
     ``solve_cycle`` and the fused window, ops/burst.py).
@@ -66,174 +67,243 @@ def walk_groups(xp, *, req, frs, grp, slot_ok, eligible, slot_count, start,
     A resource belongs to one group of its queue (``grp`` [B, R], -1:
     none covers it), and ``frs`` [B, S, R] names flavor s *of that
     group* for resource r, so every plane a (slot, resource) is a
-    group's already; the walk reduces it over each group's own
+    group's already; a pass reduces it over each group's own
     resources to planes [B, G, S] and runs G walks side by side: from
-    the group's own start slot (``start`` [B, G]), over the group's own
-    flavors (``slot_ok``, ``slot_count``), passing over what the head
-    may not take in that group (``eligible`` [B, G, S]), under the
-    queue's stop rules.  A group none of whose resources the head
-    requests is not walked.  The head is as good as its worst walked
-    group (NoFit in one is NoFit; a requested resource no group covers
-    is NoFit), borrows if any does, and takes one slot a group.
+    the group's own start slot (``start`` [B, P, G]), over the group's
+    own flavors (``slot_ok``, ``slot_count``), passing over what the
+    PodSet may not take in that group (``eligible`` [B, P, G, S]),
+    under the queue's stop rules.  A group none of whose resources the
+    PodSet requests is not walked.
+
+    PodSet p (``req`` [B, P, R]: its total request; all 0 where the
+    head has fewer) is tested at ``val = req[p] + acc``, where ``acc``
+    [B, S, R] is what the head's earlier PodSets chose on that (slot,
+    resource): the usage the host's assignment carries from PodSet to
+    PodSet.  A PodSet is as good as its worst walked group (a requested
+    resource no group covers is NoFit) and a NoFit PodSet ends the
+    head's walk; the head is as good as its worst PodSet, borrows if
+    any walk does, and takes one slot a (PodSet, group).  P = 1 is one
+    pass at ``val = req``.
 
     ``av`` / ``pot`` / ``nom`` / ``use`` / ``sq`` [B, S, R] are the
     head's queue's available, potential, nominal, usage and subtree
     quota at ``frs``.  Returns a dict; see ``classify_np``."""
     B, S, R = frs.shape
     G = slot_ok.shape[1]
-    req = req[:, None, :]                                   # [B,1,R]
+    P = req.shape[1]
     covered = frs >= 0
-    needed = req > 0
     in_g = grp[:, None, :] == xp.arange(G, dtype=grp.dtype)[None, :, None]
     sel = in_g[:, :, None, :]                               # [B,G,1,R]
-    uncovered = xp.any(needed[:, 0, :] & (grp < 0), axis=1)
-    walked = xp.any(needed & in_g, axis=2)                  # [B,G]
-
-    relevant = covered & needed
-    fit_r = req <= av
-    nofit_r = req > pot
-    preempt_capable_r = (req <= nom) | can_preempt_borrow[:, None, None]
-    res_nofit = relevant & (nofit_r | (~fit_r & ~preempt_capable_r))
-    borrow_r = relevant & (use + req > sq)
+    grp_safe = xp.maximum(grp, 0)
+    in_any = (grp >= 0)[:, None, :]
+    sidx = xp.arange(S, dtype=xp.int32)[None, None, :]
+    slot_r = xp.arange(S, dtype=xp.int32)[None, :, None]    # [1,S,1]
+    own_g = grp_safe[:, None, :] + xp.zeros((1, S, 1), dtype=grp_safe.dtype)
 
     def any_g(x):           # [B,S,R] -> [B,G,S] over the group's own
         return xp.any(x[:, None] & sel, axis=3)
 
-    missing = any_g(needed & ~covered)
-    barred = slot_ok & ~eligible
-    slot_ok = slot_ok & eligible
-    fit_s = ~any_g(relevant & ~fit_r) & ~missing & slot_ok   # [B,G,S]
-    nofit_s = any_g(res_nofit) | missing | ~slot_ok
-    preempt_s = ~fit_s & ~nofit_s
-    borrows_s = any_g(borrow_r) & has_parent[:, None, None]
+    def of_res(x):          # [B,G,S] -> [B,S,R]: r reads its own group's
+        return xp.take_along_axis(xp.swapaxes(x, 1, 2), own_g, axis=2)
 
-    # the fungibility walk: a slot STOPS it when it fits without
-    # borrowing, fits borrowing under whenCanBorrow=Borrow, or is
-    # preempt-capable under whenCanPreempt=Preempt (shouldTryNextFlavor,
-    # :620); else it keeps the best-mode slot seen (Fit > Preempt >
-    # NoFit, first occurrence), a stop overriding any earlier best
-    sidx = xp.arange(S, dtype=xp.int32)[None, None, :]
-    active_s = sidx >= start[:, :, None]
-    stop_s = (active_s & (fit_s | (preempt_s & wcp[:, None, None]))
-              & (~borrows_s | wcb[:, None, None]))
-    has_stop = xp.any(stop_s, axis=2)                       # [B,G]
-    stop_idx = xp.argmax(stop_s, axis=2).astype(xp.int32)
-    act_mode = xp.where(active_s,
-                        xp.where(fit_s, 2, xp.where(preempt_s, 1, 0)), 0)
-    best_mode = act_mode.max(axis=2)
-    best_idx = xp.argmax((act_mode == best_mode[:, :, None]) & active_s,
-                         axis=2).astype(xp.int32)
-    chosen = xp.where(has_stop, stop_idx, best_idx)         # [B,G]
-    at = chosen[:, :, None]
-    chosen_mode = xp.take_along_axis(act_mode, at, axis=2)[:, :, 0]
-    chosen_borrows = (xp.take_along_axis(borrows_s, at, axis=2)[:, :, 0]
-                      & walked)
-    # the resume state the host records for a walk: the stop slot when
-    # it stopped mid-list, else -1 (whole list attempted)
-    tried = xp.where(walked & has_stop & (chosen < slot_count - 1),
-                     chosen, -1)
+    acc = None              # [B,S,R] the earlier PodSets' choices
+    alive = valid           # no earlier PodSet was NoFit
+    passes = []
+    for p in range(P):
+        own = req[:, p][:, None, :]                         # [B,1,R]
+        val = own if acc is None else own + acc
+        needed = own > 0
+        uncovered = xp.any(needed[:, 0, :] & (grp < 0), axis=1)
+        walked = xp.any(needed & in_g, axis=2)              # [B,G]
+
+        relevant = covered & needed
+        fit_r = val <= av
+        nofit_r = val > pot
+        preempt_capable_r = (val <= nom) | can_preempt_borrow[:, None, None]
+        res_nofit = relevant & (nofit_r | (~fit_r & ~preempt_capable_r))
+        borrow_r = relevant & (use + val > sq)
+
+        missing = any_g(needed & ~covered)
+        ok_s = slot_ok & eligible[:, p]
+        barred = slot_ok & ~eligible[:, p]
+        fit_s = ~any_g(relevant & ~fit_r) & ~missing & ok_s  # [B,G,S]
+        nofit_s = any_g(res_nofit) | missing | ~ok_s
+        preempt_s = ~fit_s & ~nofit_s
+        borrows_s = any_g(borrow_r) & has_parent[:, None, None]
+
+        # the fungibility walk: a slot STOPS it when it fits without
+        # borrowing, fits borrowing under whenCanBorrow=Borrow, or is
+        # preempt-capable under whenCanPreempt=Preempt
+        # (shouldTryNextFlavor, :620); else it keeps the best-mode slot
+        # seen (Fit > Preempt > NoFit, first occurrence), a stop
+        # overriding any earlier best
+        active_s = sidx >= start[:, p][:, :, None]
+        stop_s = (active_s & (fit_s | (preempt_s & wcp[:, None, None]))
+                  & (~borrows_s | wcb[:, None, None]))
+        has_stop = xp.any(stop_s, axis=2)                   # [B,G]
+        stop_idx = xp.argmax(stop_s, axis=2).astype(xp.int32)
+        act_mode = xp.where(
+            active_s, xp.where(fit_s, 2, xp.where(preempt_s, 1, 0)), 0)
+        best_mode = act_mode.max(axis=2)
+        best_idx = xp.argmax((act_mode == best_mode[:, :, None]) & active_s,
+                             axis=2).astype(xp.int32)
+        chosen = xp.where(has_stop, stop_idx, best_idx)     # [B,G]
+        at = chosen[:, :, None]
+        chosen_mode = xp.take_along_axis(act_mode, at, axis=2)[:, :, 0]
+        chosen_borrows = (xp.take_along_axis(borrows_s, at, axis=2)[:, :, 0]
+                          & walked)
+        # the resume state the host records for a walk: the stop slot
+        # when it stopped mid-list, else -1 (whole list attempted)
+        tried = xp.where(walked & has_stop & (chosen < slot_count - 1),
+                         chosen, -1)
+        preempt_slots = preempt_s & active_s                # [B,G,S]
+
+        # each resource reads its own group's slot
+        res_slot = xp.take_along_axis(chosen, grp_safe, axis=1)  # [B,R]
+        rs = res_slot[:, None, :]
+        res_fr = xp.where(grp >= 0,
+                          xp.take_along_axis(frs, rs, axis=1)[:, 0, :], -1)
+        slot_res_fit = fit_r | ~relevant                    # [B,S,R]
+        res_fit = xp.take_along_axis(slot_res_fit, rs, axis=1)[:, 0, :]
+        oracle_ask = (of_res(preempt_slots) & in_any & relevant & ~fit_r
+                      & (val <= nom) & (use + val <= sq))
+
+        last = xp.where(has_stop, stop_idx + 1, slot_count)
+        counted = walked & alive[:, None]
+        visited = active_s & (sidx < last[:, :, None])
+        mode_g = xp.where(walked, chosen_mode, 2)
+        mode = xp.where(uncovered, 0, mode_g.min(axis=1))   # [B]
+        entered = alive & xp.any(needed[:, 0, :], axis=1)
+        passes.append({
+            "chosen": chosen, "walked": walked, "tried": tried,
+            "has_stop": has_stop, "chosen_mode": chosen_mode,
+            "preempt_slots": preempt_slots, "res_fr": res_fr,
+            "res_fit": res_fit, "slot_res_fit": slot_res_fit,
+            "slot_borrows": borrows_s, "oracle_ask": oracle_ask,
+            "mode": mode, "borrows": xp.any(chosen_borrows, axis=1),
+            # the host's assignment holds a record of this PodSet: it
+            # was reached, and it got its flavors
+            "recorded": alive & (mode > 0),
+            "preempt_count": preempt_slots.sum(axis=2),
+            "walk_slots": xp.where(
+                counted, xp.maximum(last - start[:, p], 0), 0).sum(axis=1),
+            "walk_ineligible": xp.where(
+                counted, (barred & visited).sum(axis=2), 0).sum(axis=1),
+            "group_walks": counted.sum(axis=1),
+            "entered": entered,
+            # the pass met, on a slot it visited, usage an earlier
+            # PodSet of the same head had put there
+            "charged": (entered & xp.any(
+                of_res(visited) & relevant & (acc > 0), axis=(1, 2))
+                if acc is not None else xp.zeros_like(entered)),
+            "lo": mode_g.min(axis=1),
+            "hi": xp.where(walked, chosen_mode, 0).max(axis=1),
+        })
+        put = xp.where((slot_r == rs) & in_any, own, 0)     # [B,S,R]
+        acc = put if acc is None else acc + put
+        alive = alive & (mode > 0)
+
+    def stack(name):
+        return xp.stack([d[name] for d in passes], axis=1)
+
+    def total(name):
+        return sum((d[name] for d in passes[1:]), passes[0][name])
 
     # -- the join ------------------------------------------------------
-    mode_g = xp.where(walked, chosen_mode, 2)
-    head_mode = xp.where(valid & ~uncovered, mode_g.min(axis=1), 0)
+    head_mode = xp.where(valid, stack("mode").min(axis=1), 0)
     has_fit = head_mode == 2
     has_preempt = head_mode == 1
-    borrows = xp.any(chosen_borrows, axis=1)
-    pre_g = walked & (chosen_mode == 1) & has_preempt[:, None]
-    preempt_slots = preempt_s & active_s                    # [B,G,S]
-    preempt_count = preempt_slots.sum(axis=2)
+    chosen, walked = stack("chosen"), stack("walked")       # [B,P,G]
+    has_stop = stack("has_stop")
+    pre_g = (walked & (stack("chosen_mode") == 1)
+             & has_preempt[:, None, None])
     # a policy-stopped preempt choice is final, and so is the only
     # preempt-capable slot; with several, the group's pick is the
     # reclaim oracle's (flavorassigner.go:692 RECLAIM beats PREEMPT)
-    oracle_groups = pre_g & ~has_stop & (preempt_count > 1)
-
-    # each resource reads its own group's slot
-    grp_safe = xp.maximum(grp, 0)
-    res_slot = xp.take_along_axis(chosen, grp_safe, axis=1)  # [B,R]
-    rs = res_slot[:, None, :]
-    res_fr = xp.where(grp >= 0,
-                      xp.take_along_axis(frs, rs, axis=1)[:, 0, :], -1)
-    slot_res_fit = fit_r | ~relevant                        # [B,S,R]
-    res_fit = xp.take_along_axis(slot_res_fit, rs, axis=1)[:, 0, :]
-    ps_r = xp.take_along_axis(                              # [B,S,R]
-        xp.swapaxes(preempt_slots, 1, 2), grp_safe[:, None, :]
-        + xp.zeros((1, S, 1), dtype=grp_safe.dtype), axis=2)
-    oracle_ask = (ps_r & (grp >= 0)[:, None, :] & relevant & ~fit_r
-                  & (req <= nom) & (use + req <= sq))
-
-    last = xp.where(has_stop, stop_idx + 1, slot_count)
-    counted = walked & valid[:, None]
-    walk_slots = xp.where(counted, xp.maximum(last - start, 0), 0)
-    visited = active_s & (sidx < last[:, :, None])
-    walk_ineligible = xp.where(
-        counted, (barred & visited).sum(axis=2), 0)
-    lo = xp.where(walked, chosen_mode, 2).min(axis=1)
-    hi = xp.where(walked, chosen_mode, 0).max(axis=1)
+    oracle_groups = pre_g & ~has_stop & (stack("preempt_count") > 1)
+    lo = stack("lo").min(axis=1)
+    hi = stack("hi").max(axis=1)
+    # PodSets of one head on different flavors of one group
+    first = xp.where(walked, chosen, S).min(axis=1)         # [B,G]
+    final = xp.where(walked, chosen, -1).max(axis=1)
     return {
         "has_fit": has_fit, "has_preempt": has_preempt,
-        "borrows": borrows, "chosen": chosen, "walked": walked,
-        "tried": tried, "has_stop": has_stop, "pre_g": pre_g,
-        "oracle_groups": oracle_groups, "preempt_slots": preempt_slots,
-        "res_fr": res_fr, "res_fit": res_fit,
-        "slot_res_fit": slot_res_fit, "slot_borrows": borrows_s,
-        "oracle_ask": oracle_ask,
-        "walk_slots": walk_slots.sum(axis=1),
-        "walk_ineligible": walk_ineligible.sum(axis=1),
-        "group_walks": counted.sum(axis=1),
-        "split_mode": valid & xp.any(walked, axis=1) & (lo != hi),
+        "borrows": xp.any(stack("borrows"), axis=1), "chosen": chosen,
+        "walked": walked, "tried": stack("tried"), "has_stop": has_stop,
+        "pre_g": pre_g, "oracle_groups": oracle_groups,
+        "preempt_slots": stack("preempt_slots"),
+        "res_fr": stack("res_fr"), "res_fit": stack("res_fit"),
+        "slot_res_fit": stack("slot_res_fit"),
+        "slot_borrows": stack("slot_borrows"),
+        "oracle_ask": stack("oracle_ask"),
+        "walk_slots": total("walk_slots"),
+        "walk_ineligible": total("walk_ineligible"),
+        "group_walks": total("group_walks"),
+        "split_mode": valid & xp.any(walked, axis=(1, 2)) & (lo != hi),
+        "podset_walks": stack("entered").sum(axis=1),
+        "charged_walks": stack("charged").sum(axis=1),
+        "split_flavor": (has_fit | has_preempt) & xp.any(
+            (final >= 0) & (first != final), axis=1),
+        "recorded": stack("recorded"),
     }
 
 
 def classify_np(packed, avail0=None, potential0=None, start_slot=None,
                 eligible=None):
-    """Vectorized nominate on the host: per-head, per-group slot
-    classification (``walk_groups`` over the cycle's heads).
+    """Vectorized nominate on the host: per-head, per-PodSet, per-group
+    slot classification (``walk_groups`` over the cycle's heads).
 
-    ``start_slot`` [W, G] carries the fungibility resume index a group
-    (last_tried_flavor_idx + 1 of the group's first requested resource);
-    slots below it are never attempted.  ``eligible`` [W, G, S] is False
-    where the head's PodSet may not take the flavor for a taint or a
-    selector (ops/eligibility.py): the walk visits such a slot and
-    passes on, as over a flavor that does not exist; it is NoFit for
-    that head, no stop, no preempt-capable slot and nothing to ask the
-    oracle about.
+    ``start_slot`` [W, P, G] carries the fungibility resume index a
+    (PodSet, group) (last_tried_flavor_idx + 1 of the group's first
+    requested resource); slots below it are never attempted.
+    ``eligible`` [W, P, G, S] is False where the PodSet may not take the
+    flavor for a taint or a selector (ops/eligibility.py): the walk
+    visits such a slot and passes on, as over a flavor that does not
+    exist; it is NoFit for that PodSet, no stop, no preempt-capable
+    slot and nothing to ask the oracle about.
 
     Returns a dict of [W]-shaped arrays unless noted:
-      fit0          the head fits: every walked group chose a Fit slot
-      slots0        [W, G] the slot each group's walk chose (-1: the
-                    head is NoFit); Fit slots of a fit head, and of a
-                    preempt head the Fit slots of its fitting groups
-                    beside the preempt slots of the others; no resource
-                    reads the slot of a group that was not walked
-      fit_slot0     the one-group reading: group 0's slot of a fit head,
-                    else -1
-      borrows0      the fit assignment borrows (in any group)
-      preempt0      no NoFit group, and some group chose a
+      fit0          the head fits: every walked group of every PodSet
+                    chose a Fit slot
+      slots0        [W, P, G] the slot each walk chose (-1: the head is
+                    NoFit); Fit slots of a fit head, and of a preempt
+                    head the Fit slots of its fitting walks beside the
+                    preempt slots of the others; no resource reads the
+                    slot of a group that was not walked
+      fit_slot0     the one-PodSet, one-group reading: the first
+                    PodSet's group 0's slot of a fit head, else -1
+      borrows0      the fit assignment borrows (in any walk)
+      preempt0      no NoFit walk, and some walk chose a
                     preempt-capable slot
-      preempt_borrows0  that preempt assignment borrows (in any group)
-      preempt_res_fit   [W, R] per-resource Fit flag on the slot of the
-                    resource's group (False ⇒ the resource is one
-                    needing preemption)
-      preempt_stopped0  every preempt-choosing group's walk STOPPED at
-                    its slot (the choice is policy-forced, independent
-                    of the reclaim oracle)
-      oracle_groups [W, G] the groups whose walk met several
+      preempt_borrows0  that preempt assignment borrows (in any walk)
+      preempt_res_fit   [W, P, R] per-resource Fit flag on the slot of
+                    the resource's group, at the PodSet's ``val``
+                    (False ⇒ the pair is one needing preemption)
+      preempt_stopped0  every preempt-choosing walk STOPPED at its slot
+                    (the choice is policy-forced, independent of the
+                    reclaim oracle)
+      oracle_groups [W, P, G] the walks that met several
                     preempt-capable slots and no stop: the pick among
                     them is the reclaim oracle's (``pick_preempt_slot_np``)
-      preempt_slots [W, G, S] the attempted preempt-capable slots
-      slot_res_fit  [W, S, R] per-resource Fit flag on every slot
-      slot_borrows  [W, G, S] an assignment on the slot borrows
-      oracle_ask    [W, S, R] the resources of ``preempt_slots`` on
+      preempt_slots [W, P, G, S] the attempted preempt-capable slots
+      slot_res_fit  [W, P, S, R] per-resource Fit flag on every slot
+      slot_borrows  [W, P, G, S] an assignment on the slot borrows
+      oracle_ask    [W, P, S, R] the resources of ``preempt_slots`` on
                     which the host walk asks the oracle: short of quota,
-                    within nominal, and not borrowing with the request
+                    within nominal, and not borrowing with ``val``
                     (flavorassigner.go:692, preemption_oracle.go:40)
-      tried         [W, G] the resume state the host records a group:
-                    the stop slot when the walk stopped mid-list, else -1
-      walk_slots    flavors the head's walks visited: in each group up
-                    to its stop slot, or the whole list from its start
-      walk_ineligible  of them, the flavors the head may not take
-      group_walks   the groups walked
-      split_mode    the head's groups ended in different modes
+      tried         [W, P, G] the resume state the host records a walk:
+                    the stop slot when it stopped mid-list, else -1
+      walk_slots    flavors the head's walks visited: in each up to its
+                    stop slot, or the whole list from its start
+      walk_ineligible  of them, the flavors the PodSet may not take
+      group_walks   the (PodSet, group) walks
+      split_mode    the head's walks ended in different modes
+      podset_walks  the head's PodSet passes (up to its first NoFit one)
+      charged_walks of them, those that met an earlier PodSet's usage
+                    on a slot they visited
+      split_flavor  a fit or preempt head whose PodSets chose different
+                    flavors in one group
     """
     st = packed.structure
     usage0 = packed.usage0
@@ -251,45 +321,55 @@ def classify_np(packed, avail0=None, potential0=None, start_slot=None,
     frs = st.slot_fr[cqs]                                   # [W,S,R]
     at = (cqs[:, None, None], np.maximum(frs, 0))
     W, G = len(cqs), st.n_groups
-    S = frs.shape[1]
+    S, R = frs.shape[1:]
+    req = packed.wl_requests.astype(np.int64).reshape(W, -1, R)
+    P = req.shape[1]
     valid = wl_cq >= 0
-    with _span("cycle.nominate.classify.groups"):
-        out = walk_groups(
-            np, req=packed.wl_requests.astype(np.int64), frs=frs,
-            grp=st.res_group[cqs], slot_ok=st.slot_valid[cqs],
-            eligible=(np.ones((W, G, S), dtype=bool) if eligible is None
-                      else np.asarray(eligible).reshape(W, G, S)),
-            slot_count=st.slot_count_cq[cqs],
-            start=(np.zeros((W, G), dtype=np.int32) if start_slot is None
-                   else np.asarray(start_slot, dtype=np.int32).reshape(W, G)),
-            av=avail0[at], pot=potential0[at], nom=st.nominal_cq[at],
-            use=usage0[at], sq=st.subtree_quota[at],
-            can_preempt_borrow=st.cq_can_preempt_borrow[cqs],
-            has_parent=st.parent[cqs] >= 0, wcb=st.cq_wcb_borrow[cqs],
-            wcp=st.cq_wcp_preempt[cqs], valid=valid)
+    with _span("cycle.nominate.classify.podsets"):
+        with _span("cycle.nominate.classify.groups"):
+            out = walk_groups(
+                np, req=req, frs=frs,
+                grp=st.res_group[cqs], slot_ok=st.slot_valid[cqs],
+                eligible=(np.ones((W, P, G, S), dtype=bool)
+                          if eligible is None
+                          else np.asarray(eligible).reshape(W, P, G, S)),
+                slot_count=st.slot_count_cq[cqs],
+                start=(np.zeros((W, P, G), dtype=np.int32)
+                       if start_slot is None
+                       else np.asarray(start_slot, dtype=np.int32
+                                       ).reshape(W, P, G)),
+                av=avail0[at], pot=potential0[at], nom=st.nominal_cq[at],
+                use=usage0[at], sq=st.subtree_quota[at],
+                can_preempt_borrow=st.cq_can_preempt_borrow[cqs],
+                has_parent=st.parent[cqs] >= 0, wcb=st.cq_wcb_borrow[cqs],
+                wcp=st.cq_wcp_preempt[cqs], valid=valid)
     has_fit, has_preempt = out["has_fit"], out["has_preempt"]
     chosen = out["chosen"]
-    decided = (has_fit | has_preempt)[:, None]
+    decided = (has_fit | has_preempt)[:, None, None]
     return {
         "fit0": has_fit,
         "slots0": np.where(decided, chosen, -1).astype(np.int32),
-        "fit_slot0": np.where(has_fit, chosen[:, 0], -1).astype(np.int32),
+        "fit_slot0": np.where(has_fit, chosen[:, 0, 0], -1).astype(np.int32),
         "borrows0": out["borrows"] & has_fit,
         "preempt0": has_preempt,
         "preempt_borrows0": out["borrows"] & has_preempt,
         "preempt_res_fit": out["res_fit"],
         "preempt_stopped0": has_preempt & ~np.any(
-            out["pre_g"] & ~out["has_stop"], axis=1),
+            out["pre_g"] & ~out["has_stop"], axis=(1, 2)),
         "oracle_groups": out["oracle_groups"],
         "preempt_slots": out["preempt_slots"],
         "slot_res_fit": out["slot_res_fit"],
         "slot_borrows": out["slot_borrows"],
         "oracle_ask": out["oracle_ask"],
+        "walked": out["walked"],
         "tried": out["tried"].astype(np.int32),
         "walk_slots": out["walk_slots"].astype(np.int32),
         "walk_ineligible": out["walk_ineligible"].astype(np.int32),
         "group_walks": out["group_walks"].astype(np.int32),
         "split_mode": out["split_mode"],
+        "podset_walks": out["podset_walks"].astype(np.int32),
+        "charged_walks": out["charged_walks"].astype(np.int32),
+        "split_flavor": out["split_flavor"],
         "avail0": avail0,
         "potential0": potential0,
     }
@@ -574,7 +654,8 @@ def _phase1(usage0, subtree, guaranteed, borrow_cap, has_blim, parent,
             wl_requests, cq_wcb_borrow, cq_wcp_preempt, start_slot,
             eligible, res_group, depth):
     """``solve_cycle``'s phase 1, traced into its caller's program:
-    (has_fit, fit_slot0, borrows0, preempt0, res_fr [W, R])."""
+    (has_fit, fit_slot0, borrows0, preempt0, res_fr [W, P, R], the
+    requests as [W, P, R])."""
     C, S, R = slot_fr.shape
     W = wl_cq.shape[0]
     G = slot_valid.shape[1]
@@ -584,10 +665,12 @@ def _phase1(usage0, subtree, guaranteed, borrow_cap, has_blim, parent,
         cq_wcb_borrow = jnp.ones(C, dtype=bool)
     if cq_wcp_preempt is None:
         cq_wcp_preempt = jnp.zeros(C, dtype=bool)
-    start_slot = (jnp.zeros((W, G), dtype=jnp.int32) if start_slot is None
-                  else start_slot.reshape(W, G))
-    eligible = (jnp.ones((W, G, S), dtype=bool) if eligible is None
-                else eligible.reshape(W, G, S))
+    wl_requests = wl_requests.reshape(W, -1, R)
+    P = wl_requests.shape[1]
+    start_slot = (jnp.zeros((W, P, G), dtype=jnp.int32)
+                  if start_slot is None else start_slot.reshape(W, P, G))
+    eligible = (jnp.ones((W, P, G, S), dtype=bool) if eligible is None
+                else eligible.reshape(W, P, G, S))
 
     avail0 = available_all(usage0, subtree, guaranteed, borrow_cap, has_blim,
                            parent, depth)
@@ -606,11 +689,13 @@ def _phase1(usage0, subtree, guaranteed, borrow_cap, has_blim, parent,
         has_parent=parent[cqs] >= 0, wcb=cq_wcb_borrow[cqs],
         wcp=cq_wcp_preempt[cqs], valid=wl_cq >= 0)
     has_fit = out["has_fit"]
-    fit_slot0 = jnp.where(has_fit, out["chosen"][:, 0], -1).astype(jnp.int32)
+    fit_slot0 = jnp.where(has_fit, out["chosen"][:, 0, 0],
+                          -1).astype(jnp.int32)
     borrows0 = out["borrows"] & has_fit
     preempt0 = out["has_preempt"]
 
-    return has_fit, fit_slot0, borrows0, preempt0, out["res_fr"]
+    return (has_fit, fit_slot0, borrows0, preempt0, out["res_fr"],
+            wl_requests)
 
 
 @partial(jax.jit, static_argnames=("depth", "run_scan"))
@@ -629,17 +714,18 @@ def solve_cycle(usage0, subtree, guaranteed, borrow_cap, has_blim, parent,
     within a cycle).  With ``run_scan=False`` only phase 1 runs.
 
     ``cq_wcb_borrow``/``cq_wcp_preempt`` [C] carry the FlavorFungibility
-    policy per CQ and ``start_slot`` [W, G] the fungibility resume index
-    a group; omitted, the default policy (whenCanBorrow=Borrow,
-    whenCanPreempt=TryNextFlavor) walks every slot from 0 — the legacy
-    classify surface.  ``eligible`` [W, G, S] bars a head from the
-    flavors its PodSet may not take (classify_np); omitted, none is
-    barred.  ``slot_valid`` is [C, G, S] and ``res_group`` [C, R] names
+    policy per CQ and ``start_slot`` [W, P, G] the fungibility resume
+    index a (PodSet, group); omitted, the default policy
+    (whenCanBorrow=Borrow, whenCanPreempt=TryNextFlavor) walks every
+    slot from 0 — the legacy classify surface.  ``wl_requests`` is
+    [W, R] or a request a PodSet, [W, P, R].  ``eligible`` [W, P, G, S]
+    bars a PodSet from the flavors it may not take (classify_np);
+    omitted, none is barred.  ``slot_valid`` is [C, G, S] and ``res_group`` [C, R] names
     each resource's group; omitted, every resource is in group 0.  The slots returned are
     group 0's (``classify_np``'s ``fit_slot0``); the admit scan charges
     every group's."""
     W = wl_cq.shape[0]
-    has_fit, fit_slot0, borrows0, preempt0, res_fr = _phase1(
+    has_fit, fit_slot0, borrows0, preempt0, res_fr, wl_requests = _phase1(
         usage0, subtree, guaranteed, borrow_cap, has_blim, parent,
         nominal_cq, slot_fr, slot_valid, cq_can_preempt_borrow, wl_cq,
         wl_requests, cq_wcb_borrow, cq_wcp_preempt, start_slot, eligible,
@@ -668,32 +754,55 @@ def solve_cycle(usage0, subtree, guaranteed, borrow_cap, has_blim, parent,
 def decision_pairs(res_fr, wl_requests, on):
     """Per-resource flavor-resources -> decision pairs (jax or numpy).
 
-    ``res_fr`` [W, R]: the flavor-resource each resource takes on the
-    slot its own group chose.  Returns dec_fr/dec_amt [W, R]: that, per
-    requested resource, for the heads ``on`` [W] (-1 / 0 elsewhere)."""
+    ``res_fr`` [W, P, R]: the flavor-resource each resource of each
+    PodSet takes on the slot its own group chose.  Returns
+    dec_fr/dec_amt [W, P*R]: a head's distinct flavor-resources and
+    what it asks of each, for the heads ``on`` [W] (-1 / 0 elsewhere).
+    Two PodSets of a head on one flavor-resource make one pair of their
+    sum (the first PodSet's column), which is the assignment's usage
+    map the admit scan re-checks."""
     xp = jnp if isinstance(res_fr, jnp.ndarray) else np
-    relevant = (res_fr >= 0) & (wl_requests > 0) & on[:, None]
+    W, R = res_fr.shape[0], res_fr.shape[-1]
+    res_fr = res_fr.reshape(W, -1, R)
+    wl_requests = wl_requests.reshape(W, -1, R)
+    P = res_fr.shape[1]
+    relevant = (res_fr >= 0) & (wl_requests > 0) & on[:, None, None]
     dec_fr = xp.where(relevant, res_fr, -1).astype(xp.int32)
     dec_amt = xp.where(relevant, wl_requests, 0).astype(xp.int32)
-    return dec_fr, dec_amt
+    if P > 1:
+        fr = [dec_fr[:, p] for p in range(P)]               # [W,R] each
+        amt = [dec_amt[:, p] for p in range(P)]
+        for p in range(P):
+            for q in range(p + 1, P):
+                same = (fr[q] == fr[p]) & (fr[q] >= 0)
+                amt[p] = amt[p] + xp.where(same, amt[q], 0)
+                fr[q] = xp.where(same, -1, fr[q])
+                amt[q] = xp.where(same, 0, amt[q])
+        dec_fr, dec_amt = xp.stack(fr, axis=1), xp.stack(amt, axis=1)
+    return dec_fr.reshape(W, P * R), dec_amt.reshape(W, P * R)
 
 
 def res_slots(grp, slots):
-    """[W, R] the slot each resource reads when each group takes
-    ``slots`` [W, G] (numpy): ``slots[w, grp[w, r]]``, of its own group
-    (``grp`` [W, R], -1: no group covers the resource; reads group 0)."""
-    return np.take_along_axis(np.maximum(slots, 0), np.maximum(grp, 0),
-                              axis=1)
+    """[W, P, R] the slot each resource of each PodSet reads when the
+    PodSet's groups take ``slots`` [W, P, G] (numpy):
+    ``slots[w, p, grp[w, r]]``, of its own group (``grp`` [W, R], -1: no
+    group covers the resource; reads group 0)."""
+    W, P = slots.shape[:2]
+    return np.take_along_axis(
+        np.maximum(slots, 0),
+        np.broadcast_to(np.maximum(grp, 0)[:, None, :],
+                        (W, P, grp.shape[1])), axis=2)
 
 
 def slot_frs(slot_fr, res_group, wl_cq, slots):
-    """[W, R] the flavor-resource each resource takes when each group of
-    the head's queue takes ``slots`` [W, G] (numpy)."""
+    """[W, P, R] the flavor-resource each resource of each PodSet takes
+    when the PodSet's groups of the head's queue take ``slots``
+    [W, P, G] (numpy)."""
     cqs = np.maximum(wl_cq, 0)
     grp = res_group[cqs]                                    # [W,R]
-    frs = slot_fr[cqs[:, None], res_slots(grp, slots),
+    frs = slot_fr[cqs[:, None, None], res_slots(grp, slots),
                   np.arange(slot_fr.shape[2])]
-    return np.where(grp >= 0, frs, -1)
+    return np.where((grp >= 0)[:, None, :], frs, -1)
 
 
 def add_usage_chain_batched(usage, nodes, deltas, guaranteed, parent,
@@ -801,7 +910,7 @@ def solve_cycle_forests(usage0, subtree, guaranteed, borrow_cap, has_blim,
                         *, depth: int, n_forests: int, max_forest_wl: int):
     """One-call phase 1 + forest-parallel admit scan (probe surface)."""
     W = wl_cq.shape[0]
-    has_fit, fit_slot0, borrows0, preempt0, res_fr = _phase1(
+    has_fit, fit_slot0, borrows0, preempt0, res_fr, wl_requests = _phase1(
         usage0, subtree, guaranteed, borrow_cap, has_blim, parent,
         nominal_cq, slot_fr, slot_valid, cq_can_preempt_borrow, wl_cq,
         wl_requests, None, None, None, eligible, res_group, depth)

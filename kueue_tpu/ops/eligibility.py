@@ -12,16 +12,24 @@ group's: a selector key that only the flavors of one group carry is
 matched in that group's walk and ignored in every other's.
 
 This module states that rule once for the device path: a head's
-answer is one *skip mask* a resource group of its queue, bit s set when
-the PodSet may not take slot s of that group's flavor list.  The
-per-cycle classify expands them into the ``[W, G, S]`` eligibility
-plane (ops/cycle.py ``classify_np``), the fused window carries them a
-row (``wl_flavor_skip``, ops/burst.py), or one column of zeros where
-every flavor of the structure is plain.  A mask is a function of the
-PodSet's selector, affinity and tolerations and of the flavor list
-alone, so it is evaluated once a distinct signature and flavor list,
-and cached on the ``Info`` under the structure generation (a flavor or
-queue edit bumps it).
+answer is one *skip mask* a (PodSet, resource group of its queue), bit
+s set when that PodSet may not take slot s of that group's flavor list:
+a Workload's PodSets each carry their own selector and tolerations (a
+launcher pinned to one pool, its workers to another), so each has its
+own masks.  The per-cycle classify expands them into the
+``[W, P, G, S]`` eligibility plane (ops/cycle.py ``classify_np``), the
+fused window carries them a row (``wl_flavor_skip [C, M, P*G]``,
+ops/burst.py), or one column of zeros where every flavor of the
+structure is plain.  A mask is a function of the PodSet's selector,
+affinity and tolerations and of the flavor list alone, so it is
+evaluated once a distinct signature and flavor list, and cached on the
+``Info`` under the structure generation (a flavor or queue edit bumps
+it) and the planes' PodSet extent.  What stays with the host walk: a
+queue with a missing flavor, a flavor that binds a topology or more
+than ``MASK_BITS`` declared flavors in a group (``bind_flavor_lists``),
+and a Workload with more PodSets than ``packing.MAX_POD_SETS``, a
+topology request or a partial admission that does not fit whole
+(``CycleSolver._scalar_mask``).
 """
 
 from __future__ import annotations
@@ -142,31 +150,35 @@ def _signature(pod_set) -> tuple:
 
 
 def skip_mask(info, st, ci: int, tally: dict | None = None) -> tuple:
-    """The skip masks of ``info``'s first PodSet in queue ``ci`` of
-    structure ``st``, one a resource group (all 0 for a queue the vector
-    path does not decide, or whose flavors are all plain).
+    """The skip masks of ``info``'s PodSets in queue ``ci`` of
+    structure ``st``, one a (PodSet, resource group), PodSet-major and
+    ``st.pod_sets * st.n_groups`` long: all 0 for a queue the vector
+    path does not decide or whose flavors are all plain, for the
+    PodSets the head does not have, and for a head with more PodSets
+    than the planes hold (the host walk's).
     ``tally["eligibility_masks_built"]`` counts the signatures
     evaluated, as against read from a cache."""
-    G = st.n_groups
-    if not declares(st, ci) or not info.obj.pod_sets:
-        return (0,) * G
+    G, P = st.n_groups, st.pod_sets
+    pod_sets = info.obj.pod_sets
+    if not declares(st, ci) or not pod_sets or len(pod_sets) > P:
+        return (0,) * (P * G)
     gen = st.generation           # < 0: a structure nothing vouches for
     hit = getattr(info, "_flavor_skip", None)
-    if hit is not None and hit[0] == gen >= 0 and hit[1] == ci:
+    if hit is not None and hit[0] == gen >= 0 and hit[1] == (ci, P):
         return hit[2]
-    pod_set = info.obj.pod_sets[0]
-    sig = _signature(pod_set)
     masks = []
-    for li in st.flavor_list_of_cq[ci].tolist():
-        mask = 0
-        if li >= 0 and st.flavor_lists[li].declared:
-            fl = st.flavor_lists[li]
-            mask = fl.masks.get(sig)
-            if mask is None:
-                mask = fl.masks[sig] = fl.skip_mask(pod_set)
-                if tally is not None:
-                    tally["eligibility_masks_built"] += 1
-        masks.append(mask)
-    masks = tuple(masks)
-    info._flavor_skip = (gen, ci, masks)
+    for pod_set in pod_sets:
+        sig = _signature(pod_set)
+        for li in st.flavor_list_of_cq[ci].tolist():
+            mask = 0
+            if li >= 0 and st.flavor_lists[li].declared:
+                fl = st.flavor_lists[li]
+                mask = fl.masks.get(sig)
+                if mask is None:
+                    mask = fl.masks[sig] = fl.skip_mask(pod_set)
+                    if tally is not None:
+                        tally["eligibility_masks_built"] += 1
+            masks.append(mask)
+    masks = tuple(masks) + (0,) * ((P - len(pod_sets)) * G)
+    info._flavor_skip = (gen, (ci, P), masks)
     return masks

@@ -10,6 +10,9 @@ shaped integer tensors (SURVEY §7 stage 1).  Axes:
 - S: flavor slots per resource group (max flavor-list length)
 - G: resource groups a ClusterQueue (max over the queues)
 - R: distinct resource names
+- P: PodSets a Workload (``PackedStructure.pod_sets``: the most a head
+  or a packed row has had so far, rounded up to a power of two, at most
+  ``MAX_POD_SETS``)
 
 F and G are rounded up to a power of two (``_plane_extent``): they are
 the minor extent of the fused window's row planes (``adm_usage0
@@ -32,8 +35,9 @@ O(cluster):
   flavor slots, the cohort forest, int32 scaling).  Rebuilt only when the
   cache's structure generation changes (a CQ/cohort/flavor apply), and
   cached by the solver across cycles.
-- ``pack_cycle`` — fills the per-cycle usage [N, F] and workload [W, R]
-  tensors against a cached structure.
+- ``pack_cycle`` — fills the per-cycle usage [N, F] and workload
+  [W, P, R] tensors (a request a PodSet, in the Workload's order)
+  against a cached structure.
 
 Quantities are canonical integers scaled per-resource so that everything
 fits int32 (TPU-native); per-cycle values that don't divide the cached
@@ -57,6 +61,9 @@ from ..workload import Info
 INT_INF = np.int64(2**62)  # "no limit" sentinel before scaling
 I32_MAX = 2**31 - 1
 _LIMIT = I32_MAX // 64     # ×64 headroom for sums across the tree
+# PodSets a Workload may have (upstream workload_types.go: 1 to 8); a
+# head with more is the host walk's
+MAX_POD_SETS = 8
 
 
 @dataclass
@@ -95,6 +102,17 @@ class PackedStructure:
     n_forests: int
     cq_index: dict[str, int] = field(default_factory=dict)
     cq_covers_pods: set = field(default_factory=set)
+    # P: the PodSet extent of the per-head planes (``wl_requests
+    # [W, P, R]``, the fused window's ``wl_req [C, M, P*R]``).  Not the
+    # specs': it follows the workloads seen, only ever grows, and a
+    # change of it is a change of plane shapes (``note_pod_sets``)
+    pod_sets: int = 1
+
+    def note_pod_sets(self, n: int) -> None:
+        """A head or a packed row has ``n`` PodSets: the planes hold at
+        least as many from now on, up to ``MAX_POD_SETS``."""
+        if n > self.pod_sets:
+            self.pod_sets = _plane_extent(min(n, MAX_POD_SETS))
 
     @property
     def n_groups(self) -> int:
@@ -117,11 +135,16 @@ class PackedCycle:
     usage0: np.ndarray                   # [N, F] int32: usage at snapshot time
     wl_count: int                        # true number of heads (<= W)
     wl_cq: np.ndarray                    # [W] int32 CQ index (-1 pad)
-    wl_requests: np.ndarray              # [W, R] int32 total requests (scaled)
+    wl_requests: np.ndarray              # [W, P, R] int32 a PodSet's total
+                                         # requests (scaled), in the
+                                         # Workload's order; a head with
+                                         # more PodSets than the plane
+                                         # holds has their sum in the first
     wl_priority: np.ndarray              # [W] int32
     wl_timestamp: np.ndarray             # [W] float64 queue-order timestamp
     wl_keys: list[str] = field(default_factory=list)
     exact: bool = True                   # scaled comparisons are lossless
+    wl_pod_sets: np.ndarray = None       # [W] int32 PodSets of the head
 
     # --- structure passthroughs (stable codec surface) ---
     @property
@@ -526,10 +549,14 @@ def pack_cycle(snapshot: Snapshot, heads: list[Info], ordering=None,
 
     W = _bucket(len(heads))
     wl_cq = np.full(W, -1, dtype=np.int32)
+    for h in heads:
+        st.note_pod_sets(len(h.total_requests))
+    P = st.pod_sets
+    wl_pod_sets = np.zeros(W, dtype=np.int32)
     # accumulate in int64: a cached structure's scale was chosen without
     # this cycle's requests, so scaled sums may exceed int32 — that marks
     # the pack inexact (host fallback) instead of wrapping
-    wl_requests64 = np.zeros((W, R), dtype=np.int64)
+    wl_requests64 = np.zeros((W, P, R), dtype=np.int64)
     wl_priority = np.zeros(W, dtype=np.int32)
     wl_timestamp = np.zeros(W, dtype=np.float64)
     wl_keys = []
@@ -537,7 +564,12 @@ def pack_cycle(snapshot: Snapshot, heads: list[Info], ordering=None,
         wl_keys.append(h.key)
         wl_cq[wi] = st.cq_index.get(h.cluster_queue, -1)
         covers_pods = h.cluster_queue in st.cq_covers_pods
-        for psr in h.total_requests:
+        wl_pod_sets[wi] = len(h.total_requests)
+        # more PodSets than the planes hold: the host walk's
+        # (CycleSolver._scalar_mask), summed into the first
+        summed = len(h.total_requests) > P
+        for pi, psr in enumerate(h.total_requests):
+            pi = 0 if summed else pi
             for r, v in psr.requests.items():
                 # the implicit "pods" request only participates when the
                 # head's CQ covers it (flavorassigner.go:226)
@@ -547,18 +579,18 @@ def pack_cycle(snapshot: Snapshot, heads: list[Info], ordering=None,
                 if ri is None:
                     return None
                 if st.scale_is_one:
-                    wl_requests64[wi, ri] += int(v)
+                    wl_requests64[wi, pi, ri] += int(v)
                 else:
                     s = int(scale[ri])
                     q, rem = divmod(int(v), s)
                     if rem:
                         exact = False
                         q += 1
-                    wl_requests64[wi, ri] += q
+                    wl_requests64[wi, pi, ri] += q
         wl_priority[wi] = h.obj.priority
         wl_timestamp[wi] = (ordering.queue_order_timestamp(h.obj)
                             if ordering is not None else h.obj.creation_time)
-    if wl_requests64.max(initial=0) > _LIMIT:
+    if wl_requests64.sum(axis=1).max(initial=0) > _LIMIT:
         exact = False
         np.clip(wl_requests64, None, _LIMIT, out=wl_requests64)
     wl_requests = wl_requests64.astype(np.int32)
@@ -566,6 +598,7 @@ def pack_cycle(snapshot: Snapshot, heads: list[Info], ordering=None,
     return PackedCycle(
         structure=st, usage0=usage0,
         wl_count=len(heads), wl_cq=wl_cq, wl_requests=wl_requests,
+        wl_pod_sets=wl_pod_sets,
         wl_priority=wl_priority, wl_timestamp=wl_timestamp, wl_keys=wl_keys,
         exact=exact,
     )

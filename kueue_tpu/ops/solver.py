@@ -7,14 +7,16 @@ Per cycle the solver:
    generation changes, so the per-cycle cost is O(usage + heads);
 2. runs the vectorized nominate (``ops.cycle.classify_np``) on the host
    for heads whose shape the batched math covers (any number of
-   resource groups, one flavor walk a group; single PodSet, flavors
-   without topology; node labels, taints, selectors and tolerations
-   ride in as each head's eligibility masks, ``ops.eligibility``, and
-   any fungibility policy and the resume state run in the vector walk);
-   the remaining heads are marked SCALAR — the scheduler runs the real
-   host FlavorAssigner walk for those few and attaches the resulting
-   assignment, so multi-PodSet workloads, partial admission, and TAS
-   all stay inside a device-decided cycle;
+   resource groups, one flavor walk a group; up to
+   ``packing.MAX_POD_SETS`` PodSets, walked in order, each charged with
+   what the earlier ones chose; flavors without topology; node labels,
+   taints, selectors and tolerations ride in as each PodSet's
+   eligibility masks, ``ops.eligibility``, and any fungibility policy
+   and the resume state run in the vector walk); the remaining heads
+   are marked SCALAR — the scheduler runs the real host FlavorAssigner
+   walk for those few and attaches the resulting assignment, so partial
+   admission, TAS and a gang whose earlier PodSet's pick is the reclaim
+   oracle's all stay inside a device-decided cycle;
 3. dispatches the sequential admit scan (``ops.cycle.admit_scan``) as ONE
    jitted program on the solver device (``ops.device.solver_device``: the
    default JAX backend's device, so the TPU on a chip host).  The scan
@@ -51,8 +53,8 @@ from ..scheduler.flavorassigner import (
     PodSetAssignmentResult,
 )
 from ..resources import FlavorResource, Requests
-from .packing import (PackedCycle, PackedStructure, _bucket, coarse_bucket,
-                      pack_cycle, pack_structure)
+from .packing import (MAX_POD_SETS, PackedCycle, PackedStructure, _bucket,
+                      coarse_bucket, pack_cycle, pack_structure)
 from .cycle import (admit_scan, admit_scan_forests, admit_scan_preempt,
                     classify_np, cycle_order_np, decision_pairs,
                     pick_preempt_slot_np, res_slots, slot_frs)
@@ -81,7 +83,7 @@ class ClassifiedCycle:
     borrows0: np.ndarray         # [W] bool
     preempt0: np.ndarray         # [W] bool (no fit, preempt-capable)
     preempt_borrows0: np.ndarray  # [W] bool
-    preempt_res_fit: np.ndarray  # [W, R] bool
+    preempt_res_fit: np.ndarray  # [W, P, R] bool
     preempt_stopped0: np.ndarray = None    # [W] bool: the fungibility walk
                                            # policy-stopped ON the preempt
                                            # slot (choice is final — no
@@ -89,17 +91,17 @@ class ClassifiedCycle:
     # the walk's per-slot planes (classify_np), from which the pick
     # among several preempt-capable slots is made once the reclaim
     # oracle has answered (CycleSolver.pick_preempt_slots)
-    preempt_slots: np.ndarray = None       # [W, G, S] bool
-    slot_res_fit: np.ndarray = None        # [W, S, R] bool
-    slot_borrows: np.ndarray = None        # [W, G, S] bool
-    oracle_ask: np.ndarray = None          # [W, S, R] bool
-    # one walk a resource group of the head's queue (classify_np): the
-    # slot each chose (-1: the head is NoFit), the resume
-    # state each records, and the groups whose pick is the oracle's
-    fit0: np.ndarray = None                # [W] bool: every group fits
-    slots0: np.ndarray = None              # [W, G] int32
-    tried: np.ndarray = None               # [W, G] int32
-    oracle_groups: np.ndarray = None       # [W, G] bool
+    preempt_slots: np.ndarray = None       # [W, P, G, S] bool
+    slot_res_fit: np.ndarray = None        # [W, P, S, R] bool
+    slot_borrows: np.ndarray = None        # [W, P, G, S] bool
+    oracle_ask: np.ndarray = None          # [W, P, S, R] bool
+    # one walk a (PodSet, resource group of the head's queue)
+    # (classify_np): the slot each chose (-1: the head is NoFit), the
+    # resume state each records, and the walks whose pick is the oracle's
+    fit0: np.ndarray = None                # [W] bool: every walk fits
+    slots0: np.ndarray = None              # [W, P, G] int32
+    tried: np.ndarray = None               # [W, P, G] int32
+    oracle_groups: np.ndarray = None       # [W, P, G] bool
     # heads the vectorized math can't classify: the scheduler runs the
     # host FlavorAssigner walk for these and attaches the assignment
     scalar_mask: np.ndarray = None         # [W] bool
@@ -198,6 +200,18 @@ class CycleSolver:
                                       # different modes: the join, not
                                       # one walk, set the head's mode
             "cq_shape_heads": 0,      # scalar_reasons["cq_shape"], flat
+            # one pass a PodSet (walk_groups):
+            "podset_walks": 0,        # (head, PodSet) passes the vector
+                                      # classify did; = walk_heads where
+                                      # every head has one PodSet
+            "gang_heads": 0,          # vector heads of several PodSets
+            "charged_walks": 0,       # passes that met, on a slot they
+                                      # visited, what an earlier PodSet
+                                      # of the head had chosen
+            "split_flavor_gangs": 0,  # heads whose PodSets chose
+                                      # different flavors in one group
+            "podset_scalar_heads": 0,  # scalar_reasons["multi_podset"]
+                                       # and ["podset_oracle_order"], flat
             # per-workload flavor eligibility (ops/eligibility.py):
             "walk_ineligible_slots": 0,   # of them, skipped for a taint
                                           # or a selector
@@ -349,17 +363,22 @@ class CycleSolver:
         pargs, porder, _, _, _, _, _ = self._mesh_pad(args, order, st)
         return fns["flat"](*pargs, porder)
 
-    def warmup(self, snapshot: Snapshot, max_heads: int) -> None:
+    def warmup(self, snapshot: Snapshot, max_heads: int,
+               pod_sets: int = 1) -> None:
         """One-time setup outside the hot loop: compile every admit-scan
-        and preemption-search shape a run of ``max_heads`` heads can
-        reach, through the same launch site dispatch() uses (_scan), so
-        the programs are built for the solver device — or the mesh —
-        and for nothing else.  Shapes only — no scheduling state is
-        touched."""
+        and preemption-search shape a run of ``max_heads`` heads of up
+        to ``pod_sets`` PodSets can reach, through the same launch site
+        dispatch() uses (_scan), so the programs are built for the
+        solver device — or the mesh — and for nothing else.  Shapes
+        only — no scheduling state is touched."""
         import jax
         st = self._structure_for(snapshot, [])
+        st.note_pod_sets(pod_sets)
         N, F = st.subtree_quota.shape
         C, S, R = st.slot_fr.shape
+        # the scans' decision pairs are as wide as the population's
+        # PodSets make them (_build_pair_tensors)
+        K = self._pair_width(st)
         W = 8
         buckets = []
         while True:
@@ -373,9 +392,9 @@ class CycleSolver:
                 st.borrow_cap, st.has_borrow_limit, st.parent,
                 st.nominal_cq, st.nominal_plus_blimit_cq,
                 np.full(W, -1, np.int32),
-                np.full((W, R), -1, np.int32), np.zeros((W, R), np.int32),
+                np.full((W, K), -1, np.int32), np.zeros((W, K), np.int32),
                 np.zeros(W, bool),
-                np.full((W, R), -1, np.int32), np.zeros((W, R), np.int32),
+                np.full((W, K), -1, np.int32), np.zeros((W, K), np.int32),
                 np.zeros(W, bool), np.zeros(W, bool))
             order = np.arange(W, dtype=np.int32)
             # forest scan lengths for this bucket: 4 .. bucket(max CQs
@@ -397,9 +416,9 @@ class CycleSolver:
                 jax.device_get(self._scan(st, args, order, mfw=mfw))
 
             # first padded-K bucket (scalar heads with more decision
-            # pairs than R, _build_pair_tensors): compile so a
-            # multi-PodSet head can't stall a cycle on compilation
-            Kpad = _bucket(R + 1, minimum=R if R >= 8 else 8)
+            # pairs than the vector heads', _build_pair_tensors):
+            # compile so such a head can't stall a cycle on compilation
+            Kpad = self._pair_width(st, K + 1)
             kargs = (args[:9]
                      + (np.full((W, Kpad), -1, np.int32),
                         np.zeros((W, Kpad), np.int32), args[11],
@@ -416,8 +435,8 @@ class CycleSolver:
                 mts = MT_LADDER if T == T_LADDER[0] else MT_LADDER[:1]
                 for MT in mts:
                     pre = (np.zeros(W, bool),
-                           np.full((W, R), -1, np.int32),
-                           np.zeros((W, R), np.int32),
+                           np.full((W, K), -1, np.int32),
+                           np.zeros((W, K), np.int32),
                            np.full((W, MT), -1, np.int32),
                            np.zeros(T, np.int32),
                            np.zeros((T, F), np.int32))
@@ -490,49 +509,53 @@ class CycleSolver:
                 reasons["cq_shape"] = reasons.get("cq_shape", 0) + 1
                 self.stats["cq_shape_heads"] += 1
                 continue
-            if len(h.obj.pod_sets) != 1:
-                # the host can split flavors across pod sets and accounts
-                # earlier pod sets' usage in later walks
+            if not 1 <= len(h.obj.pod_sets) <= MAX_POD_SETS:
+                # more PodSets than a plane holds (upstream allows 1 to
+                # 8): the vector walk passes over PodSets in order,
+                # each charged with the earlier ones' choices, up to
+                # that many and no further
                 mask[wi] = True
                 reasons["multi_podset"] = reasons.get("multi_podset", 0) + 1
+                self.stats["podset_scalar_heads"] += 1
                 continue
-            ps = h.obj.pod_sets[0]
-            if ps.topology_request is not None:
+            if any(ps.topology_request is not None
+                   for ps in h.obj.pod_sets):
                 mask[wi] = True
                 reasons["topology"] = reasons.get("topology", 0) + 1
         return mask
 
     def _start_slots(self, snapshot: Snapshot, heads: list[Info],
                      st: PackedStructure) -> np.ndarray:
-        """Per-head, per-group flavor-walk start slot from the
-        fungibility resume state (flavorassigner.go:359-366): a head
-        whose last attempt stopped mid-list in a group resumes that
-        group at last_tried_flavor_idx + 1, unless the CQ's quota
+        """Per-head, per-PodSet, per-group flavor-walk start slot from
+        the fungibility resume state (flavorassigner.go:359-366): a
+        PodSet whose last attempt stopped mid-list in a group resumes
+        that group at last_tried_flavor_idx + 1, unless the CQ's quota
         changed since (allocatable_generation moved on)."""
-        G = st.n_groups
-        start = np.zeros((len(heads), G), dtype=np.int32)
+        G, P = st.n_groups, st.pod_sets
+        start = np.zeros((len(heads), P, G), dtype=np.int32)
         for wi, h in enumerate(heads):
             s = resume_starts(h, snapshot.cq(h.cluster_queue),
-                              h.cluster_queue in st.cq_covers_pods, G)
+                              h.cluster_queue in st.cq_covers_pods, G, P)
             if any(s):
-                start[wi] = s
+                start[wi] = np.reshape(s, (P, G))
                 self.stats["resume_heads"] += 1
         return start
 
     def _eligible_slots(self, heads: list[Info], st: PackedStructure,
                         W: int) -> np.ndarray:
-        """The cycle's [W, G, S] eligibility plane: False where a head's
-        PodSet may not take the flavor of the group (ops/eligibility.py).
-        Rows of pads and of queues the vector walk does not decide are
-        True."""
-        skip = np.zeros((W, st.n_groups), dtype=np.int32)
+        """The cycle's [W, P, G, S] eligibility plane: False where a
+        head's PodSet may not take the flavor of the group
+        (ops/eligibility.py).  Rows of pads and of queues the vector
+        walk does not decide are True."""
+        skip = np.zeros((W, st.pod_sets * st.n_groups), dtype=np.int32)
         if st.flavors_declared:
             cq_index = st.cq_index
             for wi, h in enumerate(heads):
                 ci = cq_index.get(h.cluster_queue, -1)
                 if ci >= 0:
                     skip[wi] = skip_mask(h, st, ci, self.stats)
-        return slots_of_mask(skip, st.slot_fr.shape[1])
+        return slots_of_mask(
+            skip.reshape(W, st.pod_sets, st.n_groups), st.slot_fr.shape[1])
 
     # -- phase 1 -------------------------------------------------------
 
@@ -583,6 +606,19 @@ class CycleSolver:
             if any(ps.min_count is not None and ps.min_count < ps.count
                    for ps in heads[wi].obj.pod_sets):
                 scalar[wi] = True
+        # a gang whose earlier PodSet's pick is the reclaim oracle's,
+        # in a group a later PodSet walks too: what the later walk is
+        # charged with waits for the oracle's answer, so the host walk,
+        # which asks as it goes, decides the head
+        later = np.flip(np.cumsum(np.flip(out["walked"], 1), axis=1), 1)
+        chained = (out["oracle_groups"]
+                   & (later > out["walked"])).any(axis=(1, 2))[:n] & ~scalar
+        if chained.any():
+            scalar |= chained
+            reasons = self.stats["scalar_reasons"]
+            reasons["podset_oracle_order"] = (
+                reasons.get("podset_oracle_order", 0) + int(chained.sum()))
+            self.stats["podset_scalar_heads"] += int(chained.sum())
         if scalar.any():
             # clear the vector rows for scalar heads: their decisions come
             # from the attached host assignments instead
@@ -590,8 +626,9 @@ class CycleSolver:
             sm[:n] = scalar
             out = dict(out)
             out["fit0"] = out["fit0"] & ~sm
-            out["slots0"] = np.where(sm[:, None], -1, out["slots0"])
-            out["oracle_groups"] = out["oracle_groups"] & ~sm[:, None]
+            out["slots0"] = np.where(sm[:, None, None], -1, out["slots0"])
+            out["oracle_groups"] = (out["oracle_groups"]
+                                    & ~sm[:, None, None])
             out["borrows0"] = out["borrows0"] & ~sm
             out["preempt0"] = out["preempt0"] & ~sm
             out["preempt_borrows0"] = out["preempt_borrows0"] & ~sm
@@ -609,9 +646,17 @@ class CycleSolver:
             out["group_walks"][:n][~scalar].sum())
         self.stats["split_mode_heads"] += int(np.count_nonzero(
             out["split_mode"][:n] & ~scalar))
+        self.stats["podset_walks"] += int(
+            out["podset_walks"][:n][~scalar].sum())
+        self.stats["gang_heads"] += int(np.count_nonzero(
+            (packed.wl_pod_sets[:n] > 1) & ~scalar))
+        self.stats["charged_walks"] += int(
+            out["charged_walks"][:n][~scalar].sum())
+        self.stats["split_flavor_gangs"] += int(np.count_nonzero(
+            out["split_flavor"][:n] & ~scalar))
         self.stats["constrained_heads"] += int(np.count_nonzero(
-            (st.slot_valid[np.maximum(packed.wl_cq[:n], 0)]
-             & ~eligible[:n]).any(axis=(1, 2)) & ~scalar))
+            (st.slot_valid[np.maximum(packed.wl_cq[:n], 0)][:, None]
+             & ~eligible[:n]).any(axis=(1, 2, 3)) & ~scalar))
         return ClassifiedCycle(
             packed=packed, heads=heads, snapshot=snapshot,
             borrows0=out["borrows0"],
@@ -631,52 +676,60 @@ class CycleSolver:
 
     def oracle_queries(self, cls: ClassifiedCycle, wi: int) -> list[tuple]:
         """What the host walk would ask the reclaim oracle for head
-        ``wi``: one (slot, resource index, FlavorResource, quantity) a
-        resource short of quota on each attempted preempt-capable slot
-        of each group whose pick is the oracle's (flavorassigner.go:692);
-        the slot is one of the resource's own group."""
+        ``wi``: one (PodSet, slot, resource index, FlavorResource,
+        quantity) a resource short of quota on each attempted
+        preempt-capable slot of each walk whose pick is the oracle's
+        (flavorassigner.go:692); the slot is one of the resource's own
+        group, and the quantity the walk's ``val``: the PodSet's request
+        and what the head's earlier PodSets chose on that
+        flavor-resource."""
         st = cls.packed.structure
         h = cls.heads[wi]
         cq = cls.snapshot.cq(h.cluster_queue)
         groups = cq.spec.resource_groups
         grp = st.res_group[st.cq_index[h.cluster_queue]]
-        psr = h.total_requests[0]
+        slots = cls.slots0[wi]
+
+        def asks(psr, res):
+            return psr.count if res == "pods" else psr.requests.get(res, 0)
+
         out = []
-        for s, ri in zip(*np.nonzero(cls.oracle_ask[wi])):
+        for p, s, ri in zip(*np.nonzero(cls.oracle_ask[wi])):
             g = int(grp[ri])
-            if not cls.oracle_groups[wi, g]:
+            if not cls.oracle_groups[wi, p, g]:
                 continue
             res = st.resource_names[ri]
-            qty = psr.count if res == "pods" else psr.requests[res]
-            out.append((int(s), int(ri),
+            qty = asks(h.total_requests[p], res) + sum(
+                asks(h.total_requests[q], res) for q in range(p)
+                if slots[q, g] == s)
+            out.append((int(p), int(s), int(ri),
                         FlavorResource(groups[g].flavors[s].name, res), qty))
         return out
 
     def pick_preempt_slots(self, cls: ClassifiedCycle, heads: np.ndarray,
                            reclaim: np.ndarray) -> None:
-        """Fix, for ``heads``, the preempt slot of each group whose walk
-        met several preempt-capable slots and no stop
-        (``oracle_groups``), from the oracle's answers ``reclaim``
-        [len(heads), S, R]: the first slot of the group's best granular
-        mode, and with it the head's borrow and per-resource facts that
-        the target search and the admit scan read."""
+        """Fix, for ``heads``, the preempt slot of each walk that met
+        several preempt-capable slots and no stop (``oracle_groups``),
+        from the oracle's answers ``reclaim`` [len(heads), P, S, R]: the
+        first slot of the walk's best granular mode, and with it the
+        head's borrow and per-pair facts that the target search and the
+        admit scan read.  No later PodSet walks such a group
+        (``classify``), so every other walk of the head stands."""
         st = cls.packed.structure
         grp = st.res_group[np.maximum(cls.packed.wl_cq[heads], 0)]
-        for g in range(st.n_groups):
-            on = cls.oracle_groups[heads, g]
-            if not on.any():
-                continue
+        for p, g in zip(*np.nonzero(cls.oracle_groups[heads].any(axis=0))):
+            on = cls.oracle_groups[heads, p, g]
             slot = pick_preempt_slot_np(
-                cls.preempt_slots[heads, g], cls.slot_res_fit[heads],
-                reclaim, grp == g)
-            cls.slots0[heads[on], g] = slot[on]
-        slots = cls.slots0[heads]                           # [n, G]
+                cls.preempt_slots[heads, p, g], cls.slot_res_fit[heads, p],
+                reclaim[:, p], grp == g)
+            cls.slots0[heads[on], p, g] = slot[on]
+        slots = cls.slots0[heads]                           # [n, P, G]
         cls.preempt_borrows0[heads] = np.take_along_axis(
-            cls.slot_borrows[heads], np.maximum(slots, 0)[:, :, None],
-            axis=2).any(axis=(1, 2))
+            cls.slot_borrows[heads], np.maximum(slots, 0)[..., None],
+            axis=3).any(axis=(1, 2, 3))
         cls.preempt_res_fit[heads] = np.take_along_axis(
-            cls.slot_res_fit[heads], res_slots(grp, slots)[:, None, :],
-            axis=1)[:, 0, :]
+            cls.slot_res_fit[heads], res_slots(grp, slots)[:, :, None, :],
+            axis=2)[:, :, 0, :]
 
     # -- scalar-head decisions -----------------------------------------
 
@@ -725,10 +778,10 @@ class CycleSolver:
         packed = cls.packed
         st = packed.structure
         W = packed.wl_cq.shape[0]
-        R = len(st.resource_names)
 
-        # vector heads: each resource's pair from its own group's slot
-        # (batched); fit heads', then reserve/preempt entries'
+        # vector heads: each (PodSet, resource)'s pair from its own
+        # group's slot, one pair a distinct flavor-resource (batched);
+        # fit heads', then reserve/preempt entries'
         frs = slot_frs(st.slot_fr, st.res_group, packed.wl_cq, cls.slots0)
         fit_mask = cls.fit0.copy()
         dec_fr, dec_amt = decision_pairs(frs, packed.wl_requests, fit_mask)
@@ -740,13 +793,12 @@ class CycleSolver:
         borrows |= res_borrows
 
         scalar_pairs = cls.host_pairs
-        max_k = R
-        for pairs in scalar_pairs.values():
-            max_k = max(max_k, len(pairs))
-        if max_k > R:
-            K = _bucket(max_k, minimum=R if R >= 8 else 8)
-            pad = np.full((W, K - R), -1, np.int32)
-            zpad = np.zeros((W, K - R), np.int32)
+        have = dec_fr.shape[1]
+        K = self._pair_width(st, max(
+            (len(pairs) for pairs in scalar_pairs.values()), default=0))
+        if K > have:
+            pad = np.full((W, K - have), -1, np.int32)
+            zpad = np.zeros((W, K - have), np.int32)
             dec_fr = np.concatenate([dec_fr, pad], axis=1)
             dec_amt = np.concatenate([dec_amt, zpad], axis=1)
             res_fr = np.concatenate([res_fr, pad], axis=1)
@@ -775,6 +827,17 @@ class CycleSolver:
         pre_fr, pre_amt = res_fr, res_amt
         return (dec_fr, dec_amt, fit_mask, res_fr, res_amt, res_borrows,
                 pre_fr, pre_amt, borrows)
+
+    @staticmethod
+    def _pair_width(st: PackedStructure, most: int = 0) -> int:
+        """K of the admit scans' decision-pair tensors: a pair a
+        resource where every head has one PodSet, else a pair a
+        (PodSet, resource) of the planes' PodSet extent, and at least
+        ``most`` (a scalar head's pairs); past a pair a resource it is
+        a bucket, since every K is a compilation."""
+        R = len(st.resource_names)
+        k = max(most, R * st.pod_sets)
+        return k if k == R else _bucket(k, minimum=R if R >= 8 else 8)
 
     # -- phase 2 -------------------------------------------------------
 
@@ -1065,9 +1128,9 @@ class CycleSolver:
         preemptor's target search expects (preemption.go:466)."""
         borrow = bool(cls.preempt_borrows0[wi])
         st = cls.packed.structure
-        res_modes = {res: (Mode.FIT if cls.preempt_res_fit[wi][ri]
-                           else Mode.PREEMPT)
-                     for res, ri in st.r_index.items()}
+        res_modes = [{res: (Mode.FIT if fit[ri] else Mode.PREEMPT)
+                      for res, ri in st.r_index.items()}
+                     for fit in cls.preempt_res_fit[wi]]
         return self._build_assignment(cls, wi, Mode.PREEMPT, borrow,
                                       res_modes=res_modes)
 
@@ -1081,17 +1144,19 @@ class CycleSolver:
         h = cls.heads[wi]
         assignment = self.build_preempt_assignment(cls, wi)
         cq = cls.snapshot.cq(h.cluster_queue)
-        ps = assignment.pod_sets[0]
-        reasons = []
-        for res in sorted(ps.requests):
-            val = ps.requests[res]
-            fr = FlavorResource(ps.flavors[res].name, res)
-            avail = cq.available(fr)
-            if val > avail:
-                reasons.append(
-                    f"insufficient unused quota for {res} in flavor "
-                    f"{fr.flavor}, {val - avail} more needed")
-        ps.reasons = reasons
+        acc: dict = {}      # what the earlier PodSets chose, as the walk
+        for ps in assignment.pod_sets:
+            reasons = []
+            for res in sorted(ps.requests):
+                fr = FlavorResource(ps.flavors[res].name, res)
+                val = ps.requests[res] + acc.get(fr, 0)
+                acc[fr] = val
+                avail = cq.available(fr)
+                if val > avail:
+                    reasons.append(
+                        f"insufficient unused quota for {res} in flavor "
+                        f"{fr.flavor}, {val - avail} more needed")
+            ps.reasons = reasons
         return assignment, assignment.message()
 
     # -- back-compat one-shot API (tests/probes) -----------------------
@@ -1118,24 +1183,28 @@ class CycleSolver:
 
 def build_slot_assignment(info: Info, cq, slots, tried, mode: Mode,
                           borrow: bool,
-                          res_modes: Optional[dict] = None) -> Assignment:
+                          res_modes: Optional[list] = None) -> Assignment:
     """Reconstruct the host Assignment a device-classified head would get
-    from the flavor walks: ``slots[g]`` = the flavor index group g's walk
-    chose, ``tried[g]`` the resume state it recorded (the slot the walk
-    STOPPED on mid-list, -1 when the whole list was attempted —
-    flavorassigner.go:386-390 + shouldTryNextFlavor), written a resource
-    as ``FlavorAssigner._append`` writes it.  ``cq`` is any CQState
-    (snapshot or live cache) carrying .spec and .allocatable_generation."""
+    from the flavor walks: ``slots[p, g]`` = the flavor index the walk
+    of PodSet p in group g chose, ``tried[p, g]`` the resume state it
+    recorded (the slot the walk STOPPED on mid-list, -1 when the whole
+    list was attempted — flavorassigner.go:386-390 +
+    shouldTryNextFlavor), written a resource as
+    ``FlavorAssigner._append`` writes it; both [P, G] or flat,
+    PodSet-major.  ``res_modes[p]`` gives PodSet p's mode a resource
+    where it is not ``mode``.  ``cq`` is any CQState (snapshot or live
+    cache) carrying .spec and .allocatable_generation."""
     groups = cq.spec.resource_groups
     covers_pods = any("pods" in rg.covered_resources for rg in groups)
     group_of = {res: g for g, rg in enumerate(groups)
                 for res in rg.covered_resources}
+    slots, tried = np.atleast_2d(slots), np.atleast_2d(tried)
 
     assignment = Assignment()
     assignment.borrowing = borrow
     assignment.last_state = AssignmentClusterQueueState(
         cluster_queue_generation=cq.allocatable_generation)
-    for psr in info.total_requests:
+    for p, psr in enumerate(info.total_requests):
         # mirror the host's implicit "pods" handling
         # (flavorassigner.go:226 / _assign_flavors)
         reqs = dict(psr.requests)
@@ -1148,13 +1217,13 @@ def build_slot_assignment(info: Info, cq, slots, tried, mode: Mode,
         flavor_idx: dict[str, int] = {}
         for res in reqs:
             g = group_of[res]
-            flavor_name = groups[g].flavors[int(slots[g])].name
-            res_mode = mode if res_modes is None else res_modes.get(
+            flavor_name = groups[g].flavors[int(slots[p, g])].name
+            res_mode = mode if res_modes is None else res_modes[p].get(
                 res, mode)
             ps_res.flavors[res] = FlavorAssignmentDecision(
                 name=flavor_name, mode=res_mode, borrow=borrow,
-                tried_flavor_idx=int(tried[g]))
-            flavor_idx[res] = int(tried[g])
+                tried_flavor_idx=int(tried[p, g]))
+            flavor_idx[res] = int(tried[p, g])
             fr = FlavorResource(flavor_name, res)
             assignment.usage[fr] = (assignment.usage.get(fr, 0)
                                     + reqs[res])
@@ -1163,33 +1232,36 @@ def build_slot_assignment(info: Info, cq, slots, tried, mode: Mode,
     return assignment
 
 
-def resume_starts(info: Info, cq, covers_pods: bool, n_groups: int) -> tuple:
-    """Flavor-walk start slot a resource group for a head with
-    fungibility resume state, padded with 0 to ``n_groups``.
+def resume_starts(info: Info, cq, covers_pods: bool, n_groups: int,
+                  pod_sets: int = 1) -> tuple:
+    """Flavor-walk start slot a (PodSet, resource group) for a head with
+    fungibility resume state, PodSet-major, padded with 0 to
+    ``pod_sets * n_groups``.
 
-    Mirrors the host's entry into each group's walk
-    (flavorassigner.go:359-366 via next_flavor_to_try of the group's
-    first resource in sorted request order): 0 when there is no usable
-    resume state, last_tried + 1 otherwise.  The state is void when the
-    CQ's quota changed since it was recorded (assign() clears it on
+    Mirrors the host's entry into each walk (flavorassigner.go:359-366
+    via next_flavor_to_try of the PodSet and the group's first resource
+    in sorted request order): 0 when there is no usable resume state,
+    last_tried + 1 otherwise.  The state is void when the CQ's quota
+    changed since it was recorded (assign() clears it on
     allocatable_generation advance)."""
-    none = (0,) * n_groups
+    none = (0,) * (n_groups * pod_sets)
     last = info.last_assignment
     if last is None or cq is None:
         return none
     if cq.allocatable_generation > last.cluster_queue_generation:
         return none
-    if not info.total_requests:
+    if not 0 < len(info.total_requests) <= pod_sets:
         return none
-    psr = info.total_requests[0]
-    reqs = set(psr.requests)
-    if covers_pods:
-        reqs.add("pods")
-    else:
-        reqs.discard("pods")
     out = list(none)
-    for g, rg in enumerate(cq.spec.resource_groups[:n_groups]):
-        mine = reqs.intersection(rg.covered_resources)
-        if mine:
-            out[g] = max(0, int(last.next_flavor_to_try(0, min(mine))))
+    for p, psr in enumerate(info.total_requests):
+        reqs = set(psr.requests)
+        if covers_pods:
+            reqs.add("pods")
+        else:
+            reqs.discard("pods")
+        for g, rg in enumerate(cq.spec.resource_groups[:n_groups]):
+            mine = reqs.intersection(rg.covered_resources)
+            if mine:
+                out[p * n_groups + g] = max(
+                    0, int(last.next_flavor_to_try(p, min(mine))))
     return tuple(out)

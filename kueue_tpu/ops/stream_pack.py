@@ -242,8 +242,9 @@ class _Order:
         return np.nonzero(todo)[0]
 
 
-# row-plane layout: name -> (pad value, dtype, extra axis: None | "R" | "F"
-# | "G", the structure's resource groups a queue)
+# row-plane layout: name -> (pad value, dtype, extra axis: None | "F" |
+# "R" | "G": a PodSet's resources / resource groups a queue after
+# another's, ``PackedStructure.pod_sets`` of them)
 _ROW_PLANES = {
     "wl_req": (0, np.int32, "R"),
     "wl_rank": (_b.INF_I32, np.int32, None),
@@ -287,8 +288,8 @@ class StreamState:
 
 
 def _views(arena: PlaneArena, C: int, M: int, st) -> dict:
-    extent = {"R": len(st.resource_names), "F": st.n_frs,
-              "G": st.n_groups}
+    extent = {"R": st.pod_sets * len(st.resource_names), "F": st.n_frs,
+              "G": st.pod_sets * st.n_groups}
     F = extent["F"]
     out = {}
     for name, (pad, dt, extra) in _ROW_PLANES.items():
@@ -492,7 +493,7 @@ def _row_patch_job(state, st, queues, cache, scheduler, ci, key):
                 for s in obj.admission_check_states.values()):
             ok = False
     resume_now = resume_starts(info, cq_live, covers_pods,
-                               rec.resume.shape[1])
+                               st.n_groups, st.pod_sets)
     if (parked_now == bool(rec.parked[idx])
             and resume_now == tuple(rec.resume[idx].tolist())
             and ok == bool(rec.ok[idx])):
@@ -1086,8 +1087,11 @@ def pack_burst_streaming(structure, queues, cache, scheduler, clock,
     contract ``(plan, state, was_delta)``, bit-identical plans."""
     st = structure
     t0 = time.perf_counter()
-    key = (st.generation, st.resource_scale.tobytes(),
-           tuple(st.cq_names), window, _agg.agg_planes_enabled())
+    def state_key():
+        return (st.generation, st.resource_scale.tobytes(),
+                tuple(st.cq_names), window, _agg.agg_planes_enabled(),
+                st.pod_sets)
+    key = state_key()
     # hard dirt by journal: the queue manager's is the pending side's;
     # the cache's, where it names no workload, is a whole queue's
     dirty: set = set()
@@ -1133,7 +1137,7 @@ def pack_burst_streaming(structure, queues, cache, scheduler, clock,
                 if not _b._roundtrips_clean(
                         state.records[ci], queues.queue_for(name),
                         cache.cluster_queue(name), skeys,
-                        name in st.cq_covers_pods):
+                        name in st.cq_covers_pods, st):
                     dirty.add(name)   # the pending side's own facts
             row_jobs = []
             rows_verified = 0
@@ -1212,6 +1216,13 @@ def pack_burst_streaming(structure, queues, cache, scheduler, clock,
                 walked = [_walk_one(ci) for ci in cis]
             if any(rec is None for rec in walked):
                 return None, None, False
+            if state_key() != key:
+                # a row that came has more PodSets than the planes hold:
+                # the grid is laid out again at the structure's new
+                # extent (PackedStructure.note_pod_sets)
+                return _init_full(st, queues, cache, scheduler,
+                                  state_key(), min_m, window, arena,
+                                  stats, t0)
             placed = _place_rows(state, walked)
 
         with _span("burst.pack.grid"):

@@ -363,7 +363,11 @@ class Preemptor:
         """The reclaim oracle (preemption_oracle.go:40) for a cycle's
         questions at once: ``queries`` = [(head Info, FlavorResource,
         quantity)], each a target search of its own for that one
-        flavor-resource, all through the batched device dispatch.
+        flavor-resource, all through the batched device dispatch.  The
+        quantity is the flavor walk's ``val``: of a gang's later PodSet,
+        its request and what the head's earlier PodSets chose on that
+        flavor-resource (a head may ask about one flavor-resource twice,
+        at two quantities).
         Reclaim is possible when the search evicts nobody of the head's
         own queue."""
         with _span("cycle.nominate.oracle"):

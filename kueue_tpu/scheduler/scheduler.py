@@ -466,8 +466,8 @@ class Scheduler:
 
         def scalar_walk(wi: int) -> bool:
             """Host FlavorAssigner walk for one head (nominate-time,
-            snapshot state) — multi-RG/multi-PodSet/taints/
-            partial-admission/TAS heads stay inside the device-decided
+            snapshot state) — partial-admission/TAS heads, and gangs
+            the vector walk turns away, stay inside the device-decided
             cycle this way."""
             e = deferred[wi]
             e.inadmissible_msg = ""
@@ -500,7 +500,7 @@ class Scheduler:
             # group's best-mode pick is the reclaim oracle's
             # (flavorassigner.go:692 RECLAIM beats PREEMPT)
             self._pick_by_oracle(
-                cls, pre[cls.oracle_groups[pre].any(axis=1)], snapshot)
+                cls, pre[cls.oracle_groups[pre].any(axis=(1, 2))], snapshot)
             # in the walk's span, where the loop stood before the oracle
             # came between: the span's total by name is read, and
             # ``cycle.nominate.self`` is what no child covers
@@ -543,12 +543,14 @@ class Scheduler:
                 walked)
 
     def _pick_by_oracle(self, cls, heads, snapshot: Snapshot) -> None:
-        """The preempt slot of the heads' groups whose walk met several
-        preempt-capable flavors and no stop: every question the host
-        walk would put to the reclaim oracle in those groups, answered
-        in the cycle's batched search (one launch ahead of the heads'
-        own), and the Reclaim / Preempt lattice applied to the answers
-        a group."""
+        """The preempt slot of the heads' walks (one a PodSet and
+        group) that met several preempt-capable flavors and no stop:
+        every question the host walk would put to the reclaim oracle in
+        those walks (a (head, PodSet, flavor, resource) at the walk's
+        ``val``: the PodSet's request and what the earlier PodSets
+        chose there), answered in the cycle's batched search (one
+        launch ahead of the heads' own), and the Reclaim / Preempt
+        lattice applied to the answers a walk."""
         if not len(heads):
             return
         import numpy as np
@@ -557,9 +559,9 @@ class Scheduler:
                            dtype=bool)
         queries, at = [], []
         for hi, wi in enumerate(heads):
-            for s, ri, fr, qty in solver.oracle_queries(cls, int(wi)):
+            for p, s, ri, fr, qty in solver.oracle_queries(cls, int(wi)):
                 queries.append((cls.heads[wi], fr, qty))
-                at.append((hi, s, ri))
+                at.append((hi, p, s, ri))
         if queries:
             answers = self.preemptor.reclaim_possible_batch(queries,
                                                             snapshot)
@@ -691,8 +693,8 @@ class Scheduler:
         """Apply one fused-burst cycle's decisions to the real state.
 
         ``modeled``: {workload key: (kind, slots, tried, borrows,
-        targets)} from the burst kernel (``slots`` and ``tried`` one a
-        resource group of the head's queue), where kind ∈ "admit"|"skip"|"park"|"preempt"|
+        targets)} from the burst kernel (``slots`` and ``tried`` [P, G]:
+        one a PodSet and resource group of the head's queue), where kind ∈ "admit"|"skip"|"park"|"preempt"|
         "reserve"|"overlap_skip"|"pre_nofit" and ``targets`` (preempt
         only) is [(target key, target cq name), ...].  The caller has
         already validated that ``heads`` matches the modeled head set
@@ -707,6 +709,7 @@ class Scheduler:
         Info: the kernel's model of admitted capacity diverged from the
         real cache, so every decision in the cycle is suspect and the
         caller must re-decide on the host path."""
+        import numpy as np
         from ..ops.solver import build_slot_assignment
         from ..api.types import (
             IN_CLUSTER_QUEUE_REASON,
@@ -809,7 +812,14 @@ class Scheduler:
                                      "processing another workload")
                 stats.skipped.append(info.key)
             else:  # park: NoFit at nominate (BestEffortFIFO parks it)
+                # ... unless a PodSet before the one that found no
+                # flavor stopped mid-list: the host's record of it
+                # stands, and the head comes back for the next flavor
                 e.info.last_assignment = None
+                if (np.asarray(tried) >= 0).any():
+                    e.info.last_assignment = build_slot_assignment(
+                        info, cq, np.maximum(slot, 0), tried, Mode.NO_FIT,
+                        False).last_state
                 e.inadmissible_msg = ("couldn't assign flavors to pod "
                                       "set: insufficient quota")
                 stats.inadmissible.append(info.key)

@@ -488,8 +488,8 @@ def test_classify_np_and_the_jitted_classify_agree_under_a_plane():
         assert np.array_equal(np.asarray(got[4]), want["fit_slot0"])
         assert np.array_equal(np.asarray(got[3]), want["preempt0"])
         # nothing the pick or the oracle reads names a barred slot
-        assert not (want["preempt_slots"][:, 0] & ~eligible).any()
-        assert not (want["oracle_ask"] & ~eligible[:, :, None]).any()
+        assert not (want["preempt_slots"][:, 0, 0] & ~eligible).any()
+        assert not (want["oracle_ask"][:, 0] & ~eligible[:, :, None]).any()
         n = packed.wl_count
         assert (want["walk_ineligible"][:n] <= want["walk_slots"][:n]).all()
 

@@ -178,7 +178,12 @@ def test_multi_podset_workloads():
                 ]))
         return out
 
-    assert_parity(build)
+    # since PR 41 a two-PodSet head is the vector walk's: one pass a
+    # PodSet, the second charged with the first's choice
+    _, stats = assert_parity(build, expect_scalar=False)
+    assert stats["gang_heads"] >= 1 and stats["charged_walks"] >= 1, stats
+    assert stats["podset_walks"] == 2 * stats["walk_heads"], stats
+    assert stats["scalar_heads"] == 0, stats
 
 
 # ---------------------------------------------------------------------------
@@ -440,8 +445,10 @@ def test_mixed_vector_and_scalar_heads():
                 FlavorQuotas(name="default", resources={
                     "cpu": ResourceQuota(nominal=4000,
                                          borrowing_limit=4000)})])]))
-        # cq-1: two resource groups (vector heads since PR 37; its
-        # two-PodSet workloads below are the scalar heads)
+        # cq-1: two resource groups (vector heads since PR 37); its
+        # two-PodSet workloads are vector heads since PR 41, and the
+        # one with a partial admission that does not fit whole is the
+        # scalar head
         d.apply_cluster_queue(ClusterQueue(
             name="cq-1", cohort="team",
             resource_groups=[
@@ -467,6 +474,9 @@ def test_mixed_vector_and_scalar_heads():
             if q == 1 and i % 4 == 0:
                 pod_sets.append(PodSet(name="aux", count=1,
                                        requests={"cpu": 1000}))
+            if q == 1 and i % 4 == 2:
+                pod_sets = [PodSet(name="main", count=4, min_count=1,
+                                   requests={"cpu": 1500})]
             out.append(Workload(
                 name=f"wl-{i}", queue_name=f"lq-{q}",
                 priority=rng.choice([10, 50]), creation_time=float(i + 1),
