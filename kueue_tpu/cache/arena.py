@@ -23,7 +23,10 @@ it keeps and writes over for the next plan once nothing else refers to
 the last one: a window of 5 GB in pages the process already holds is
 copied and sent to the device at the bus's rate, where pages mapped
 fresh every window are faulted in one by one first and transfer at a
-rate that changes from window to window.
+rate that changes from window to window.  It also remembers which state
+of the slab the kept buffer holds (the caller's token), so a caller
+that says where the slab has changed since that state gets the buffer
+brought up to date by those cells, not copied whole.
 
 The arena also keeps the occupancy/growth counters surfaced as
 ``kueue_pack_arena_*`` gauges.
@@ -45,6 +48,20 @@ def _cap(n: int) -> int:
     return c
 
 
+def _copy_runs(buf: np.ndarray, view: np.ndarray, n: int, W: int,
+               where: tuple) -> int:
+    """Copy the runs ``where`` of ``view`` into ``buf`` (see
+    ``PlaneArena.snapshot``); returns the bytes copied."""
+    if view.shape[1] != n:
+        np.copyto(buf, view)
+        return buf.nbytes
+    cut = (view.shape[0], n // W, W) + view.shape[2:]
+    # splitting one axis of a strided view is itself a view: no copy
+    vals = view.reshape(cut)[where]
+    buf.reshape(cut)[where] = vals
+    return vals.nbytes
+
+
 class PlaneArena:
     """Named persistent plane slabs; see module docstring."""
 
@@ -52,10 +69,18 @@ class PlaneArena:
         self._slabs: dict[str, np.ndarray] = {}
         self._fills: dict[str, object] = {}
         self._snaps: dict[str, np.ndarray] = {}
+        # the caller's token for the state a kept buffer holds
+        self._snap_tokens: dict[str, object] = {}
         self.stats = {"arena_growth_events": 0, "arena_planes": 0,
                       "arena_bytes": 0, "arena_used_bytes": 0,
+                      # buffers written over in place, whole or by cells
                       "arena_snapshots_reused": 0,
                       "arena_snapshots_fresh": 0,
+                      # every snapshot is one or the other: brought up
+                      # to date by the cells that changed, or copied whole
+                      "arena_snapshots_delta": 0,
+                      "arena_snapshots_whole": 0,
+                      # bytes copied, not bytes held
                       "arena_snapshot_bytes": 0}
 
     def drop(self) -> None:
@@ -63,6 +88,7 @@ class PlaneArena:
         self._slabs.clear()
         self._fills.clear()
         self._snaps.clear()
+        self._snap_tokens.clear()
 
     def ensure(self, name: str, shape: tuple, dtype, fill,
                grow_axes: int = 2) -> np.ndarray:
@@ -95,24 +121,55 @@ class PlaneArena:
     def view(self, name: str, shape: tuple) -> np.ndarray:
         return self._slabs[name][tuple(slice(0, int(s)) for s in shape)]
 
-    def snapshot(self, name: str, view: np.ndarray) -> np.ndarray:
+    def snapshot(self, name: str, view: np.ndarray, token=None,
+                 prev_token=None, runs=None) -> np.ndarray:
         """A contiguous copy of ``view`` for a plan to own.  The buffer
         of the last snapshot under ``name`` is written over when the
         arena holds the only reference to it, that is when the plan it
         was made for, every view of it and any transfer still reading it
         are gone; a buffer somebody still holds is left to its holder
-        and a fresh one takes its place."""
+        and a fresh one takes its place.
+
+        ``token`` names the state of the slab this snapshot holds.  A
+        caller that knows where the slab has changed since the state
+        ``prev_token`` passes ``runs``, ``(n, W, (i, j))``: axis 1, of
+        length ``n``, cut into runs of ``W``, and run ``j[k]`` of row
+        ``i[k]`` for every k, which together cover every cell that has
+        changed, every trailing axis whole.  Where the kept buffer is
+        nobody else's, has the view's shape and dtype and holds the
+        state ``prev_token``, only those runs are copied into it; a
+        plane whose axis 1 is not the one the runs were laid out for
+        (one column that stands for a grid) is small and copied whole
+        under the same conditions.  In every other case (no runs, no
+        kept buffer, another shape, a buffer still held, a state in
+        between that no snapshot was taken of) the copy is whole.  The
+        result is the array a whole copy would have given, provided the
+        caller's statement holds: a cell that changed outside the runs
+        is not picked up.  What the last plan's holder wrote into its
+        copy inside the runs is written over like any other cell."""
         buf = self._snaps.pop(name, None)
+        held = self._snap_tokens.pop(name, None)
+        stats = self.stats
         # two references: ``buf`` and getrefcount's own argument
         if (buf is not None and buf.shape == view.shape
                 and buf.dtype == view.dtype and sys.getrefcount(buf) == 2):
-            np.copyto(buf, view)
-            self.stats["arena_snapshots_reused"] += 1
+            if (runs is not None and prev_token is not None
+                    and held == prev_token):
+                copied = _copy_runs(buf, view, *runs)
+                stats["arena_snapshots_delta"] += 1
+            else:
+                np.copyto(buf, view)
+                copied = buf.nbytes
+                stats["arena_snapshots_whole"] += 1
+            stats["arena_snapshots_reused"] += 1
         else:
             buf = view.copy()
-            self.stats["arena_snapshots_fresh"] += 1
+            copied = buf.nbytes
+            stats["arena_snapshots_fresh"] += 1
+            stats["arena_snapshots_whole"] += 1
         self._snaps[name] = buf
-        self.stats["arena_snapshot_bytes"] += buf.nbytes
+        self._snap_tokens[name] = token
+        stats["arena_snapshot_bytes"] += copied
         return buf
 
     def refresh_stats(self, used_shapes: dict | None = None) -> dict:

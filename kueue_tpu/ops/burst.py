@@ -1979,6 +1979,10 @@ class BurstSolver:
                       # of those copies, the ones that had to allocate:
                       # the kept buffer was still somebody's
                       "pack_arena_snapshots_fresh": 0,
+                      # buffers brought up to date by the cells under
+                      # the window's row_extent, and buffers copied whole
+                      "pack_arena_snapshots_delta": 0,
+                      "pack_arena_snapshots_whole": 0,
                       # graceful degradation (chaos shard.device_loss or
                       # lose_devices): mesh rebuilt over the survivors,
                       # serial fallback when fewer than two remain

@@ -71,6 +71,19 @@ or patched) and ``row_extent`` (a CQ's rows as they were or as they
 are, whichever reach further: every cell a delta window writes lies
 under it), which the launch's device-resident copies update from
 (ops/burst.py ``_resident_inputs``, ``_resident_rows``).
+
+The host copy is made the same way (``_materialize``,
+``PlaneArena.snapshot``): the arena keeps the buffers of the last plan
+and the pack token whose state they hold, and a delta window whose
+``prev_token`` is that token, with the buffers released and of the
+same shapes, copies the runs of ``RESIDENT_RUN`` slots that cover
+``[ci, 0:row_extent[ci])`` into them, every trailing axis whole, one
+indexed assignment a plane.  The copy is whole whenever that does not
+hold: a full pack (no ``row_extent``), a grid whose M grew (another
+shape), a window after a delta pack that made no plan (its token is
+not the buffers'), a plan somebody still holds (a fresh buffer).
+``arena_snapshots_delta`` / ``arena_snapshots_whole`` count the two,
+``arena_snapshot_bytes`` the bytes copied.
 """
 
 from __future__ import annotations
@@ -508,7 +521,20 @@ def _bump(stats, key, n=1):
 
 def _materialize(st, state, s, views, scheduler, dirty_cis, prev_token,
                  rank_patches, stats, row_extent=None):
-    """Build the BurstPlan snapshot from the patched arena state."""
+    """Build the BurstPlan snapshot from the patched arena state.
+
+    The plan owns a copy of every row plane and of the keys' grid
+    (``PlaneArena.snapshot``).  A delta window passes the token of the
+    state before it and its ``row_extent``: where the buffer the last
+    plan left holds that state and nobody holds the buffer, the copy is
+    the cells ``[ci, 0:row_extent[ci])`` laid out as runs, since every
+    cell the window wrote lies under them; a full pack, a grid that
+    grew, a window after one that made no plan and a plan still held
+    get the whole copy by the arena's own conditions.  The one thing a
+    plan's holder writes into its copy is a finish into ``death0``
+    (``Driver._fill_burst_finishes``), in a row of that plan, so under
+    the next window's ``rows_before`` and its ``row_extent``: the runs
+    write the arena's constant plane back over it."""
     C = len(st.cq_names)
     M = state.M
     n = int(state.n_rows_cq.sum())
@@ -556,9 +582,19 @@ def _materialize(st, state, s, views, scheduler, dirty_cis, prev_token,
     cand_rows, cand_lmem, self_lmem = tables
     arena = state.arena
     with _span("burst.pack.grid.snapshot"):
-        arrays = {name: arena.snapshot(name, views[name])
+        # the runs the launch's device mirror is updated by
+        # (BurstSolver._send_runs), laid out once for every plane
+        runs = None
+        if row_extent is not None:
+            W = min(_b.RESIDENT_RUN, M)
+            at, _ = _b._row_runs(row_extent, W,
+                                 int((-(-row_extent // W)).sum()))
+            runs = (M, W, (at[:, 0], at[:, 1] // W))
+        arrays = {name: arena.snapshot(name, views[name], state.token,
+                                       prev_token, runs)
                   for name in _ROW_PLANES}
-        keys_grid = arena.snapshot("keys_grid", views["keys_grid"])
+        keys_grid = arena.snapshot("keys_grid", views["keys_grid"],
+                                   state.token, prev_token, runs)
     arrays["u_cq0"] = views["u_cq0"].copy()
     arrays.update(
         potential0=s.potential0, subtree=st.subtree_quota,

@@ -712,3 +712,282 @@ def test_delta_pack_runs_at_any_dirty_share():
     assert stats.get("burst_delta_packs", 0) == 2
     assert not hasattr(__import__("kueue_tpu.ops.stream_pack",
                                   fromlist=["x"]), "_DELTA_MAX_DIRTY_FRAC")
+
+
+# ---------------------------------------------------------------------------
+# A plan's snapshot is brought up to date from the plan before it
+# ---------------------------------------------------------------------------
+
+KINDS = ("one_flavor", "four_plain", "four_labelled", "two_groups",
+         "two_podsets")
+
+
+def build_kind(kind):
+    """Four queues in two cohorts, full enough to preempt, in the shape
+    of one of the five deployment kinds; returns (driver, clock, a
+    maker of the kind's workloads)."""
+    from kueue_tpu.api.types import (FlavorFungibility,
+                                     FlavorFungibilityPolicy, Taint,
+                                     Toleration)
+    if kind == "one_flavor":
+        d, clock = build_cluster(preempt=True)
+        return d, clock, lambda name, lq, cpu, prio, t, k: mk(
+            name, lq, cpu, prio=prio, t=t)
+    clock = Clock()
+    d = Driver(clock=clock, use_device_solver=True)
+    spot = Taint(key="spot", value="true", effect="NoSchedule")
+    GI = 1 << 30
+    if kind in ("four_plain", "four_labelled"):
+        labelled = kind == "four_labelled"
+        flavors = [
+            ResourceFlavor(name=n, node_labels=(
+                {"instance-type": n.split("-")[0]} if labelled else {}),
+                node_taints=([spot] if labelled and "spot" in n else []))
+            for n in ("reserved", "on-demand", "spot-a", "spot-b")]
+        groups = [ResourceGroup(
+            covered_resources=["cpu"],
+            flavors=[FlavorQuotas(name=f.name, resources={
+                "cpu": ResourceQuota(nominal=1500, borrowing_limit=1000)})
+                for f in flavors])]
+    else:
+        flavors = [
+            ResourceFlavor(name="x86", node_labels={"cpu-arch": "x86"}),
+            ResourceFlavor(name="arm", node_labels={"cpu-arch": "arm"}),
+            ResourceFlavor(name="default-flavor")]
+        groups = [
+            ResourceGroup(covered_resources=["cpu"], flavors=[
+                FlavorQuotas(name=n, resources={"cpu": ResourceQuota(
+                    nominal=3000, borrowing_limit=1000)})
+                for n in ("x86", "arm")]),
+            ResourceGroup(covered_resources=["memory"], flavors=[
+                FlavorQuotas(name="default-flavor", resources={
+                    "memory": ResourceQuota(nominal=24 * GI,
+                                            borrowing_limit=8 * GI)})])]
+    for f in flavors:
+        d.apply_resource_flavor(f)
+    for c in range(2):
+        for q in range(2):
+            d.apply_cluster_queue(ClusterQueue(
+                name=f"cq-{c}-{q}", cohort=f"co-{c}",
+                flavor_fungibility=FlavorFungibility(
+                    when_can_preempt=FlavorFungibilityPolicy.TRY_NEXT_FLAVOR),
+                preemption=PreemptionPolicy(
+                    reclaim_within_cohort=ReclaimWithinCohort.ANY,
+                    within_cluster_queue=WithinClusterQueue.LOWER_PRIORITY),
+                queueing_strategy=QueueingStrategy.BEST_EFFORT_FIFO,
+                resource_groups=groups))
+            d.apply_local_queue(LocalQueue(name=f"lq-{c}-{q}",
+                                           cluster_queue=f"cq-{c}-{q}"))
+    tolerates = Toleration(key="spot", operator="Exists",
+                           effect="NoSchedule")
+
+    def make(name, lq, cpu, prio, t, k):
+        if kind == "four_plain":
+            sets = [PodSet(name="main", count=1, requests={"cpu": cpu})]
+        elif kind == "four_labelled":
+            # four job classes, three of them constrained
+            sets = [PodSet(
+                name="main", count=1, requests={"cpu": cpu},
+                node_selector=[{}, {"instance-type": "on-demand"},
+                               {"instance-type": "spot"}, {}][k % 4],
+                tolerations=[tolerates] if k % 4 >= 2 else [])]
+        else:
+            sel = [{}, {"cpu-arch": "arm"}, {"cpu-arch": "x86"}][k % 3]
+            req = {"cpu": cpu, "memory": 2 * GI}
+            sets = [PodSet(name="workers", count=1, requests=dict(req),
+                           node_selector=sel)]
+            if kind == "two_podsets" and k % 3:
+                sets.insert(0, PodSet(name="launcher", count=1,
+                                      requests=dict(req),
+                                      node_selector={"cpu-arch": "x86"}))
+        return Workload(name=name, queue_name=lq, priority=prio,
+                        creation_time=t, pod_sets=sets)
+    return d, clock, make
+
+
+def assert_plan_is_the_arenas(d, plan, ctx):
+    """Every plane the arena snapshots for a plan equals the arena's
+    live view of it, and the plan owns it."""
+    from kueue_tpu.ops.stream_pack import _ROW_PLANES
+    arena = d.cache._pack_arena
+    for name in _ROW_PLANES:
+        got = plan.arrays[name]
+        live = arena.view(name, got.shape)
+        assert got.dtype == live.dtype, f"{ctx}: {name} dtype"
+        assert not np.shares_memory(got, live), f"{ctx}: {name}"
+        assert np.array_equal(got, live), \
+            f"{ctx}: {name} differs from the arena at " \
+            f"{np.argwhere(got != live)[:5].tolist()}"
+    live = arena.view("keys_grid", (plan.C, plan.M))
+    assert plan.keys.tolist() == live.tolist(), f"{ctx}: keys"
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_a_delta_windows_snapshot_is_the_whole_copys(monkeypatch, kind):
+    """Window after window on each deployment kind's shape, with runs
+    short enough that a queue's rows take several: after every
+    ``_materialize`` the plan's planes and keys equal the arena's live
+    views and a from-scratch pack, though a delta window copied only
+    the cells under its ``row_extent`` into the buffer the last plan
+    left.  Admissions, finishes, evictions, a queue that shrinks, and
+    one that grows past M (a new shape: the whole copy, then the chain
+    again)."""
+    from kueue_tpu.ops import burst as _b
+    monkeypatch.setattr(_b, "RESIDENT_RUN", 4)
+    d, clock, make = build_kind(kind)
+    k = 0
+    for c in range(2):
+        for q in range(2):
+            for i in range(6):
+                k += 1
+                d.create_workload(make(
+                    f"w-{c}-{q}-{i}", f"lq-{c}-{q}", 1500, (i % 3) * 10,
+                    float(10 * c + 3 * q + i), k))
+    stats = {}
+    box = {"state": None, "min_m": 16}
+
+    def window(ctx):
+        st = current_structure(d)
+        plan, box["state"], _ = pack_burst_cached(
+            st, d.queues, d.cache, d.scheduler, d.clock,
+            state=box["state"], min_m=box["min_m"], window=0, stats=stats)
+        assert plan is not None, ctx
+        assert_plan_is_the_arenas(d, plan, f"{kind}:{ctx}")
+        assert_plans_equal(plan, pack_burst(
+            st, d.queues, d.cache, d.scheduler, d.clock,
+            min_m=box["min_m"], window=0), f"{kind}:{ctx}")
+        box["min_m"] = max(box["min_m"], plan.M)
+        return plan.M, plan.prev_token is not None
+
+    def counted():
+        return tuple(stats.get("pack_arena_snapshots_" + n, 0)
+                     for n in ("delta", "whole", "fresh"))
+
+    def cycles(n):
+        for _ in range(n):
+            clock.t += 1.0
+            d.schedule_once()
+
+    def admissions():
+        cycles(2)
+        assert d.admitted_keys()
+
+    def finishes():
+        for key in sorted(d.admitted_keys())[:2]:
+            d.finish_workload(key)
+
+    def evictions():
+        before = d.admitted_keys()
+        for i in range(3):
+            d.create_workload(make(f"urgent-{i}", "lq-0-0", 1500, 100,
+                                   clock.t + i * 1e-3, 3))
+        cycles(4)
+        assert before - d.admitted_keys(), "nothing was evicted"
+
+    def arrivals():
+        for i in range(3):
+            d.create_workload(make(f"late-{i}", f"lq-{i % 2}-1", 1000, 5,
+                                   clock.t + i * 1e-3, i))
+
+    def a_queue_shrinks():
+        for key in [x for x in sorted(d.workloads) if "w-1-0-" in x][1:]:
+            if key in d.admitted_keys():
+                d.finish_workload(key)
+            else:
+                d.delete_workload(key)
+
+    def a_queue_grows_past_m():
+        for i in range(box["min_m"]):
+            d.create_workload(make(f"more-{i}", "lq-1-1", 1000, 5 * (i % 4),
+                                   clock.t + i * 1e-3, i))
+
+    m0, _ = window("init")
+    assert counted() == (0, 16, 16)
+    chains = 0
+    steps = [admissions, evictions, finishes, arrivals, a_queue_shrinks,
+             lambda: cycles(1), a_queue_grows_past_m, arrivals, finishes]
+    for n, step in enumerate(steps):
+        step()
+        before = counted()
+        m, chained = window(f"{n}:{getattr(step, '__name__', 'cycle')}")
+        delta, whole, fresh = (b - a for a, b in zip(before, counted()))
+        assert delta + whole == 16
+        if not chained:
+            # the planes were laid out again: a gang's second PodSet
+            assert kind == "two_podsets" and delta == 0
+        elif step is a_queue_grows_past_m:
+            # new shapes, nothing to bring up to date; a mask plane of
+            # one column has no M and keeps its buffer
+            assert m > m0 and delta <= 1 and fresh == 16 - delta
+        else:
+            assert (delta, whole) == (16, 0), (n, counted())
+            chains += 1
+    assert chains >= 6
+    assert stats["burst_full_packs"] + stats["burst_delta_packs"] == (
+        1 + len(steps))
+
+
+def test_a_plans_finishes_are_gone_from_the_next_windows_snapshot(
+        monkeypatch):
+    """``schedule_burst(K, runtime=2)`` has ``_fill_burst_finishes``
+    write finish cycles into the ``death0`` its plan owns.  The next
+    delta window brings that very buffer up to date by the cells under
+    its ``row_extent``; the finishes were rows of the plan before, so
+    they lie under it and the arena's constant plane comes back over
+    them: every plan handed to the driver equals the arena's views and
+    a from-scratch pack before the driver writes into it."""
+    from kueue_tpu.ops import burst as _b
+    monkeypatch.setattr(_b, "RESIDENT_RUN", 4)
+    d, clock = build_cluster(preempt=True)
+    for c in range(2):
+        for q in range(2):
+            for i in range(8):
+                d.create_workload(mk(
+                    f"w-{c}-{q}-{i}", f"lq-{c}-{q}", 1500,
+                    prio=(i % 3) * 10, t=float(10 * c + 3 * q + i)))
+    real_pack = _b.pack_burst_cached
+    real_fill = Driver._fill_burst_finishes
+    wrote, followed = set(), []
+
+    def checked_pack(structure, queues, cache, scheduler, clk, **kw):
+        plan, state, was_delta = real_pack(structure, queues, cache,
+                                           scheduler, clk, **kw)
+        if plan is not None:
+            ctx = f"window {plan.pack_token}"
+            assert_plan_is_the_arenas(d, plan, ctx)
+            assert (plan.arrays["death0"] == _b.I32_MAX).all(), ctx
+            assert_plans_equal(plan, pack_burst(
+                structure, queues, cache, scheduler, clk,
+                min_m=kw.get("min_m", 0), window=kw.get("window", 0)), ctx)
+            if plan.prev_token in wrote:
+                followed.append(plan.pack_token)
+        return plan, state, was_delta
+
+    def noting_fill(self, st, plan, *a):
+        ok = real_fill(self, st, plan, *a)
+        if plan.finite_deaths:
+            assert (plan.arrays["death0"] != _b.I32_MAX).any()
+            wrote.add(plan.pack_token)
+        return ok
+
+    monkeypatch.setattr("kueue_tpu.ops.burst.pack_burst_cached",
+                        checked_pack)
+    monkeypatch.setattr(Driver, "_fill_burst_finishes", noting_fill)
+
+    def tick(_k):
+        clock.t += 1.0
+
+    for _ in range(2):
+        tick(0)
+        d.schedule_once()
+    for n in range(4):
+        # a finish the caller schedules and the ones ``runtime`` models:
+        # both are rows of the plan, written into its ``death0``
+        d.schedule_burst(6, runtime=2, on_cycle_start=tick, pipeline=False,
+                         external_finishes={1: sorted(d.admitted_keys())[:1]})
+        d.create_workload(mk(f"late-{n}", f"lq-{n % 2}-1", 1500,
+                             prio=10 * n, t=clock.t + 0.5))
+    bs = d._burst_solver.stats
+    assert wrote and followed, (wrote, followed)
+    assert bs["pack_arena_snapshots_delta"] > 0
+    assert bs["pack_arena_snapshots_delta"] % 16 == 0

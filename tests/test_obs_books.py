@@ -218,7 +218,7 @@ def test_tracing_changes_no_decision_and_no_stats(traced_toy):
         # as is the young generation's count where a section closed
         return {k: v for k, v in stats.items()
                 if not k.endswith(("_s", "_ms"))
-                and not k.startswith("pack_arena_snapshots_")
+                and not k.startswith("pack_arena_snapshot")
                 and k != "collector_deferred_allocations"}
     assert counts(dt.scheduler.solver.stats) == \
         counts(dc.scheduler.solver.stats)
@@ -298,24 +298,73 @@ def test_collection_on_another_thread_records_nothing():
 # The two counters
 # ---------------------------------------------------------------------------
 
-def test_arena_snapshot_bytes_are_the_planes_a_plan_holds(traced_toy):
-    """Window by window: the counter grows by the summed ``nbytes`` of
-    what ``PlaneArena.snapshot`` handed the plan (the row planes and
-    the keys)."""
-    from kueue_tpu.ops import stream_pack
+def test_arena_snapshot_bytes_are_the_bytes_copied(traced_toy):
+    """Window by window the counter grows by what ``PlaneArena.snapshot``
+    copied for the plan (the row planes and the keys): every plane's
+    ``nbytes`` in a full pack's window, and in a delta window that
+    chains the plan before it the bytes of the runs under its
+    ``row_extent`` (a plane with no cell a grid slot whole)."""
+    import numpy as np
+    from kueue_tpu.ops import burst, stream_pack
     d, _, _, per_round = traced_toy
     arena = d.cache._pack_arena
-    held = sum(buf.nbytes for buf in arena._snaps.values())
-    assert set(arena._snaps) == set(stream_pack._ROW_PLANES) | {"keys_grid"}
+    planes = set(stream_pack._ROW_PLANES) | {"keys_grid"}
+    assert set(arena._snaps) == set(arena._snap_tokens) == planes
     total = per_round[-1]["pack_arena_snapshot_bytes"]
     assert total == arena.stats["arena_snapshot_bytes"] > 0
-    windows = (per_round[-1]["burst_full_packs"]
-               + per_round[-1]["burst_delta_packs"])
-    # the toy's grid does not grow after its first window
-    assert total == windows * held
-    # and a round's growth is its windows'
-    w1 = per_round[0]["burst_full_packs"] + per_round[0]["burst_delta_packs"]
-    assert per_round[0]["pack_arena_snapshot_bytes"] == w1 * held
+    assert per_round[-1]["pack_arena_snapshots_delta"] == (
+        arena.stats["arena_snapshots_delta"]) > 0
+    assert (per_round[-1]["pack_arena_snapshots_delta"]
+            + per_round[-1]["pack_arena_snapshots_whole"]) == len(planes) * (
+        per_round[-1]["burst_full_packs"] + per_round[-1]["burst_delta_packs"])
+
+    # the same toy with runs short enough that a queue's rows take a few
+    real = stream_pack._materialize
+    windows = []
+
+    def noting(st, state, *a, **kw):
+        stats = state.arena.stats
+        before = dict(stats)
+        plan = real(st, state, *a, **kw)
+        held = {n: plan.arrays[n] for n in stream_pack._ROW_PLANES}
+        held["keys_grid"] = plan.keys._g
+        windows.append((
+            plan.row_extent, plan.M,
+            {n: (x.shape, x.nbytes) for n, x in held.items()},
+            {k: stats[k] - before[k] for k in (
+                "arena_snapshots_delta", "arena_snapshots_whole",
+                "arena_snapshots_fresh", "arena_snapshot_bytes")}))
+        return plan
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(burst, "RESIDENT_RUN", 2)
+        mp.setattr(stream_pack, "_materialize", noting)
+        toy_rounds(False)
+    assert windows[0][0] is None            # the structure's full pack
+    deltas = 0
+    for extent, M, shapes, grew in windows:
+        whole = sum(nbytes for _, nbytes in shapes.values())
+        if extent is None:
+            assert grew == {"arena_snapshots_delta": 0,
+                            "arena_snapshots_whole": len(planes),
+                            "arena_snapshots_fresh": len(planes),
+                            "arena_snapshot_bytes": whole}
+            continue
+        # the toy's grid does not grow and its plans are let go of; on
+        # this backend a plane put on the device whole shares the plan's
+        # memory, and the mirror keeps the ``death0`` of the upload that
+        # installed it: the window after finds that one buffer held
+        held = {"death0"} if grew["arena_snapshots_fresh"] else set()
+        assert grew["arena_snapshots_fresh"] == len(held)
+        assert grew["arena_snapshots_delta"] == len(planes) - len(held)
+        W = min(2, M)
+        cells = int((-(-np.asarray(extent) // W)).sum()) * W
+        want = sum(nbytes if shape[1] != M or name in held
+                   else cells * (nbytes // (shape[0] * M))
+                   for name, (shape, nbytes) in shapes.items())
+        assert grew["arena_snapshot_bytes"] == want < whole
+        deltas += 1
+    assert deltas >= 2
 
 
 @pytest.mark.parametrize("how", ["incremental", "full"])

@@ -101,6 +101,114 @@ def test_arena_snapshot_is_written_over_only_when_nobody_holds_it(holder):
         assert np.array_equal(held, kept[1, 2:])
 
 
+def _runs_under(extent, n, W):
+    """The arena's ``runs`` for the cells ``[i, 0:extent[i])``."""
+    from kueue_tpu.ops.burst import _row_runs
+    extent = np.asarray(extent)
+    at, _ = _row_runs(extent, W, int((-(-extent // W)).sum()))
+    return (n, W, (at[:, 0], at[:, 1] // W))
+
+
+@pytest.mark.parametrize("case", [
+    "chains", "trailing_axis", "objects", "one_column", "stale_token",
+    "changed_shape", "held", "no_extent", "first_of_a_name",
+    "outside_extent", "holder_wrote"])
+def test_arena_snapshot_is_brought_up_to_date_by_the_cells_that_changed(case):
+    """A kept buffer nobody holds, of the view's shape, that holds the
+    state ``prev_token`` takes only the runs the caller names and equals
+    a whole copy; any condition short of that is the whole copy.  The
+    statement that every changed cell lies in the runs is the caller's
+    (the pack's ``row_extent``): a cell changed outside them is not
+    picked up.  What a holder wrote into its copy inside the runs (the
+    driver's finishes in ``death0``) is written over."""
+    from kueue_tpu.cache.arena import PlaneArena
+    arena = PlaneArena()
+    C, M, W = 5, 16, 4
+    tail = {"trailing_axis": (3,)}.get(case, ())
+    dtype = object if case == "objects" else np.int32
+    fill = None if case == "objects" else -1
+    shape = (C, 1) + tail if case == "one_column" else (C, M) + tail
+    live = arena.ensure("p", shape, dtype, fill)
+    # the slab is wider than the view: the view's rows are strided
+    assert arena._slabs["p"].shape[0] > C
+
+    def values(k):
+        a = (np.arange(live.size).reshape(live.shape) * 3 + k)
+        return (np.frompyfunc(lambda x: f"k{x}", 1, 1)(a)
+                if case == "objects" else a.astype(np.int32))
+
+    live[...] = values(0)
+    first = arena.snapshot("p", live, token=10)
+    assert arena.stats["arena_snapshots_whole"] == 1
+    assert arena.stats["arena_snapshot_bytes"] == first.nbytes
+    where = first.ctypes.data
+    if case == "holder_wrote":
+        first[0, 2] = 77        # a row of that plan: under the extent
+        first[3, 0] = 78
+    held = first if case == "held" else None
+    del first
+    # the window's changes: rows came, went and moved under the extent
+    extent = np.array([6, 0, 16, 3, 1])
+    new = values(1)
+    under = np.arange(live.shape[1])[None, :] < extent[:, None]
+    if case == "one_column":
+        under = np.ones((C, 1), bool)
+    live[under] = new[under]
+    if case == "outside_extent":
+        live[1, 9] = 12345      # no run covers it: extent[1] is 0
+    if case == "changed_shape":
+        live = arena.ensure("p", (C + 1, M), dtype, fill)
+    name = "q" if case == "first_of_a_name" else "p"
+    runs = None if case == "no_extent" else _runs_under(extent, M, W)
+    prev = 9 if case == "stale_token" else 10
+    before = dict(arena.stats)
+    second = arena.snapshot(name, live, token=11, prev_token=prev, runs=runs)
+    grew = {k: v - before[k] for k, v in arena.stats.items()
+            if v != before[k]}
+    assert second.flags.c_contiguous and second.base is None
+    assert second.dtype == live.dtype and second.shape == live.shape
+    n_runs = int((-(-extent // W)).sum())
+    if case in ("chains", "trailing_axis", "objects", "holder_wrote"):
+        assert np.array_equal(second, live)
+        assert second.ctypes.data == where
+        assert grew == {"arena_snapshots_delta": 1,
+                        "arena_snapshots_reused": 1,
+                        "arena_snapshot_bytes":
+                            n_runs * W * second[0, :1].nbytes}
+    elif case == "one_column":
+        # no cell a grid slot: copied whole, under the same conditions
+        assert np.array_equal(second, live)
+        assert grew == {"arena_snapshots_delta": 1,
+                        "arena_snapshots_reused": 1,
+                        "arena_snapshot_bytes": second.nbytes}
+    elif case == "outside_extent":
+        assert second[1, 9] != live[1, 9]
+        live[1, 9] = second[1, 9]
+        assert np.array_equal(second, live)
+    elif case in ("stale_token", "no_extent"):
+        assert np.array_equal(second, live)
+        assert second.ctypes.data == where
+        assert grew == {"arena_snapshots_whole": 1,
+                        "arena_snapshots_reused": 1,
+                        "arena_snapshot_bytes": second.nbytes}
+    else:
+        assert np.array_equal(second, live)
+        assert grew == {"arena_snapshots_whole": 1,
+                        "arena_snapshots_fresh": 1,
+                        "arena_snapshot_bytes": second.nbytes}
+    if case == "held":
+        assert np.array_equal(held, values(0))
+    # whichever way it was made, the buffer now holds the state 11 and
+    # the next window chains it
+    del second
+    live[2, 0] = live[0, 0]
+    chained = arena.stats["arena_snapshots_delta"]
+    third = arena.snapshot(name, live, token=12, prev_token=11,
+                           runs=_runs_under(extent, M, W))
+    assert arena.stats["arena_snapshots_delta"] == chained + 1
+    assert np.array_equal(third, live)
+
+
 def test_a_plan_still_held_keeps_its_planes_across_the_next_pack():
     """Two plans of one pack state held side by side do not share
     memory; released, the next pack takes the newest's buffers, and its
