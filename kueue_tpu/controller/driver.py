@@ -970,21 +970,23 @@ class Driver:
         """``schedule_burst``'s loop, inside its ``burst`` span: what no
         child span covers is the span's self time."""
         import numpy as np
-        from ..ops.burst import (BurstSolver, pack_burst_cached,
-                                 K_BURST_LADDER)
+        from ..ops.burst import (BurstSolver, fs_window_statics,
+                                 pack_burst_cached, K_BURST_LADDER)
 
         ext = {int(k): list(v) for k, v in
                (external_finishes or {}).items()}
         out: list = []
-        burst_ineligible = (
-            self.scheduler.fair_sharing
-            or (self.wait_for_pods_ready.enable
-                and self.wait_for_pods_ready.block_admission))
         if self._burst_solver is None:
             self._burst_solver = BurstSolver()
             shards = self._env_shards()
             if shards > 1:
                 self._burst_solver.set_shards(shards)
+        fair = self.scheduler.fair_sharing
+        burst_ineligible = (
+            (self.wait_for_pods_ready.enable
+             and self.wait_for_pods_ready.block_admission)
+            # the sharded window has no tournament
+            or (fair and self._burst_solver.n_shards > 1))
         solver = self.scheduler.solver
         normal_streak = 0   # cycles to run normally before re-bursting
 
@@ -1003,6 +1005,10 @@ class Driver:
         # stale entries the moment the reservation drops)
         sched_ts: dict = {key: _reservation_ts(key)
                           for keys in ext.values() for key in keys}
+        # the reservation each applied cycle's admissions took: a
+        # modeled run ends ``runtime`` cycles after the admission that
+        # began it, and ends that one alone
+        adm_ts: list[dict] = []
 
         def finish_cycle(stats) -> None:
             """Record one applied cycle + its end-of-cycle finishes.
@@ -1014,16 +1020,20 @@ class Driver:
             import time as _time
             k = len(out)
             out.append(stats)
+            adm_ts.append({key: _reservation_ts(key)
+                           for key in stats.admitted})
             for key in stats.admitted:
                 sched_ts[key] = _reservation_ts(key)
-            due = list(ext.pop(k, []))
+            due = [key for key in ext.pop(k, [])
+                   if _reservation_ts(key) == sched_ts.get(key)]
             if runtime > 0 and k - runtime >= 0:
-                due.extend(out[k - runtime].admitted)
+                began = adm_ts[k - runtime]
+                due.extend(key for key in out[k - runtime].admitted
+                           if _reservation_ts(key) == began[key])
             t0 = _time.perf_counter()
             batch = [key for key in due
                      if (wl := self.workloads.get(key)) is not None
-                     and wl.has_quota_reservation
-                     and _reservation_ts(key) == sched_ts.get(key)]
+                     and wl.has_quota_reservation]
             if batch:
                 self.finish_workloads(batch)
             stats.finish_s = _time.perf_counter() - t0
@@ -1142,6 +1152,11 @@ class Driver:
                         min_m=self._burst_m, window=K, stats=bstats)
                 bstats["burst_pack_s"] += time.perf_counter() - _t_pack
                 bstats["burst_packs"] += 1
+                if fair and plan is not None:
+                    plan.fs_statics = fs_window_statics(
+                        st, self.cache.snapshot)
+                    if plan.fs_statics is None:
+                        plan = None      # the int32 DRS could overflow
                 if plan is None:
                     if not normal_cycle() and quiescent():
                         break
@@ -1162,7 +1177,7 @@ class Driver:
                         keys = [key for key in out[j].admitted
                                 if _reservation_ts(key) is not None
                                 and _reservation_ts(key)
-                                == sched_ts.get(key)]
+                                == adm_ts[j][key]]
                         if keys:
                             sched.setdefault(due, []).extend(keys)
                 if not self._fill_burst_finishes(st, plan, sched,
@@ -1269,6 +1284,8 @@ class Driver:
                         bstats["burst_dirty_scalar"] += 1
                     if r & _b.DIRTY_RESUME:
                         bstats["burst_dirty_resume"] += 1
+                    if r & _b.DIRTY_FAIR_PREEMPT:
+                        bstats["burst_fair_dirty_cycles"] += 1
                     normal_cycle(heads=heads, advance=False)
                     if applied == 0:
                         dirty_backoff = min(8, max(1, 2 * dirty_backoff))
@@ -1299,6 +1316,8 @@ class Driver:
                     if stats is not None:
                         if has_pre_kind:
                             bstats["burst_preempt_cycles"] += 1
+                        if fair:
+                            solver.stats["fs_window_cycles"] += 1
                         self.metrics.admission_attempt(
                             bool(stats.admitted), stats.duration_s)
                         if stats.admitted:

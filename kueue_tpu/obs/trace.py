@@ -87,6 +87,7 @@ HOT_PATH_PHASES = (
     "cycle.nominate.search_decode",   # masks to Target lists
     "cycle.nominate.search_fallback",  # the one-launch-a-head route
     "cycle.nominate.scan_dispatch",   # pack targets + admit-scan dispatch
+    "cycle.nominate.fair_preempt",    # one fair-sharing target search
     "cycle.order",      # classical sort or fair-sharing tournament setup
     "cycle.admit",      # sequential admit loop (assume/apply/requeue)
     "cycle.admit.prepare",            # per-head assignments, speculative
@@ -94,6 +95,7 @@ HOT_PATH_PHASES = (
     "cycle.admit.fetch",              # blocking wait for the admit scan
     "cycle.admit.apply",              # admit, issue preemptions, skip
     "cycle.admit.requeue",            # requeue of every head not assumed
+    "cycle.admit.tournament",         # a host tournament round: DRS, pick
     "burst",            # one whole schedule_burst call; parent of the
                         # burst.* phases and of its per-cycle cycles
     "burst.pack",       # burst-window pack (streaming, or full)

@@ -81,6 +81,7 @@ import numpy as np
 
 from .quota_kernel import available_all, available_at
 from .cycle import add_usage_chain_batched, walk_groups
+from .fs_scan import entry_paths, tournament
 from ..chaos import injector as _chaos
 from ..features import env_value
 from ..obs.trace import span as _span
@@ -114,6 +115,8 @@ KIND_PRE_NOFIT = 7     # preempt entry no longer fits in-scan
 DIRTY_PREEMPT = 1      # preempt head outside the modeled envelope
 DIRTY_SCALAR = 2       # head outside vectorized-classify coverage
 DIRTY_RESUME = 4       # head with fungibility resume state
+DIRTY_FAIR_PREEMPT = 8  # fair sharing: a preempt-capable head (the
+                        # fair-sharing search is the host's)
 
 
 def _burst_cycles(
@@ -174,6 +177,7 @@ def _burst_cycles(
     ext_unpark,      # [K, G] bool forest unpark events at END of cycle k
     *, K: int, depth: int, L: int, S: int, KC: int,
     n_levels: int, G: int, runtime: int, axis_name=None,
+    fair: bool = False, fs_statics=None,
 ):
     """Run K fused admission cycles with in-kernel preemption.
 
@@ -201,7 +205,19 @@ def _burst_cycles(
     that exit as soon as every searching lane either fitted or ran out
     of quota-holding candidates (candidates sort admitted-first), so
     their cost tracks the candidates actually walked — not the KC = L*M
-    table capacity — with no extra compilation shapes."""
+    table capacity — with no extra compilation shapes.
+
+    ``fair`` (static) decides each cycle as fair sharing does
+    (fair_sharing_iterator.go): ``fs_statics`` = (sq_mask [N, F],
+    lendable_r [N, R], onehot [F, R], child_order [N], weights [N]),
+    ``ops/fs_scan.py``'s ``FSStatics`` and the structure's fair
+    weights; ``wl_cycle_rank`` then holds an equality-preserving
+    (priority desc, timestamp asc) rank (``_fair_cycle_rank``).  Every
+    tree's tournament picks one head a round (``fs_scan.tournament``,
+    the one ``fs_admit_scan`` runs), a NoFit pick is dropped, a fit one
+    re-checks its chain's availability and is admitted or skipped.  A
+    preempt-capable head makes its cycle dirty (``DIRTY_FAIR_PREEMPT``):
+    the fair-sharing search is the host's."""
     # dtype-tightened planes (ops/packing.py tighten_arrays) cross the
     # host boundary narrow and upcast here; already-int32 inputs make
     # these no-ops that XLA elides.  The kernel body below is unchanged.
@@ -252,6 +268,11 @@ def _burst_cycles(
     g_mem = jnp.where(mem_valid[:, :, None], guaranteed[memCs], 0)
     lane_oh = (jnp.arange(L, dtype=jnp.int32)[None, :]
                == self_lmem[:, None])                  # [C, L] own slot
+    if fair:
+        (fs_sq_mask, fs_lendable, fs_onehot, fs_child_order,
+         fs_weights) = fs_statics
+        fs_path = entry_paths(cidx, parent, depth)     # [C, depth]
+        fs_root = parent < 0                           # [N]
 
     def rebuild_usage(u_cq):
         """CQ usage → full node usage via the subtree invariant."""
@@ -349,8 +370,13 @@ def _burst_cycles(
         # the reclaim oracle (ops/cycle.py walk_groups) — a
         # policy-stopped walk is final, and a single preempt-capable
         # slot leaves the best-mode pick no freedom either
-        pre_model = (has_preempt & preempt_ok
-                     & ~jnp.any(w["oracle_groups"], axis=(1, 2)))
+        if fair:
+            # the fair-sharing strategies are the host's: no preemption
+            # is modeled, and a preempt-capable head makes the cycle dirty
+            pre_model = jnp.zeros(C, dtype=bool)
+        else:
+            pre_model = (has_preempt & preempt_ok
+                         & ~jnp.any(w["oracle_groups"], axis=(1, 2)))
 
         dirty_c = has_head & ((has_preempt & ~pre_model)
                               | ~vec_ok[cidx, row])
@@ -361,13 +387,18 @@ def _burst_cycles(
         # local reduction and the cross-shard psum is hoisted out of
         # the scan: one collective per WINDOW instead of one per cycle,
         # which removes K sync barriers from every sharded dispatch
-        dflags = jnp.stack([
+        lanes = [
             jnp.any(dirty_c).astype(jnp.int32),
             jnp.any(has_preempt & ~pre_model).astype(jnp.int32),
             jnp.any(has_head & ~vec_ok[cidx, row]).astype(jnp.int32),
             # fungibility resume runs in-kernel now; the DIRTY_RESUME
             # lane stays for flag-layout compatibility and is always 0
-            jnp.zeros((), dtype=jnp.int32)])
+            jnp.zeros((), dtype=jnp.int32)]
+        if fair:
+            # a preempt-capable head's dirt is fair sharing's own
+            lanes.append(lanes[1])
+            lanes[1] = jnp.zeros((), dtype=jnp.int32)
+        dflags = jnp.stack(lanes)
 
         # -- nominate-time preemption searches (preemption.go:127-342) -
         def run_searches(_):
@@ -543,141 +574,196 @@ def _burst_cycles(
             tgt = tgt & preempting[:, None]
             return preempting, tgt
 
-        preempting0, tgt0 = jax.lax.cond(
-            jnp.any(pre_model), run_searches,
-            lambda _: (jnp.zeros(C, dtype=bool),
-                       jnp.zeros((C, KC), dtype=bool)),
-            operand=None)
+        def forest_scan(has_fit, borrows, p_borrows, preempting0, tgt0,
+                        reserve_c, wu, row, u_cq):
+            """The classic cycle's order and admit scan: entryOrdering
+            within each forest, one entry a forest a step."""
+            # -- cycle order + forest schedule ----------------------------
+            # entryOrdering (scheduler.go:567) within each forest: borrows
+            # asc then the static (priority desc, ts asc, position) rank.
+            # Fit heads AND modeled preempt heads participate.
+            head_crank = wl_cycle_rank[cidx, row]
+            entry_borrows = jnp.where(has_fit, borrows, p_borrows)
+            in_scan = has_fit | preempting0 | reserve_c
+            fit_key = jnp.where(
+                in_scan,
+                head_crank + jnp.where(entry_borrows, _BORROW_BIT, 0),
+                INF_I32)                                           # [C]
+            mem_safe = jnp.maximum(members, 0)
+            keys_gl = jnp.where(members >= 0, fit_key[mem_safe],
+                                INF_I32)                           # [G, L]
+            ord_gl = jnp.argsort(keys_gl, axis=1)
+            keys_sorted = jnp.take_along_axis(keys_gl, ord_gl, axis=1)
+            mat = jnp.where(keys_sorted < INF_I32,
+                            jnp.take_along_axis(mem_safe, ord_gl, axis=1),
+                            -1)                                    # [G, L]
+
+            # -- admit scan: one entry per forest per step ----------------
+            # Carries CQ-level scan/check usage (admit_scan_preempt's
+            # usage / usage_check split, scheduler.go:372 fits under
+            # PreemptedWorkloads) + the used-target marks; upper tree levels
+            # are rebuilt from the subtree invariant each step.  The target
+            # gather/scatter machinery is KC-sized per step, so cycles with
+            # no preempting entry run a light scan without it.
+            def make_step(with_targets: bool):
+                def step(scan_carry, col):
+                    u_scan, u_check, used = scan_carry
+                    cqs = mat[:, col]                              # [G]
+                    valid_l = cqs >= 0
+                    cs = jnp.maximum(cqs, 0)
+                    lane_pre = preempting0[cs] & valid_l           # [G]
+                    if with_targets:
+                        lane_tgt = tgt0[cs] & lane_pre[:, None]    # [G, KC]
+                        rows_l = jnp.maximum(crows[cs], 0)         # [G, KC]
+                        tci = (rows_l // M).astype(jnp.int32)
+                        tmi = (rows_l % M).astype(jnp.int32)
+                        overlap = jnp.any(used[tci * M + tmi] & lane_tgt,
+                                          axis=1)
+                        act = lane_pre & ~overlap
+                        tgt_act = lane_tgt & act[:, None]
+                        tdelta = adm_usage[tci, tmi]               # [G,KC,F]
+                        rem = jnp.zeros((C, F), dtype=jnp.int32).at[tci].add(
+                            jnp.where(tgt_act[:, :, None], tdelta, 0))
+                        plane_check2 = rebuild_usage(u_check - rem)
+                    else:
+                        overlap = jnp.zeros(G, dtype=bool)
+                        act = lane_pre
+                        plane_check2 = rebuild_usage(u_check)
+                    plane_scan = rebuild_usage(u_scan)
+
+                    def lane(cq, is_act):
+                        cq_s = jnp.maximum(cq, 0)
+                        avail_row = available_at(plane_check2, subtree,
+                                                 guaranteed, borrow_cap,
+                                                 has_blim, parent, cq_s,
+                                                 depth)
+                        # fit entry: the fixed slots' usage re-checked (Fits
+                        # over assignment.Usage, scheduler.go:372); a
+                        # preempting entry: the same after its targets went
+                        wuc = wu[cq_s]
+                        pre_ok = jnp.all(jnp.where(wuc > 0,
+                                                   wuc <= avail_row, True))
+                        admit = (cq >= 0) & has_fit[cq_s] & pre_ok
+                        delta = jnp.where(admit, wuc, 0)
+                        pre_now = is_act & pre_ok
+                        delta = delta + jnp.where(pre_now, wuc, 0)
+                        # reserve entry (resourcesToReserve, scheduler:383)
+                        is_res = (cq >= 0) & reserve_c[cq_s]
+                        cur = plane_scan[cq_s]                     # [F]
+                        res_b = jnp.minimum(wuc, npb_cq[cq_s] - cur)
+                        res_n = jnp.maximum(0, jnp.minimum(
+                            wuc, nominal_cq[cq_s] - cur))
+                        rdelta = jnp.where(p_borrows[cq_s], res_b, res_n)
+                        delta = delta + jnp.where(is_res & (wuc > 0),
+                                                  rdelta, 0)
+                        charged = admit | pre_now | is_res
+                        return (admit, pre_now, is_act & ~pre_ok, delta,
+                                charged)
+
+                    admit_l, pre_l, nofit_l, deltas, charged_l = (
+                        jax.vmap(lane)(cqs, act))
+                    add = jnp.where((charged_l & valid_l)[:, None],
+                                    deltas, 0)
+                    u_scan = u_scan.at[cs].add(add)
+                    if with_targets:
+                        rem_commit = jnp.zeros(
+                            (C, F), dtype=jnp.int32).at[tci].add(
+                            jnp.where((tgt_act & pre_l[:, None])[:, :, None],
+                                      tdelta, 0))
+                        u_check = u_check - rem_commit
+                        used = used.at[(tci * M + tmi).reshape(-1)].max(
+                            (tgt_act & pre_l[:, None]).reshape(-1))
+                    u_check = u_check.at[cs].add(add)
+                    return (u_scan, u_check, used), (admit_l, pre_l,
+                                                     nofit_l,
+                                                     overlap & lane_pre)
+                return step
+
+            used0 = jnp.zeros(CM, dtype=bool)
+            cols = jnp.arange(L)
+
+            def scan_heavy(_):
+                return jax.lax.scan(make_step(True), (u_cq, u_cq, used0),
+                                    cols)
+
+            def scan_light(_):
+                return jax.lax.scan(make_step(False), (u_cq, u_cq, used0),
+                                    cols)
+
+            (u_scan, _, used), (admit_cols, pre_cols, nofit_cols,
+                                ovl_cols) = jax.lax.cond(
+                jnp.any(preempting0), scan_heavy, scan_light, operand=None)
+            # scatter scan lanes back to per-CQ flags
+            flat_cq = mat.T.reshape(-1)                            # [L*G]
+            fv = flat_cq >= 0
+            fs_ = jnp.maximum(flat_cq, 0)
+
+            def scatter_flag(cols):
+                return jnp.zeros(C, dtype=bool).at[fs_].max(
+                    cols.reshape(-1) & fv)
+
+            admitted_c = scatter_flag(admit_cols)
+            preempting_c = scatter_flag(pre_cols)
+            pre_nofit_c = scatter_flag(nofit_cols)
+            overlap_c = scatter_flag(ovl_cols)
+            return admitted_c, preempting_c, pre_nofit_c, overlap_c, used
+
+        def fs_rounds(u_cq, has_head, has_fit, wu, prio_head, ts_head):
+            """The fair-sharing cycle's admit loop (fair_sharing_iterator.go
+            with scheduler.go:211-284): a round, every tree's tournament
+            picks one of its remaining heads (``fs_scan.tournament``); a
+            NoFit pick is dropped, a fit one is admitted where its usage
+            still fits its chain (scheduler.go:372) and skipped where not.
+            Trees share no usage, so their rounds run side by side and
+            each tree's picks come in the order the host's iterator yields
+            them.  Returns the admitted heads."""
+            u_e = jnp.where(has_fit[:, None], wu, 0)
+
+            def body(state):
+                u_scan, remaining, admitted = state
+                usage_r = rebuild_usage(u_scan)
+                node_winner = tournament(
+                    usage_r, remaining, fs_path, cidx, u_e, prio_head,
+                    ts_head, subtree, fs_sq_mask, guaranteed, parent,
+                    node_level, fs_weights, fs_lendable, fs_onehot,
+                    fs_child_order, n_levels=n_levels)
+                picks = jnp.where(fs_root & (node_winner >= 0),
+                                  node_winner, C)
+                won = jnp.zeros(C + 1, dtype=bool).at[picks].set(True)[:C]
+                avail_r = available_all(usage_r, subtree, guaranteed,
+                                        borrow_cap, has_blim, parent,
+                                        depth)[:C]
+                fits = jnp.all(jnp.where(u_e > 0, u_e <= avail_r, True),
+                               axis=1)
+                now = won & has_fit & fits
+                return (u_scan + jnp.where(now[:, None], u_e, 0),
+                        remaining & ~won, admitted | now)
+
+            _, _, admitted = jax.lax.while_loop(
+                lambda state: jnp.any(state[1]), body,
+                (u_cq, has_head, jnp.zeros(C, dtype=bool)))
+            return admitted
+
+        if fair:
+            preempting0 = jnp.zeros(C, dtype=bool)
+            tgt0 = jnp.zeros((C, KC), dtype=bool)
+        else:
+            preempting0, tgt0 = jax.lax.cond(
+                jnp.any(pre_model), run_searches,
+                lambda _: (jnp.zeros(C, dtype=bool),
+                           jnp.zeros((C, KC), dtype=bool)),
+                operand=None)
         reserve_c = pre_model & ~preempting0
-
-        # -- cycle order + forest schedule ----------------------------
-        # entryOrdering (scheduler.go:567) within each forest: borrows
-        # asc then the static (priority desc, ts asc, position) rank.
-        # Fit heads AND modeled preempt heads participate.
-        head_crank = wl_cycle_rank[cidx, row]
-        entry_borrows = jnp.where(has_fit, borrows, p_borrows)
-        in_scan = has_fit | preempting0 | reserve_c
-        fit_key = jnp.where(
-            in_scan,
-            head_crank + jnp.where(entry_borrows, _BORROW_BIT, 0),
-            INF_I32)                                           # [C]
-        mem_safe = jnp.maximum(members, 0)
-        keys_gl = jnp.where(members >= 0, fit_key[mem_safe],
-                            INF_I32)                           # [G, L]
-        ord_gl = jnp.argsort(keys_gl, axis=1)
-        keys_sorted = jnp.take_along_axis(keys_gl, ord_gl, axis=1)
-        mat = jnp.where(keys_sorted < INF_I32,
-                        jnp.take_along_axis(mem_safe, ord_gl, axis=1),
-                        -1)                                    # [G, L]
-
-        # -- admit scan: one entry per forest per step ----------------
-        # Carries CQ-level scan/check usage (admit_scan_preempt's
-        # usage / usage_check split, scheduler.go:372 fits under
-        # PreemptedWorkloads) + the used-target marks; upper tree levels
-        # are rebuilt from the subtree invariant each step.  The target
-        # gather/scatter machinery is KC-sized per step, so cycles with
-        # no preempting entry run a light scan without it.
-        def make_step(with_targets: bool):
-            def step(scan_carry, col):
-                u_scan, u_check, used = scan_carry
-                cqs = mat[:, col]                              # [G]
-                valid_l = cqs >= 0
-                cs = jnp.maximum(cqs, 0)
-                lane_pre = preempting0[cs] & valid_l           # [G]
-                if with_targets:
-                    lane_tgt = tgt0[cs] & lane_pre[:, None]    # [G, KC]
-                    rows_l = jnp.maximum(crows[cs], 0)         # [G, KC]
-                    tci = (rows_l // M).astype(jnp.int32)
-                    tmi = (rows_l % M).astype(jnp.int32)
-                    overlap = jnp.any(used[tci * M + tmi] & lane_tgt,
-                                      axis=1)
-                    act = lane_pre & ~overlap
-                    tgt_act = lane_tgt & act[:, None]
-                    tdelta = adm_usage[tci, tmi]               # [G,KC,F]
-                    rem = jnp.zeros((C, F), dtype=jnp.int32).at[tci].add(
-                        jnp.where(tgt_act[:, :, None], tdelta, 0))
-                    plane_check2 = rebuild_usage(u_check - rem)
-                else:
-                    overlap = jnp.zeros(G, dtype=bool)
-                    act = lane_pre
-                    plane_check2 = rebuild_usage(u_check)
-                plane_scan = rebuild_usage(u_scan)
-
-                def lane(cq, is_act):
-                    cq_s = jnp.maximum(cq, 0)
-                    avail_row = available_at(plane_check2, subtree,
-                                             guaranteed, borrow_cap,
-                                             has_blim, parent, cq_s,
-                                             depth)
-                    # fit entry: the fixed slots' usage re-checked (Fits
-                    # over assignment.Usage, scheduler.go:372); a
-                    # preempting entry: the same after its targets went
-                    wuc = wu[cq_s]
-                    pre_ok = jnp.all(jnp.where(wuc > 0,
-                                               wuc <= avail_row, True))
-                    admit = (cq >= 0) & has_fit[cq_s] & pre_ok
-                    delta = jnp.where(admit, wuc, 0)
-                    pre_now = is_act & pre_ok
-                    delta = delta + jnp.where(pre_now, wuc, 0)
-                    # reserve entry (resourcesToReserve, scheduler:383)
-                    is_res = (cq >= 0) & reserve_c[cq_s]
-                    cur = plane_scan[cq_s]                     # [F]
-                    res_b = jnp.minimum(wuc, npb_cq[cq_s] - cur)
-                    res_n = jnp.maximum(0, jnp.minimum(
-                        wuc, nominal_cq[cq_s] - cur))
-                    rdelta = jnp.where(p_borrows[cq_s], res_b, res_n)
-                    delta = delta + jnp.where(is_res & (wuc > 0),
-                                              rdelta, 0)
-                    charged = admit | pre_now | is_res
-                    return (admit, pre_now, is_act & ~pre_ok, delta,
-                            charged)
-
-                admit_l, pre_l, nofit_l, deltas, charged_l = (
-                    jax.vmap(lane)(cqs, act))
-                add = jnp.where((charged_l & valid_l)[:, None],
-                                deltas, 0)
-                u_scan = u_scan.at[cs].add(add)
-                if with_targets:
-                    rem_commit = jnp.zeros(
-                        (C, F), dtype=jnp.int32).at[tci].add(
-                        jnp.where((tgt_act & pre_l[:, None])[:, :, None],
-                                  tdelta, 0))
-                    u_check = u_check - rem_commit
-                    used = used.at[(tci * M + tmi).reshape(-1)].max(
-                        (tgt_act & pre_l[:, None]).reshape(-1))
-                u_check = u_check.at[cs].add(add)
-                return (u_scan, u_check, used), (admit_l, pre_l,
-                                                 nofit_l,
-                                                 overlap & lane_pre)
-            return step
-
-        used0 = jnp.zeros(CM, dtype=bool)
-        cols = jnp.arange(L)
-
-        def scan_heavy(_):
-            return jax.lax.scan(make_step(True), (u_cq, u_cq, used0),
-                                cols)
-
-        def scan_light(_):
-            return jax.lax.scan(make_step(False), (u_cq, u_cq, used0),
-                                cols)
-
-        (u_scan, _, used), (admit_cols, pre_cols, nofit_cols,
-                            ovl_cols) = jax.lax.cond(
-            jnp.any(preempting0), scan_heavy, scan_light, operand=None)
-        # scatter scan lanes back to per-CQ flags
-        flat_cq = mat.T.reshape(-1)                            # [L*G]
-        fv = flat_cq >= 0
-        fs_ = jnp.maximum(flat_cq, 0)
-
-        def scatter_flag(cols):
-            return jnp.zeros(C, dtype=bool).at[fs_].max(
-                cols.reshape(-1) & fv)
-
-        admitted_c = scatter_flag(admit_cols)
-        preempting_c = scatter_flag(pre_cols)
-        pre_nofit_c = scatter_flag(nofit_cols)
-        overlap_c = scatter_flag(ovl_cols)
+        if fair:
+            admitted_c = fs_rounds(u_cq, has_head, has_fit, wu, prio_head,
+                                   wl_cycle_rank[cidx, row])
+            no_c = jnp.zeros(C, dtype=bool)
+            preempting_c = pre_nofit_c = overlap_c = no_c
+            used = jnp.zeros(CM, dtype=bool)
+        else:
+            (admitted_c, preempting_c, pre_nofit_c, overlap_c,
+             used) = forest_scan(has_fit, borrows, p_borrows, preempting0,
+                                 tgt0, reserve_c, wu, row, u_cq)
 
         # -- end-of-cycle state transitions ---------------------------
         # admit delta per admitted head (committed usage)
@@ -785,6 +871,9 @@ def _burst_cycles(
         (dflags[:, 1] > 0).astype(jnp.int32) * DIRTY_PREEMPT
         + (dflags[:, 2] > 0).astype(jnp.int32) * DIRTY_SCALAR
         + (dflags[:, 3] > 0).astype(jnp.int32) * DIRTY_RESUME)
+    if fair:
+        dirty_reason = dirty_reason + (
+            (dflags[:, 4] > 0).astype(jnp.int32) * DIRTY_FAIR_PREEMPT)
     # the full final carry is returned so a pipelined caller can chain
     # the NEXT window's dispatch off the device-resident state (death
     # rebased by -K, seq_base advanced) without a host re-pack
@@ -798,7 +887,37 @@ def _burst_cycles(
 burst_cycles = partial(
     jax.jit,
     static_argnames=("K", "depth", "L", "S", "KC", "n_levels", "G",
-                     "runtime", "axis_name"))(_burst_cycles)
+                     "runtime", "axis_name", "fair"))(_burst_cycles)
+
+
+def _fair_args(plan) -> dict:
+    """The window's fair-sharing arguments: none for a classic plan, so
+    that its program is the one it always was."""
+    if plan.fs_statics is None:
+        return {}
+    return {"fair": True, "fs_statics": plan.fs_statics}
+
+
+def fs_window_statics(st, snapshot) -> Optional[tuple]:
+    """``_burst_cycles``' ``fs_statics`` for a structure, built once a
+    structure generation beside ``CycleSolver.dispatch_fs``' own
+    (``st._fs_statics``); None where the tournament's int32 arithmetic
+    could overflow (the per-cycle engine decides then).  ``snapshot``
+    is called for the cache's snapshot where they are built."""
+    got = getattr(st, "_fs_window", "unset")
+    if not isinstance(got, str):
+        return got
+    from .fs_scan import build_fs_statics, window_bounds_ok
+    fs = getattr(st, "_fs_statics", "unset")
+    if isinstance(fs, str):
+        fs = build_fs_statics(snapshot(), st)
+        st._fs_statics = fs
+    got = None
+    if fs is not None and window_bounds_ok(fs):
+        got = (fs.sq_mask, fs.lendable_r, fs.onehot, fs.child_order,
+               np.asarray(st.fair_weight_milli, dtype=np.int32))
+    st._fs_window = got
+    return got
 
 
 def build_members(forest_of_cq: np.ndarray, n_forests: int,
@@ -853,6 +972,10 @@ class BurstPlan:
     # (budget_rows == grid_rows when KUEUE_TPU_HEAD_PACK=0)
     budget_rows: int = 0
     grid_rows: int = 0
+    # fair sharing: the tournament's statics (``_burst_cycles``'
+    # ``fs_statics``), set by who launches a fair-sharing window; None
+    # decides the classic cycle
+    fs_statics: Optional[tuple] = None
 
 
 def build_candidate_tables(forest_of_cq: np.ndarray, members: np.ndarray,
@@ -1032,6 +1155,18 @@ class _PackStatics:
                  "members", "deep", "wcq_lower", "rwc_enabled",
                  "rwc_only_lower", "modelable_base", "potential0",
                  "comp_cq", "cand_tables")
+
+
+def tie_starts(prio_sorted: np.ndarray, ts_sorted: np.ndarray) -> np.ndarray:
+    """Of rows in cycle order, each row's first position among the rows
+    of its (priority, timestamp): the fair-sharing window's
+    ``wl_cycle_rank``, equal where the tournament's priority and
+    timestamp keys tie, ordered as they are (``_burst_cycles``)."""
+    n = len(prio_sorted)
+    new = np.ones(n, dtype=bool)
+    new[1:] = ((prio_sorted[1:] != prio_sorted[:-1])
+               | (ts_sorted[1:] != ts_sorted[:-1]))
+    return np.maximum.accumulate(np.where(new, np.arange(n), 0))
 
 
 def _pack_statics(st, cache) -> _PackStatics:
@@ -1615,7 +1750,10 @@ def _assemble_plan(st, records, cache, scheduler, min_m):
     # the key tiebreak keeps the rank independent of heap-array order
     # (pops/pushes permute heap.items()), which delta reuse requires
     crank = np.empty(n, dtype=np.int64)
-    crank[np.lexsort((key_arr, pos_a, ts_a, -prio_a))] = np.arange(n)
+    corder = np.lexsort((key_arr, pos_a, ts_a, -prio_a))
+    crank[corder] = (tie_starts(prio_a[corder], ts_a[corder])
+                     if getattr(scheduler, "fair_sharing", False)
+                     else np.arange(n))
     # uid rank (candidatesOrdering final tiebreak) + reservation-time
     # dense rank (ties share a value; uid breaks them separately).
     # Head-pack mode scopes the rank to budget rows — rows of forests
@@ -1956,6 +2094,9 @@ class BurstSolver:
                       "burst_dirty_preempt": 0,
                       "burst_dirty_scalar": 0,
                       "burst_dirty_resume": 0,
+                      # fair sharing: cycles a preempt-capable head made
+                      # dirty (DIRTY_FAIR_PREEMPT)
+                      "burst_fair_dirty_cycles": 0,
                       # cycles decided inside bursts by kind
                       "burst_preempt_cycles": 0,
                       # pipelined boundary (speculative next-window
@@ -2224,7 +2365,8 @@ class BurstSolver:
                 ext_release, ext_unpark,
                 K=K, depth=st.depth, L=plan.L,
                 S=int(st.slot_fr.shape[1]), KC=plan.KC,
-                n_levels=plan.n_levels, G=plan.G, runtime=max(0, runtime))
+                n_levels=plan.n_levels, G=plan.G, runtime=max(0, runtime),
+                **_fair_args(plan))
         self.stats["burst_dispatches"] += 1
         self.stats["burst_cycles_decided"] += K
         if speculative:

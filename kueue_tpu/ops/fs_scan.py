@@ -149,14 +149,146 @@ def fs_bounds_ok(statics: FSStatics, usage0, u_e) -> bool:
 
     Structural bound: the device path only ever adds FIT-checked entry
     usage to usage from admitted workloads, so borrowing never exceeds
-    the parent's lendable capacity and ratio <= 1000; the remaining
-    products are borrowing*1000 (bounded by both total usage and max
-    lendable) and ratio*1000 (<= 10^6).  The kernel additionally clamps
-    ratio so a violated assumption can't wrap."""
+    the parent's lendable capacity and ratio <= 1000; the per-mille
+    ratio is long division (``permille``), whose products stay under
+    10 x lendable, and ratio*1000 is <= 10^6.  The kernel clamps the
+    borrowing to the lendable so a violated assumption can't wrap."""
     b = (int(np.abs(usage0.astype(np.int64)).max(initial=0))
          + int(np.abs(u_e.astype(np.int64)).sum(axis=0).max(initial=0)))
+    return window_bounds_ok(statics) and b < 2**31
+
+
+def window_bounds_ok(statics: FSStatics) -> bool:
+    """The lendable quota's part of ``fs_bounds_ok``: what the fused
+    window's tournament needs of a structure."""
     lend_max = int(statics.lendable_r.astype(np.int64).max(initial=0))
-    return (min(b, lend_max) * 1000 < 2**31) and (b < 2**31)
+    return lend_max * 10 < 2**31
+
+
+def permille(b, lend):
+    """``b * 1000 // lend`` for ``0 <= b <= lend``, exact in int32 while
+    ``10 * lend`` is: three digits of long division, so that a lendable
+    quota in milli-cpu (10^8 at the cells' size) does not overflow the
+    product the division would otherwise need."""
+    rem = b
+    out = jnp.zeros_like(b)
+    for _ in range(3):
+        rem = rem * 10
+        digit = rem // lend
+        rem = rem - digit * lend
+        out = out * 10 + digit
+    return out
+
+
+def entry_paths(cq_safe, parent, depth: int):
+    """[W, depth] the nodes from each entry's CQ up to its root (-1
+    past the root)."""
+    paths = [cq_safe]
+    for _ in range(depth - 1):
+        prev = paths[-1]
+        nxt = jnp.where(prev >= 0, parent[jnp.maximum(prev, 0)], -1)
+        paths.append(jnp.where(paths[-1] >= 0, nxt, -1))
+    return jnp.stack(paths, axis=1)
+
+
+def tournament(usage, remaining, path, cq_safe, u_e, prio, ts_rank,
+               subtree, sq_mask, guaranteed, parent, node_level, weights,
+               lendable_r, onehot, child_order, *, n_levels: int):
+    """One round of fair_sharing_iterator.go's tournament over every
+    cohort tree at once: ``node_winner [N]``, the remaining entry each
+    node's subtree yields (-1 where none remains).  A root's entry is
+    its tree's pick; a parentless CQ is its own root.
+
+    ``usage [N, F]`` is the round's node usage, ``remaining [W]`` the
+    entries still in the cycle, ``path [W, L]`` each entry's nodes from
+    its CQ up (``entry_paths``), ``u_e [W, F]`` the usage each entry
+    would add, ``prio`` and ``ts_rank`` its priority and an
+    equality-preserving rank of its queue-order timestamp.  At every
+    node the survivor minimizes the DRS of its child-of-this-node
+    ancestor with the entry's usage added, then priority descending,
+    timestamp ascending, then child order (runTournament /
+    entryComparer.less, :121, :167).  ``fs_admit_scan`` takes the pick
+    of the first remaining entry's tree a round; the fused window
+    (ops/burst.py) takes every tree's pick a round."""
+    N = usage.shape[0]
+    W, L = path.shape
+    cidx = jnp.arange(W, dtype=jnp.int32)
+
+    # -- DRS of every remaining entry at every path level -------------
+    drs_lv = []
+    carry_u = u_e                                      # [W, F]
+    for lvl in range(L):
+        node = path[:, lvl]
+        alive = node >= 0
+        ns = jnp.maximum(node, 0)
+        has_par = alive & (parent[ns] >= 0)
+        u_after = usage[ns] + carry_u
+        borrowed = jnp.maximum(0, u_after - subtree[ns]) * sq_mask[ns]
+        borrowing_r = borrowed @ onehot                # [W, R]
+        has_borrow = jnp.any(borrowing_r > 0, axis=1)
+        lend = lendable_r[ns]
+        qual = (borrowing_r > 0) & (lend > 0)
+        # borrowing <= lendable in every reachable state (fit-checked
+        # additions over admitted usage); the clamp guards the int32
+        # product if that invariant is ever violated
+        safe_b = jnp.minimum(borrowing_r, jnp.maximum(lend, 1))
+        ratio = jnp.where(qual, permille(safe_b, jnp.maximum(lend, 1)),
+                          -1)
+        drs_raw = jnp.max(ratio, axis=1)
+        w = weights[ns]
+        core = drs_raw * 1000 // jnp.maximum(w, 1)
+        dws = jnp.where(has_borrow, core, 0)
+        dws = jnp.where(w == 0, MAX_DRS_I32, dws)
+        dws = jnp.where(has_par, dws, 0)
+        drs_lv.append(dws)
+        local_avail = jnp.maximum(0, guaranteed[ns] - usage[ns])
+        carry_u = jnp.where(alive[:, None],
+                            jnp.maximum(0, carry_u - local_avail),
+                            carry_u)
+    drs = jnp.stack(drs_lv, axis=1)                    # [W, L]
+
+    # -- bottom-up winner propagation ---------------------------------
+    # node_winner[n] = index of the best remaining entry in n's
+    # subtree; promoted level by level with 4-key scatter-argmin
+    # (drs at the child node, priority desc, ts asc, child order).
+    # Only live entries scatter; padded/consumed rows target the
+    # out-of-bounds drop bucket (each CQ holds at most one head)
+    tgt0 = jnp.where(remaining, cq_safe, N)
+    node_winner = jnp.full(N, -1, dtype=jnp.int32).at[tgt0].set(
+        cidx, mode="drop")
+    cq_lv = node_level[cq_safe]                        # [W]
+    for lvl in range(n_levels - 1, 0, -1):
+        # promote winners of level-`lvl` nodes into their parents
+        is_l = node_level == lvl
+        src = jnp.arange(N)
+        has_w = is_l & (node_winner >= 0) & (parent >= 0)
+        e = jnp.maximum(node_winner, 0)
+        # the winner's drs AT the child node: path index = depth of
+        # the entry's CQ minus the node's level
+        li = jnp.clip(cq_lv[e] - lvl, 0, L - 1)
+        k_drs = jnp.where(has_w, drs[e, li], INF_I32)
+        k_prio = jnp.where(has_w, -prio[e], INF_I32)
+        k_ts = jnp.where(has_w, ts_rank[e], INF_I32)
+        k_ord = jnp.where(has_w, child_order[src], INF_I32)
+        p_s = jnp.maximum(parent, 0)
+        tgt = jnp.where(has_w, p_s, N)                 # drop bucket N
+        m1 = jnp.full(N + 1, INF_I32, jnp.int32).at[tgt].min(k_drs)
+        ok1 = has_w & (k_drs == m1[tgt])
+        m2 = jnp.full(N + 1, INF_I32, jnp.int32).at[tgt].min(
+            jnp.where(ok1, k_prio, INF_I32))
+        ok2 = ok1 & (k_prio == m2[tgt])
+        m3 = jnp.full(N + 1, INF_I32, jnp.int32).at[tgt].min(
+            jnp.where(ok2, k_ts, INF_I32))
+        ok3 = ok2 & (k_ts == m3[tgt])
+        m4 = jnp.full(N + 1, INF_I32, jnp.int32).at[tgt].min(
+            jnp.where(ok3, k_ord, INF_I32))
+        ok4 = ok3 & (k_ord == m4[tgt])
+        promoted = jnp.full(N + 1, -1, jnp.int32).at[tgt].max(
+            jnp.where(ok4, node_winner, -1))
+        node_winner = jnp.where(
+            (node_level == lvl - 1) & (promoted[:N] >= 0),
+            promoted[:N], node_winner)
+    return node_winner
 
 
 @partial(jax.jit, static_argnames=("depth", "n_levels"))
@@ -169,104 +301,23 @@ def fs_admit_scan(usage0, subtree, sq_mask, guaranteed, borrow_cap,
     admit.  Returns (order [W] winner per round or -1, admitted [W],
     processed [W]) in head order; a fit head with ``processed`` and not
     ``admitted`` lost capacity in-cycle (skip)."""
-    N, F = usage0.shape
     W = wl_cq.shape[0]
     L = depth
-    cidx = jnp.arange(W, dtype=jnp.int32)
     cq_safe = jnp.maximum(wl_cq, 0)
     # static per entry: the path from its CQ to the root
-    paths = [cq_safe]
-    for _ in range(L - 1):
-        prev = paths[-1]
-        nxt = jnp.where(prev >= 0, parent[jnp.maximum(prev, 0)], -1)
-        paths.append(jnp.where(paths[-1] >= 0, nxt, -1))
-    path = jnp.stack(paths, axis=1)                   # [W, L]
+    path = entry_paths(cq_safe, parent, L)             # [W, L]
     parentless = parent[cq_safe] < 0
 
     def round_step(carry, _):
         usage, remaining = carry
-
-        # -- DRS of every remaining entry at every path level ---------
-        drs_lv = []
-        carry_u = u_e                                  # [W, F]
-        for lvl in range(L):
-            node = path[:, lvl]
-            alive = node >= 0
-            ns = jnp.maximum(node, 0)
-            has_par = alive & (parent[ns] >= 0)
-            u_after = usage[ns] + carry_u
-            borrowed = jnp.maximum(0, u_after - subtree[ns]) * sq_mask[ns]
-            borrowing_r = borrowed @ onehot            # [W, R]
-            has_borrow = jnp.any(borrowing_r > 0, axis=1)
-            lend = lendable_r[ns]
-            qual = (borrowing_r > 0) & (lend > 0)
-            # borrowing <= lendable in every reachable state (fit-checked
-            # additions over admitted usage); the clamp guards the int32
-            # product if that invariant is ever violated
-            safe_b = jnp.minimum(borrowing_r, jnp.maximum(lend, 1))
-            ratio = jnp.where(qual,
-                              safe_b * 1000 // jnp.maximum(lend, 1),
-                              -1)
-            drs_raw = jnp.max(ratio, axis=1)
-            w = weights[ns]
-            core = drs_raw * 1000 // jnp.maximum(w, 1)
-            dws = jnp.where(has_borrow, core, 0)
-            dws = jnp.where(w == 0, MAX_DRS_I32, dws)
-            dws = jnp.where(has_par, dws, 0)
-            drs_lv.append(dws)
-            local_avail = jnp.maximum(0, guaranteed[ns] - usage[ns])
-            carry_u = jnp.where(alive[:, None],
-                                jnp.maximum(0, carry_u - local_avail),
-                                carry_u)
-        drs = jnp.stack(drs_lv, axis=1)                # [W, L]
-
-        # -- tournament: bottom-up winner propagation -----------------
-        # node_winner[n] = index of the best remaining entry in n's
-        # subtree; promoted level by level with 4-key scatter-argmin
-        # (drs at the child node, priority desc, ts asc, child order)
-        any_remaining = jnp.any(remaining & valid)
-        e0 = jnp.argmax(remaining & valid).astype(jnp.int32)
-
-        # only live entries scatter; padded/consumed rows target the
-        # out-of-bounds drop bucket (each CQ holds at most one head)
-        tgt0 = jnp.where(remaining & valid, cq_safe, N)
-        node_winner = jnp.full(N, -1, dtype=jnp.int32).at[tgt0].set(
-            cidx, mode="drop")
-        cq_lv = node_level[cq_safe]                    # [W]
-
-        for lvl in range(n_levels - 1, 0, -1):
-            # promote winners of level-`lvl` nodes into their parents
-            is_l = node_level == lvl
-            src = jnp.arange(N)
-            has_w = is_l & (node_winner >= 0) & (parent >= 0)
-            e = jnp.maximum(node_winner, 0)
-            # the winner's drs AT the child node: path index = depth of
-            # the entry's CQ minus the node's level
-            li = jnp.clip(cq_lv[e] - lvl, 0, L - 1)
-            k_drs = jnp.where(has_w, drs[e, li], INF_I32)
-            k_prio = jnp.where(has_w, -prio[e], INF_I32)
-            k_ts = jnp.where(has_w, ts_rank[e], INF_I32)
-            k_ord = jnp.where(has_w, child_order[src], INF_I32)
-            p_s = jnp.maximum(parent, 0)
-            tgt = jnp.where(has_w, p_s, N)             # drop bucket N
-            m1 = jnp.full(N + 1, INF_I32, jnp.int32).at[tgt].min(k_drs)
-            ok1 = has_w & (k_drs == m1[tgt])
-            m2 = jnp.full(N + 1, INF_I32, jnp.int32).at[tgt].min(
-                jnp.where(ok1, k_prio, INF_I32))
-            ok2 = ok1 & (k_prio == m2[tgt])
-            m3 = jnp.full(N + 1, INF_I32, jnp.int32).at[tgt].min(
-                jnp.where(ok2, k_ts, INF_I32))
-            ok3 = ok2 & (k_ts == m3[tgt])
-            m4 = jnp.full(N + 1, INF_I32, jnp.int32).at[tgt].min(
-                jnp.where(ok3, k_ord, INF_I32))
-            ok4 = ok3 & (k_ord == m4[tgt])
-            promoted = jnp.full(N + 1, -1, jnp.int32).at[tgt].max(
-                jnp.where(ok4, node_winner, -1))
-            node_winner = jnp.where(
-                (node_level == lvl - 1) & (promoted[:N] >= 0),
-                promoted[:N], node_winner)
-
-        # root of e0's tree
+        live = remaining & valid
+        node_winner = tournament(
+            usage, live, path, cq_safe, u_e, prio, ts_rank, subtree,
+            sq_mask, guaranteed, parent, node_level, weights, lendable_r,
+            onehot, child_order, n_levels=n_levels)
+        # the first remaining entry in heads order names the tree
+        any_remaining = jnp.any(live)
+        e0 = jnp.argmax(live).astype(jnp.int32)
         root = cq_safe[e0]
         for _ in range(L - 1):
             p = parent[root]

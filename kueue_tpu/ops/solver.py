@@ -164,6 +164,14 @@ class CycleSolver:
         self.stats = {
             "full_cycles": 0,         # fully device-decided cycles
             "fs_full_cycles": 0,      # fair-sharing cycles decided in-scan
+            "fs_window_cycles": 0,    # fair-sharing cycles the fused
+                                      # window decided and the driver
+                                      # applied (controller/driver.py)
+            "fs_host_cycles": 0,      # fair-sharing cycles ordered by
+                                      # the host tournament
+            "fs_tracker_unavailable_cycles": 0,  # of them, the ones
+                                      # whose DRS took the scalar path
+                                      # (Scheduler.fs_stats)
             "fs_noop_skips": 0,       # FS cycles with no fit head: the
                                       # tournament dispatch was skipped
             "fs_noop_reuses": 0,      # no-op FS cycles whose per-head

@@ -277,6 +277,16 @@ _ROW_PLANES = {
 }
 
 
+def _crank_values(state, todo: np.ndarray) -> np.ndarray:
+    """``wl_cycle_rank`` at the positions ``todo`` of the cycle order:
+    the position itself, or under fair sharing the first position of
+    its (priority, timestamp) (``burst.tie_starts``)."""
+    if not state.fair:
+        return todo.astype(np.int32)
+    sk = state.crank.skey.view(_SKEY_DT)
+    return _b.tie_starts(sk["p"], sk["t"])[todo].astype(np.int32)
+
+
 class StreamState:
     """Persistent streaming pack state, valid for one (structure
     generation, resource scale, CQ set, window) key.  ``token`` is a
@@ -284,7 +294,7 @@ class StreamState:
     consumed/produced so a shard-resident device copy can prove it
     chains from the same state (object identity is not enough — ids
     alias after GC)."""
-    __slots__ = ("key", "records", "token", "arena",
+    __slots__ = ("key", "records", "token", "arena", "fair",
                  "crank", "uord", "aord", "seq_base",
                  "mi_of", "sk_of",
                  "n_rows_cq", "n_pend_cq", "maxabs_prio_cq", "bad_cq",
@@ -810,11 +820,12 @@ def _init_full(st, queues, cache, scheduler, key, min_m, window, arena,
         _agg.agg_fill(views, records)
 
         # maintained global orders + their dense rank planes
+        state.fair = bool(getattr(scheduler, "fair_sharing", False))
         state.crank = _Order(_SKEY_S)
         state.crank.set(sk_all, ci_a, mi_a)
         if n:
             views["wl_cycle_rank"][state.crank.ci, state.crank.mi] = \
-                np.arange(n, dtype=np.int32)
+                _crank_values(state, np.arange(n, dtype=np.int32))
         # head-pack: the uid order (and so the 19-bit uidrank field) only
         # tracks budget rows — rows of preempting forests; exempt rows keep
         # the pad rank 0, which the kernel never reads for them (candidate
@@ -1085,7 +1096,7 @@ def _patch_grid(st, state, statics, arena, placed, pos_dirty_cis, min_m):
     todo = state.crank.rewrite_from(first, moved)
     if len(todo):
         views["wl_cycle_rank"][state.crank.ci[todo], state.crank.mi[todo]] \
-            = todo.astype(np.int32)
+            = _crank_values(state, todo)
         rank_patches += len(todo)
 
     # uid rank: head-pack keeps exempt (never-candidate) CQs out of the

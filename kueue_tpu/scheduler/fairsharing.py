@@ -102,7 +102,10 @@ class TargetClusterQueueOrdering:
     """reference fairsharing/ordering.go:43."""
 
     def __init__(self, preemptor_cq: CQState, candidates: list[Info],
-                 snapshot_cqs: dict[str, CQState]):
+                 snapshot_cqs: dict[str, CQState],
+                 groups: Optional[dict[str, list[Info]]] = None):
+        """``candidates`` in order, grouped here by queue; or ``groups``,
+        each queue's in order, already grouped (taken over, and popped)."""
         self.preemptor_cq = preemptor_cq
         self.snapshot_cqs = snapshot_cqs
         self.preemptor_ancestors: set = set()
@@ -110,7 +113,8 @@ class TargetClusterQueueOrdering:
         while cohort is not None:
             self.preemptor_ancestors.add(cohort)
             cohort = cohort.parent
-        self.cq_to_targets: dict[str, list[Info]] = {}
+        self.cq_to_targets: dict[str, list[Info]] = (
+            groups if groups is not None else {})
         for cand in candidates:
             self.cq_to_targets.setdefault(cand.cluster_queue, []).append(cand)
         self.pruned_cqs: set[int] = set()

@@ -11,6 +11,7 @@ reclaim oracle used by the flavor assigner (preemption_oracle.go:40).
 from __future__ import annotations
 
 import time
+from contextlib import contextmanager
 from dataclasses import dataclass, field
 from typing import Callable, Iterator, Optional
 
@@ -254,6 +255,13 @@ class Preemptor:
                       # searches it asked for, and those answered Reclaim
                       "oracle_specs": 0,
                       "oracle_reclaims": 0,
+                      # fair sharing (_fair_preemptions): the searches,
+                      # a head's or the oracle's on its behalf; those
+                      # only the second strategy fitted; the candidates
+                      # handed to the first strategy's ordering
+                      "fs_preempt_heads": 0,
+                      "fs_second_strategy_heads": 0,
+                      "fs_candidates": 0,
                       # real candidates in the batched launches, and the
                       # S x K slots of the buckets they were padded to
                       "search_candidate_slots": 0,
@@ -302,11 +310,17 @@ class Preemptor:
         return self._get_targets(ctx)
 
     def _get_targets(self, ctx: _PreemptionCtx) -> list[Target]:
+        if self.enable_fair_sharing:
+            # the fair-sharing search takes candidates a queue at a
+            # time, and a queue's, in candidatesOrdering's order, are
+            # its part's as they stand (_ordered keeps a queue's rows in
+            # their part's order)
+            groups = {part.queue: part.infos.tolist()
+                      for part in self._candidate_parts(ctx)[0]}
+            return self._fair_preemptions(ctx, groups) if groups else []
         candidates = self._find_candidates(ctx)
         if not candidates:
             return []
-        if self.enable_fair_sharing:
-            return self._fair_preemptions(ctx, candidates)
 
         specs, staged = self.plan_searches(ctx, candidates)
         cands, ab, thr = specs[0]
@@ -417,22 +431,18 @@ class Preemptor:
 
         flat_specs: list[tuple] = []
         plans: list[tuple[list[int], bool]] = []
-        with _span("cycle.nominate.candidates"):
-            self._parts_memo = {}
-            try:
-                for ctx in ctxs:
-                    candidates = self._find_candidates(ctx)
-                    if not candidates:
-                        plans.append(([], False))
-                        continue
-                    specs, staged = self.plan_searches(ctx, candidates)
-                    idxs = []
-                    for cands, ab, thr in specs:
-                        idxs.append(len(flat_specs))
-                        flat_specs.append((ctx, cands, ab, thr))
-                    plans.append((idxs, staged))
-            finally:
-                self._parts_memo = None
+        with _span("cycle.nominate.candidates"), self.parts_kept():
+            for ctx in ctxs:
+                candidates = self._find_candidates(ctx)
+                if not candidates:
+                    plans.append(([], False))
+                    continue
+                specs, staged = self.plan_searches(ctx, candidates)
+                idxs = []
+                for cands, ab, thr in specs:
+                    idxs.append(len(flat_specs))
+                    flat_specs.append((ctx, cands, ab, thr))
+                plans.append((idxs, staged))
 
         # the launch plan follows each spec's size: one with no
         # candidate is answered here, one over the K ladder's top rung
@@ -520,6 +530,15 @@ class Preemptor:
         candidate table and over those of its cohort's borrowing
         queues, a part each (``_queue_part``), then one sort
         (``_ordered``)."""
+        parts, own = self._candidate_parts(ctx)
+        if not parts:
+            return NO_CANDIDATES
+        return _ordered(parts, own)
+
+    def _candidate_parts(self, ctx: _PreemptionCtx
+                         ) -> tuple[list[_Part], Optional[_Part]]:
+        """The head's candidate parts, its own queue's first, and that
+        one apart."""
         cq = ctx.preemptor_cq
         wl = ctx.preemptor
         frs = ctx.frs_need_preemption
@@ -546,9 +565,22 @@ class Preemptor:
                     parts.append(part)
 
         self._count_rows_built(cq.candidates.tally)
-        if not parts:
-            return NO_CANDIDATES
-        return _ordered(parts, own)
+        return parts, own
+
+    @contextmanager
+    def parts_kept(self):
+        """Keep the candidate parts a snapshot gives for the searches
+        made inside the block, as a batch keeps them: the block's
+        searches are against one snapshot, at one time, each put back
+        as it found it.  A block inside another keeps the outer's."""
+        if self._parts_memo is not None:
+            yield
+            return
+        self._parts_memo = {}
+        try:
+            yield
+        finally:
+            self._parts_memo = None
 
     def _queue_part(self, cq: CQState, frs: set[FlavorResource],
                     below: Optional[int], equal_if_older=None
@@ -686,12 +718,22 @@ class Preemptor:
     # ------------------------------------------------------------------
 
     def _fair_preemptions(self, ctx: _PreemptionCtx,
-                          candidates: list[Info]) -> list[Target]:
+                          groups: dict[str, list[Info]]) -> list[Target]:
+        """``groups``: each queue's candidates, in order."""
+        with _span("cycle.nominate.fair_preempt"):
+            return self._fair_preemption_targets(ctx, groups)
+
+    def _fair_preemption_targets(self, ctx: _PreemptionCtx,
+                                 groups: dict[str, list[Info]]
+                                 ) -> list[Target]:
+        self.stats["fs_preempt_heads"] += 1
+        self.stats["fs_candidates"] += sum(len(g) for g in groups.values())
         revert = ctx.preemptor_cq.simulate_usage_addition(ctx.workload_usage)
         fits, targets, retry = self._run_first_fs_strategy(
-            ctx, candidates, self.fs_strategies[0])
+            ctx, groups, self.fs_strategies[0])
         if not fits and len(self.fs_strategies) > 1:
             fits, targets = self._run_second_fs_strategy(retry, ctx, targets)
+            self.stats["fs_second_strategy_heads"] += int(fits)
         revert()
         if not fits:
             self._restore(ctx.snapshot, targets)
@@ -700,10 +742,12 @@ class Preemptor:
         self._restore(ctx.snapshot, targets)
         return targets
 
-    def _run_first_fs_strategy(self, ctx: _PreemptionCtx, candidates: list[Info],
-                               strategy) -> tuple[bool, list[Target], list[Info]]:
+    def _run_first_fs_strategy(self, ctx: _PreemptionCtx,
+                               groups: dict[str, list[Info]], strategy
+                               ) -> tuple[bool, list[Target], list[Info]]:
         ordering = fairsharing.TargetClusterQueueOrdering(
-            ctx.preemptor_cq, candidates, ctx.snapshot.cluster_queues)
+            ctx.preemptor_cq, [], ctx.snapshot.cluster_queues,
+            groups=groups)
         targets: list[Target] = []
         retry_candidates: list[Info] = []
         for tcq in ordering.iterate():

@@ -16,6 +16,7 @@ side effects — exactly the boundary the batched TPU solver
 from __future__ import annotations
 
 import time
+from contextlib import nullcontext
 from dataclasses import dataclass, field
 from typing import Callable, Optional
 
@@ -169,7 +170,12 @@ class Scheduler:
             # snapshot cloned again, on a refresh or a full rebuild
             self.solver.stats["snapshot_cqs_recloned"] += (
                 self.cache.snapshot_stats["snap_cqs_recloned"] - recloned)
-        with _span("cycle.nominate"):
+        # a fair-sharing cycle's target searches (a head's, and the
+        # reclaim oracle's for it) go one at a time, all against this
+        # snapshot: the candidate parts of a queue are built once
+        kept = (self.preemptor.parts_kept() if self.fair_sharing
+                else nullcontext())
+        with _span("cycle.nominate"), kept:
             with _span("cycle.nominate.validate"):
                 entries = self.nominate(heads, snapshot)
             device_final = self._maybe_solve_on_device(entries, snapshot)
@@ -1010,6 +1016,10 @@ class Scheduler:
                 vecs[cq_name] = vec
         if tracker is None and self.fs_batched:
             self.fs_stats["tracker_unavailable_cycles"] += 1
+        if self.solver is not None:
+            self.solver.stats["fs_host_cycles"] += 1
+            self.solver.stats["fs_tracker_unavailable_cycles"] = (
+                self.fs_stats["tracker_unavailable_cycles"])
         self._fs_tracker = tracker
         try:
             while remaining:
@@ -1018,29 +1028,8 @@ class Scheduler:
                 if cq.parent is None:
                     yield remaining.pop(cq_name)
                     continue
-                if tracker is not None and not tracker.stale:
-                    keys = list(remaining)
-                    cq_is = np.array([tracker.cq_index[k] for k in keys],
-                                     dtype=np.int64)
-                    u_es = np.stack([vecs[k] for k in keys])
-                    paths, drs = tracker.drs_for(cq_is, u_es)
-                    drs_values: dict[tuple[str, str], int] = {}
-                    for j, k in enumerate(keys):
-                        wl_key = remaining[k].info.key
-                        for level in range(paths.shape[1]):
-                            node = int(paths[j, level])
-                            if node < 0:
-                                break
-                            par = int(tracker.parent[node])
-                            if par < 0:
-                                break
-                            drs_values[(tracker.names[par], wl_key)] = int(
-                                drs[j, level])
-                else:
-                    self.fs_stats["scalar_drs_rounds"] += 1
-                    drs_values = self._fs_drs_values_ref(remaining)
-                winner = self._fs_tournament(cq.parent.root(), remaining,
-                                             drs_values)
+                with _span("cycle.admit.tournament"):
+                    winner = self._fs_round(remaining, cq, tracker, vecs)
                 if winner is None:
                     yield remaining.pop(cq_name)
                     continue
@@ -1048,6 +1037,35 @@ class Scheduler:
                 yield winner
         finally:
             self._fs_tracker = None
+
+    def _fs_round(self, remaining: dict[str, Entry], cq, tracker,
+                  vecs) -> Optional[Entry]:
+        """One round of the host tournament: every remaining entry's DRS
+        (the batched tracker's pass, or the scalar oracle's), then the
+        pick over ``cq``'s cohort tree."""
+        import numpy as np
+        if tracker is not None and not tracker.stale:
+            keys = list(remaining)
+            cq_is = np.array([tracker.cq_index[k] for k in keys],
+                             dtype=np.int64)
+            u_es = np.stack([vecs[k] for k in keys])
+            paths, drs = tracker.drs_for(cq_is, u_es)
+            drs_values: dict[tuple[str, str], int] = {}
+            for j, k in enumerate(keys):
+                wl_key = remaining[k].info.key
+                for level in range(paths.shape[1]):
+                    node = int(paths[j, level])
+                    if node < 0:
+                        break
+                    par = int(tracker.parent[node])
+                    if par < 0:
+                        break
+                    drs_values[(tracker.names[par], wl_key)] = int(
+                        drs[j, level])
+        else:
+            self.fs_stats["scalar_drs_rounds"] += 1
+            drs_values = self._fs_drs_values_ref(remaining)
+        return self._fs_tournament(cq.parent.root(), remaining, drs_values)
 
     def _note_fs_usage(self, cq_name: str, usage) -> None:
         """Mirror an admit-loop usage mutation into the tournament's
