@@ -11,10 +11,10 @@ tree walk.  The final exact int64 ratio/weight division happens host-side
 ``TournamentDRS`` is the admission-tournament backend (reference
 fair_sharing_iterator.go computeDRS): it packs the snapshot once per
 cycle into unscaled int64 node tensors, maintains the usage tensor
-incrementally as the admit loop mutates the snapshot, and computes every
-remaining entry's DRS at every cohort level in ONE vectorized pass per
-tournament round — replacing the per-entry simulate/revert walk that made
-the tournament O(heads²·tree) in Python."""
+incrementally as the admit loop mutates the snapshot, and computes the
+DRS of every remaining entry of the picked tree at every cohort level in
+ONE vectorized pass per tournament round — replacing the per-entry
+simulate/revert walk that made the tournament O(heads²·tree) in Python."""
 
 from __future__ import annotations
 
@@ -61,11 +61,12 @@ class TournamentDRS:
 
     Packs the snapshot's cohort forest once per cycle into unscaled int64
     tensors (host numpy — no int32 scaling concerns), then per tournament
-    round computes every remaining entry's DominantResourceShare at every
-    level of its CQ→root path in one vectorized pass, bit-matching
-    cache.state.dominant_resource_share.  ``note_add`` mirrors the admit
-    loop's ``simulate_usage_addition`` chain-adds into the usage tensor so
-    no per-round repack is needed."""
+    round computes the DominantResourceShare of every remaining entry of
+    the picked tree at every level of its CQ→root path in one vectorized
+    pass, bit-matching cache.state.dominant_resource_share.  ``note_add``
+    mirrors the admit loop's ``simulate_usage_addition`` chain-adds into
+    the usage tensor so no per-round repack is needed.  ``root_of`` maps
+    a node to its tree's root, which groups the entries a round reads."""
 
     _NO_LIMIT = np.int64(2) ** 61
 
@@ -157,6 +158,7 @@ class TournamentDRS:
         masked = np.where(sq_mask[root_of] & (parent >= 0)[:, None],
                           potential[p_safe], 0)
         self.lendable_r = masked @ self.onehot                     # [N, R]
+        self.root_of = root_of
 
     def u_vec(self, usage) -> Optional[np.ndarray]:
         """FlavorResourceQuantities → [F] int64, or None on unknown fr."""

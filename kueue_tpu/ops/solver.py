@@ -172,6 +172,9 @@ class CycleSolver:
             "fs_tracker_unavailable_cycles": 0,  # of them, the ones
                                       # whose DRS took the scalar path
                                       # (Scheduler.fs_stats)
+            "fs_drs_entries": 0,      # heads whose DRS a host tournament
+                                      # round computed, summed over rounds
+                                      # (the picked tree's remaining)
             "fs_noop_skips": 0,       # FS cycles with no fit head: the
                                       # tournament dispatch was skipped
             "fs_noop_reuses": 0,      # no-op FS cycles whose per-head

@@ -965,13 +965,13 @@ class Scheduler:
                 best = cur
         return best
 
-    def _fs_drs_values_ref(self, remaining: dict[str, Entry]
+    def _fs_drs_values_ref(self, tree: dict[str, Entry]
                            ) -> dict[tuple[str, str], int]:
         """Scalar per-entry computeDRS (simulate + revert per entry) —
         the oracle the batched TournamentDRS is parity-tested against,
         and the fallback when the tracker can't represent an entry."""
         drs_values: dict[tuple[str, str], int] = {}
-        for cq_name, e in remaining.items():
+        for cq_name, e in tree.items():
             cq = e.cq_snapshot
             revert = cq.simulate_usage_addition(e.assignment.usage)
             drs_values[(getattr(cq.parent, "name", ""), e.info.key)] = (
@@ -988,12 +988,15 @@ class Scheduler:
         """Per-cohort tournament minimizing post-admission DRS
         (reference fair_sharing_iterator.go:121).
 
-        Per round the DRS values for ALL remaining entries come from one
-        batched TournamentDRS pass over packed int64 tensors; the admit
-        loop's usage mutations are mirrored in via ``_note_fs_usage`` so
-        no per-round repack or per-entry simulate/revert happens.  Falls
-        back to the scalar computeDRS when an entry's usage can't be
-        packed (unseen flavor-resource)."""
+        Per round the DRS values for the remaining entries of the picked
+        tree come from one batched TournamentDRS pass over packed int64
+        tensors; the admit loop's usage mutations are mirrored in via
+        ``_note_fs_usage`` so no per-round repack or per-entry
+        simulate/revert happens.  Falls back to the scalar computeDRS
+        when an entry's usage can't be packed (unseen flavor-resource).
+        Trees are independent — a usage addition moves only its own
+        queue's chain and the pick reads only its own tree's keys — so a
+        round reads no other tree's entries."""
         import numpy as np
         from ..ops.fairsharing_kernel import TournamentDRS
 
@@ -1020,6 +1023,17 @@ class Scheduler:
             self.solver.stats["fs_host_cycles"] += 1
             self.solver.stats["fs_tracker_unavailable_cycles"] = (
                 self.fs_stats["tracker_unavailable_cycles"])
+        # the remaining entries of each root tree, in ``remaining``'s order
+        trees: dict[object, dict[str, Entry]] = {}
+        tree_of: dict[str, object] = {}
+        for cq_name, e in remaining.items():
+            if e.cq_snapshot.parent is None:
+                continue
+            root = (int(tracker.root_of[tracker.cq_index[cq_name]])
+                    if tracker is not None
+                    else id(e.cq_snapshot.parent.root()))
+            trees.setdefault(root, {})[cq_name] = e
+            tree_of[cq_name] = root
         self._fs_tracker = tracker
         try:
             while remaining:
@@ -1028,31 +1042,36 @@ class Scheduler:
                 if cq.parent is None:
                     yield remaining.pop(cq_name)
                     continue
+                tree = trees[tree_of[cq_name]]
                 with _span("cycle.admit.tournament"):
-                    winner = self._fs_round(remaining, cq, tracker, vecs)
+                    winner = self._fs_round(tree, cq, tracker, vecs)
                 if winner is None:
+                    del tree[cq_name]
                     yield remaining.pop(cq_name)
                     continue
                 del remaining[winner.info.cluster_queue]
+                del tree[winner.info.cluster_queue]
                 yield winner
         finally:
             self._fs_tracker = None
 
-    def _fs_round(self, remaining: dict[str, Entry], cq, tracker,
+    def _fs_round(self, tree: dict[str, Entry], cq, tracker,
                   vecs) -> Optional[Entry]:
-        """One round of the host tournament: every remaining entry's DRS
-        (the batched tracker's pass, or the scalar oracle's), then the
-        pick over ``cq``'s cohort tree."""
+        """One round of the host tournament: the DRS of every remaining
+        entry of ``cq``'s cohort tree (the batched tracker's pass, or the
+        scalar oracle's), then the pick over that tree."""
         import numpy as np
+        if self.solver is not None:
+            self.solver.stats["fs_drs_entries"] += len(tree)
         if tracker is not None and not tracker.stale:
-            keys = list(remaining)
+            keys = list(tree)
             cq_is = np.array([tracker.cq_index[k] for k in keys],
                              dtype=np.int64)
             u_es = np.stack([vecs[k] for k in keys])
             paths, drs = tracker.drs_for(cq_is, u_es)
             drs_values: dict[tuple[str, str], int] = {}
             for j, k in enumerate(keys):
-                wl_key = remaining[k].info.key
+                wl_key = tree[k].info.key
                 for level in range(paths.shape[1]):
                     node = int(paths[j, level])
                     if node < 0:
@@ -1064,8 +1083,8 @@ class Scheduler:
                         drs[j, level])
         else:
             self.fs_stats["scalar_drs_rounds"] += 1
-            drs_values = self._fs_drs_values_ref(remaining)
-        return self._fs_tournament(cq.parent.root(), remaining, drs_values)
+            drs_values = self._fs_drs_values_ref(tree)
+        return self._fs_tournament(cq.parent.root(), tree, drs_values)
 
     def _note_fs_usage(self, cq_name: str, usage) -> None:
         """Mirror an admit-loop usage mutation into the tournament's

@@ -30,7 +30,11 @@ from tests.conftest import FakeClock
 
 def build_fs_driver(seed, *, batched, use_device=False, n_cohorts=2,
                     cqs_per_cohort=3, n_wl=60, nested=False,
-                    lending_and_memory=False):
+                    lending_and_memory=False, departments=0):
+    """``n_cohorts`` team cohorts of ``cqs_per_cohort`` queues: flat roots,
+    under one ``org`` root (``nested``), or under ``departments`` root
+    cohorts, team c in ``dept-{c % departments}``, every other team of
+    weight 2 — several trees of three levels."""
     rng = random.Random(seed)
     clock = FakeClock()
     d = Driver(clock=clock, fair_sharing=True,
@@ -41,6 +45,10 @@ def build_fs_driver(seed, *, batched, use_device=False, n_cohorts=2,
     if nested:
         for c in range(n_cohorts):
             d.apply_cohort(Cohort(name=f"cohort-{c}", parent_name="org"))
+    for c in range(n_cohorts if departments else 0):
+        d.apply_cohort(Cohort(
+            name=f"cohort-{c}", parent_name=f"dept-{c % departments}",
+            fair_sharing=FairSharing(weight=2000 if c % 2 else 1000)))
     weights = [500, 1000, 2000, 1000]
     for c in range(n_cohorts):
         for q in range(cqs_per_cohort):
@@ -120,6 +128,89 @@ def test_batched_tournament_matches_scalar(seed, nested):
     for cyc, (r, b) in enumerate(zip(rlog, blog)):
         assert r == b, f"seed {seed} cycle {cyc}:\nscalar={r}\nbatched={b}"
     assert any(c["admitted"] for c in rlog)
+
+
+DEPARTMENTS = dict(departments=3, n_cohorts=6, cqs_per_cohort=2, n_wl=240)
+
+
+@pytest.mark.parametrize("seed", [61, 62, 63])
+@pytest.mark.parametrize("lending_and_memory", [False, True])
+def test_batched_tournament_matches_scalar_over_departments(
+        seed, lending_and_memory):
+    """Three department trees of two weighted teams each: a round reads
+    only the picked tree's entries, on either path, and every cycle's
+    decisions agree."""
+    ref, rclock, rwl = build_fs_driver(
+        seed, batched=False, lending_and_memory=lending_and_memory,
+        **DEPARTMENTS)
+    bat, bclock, bwl = build_fs_driver(
+        seed, batched=True, lending_and_memory=lending_and_memory,
+        **DEPARTMENTS)
+    rlog = drive(ref, rclock, rwl)
+    blog = drive(bat, bclock, bwl)
+    for cyc, (r, b) in enumerate(zip(rlog, blog)):
+        assert r == b, f"seed {seed} cycle {cyc}:\nscalar={r}\nbatched={b}"
+    assert any(c["admitted"] for c in rlog)
+
+
+def watch_rounds(sched, rounds):
+    """Wrap ``sched``'s fair-sharing iterator; for each tournament round
+    it runs, append (remaining heads in the first head's tree, remaining
+    heads of every tree, trees with a remaining head), read off the
+    entries themselves before the round."""
+    real = sched._fair_sharing_iterator
+
+    def wrapped(entries, snapshot):
+        left = {e.info.cluster_queue: e for e in entries
+                if e.cq_snapshot is not None}
+        inner = real(entries, snapshot)
+        while True:
+            pending = None
+            first = next(iter(left.values()), None)
+            if first is not None and first.cq_snapshot.parent is not None:
+                roots = [e.cq_snapshot.parent.root() for e in left.values()
+                         if e.cq_snapshot.parent is not None]
+                root = first.cq_snapshot.parent.root()
+                pending = (sum(r is root for r in roots), len(roots),
+                           len({id(r) for r in roots}))
+            try:
+                e = next(inner)
+            except StopIteration:
+                return
+            if e.cq_snapshot is not None:
+                left.pop(e.info.cluster_queue, None)
+                if pending is not None:
+                    rounds.append(pending)
+            yield e
+    sched._fair_sharing_iterator = wrapped
+
+
+@pytest.mark.parametrize("batched", [True, False])
+@pytest.mark.parametrize("seed", [71, 72])
+def test_drs_entries_count_the_picked_tree(seed, batched):
+    """``fs_drs_entries`` grows, a cycle, by the remaining heads of each
+    round's picked tree, and by less than every remaining head wherever
+    a round had two trees or more to pick from."""
+    d, clock, wls = build_fs_driver(seed, batched=batched, use_device=True,
+                                    **DEPARTMENTS)
+    for wl in wls:
+        d.create_workload(wl)
+    stats = d.scheduler.solver.stats
+    rounds = []
+    watch_rounds(d.scheduler, rounds)
+    several = 0
+    for _ in range(12):
+        clock.t += 1.0
+        rounds.clear()
+        before = stats["fs_drs_entries"]
+        d.schedule_once()
+        counted = stats["fs_drs_entries"] - before
+        assert counted == sum(r[0] for r in rounds)
+        if any(r[2] >= 2 for r in rounds):
+            several += 1
+            assert counted < sum(r[1] for r in rounds)
+    assert several >= 1
+    assert stats["fs_host_cycles"] >= 1
 
 
 @pytest.mark.parametrize("seed", [51, 52, 53])
