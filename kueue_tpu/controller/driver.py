@@ -943,6 +943,16 @@ class Driver:
         itself.  ``on_cycle_start(k)`` / ``on_cycle(k, stats)`` bracket
         each applied cycle (clock advancement, bookkeeping).
 
+        A window truncated by a dirty cycle (a cycle the kernel could not
+        model: a preempting head, a scalar or resume head) starts a hold:
+        the following cycles run on the per-cycle engine and no window is
+        packed or launched, since the head that made the cycle dirty is
+        still at the front of the backlog.  The hold ends when a cycle's
+        finishes (``external_finishes`` or a modelled ``runtime``) release
+        quota, the dirty cycle's own included, and at the end of the
+        call: the next call starts with no hold.  Held cycles are counted
+        in ``burst_held_cycles`` and in ``burst_suppressed_cycles``.
+
         ``pipeline`` double-buffers the burst boundary: after a window
         with no modeled-dirty cycle is fetched, the NEXT window is dispatched
         speculatively off the kernel's final carry — device-resident,
@@ -988,7 +998,11 @@ class Driver:
             # the sharded window has no tournament
             or (fair and self._burst_solver.n_shards > 1))
         solver = self.scheduler.solver
-        normal_streak = 0   # cycles to run normally before re-bursting
+        # a window truncated by a dirty cycle holds the call on the
+        # per-cycle engine: the backlog that made it dirty is still at
+        # the front until quota comes free, so a relaunch would meet it
+        # again.  finish_cycle lifts the hold; the call's end drops it
+        held = False
 
         from ..api.types import WL_QUOTA_RESERVED
 
@@ -1018,6 +1032,7 @@ class Driver:
             scheduler-cycle latency — per-cycle benchmarks exclude it
             the same way the per-cycle harness loop does."""
             import time as _time
+            nonlocal held
             k = len(out)
             out.append(stats)
             adm_ts.append({key: _reservation_ts(key)
@@ -1036,6 +1051,7 @@ class Driver:
                      and wl.has_quota_reservation]
             if batch:
                 self.finish_workloads(batch)
+                held = False     # released quota can change the front
             stats.finish_s = _time.perf_counter() - t0
             if self._wal is not None:
                 self.host_pool.commit_wal(self._wal)
@@ -1083,7 +1099,6 @@ class Driver:
             return bool(stats.admitted or stats.skipped
                         or stats.inadmissible or stats.preempting)
 
-        dirty_backoff = 0
         bstats = self._burst_solver.stats
         spec = None          # speculative BurstHandle for the next window
         plan = handle = None
@@ -1110,15 +1125,15 @@ class Driver:
                     bstats["burst_chaos_divergences"] = (
                         bstats.get("burst_chaos_divergences", 0) + 1)
                     spec = cancel_spec(spec)
-            if (burst_ineligible or solver is None or normal_streak > 0
+            if (burst_ineligible or solver is None or held
                     or self._resume_mask):
                 # a pending resume mask routes the first post-recovery
                 # cycle through schedule_once, which completes the
                 # WAL-interrupted cycle before bursting resumes
                 spec = cancel_spec(spec)
-                if normal_streak > 0 and not burst_ineligible:
+                if held:
                     bstats["burst_suppressed_cycles"] += 1
-                normal_streak = max(0, normal_streak - 1)
+                    bstats["burst_held_cycles"] += 1
                 if not normal_cycle() and quiescent():
                     break
                 continue
@@ -1234,7 +1249,6 @@ class Driver:
             cand_rows = plan.arrays["cand_rows"]
             forest_of_cq = plan.arrays["forest_of_cq"]
             st_names = st.cq_names
-            applied = 0
             consumed = 0     # window cycles applied or empty, not dropped
             drained = False
             window_complete = False
@@ -1286,10 +1300,9 @@ class Driver:
                         bstats["burst_dirty_resume"] += 1
                     if r & _b.DIRTY_FAIR_PREEMPT:
                         bstats["burst_fair_dirty_cycles"] += 1
+                    # held before the cycle: a finish at its end lifts it
+                    held = True
                     normal_cycle(heads=heads, advance=False)
-                    if applied == 0:
-                        dirty_backoff = min(8, max(1, 2 * dirty_backoff))
-                        normal_streak = dirty_backoff
                     break   # kernel state is stale past a host cycle
                 if has_pre_kind and not clock_monotone:
                     # modeled candidate order may diverge from the host's
@@ -1355,10 +1368,7 @@ class Driver:
                     bstats["burst_target_divergences"] += 1
                     normal_cycle(heads=heads, advance=False)
                     break
-                applied += 1
                 consumed += 1
-                normal_streak = 0
-                dirty_backoff = 0
                 if _chaos.ACTIVE is not None:
                     _chaos.ACTIVE.crashpoint("burst.mid_window")
             else:

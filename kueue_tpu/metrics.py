@@ -234,6 +234,7 @@ class Registry:
             "burst_dispatches": "kueue_burst_dispatches",
             "burst_cycles_decided": "kueue_burst_cycles_decided",
             "burst_suppressed_cycles": "kueue_burst_suppressed_cycles",
+            "burst_held_cycles": "kueue_burst_held_cycles",
             "burst_dirty_cycles": "kueue_burst_dirty_cycles",
             "burst_dirty_preempt": "kueue_burst_dirty_preempt",
             "burst_dirty_scalar": "kueue_burst_dirty_scalar",
@@ -578,6 +579,9 @@ _SERIES_DEFS = [
      "Cycles decided on-device by the burst solver."),
     ("kueue_burst_suppressed_cycles", "gauge", (),
      "Burst cycles suppressed by the dirty-set check."),
+    ("kueue_burst_held_cycles", "gauge", (),
+     "Burst cycles held on the per-cycle engine after a dirty "
+     "truncation, until quota is released or the call ends."),
     ("kueue_burst_dirty_cycles", "gauge", (),
      "Burst cycles invalidated and replayed on host."),
     ("kueue_burst_dirty_preempt", "gauge", (),

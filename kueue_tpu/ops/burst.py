@@ -2090,6 +2090,9 @@ class BurstSolver:
                       # boundary + fallback visibility (VERDICT r4 item 9)
                       "burst_pack_s": 0.0, "burst_packs": 0,
                       "burst_suppressed_cycles": 0,
+                      # of those, cycles held on the per-cycle engine
+                      # after a dirty truncation, until quota is released
+                      "burst_held_cycles": 0,
                       "burst_dirty_cycles": 0,
                       "burst_dirty_preempt": 0,
                       "burst_dirty_scalar": 0,
