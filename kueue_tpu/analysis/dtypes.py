@@ -31,7 +31,7 @@ _SCOPE_SUFFIXES = ("ops/packing.py", "ops/stream_pack.py", "cache/arena.py")
 #: the per-CQ usage plane, and the int32 structure planes that
 #: ``TIGHTEN_PLANES`` is allowed to narrow.
 PLANE_SCHEMA: dict[str, str] = {
-    # streamed row planes (_ROW_PLANES)
+    # row planes (ops/packing.py _ROW_PLANES)
     "wl_req": "int32", "wl_rank": "int32", "wl_cycle_rank": "int32",
     "wl_prio": "int32", "wl_uidrank": "int32",
     "vec_ok": "bool", "wl_flavor_skip": "uint8",
@@ -40,10 +40,6 @@ PLANE_SCHEMA: dict[str, str] = {
     "adm_usage0": "int32", "adm_uses0": "bool", "death0": "int32",
     # arena extras
     "u_cq0": "int32", "keys_grid": "object",
-    # cohort-forest aggregate planes (ops/aggregate.py)
-    "agg_heads": "int32", "agg_rows": "int32", "agg_comp": "int32",
-    "agg_comp_ts": "float64", "agg_best_prio": "int32",
-    "agg_best_ts": "float64",
     # tightenable structure planes
     "parent": "int32", "node_level": "int32", "nominal_cq": "int32",
     "slot_fr": "int32", "forest_of_cq": "int32", "members": "int32",

@@ -90,6 +90,11 @@ class PlaneArena:
         self._snaps.clear()
         self._snap_tokens.clear()
 
+    def reset(self) -> None:
+        """Every slab back to its fill value, whole."""
+        for name, slab in self._slabs.items():
+            slab[...] = self._fills[name]
+
     def ensure(self, name: str, shape: tuple, dtype, fill,
                grow_axes: int = 2) -> np.ndarray:
         """Return a ``shape``-sized view of the named slab, growing (or

@@ -167,10 +167,6 @@ ENV_FLAGS: dict[str, EnvFlag] = {f.name: f for f in (
             "Durable ingest-journal path; empty = in-memory only."),
     EnvFlag("KUEUE_TPU_SVC_SEED", "1709", "int",
             "Seed for the serving soak."),
-    EnvFlag("KUEUE_TPU_AGG_PLANES", "1", "bool",
-            "Cohort-forest compression: keep admitted rows of "
-            "non-preempting forests out of the packed planes and track "
-            "them in per-CQ aggregates instead."),
     EnvFlag("KUEUE_TPU_LAZY_HEAP", "1", "bool",
             "Lazy heap repair: buffer pushes/updates and settle with "
             "one amortized sift pass at the next ordered read."),
@@ -180,11 +176,6 @@ ENV_FLAGS: dict[str, EnvFlag] = {f.name: f for f in (
     EnvFlag("KUEUE_TPU_WAL_SHARDS", "1", "int",
             "CycleWAL segment count; >1 stripes group-commit across "
             "that many journal files with merged total-order replay."),
-    EnvFlag("KUEUE_TPU_HEAD_PACK", "1", "bool",
-            "Head-only packing: charge the kernel's 2^19 composite-key "
-            "row budget (uid rank + poison gates) only to rows of "
-            "forests that can preempt; pending rows of never-preempting "
-            "forests ride along as rank context outside the budget."),
     EnvFlag("KUEUE_TPU_HOST_WORKERS", "0", "int",
             "Worker threads for the parallel host apply/pack plane "
             "(cache rebuild fan-out, dirty-CQ pack walk, requeue "

@@ -302,7 +302,7 @@ class Registry:
                          wal_shard_stats=None, head_pack_stats=None,
                          host_pool_stats=None) -> None:
         """Publish the 1M-CQ scale-path telemetry: cohort-forest
-        aggregate compression (``kueue_agg_*``, ops/aggregate.py), lazy
+        aggregate compression (``kueue_agg_*``, ops/stream_pack.py), lazy
         heap repair (``kueue_heap_repair_*``, utils/heap.py), sharded
         WAL striping (``kueue_wal_shard_*``, utils/journal.py),
         head-only packing (``kueue_head_pack_*``, ops/burst.py budget
@@ -647,7 +647,7 @@ _SERIES_DEFS = [
     ("kueue_agg_rows_packed", "gauge", (),
      "Admitted rows materialized as packed kernel rows."),
     ("kueue_agg_heads", "gauge", (),
-     "Pending heads tracked by the aggregate planes."),
+     "Pending rows the window's pack holds."),
     ("kueue_agg_cqs_compressible", "gauge", (),
      "CQs in non-preempting forests eligible for row compression."),
     ("kueue_heap_repair_settles", "gauge", (),

@@ -86,9 +86,8 @@ from ..chaos import injector as _chaos
 from ..features import env_value
 from ..obs.trace import span as _span
 from .device import on_accelerator, output_devices, solver_device
-from .eligibility import (declares, mask_plane_width, skip_mask,
-                          slots_of_mask)
-from .packing import MAX_POD_SETS
+from .eligibility import declares, skip_mask, slots_of_mask
+from .packing import MAX_POD_SETS, _ROW_PLANES, row_plane_shapes
 
 INF_I32 = np.int32(2**31 - 1)
 I32_MAX = 2**31 - 1
@@ -967,9 +966,9 @@ class BurstPlan:
     # whether ``death0`` holds a finish: set by who writes them
     # (Driver._fill_burst_finishes); None = not known, the launch looks
     finite_deaths: Optional[bool] = None
-    # head-pack accounting: rows charged against the kernel's 2^19
-    # composite-key budget vs total rows packed into the [C, M] grid
-    # (budget_rows == grid_rows when KUEUE_TPU_HEAD_PACK=0)
+    # rows charged against the kernel's 2^19 composite-key budget (the
+    # rows of preempting forests, ``compressible_cqs``) vs total rows
+    # packed into the [C, M] grid
     budget_rows: int = 0
     grid_rows: int = 0
     # fair sharing: the tournament's statics (``_burst_cycles``'
@@ -1103,8 +1102,8 @@ class _CQRows:
     they break the modeled candidate ordering (Evicted, no reservation,
     no exact usage vector); ``comp_ts`` holds, by key, the reservation
     time of each admitted row of a compressible forest
-    (ops/aggregate.py) that was walked but NOT packed: their count and
-    max reservation time are all the plan needs from them (usage is
+    (``compressible_cqs``) that was walked but NOT packed: their count
+    and max reservation time are all the plan needs from them (usage is
     already in ``u_row``).  ``infos`` are the pending rows' (an admitted
     row's is the admitted table's).  ``kept_idx`` is set on a record
     built from an old one: the old record's indices of the rows it kept
@@ -1169,6 +1168,34 @@ def tie_starts(prio_sorted: np.ndarray, ts_sorted: np.ndarray) -> np.ndarray:
     return np.maximum.accumulate(np.where(new, np.arange(n), 0))
 
 
+def compressible_cqs(statics) -> np.ndarray:
+    """[C] bool: the queue sits in a cohort forest no member of which
+    can preempt (no ``wcq_lower``, no ``rwc_enabled``).
+
+    The kernel reads an admitted row only as a preemption candidate, and
+    draws candidates from the head's own forest alone
+    (``cand_rows[forest_of_cq[c]]``), eligible only where the head's
+    queue has ``wcq_lower`` or ``rwc_enabled``.  So in such a forest:
+
+    - an admitted row is never read.  Its usage is in ``u_cq0`` and a
+      finish mid-window reaches the kernel through ``ext_release``, as
+      for any workload without a row, so the pack folds these rows into
+      a count and a newest reservation time a queue instead of rows
+      (``_RowWalk.admitted``): the grid scales with heads and preempting
+      forests, not with live workloads;
+    - a pending row can win its own queue's head slot (a per-queue
+      sort) but is never a candidate, so its uid-rank cell is never
+      read: the uid rank and the 2^19 / 2^20 gates of the composite
+      candidate key count only the other rows, the "budget rows".  The
+      rank over a subset keeps that subset's order, so candidate order,
+      and with it every decision, is that of a rank over every row."""
+    forest_of_cq = statics.forest_of_cq
+    preempting = np.zeros(len(statics.deep), dtype=bool)
+    np.logical_or.at(preempting, forest_of_cq,
+                     statics.wcq_lower | statics.rwc_enabled)
+    return ~preempting[forest_of_cq]
+
+
 def _pack_statics(st, cache) -> _PackStatics:
     s = getattr(st, "_burst_statics", None)
     if s is not None:
@@ -1226,7 +1253,6 @@ def _pack_statics(st, cache) -> _PackStatics:
     s.rwc_enabled = rwc_enabled
     s.rwc_only_lower = rwc_only_lower
     s.modelable_base = modelable_base
-    from .aggregate import compressible_cqs
     s.comp_cq = compressible_cqs(s)
     s.potential0 = np.minimum(available_all_np(
         np.zeros((N, F), np.int64), st.subtree_quota, st.guaranteed,
@@ -1523,11 +1549,11 @@ def _pack_cq_rows(st, ci, pos, queues, cache, scheduler, assumed,
     can't be represented (missing from the cache, inexact usage
     scaling).
 
-    With ``compress`` (CQ in a compressible forest + aggregate planes
-    on) the admitted walk runs identically — same bad-detection, same
-    usage-vector check, so ``rec.bad`` matches the uncompressed arm
-    byte for byte — but representable admitted rows are folded into
-    ``n_comp`` / ``comp_max_ts`` aggregates instead of packed rows.
+    With ``compress`` (the CQ is in a compressible forest) the admitted
+    walk runs identically — same bad-detection, same usage-vector check,
+    so ``rec.bad`` is what it would be without — but representable
+    admitted rows are folded into ``n_comp`` / ``comp_max_ts`` instead
+    of packed rows.
 
     With ``old`` (the queue's record of the last window) the pending
     side's members are found afresh, a member whose ``Info`` the old
@@ -1635,9 +1661,7 @@ def _walk_records(st, queues, cache, scheduler, window):
     assumed = cache.assumed_workloads
     scale_of = {r: int(st.resource_scale[i])
                 for i, r in enumerate(st.resource_names)}
-    from .aggregate import agg_planes_enabled
-    s = _pack_statics(st, cache)
-    comp_cq = s.comp_cq if agg_planes_enabled() else None
+    comp_cq = _pack_statics(st, cache).comp_cq
     while True:
         pod_sets = st.pod_sets
         records = []
@@ -1645,8 +1669,7 @@ def _walk_records(st, queues, cache, scheduler, window):
             rec = _pack_cq_rows(st, ci, pos_of.get(st.cq_names[ci], C),
                                 queues, cache, scheduler, assumed,
                                 scale_of, window,
-                                compress=(comp_cq is not None
-                                          and bool(comp_cq[ci])))
+                                compress=bool(comp_cq[ci]))
             if rec is _PACK_FAIL:
                 return None
             records.append(rec)
@@ -1657,8 +1680,7 @@ def _walk_records(st, queues, cache, scheduler, window):
         # again at that extent
 
 
-_ROW_ATTRS = ("adm", "prio", "ts", "res_ts", "parked", "ok",
-              "resume", "req", "skip", "usage", "uses", "keys", "uids")
+_ROW_ATTRS = _DERIVED_ATTRS + ("adm",)
 
 
 def _assemble_plan(st, records, cache, scheduler, min_m):
@@ -1666,19 +1688,14 @@ def _assemble_plan(st, records, cache, scheduler, min_m):
 
     Pure vectorized numpy over the concatenated rows; every rank comes
     from a total-order lexsort (key/uid final tiebreaks), so the output
-    is independent of record row order."""
-    ordering = scheduler.ordering
+    is independent of record row order.  The streaming pack lays out the
+    same rows its own way (ops/stream_pack.py); the two share only the
+    plan around the rows (``_burst_plan``)."""
     C = len(st.cq_names)
-    F = st.n_frs
-    R = len(st.resource_names)
     n_pending = sum(r.n_pend for r in records)
     if n_pending == 0:
         return None
     s = _pack_statics(st, cache)
-    G = st.n_forests
-    forest_of_cq = s.forest_of_cq
-    L = s.L
-    node_level = s.node_level
 
     from .packing import _bucket
     # sticky minimum keeps M stable across re-packs as queues drain
@@ -1702,35 +1719,11 @@ def _assemble_plan(st, records, cache, scheduler, min_m):
     ts_a = fields["ts"]
     parked_a = fields["parked"]
     res_ts_a = fields["res_ts"]
-    ok_a = fields["ok"]
-    resume_a = fields["resume"]
-    req_all = fields["req"]
-    usage_all = fields["usage"]
-    uses_all = fields["uses"]
     key_arr = fields["keys"]
     uid_arr = fields["uids"]
     n = int(n_rows_arr.sum())
-    strict = np.fromiter((r.strict for r in records), dtype=bool,
-                         count=C)
-
-    P = st.pod_sets
-    wl_req = np.zeros((C, M, P * R), dtype=np.int32)
-    wl_rank = np.full((C, M), INF_I32, dtype=np.int32)
-    wl_cycle_rank = np.zeros((C, M), dtype=np.int32)
-    wl_prio = np.zeros((C, M), dtype=np.int32)
-    wl_uidrank = np.zeros((C, M), dtype=np.int32)
-    vec_ok = np.zeros((C, M), dtype=bool)
-    RG = P * st.n_groups
-    wl_flavor_skip = np.zeros((C, mask_plane_width(st, M), RG),
-                              dtype=np.uint8)
-    elig = np.zeros((C, M), dtype=bool)
-    parked = np.zeros((C, M), dtype=bool)
-    resume = np.zeros((C, M, RG), dtype=np.int32)
-    adm = np.zeros((C, M), dtype=bool)
-    adm_seq = np.zeros((C, M), dtype=np.int32)
-    adm_usage = np.zeros((C, M, F), dtype=np.int32)
-    adm_uses = np.zeros((C, M, F), dtype=bool)
-    death = np.full((C, M), I32_MAX, dtype=np.int32)
+    planes = {name: np.full(shape, *_ROW_PLANES[name][:2])
+              for name, shape in row_plane_shapes(st, C, M).items()}
 
     # heap rank within each CQ: one global lexsort replaces C Python
     # sorts (priority desc, queue-order ts asc, key asc —
@@ -1754,123 +1747,121 @@ def _assemble_plan(st, records, cache, scheduler, min_m):
     crank[corder] = (tie_starts(prio_a[corder], ts_a[corder])
                      if getattr(scheduler, "fair_sharing", False)
                      else np.arange(n))
-    # uid rank (candidatesOrdering final tiebreak) + reservation-time
-    # dense rank (ties share a value; uid breaks them separately).
-    # Head-pack mode scopes the rank to budget rows — rows of forests
-    # that can preempt (~comp_cq); the rest can never be candidate-
-    # gathered (see aggregate.head_pack_enabled), so their uidrank
-    # cells are never read and the subset rank preserves the eligible
-    # ordering bit for bit while freeing the 19-bit field's range.
-    from .aggregate import head_pack_enabled
-    head_pack = head_pack_enabled()
+    # uid rank (candidatesOrdering final tiebreak) over the budget rows
+    # alone: the others' cells are never read and keep the pad 0
+    # (``compressible_cqs``); reservation-time dense rank (ties share a
+    # value; uid breaks them separately)
+    budget = np.nonzero(~s.comp_cq[ci_a])[0]
     uidrank = np.zeros(n, dtype=np.int64)
-    if head_pack:
-        bidx = np.nonzero(~s.comp_cq[ci_a])[0]
-        uidrank[bidx[np.argsort(uid_arr[bidx], kind="stable")]] = \
-            np.arange(len(bidx))
-        n_budget = int(len(bidx))
-        prio_budget = (int(np.abs(prio_a[bidx]).max()) if n_budget else 0)
-    else:
-        uidrank[np.argsort(uid_arr, kind="stable")] = np.arange(n)
-        n_budget = n
-        prio_budget = int(np.abs(prio_a).max(initial=0))
+    uidrank[budget[np.argsort(uid_arr[budget], kind="stable")]] = \
+        np.arange(len(budget))
     uniq_ts = np.unique(res_ts_a[adm_a]) if adm_a.any() else np.empty(0)
     seq_a = np.zeros(n, dtype=np.int64)
     if len(uniq_ts):
         seq_a[adm_a] = np.searchsorted(uniq_ts, res_ts_a[adm_a]) + 1
-    seq_base = int(len(uniq_ts)) + 2
 
-    wl_rank[ci_a, mi_a] = mi_a
-    wl_cycle_rank[ci_a, mi_a] = crank
-    wl_prio[ci_a, mi_a] = np.clip(prio_a, -I32_MAX, I32_MAX)
-    wl_uidrank[ci_a, mi_a] = uidrank
-    parked[ci_a, mi_a] = parked_a
-    elig[ci_a, mi_a] = ~parked_a & ~adm_a
-    vec_ok[ci_a, mi_a] = ok_a
-    resume[ci_a, mi_a] = resume_a
-    wl_req[ci_a, mi_a] = req_all
+    at = (ci_a, mi_a)
+    planes["wl_rank"][at] = mi_a
+    planes["wl_cycle_rank"][at] = crank
+    planes["wl_prio"][at] = np.clip(prio_a, -I32_MAX, I32_MAX)
+    planes["wl_uidrank"][at] = uidrank
+    planes["parked0"][at] = parked_a
+    planes["elig0"][at] = ~parked_a & ~adm_a
+    planes["vec_ok"][at] = fields["ok"]
+    planes["resume0"][at] = fields["resume"]
+    planes["wl_req"][at] = fields["req"]
     if st.flavors_declared:
-        wl_flavor_skip[ci_a, mi_a] = fields["skip"]
-    adm[ci_a, mi_a] = adm_a
-    adm_seq[ci_a, mi_a] = seq_a
-    adm_usage[ci_a, mi_a] = usage_all
-    adm_uses[ci_a, mi_a] = uses_all
+        planes["wl_flavor_skip"][at] = fields["skip"]
+    planes["adm0"][at] = adm_a
+    planes["adm_seq0"][at] = seq_a
+    planes["adm_usage0"][at] = fields["usage"]
+    planes["adm_uses0"][at] = fields["uses"]
+    # CQ-level usage, scaled exactly (else no burst) — per-record rows
+    planes["u_cq0"] = np.stack([r.u_row for r in records])
     key_list = key_arr.tolist()   # plain str (key_arr is unicode-dtype)
     keys_grid = np.empty((C, M), dtype=object)   # fills with None
-    keys_grid[ci_a, mi_a] = np.array(key_list, dtype=object)
-    keys: list[list] = keys_grid.tolist()
+    keys_grid[at] = np.array(key_list, dtype=object)
     row_of_key: dict = dict(zip(
         key_list, zip(ci_a.tolist(), mi_a.tolist())))
+    return _burst_plan(
+        st, s, scheduler, planes, keys_grid.tolist(), row_of_key,
+        bad_cq=np.fromiter((r.bad for r in records), bool, C),
+        strict_cq=np.fromiter((r.strict for r in records), bool, C),
+        budget_rows=len(budget),
+        budget_prio=int(np.abs(prio_a[budget]).max(initial=0)),
+        grid_rows=n, seq_base=len(uniq_ts) + 2,
+        newest_res_ts=(float(res_ts_a[adm_a].max()) if adm_a.any()
+                       else None),
+        comp_max_ts=np.fromiter((r.comp_max_ts for r in records),
+                                np.float64, C))
 
-    # CQ-level usage, scaled exactly (else no burst) — per-record rows
-    u_cq = np.stack([r.u_row for r in records])
 
-    # preemption policy flags + the in-kernel modeling envelope
-    forest_bad = s.deep.copy()
-    for r in records:
-        if r.bad:
-            forest_bad[int(forest_of_cq[r.ci])] = True
+def _burst_plan(st, s, scheduler, planes, keys, row_of_key, *, bad_cq,
+                strict_cq, budget_rows, budget_prio, grid_rows, seq_base,
+                newest_res_ts, comp_max_ts) -> BurstPlan:
+    """The plan around a pack's row planes (``planes``, ``u_cq0``
+    included), one for both packs: the in-kernel preemption envelope,
+    the candidate tables and the structure arrays every plan carries.
+
+    A forest is out of the envelope (``preempt_ok`` False for its
+    queues: their preempting heads go to the host) when it is deeper
+    than a cohort of queues or one of its queues left an admitted
+    workload out of its rows (``bad_cq``); every forest is when the
+    forest's candidate slots ``L * M`` pass ``KC_CAP``, when priority
+    does not order candidates within a cohort, or when a field of the
+    composite candidate key could overflow.  Priority and reservation
+    seq have 20 bits, uid rank 19; only budget rows are charged to the
+    priority and uid fields (``compressible_cqs``), and the seq field
+    holds the dense ranks of every admitted timestamp
+    (``seq_base - 2`` of them) and the largest window's admissions.
+
+    ``max_res_ts``, the driver's admission-clock anchor, is the newest
+    reservation of a packed admitted row (``newest_res_ts``, None: no
+    row) or of a compressed one (``comp_max_ts`` a queue, -inf where it
+    has none): a compressed admission is real, only its row is elided."""
+    C, M = planes["wl_rank"].shape
+    L = s.L
     KC = min(KC_CAP, ((L * M + 31) // 32) * 32)
-    if L * M > KC:
-        forest_bad[:] = True
-    if not ordering.priority_sorting_within_cohort:
-        forest_bad[:] = True
-    # the kernel's composite candidate-ordering keys pack priority and
-    # reservation-seq into 20-bit fields and uid rank into 19; in-burst
-    # admissions consume seq_base..seq_base+K-1, so the headroom is the
-    # largest window the ladder can dispatch (not a hardcoded constant).
-    # Only budget rows (rows the candidate keys can ever encode) are
-    # charged against the 2^19/2^20 fields; the seq gate stays global
-    # because reservation seqs are dense over distinct admitted
-    # timestamps regardless of forest.
-    if (prio_budget >= (1 << 20)
+    forest_bad = s.deep.copy()
+    forest_bad[s.forest_of_cq[bad_cq]] = True
+    if (L * M > KC
+            or not scheduler.ordering.priority_sorting_within_cohort
+            or budget_prio >= (1 << 20)
             or seq_base + max(K_BURST_LADDER) >= (1 << 20)
-            or n_budget >= (1 << 19)):
+            or budget_rows >= (1 << 19)):
         forest_bad[:] = True
-    preempt_ok = s.modelable_base & ~forest_bad[forest_of_cq]
     # pure function of the structure statics + (M, KC); M is sticky
     # across re-packs, so boundaries after the first reuse the tables
     tables = s.cand_tables.get((M, KC))
     if tables is None:
-        tables = build_candidate_tables(forest_of_cq, s.members, M, KC)
+        tables = build_candidate_tables(s.forest_of_cq, s.members, M, KC)
         s.cand_tables[(M, KC)] = tables
     cand_rows, cand_lmem, self_lmem = tables
-
+    max_res_ts = newest_res_ts
+    comp = float(np.max(comp_max_ts, initial=-np.inf))
+    if np.isfinite(comp):
+        max_res_ts = comp if max_res_ts is None else max(max_res_ts, comp)
     arrays = dict(
-        wl_req=wl_req, wl_rank=wl_rank, wl_cycle_rank=wl_cycle_rank,
-        wl_prio=wl_prio, wl_uidrank=wl_uidrank,
-        vec_ok=vec_ok, wl_flavor_skip=wl_flavor_skip,
-        elig0=elig, parked0=parked, resume0=resume,
-        adm0=adm, adm_seq0=adm_seq, adm_usage0=adm_usage,
-        adm_uses0=adm_uses, death0=death,
-        u_cq0=u_cq, potential0=s.potential0,
+        planes, potential0=s.potential0,
         subtree=st.subtree_quota, guaranteed=st.guaranteed,
         borrow_cap=st.borrow_cap, has_blim=st.has_borrow_limit,
-        parent=st.parent, node_level=node_level,
+        parent=st.parent, node_level=s.node_level,
         nominal_cq=st.nominal_cq, npb_cq=st.nominal_plus_blimit_cq,
         slot_fr=st.slot_fr, slot_valid=st.slot_valid,
         res_group=st.res_group,
         cq_can_preempt_borrow=st.cq_can_preempt_borrow,
         cq_wcb_borrow=st.cq_wcb_borrow, cq_wcp_preempt=st.cq_wcp_preempt,
-        forest_of_cq=forest_of_cq, strict_cq=strict,
+        forest_of_cq=s.forest_of_cq, strict_cq=strict_cq,
         wcq_lower=s.wcq_lower, rwc_enabled=s.rwc_enabled,
-        rwc_only_lower=s.rwc_only_lower, preempt_ok=preempt_ok,
+        rwc_only_lower=s.rwc_only_lower,
+        preempt_ok=s.modelable_base & ~forest_bad[s.forest_of_cq],
         members=s.members, cand_rows=cand_rows, cand_lmem=cand_lmem,
         self_lmem=self_lmem)
-    # max_res_ts feeds the driver's admission-clock monotonicity check,
-    # so it must cover aggregate-compressed admitted rows too (their
-    # reservation times are real; only their packed rows are elided)
-    max_res_ts = float(res_ts_a[adm_a].max()) if adm_a.any() else None
-    comp_max = max((r.comp_max_ts for r in records if r.n_comp),
-                   default=None)
-    if comp_max is not None:
-        max_res_ts = (comp_max if max_res_ts is None
-                      else max(max_res_ts, comp_max))
     return BurstPlan(structure=st, arrays=arrays, keys=keys,
-                     C=C, M=M, L=L, G=G, n_levels=s.n_levels, KC=KC,
-                     seq_base=seq_base, row_of_key=row_of_key,
+                     C=C, M=M, L=L, G=st.n_forests, n_levels=s.n_levels,
+                     KC=KC, seq_base=seq_base, row_of_key=row_of_key,
                      max_res_ts=max_res_ts,
-                     budget_rows=n_budget, grid_rows=n)
+                     budget_rows=budget_rows, grid_rows=grid_rows)
 
 
 def pack_burst(structure, queues, cache, scheduler, clock,
@@ -1939,15 +1930,16 @@ def _roundtrips_clean(rec, q, cq_live, keys, covers_pods, st) -> bool:
 def pack_burst_cached(structure, queues, cache, scheduler, clock,
                       state=None, min_m: int = 0, window: int = 0,
                       stats=None):
-    """Incrementally maintained pack_burst; returns ``(plan, state,
-    was_delta)``.
+    """The pack of a window; returns ``(plan, state, was_delta)``.
 
     The streaming pack (ops/stream_pack.py) serves the boundary: it
-    patches a persistent packed-universe arena in place, O(arrivals +
-    dirty) per window, and its plans are bit-identical to ``pack_burst``
-    of the same live state (test-enforced).  A structure its encoder
+    keeps the grid in a persistent arena, builds it once for a structure
+    and patches it from the journals after that, at the cost of what
+    changed; its plans are bit-identical to ``pack_burst`` of the same
+    live state (test-enforced), whose rows it lays out independently and
+    whose envelope (``_burst_plan``) it shares.  A structure its encoder
     cannot model (non-ASCII or oversized workload keys poison it) is
-    packed in full every window and carries no state."""
+    packed by ``pack_burst`` every window and carries no state."""
     if not getattr(structure, "_stream_poison", False):
         from .stream_pack import pack_burst_streaming
         return pack_burst_streaming(structure, queues, cache, scheduler,
@@ -1983,13 +1975,11 @@ H2D_BATCH_BYTES = 2 << 30
 H2D_STAGE_MIN_BYTES = 1 << 20
 
 
-# The fused kernel's [C, M(, k)] row inputs; with the scan state's planes
-# (``_STATE_INPUTS`` less the small ``u_cq0``) they are what a one-chip
-# launch keeps on the device between windows.
-_ROW_INPUTS = ("wl_req", "wl_rank", "wl_cycle_rank", "wl_prio",
-               "wl_uidrank", "vec_ok", "wl_flavor_skip")
-_STATE_INPUTS = ("elig0", "parked0", "resume0", "adm0", "adm_seq0",
-                 "adm_usage0", "adm_uses0", "death0", "u_cq0")
+# The fused kernel's [C, M(, k)] row inputs and its scan state, in its
+# order (``_ROW_PLANES``); less the small ``u_cq0`` they are what a
+# one-chip launch keeps on the device between windows.
+_ROW_INPUTS = tuple(n for n, p in _ROW_PLANES.items() if not p[3])
+_STATE_INPUTS = tuple(n for n, p in _ROW_PLANES.items() if p[3]) + ("u_cq0",)
 # The one-chip resident update writes runs of this many slots along M
 # (a whole row where M is shorter), one after another in a loop on the
 # device: a run costs it about as much whatever its length, a few runs a

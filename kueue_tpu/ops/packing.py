@@ -57,6 +57,7 @@ from ..cache.snapshot import Snapshot
 from ..cache.state import CohortState, CQState
 from ..resources import FlavorResource
 from ..workload import Info
+from .eligibility import mask_plane_width
 
 INT_INF = np.int64(2**62)  # "no limit" sentinel before scaling
 I32_MAX = 2**31 - 1
@@ -602,6 +603,47 @@ def pack_cycle(snapshot: Snapshot, heads: list[Info], ordering=None,
         wl_priority=wl_priority, wl_timestamp=wl_timestamp, wl_keys=wl_keys,
         exact=exact,
     )
+
+
+# ---------------------------------------------------------------------------
+# The row planes of a fused window's plan
+# ---------------------------------------------------------------------------
+
+# name -> (pad, dtype, extra axis, scan state): the one declaration of a
+# row plane, [C, M] or [C, M, k].  The extra axis is None, "R" (a
+# PodSet's resources), "F" (flavor-resources) or "G" (resource groups;
+# "R" and "G" a PodSet after another, ``PackedStructure.pod_sets`` of
+# them).  A scan-state plane is carried from cycle to cycle by the fused
+# kernel, the others are read only; both packs allocate from this table
+# and the launch splits its inputs by it, in this order.
+_ROW_PLANES = {
+    "wl_req": (0, np.int32, "R", False),
+    "wl_rank": (I32_MAX, np.int32, None, False),   # I32_MAX: no row
+    "wl_cycle_rank": (0, np.int32, None, False),
+    "wl_prio": (0, np.int32, None, False),
+    "wl_uidrank": (0, np.int32, None, False),
+    "vec_ok": (False, bool, None, False),
+    "wl_flavor_skip": (0, np.uint8, "G", False),
+    "elig0": (False, bool, None, True),
+    "parked0": (False, bool, None, True),
+    "resume0": (0, np.int32, "G", True),
+    "adm0": (False, bool, None, True),
+    "adm_seq0": (0, np.int32, None, True),
+    "adm_usage0": (0, np.int32, "F", True),
+    "adm_uses0": (False, bool, "F", True),
+    "death0": (I32_MAX, np.int32, None, True),   # constant plane
+}
+
+
+def row_plane_shapes(st: PackedStructure, C: int, M: int) -> dict:
+    """Each row plane's shape for a ``[C, M]`` grid of ``st``."""
+    extent = {None: (), "R": (st.pod_sets * len(st.resource_names),),
+              "F": (st.n_frs,), "G": (st.pod_sets * st.n_groups,)}
+    shapes = {name: (C, M) + extent[extra]
+              for name, (_, _, extra, _) in _ROW_PLANES.items()}
+    # every flavor plain: one column of zeros stands for the grid
+    shapes["wl_flavor_skip"] = (C, mask_plane_width(st, M)) + extent["G"]
+    return shapes
 
 
 # ---------------------------------------------------------------------------

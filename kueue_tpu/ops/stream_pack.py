@@ -12,9 +12,10 @@ keeps the packed universe *resident on the host* between
 windows (cache/arena.py PlaneArena slabs, slab-doubling growth) and
 patches it from the PackJournal.  The first window of a structure, a
 forced-full drain (a lost or spurious journal touch, a global input
-changed) and a changed ``window`` pack in full (``_init_full``); every
-other window is a delta pack, at any share of dirty CQs, and costs
-what changed:
+changed) and a changed ``window`` pack in full (``_init_full``: every
+CQ walked, its rows laid out by the delta window's own code from an
+empty grid); every other window is a delta pack, at any share of dirty
+CQs, and costs what changed:
 
 - **a dirty CQ's pending side** is found afresh (its heap and parking
   lot cut to ``window + 2`` rows): a member whose ``Info`` the old
@@ -96,10 +97,8 @@ import numpy as np
 
 from ..cache.arena import PlaneArena
 from ..obs.trace import span as _span
-from . import aggregate as _agg
 from . import burst as _b
-from .eligibility import mask_plane_width
-from .packing import _bucket
+from .packing import _ROW_PLANES, _bucket, row_plane_shapes
 
 _KEY_BYTES = 48          # workload key width in the encoded sort keys
 _UID_BYTES = 64
@@ -190,13 +189,6 @@ class _Order:
         self.mi = np.empty(0, dtype=np.int32)
         self.aux = aux
 
-    def set(self, skey, ci, mi, **aux):
-        srt = np.argsort(skey, kind="stable")
-        self.skey = skey[srt]
-        self.ci = np.asarray(ci, np.int32)[srt]
-        self.mi = np.asarray(mi, np.int32)[srt]
-        self.aux = {name: col[srt] for name, col in aux.items()}
-
     def update(self, drop, moved, new) -> Optional[int]:
         """Row-grade update, in three steps.  ``drop`` = (skey, ci, mi)
         of the entries that leave: each is found by its key and must
@@ -255,28 +247,6 @@ class _Order:
         return np.nonzero(todo)[0]
 
 
-# row-plane layout: name -> (pad value, dtype, extra axis: None | "F" |
-# "R" | "G": a PodSet's resources / resource groups a queue after
-# another's, ``PackedStructure.pod_sets`` of them)
-_ROW_PLANES = {
-    "wl_req": (0, np.int32, "R"),
-    "wl_rank": (_b.INF_I32, np.int32, None),
-    "wl_cycle_rank": (0, np.int32, None),
-    "wl_prio": (0, np.int32, None),
-    "wl_uidrank": (0, np.int32, None),
-    "vec_ok": (False, bool, None),
-    "wl_flavor_skip": (0, np.uint8, "G"),
-    "elig0": (False, bool, None),
-    "parked0": (False, bool, None),
-    "resume0": (0, np.int32, "G"),
-    "adm0": (False, bool, None),
-    "adm_seq0": (0, np.int32, None),
-    "adm_usage0": (0, np.int32, "F"),
-    "adm_uses0": (False, bool, "F"),
-    "death0": (_b.I32_MAX, np.int32, None),   # constant plane
-}
-
-
 def _crank_values(state, todo: np.ndarray) -> np.ndarray:
     """``wl_cycle_rank`` at the positions ``todo`` of the cycle order:
     the position itself, or under fair sharing the first position of
@@ -311,27 +281,13 @@ class StreamState:
 
 
 def _views(arena: PlaneArena, C: int, M: int, st) -> dict:
-    extent = {"R": st.pod_sets * len(st.resource_names), "F": st.n_frs,
-              "G": st.pod_sets * st.n_groups}
-    F = extent["F"]
     out = {}
-    for name, (pad, dt, extra) in _ROW_PLANES.items():
-        shape = (C, M) if extra is None else (C, M, extent[extra])
-        if name == "wl_flavor_skip":
-            # every flavor plain: one column of zeros stands for the grid
-            shape = (C, mask_plane_width(st, M), extent["G"])
+    for name, shape in row_plane_shapes(st, C, M).items():
+        pad, dt = _ROW_PLANES[name][:2]
         out[name] = arena.ensure(name, shape, dt, pad)
-    out["u_cq0"] = arena.ensure("u_cq0", (C, F), np.int32, 0, grow_axes=1)
+    out["u_cq0"] = arena.ensure("u_cq0", (C, st.n_frs), np.int32, 0,
+                                grow_axes=1)
     out["keys_grid"] = arena.ensure("keys_grid", (C, M), object, None)
-    out["agg_heads"] = arena.ensure("agg_heads", (C,), np.int32, 0)
-    out["agg_rows"] = arena.ensure("agg_rows", (C,), np.int32, 0)
-    out["agg_comp"] = arena.ensure("agg_comp", (C,), np.int32, 0)
-    out["agg_comp_ts"] = arena.ensure("agg_comp_ts", (C,),
-                                      np.float64, -1.0)
-    out["agg_best_prio"] = arena.ensure("agg_best_prio", (C,),
-                                        np.int32, 0)
-    out["agg_best_ts"] = arena.ensure("agg_best_ts", (C,),
-                                      np.float64, -1.0)
     return out
 
 
@@ -341,25 +297,12 @@ def _slab(view: np.ndarray) -> np.ndarray:
     return view
 
 
-def _reset_views(views: dict) -> None:
-    for name, v in views.items():
-        if name == "keys_grid":
-            pad = None
-        elif name == "u_cq0":
-            pad = 0
-        elif name in _agg.AGG_PLANES:
-            pad = _agg.AGG_PLANES[name][0]
-        else:
-            pad = _ROW_PLANES[name][0]
-        _slab(v)[...] = pad
-
-
 def _row_slabs(views: dict) -> list:
     """(slab, pad) of every plane that holds a value a row: what a
     CQ's cells past its last row are reset in, in the slabs, so that
     later M growth exposes pads."""
     out = [(_slab(views[name]), pad)
-           for name, (pad, _, _) in _ROW_PLANES.items()
+           for name, (pad, _, _, _) in _ROW_PLANES.items()
            if name != "death0" and not (
                # every flavor plain: the one column stays 0
                name == "wl_flavor_skip" and views[name].shape[1] == 1)]
@@ -373,13 +316,13 @@ def _write_rows(state: "StreamState", views: dict, batch: list) -> None:
     the whole batch.  ``batch`` holds (ci, record, grid positions,
     rows): every row of the record (rows None) or, of a record built
     from the last window's, the rows named, the others lying in the
-    grid as they are.  The one writer of a delta window's rows."""
+    grid as they are.  The one writer of a streaming plan's rows, in a
+    full build (``_init_full``) as in a delta window."""
     ci_l, mi_l, part = [], [], {a: [] for a in (
         "req", "prio", "ok", "skip", "parked", "adm", "resume", "usage",
         "uses", "keys")}
     for ci, rec, mi, sel in batch:
         views["u_cq0"][ci] = rec.u_row
-        _agg.agg_write_cq(views, ci, rec)
         if sel is not None:
             mi = mi[sel]
         if not len(mi):
@@ -529,6 +472,17 @@ def _bump(stats, key, n=1):
         stats[key] = stats.get(key, 0) + n
 
 
+def agg_summary(state, comp_cq) -> dict:
+    """The pack's row census for the driver's stats block and the
+    ``kueue_agg_*`` metrics."""
+    return {
+        "agg_rows_compressed": int(state.n_comp_cq.sum()),
+        "agg_rows_packed": int(state.n_rows_cq.sum()),
+        "agg_heads": int(state.n_pend_cq.sum()),
+        "agg_cqs_compressible": int(np.count_nonzero(comp_cq)),
+    }
+
+
 def _materialize(st, state, s, views, scheduler, dirty_cis, prev_token,
                  rank_patches, stats, row_extent=None):
     """Build the BurstPlan snapshot from the patched arena state.
@@ -545,51 +499,11 @@ def _materialize(st, state, s, views, scheduler, dirty_cis, prev_token,
     (``Driver._fill_burst_finishes``), in a row of that plan, so under
     the next window's ``rows_before`` and its ``row_extent``: the runs
     write the arena's constant plane back over it."""
-    C = len(st.cq_names)
     M = state.M
     n = int(state.n_rows_cq.sum())
-    L, G = s.L, st.n_forests
-    KC = min(_b.KC_CAP, ((L * M + 31) // 32) * 32)
-    # seq_base / max_res_ts from the maintained admitted-ts multiset;
-    # max_res_ts (the driver's admission clock) must also cover
-    # aggregate-compressed admitted rows, whose reservation times live
-    # only in the per-CQ comp_max_cq aggregate
-    seq_base = state.seq_base
-    adm_ts = state.aord.aux["ts"]
-    max_res_ts = float(adm_ts[-1]) if len(adm_ts) else None
-    comp_max = float(state.comp_max_cq.max(initial=-np.inf))
-    if np.isfinite(comp_max):
-        max_res_ts = (comp_max if max_res_ts is None
-                      else max(max_res_ts, comp_max))
-    forest_bad = s.deep.copy()
-    bad_idx = np.nonzero(state.bad_cq)[0]
-    if len(bad_idx):
-        forest_bad[s.forest_of_cq[bad_idx]] = True
-    if L * M > KC:
-        forest_bad[:] = True
-    if not scheduler.ordering.priority_sorting_within_cohort:
-        forest_bad[:] = True
-    # budget scoping mirrors _assemble_plan: with head-pack on, only
-    # rows of preempting forests can ever be candidate-encoded, so only
-    # they are charged against the 19/20-bit composite-key fields
-    if _agg.head_pack_enabled():
-        bm = ~s.comp_cq
-        n_budget = int(state.n_rows_cq[bm].sum())
-        prio_budget = int(state.maxabs_prio_cq[bm].max(initial=0))
-    else:
-        n_budget = n
-        prio_budget = int(state.maxabs_prio_cq.max(initial=0))
-    if (prio_budget >= (1 << 20)
-            or seq_base + max(_b.K_BURST_LADDER) >= (1 << 20)
-            or n_budget >= (1 << 19)):
-        forest_bad[:] = True
-    preempt_ok = s.modelable_base & ~forest_bad[s.forest_of_cq]
-    tables = s.cand_tables.get((M, KC))
-    if tables is None:
-        tables = _b.build_candidate_tables(s.forest_of_cq, s.members,
-                                           M, KC)
-        s.cand_tables[(M, KC)] = tables
-    cand_rows, cand_lmem, self_lmem = tables
+    # the rows of preempting forests are the budget rows
+    bm = ~s.comp_cq
+    n_budget = int(state.n_rows_cq[bm].sum())
     arena = state.arena
     with _span("burst.pack.grid.snapshot"):
         # the runs the launch's device mirror is updated by
@@ -600,35 +514,23 @@ def _materialize(st, state, s, views, scheduler, dirty_cis, prev_token,
             at, _ = _b._row_runs(row_extent, W,
                                  int((-(-row_extent // W)).sum()))
             runs = (M, W, (at[:, 0], at[:, 1] // W))
-        arrays = {name: arena.snapshot(name, views[name], state.token,
+        planes = {name: arena.snapshot(name, views[name], state.token,
                                        prev_token, runs)
                   for name in _ROW_PLANES}
         keys_grid = arena.snapshot("keys_grid", views["keys_grid"],
                                    state.token, prev_token, runs)
-    arrays["u_cq0"] = views["u_cq0"].copy()
-    arrays.update(
-        potential0=s.potential0, subtree=st.subtree_quota,
-        guaranteed=st.guaranteed, borrow_cap=st.borrow_cap,
-        has_blim=st.has_borrow_limit, parent=st.parent,
-        node_level=s.node_level, nominal_cq=st.nominal_cq,
-        npb_cq=st.nominal_plus_blimit_cq, slot_fr=st.slot_fr,
-        slot_valid=st.slot_valid, res_group=st.res_group,
-        cq_can_preempt_borrow=st.cq_can_preempt_borrow,
-        cq_wcb_borrow=st.cq_wcb_borrow,
-        cq_wcp_preempt=st.cq_wcp_preempt,
-        forest_of_cq=s.forest_of_cq,
-        strict_cq=state.strict_cq.copy(),
-        wcq_lower=s.wcq_lower, rwc_enabled=s.rwc_enabled,
-        rwc_only_lower=s.rwc_only_lower, preempt_ok=preempt_ok,
-        members=s.members, cand_rows=cand_rows, cand_lmem=cand_lmem,
-        self_lmem=self_lmem)
-    plan = _b.BurstPlan(
-        structure=st, arrays=arrays,
-        keys=_KeysView(keys_grid),
-        C=C, M=M, L=L, G=G, n_levels=s.n_levels, KC=KC,
-        seq_base=seq_base, row_of_key=state.row_of_key,
-        max_res_ts=max_res_ts,
-        budget_rows=n_budget, grid_rows=n)
+    planes["u_cq0"] = views["u_cq0"].copy()
+    # seq_base and the newest packed reservation from the maintained
+    # admitted order
+    adm_ts = state.aord.aux["ts"]
+    plan = _b._burst_plan(
+        st, s, scheduler, planes, _KeysView(keys_grid), state.row_of_key,
+        bad_cq=state.bad_cq, strict_cq=state.strict_cq.copy(),
+        budget_rows=n_budget,
+        budget_prio=int(state.maxabs_prio_cq[bm].max(initial=0)),
+        grid_rows=n, seq_base=state.seq_base,
+        newest_res_ts=float(adm_ts[-1]) if len(adm_ts) else None,
+        comp_max_ts=state.comp_max_cq)
     plan.pack_token = state.token
     plan.prev_token = prev_token
     plan.row_extent = row_extent
@@ -639,12 +541,11 @@ def _materialize(st, state, s, views, scheduler, dirty_cis, prev_token,
     if stats is not None:
         stats["pack_rank_patches"] = (
             stats.get("pack_rank_patches", 0) + int(rank_patches))
-        shapes = {name: a.shape for name, a in arrays.items()
-                  if name in _ROW_PLANES or name == "u_cq0"}
-        state.arena.refresh_stats(shapes)
+        state.arena.refresh_stats({name: a.shape
+                                   for name, a in planes.items()})
         stats.update({("pack_" + k): v
                       for k, v in state.arena.stats.items()})
-        stats.update(_agg.agg_summary(state, s.comp_cq))
+        stats.update(agg_summary(state, s.comp_cq))
         stats["head_pack_budget_rows"] = n_budget
         stats["head_pack_exempt_rows"] = n - n_budget
     return plan
@@ -707,10 +608,10 @@ def _reseq(state, views, moved_cq) -> int:
 
 def _init_full(st, queues, cache, scheduler, key, min_m, window, arena,
                stats, t0):
-    """Full streaming (re)build: walk every CQ, reset + refill the
-    arena, rebuild the maintained orders.  The assembly math mirrors
-    ops/burst._assemble_plan line for line — same sorts, same pads —
-    so the first streaming plan equals the reference plan bit for bit."""
+    """Full streaming (re)build: walk every CQ, reset the arena, then
+    lay the rows out as a delta window does, from an empty grid:
+    ``_place_rows`` places every record's rows and ``_patch_grid``
+    writes them and merges them into the three orders."""
     if _b._unknown_active_cq(st, queues):
         return None, None, False
     with _span("burst.pack.walk"):
@@ -719,137 +620,38 @@ def _init_full(st, queues, cache, scheduler, key, min_m, window, arena,
         return None, None, False
     with _span("burst.pack.grid"):
         C = len(st.cq_names)
-        R = len(st.resource_names)
-        F = st.n_frs
         s = _b._pack_statics(st, cache)
-
         state = StreamState(key, arena)
-        state.records = records
         state.cq_names_list = list(queues.cluster_queue_names())
         pos_of = {name: i for i, name in enumerate(state.cq_names_list)}
         state.pos_cq = np.fromiter(
             (pos_of.get(nm, C) for nm in st.cq_names), np.int32, C)
         for rec in records:
             rec.pos = int(state.pos_cq[rec.ci])
-        state.n_rows_cq = np.fromiter((r.n_rows for r in records),
-                                      np.int64, C)
-        state.n_pend_cq = np.fromiter((r.n_pend for r in records),
-                                      np.int64, C)
-        state.bad_cq = np.fromiter((r.bad for r in records), bool, C)
-        state.strict_cq = np.fromiter((r.strict for r in records), bool, C)
-        state.n_comp_cq = np.fromiter((r.n_comp for r in records),
-                                      np.int64, C)
-        state.comp_max_cq = np.fromiter((r.comp_max_ts for r in records),
-                                        np.float64, C)
-        bounds = np.concatenate(([0], np.cumsum(state.n_rows_cq)))
-        n = int(bounds[-1])
-
-        nz = [r for r in records if r.n_rows]
-        def cat(attr, empty_dtype):
-            if nz:
-                return np.concatenate([getattr(r, attr) for r in nz])
-            return np.empty(0, dtype=empty_dtype)
-        keys_a = cat("keys", "U1")
-        uids_a = cat("uids", "U1")
-        prio_a = cat("prio", np.int64)
-        ts_a = cat("ts", np.float64)
-        res_ts_a = cat("res_ts", np.float64)
-        adm_a = cat("adm", bool)
-        kb_all = _enc_str(keys_a, _KEY_BYTES)      # may bail -> caller
-        ub_all = _enc_str(uids_a, _UID_BYTES)
-        ci_a = np.repeat(np.arange(C, dtype=np.int32), state.n_rows_cq)
-        pos_a = np.repeat(state.pos_cq, state.n_rows_cq)
-
-        # per-CQ |prio| maxima (reduceat; empty segments masked out)
-        state.maxabs_prio_cq = np.zeros(C, np.int64)
-        if n:
-            red = np.maximum.reduceat(
-                np.abs(prio_a), np.minimum(bounds[:-1], n - 1))
-            state.maxabs_prio_cq = np.where(state.n_rows_cq > 0, red, 0)
-
-        rows_per_cq = int(state.n_rows_cq.max(initial=0))
-        state.M = M = max(_bucket(rows_per_cq, minimum=4), min_m)
-        views = _views(arena, C, M, st)
-        _reset_views(views)
-
-        # per-CQ heap rank: the reference ci-segmented lexsort
-        order = np.lexsort((keys_a, ts_a, -prio_a, ci_a))
-        ci_sorted = ci_a[order]
-        first = np.ones(n, dtype=bool)
-        first[1:] = ci_sorted[1:] != ci_sorted[:-1]
-        idx = np.arange(n, dtype=np.int32)
-        seg_start = np.maximum.accumulate(np.where(first, idx, np.int32(0)))
-        mi_sorted = idx - seg_start
-        mi_a = np.empty(n, dtype=np.int32)
-        mi_a[order] = mi_sorted
-
-        # a record row's grid position and its key in the crank order:
-        # where a later window finds the row, in the grid and among its
-        # CQ's rows
-        sk_all = _crank_skey(prio_a, ts_a, pos_a, kb_all)
-        state.mi_of = {}
-        state.sk_of = {}
-        for ci in range(C):
-            lo, hi = int(bounds[ci]), int(bounds[ci + 1])
-            state.mi_of[ci] = mi_a[lo:hi]
-            state.sk_of[ci] = sk_all[lo:hi]
-
-        if n:
-            views["wl_req"][ci_a, mi_a] = cat("req", np.int32)
-            views["wl_rank"][ci_a, mi_a] = mi_a
-            views["wl_prio"][ci_a, mi_a] = np.clip(
-                prio_a, -_b.I32_MAX, _b.I32_MAX)
-            parked_a = cat("parked", bool)
-            views["parked0"][ci_a, mi_a] = parked_a
-            views["elig0"][ci_a, mi_a] = ~parked_a & ~adm_a
-            views["vec_ok"][ci_a, mi_a] = cat("ok", bool)
-            if views["wl_flavor_skip"].shape[1] > 1:
-                views["wl_flavor_skip"][ci_a, mi_a] = cat("skip", np.uint8)
-            views["resume0"][ci_a, mi_a] = cat("resume", np.int32)
-            views["adm0"][ci_a, mi_a] = adm_a
-            views["adm_usage0"][ci_a, mi_a] = cat("usage", np.int32)
-            views["adm_uses0"][ci_a, mi_a] = cat("uses", bool)
-            key_list = keys_a.tolist()
-            views["keys_grid"][ci_a, mi_a] = np.array(key_list, dtype=object)
-            state.row_of_key = dict(zip(
-                key_list, zip(ci_a.tolist(), mi_a.tolist())))
-        else:
-            state.row_of_key = {}
-        for ci, rec in enumerate(records):
-            views["u_cq0"][ci] = rec.u_row
-        _agg.agg_fill(views, records)
-
-        # maintained global orders + their dense rank planes
+        # the empty grid: no record, no row, every order empty
+        state.records = [None] * C
+        state.mi_of = dict.fromkeys(range(C), np.empty(0, np.int32))
+        state.sk_of = dict.fromkeys(range(C), np.empty(0, _SKEY_S))
+        state.row_of_key = {}
+        for name, dt in (("n_rows_cq", np.int64), ("n_pend_cq", np.int64),
+                         ("n_comp_cq", np.int64),
+                         ("maxabs_prio_cq", np.int64),
+                         ("comp_max_cq", np.float64),
+                         ("bad_cq", bool), ("strict_cq", bool)):
+            setattr(state, name, np.zeros(C, dt))
         state.fair = bool(getattr(scheduler, "fair_sharing", False))
         state.crank = _Order(_SKEY_S)
-        state.crank.set(sk_all, ci_a, mi_a)
-        if n:
-            views["wl_cycle_rank"][state.crank.ci, state.crank.mi] = \
-                _crank_values(state, np.arange(n, dtype=np.int32))
-        # head-pack: the uid order (and so the 19-bit uidrank field) only
-        # tracks budget rows — rows of preempting forests; exempt rows keep
-        # the pad rank 0, which the kernel never reads for them (candidate
-        # eligibility needs the head's wcq_lower/rwc_enabled census bits)
         state.uord = _Order(f"S{_UID_BYTES}")
-        if _agg.head_pack_enabled() and n:
-            bsel = np.nonzero(~s.comp_cq[ci_a])[0]
-            state.uord.set(ub_all[bsel], ci_a[bsel], mi_a[bsel])
-        else:
-            state.uord.set(ub_all, ci_a, mi_a)
-        n_uord = len(state.uord.ci)
-        if n_uord:
-            views["wl_uidrank"][state.uord.ci, state.uord.mi] = \
-                np.arange(n_uord, dtype=np.int32)
-        am = np.nonzero(adm_a)[0]
-        state.aord = _Order(_AKEY_S)
-        state.aord.set(_adm_skey(res_ts_a[am], kb_all[am]),
-                       ci_a[am], mi_a[am], ts=res_ts_a[am],
-                       seq=np.zeros(len(am), np.int32))
-        _reseq(state, views, np.zeros(C, dtype=bool))
+        state.aord = _Order(_AKEY_S, ts=np.empty(0, np.float64),
+                            seq=np.empty(0, np.int32))
+        placed = _place_rows(state, records)   # a bail writes nothing
+        arena.reset()
+        views, _, repacked = _patch_grid(st, state, s, arena, placed, (),
+                                         min_m)
 
         _bump(stats, "burst_full_packs")
         _bump(stats, "stream_full_packs")
-        _bump(stats, "rows_repacked", n)
+        _bump(stats, "rows_repacked", repacked)
         if int(state.n_pend_cq.sum()) == 0:
             _note_ms(stats, t0)
             return None, state, False
@@ -887,8 +689,7 @@ def _walk_dirty(state, st, ci, whole, events, walk_args):
                 old_idx[key] = int(at[loc[1]])
     rec = _b._pack_cq_rows(st, ci, int(state.pos_cq[ci]), queues, cache,
                            scheduler, assumed, scale_of, window,
-                           compress=(comp_cq is not None
-                                     and bool(comp_cq[ci])),
+                           compress=bool(comp_cq[ci]),
                            old=old, events=events, old_idx=old_idx)
     return None if rec is _b._PACK_FAIL else rec
 
@@ -984,12 +785,13 @@ def _place_rows(state, recs) -> list:
 
 
 def _patch_grid(st, state, statics, arena, placed, pos_dirty_cis, min_m):
-    """Stage B of a delta window, row grade: the rows that went leave
-    the grid, ``row_of_key`` and the three maintained orders; the rows
-    that came are written and merge-inserted; a CQ's kept rows that
-    changed place are written again at their new cells (``_write_rows``,
-    the one writer of a window's rows) and their locators in the orders
-    are remapped in place.  Returns (the grid's views, rank cells
+    """Stage B of a delta window, and of a full build from an empty
+    grid, row grade: the rows that went leave the grid, ``row_of_key``
+    and the three maintained orders; the rows that came are written and
+    merge-inserted; a CQ's kept rows that changed place are written
+    again at their new cells (``_write_rows``, the one writer of a
+    window's rows) and their locators in the orders are remapped in
+    place.  Returns (the grid's views, rank cells
     rewritten, rows derived)."""
     C = len(st.cq_names)
     for p in placed:
@@ -1099,10 +901,8 @@ def _patch_grid(st, state, statics, arena, placed, pos_dirty_cis, min_m):
             = _crank_values(state, todo)
         rank_patches += len(todo)
 
-    # uid rank: head-pack keeps exempt (never-candidate) CQs out of the
-    # maintained uid order, mirroring the _init_full budget filter
-    in_uord = (~statics.comp_cq if _agg.head_pack_enabled()
-               else np.ones(C, dtype=bool))
+    # uid rank: over the budget rows alone (``_b.compressible_cqs``)
+    in_uord = ~statics.comp_cq
     gu, ju = in_uord[g["ci"]], in_uord[j["ci"]]
     first = state.uord.update(
         (_enc_str(g["uids"][gu], _UID_BYTES), g["ci"][gu], g["mi"][gu]),
@@ -1136,8 +936,7 @@ def pack_burst_streaming(structure, queues, cache, scheduler, clock,
     t0 = time.perf_counter()
     def state_key():
         return (st.generation, st.resource_scale.tobytes(),
-                tuple(st.cq_names), window, _agg.agg_planes_enabled(),
-                st.pod_sets)
+                tuple(st.cq_names), window, st.pod_sets)
     key = state_key()
     # hard dirt by journal: the queue manager's is the pending side's;
     # the cache's, where it names no workload, is a whole queue's
@@ -1232,9 +1031,7 @@ def pack_burst_streaming(structure, queues, cache, scheduler, clock,
                         for i, r in enumerate(st.resource_names)}
             statics = _b._pack_statics(st, cache)
             walk_args = (queues, cache, scheduler, cache.assumed_workloads,
-                         scale_of, window,
-                         statics.comp_cq if _agg.agg_planes_enabled()
-                         else None)
+                         scale_of, window, statics.comp_cq)
             # a CQ whose rows all take a new crank key is walked whole
             whole_cis = {index_of[name] for name in whole
                          if name in index_of} | set(pos_dirty_cis)

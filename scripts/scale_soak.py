@@ -11,16 +11,16 @@ Publishes ``SCALE_r19.json``:
               A/B (one arrival per CQ per round, so admissions/cycle
               scale with the universe) across THREE arms: streaming
               (every r19 optimization on, pooled host plane included),
-              rebuild-every-boundary, and "classic" (head-only packing,
-              aggregate compression, lazy heap repair, cycle bulk apply
-              and the worker pool all off — the full row-backed serial
+              rebuild-every-boundary, and "classic" (lazy heap repair,
+              cycle bulk apply and the worker pool all off — the serial
               control) — decisions must be bit-identical across all
               arms at every probed size;
   ceiling   — the r19 wall broken: a universe of >= 1M ACTIVE CQs
               (every one holding pending work) whose head-packed budget
               rows stay under the kernel's 2^19 composite-key budget
-              while the row-backed pack of the SAME state is ~4x over
-              it, with a completed admission round and the measured
+              while a row-backed pack of the SAME state (every live
+              workload a row, counted on the host) is ~4x over it, with
+              a completed admission round and the measured
               per-round wall at that size;
   head_pack — the budget accounting at the ceiling: budget rows
               (charged) vs grid rows (packed) vs live workloads;
@@ -30,15 +30,15 @@ Publishes ``SCALE_r19.json``:
               fsync'd WAL attached, decision parity, the cores-vs-
               throughput curve of the pooled WAL-commit plane, and the
               honest ``cores_available`` of this box;
-  aggregate — packed rows vs live rows per size with compression on vs
-              off, and the ``max_res_ts`` (clock-anchor) equality
-              verdicts;
+  aggregate — packed rows vs the rows a row-backed pack would hold per
+              size, and the ``max_res_ts`` (clock-anchor) verdicts
+              against the cache's newest reservation;
   heap      — lazy vs eager heap repair: per-cycle decision-apply cost
               at 100k items across per-key touch rates (the 1-touch
               regime now exercises the adaptive demotion), plus the
               driver-level host apply+heap time: the single-flag
               bulk-apply A/B (stream vs the same arm with bulk off)
-              and the everything-off classic reference;
+              and the classic reference;
   wal_shard — sharded vs single-file CycleWAL append+group-commit wall
               (the r19 single-appender auto-collapse closes r18's
               0.84x single-thread regression) and the seq-merged
@@ -81,7 +81,6 @@ import os
 import random
 import sys
 import time
-from contextlib import contextmanager
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
@@ -118,27 +117,32 @@ from kueue_tpu.utils.journal import (
 #: into 19 bits) — the ceiling this artifact is about
 ROW_BUDGET = 1 << 19
 
-_AGG_FLAG = "KUEUE_TPU_AGG_PLANES"
-_HEAD_FLAG = "KUEUE_TPU_HEAD_PACK"
 _POOL_FLAG = "KUEUE_TPU_HOST_WORKERS"
 
 
-@contextmanager
-def agg_planes_off():
-    """The row-backed control pack: aggregate compression AND head-only
-    packing forced off (every live workload charged a budget row),
-    environment restored on exit."""
-    old = {k: os.environ.get(k) for k in (_AGG_FLAG, _HEAD_FLAG)}
-    os.environ[_AGG_FLAG] = "0"
-    os.environ[_HEAD_FLAG] = "0"
-    try:
-        yield
-    finally:
-        for k, v in old.items():
-            if v is None:
-                os.environ.pop(k, None)
-            else:
-                os.environ[k] = v
+def row_backed(d, plan) -> tuple:
+    """What a pack that folded no admitted row out of its grid would
+    hold, counted on the host: the plan's pending rows plus every
+    workload holding quota in the cache and not evicted.  Returns
+    (rows, the newest reservation time among those workloads or None):
+    the second is what the plan's ``max_res_ts`` has to equal."""
+    from kueue_tpu.api.types import WL_EVICTED, WL_QUOTA_RESERVED
+    pending = 0 if plan is None else int(
+        (np.asarray(plan.arrays["elig0"])
+         | np.asarray(plan.arrays["parked0"])).sum())
+    admitted, newest = 0, None
+    for name in d.queues.cluster_queue_names():
+        cq = d.cache.cluster_queue(name)
+        if cq is None:
+            continue
+        for info in cq.workloads.values():
+            cond = info.obj.conditions.get(WL_QUOTA_RESERVED)
+            if cond is None or info.obj.condition_true(WL_EVICTED):
+                continue
+            admitted += 1
+            ts = cond.last_transition_time
+            newest = ts if newest is None else max(newest, ts)
+    return pending + admitted, newest
 
 
 class VirtualClock:
@@ -325,16 +329,10 @@ def pack_curve_point(n_cqs: int, boundaries: int, n_churn: int,
                 for v in tighten_arrays(arrays, tight).values())
             rows = sum(1 for row in plan_s.keys
                        for k in row if k is not None)
-    # the row-backed control pack on the SAME final state: aggregate
-    # compression off, everything else identical — the packed-row
-    # shrink and the max_res_ts (clock-anchor) equality come from here
-    with agg_planes_off():
-        plan_row = pack_burst(st, d.queues, d.cache, d.scheduler, clock)
-    rows_row_backed = 0 if plan_row is None else sum(
-        1 for row in plan_row.keys for k in row if k is not None)
-    agg_max_ts_equal = (
-        (plan_s is None) == (plan_row is None)
-        and (plan_s is None or plan_s.max_res_ts == plan_row.max_res_ts))
+    # the row-backed count of the SAME final state, on the host: the
+    # packed-row shrink and the max_res_ts (clock-anchor) verdict
+    rows_row_backed, newest = row_backed(d, plan_s)
+    agg_max_ts_equal = plan_s is None or plan_s.max_res_ts == newest
     out = {
         "cqs": n_cqs,
         "rows": rows,
@@ -386,20 +384,15 @@ _ARM_ENV = {
     # path and would confound the measurement)
     "nobulk": {"KUEUE_TPU_HOST_WORKERS": "4",
                "KUEUE_TPU_CYCLE_BULK_APPLY": "0"},
-    # the r19 bit-identity control: streaming pack on, every scale
-    # optimization off — head-only packing, aggregate compression,
-    # lazy heap repair, one-settle cycle bulk apply, worker pool.
-    # This is the full row-backed serial arm of the head-pack parity
-    # claim.
-    "classic": {"KUEUE_TPU_AGG_PLANES": "0",
-                "KUEUE_TPU_HEAD_PACK": "0",
-                "KUEUE_TPU_LAZY_HEAP": "0",
+    # the bit-identity control: streaming pack on, every host-side
+    # scale optimization off — lazy heap repair, one-settle cycle bulk
+    # apply, worker pool: the serial arm.
+    "classic": {"KUEUE_TPU_LAZY_HEAP": "0",
                 "KUEUE_TPU_CYCLE_BULK_APPLY": "0",
                 "KUEUE_TPU_HOST_WORKERS": "0"},
 }
 
-_ARM_KEYS = ("KUEUE_TPU_AGG_PLANES", "KUEUE_TPU_HEAD_PACK",
-             "KUEUE_TPU_LAZY_HEAP", "KUEUE_TPU_CYCLE_BULK_APPLY",
+_ARM_KEYS = ("KUEUE_TPU_LAZY_HEAP", "KUEUE_TPU_CYCLE_BULK_APPLY",
              "KUEUE_TPU_HOST_WORKERS")
 
 #: span phases that are pack or device work — everything else inside
@@ -502,8 +495,8 @@ def ceiling_probe(n_cqs: int, preempt_cohorts: int, seed: int) -> dict:
     holding pending work after the preload's completed admission round)
     whose head-packed BUDGET rows — rows of the ``preempt_cohorts``
     forests that can preempt — stay far under the kernel's 2^19
-    composite-key budget, while the row-backed pack of the SAME state
-    charges every live workload a row and lands ~4x over it.  One
+    composite-key budget, while a row-backed pack of the SAME state
+    would charge every live workload a row and land ~4x over it.  One
     soak-style round (one arrival per CQ, fused cycles, retirement)
     measures the honest per-round wall at this size.
 
@@ -531,15 +524,9 @@ def ceiling_probe(n_cqs: int, preempt_cohorts: int, seed: int) -> dict:
     # the quantity the 2^19 budget binds from r19 on: rows charged to
     # the composite-key uid rank + poison gates (preempting forests)
     rows_budget = 0 if plan is None else int(plan.budget_rows)
-    with agg_planes_off():
-        t2 = time.perf_counter()
-        plan_row = pack_burst(st, d.queues, d.cache, d.scheduler, clock)
-        pack_row_s = time.perf_counter() - t2
-    rows_row_backed = 0 if plan_row is None else sum(
-        1 for row in plan_row.keys for k in row if k is not None)
-    row_backed_budget = 0 if plan_row is None \
-        else int(plan_row.budget_rows)
-    del plan, plan_row
+    # a row-backed pack holds and charges every live workload a row
+    rows_row_backed, _ = row_backed(d, plan)
+    del plan
     gc.collect()
     # one soak-style round at the ceiling: the per-round wall that
     # sizes any longer soak at this universe
@@ -565,11 +552,10 @@ def ceiling_probe(n_cqs: int, preempt_cohorts: int, seed: int) -> dict:
         "rows_packed": rows_budget,
         "rows_grid": rows_grid,
         "rows_row_backed": rows_row_backed,
-        "rows_budget_row_backed": row_backed_budget,
+        "rows_budget_row_backed": rows_row_backed,
         "packed_under_budget": rows_budget < ROW_BUDGET,
         "row_backed_over_budget": rows_row_backed >= ROW_BUDGET,
         "pack_ms_agg": round(pack_agg_s * 1e3, 1),
-        "pack_ms_row_backed": round(pack_row_s * 1e3, 1),
         "build_s": round(build_s, 1),
         "round": {"arrivals": n_cqs, "admitted": admitted,
                   "retired": len(done), "wall_s": round(round_s, 1)},
@@ -1069,9 +1055,7 @@ def main() -> int:
         point["host_apply_ms_classic"] = e_c["host_apply_ms"]
         # the e2e bulk-apply speedup: single-flag A/B (stream vs the
         # same arm with KUEUE_TPU_CYCLE_BULK_APPLY=0); classic is kept
-        # as the everything-off reference — it also drops aggregate
-        # compression, whose per-admission fold cost sits in apply, so
-        # classic/stream under-reports the bulk win by that tax
+        # as the everything-off reference
         point["host_apply_speedup"] = round(
             e_n["host_apply_ms"] / max(e_s["host_apply_ms"], 1e-3), 2)
         point["host_apply_speedup_vs_classic"] = round(
@@ -1177,7 +1161,6 @@ def main() -> int:
                  "pack": top["pack_counters_rebuild"]})
 
     aggregate = {
-        "flag": "KUEUE_TPU_AGG_PLANES",
         "row_budget": ROW_BUDGET,
         "points": [{"cqs": p["cqs"], "live_rows": p["live_rows"],
                     "rows_packed": p["rows"],
@@ -1207,7 +1190,6 @@ def main() -> int:
                    if p["touches_per_key"] == 8)
     soak_rate = soak_block["workloads_per_s"]
     head_pack = {
-        "flag": "KUEUE_TPU_HEAD_PACK",
         "row_budget": ROW_BUDGET,
         "ceiling_cqs": ceiling["cqs"],
         "active_cqs_pending": ceiling["active_cqs_pending"],
@@ -1228,7 +1210,6 @@ def main() -> int:
                         "composite-key budget capped ACTIVE CQs near "
                         "524,288 (r18 probed 500k CQs / 1M live rows)",
              "status": "lifted",
-             "flag": "KUEUE_TPU_HEAD_PACK",
              "mechanism": "head-only packing: the uid rank and the "
                           "n/prio poison gates charge only rows of "
                           "forests that can preempt; pending rows of "

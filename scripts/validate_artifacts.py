@@ -513,8 +513,6 @@ def _check_scale_r19(d, path, out):
               "budget_rows", "grid_rows", "live_rows"):
         if not isinstance(hp.get(k), int):
             _err(out, path, f"'head_pack.{k}' must be an int")
-    if not isinstance(hp.get("flag"), str):
-        _err(out, path, "'head_pack.flag' must name the env flag")
     pool = d.get("host_pool")
     if not isinstance(pool, dict):
         _err(out, path, "r19 artifacts must carry a 'host_pool' block")

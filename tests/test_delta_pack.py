@@ -106,15 +106,18 @@ def assert_plans_equal(a, b, ctx=""):
             f"{np.argwhere(x != y)[:5].tolist()}"
 
 
-def check_step(d, state, stats, window, ctx):
-    """One boundary: delta pack vs fresh pack of the same live state."""
+def check_step(d, state, stats, window, ctx, min_m=0, seen=None):
+    """One boundary: delta pack vs fresh pack of the same live state;
+    ``seen``, a list, gets the fresh plan."""
     st = current_structure(d)
     plan_d, state, _ = pack_burst_cached(
         st, d.queues, d.cache, d.scheduler, d.clock,
-        state=state, window=window, stats=stats)
+        state=state, min_m=min_m, window=window, stats=stats)
     plan_f = pack_burst(st, d.queues, d.cache, d.scheduler, d.clock,
-                        window=window)
+                        min_m=min_m, window=window)
     assert_plans_equal(plan_d, plan_f, ctx)
+    if seen is not None:
+        seen.append(plan_f)
     return state
 
 
@@ -217,6 +220,38 @@ def test_delta_pack_randomized_parity(window):
     # counted (every sequence starts with at least one full pack)
     assert total_delta > 0, "delta path never taken"
     assert total_full >= n_seqs, "full-repack fallbacks not counted"
+
+
+@pytest.mark.parametrize("window", [0, 4])
+def test_plans_agree_over_the_envelope(monkeypatch, window):
+    """The benchmark cells' windows: a forest's candidate slots ``L * M``
+    pass ``KC_CAP`` (lowered here, as tests/test_burst_hold.py does), so
+    every forest is out of the in-kernel preemption envelope.  The
+    streaming plan still equals the full pack's, poison included, at
+    every boundary of a mutation storm on a preempting cluster."""
+    from kueue_tpu.ops import burst
+    monkeypatch.setattr(burst, "KC_CAP", 32)
+    plans, deltas = [], 0
+    for seed in range(3):
+        rng = random.Random(4600 + seed)
+        d, clock = build_cluster(seed, preempt=True)
+        names = _counter()
+        for i in range(6):
+            d.create_workload(mk(f"init{i}", f"lq-{i % 2}-{i // 3}",
+                                 2000, prio=(i % 3) * 10, t=float(i)))
+        stats = {}
+        state = None
+        for step in range(10):
+            state = check_step(d, state, stats, window,
+                               f"seed{seed}:step{step}", min_m=32,
+                               seen=plans)
+            random_mutation(rng, d, clock, names)
+        deltas += stats.get("burst_delta_packs", 0)
+    plans = [p for p in plans if p is not None]
+    assert deltas > 0 and len(plans) >= 10
+    for plan in plans:
+        assert plan.KC == 32 < plan.L * plan.M
+        assert not np.asarray(plan.arrays["preempt_ok"]).any()
 
 
 def test_delta_pack_rows_reused_counted():

@@ -1,20 +1,20 @@
 """Head-only packing: budget scoping is decision-invisible.
 
-``KUEUE_TPU_HEAD_PACK`` charges the kernel's 2^19 composite-key row
-budget (the 19-bit uid rank plus the n/prio poison gates) only to rows
-of forests that can preempt; pending rows of never-preempting forests
-ride along as rank context outside the budget, so the active-CQ
-ceiling scales with preempting-forest rows instead of all live rows.
-The soundness argument is the same census aggregate compression uses:
-a row of a never-preempting forest is never gathered as a preemption
-candidate (eligibility requires the head CQ's ``wcq_lower``/
-``rwc_enabled``), so its uidrank cell is never read and the scoped
-rank — the subset rank, order-preserving over budget rows — yields
-bit-identical candidate ordering.  These tests prove it: budget
-accounting, poison-gate scoping (the ceiling-lift mechanism observable
-at unit scale), twin-driver decision identity across head-only /
-row-backed arms, 8-seed streaming parity storms with head flips, and
-composition with ``KUEUE_TPU_AGG_PLANES``.
+The pack charges the kernel's 2^19 composite-key row budget (the 19-bit
+uid rank plus the n/prio poison gates) only to rows of forests that can
+preempt; pending rows of never-preempting forests ride along as rank
+context outside the budget, so the active-CQ ceiling scales with
+preempting-forest rows instead of all live rows.  The soundness
+argument is the census that folds admitted rows out of the grid
+(``burst.compressible_cqs``): a row of a never-preempting forest is
+never gathered as a preemption candidate (eligibility requires the head
+CQ's ``wcq_lower``/``rwc_enabled``), so its uidrank cell is never read
+and the scoped rank — the subset rank, order-preserving over budget
+rows — yields the same candidate ordering.  These tests prove it:
+budget accounting, poison-gate scoping (the ceiling-lift mechanism
+observable at unit scale), burst decisions equal to the host
+scheduler's on a mixed cluster, and 8-seed streaming parity storms with
+head flips.
 """
 
 from __future__ import annotations
@@ -25,11 +25,9 @@ import numpy as np
 import pytest
 
 from kueue_tpu.ops import burst as _b
-from kueue_tpu.ops.aggregate import head_pack_enabled
 
-from test_aggregate_compression import build_mixed
+from test_aggregate_compression import build_mixed, burst_and_host
 from test_delta_pack import (
-    Clock,
     _counter,
     build_cluster,
     check_step,
@@ -54,89 +52,67 @@ def _pack(d):
     return _b.pack_burst(st, d.queues, d.cache, d.scheduler, d.clock)
 
 
-def test_flag_default_on():
-    assert head_pack_enabled() is True
-
-
-def test_budget_rows_count_preempting_forests_only(monkeypatch):
+def test_budget_rows_count_preempting_forests_only():
     """build_mixed: co-0 preempts (budget rows), co-1 never does
-    (exempt).  With the flag on, only co-0's rows are charged; with it
-    off, every packed row is."""
-    monkeypatch.setenv("KUEUE_TPU_HEAD_PACK", "1")
+    (exempt): only co-0's rows are charged.  In a cluster where every
+    forest preempts, every packed row is."""
     d, _ = build_mixed()
     _fill_pending(d, per_q=3)
     plan = _pack(d)
     assert plan.grid_rows == 12
     assert plan.budget_rows == 6, "only the preempting cohort is charged"
 
-    monkeypatch.setenv("KUEUE_TPU_HEAD_PACK", "0")
-    d0, _ = build_mixed()
+    d0, _ = build_cluster(preempt=True)
     _fill_pending(d0, per_q=3)
     plan0 = _pack(d0)
     assert plan0.grid_rows == 12 and plan0.budget_rows == 12
 
 
-def test_scoped_uidrank_is_subset_rank(monkeypatch):
-    """The head-pack uid rank over budget rows must be the subset rank
-    of the global uid rank: same relative order, dense from 0; exempt
-    rows keep the pad value 0 (never read)."""
-    planes = {}
-    for flag in ("1", "0"):
-        monkeypatch.setenv("KUEUE_TPU_HEAD_PACK", flag)
-        d, _ = build_mixed()
-        _fill_pending(d, per_q=3)
-        st = current_structure(d)
-        comp_cq = _b._pack_statics(st, d.cache).comp_cq
-        plan = _b.pack_burst(st, d.queues, d.cache, d.scheduler, d.clock)
-        planes[flag] = (np.asarray(plan.arrays["wl_uidrank"]),
-                        ~np.asarray(comp_cq),
-                        np.asarray(plan.keys, dtype=object))
-    on_rank, budget_cq, keys = planes["1"]
-    off_rank, _, off_keys = planes["0"]
-    assert (keys == off_keys).all(), "same packed universe"
+def test_scoped_uidrank_is_subset_rank():
+    """The uid rank over budget rows is the rank of their uids among
+    themselves: dense from 0, in uid order; exempt rows keep the pad
+    value 0 (never read)."""
+    d, _ = build_mixed()
+    _fill_pending(d, per_q=3)
+    st = current_structure(d)
+    budget_cq = ~np.asarray(_b._pack_statics(st, d.cache).comp_cq)
+    plan = _b.pack_burst(st, d.queues, d.cache, d.scheduler, d.clock)
+    rank = np.asarray(plan.arrays["wl_uidrank"])
+    keys = np.asarray(plan.keys, dtype=object)
     has_row = keys != None                                   # noqa: E711
     bmask = has_row & budget_cq[:, None]
-    # subset rank: dense 0..n_budget-1 and order-preserving vs global
-    bvals_on = on_rank[bmask]
-    bvals_off = off_rank[bmask]
-    assert sorted(bvals_on.tolist()) == list(range(int(bmask.sum())))
-    assert np.array_equal(np.argsort(bvals_on, kind="stable"),
-                          np.argsort(bvals_off, kind="stable"))
-    assert (on_rank[has_row & ~budget_cq[:, None]] == 0).all(), \
+    uids = [d.workloads[k].uid for k in keys[bmask]]
+    want = np.empty(len(uids), dtype=np.int64)
+    want[np.argsort(np.array(uids), kind="stable")] = np.arange(len(uids))
+    assert len(uids) == 6
+    assert rank[bmask].tolist() == want.tolist()
+    assert (rank[has_row & ~budget_cq[:, None]] == 0).all(), \
         "exempt rows keep the pad rank"
 
 
-def test_poison_gates_scoped_to_budget_rows(monkeypatch):
+@pytest.mark.parametrize("lq, modeled", [("lq-1-0", True),
+                                         ("lq-0-0", False)],
+                         ids=["exempt", "budget"])
+def test_poison_gates_scoped_to_budget_rows(lq, modeled):
     """The ceiling-lift mechanism, observable at unit scale: a
     field-overflowing priority on an *exempt* CQ must not poison the
-    in-kernel preemption envelope when head-pack is on — with it off,
-    the same universe collapses every forest to the host path."""
-    for flag, expect_modeled in (("1", True), ("0", False)):
-        monkeypatch.setenv("KUEUE_TPU_HEAD_PACK", flag)
-        d, _ = build_mixed()
-        _fill_pending(d, per_q=2)
-        # co-1 is never-preempting (exempt): a 2^21 priority there
-        # overflows the 20-bit composite-key field
-        d.create_workload(mk("huge", "lq-1-0", 1000,
-                             prio=(1 << 21), t=99.0))
-        plan = _pack(d)
-        preempt_ok = np.asarray(plan.arrays["preempt_ok"])
-        if expect_modeled:
-            assert preempt_ok.any(), \
-                "exempt-row overflow must not gate the budget forests"
-        else:
-            assert not preempt_ok.any(), \
-                "row-backed arm must poison on the global overflow"
+    in-kernel preemption envelope; the same priority on a budget CQ
+    collapses every forest to the host path."""
+    d, _ = build_mixed()
+    _fill_pending(d, per_q=2)
+    # a 2^21 priority overflows the 20-bit composite-key field
+    d.create_workload(mk("huge", lq, 1000, prio=(1 << 21), t=99.0))
+    preempt_ok = np.asarray(_pack(d).arrays["preempt_ok"])
+    assert preempt_ok.any() == modeled
 
 
-@pytest.mark.parametrize("agg", ["1", "0"], ids=["agg-on", "agg-off"])
+@pytest.mark.parametrize("runtime", [2, 0], ids=["runtime-2", "no-finish"])
 @pytest.mark.parametrize("two_flavors", [False, True],
                          ids=["one-flavor", "flavor-walk"])
-def test_burst_decisions_identical_head_pack_on_off(monkeypatch, agg,
-                                                    two_flavors):
-    """Twin-driver end-to-end: schedule_burst decisions with head-only
-    packing on vs off (the row-backed parity arm) are bit-identical
-    under churn, composed with aggregate compression both ways."""
+def test_burst_decisions_identical_head_pack_on_off(runtime, two_flavors):
+    """schedule_burst on the mixed cluster, whose exempt rows are out of
+    the budget, decides every cycle as the host scheduler's cycles do on
+    the same spec, with runs that finish and with runs that do not."""
     def spec(d):
         for c in range(2):
             for q in range(2):
@@ -146,29 +122,15 @@ def test_burst_decisions_identical_head_pack_on_off(monkeypatch, agg,
                         1500 if i % 3 else 2500,
                         prio=(i % 3) * 10, t=float(10 * c + 3 * q + i)))
 
-    runs = {}
-    monkeypatch.setenv("KUEUE_TPU_AGG_PLANES", agg)
-    for flag in ("1", "0"):
-        monkeypatch.setenv("KUEUE_TPU_HEAD_PACK", flag)
-        d, clock = build_mixed(two_flavors=two_flavors)
-        spec(d)
-        stats = d.schedule_burst(
-            16, runtime=2,
-            on_cycle_start=lambda k: setattr(clock, "t", clock.t + 1.0))
-        runs[flag] = (
-            [(sorted(s.admitted), sorted(s.skipped),
-              sorted(s.inadmissible), sorted(s.preempted_targets))
-             for s in stats],
-            d.admitted_keys())
-    assert runs["1"][0] == runs["0"][0], "per-cycle decisions diverged"
-    assert runs["1"][1] == runs["0"][1]
+    burst, host = burst_and_host(spec, two_flavors, runtime)
+    assert burst[0] == host[0][:len(burst[0])], "per-cycle decisions diverged"
+    assert burst[1] == host[1]
 
 
-def test_head_flip_sequence_parity(monkeypatch):
+def test_head_flip_sequence_parity():
     """Deterministic head churn: admit, finish the head, evict, requeue
     — streaming pack parity (and the scoped uid order's delta
     maintenance) must hold after every flip."""
-    monkeypatch.setenv("KUEUE_TPU_HEAD_PACK", "1")
     d, clock = build_mixed()
     for i in range(8):
         d.create_workload(mk(f"w{i}", f"lq-{i % 2}-{(i // 2) % 2}", 1500,
@@ -213,8 +175,7 @@ def test_streaming_parity_under_churn_head_pack(window):
                                f"seed{seed}:step{step}:{label}")
 
 
-def test_head_pack_stats_surface(monkeypatch):
-    monkeypatch.setenv("KUEUE_TPU_HEAD_PACK", "1")
+def test_head_pack_stats_surface():
     d, clock = build_mixed()
     _fill_pending(d, per_q=2)
     d.schedule_burst(

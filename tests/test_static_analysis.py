@@ -495,12 +495,14 @@ def test_mi_pipeline_int32_matches_int64_reference():
 
 
 def test_stream_pack_mi_planes_are_int32_end_to_end():
-    # regression guard: the live _init_full must hand int32 slot
-    # indices to the order maintainers and grids
+    # regression guard: a full build must hand int32 slot indices to
+    # the order maintainers and grids; it places its rows as a delta
+    # window does, by the per-CQ rank of _cq_mi
     import inspect
     from kueue_tpu.ops import stream_pack
-    src = inspect.getsource(stream_pack)
-    assert "np.arange(n, dtype=np.int32)" in src
-    assert "mi_a = np.empty(n, dtype=np.int32)" in src
-    assert "mi_sorted = idx - seg_start" in src
-    assert "mi_a32" not in src  # the old widening alias is gone
+    src = inspect.getsource(stream_pack._cq_mi)
+    assert "mi = np.empty(rec.n_rows, dtype=np.int32)" in src
+    assert "np.arange(rec.n_rows, dtype=np.int32)" in src
+    assert "_place_rows(state, records)" in inspect.getsource(
+        stream_pack._init_full)
+    assert "mi_a32" not in inspect.getsource(stream_pack)

@@ -34,10 +34,13 @@ from kueue_tpu.api.types import (
     ClusterQueue,
     FlavorQuotas,
     LocalQueue,
+    PreemptionPolicy,
     QueueingStrategy,
+    ReclaimWithinCohort,
     ResourceFlavor,
     ResourceGroup,
     ResourceQuota,
+    WithinClusterQueue,
 )
 from kueue_tpu.chaos import injector as chaos
 from kueue_tpu.chaos.injector import ChaosInjector, InjectedCrash
@@ -288,7 +291,11 @@ def test_schedule_burst_packs_every_window_after_the_first_in_place(calls):
 # Streaming parity: row-grade admission-check flips
 # ---------------------------------------------------------------------------
 
-def build_checked_cluster(n_cqs=4, checks=("chk-a", "chk-b")):
+def build_checked_cluster(n_cqs=4, checks=("chk-a", "chk-b"),
+                          preempt=False):
+    """Two cohorts of queues behind two admission checks; with
+    ``preempt`` every queue may preempt, so no admitted row is folded
+    out of the grid (``burst.compressible_cqs``)."""
     clock = Clock()
     d = Driver(clock=clock, use_device_solver=True)
     d.apply_resource_flavor(ResourceFlavor(name="default"))
@@ -298,6 +305,10 @@ def build_checked_cluster(n_cqs=4, checks=("chk-a", "chk-b")):
         d.apply_cluster_queue(ClusterQueue(
             name=f"cq-{i}", cohort=f"co-{i % 2}",
             admission_checks=list(checks),
+            preemption=(PreemptionPolicy(
+                reclaim_within_cohort=ReclaimWithinCohort.ANY,
+                within_cluster_queue=WithinClusterQueue.LOWER_PRIORITY)
+                if preempt else PreemptionPolicy()),
             queueing_strategy=QueueingStrategy.BEST_EFFORT_FIFO,
             resource_groups=[ResourceGroup(
                 covered_resources=["cpu"],
@@ -316,18 +327,17 @@ def _reserved_unadmitted(d):
                   and w.has_quota_reservation and not w.is_admitted)
 
 
-def test_streaming_parity_row_grade_check_flips(monkeypatch):
+def test_streaming_parity_row_grade_check_flips():
     """Admission-check state flips journal row-grade dirt (touch_row):
     one ready check out of two moves exactly one workload's ok bit —
     the streaming pack must patch that single row, not re-walk the CQ,
     and stay bit-identical to a fresh pack at every boundary.
 
-    Pinned to the uncompressed arm: with aggregate planes on, these
-    reserved rows are compressed out of the pack and the row patch is
+    On a preempting cluster: where no queue of a forest preempts, these
+    reserved rows are folded out of the grid and the row patch is
     (correctly) skipped — tests/test_aggregate_compression.py covers
     that side."""
-    monkeypatch.setenv("KUEUE_TPU_AGG_PLANES", "0")
-    d, clock = build_checked_cluster()
+    d, clock = build_checked_cluster(preempt=True)
     for i in range(8):
         d.create_workload(mk(f"w{i}", f"lq-{i % 4}", 2000,
                              prio=(i % 3) * 10, t=float(i)))
