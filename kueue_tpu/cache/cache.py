@@ -200,10 +200,14 @@ class Cache:
     def add_or_update_topology(self, topology: Topology) -> None:
         with self._lock:
             self.tas.add_topology(topology)
+            # which flavors a PodSet may take turns on the levels
+            # (ops/eligibility.py): the solver's structure is rebuilt
+            self.structure_generation += 1
 
     def delete_topology(self, name: str) -> None:
         with self._lock:
             self.tas.delete_topology(name)
+            self.structure_generation += 1
 
     # ------------------------------------------------------------------
     # Workloads (admitted / assumed) — reference cache.go:536-658

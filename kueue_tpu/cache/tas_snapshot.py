@@ -17,12 +17,14 @@ from __future__ import annotations
 from dataclasses import dataclass, field
 from typing import Optional
 
+import numpy as np
+
 from ..api.types import (
     PodSetTopologyRequest,
     TopologyAssignment,
     TopologyDomainAssignment,
 )
-from .tas_cache import NodeInfo
+from .tas_cache import NodeInfo, TASLayout
 
 
 @dataclass(eq=False)  # identity hash: domains are keyed in chosen-maps
@@ -32,72 +34,147 @@ class Domain:
     level: int
     parent: Optional["Domain"] = None
     children: list["Domain"] = field(default_factory=list)
-    # leaf-only: free capacity (canonical ints)
+    # leaf-only: free capacity and allocatable (canonical ints)
     free: dict[str, int] = field(default_factory=dict)
+    capacity: dict[str, int] = field(default_factory=dict)
     # per-query state
     state: int = 0                 # how many pods fit in this subtree
 
 
 class TASFlavorSnapshot:
-    def __init__(self, flavor: str, levels: list[str]):
+    """One flavor's topology as a cycle sees it: the flavor's layout
+    (``TASLayout``: the domains, level-sorted) and its leaves' free
+    capacity ``free`` [N_leaf, R] (allocatable less usage) as the cycle
+    started.  The ``Domain`` tree the host walk reads is built from them
+    on first use; the batched topology stage (``ops/tas_kernel.py``)
+    reads the planes.  ``charge``/``holds`` keep what the cycle's
+    admissions placed (``cycle_used``), apart from ``free``."""
+
+    def __init__(self, flavor: str, levels: list[str],
+                 layout: Optional[TASLayout] = None,
+                 free: Optional[np.ndarray] = None):
         self.flavor = flavor
         self.levels = levels
-        self.leaves: dict[tuple, Domain] = {}
-        self.roots: list[Domain] = []
-        self.domains_per_level: list[list[Domain]] = [[] for _ in levels]
+        if layout is None:
+            layout = TASLayout.build(levels, [])
+            free = layout.capacity.copy()
+        self.layout = layout
+        self.free = free
+        self.cycle_used: Optional[np.ndarray] = None
+        self.host_queries = 0          # find_topology_assignment calls
+        self._tree = None
 
     @staticmethod
     def build(flavor: str, levels: list[str], nodes: list[NodeInfo],
               usage: dict[tuple, dict[str, int]]) -> "TASFlavorSnapshot":
-        snap = TASFlavorSnapshot(flavor, levels)
-        by_id: dict[tuple, Domain] = {}
-        for node in nodes:
-            values = tuple(node.labels.get(lvl, "") for lvl in levels)
-            if any(v == "" for v in values):
-                continue  # node not fully labelled for this topology
-            leaf = by_id.get(values)
-            if leaf is None:
-                leaf = Domain(id=values, level=len(levels) - 1)
-                by_id[values] = leaf
-                snap.leaves[values] = leaf
-            for rname, cap in node.capacity.items():
-                leaf.free[rname] = leaf.free.get(rname, 0) + cap
-        for dom_id, used in usage.items():
-            leaf = snap.leaves.get(tuple(dom_id))
-            if leaf is not None:
-                for rname, qty in used.items():
-                    leaf.free[rname] = leaf.free.get(rname, 0) - qty
-        # link up the tree
-        for leaf in list(snap.leaves.values()):
-            child = leaf
-            for lvl in range(len(levels) - 2, -1, -1):
-                pid = child.id[: lvl + 1]
-                parent = by_id.get(pid)
-                if parent is None:
-                    parent = Domain(id=pid, level=lvl)
-                    by_id[pid] = parent
-                if child.parent is None:
-                    child.parent = parent
-                    parent.children.append(child)
-                child = parent
-        for dom in by_id.values():
-            snap.domains_per_level[dom.level].append(dom)
-            if dom.level == 0:
-                snap.roots.append(dom)
-        return snap
+        layout = TASLayout.build(
+            levels, nodes,
+            extra_resources={r for per in usage.values() for r in per})
+        return TASFlavorSnapshot(flavor, levels, layout=layout,
+                                 free=layout.capacity
+                                 - layout.usage_plane(usage))
+
+    # -- the host walk's tree, built on first use -----------------------
+
+    def _build_tree(self):
+        lay = self.layout
+        names = lay.resource_names
+        doms = [[Domain(id=did, level=lvl) for did in ids]
+                for lvl, ids in enumerate(lay.domain_ids)]
+        for lvl, par in enumerate(lay.parents):
+            upper = doms[lvl]
+            for child, p in zip(doms[lvl + 1], par.tolist()):
+                child.parent = upper[p]
+                upper[p].children.append(child)
+        free = self.free.tolist()
+        cap = lay.capacity.tolist()
+        leaves = {}
+        for leaf, f, c in zip(doms[-1], free, cap):
+            leaf.free = dict(zip(names, f))
+            leaf.capacity = dict(zip(names, c))
+            leaves[leaf.id] = leaf
+        self._tree = (leaves, doms[0] if doms else [], doms)
+        return self._tree
+
+    @property
+    def leaves(self) -> dict[tuple, Domain]:
+        return (self._tree or self._build_tree())[0]
+
+    @property
+    def roots(self) -> list[Domain]:
+        return (self._tree or self._build_tree())[1]
+
+    @property
+    def domains_per_level(self) -> list[list[Domain]]:
+        return (self._tree or self._build_tree())[2]
+
+    # -- in-cycle usage (scheduler admit loop) ---------------------------
+
+    def demand(self, placements) -> Optional[tuple]:
+        """``placements`` = [(TopologyAssignment, per-pod requests)] as
+        (leaf indices, [k, R] demand); None where one names a leaf or a
+        resource the layout does not know."""
+        lay = self.layout
+        r_index = lay.r_index
+        idx, need = [], []
+        for assignment, per_pod in placements:
+            vec = np.zeros(len(lay.resource_names), dtype=np.int64)
+            for r, v in per_pod.items():
+                if v <= 0:
+                    continue
+                ri = r_index.get(r)
+                if ri is None:
+                    return None
+                vec[ri] = v
+            for d in assignment.domains:
+                i = lay.leaf_index.get(tuple(d.values))
+                if i is None:
+                    return None
+                idx.append(i)
+                need.append(d.count * vec)
+        if not idx:
+            return (np.zeros(0, dtype=np.int64),
+                    np.zeros((0, len(lay.resource_names)), dtype=np.int64))
+        return np.asarray(idx, dtype=np.int64), np.stack(need)
+
+    def holds(self, demand) -> bool:
+        """Do the demand's leaves still hold it after what the cycle's
+        earlier admissions placed (``charge``)?"""
+        if demand is None:
+            return False
+        idx, need = demand
+        uniq, inv = np.unique(idx, return_inverse=True)
+        total = np.zeros((len(uniq), need.shape[1]), dtype=np.int64)
+        np.add.at(total, inv, need)
+        left = self.free[uniq]
+        if self.cycle_used is not None:
+            left = left - self.cycle_used[uniq]
+        return bool((total <= left).all())
+
+    def charge(self, demand) -> None:
+        if demand is None:
+            return
+        if self.cycle_used is None:
+            self.cycle_used = np.zeros_like(self.free)
+        idx, need = demand
+        np.add.at(self.cycle_used, idx, need)
 
     # ------------------------------------------------------------------
 
     def _fill_in_counts(self, per_pod: dict[str, int],
-                        assumed: dict[tuple, dict[str, int]] | None = None) -> None:
+                        assumed: dict[tuple, dict[str, int]] | None = None,
+                        simulate_empty: bool = False) -> None:
         """Phase 1 (reference tas_flavor_snapshot.go fillInCounts): compute
-        how many pods fit in each domain, bottom-up."""
+        how many pods fit in each domain, bottom-up.  ``simulate_empty``
+        counts against the leaves' allocatable, as if no workload placed
+        by topology held any; ``assumed`` is charged either way."""
         for leaf in self.leaves.values():
             fits = None
+            have = leaf.capacity if simulate_empty else leaf.free
             for rname, need in per_pod.items():
                 if need <= 0:
                     continue
-                free = leaf.free.get(rname, 0)
+                free = have.get(rname, 0)
                 if assumed:
                     free -= assumed.get(leaf.id, {}).get(rname, 0)
                 n = max(0, free) // need
@@ -119,11 +196,13 @@ class TASFlavorSnapshot:
             self, count: int, per_pod: dict[str, int],
             request: PodSetTopologyRequest,
             assumed: dict[tuple, dict[str, int]] | None = None,
+            simulate_empty: bool = False,
     ) -> tuple[Optional[TopologyAssignment], str]:
         """Phase 1 + 2 (reference tas_flavor_snapshot.go:406-613).
 
         Returns (assignment at the leaf level, reason-on-failure).
         """
+        self.host_queries += 1
         if not self.levels:
             return None, "no topology levels"
         required_idx = self._level_index(request.required)
@@ -133,9 +212,11 @@ class TASFlavorSnapshot:
         if request.preferred and preferred_idx is None:
             return None, f"level {request.preferred} not in topology"
         if self._device_kernel_eligible(request):
-            return self._find_device(count, per_pod, request, assumed,
-                                     required_idx, preferred_idx)
-        self._fill_in_counts(per_pod, assumed)
+            found = self._find_device(count, per_pod, request, assumed,
+                                      simulate_empty)
+            if found is not None:
+                return found
+        self._fill_in_counts(per_pod, assumed, simulate_empty)
 
         if request.unconstrained:
             # any set of leaves; minimize domain count from the top
@@ -171,12 +252,13 @@ class TASFlavorSnapshot:
     # -- device kernel path (ops/tas_kernel, TASDeviceKernel gate) -----
 
     def _device_kernel_eligible(self, request: PodSetTopologyRequest) -> bool:
-        """The batched kernel implements all three TAS profiles
+        """The kernel implements all three TAS profiles
         (BestFit default, TASProfileMostFreeCapacity,
         TASProfileLeastFreeCapacity incl. Mixed's unconstrained
         variant — tas_flavor_snapshot.go:551-568)."""
         from .. import features
-        return features.enabled("TASDeviceKernel") and bool(self.leaves)
+        return (features.enabled("TASDeviceKernel")
+                and bool(self.layout.leaf_ids))
 
     def _device_profile(self, unconstrained: bool) -> str:
         if self._use_best_fit(unconstrained):
@@ -185,106 +267,92 @@ class TASFlavorSnapshot:
             return "leastfree"
         return "mostfree"
 
+    def question(self, count: int, per_pod: dict[str, int],
+                 request: PodSetTopologyRequest, empty: bool = False,
+                 charges: tuple = ()):
+        """The placement question of ``count`` pods of ``per_pod`` under
+        ``request`` as the kernel asks it (``ops/tas_kernel.TASQuery``),
+        against the free nodes or the empty tree, less ``charges``
+        ((leaf index, [R] demand), ...); None where a requested resource
+        is on no node (nothing holds a pod)."""
+        from ..ops import tas_kernel as tk
+        lay = self.layout
+        vec = np.zeros(len(lay.resource_names), dtype=np.int64)
+        for r, v in per_pod.items():
+            if v > 0:
+                ri = lay.r_index.get(r)
+                if ri is None:
+                    return None
+                vec[ri] = v
+        if request.unconstrained:
+            kind, level = tk.QUERY_UNCONSTRAINED, -1
+        elif request.required is not None:
+            kind, level = tk.QUERY_REQUIRED, self._level_index(
+                request.required)
+        else:
+            kind = tk.QUERY_PREFERRED
+            level = (self._level_index(request.preferred)
+                     if request.preferred is not None
+                     else len(self.levels) - 1)
+        return tk.TASQuery(vec, count, kind, level, empty, charges)
+
+    def assumed(self, charges) -> dict[tuple, dict[str, int]]:
+        """``question``'s charges as the walk's ``assumed``: a leaf id's
+        demand by resource name."""
+        lay = self.layout
+        out: dict[tuple, dict[str, int]] = {}
+        for leaf, vec in charges:
+            slot = out.setdefault(lay.leaf_ids[leaf], {})
+            for r, v in zip(lay.resource_names, np.asarray(vec).tolist()):
+                if v:
+                    slot[r] = slot.get(r, 0) + v
+        return out
+
+    def answered(self, query, answer) -> tuple[Optional[TopologyAssignment],
+                                               str]:
+        """A kernel answer as ``find_topology_assignment`` returns it:
+        the placement at the leaf level, or the reason none was found."""
+        from ..ops import tas_kernel as tk
+        if not answer.ok:
+            return None, self._no_fit(
+                query.count, answer.best,
+                query.level if query.kind == tk.QUERY_REQUIRED else None)
+        leaf_ids = self.layout.leaf_ids
+        return TopologyAssignment(levels=list(self.levels), domains=[
+            TopologyDomainAssignment(values=list(leaf_ids[leaf]), count=c)
+            for leaf, c in answer.placement]), ""
+
     def _find_device(self, count: int, per_pod: dict[str, int],
                      request: PodSetTopologyRequest,
                      assumed: dict[tuple, dict[str, int]] | None,
-                     required_idx: Optional[int],
-                     preferred_idx: Optional[int],
-                     ) -> tuple[Optional[TopologyAssignment], str]:
-        """find_topology_assignment on the batched kernel
-        (ops/tas_kernel: segment reductions over level-CSR arrays),
-        decision-identical to the scalar walk (tests/test_tas_kernel.py
-        + test_tas_device_path)."""
-        import numpy as np
+                     simulate_empty: bool = False):
+        """find_topology_assignment as one question of the batched
+        kernel (``ops/tas_kernel.answer_queries``), decision-identical
+        to the scalar walk (tests/test_tas_kernel.py,
+        tests/test_tas_device_path.py); None where the int32 kernel
+        cannot answer it exactly, for the walk to answer."""
         from ..ops import tas_kernel as tk
-
-        packed = getattr(self, "_packed_tas", None)
-        if packed is None:
-            packed = self._packed_tas = tk.pack_tas(self)
-        sizes = tuple(packed.level_sizes)
-        parents = tuple(packed.parents)
-        r_index = {r: i for i, r in enumerate(packed.resource_names)}
-
-        per_pod_vec = np.zeros(max(1, len(packed.resource_names)),
-                               dtype=np.int32)
-        unknown_requested = False
-        for r, v in per_pod.items():
-            if v <= 0:
+        lay = self.layout
+        charges = []
+        for did, a in (assumed or {}).items():
+            i = lay.leaf_index.get(did)
+            if i is None:
                 continue
-            ri = r_index.get(r)
-            if ri is None:
-                unknown_requested = True  # no leaf has it: states all 0
-            else:
-                per_pod_vec[ri] = v
-        leaf_free = packed.leaf_free
-        if assumed:
-            leaf_free = leaf_free.copy()
-            for i, did in enumerate(packed.leaf_ids):
-                a = assumed.get(did)
-                if a:
-                    for r, v in a.items():
-                        ri = r_index.get(r)
-                        if ri is not None:
-                            leaf_free[i, ri] = max(0, leaf_free[i, ri] - v)
-        if unknown_requested:
-            leaf_free = np.zeros_like(packed.leaf_free)
-
-        def level_states(level: int) -> np.ndarray:
-            states = tk.fill_counts(leaf_free, per_pod_vec, parents,
-                                    level_sizes=sizes)
-            return np.asarray(states[level])
-
-        def total_fit() -> int:
-            return int(level_states(0).sum())
-
-        def finish(leaf_counts) -> TopologyAssignment:
-            domains = [TopologyDomainAssignment(values=list(did),
-                                                count=int(c))
-                       for did, c in sorted(
-                           (packed.leaf_ids[i], int(c))
-                           for i, c in enumerate(np.asarray(leaf_counts))
-                           if c)]
-            return TopologyAssignment(levels=list(self.levels),
-                                      domains=domains)
-
-        profile = self._device_profile(False)
-        if request.unconstrained:
-            ok, counts = tk.split_across_roots(
-                leaf_free, per_pod_vec, parents, count, level_sizes=sizes,
-                profile=self._device_profile(True),
-                descend_profile=profile)
-            if not bool(ok):
-                return None, self._fit_message(count, total_fit())
-            return finish(counts), ""
-
-        if required_idx is not None:
-            ok, counts = tk.best_fit_descend(
-                leaf_free, per_pod_vec, parents, count,
-                level_sizes=sizes, level=required_idx, profile=profile)
-            if not bool(ok):
-                # host message reads Domain.state, unfilled on this path:
-                # compute the best single-domain fit from kernel states
-                best = int(level_states(required_idx).max(initial=0))
-                return None, (
-                    f"topology {self.flavor!r} allows to fit only {best} "
-                    f"out of {count} pod(s) in a single "
-                    f"{self.levels[required_idx]!r}")
-            return finish(counts), ""
-
-        start = (preferred_idx if preferred_idx is not None
-                 else len(self.levels) - 1)
-        for lvl in range(start, -1, -1):
-            ok, counts = tk.best_fit_descend(
-                leaf_free, per_pod_vec, parents, count,
-                level_sizes=sizes, level=lvl, profile=profile)
-            if bool(ok):
-                return finish(counts), ""
-        ok, counts = tk.split_across_roots(
-            leaf_free, per_pod_vec, parents, count, level_sizes=sizes,
-            profile=profile)
-        if not bool(ok):
-            return None, self._fit_message(count, total_fit())
-        return finish(counts), ""
+            vec = np.zeros(len(lay.resource_names), dtype=np.int64)
+            for r, v in a.items():
+                ri = lay.r_index.get(r)
+                if ri is not None:
+                    vec[ri] = v
+            charges.append((i, vec))
+        q = self.question(count, per_pod, request, simulate_empty,
+                          tuple(charges))
+        if q is None:
+            return None, self._no_fit(
+                count, 0, self._level_index(request.required))
+        ans, = tk.answer_queries(lay, self.free, [q],
+                                 self._device_profile(False),
+                                 self._device_profile(True))
+        return None if ans is None else self.answered(q, ans)
 
     # -- helpers --
 
@@ -377,10 +445,18 @@ class TASFlavorSnapshot:
             self._descend(child, take, out)
 
     def _fit_message(self, count: int, total: int) -> str:
-        return (f"topology {self.flavor!r} allows to fit only {total} "
-                f"out of {count} pod(s)")
+        return self._no_fit(count, total)
 
     def _fit_message_level(self, count: int, level: int) -> str:
         best = max((d.state for d in self.domains_per_level[level]), default=0)
-        return (f"topology {self.flavor!r} allows to fit only {best} "
-                f"out of {count} pod(s) in a single {self.levels[level]!r}")
+        return self._no_fit(count, best, level)
+
+    def _no_fit(self, count: int, best: int,
+                level: Optional[int] = None) -> str:
+        """Why no placement was found: ``best`` pods fit, in all the
+        roots, or in a single domain of a required ``level``."""
+        msg = (f"topology {self.flavor!r} allows to fit only {best} "
+               f"out of {count} pod(s)")
+        if level is not None:
+            msg += f" in a single {self.levels[level]!r}"
+        return msg

@@ -54,9 +54,13 @@ DEFAULT_FEATURE_GATES: dict[str, FeatureSpec] = {
     "TASProfileMostFreeCapacity": FeatureSpec(False, "Alpha"),
     "TASProfileLeastFreeCapacity": FeatureSpec(False, "Alpha"),
     "TASProfileMixed": FeatureSpec(False, "Alpha"),
-    # kueue-tpu extension: route find_topology_assignment through the
-    # batched segment-tree kernel (ops/tas_kernel) — implements all
-    # three TAS profiles, bit-matching the scalar tree walk
+    # kueue-tpu extension: the host walk's find_topology_assignment
+    # asks its one question of the batched kernel
+    # (ops/tas_kernel.answer_queries) instead of the scalar tree walk —
+    # all three TAS profiles, bit-matching it; a question the int32
+    # kernel cannot answer exactly is walked.  The per-cycle engine's
+    # vector heads are placed by the batched stage (CycleSolver.tas_stage:
+    # a cycle's questions a launch) either way
     "TASDeviceKernel": FeatureSpec(True, "Beta"),
 }
 

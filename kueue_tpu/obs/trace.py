@@ -88,6 +88,9 @@ HOT_PATH_PHASES = (
     "cycle.nominate.search_fallback",  # the one-launch-a-head route
     "cycle.nominate.scan_dispatch",   # pack targets + admit-scan dispatch
     "cycle.nominate.fair_preempt",    # one fair-sharing target search
+    "cycle.nominate.tas",             # the batched topology stage
+    "cycle.nominate.tas.pack",        # the queries and planes laid out
+    "cycle.nominate.tas.fetch",       # the launch and its placements back
     "cycle.order",      # classical sort or fair-sharing tournament setup
     "cycle.admit",      # sequential admit loop (assume/apply/requeue)
     "cycle.admit.prepare",            # per-head assignments, speculative

@@ -1337,7 +1337,7 @@ class _RowWalk:
         self.cq_live = cq_live
         cq_name = st.cq_names[ci]
         self.covers_pods = cq_name in st.cq_covers_pods
-        cq_ok = st.cq_vector_ok
+        cq_ok = st.cq_window_ok
         cq_vec = bool(cq_ok[ci]) if cq_ok is not None else False
         if cq_vec and cq_live.spec.namespace_selector:
             cq_vec = False   # selector evaluation stays on the host path
@@ -1500,12 +1500,14 @@ class _RowWalk:
         else:
             # the flavors a row's PodSet may not take, a group: all
             # zero, and nothing to ask a row, unless a flavor of this
-            # queue carries labels or taints
+            # queue carries labels or taints (a queue the window does
+            # not decide has no row it reads)
             G = self.P * st.n_groups
             skip = (np.array([skip_mask(info, st, self.ci)
                               for info in self.infos],
                              dtype=np.uint8).reshape(i, G)
-                    if declares(st, self.ci) and st.pod_sets == self.P
+                    if (self.cq_vec and declares(st, self.ci)
+                        and st.pod_sets == self.P)
                     else np.zeros((i, G), dtype=np.uint8))
             derived = (
                 np.asarray(self.key_l) if i else np.empty(0, dtype="U1"),

@@ -59,7 +59,8 @@ from .cycle import (admit_scan, admit_scan_forests, admit_scan_preempt,
                     classify_np, cycle_order_np, decision_pairs,
                     pick_preempt_slot_np, res_slots, slot_frs)
 from .device import on_accelerator, output_devices
-from .eligibility import bind_flavor_lists, skip_mask, slots_of_mask
+from .eligibility import (bind_flavor_lists, skip_mask, slots_of_mask,
+                          tas_gate)
 
 # A flat admit scan is one lax.scan step per head; the forest-parallel
 # variant processes one head per cohort forest per step.  Below this head
@@ -107,6 +108,16 @@ class ClassifiedCycle:
     scalar_mask: np.ndarray = None         # [W] bool
     host_assignments: dict = None          # {wi: Assignment}
     host_pairs: dict = None                # {wi: [(F-index, amount)]}
+    # the batched topology stage (CycleSolver.tas_stage): a Fit head's
+    # placements (one TopologyAssignment or None a PodSet), the heads it
+    # turned from Fit to Preempt (no flavor resource needs preemption)
+    # and to NoFit, the reasons it gave a head, and whether two Fit
+    # heads' placements share a node that cannot hold both
+    tas_place: dict = None                 # {wi: [TopologyAssignment|None]}
+    tas_demoted: np.ndarray = None         # [W] bool
+    tas_nofit: np.ndarray = None           # [W] bool
+    tas_reason: dict = None                # {wi: [(PodSet, message)]}
+    tas_overlap: bool = False
 
     @property
     def n(self) -> int:
@@ -230,6 +241,24 @@ class CycleSolver:
                                       # every flavor of their queue
             "eligibility_masks_built": 0,  # signatures evaluated, as
                                            # against read from a cache
+            # the batched topology stage (tas_stage, ops/tas_kernel.py):
+            "tas_heads": 0,           # vector heads it placed or tried
+            "tas_queries": 0,         # PodSet queries, against the free
+                                      # nodes and against the empty tree
+            "tas_unique_queries": 0,  # of them, the distinct ones a
+                                      # launch evaluated
+            "tas_launches": 0,        # place_queries launches
+            "tas_host_queries": 0,    # find_topology_assignment calls
+                                      # of the cycles' host walks
+            "tas_topology_nofit": 0,  # queries no domain held
+            "tas_preferred_climbs": 0,  # preferred queries placed above
+                                        # their level
+            "tas_refit_skips": 0,     # heads the admit loop skipped: an
+                                      # earlier admission of the cycle
+                                      # filled a node of their placement
+            "tas_host_admit_cycles": 0,  # cycles whose Fit placements
+                                         # shared a node: the host admit
+                                         # loop decided them in order
         }
         self._structure: Optional[PackedStructure] = None
         self._potential0 = None
@@ -494,7 +523,8 @@ class CycleSolver:
                        heads: list[Info]) -> PackedStructure:
         gen = getattr(snapshot, "structure_generation", -1)
         st = self._structure
-        if st is None or st.generation != gen or gen < 0:
+        if (st is None or st.generation != gen or gen < 0
+                or getattr(st, "tas_gate", None) != tas_gate()):
             st = pack_structure(snapshot, heads, generation=gen)
             bind_flavor_lists(snapshot, st)
             self._structure = st
@@ -528,11 +558,6 @@ class CycleSolver:
                 mask[wi] = True
                 reasons["multi_podset"] = reasons.get("multi_podset", 0) + 1
                 self.stats["podset_scalar_heads"] += 1
-                continue
-            if any(ps.topology_request is not None
-                   for ps in h.obj.pod_sets):
-                mask[wi] = True
-                reasons["topology"] = reasons.get("topology", 0) + 1
         return mask
 
     def _start_slots(self, snapshot: Snapshot, heads: list[Info],
@@ -559,7 +584,7 @@ class CycleSolver:
         (ops/eligibility.py).  Rows of pads and of queues the vector
         walk does not decide are True."""
         skip = np.zeros((W, st.pod_sets * st.n_groups), dtype=np.int32)
-        if st.flavors_declared:
+        if st.masks_needed:
             cq_index = st.cq_index
             for wi, h in enumerate(heads):
                 ci = cq_index.get(h.cluster_queue, -1)
@@ -741,6 +766,249 @@ class CycleSolver:
         cls.preempt_res_fit[heads] = np.take_along_axis(
             cls.slot_res_fit[heads], res_slots(grp, slots)[:, :, None, :],
             axis=2)[:, :, 0, :]
+
+    # -- the batched topology stage ------------------------------------
+
+    @staticmethod
+    def warm_topology(snapshot: Snapshot, pod_sets: list) -> int:
+        """One-time setup for the topology stage: compile every launch
+        the placement questions of ``pod_sets`` (the distinct PodSets
+        the queues hold: count, requests, topology request; None asks
+        unconstrained) can make on each topology flavor, against the
+        free nodes and the empty tree (``tas_kernel.warm_queries``).
+        Returns the launches built."""
+        from ..api.types import PodSetTopologyRequest
+        from .tas_kernel import warm_queries
+        built = 0
+        for snap in snapshot.tas_flavors.values():
+            if not snap.layout.leaf_ids:
+                continue
+            queries = []
+            for ps in pod_sets:
+                req = (ps.topology_request
+                       or PodSetTopologyRequest(unconstrained=True))
+                for empty in (False, True):
+                    q = snap.question(ps.count, ps.requests, req, empty)
+                    if q is not None:
+                        queries.append(q)
+            built += warm_queries(snap.layout, queries,
+                                  snap._device_profile(False),
+                                  snap._device_profile(True))
+        return built
+
+    def tas_active(self, cls: ClassifiedCycle) -> bool:
+        """Does this cycle have vector heads on a queue with a TAS
+        flavor for the topology stage to place?"""
+        st = cls.packed.structure
+        if not st.cq_tas.any():
+            return False
+        n = cls.n
+        on = (cls.fit0[:n] | cls.preempt0[:n]) & ~cls.scalar_mask[:n]
+        return bool(on.any() and st.cq_tas[
+            np.maximum(cls.packed.wl_cq[:n], 0)][on].any())
+
+    def _tas_requests(self, cls: ClassifiedCycle, wi: int) -> list:
+        """Head ``wi``'s placement questions, one a PodSet (None where
+        the PodSet asks none): (flavor, request, pods, per-pod requests),
+        the flavor the smallest name among those its walk chose, as
+        ``FlavorAssigner._find_tas`` asks them."""
+        from ..api.types import PodSetTopologyRequest
+        st = cls.packed.structure
+        h = cls.heads[wi]
+        ci = int(cls.packed.wl_cq[wi])
+        cq = cls.snapshot.cq(h.cluster_queue)
+        groups = cq.spec.resource_groups
+        grp = st.res_group[ci]
+        covers_pods = h.cluster_queue in st.cq_covers_pods
+        implied = bool(st.cq_tas_only[ci])
+        out = []
+        for p, psr in enumerate(h.total_requests):
+            req = psr.topology_request
+            if req is None:
+                if not implied:
+                    out.append(None)
+                    continue
+                req = PodSetTopologyRequest(unconstrained=True)
+            names = set()
+            for res in psr.requests:
+                if res == "pods" and not covers_pods:
+                    continue
+                g = int(grp[st.r_index[res]])
+                names.add(groups[g].flavors[int(cls.slots0[wi, p, g])].name)
+            if not names:
+                out.append(None)
+                continue
+            per_pod = {r: v // max(1, psr.count)
+                       for r, v in psr.requests.items()}
+            out.append((min(names), req, psr.count, per_pod))
+        return out
+
+    def _tas_run(self, cls: ClassifiedCycle, plans: dict,
+                 empty: dict) -> dict:
+        """The heads' questions, PodSet after PodSet, each rank's in one
+        launch a flavor; a head's later PodSets on a flavor are charged
+        with its earlier ones' placements there.  Returns {wi: (ok,
+        [TopologyAssignment or None a PodSet], (PodSet, reason) or None,
+        {flavor: [(leaf, [R] demand)]})}."""
+        snaps = cls.snapshot.tas_flavors
+        out = {wi: [True, [None] * len(pl), None, {}]
+               for wi, pl in plans.items()}
+        for p in range(max((len(pl) for pl in plans.values()), default=0)):
+            batch: dict[str, list] = {}
+            with _span("cycle.nominate.tas.pack"):
+                self._tas_batch(plans, p, out, snaps, empty, batch)
+            with _span("cycle.nominate.tas.fetch"):
+                self._tas_answer(batch, out, snaps)
+        return {wi: tuple(row) for wi, row in out.items()}
+
+    @staticmethod
+    def _tas_batch(plans: dict, p: int, out: dict, snaps: dict,
+                   empty: dict, batch: dict) -> None:
+        """The heads' PodSet-``p`` questions, a list a flavor; a head
+        whose flavor has no topology fails here."""
+        for wi, pl in plans.items():
+            row = out[wi]
+            if not row[0] or p >= len(pl) or pl[p] is None:
+                continue
+            f_name, req, count, per_pod = pl[p]
+            snap = snaps.get(f_name)
+            if snap is None:
+                row[0] = False
+                row[2] = (p, f"no topology information for flavor "
+                             f"{f_name}")
+                continue
+            q = snap.question(count, per_pod, req, bool(empty[wi]),
+                              tuple(row[3].get(f_name, ())))
+            batch.setdefault(f_name, []).append((wi, p, q, pl[p]))
+
+    def _tas_answer(self, batch: dict, out: dict, snaps: dict) -> None:
+        """One launch a flavor; each answer kept on its head's row.  A
+        question the kernel cannot answer exactly is asked of the host
+        walk (counted in ``tas_host_queries``)."""
+        from . import tas_kernel as tk
+        stats = self.stats
+        for f_name, items in batch.items():
+            snap = snaps[f_name]
+            asked = [q for _, _, q, _ in items if q is not None]
+            answers = iter(tk.answer_queries(
+                snap.layout, snap.free, asked, snap._device_profile(False),
+                snap._device_profile(True), stats))
+            for wi, pp, q, (_, req, count, per_pod) in items:
+                stats["tas_queries"] += 1
+                row = out[wi]
+                ans = None if q is None else next(answers)
+                if q is None:
+                    placed, why = None, snap._no_fit(
+                        count, 0, snap._level_index(req.required))
+                elif ans is None:
+                    placed, why = snap.find_topology_assignment(
+                        count, per_pod, req, assumed=snap.assumed(q.charges),
+                        simulate_empty=q.empty)
+                else:
+                    placed, why = snap.answered(q, ans)
+                if placed is None:
+                    stats["tas_topology_nofit"] += 1
+                    row[0] = False
+                    row[2] = (pp, why)
+                    continue
+                row[1][pp] = placed
+                lay = snap.layout
+                row[3].setdefault(f_name, []).extend(
+                    (lay.leaf_index[tuple(d.values)], d.count * q.per_pod)
+                    for d in placed.domains)
+                if (q.kind == tk.QUERY_PREFERRED and ans is not None
+                        and ans.level != q.level):
+                    stats["tas_preferred_climbs"] += 1
+
+    def tas_stage(self, cls: ClassifiedCycle,
+                  preempt_heads: bool = True) -> None:
+        """``FlavorAssigner._apply_tas`` for every vector head of the
+        cycle at once (reference flavorassigner.go:438-465): a Fit
+        head's PodSets are placed against the nodes as the cycle found
+        them, and one that no domain holds turns the head to Preempt
+        with no flavor resource needing preemption; a Preempt head's,
+        and such a head's, are placed against the empty tree, and one
+        that fails there is NoFit.  The placements of Fit heads are
+        kept on ``cls.tas_place``; ``cls.tas_overlap`` says whether two
+        of them share a node that cannot hold both, so that the admit
+        loop has to decide them in order.  ``preempt_heads=False``
+        leaves the Preempt heads to the host walk."""
+        st = cls.packed.structure
+        n = cls.n
+        W = len(cls.fit0)
+        cls.tas_place, cls.tas_reason = {}, {}
+        cls.tas_demoted = np.zeros(W, dtype=bool)
+        cls.tas_nofit = np.zeros(W, dtype=bool)
+        cls.tas_overlap = False
+        cq_of = np.maximum(cls.packed.wl_cq[:n], 0)
+        on = ((cls.fit0[:n] | (cls.preempt0[:n] & preempt_heads))
+              & ~cls.scalar_mask[:n] & st.cq_tas[cq_of])
+        plans = {}
+        for wi in np.flatnonzero(on).tolist():
+            pl = self._tas_requests(cls, wi)
+            if any(x is not None for x in pl):
+                plans[wi] = pl
+        if not plans:
+            return
+        self.stats["tas_heads"] += len(plans)
+        first = self._tas_run(cls, plans, {wi: not cls.fit0[wi]
+                                           for wi in plans})
+        retry = {wi: plans[wi] for wi in plans
+                 if cls.fit0[wi] and not first[wi][0]}
+        again = (self._tas_run(cls, retry, {wi: True for wi in retry})
+                 if retry else {})
+        need: dict[str, list] = {}
+        for wi, (ok, places, why, charged) in first.items():
+            if ok:
+                if cls.fit0[wi]:
+                    cls.tas_place[wi] = places
+                    for f_name, items in charged.items():
+                        need.setdefault(f_name, []).extend(items)
+                continue
+            reasons = [why]
+            if cls.fit0[wi]:
+                # every resource fit: its flavors' modes stay Fit
+                cls.preempt_res_fit[wi] = True
+            if cls.fit0[wi] and again[wi][0]:
+                cls.tas_demoted[wi] = True
+                cls.fit0[wi] = False
+                cls.preempt0[wi] = True
+                cls.preempt_borrows0[wi] = cls.borrows0[wi]
+            else:
+                if cls.fit0[wi]:
+                    reasons.append(again[wi][2])
+                cls.tas_nofit[wi] = True
+                cls.fit0[wi] = False
+                cls.preempt0[wi] = False
+            cls.tas_reason[wi] = reasons
+        for f_name, items in need.items():
+            free = cls.snapshot.tas_flavors[f_name].free
+            total = np.zeros_like(free)
+            idx = np.fromiter((leaf for leaf, _ in items), dtype=np.int64,
+                              count=len(items))
+            np.add.at(total, idx, np.stack([d for _, d in items]))
+            if (total > np.maximum(free, 0)).any():
+                cls.tas_overlap = True
+
+    def _with_tas_reasons(self, cls: ClassifiedCycle, wi: int,
+                          assignment: Assignment) -> Assignment:
+        for p, msg in (cls.tas_reason or {}).get(wi, ()):
+            assignment.pod_sets[p].reasons.append(msg)
+        return assignment
+
+    def tas_nofit_details(self, cls: ClassifiedCycle, wi: int
+                          ) -> tuple[Assignment, str]:
+        """Assignment + message of a head the topology stage made NoFit,
+        as the host walk records them: the walk's flavors and resume
+        state, the quota reasons of a Preempt head, the topology's."""
+        if cls.preempt_res_fit[wi].all():
+            assignment = self._build_assignment(
+                cls, wi, Mode.FIT, bool(cls.borrows0[wi]))
+        else:
+            assignment, _ = self.reserve_details(cls, wi)
+        self._with_tas_reasons(cls, wi, assignment)
+        assignment.set_representative_mode(Mode.NO_FIT)
+        return assignment, assignment.message()
 
     # -- scalar-head decisions -----------------------------------------
 
@@ -1119,9 +1387,14 @@ class CycleSolver:
     def build_fit_assignment(self, cls: ClassifiedCycle,
                              wi) -> Assignment:
         """Host Assignment for a device-classified Fit head, including the
-        fungibility resume state the host walk would record."""
-        return self._build_assignment(cls, wi, Mode.FIT,
-                                      bool(cls.borrows0[wi]))
+        fungibility resume state the host walk would record and the
+        topology stage's placements."""
+        assignment = self._build_assignment(cls, wi, Mode.FIT,
+                                            bool(cls.borrows0[wi]))
+        for ps, ta in zip(assignment.pod_sets,
+                          (cls.tas_place or {}).get(int(wi), ())):
+            ps.topology_assignment = ta
+        return assignment
 
     def _build_assignment(self, cls: ClassifiedCycle, wi: int,
                           mode: Mode, borrow: bool,
@@ -1138,6 +1411,12 @@ class CycleSolver:
         shortfall resources PREEMPT — flavorassigner.go:692), as the
         preemptor's target search expects (preemption.go:466)."""
         borrow = bool(cls.preempt_borrows0[wi])
+        if cls.tas_demoted is not None and cls.tas_demoted[wi]:
+            # quota fits and no domain holds it: every resource Fit, the
+            # head Preempt (flavorassigner.go:438-465)
+            assignment = self._build_assignment(cls, wi, Mode.FIT, borrow)
+            assignment.set_representative_mode(Mode.PREEMPT)
+            return self._with_tas_reasons(cls, wi, assignment)
         st = cls.packed.structure
         res_modes = [{res: (Mode.FIT if fit[ri] else Mode.PREEMPT)
                       for res, ri in st.r_index.items()}
@@ -1154,6 +1433,8 @@ class CycleSolver:
         messages)."""
         h = cls.heads[wi]
         assignment = self.build_preempt_assignment(cls, wi)
+        if cls.tas_demoted is not None and cls.tas_demoted[wi]:
+            return assignment, assignment.message()
         cq = cls.snapshot.cq(h.cluster_queue)
         acc: dict = {}      # what the earlier PodSets chose, as the walk
         for ps in assignment.pod_sets:
@@ -1181,6 +1462,8 @@ class CycleSolver:
         if cls is None:
             self.stats["host_cycles"] += 1
             return None
+        if self.tas_active(cls):
+            self.tas_stage(cls)
         if cls.preempt0[:cls.n].any() or cls.scalar_mask[:cls.n].any():
             self.stats["host_cycles"] += 1
             return None

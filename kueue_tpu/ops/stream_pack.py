@@ -396,7 +396,7 @@ def _row_patch_job(state, st, queues, cache, scheduler, ci, key):
     if cq_live is None:
         return _ESCALATE
     covers_pods = cq_name in st.cq_covers_pods
-    cq_ok = st.cq_vector_ok
+    cq_ok = st.cq_window_ok
     cq_vec = bool(cq_ok[ci]) if cq_ok is not None else False
     if cq_vec and cq_live.spec.namespace_selector:
         cq_vec = False

@@ -483,6 +483,12 @@ class FlavorAssigner:
     def _find_tas(self, assignment: Assignment,
                   requests: list[PodSetResources],
                   simulate_empty: bool, record: bool = True) -> bool:
+        """Each PodSet's placement in order (reference
+        FindTopologyAssignmentsForWorkload): a later PodSet's on the
+        same flavor is charged with the earlier ones'; ``simulate_empty``
+        places against the nodes' allocatable, as if no workload placed
+        by topology held any; ``record`` keeps the placements on the
+        assignment."""
         implied = self._is_tas_only()
         assumed: dict[str, dict[tuple, dict[str, int]]] = {}
         for psr, ps_result in zip(requests, assignment.pod_sets):
@@ -505,19 +511,19 @@ class FlavorAssigner:
             per_pod = ({r: v // max(1, psr.count)
                         for r, v in psr.requests.items()})
             tas_assignment, reason = snap.find_topology_assignment(
-                psr.count, per_pod, request,
-                assumed=None if simulate_empty else assumed.get(f_name))
+                psr.count, per_pod, request, assumed=assumed.get(f_name),
+                simulate_empty=simulate_empty)
             if tas_assignment is None:
                 ps_result.reasons.append(reason)
                 return False
             if record:
                 ps_result.topology_assignment = tas_assignment
-                per_flavor = assumed.setdefault(f_name, {})
-                for dom in tas_assignment.domains:
-                    dom_id = tuple(dom.values)
-                    slot = per_flavor.setdefault(dom_id, {})
-                    for r, v in per_pod.items():
-                        slot[r] = slot.get(r, 0) + v * dom.count
+            per_flavor = assumed.setdefault(f_name, {})
+            for dom in tas_assignment.domains:
+                dom_id = tuple(dom.values)
+                slot = per_flavor.setdefault(dom_id, {})
+                for r, v in per_pod.items():
+                    slot[r] = slot.get(r, 0) + v * dom.count
         return True
 
 

@@ -183,16 +183,16 @@ class Scheduler:
             with _span("cycle.admit"):
                 self._admit_device_cycle(device_final, snapshot, stats)
                 self._requeue_unassumed(entries, stats)
-            self._rewake_if_gate_opened()
-            stats.duration_s = self.clock() - start
-            return stats
-        with _span("cycle.order"):
-            iterator = self._make_iterator(entries, snapshot)
-
-        with _span("cycle.admit"):
-            with _span("cycle.admit.apply"):
-                self._admit_host_cycle(iterator, snapshot, stats)
-            self._requeue_unassumed(entries, stats)
+        else:
+            with _span("cycle.order"):
+                iterator = self._make_iterator(entries, snapshot)
+            with _span("cycle.admit"):
+                with _span("cycle.admit.apply"):
+                    self._admit_host_cycle(iterator, snapshot, stats)
+                self._requeue_unassumed(entries, stats)
+        if self.solver is not None and snapshot.tas_flavors:
+            self.solver.stats["tas_host_queries"] += sum(
+                s.host_queries for s in snapshot.tas_flavors.values())
         self._rewake_if_gate_opened()
         stats.duration_s = self.clock() - start
         return stats
@@ -230,6 +230,8 @@ class Scheduler:
                               e.preemption_targets):
                 self._set_skipped(e, "Workload no longer fits after "
                                      "processing another workload")
+                continue
+            if not self._tas_holds(e, snapshot):
                 continue
             for t in e.preemption_targets:
                 preempted_workloads[t.info.key] = t.info
@@ -404,6 +406,13 @@ class Scheduler:
             # otherwise device classification still replaces the
             # per-head flavor walk and the host tournament decides
             n = cls.n
+            tas = solver.tas_active(cls)
+            if tas:
+                # the Fit heads' placements (a Preempt head's flavor is
+                # the host walk's to choose here, and so is its
+                # placement)
+                with _span("cycle.nominate.tas"):
+                    solver.tas_stage(cls, preempt_heads=False)
             if not cls.fit0[:n].any():
                 # nothing can admit: fs_admit_scan's can_admit requires
                 # a fit slot, and the dispatch gate below already
@@ -416,7 +425,7 @@ class Scheduler:
                 # when that fingerprint matches the last no-op cycle,
                 # reuse its per-head walk results instead of re-running
                 # C _assign_entry walks against an unchanged snapshot
-                cacheable = (not cls.scalar_mask[:n].any()
+                cacheable = (not tas and not cls.scalar_mask[:n].any()
                              and not cls.preempt0[:n].any())
                 fp = None
                 if cacheable:
@@ -451,7 +460,8 @@ class Scheduler:
             fs_handle = None
             if (not self._cycle_blocked
                     and not cls.scalar_mask[:n].any()
-                    and not cls.preempt0[:n].any()):
+                    and not cls.preempt0[:n].any()
+                    and not cls.tas_overlap):
                 with _span("cycle.nominate.scan_dispatch"):
                     fs_handle = solver.dispatch_fs(cls)
             if fs_handle is None:
@@ -507,6 +517,13 @@ class Scheduler:
             # (flavorassigner.go:692 RECLAIM beats PREEMPT)
             self._pick_by_oracle(
                 cls, pre[cls.oracle_groups[pre].any(axis=(1, 2))], snapshot)
+            if solver.tas_active(cls):
+                # the heads' placements, after their flavors are final:
+                # a Fit head no domain holds turns Preempt, a Preempt
+                # head the empty tree does not hold NoFit
+                with _span("cycle.nominate.tas"):
+                    solver.tas_stage(cls)
+                pre = np.nonzero(cls.preempt0[:n])[0]
             # in the walk's span, where the loop stood before the oracle
             # came between: the span's total by name is read, and
             # ``cycle.nominate.self`` is what no child covers
@@ -528,6 +545,21 @@ class Scheduler:
                     assignments_by_wi[wi] = assignment
                 else:
                     reserve[wi] = True
+
+        if full_ok and (cls.tas_overlap or any(
+                ps.topology_assignment is not None
+                for wi in walked for ps in deferred[wi].assignment.pod_sets)):
+            # two Fit heads' placements share a node that cannot hold
+            # both (or a host-walked head holds one the stage did not
+            # weigh): which is admitted turns on the order, so the host
+            # admit loop decides the cycle, on the assignments above
+            solver.stats["classify_cycles"] += 1
+            solver.stats["tas_host_admit_cycles"] += 1
+            with _span("cycle.nominate.walk"):
+                self._assign_device_entries(
+                    deferred, cls, reserve, assignments_by_wi,
+                    targets_by_wi, walked)
+            return None
 
         if full_ok:
             with _span("cycle.nominate.scan_dispatch"):
@@ -587,10 +619,54 @@ class Scheduler:
             if not cls.scalar_mask[wi] and cls.fit0[wi]:
                 e.assignment = solver.build_fit_assignment(cls, wi)
                 e.info.last_assignment = e.assignment.last_state
+            elif cls.tas_nofit is not None and cls.tas_nofit[wi]:
+                e.assignment, e.inadmissible_msg = \
+                    solver.tas_nofit_details(cls, wi)
+                e.info.last_assignment = e.assignment.last_state
             else:
                 # preempt/nofit/scalar heads need the host walk (targets,
                 # exact reasons, resume state)
                 self._assign_entry(e, snapshot)
+
+    def _assign_device_entries(self, deferred: list[Entry], cls, reserve,
+                               assignments_by_wi: dict,
+                               targets_by_wi: dict, walked: set) -> None:
+        """Every head's assignment, message and resume state from the
+        device-decided nominate: Fit heads' from their walks (with their
+        placements), preempting heads' with their targets, reserving
+        heads' and the topology stage's NoFit heads' reasons rebuilt;
+        a head the walks found NoFit is walked on the host for its exact
+        reasons (no placement is asked for it)."""
+        solver = self.solver
+        tas_nofit = cls.tas_nofit
+        for wi in range(cls.n):
+            e = deferred[wi]
+            if wi in walked:
+                # scalar head: the host walk already produced the
+                # assignment, message, resume state, and targets
+                continue
+            if cls.fit0[wi]:
+                e.assignment = solver.build_fit_assignment(cls, wi)
+                e.info.last_assignment = e.assignment.last_state
+                e.inadmissible_msg = ""
+            elif wi in assignments_by_wi:
+                e.assignment = assignments_by_wi[wi]
+                e.inadmissible_msg = e.assignment.message()
+                e.info.last_assignment = e.assignment.last_state
+                e.preemption_targets = targets_by_wi[wi]
+            elif reserve[wi]:
+                e.assignment, e.inadmissible_msg = solver.reserve_details(
+                    cls, wi)
+                e.info.last_assignment = e.assignment.last_state
+            elif tas_nofit is not None and tas_nofit[wi]:
+                e.assignment, e.inadmissible_msg = \
+                    solver.tas_nofit_details(cls, wi)
+                e.info.last_assignment = e.assignment.last_state
+            else:
+                # NoFit: the host walk produces the exact reasons and
+                # resume state
+                e.inadmissible_msg = ""
+                self._assign_entry(e, snapshot=cls.snapshot)
 
     def _admit_device_cycle(self, device, snapshot: Snapshot,
                             stats: CycleStats) -> None:
@@ -607,30 +683,9 @@ class Scheduler:
         solver = self.solver
         n = cls.n
         with _span("cycle.admit.prepare"):
-            for wi in range(n):
-                e = deferred[wi]
-                if wi in walked:
-                    # scalar head: the host walk already produced the
-                    # assignment, message, resume state, and targets
-                    continue
-                if cls.fit0[wi]:
-                    e.assignment = solver.build_fit_assignment(cls, wi)
-                    e.info.last_assignment = e.assignment.last_state
-                    e.inadmissible_msg = ""
-                elif wi in assignments_by_wi:
-                    e.assignment = assignments_by_wi[wi]
-                    e.inadmissible_msg = e.assignment.message()
-                    e.info.last_assignment = e.assignment.last_state
-                    e.preemption_targets = targets_by_wi[wi]
-                elif handle.rmask[wi]:
-                    e.assignment, e.inadmissible_msg = solver.reserve_details(
-                        cls, wi)
-                    e.info.last_assignment = e.assignment.last_state
-                else:
-                    # NoFit: the host walk produces the exact reasons and
-                    # resume state
-                    e.inadmissible_msg = ""
-                    self._assign_entry(e, snapshot)
+            self._assign_device_entries(deferred, cls, handle.rmask,
+                                        assignments_by_wi, targets_by_wi,
+                                        walked)
             if handle.route == "accel":
                 # the round trip dwarfs per-head prep: speculatively build the
                 # admission objects for every fit head while the chip works
@@ -649,6 +704,8 @@ class Scheduler:
                 e = deferred[wi]
                 cq = snapshot.cq(e.info.cluster_queue)
                 if final.admitted[wi]:
+                    if not self._tas_holds(e, snapshot):
+                        continue
                     if self._cycle_blocked:
                         e.inadmissible_msg = (
                             "Waiting for all admitted workloads to be in the "
@@ -1108,6 +1165,42 @@ class Scheduler:
                 seen.add(w.key)
                 unique.append(w)
         return _fits_with_removal(cq, usage, unique)
+
+    def _tas_holds(self, e: Entry, snapshot: Snapshot) -> bool:
+        """The in-cycle half of upstream's ``ClusterQueueSnapshot.Fits``
+        for topology: an entry's placements were found on the cycle's
+        snapshot, and an earlier admission of the cycle may have filled
+        one of their nodes.  True, and the placements charged to the
+        snapshot's nodes, where every node still holds them; else the
+        entry is skipped ("no longer fits") and counted."""
+        if not snapshot.tas_flavors or e.assignment is None:
+            return True
+        per_flavor: dict = {}
+        for psr, ps in zip(e.info.total_requests, e.assignment.pod_sets):
+            ta = ps.topology_assignment
+            if ta is None:
+                continue
+            flavor = next((f.name for f in ps.flavors.values()
+                           if f.name in snapshot.tas_flavors), None)
+            per_pod = {r: v // max(1, psr.count)
+                       for r, v in psr.requests.items()}
+            per_flavor.setdefault(flavor, []).append((ta, per_pod))
+        if not per_flavor:
+            return True
+        demands = []
+        for flavor, placements in per_flavor.items():
+            snap = snapshot.tas_flavors.get(flavor)
+            demand = None if snap is None else snap.demand(placements)
+            if snap is None or not snap.holds(demand):
+                self._set_skipped(e, "Workload no longer fits after "
+                                     "processing another workload")
+                if self.solver is not None:
+                    self.solver.stats["tas_refit_skips"] += 1
+                return False
+            demands.append((snap, demand))
+        for snap, demand in demands:
+            snap.charge(demand)
+        return True
 
     def _resources_to_reserve(self, e: Entry, cq: CQState) -> FlavorResourceQuantities:
         """reference scheduler.go:383-408 resourcesToReserve."""

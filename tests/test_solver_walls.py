@@ -294,14 +294,16 @@ def test_two_resource_groups_with_declared_flavors_walk_a_group():
 
 
 def test_two_resource_groups_with_a_topology_flavor_stay_scalar():
-    """What is still refused: a flavor that binds a topology in one of
-    the two groups sends every head of the queue to the host walk, and
-    ``cq_shape`` counts them."""
+    """A flavor that binds a topology in one of the two groups no longer
+    sends the queue's heads to the host walk: with the
+    TopologyAwareScheduling gate off (the default) the host walk reads
+    it as a plain flavor, and so does the vector walk; ``cq_shape``
+    counts nobody (tests/test_tas_batched.py holds the gate on)."""
     _, stats = assert_parity(lambda d: _two_groups_declared(
-        d, ResourceFlavor(name="gpu-x", topology_name="rack")))
-    assert stats["scalar_reasons"].get("cq_shape", 0) == stats[
-        "scalar_heads"] == stats["cq_shape_heads"] > 0, stats
-    assert stats["constrained_heads"] == stats["walk_heads"] == 0, stats
+        d, ResourceFlavor(name="gpu-x", topology_name="rack")),
+        expect_scalar=False)
+    assert stats["scalar_heads"] == stats["cq_shape_heads"] == 0, stats
+    assert stats["walk_heads"] > 0 and stats["tas_heads"] == 0, stats
 
 
 # ---------------------------------------------------------------------------

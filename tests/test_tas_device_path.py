@@ -264,4 +264,8 @@ def test_tas_thousand_heads_full_cycle(tas_kernel_gate):
     assert assignments(dd) == assignments(dh)
     stats = dd.scheduler.solver.stats
     assert stats["full_cycles"] == 1, stats       # FULL-mode cycle
-    assert stats["scalar_heads"] >= 1000, stats   # TAS heads attached
+    # every TAS head on the vector path, placed by the batched stage
+    assert stats["tas_heads"] >= 1000, stats
+    assert stats["scalar_heads"] == 0, stats
+    assert stats["tas_host_queries"] == 0, stats
+    assert stats["tas_launches"] == 1, stats

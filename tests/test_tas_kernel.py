@@ -1,11 +1,16 @@
 """Device TAS kernel parity vs the host TASFlavorSnapshot (reference
-tas_flavor_snapshot.go semantics), plus the topology ungater."""
+tas_flavor_snapshot.go semantics), plus the topology ungater.  The
+kernel is the batched one (``place_queries`` through
+``answer_queries``), asked one question at a time; the host is the
+scalar tree walk (the ``TASDeviceKernel`` gate off)."""
 
 import random
 
+import jax
 import numpy as np
 import pytest
 
+from kueue_tpu import features
 from kueue_tpu.api.types import (
     PodSetTopologyRequest,
     TopologyAssignment,
@@ -18,12 +23,7 @@ from kueue_tpu.controller.tas_ungater import (
     assign_pods_to_domains,
     pod_rank,
 )
-from kueue_tpu.ops.tas_kernel import (
-    best_fit_descend,
-    fill_counts,
-    pack_tas,
-    split_across_roots,
-)
+from kueue_tpu.ops.tas_kernel import answer_queries, level_states
 
 LEVELS = ["block", "rack", "host"]
 
@@ -42,80 +42,74 @@ def random_snapshot(rng, n_blocks=3, racks_per_block=3, hosts_per_rack=4):
     return TASFlavorSnapshot.build("tas-flavor", LEVELS, nodes, {})
 
 
-def kernel_args(snap):
-    packed = pack_tas(snap)
-    return packed, tuple(packed.level_sizes), tuple(
-        np.asarray(p) for p in packed.parents)
+def host_walk(snap, count, per_pod, request):
+    features.set_feature_gates({"TASDeviceKernel": False})
+    try:
+        return snap.find_topology_assignment(count, per_pod, request)
+    finally:
+        features.set_feature_gates({"TASDeviceKernel": True})
+
+
+def kernel(snap, count, per_pod, request):
+    """One question of the batched kernel: (ok, {leaf id: pods})."""
+    q = snap.question(count, per_pod, request)
+    ans, = answer_queries(snap.layout, snap.free, [q])
+    return ans.ok, {snap.layout.leaf_ids[i]: c for i, c in ans.placement}
 
 
 @pytest.mark.parametrize("seed", [11, 22, 33])
 def test_fill_counts_matches_host(seed):
+    """Phase 1 (``level_states``) against the walk's fillInCounts."""
     rng = random.Random(seed)
     snap = random_snapshot(rng)
-    packed, sizes, parents = kernel_args(snap)
+    lay = snap.layout
     per_pod_map = {"cpu": 2000, "tpu": 1}
     per_pod = np.array([per_pod_map.get(r, 0)
-                        for r in packed.resource_names], dtype=np.int32)
-    states = fill_counts(packed.leaf_free, per_pod, parents,
-                         level_sizes=sizes)
+                        for r in lay.resource_names], dtype=np.int32)
+    starts = tuple(np.asarray(c, dtype=np.int32) for c in lay.child_start)
+    states = jax.jit(level_states)(
+        np.clip(snap.free, 0, None).astype(np.int32), per_pod, starts)
     snap._fill_in_counts(per_pod_map)
     for lvl in range(len(LEVELS)):
         host = {d.id: d.state for d in snap.domains_per_level[lvl]}
         dev = np.asarray(states[lvl])
-        for i, did in enumerate(packed.domain_ids[lvl]):
+        for i, did in enumerate(lay.domain_ids[lvl]):
             assert dev[i] == host[did], (lvl, did)
 
 
 @pytest.mark.parametrize("seed", [7, 8, 9, 10])
 @pytest.mark.parametrize("level_name", ["block", "rack", "host"])
 def test_best_fit_descend_matches_host(seed, level_name):
+    """A required level: the best domain there and the descent."""
     rng = random.Random(seed)
     snap = random_snapshot(rng)
-    packed, sizes, parents = kernel_args(snap)
     per_pod_map = {"cpu": 2000}
-    per_pod = np.array([per_pod_map.get(r, 0)
-                        for r in packed.resource_names], dtype=np.int32)
     count = rng.choice([1, 2, 5, 9])
-    level = LEVELS.index(level_name)
-
-    ok, leaf_counts = best_fit_descend(
-        packed.leaf_free, per_pod, parents, count,
-        level_sizes=sizes, level=level)
-    host_asg, _ = snap.find_topology_assignment(
-        count, per_pod_map,
-        PodSetTopologyRequest(required=level_name))
-
+    request = PodSetTopologyRequest(required=level_name)
+    ok, dev_counts = kernel(snap, count, per_pod_map, request)
+    host_asg, _ = host_walk(snap, count, per_pod_map, request)
     if host_asg is None:
-        assert not bool(ok)
+        assert not ok
         return
-    assert bool(ok)
-    host_counts = {tuple(d.values): d.count for d in host_asg.domains}
-    dev_counts = {packed.leaf_ids[i]: int(c)
-                  for i, c in enumerate(np.asarray(leaf_counts)) if c}
-    assert dev_counts == host_counts
+    assert ok
+    assert dev_counts == {tuple(d.values): d.count for d in host_asg.domains}
 
 
 @pytest.mark.parametrize("seed", [41, 42])
 def test_split_across_roots_matches_host(seed):
+    """Unconstrained: the split over the roots and the descent."""
     rng = random.Random(seed)
     snap = random_snapshot(rng)
-    packed, sizes, parents = kernel_args(snap)
     per_pod_map = {"cpu": 4000}
-    per_pod = np.array([per_pod_map.get(r, 0)
-                        for r in packed.resource_names], dtype=np.int32)
     count = 11
-    ok, leaf_counts = split_across_roots(
-        packed.leaf_free, per_pod, parents, count, level_sizes=sizes)
-    host_asg, _ = snap.find_topology_assignment(
-        count, per_pod_map, PodSetTopologyRequest(unconstrained=True))
+    request = PodSetTopologyRequest(unconstrained=True)
+    ok, dev_counts = kernel(snap, count, per_pod_map, request)
+    host_asg, _ = host_walk(snap, count, per_pod_map, request)
     if host_asg is None:
-        assert not bool(ok)
+        assert not ok
         return
-    assert bool(ok)
-    host_counts = {tuple(d.values): d.count for d in host_asg.domains}
-    dev_counts = {packed.leaf_ids[i]: int(c)
-                  for i, c in enumerate(np.asarray(leaf_counts)) if c}
-    assert dev_counts == host_counts
+    assert ok
+    assert dev_counts == {tuple(d.values): d.count for d in host_asg.domains}
 
 
 # ---------------------------------------------------------------------------
