@@ -14,7 +14,11 @@ cycle into unscaled int64 node tensors, maintains the usage tensor
 incrementally as the admit loop mutates the snapshot, and computes the
 DRS of every remaining entry of the picked tree at every cohort level in
 ONE vectorized pass per tournament round — replacing the per-entry
-simulate/revert walk that made the tournament O(heads²·tree) in Python."""
+simulate/revert walk that made the tournament O(heads²·tree) in Python.
+``note_add`` lists the nodes whose usage it changed (``changed``), and
+``drs_path`` recomputes one entry's DRS from a level up in plain integer
+arithmetic, so a round after a tree's first recomputes only the entries
+whose path crosses a changed node."""
 
 from __future__ import annotations
 
@@ -65,8 +69,10 @@ class TournamentDRS:
     the picked tree at every level of its CQ→root path in one vectorized
     pass, bit-matching cache.state.dominant_resource_share.  ``note_add``
     mirrors the admit loop's ``simulate_usage_addition`` chain-adds into
-    the usage tensor so no per-round repack is needed.  ``root_of`` maps
-    a node to its tree's root, which groups the entries a round reads."""
+    the usage tensor so no per-round repack is needed, and appends the
+    nodes it changed to ``changed`` for the caller to drain.  ``root_of``
+    maps a node to its tree's root, which groups the entries a round
+    reads."""
 
     _NO_LIMIT = np.int64(2) ** 61
 
@@ -77,6 +83,8 @@ class TournamentDRS:
         self.names: list[str] = list(cq_names) + [c.name for c in cohorts]
         self.cq_index = {n: i for i, n in enumerate(cq_names)}
         self.stale = False
+        self.changed: list[int] = []
+        self._rows: dict[int, tuple] = {}
         N = len(nodes)
 
         frs = set()
@@ -96,6 +104,8 @@ class TournamentDRS:
             fr_to_r[fi] = r_index[fr.resource]
         self.onehot = (fr_to_r[:, None]
                        == np.arange(R)[None, :]).astype(np.int64)  # [F,R]
+        self._fr_to_r = fr_to_r.tolist()
+        self._R = R
 
         parent = np.full(N, -1, dtype=np.int64)
         subtree = np.zeros((N, F), dtype=np.int64)
@@ -181,12 +191,11 @@ class TournamentDRS:
             self.stale = True  # unseen fr: callers fall back per-entry
             return
         cur = ci
-        while cur >= 0:
+        while cur >= 0 and carry.any():
+            self.changed.append(cur)
             local_avail = np.maximum(0, self.guaranteed[cur] - self.u[cur])
             self.u[cur] += carry
             carry = np.maximum(0, carry - local_avail)
-            if not carry.any():
-                break
             cur = int(self.parent[cur])
 
     def drs_for(self, cq_is: np.ndarray, u_es: np.ndarray
@@ -232,6 +241,51 @@ class TournamentDRS:
                              np.maximum(0, carry - local_avail), carry)
             cur = np.where(alive, par, -1)
         return paths, drs
+
+    def _static_row(self, node: int) -> tuple:
+        """A node's per-cycle constants as Python values, built once."""
+        row = self._rows.get(node)
+        if row is None:
+            row = (int(self.parent[node]), self.subtree[node].tolist(),
+                   self.sq_mask[node].tolist(),
+                   self.guaranteed[node].tolist(),
+                   self.lendable_r[node].tolist(), int(self.weights[node]))
+            self._rows[node] = row
+        return row
+
+    def drs_path(self, ci: int, u_e: list[int], start: int
+                 ) -> list[tuple[int, int]]:
+        """One entry's (parent node, DRS) at every path level from
+        ``start`` up, with the entry's usage ``u_e`` ([F] ints) added
+        along its chain: what ``drs_for`` gives that entry, in plain
+        integers, bit for bit, without a vectorized pass's fixed cost."""
+        out = []
+        carry = list(u_e)
+        cur, level = ci, 0
+        while cur >= 0:
+            par, sub, mask, guar, lend, w = self._static_row(cur)
+            u = self.u[cur].tolist()
+            if level >= start and par >= 0:
+                borrowing = [0] * self._R
+                for f, r in enumerate(self._fr_to_r):
+                    b = u[f] + carry[f] - sub[f]
+                    if mask[f] and b > 0:
+                        borrowing[r] += b
+                if w == 0:
+                    d = MAX_DRS
+                elif any(b > 0 for b in borrowing):
+                    raw = -1
+                    for b, le in zip(borrowing, lend):
+                        if b > 0 and le > 0:
+                            raw = max(raw, b * 1000 // le)
+                    d = raw * 1000 // w
+                else:
+                    d = 0
+                out.append((par, d))
+            carry = [max(0, c - max(0, g - x))
+                     for c, g, x in zip(carry, guar, u)]
+            cur, level = par, level + 1
+        return out
 
 
 def compute_all_drs(snapshot) -> dict[str, int]:

@@ -185,7 +185,13 @@ class CycleSolver:
                                       # (Scheduler.fs_stats)
             "fs_drs_entries": 0,      # heads whose DRS a host tournament
                                       # round computed, summed over rounds
-                                      # (the picked tree's remaining)
+                                      # (a tree's first round: its
+                                      # remaining; a later one: those
+                                      # crossing a node the last pick
+                                      # changed)
+            "fs_picks_reused": 0,     # cohort picks a host tournament
+                                      # round read from the tree's kept
+                                      # answers instead of recomputing
             "fs_noop_skips": 0,       # FS cycles with no fit head: the
                                       # tournament dispatch was skipped
             "fs_noop_reuses": 0,      # no-op FS cycles whose per-head

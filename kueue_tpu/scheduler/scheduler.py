@@ -84,6 +84,71 @@ class CycleStats:
     finish_s: float = 0.0     # workload-finish application (burst mode)
 
 
+class _KeptTree:
+    """One cohort tree's host-tournament answers, kept across its rounds
+    of a cycle.  An entry's DRS at a level reads the usage of its path's
+    nodes up to that level (the lendable is quota-only, weights static),
+    and a cohort's pick reads only its subtree's remaining entries and
+    their DRS: so after a pick only the entries crossing a node whose
+    usage changed (``dirty``, from ``TournamentDRS.changed``) need new
+    values, and only the cohorts above such a node or on the winner's
+    chain a new pick."""
+
+    __slots__ = ("drs_values", "picks", "through", "dirty", "reused")
+
+    def __init__(self, tree: dict[str, Entry], tracker, vecs):
+        """The tree's first round: one batched ``drs_for`` pass over its
+        remaining entries."""
+        import numpy as np
+        # (parent cohort name, workload key) -> DRS, as computeDRS keys it
+        self.drs_values: dict[tuple[str, str], int] = {}
+        self.picks: dict[str, Optional[Entry]] = {}   # cohort name -> pick
+        # non-root node -> (queue, path level) of the entries crossing it
+        self.through: dict[int, list[tuple[str, int]]] = {}
+        self.dirty: set[int] = set()
+        self.reused = 0        # picks answered from ``picks`` this round
+        keys = list(tree)
+        cq_is = np.array([tracker.cq_index[k] for k in keys], dtype=np.int64)
+        paths, drs = tracker.drs_for(cq_is, np.stack([vecs[k] for k in keys]))
+        for k, path, row in zip(keys, paths.tolist(), drs.tolist()):
+            wl_key = tree[k].info.key
+            for level, node in enumerate(path):
+                par = int(tracker.parent[node]) if node >= 0 else -1
+                if par < 0:
+                    break
+                self.drs_values[(tracker.names[par], wl_key)] = row[level]
+                self.through.setdefault(node, []).append((k, level))
+
+    def forget_chain(self, cq) -> None:
+        """Drop the picks of the cohorts above ``cq``, whose entry just
+        left the tree."""
+        cohort = cq.parent
+        while cohort is not None:
+            self.picks.pop(cohort.name, None)
+            cohort = cohort.parent
+
+    def refresh(self, tree: dict[str, Entry], tracker, vecs) -> int:
+        """Recompute the DRS of the remaining entries whose path crosses a
+        dirty node, from that node's level up, and drop the picks above
+        the dirty nodes.  Returns the entries recomputed."""
+        start: dict[str, int] = {}
+        for node in self.dirty:
+            for cq_name, level in self.through.get(node, ()):
+                if cq_name in tree and level < start.get(cq_name, level + 1):
+                    start[cq_name] = level
+            par = int(tracker.parent[node])
+            while par >= 0:
+                self.picks.pop(tracker.names[par], None)
+                par = int(tracker.parent[par])
+        self.dirty.clear()
+        for cq_name, level in start.items():
+            wl_key = tree[cq_name].info.key
+            for par, d in tracker.drs_path(tracker.cq_index[cq_name],
+                                           vecs[cq_name].tolist(), level):
+                self.drs_values[(tracker.names[par], wl_key)] = d
+        return len(start)
+
+
 class Scheduler:
     """reference scheduler.go:64."""
 
@@ -1003,23 +1068,29 @@ class Scheduler:
                 < self.ordering.queue_order_timestamp(b.obj))
 
     def _fs_tournament(self, cohort, remaining: dict[str, Entry],
-                       drs_values) -> Optional[Entry]:
-        """runTournament (fair_sharing_iterator.go:121)."""
+                       drs_values, kept: Optional[_KeptTree] = None
+                       ) -> Optional[Entry]:
+        """runTournament (fair_sharing_iterator.go:121).  With ``kept``,
+        a cohort whose pick it holds is answered from it, and every pick
+        made is kept."""
+        if kept is not None and cohort.name in kept.picks:
+            kept.reused += 1
+            return kept.picks[cohort.name]
         candidates = []
         for child in cohort.child_cohorts:
-            cand = self._fs_tournament(child, remaining, drs_values)
+            cand = self._fs_tournament(child, remaining, drs_values, kept)
             if cand is not None:
                 candidates.append(cand)
         for cq in cohort.child_cqs:
             cand = remaining.get(cq.name)
             if cand is not None and cand.cq_snapshot is cq:
                 candidates.append(cand)
-        if not candidates:
-            return None
-        best = candidates[0]
+        best = candidates[0] if candidates else None
         for cur in candidates[1:]:
             if self._fs_less(cur, best, cohort.name, drs_values):
                 best = cur
+        if kept is not None:
+            kept.picks[cohort.name] = best
         return best
 
     def _fs_drs_values_ref(self, tree: dict[str, Entry]
@@ -1045,15 +1116,19 @@ class Scheduler:
         """Per-cohort tournament minimizing post-admission DRS
         (reference fair_sharing_iterator.go:121).
 
-        Per round the DRS values for the remaining entries of the picked
-        tree come from one batched TournamentDRS pass over packed int64
+        A tree's first round in a cycle takes the DRS of its remaining
+        entries from one batched TournamentDRS pass over packed int64
         tensors; the admit loop's usage mutations are mirrored in via
         ``_note_fs_usage`` so no per-round repack or per-entry
-        simulate/revert happens.  Falls back to the scalar computeDRS
-        when an entry's usage can't be packed (unseen flavor-resource).
-        Trees are independent — a usage addition moves only its own
-        queue's chain and the pick reads only its own tree's keys — so a
-        round reads no other tree's entries."""
+        simulate/revert happens.  The tree keeps those values and its
+        cohorts' picks (``_KeptTree``): a later round recomputes only the
+        entries whose path crosses a node the last pick changed, and the
+        picks of the cohorts above such a node or on the winner's chain.
+        Falls back to the scalar computeDRS every round when an entry's
+        usage can't be packed (unseen flavor-resource).  Trees are
+        independent — a usage addition moves only its own queue's chain
+        and the pick reads only its own tree's keys — so a round reads no
+        other tree's entries."""
         import numpy as np
         from ..ops.fairsharing_kernel import TournamentDRS
 
@@ -1091,6 +1166,7 @@ class Scheduler:
                     else id(e.cq_snapshot.parent.root()))
             trees.setdefault(root, {})[cq_name] = e
             tree_of[cq_name] = root
+        kept: dict[object, _KeptTree] = {}
         self._fs_tracker = tracker
         try:
             while remaining:
@@ -1099,49 +1175,54 @@ class Scheduler:
                 if cq.parent is None:
                     yield remaining.pop(cq_name)
                     continue
-                tree = trees[tree_of[cq_name]]
+                root = tree_of[cq_name]
+                tree = trees[root]
                 with _span("cycle.admit.tournament"):
-                    winner = self._fs_round(tree, cq, tracker, vecs)
-                if winner is None:
-                    del tree[cq_name]
-                    yield remaining.pop(cq_name)
-                    continue
-                del remaining[winner.info.cluster_queue]
-                del tree[winner.info.cluster_queue]
-                yield winner
+                    winner = self._fs_round(tree, cq, tracker, vecs, kept,
+                                            root)
+                if winner is not None:
+                    cq_name = winner.info.cluster_queue
+                del tree[cq_name]
+                state = kept.get(root)
+                if state is not None:
+                    state.forget_chain(remaining[cq_name].cq_snapshot)
+                yield remaining.pop(cq_name)
         finally:
             self._fs_tracker = None
 
-    def _fs_round(self, tree: dict[str, Entry], cq, tracker,
-                  vecs) -> Optional[Entry]:
-        """One round of the host tournament: the DRS of every remaining
-        entry of ``cq``'s cohort tree (the batched tracker's pass, or the
-        scalar oracle's), then the pick over that tree."""
-        import numpy as np
-        if self.solver is not None:
-            self.solver.stats["fs_drs_entries"] += len(tree)
-        if tracker is not None and not tracker.stale:
-            keys = list(tree)
-            cq_is = np.array([tracker.cq_index[k] for k in keys],
-                             dtype=np.int64)
-            u_es = np.stack([vecs[k] for k in keys])
-            paths, drs = tracker.drs_for(cq_is, u_es)
-            drs_values: dict[tuple[str, str], int] = {}
-            for j, k in enumerate(keys):
-                wl_key = tree[k].info.key
-                for level in range(paths.shape[1]):
-                    node = int(paths[j, level])
-                    if node < 0:
-                        break
-                    par = int(tracker.parent[node])
-                    if par < 0:
-                        break
-                    drs_values[(tracker.names[par], wl_key)] = int(
-                        drs[j, level])
-        else:
+    def _fs_round(self, tree: dict[str, Entry], cq, tracker, vecs,
+                  kept: dict[object, _KeptTree], root) -> Optional[Entry]:
+        """One round of the host tournament over ``cq``'s cohort tree:
+        its first round in the cycle computes the DRS of every remaining
+        entry (the batched tracker's pass) and keeps it, a later one
+        brings the kept values and picks up to date; with no usable
+        tracker, the scalar oracle's full recompute, every round."""
+        stats = self.solver.stats if self.solver is not None else None
+        if tracker is None or tracker.stale:
+            kept.clear()
+            if stats is not None:
+                stats["fs_drs_entries"] += len(tree)
             self.fs_stats["scalar_drs_rounds"] += 1
-            drs_values = self._fs_drs_values_ref(tree)
-        return self._fs_tournament(cq.parent.root(), tree, drs_values)
+            return self._fs_tournament(cq.parent.root(), tree,
+                                       self._fs_drs_values_ref(tree))
+        for node in tracker.changed:
+            state = kept.get(int(tracker.root_of[node]))
+            if state is not None:
+                state.dirty.add(node)
+        tracker.changed.clear()
+        state = kept.get(root)
+        if state is None:
+            state = kept[root] = _KeptTree(tree, tracker, vecs)
+            n_entries = len(tree)
+        else:
+            n_entries = state.refresh(tree, tracker, vecs)
+        state.reused = 0
+        winner = self._fs_tournament(cq.parent.root(), tree,
+                                     state.drs_values, state)
+        if stats is not None:
+            stats["fs_drs_entries"] += n_entries
+            stats["fs_picks_reused"] += state.reused
+        return winner
 
     def _note_fs_usage(self, cq_name: str, usage) -> None:
         """Mirror an admit-loop usage mutation into the tournament's

@@ -22,6 +22,7 @@ from kueue_tpu.api.types import (
     ResourceFlavor,
     ResourceGroup,
     ResourceQuota,
+    WithinClusterQueue,
     Workload,
 )
 from kueue_tpu.controller.driver import Driver
@@ -155,9 +156,11 @@ def test_batched_tournament_matches_scalar_over_departments(
 
 def watch_rounds(sched, rounds):
     """Wrap ``sched``'s fair-sharing iterator; for each tournament round
-    it runs, append (remaining heads in the first head's tree, remaining
-    heads of every tree, trees with a remaining head), read off the
-    entries themselves before the round."""
+    it runs, append a dict read off the entries themselves before the
+    round: ``tree`` (remaining heads in the first head's tree), ``all``
+    (remaining heads of every tree), ``trees`` (trees with a remaining
+    head), ``root`` (the picked tree's root cohort), ``left`` (the picked
+    tree's remaining entries) and ``winner`` (the entry it yielded)."""
     real = sched._fair_sharing_iterator
 
     def wrapped(entries, snapshot):
@@ -168,11 +171,16 @@ def watch_rounds(sched, rounds):
             pending = None
             first = next(iter(left.values()), None)
             if first is not None and first.cq_snapshot.parent is not None:
-                roots = [e.cq_snapshot.parent.root() for e in left.values()
-                         if e.cq_snapshot.parent is not None]
+                in_tree = [e for e in left.values()
+                           if e.cq_snapshot.parent is not None]
                 root = first.cq_snapshot.parent.root()
-                pending = (sum(r is root for r in roots), len(roots),
-                           len({id(r) for r in roots}))
+                picked = [e for e in in_tree
+                          if e.cq_snapshot.parent.root() is root]
+                pending = dict(
+                    tree=len(picked), all=len(in_tree),
+                    trees=len({id(e.cq_snapshot.parent.root())
+                               for e in in_tree}),
+                    root=root, left=picked)
             try:
                 e = next(inner)
             except StopIteration:
@@ -180,17 +188,52 @@ def watch_rounds(sched, rounds):
             if e.cq_snapshot is not None:
                 left.pop(e.info.cluster_queue, None)
                 if pending is not None:
-                    rounds.append(pending)
+                    rounds.append(dict(pending, winner=e))
             yield e
     sched._fair_sharing_iterator = wrapped
+
+
+def _cohorts_below_root(cq):
+    """The cohorts on ``cq``'s chain, its root left out."""
+    out, cohort = set(), cq.parent
+    while cohort is not None and cohort.parent is not None:
+        out.add(cohort.name)
+        cohort = cohort.parent
+    return out
+
+
+def kept_round_bounds(rounds):
+    """(least, most) ``fs_drs_entries`` the batched tournament can count
+    over ``rounds``: a tree's first round computes its remaining heads;
+    a later one only those whose path crosses a node the tree's last
+    winner changed, at most the heads sharing a cohort below the root
+    with that winner, and none where its usage stayed in its queue."""
+    least = most = 0
+    last_winner = {}
+    for r in rounds:
+        root = id(r["root"])
+        prev = last_winner.get(root)
+        if prev is None:
+            least += r["tree"]
+            most += r["tree"]
+        else:
+            shared = _cohorts_below_root(prev.cq_snapshot)
+            most += sum(bool(_cohorts_below_root(e.cq_snapshot) & shared)
+                        for e in r["left"])
+        last_winner[root] = r["winner"]
+    return least, most
 
 
 @pytest.mark.parametrize("batched", [True, False])
 @pytest.mark.parametrize("seed", [71, 72])
 def test_drs_entries_count_the_picked_tree(seed, batched):
-    """``fs_drs_entries`` grows, a cycle, by the remaining heads of each
-    round's picked tree, and by less than every remaining head wherever
-    a round had two trees or more to pick from."""
+    """``fs_drs_entries`` grows, a cycle, by less than every remaining
+    head wherever a round had two trees or more to pick from.  On the
+    scalar path it grows by the remaining heads of each round's picked
+    tree.  On the batched path it grows by a tree's remaining heads at
+    its first round of the cycle, plus, at a later round, the heads whose
+    path crosses a node the tree's last pick changed: fewer than the
+    scalar count wherever a tree has a second round."""
     d, clock, wls = build_fs_driver(seed, batched=batched, use_device=True,
                                     **DEPARTMENTS)
     for wl in wls:
@@ -198,19 +241,235 @@ def test_drs_entries_count_the_picked_tree(seed, batched):
     stats = d.scheduler.solver.stats
     rounds = []
     watch_rounds(d.scheduler, rounds)
-    several = 0
+    several = second_rounds = 0
     for _ in range(12):
         clock.t += 1.0
         rounds.clear()
         before = stats["fs_drs_entries"]
         d.schedule_once()
         counted = stats["fs_drs_entries"] - before
-        assert counted == sum(r[0] for r in rounds)
-        if any(r[2] >= 2 for r in rounds):
+        whole = sum(r["tree"] for r in rounds)
+        if batched:
+            least, most = kept_round_bounds(rounds)
+            assert least <= counted <= most
+            if len(rounds) > len({id(r["root"]) for r in rounds}):
+                second_rounds += 1
+                assert counted < whole
+        else:
+            assert counted == whole
+        if any(r["trees"] >= 2 for r in rounds):
             several += 1
-            assert counted < sum(r[1] for r in rounds)
+            assert counted < sum(r["all"] for r in rounds)
     assert several >= 1
     assert stats["fs_host_cycles"] >= 1
+    if batched:
+        assert second_rounds >= 1
+        assert stats["fs_picks_reused"] > 0
+    else:
+        assert stats["fs_picks_reused"] == 0
+
+
+def build_tree_driver(seed, *, batched, depth, roots=2, fanout=2,
+                      queues=2, beside=False, weights=(1000,),
+                      lending=None, memory=False, ties=False,
+                      nofit=False, reserve=False, n_wl=120):
+    """``roots`` root cohorts, each a tree of ``depth`` levels counting
+    its queues: every cohort above the lowest has ``fanout`` child
+    cohorts (and, with ``beside``, one queue of its own beside them), the
+    lowest ``queues`` queues.  Queues and cohorts below a root take
+    ``weights`` in turn; ``lending`` is every other queue's lending
+    limit on its 4,000 cpu (the rest is guaranteed quota, where an
+    admission's carry stops in its queue), ``memory`` a second resource; ``ties`` makes every workload alike in
+    size, priority and timestamp; ``nofit`` adds workloads no queue can
+    hold; ``reserve`` makes queues preempt only lower priorities within
+    themselves and never reclaim, so a preempt-capable head finds no
+    target and reserves."""
+    rng = random.Random(seed)
+    clock = FakeClock()
+    d = Driver(clock=clock, fair_sharing=True, use_device_solver=True)
+    d.scheduler.fs_batched = batched
+    d.apply_resource_flavor(ResourceFlavor(name="default"))
+    pre = (PreemptionPolicy(
+        within_cluster_queue=WithinClusterQueue.LOWER_PRIORITY)
+        if reserve else
+        PreemptionPolicy(reclaim_within_cohort=ReclaimWithinCohort.ANY))
+    weight = iter(weights * 1000)
+    lqs = []
+
+    def add_queue(name, cohort):
+        q = len(lqs)
+        resources = {"cpu": ResourceQuota(
+            nominal=4000, borrowing_limit=8000,
+            lending_limit=lending if q % 2 else None)}
+        if memory:
+            resources["memory"] = ResourceQuota(nominal=8000,
+                                                borrowing_limit=8000)
+        d.apply_cluster_queue(ClusterQueue(
+            name=name, cohort=cohort, preemption=pre,
+            fair_sharing=FairSharing(weight=next(weight)),
+            resource_groups=[ResourceGroup(
+                covered_resources=list(resources),
+                flavors=[FlavorQuotas(name="default",
+                                      resources=resources)])]))
+        d.apply_local_queue(LocalQueue(name=f"lq-{name}", cluster_queue=name))
+        lqs.append(f"lq-{name}")
+
+    def grow(name, parent, level):
+        d.apply_cohort(Cohort(
+            name=name, parent_name=parent,
+            fair_sharing=(FairSharing(weight=next(weight))
+                          if parent is not None else None)))
+        if level == depth - 1:
+            for q in range(queues):
+                add_queue(f"{name}-q{q}", name)
+            return
+        for c in range(fanout):
+            grow(f"{name}-{c}", name, level + 1)
+        if beside:
+            add_queue(f"{name}-q", name)
+
+    for r in range(roots):
+        grow(f"r{r}", None, 1)
+    for i in range(n_wl):
+        cpu = 2000 if ties else rng.choice([1000, 2000, 4000])
+        if nofit and i % 10 == 0:
+            cpu = 64000
+        reqs = {"cpu": cpu}
+        if memory:
+            reqs["memory"] = 4000 if ties else rng.choice([1000, 4000, 8000])
+        d.create_workload(Workload(
+            name=f"wl-{i}", queue_name=rng.choice(lqs),
+            priority=10 if ties or reserve else rng.choice([10, 10, 50, 100]),
+            creation_time=1.0 if ties else float(i + 1),
+            pod_sets=[PodSet(name="main", count=1, requests=reqs)]))
+    return d, clock
+
+
+@pytest.mark.parametrize("seed", [91, 92, 93])
+def test_drs_path_is_a_row_of_drs_for(seed):
+    """``TournamentDRS.drs_path`` gives, from any level up, what
+    ``drs_for`` gives the same entry there, bit for bit: over a depth-4
+    forest whose usage, quotas, subtree masks, lendables and weights
+    (zero among them) are drawn at random, so usage under guaranteed
+    quota, a resource outside a node's subtree quota and every DRS
+    branch are met."""
+    import numpy as np
+    from kueue_tpu.ops.fairsharing_kernel import TournamentDRS
+    d, _ = build_tree_driver(seed, batched=True, depth=4, beside=True,
+                             memory=True, n_wl=0)
+    t = TournamentDRS(d.scheduler.cache.snapshot())
+    rng = np.random.default_rng(seed)
+    N, F = t.u.shape
+    t.u = rng.integers(0, 9000, (N, F))
+    t.subtree = rng.integers(0, 6000, (N, F))
+    t.sq_mask = rng.random((N, F)) < 0.8
+    t.guaranteed = rng.integers(0, 5000, (N, F))
+    t.lendable_r = rng.integers(0, 3, t.lendable_r.shape) * rng.integers(
+        0, 20000, t.lendable_r.shape)
+    t.weights = rng.choice([0, 500, 1000, 2000, 3], N)
+    cq_is = np.array(sorted(t.cq_index.values()) * 4)
+    u_es = rng.integers(0, 7000, (len(cq_is), F))
+    paths, drs = t.drs_for(cq_is, u_es)
+    for j, ci in enumerate(cq_is.tolist()):
+        want = [(int(t.parent[node]), int(v))
+                for node, v in zip(paths[j], drs[j])
+                if node >= 0 and t.parent[node] >= 0]
+        for start in range(t.depth):
+            got = t.drs_path(ci, u_es[j].tolist(), start)
+            assert got == want[start:len(want)], (ci, start)
+
+
+KEPT_CASES = {
+    "depth2": dict(depth=2, queues=4),
+    "depth3": dict(depth=3, fanout=3),
+    "depth4": dict(depth=4),
+    "queue_beside_cohorts": dict(depth=3, beside=True),
+    "ties": dict(depth=3, fanout=3, ties=True),
+    "weights_0_1_2_and_lending": dict(depth=3, weights=(0, 1000, 2000),
+                                      lending=2000, memory=True),
+    "preempt_reserve": dict(depth=3, reserve=True),
+    "nofit_and_skipped": dict(depth=3, nofit=True),
+    "carry_stops_below_team": dict(depth=4, lending=0),
+    "stale_mid_cycle": dict(depth=3, fanout=3),
+}
+
+
+def record_picks(sched):
+    """Wrap ``sched``'s fair-sharing iterator; returns the list that each
+    cycle appends the keys it yields to, in order."""
+    cycles = []
+    real = sched._fair_sharing_iterator
+
+    def wrapped(entries, snapshot):
+        picks = []
+        cycles.append(picks)
+        for e in real(entries, snapshot):
+            picks.append(e.info.key)
+            yield e
+    sched._fair_sharing_iterator = wrapped
+    return cycles
+
+
+@pytest.mark.parametrize("case", sorted(KEPT_CASES))
+def test_kept_tournament_matches_full_recompute(case, monkeypatch):
+    """The tournament that keeps each tree's DRS values and cohort picks
+    across its rounds yields, pick for pick and cycle for cycle, what the
+    scalar oracle's full recompute every round yields, and decides the
+    same; each case also shows that its path was taken."""
+    from kueue_tpu.ops.fairsharing_kernel import TournamentDRS
+    from kueue_tpu.scheduler.scheduler import Mode
+    spec = KEPT_CASES[case]
+    notes = []                 # nodes each note_add changed
+    real_note = TournamentDRS.note_add
+
+    def note_add(self, cq_name, usage):
+        self.notes = getattr(self, "notes", 0) + 1
+        if case == "stale_mid_cycle" and self.notes == 3:
+            self.stale = True   # as an unseen flavor-resource makes it
+            return
+        before = len(self.changed)
+        real_note(self, cq_name, usage)
+        notes.append(self.changed[before:])
+    monkeypatch.setattr(TournamentDRS, "note_add", note_add)
+
+    runs = []
+    for batched in (False, True):
+        d, clock = build_tree_driver(8100, batched=batched, **spec)
+        reserved = []
+        real_reserve = d.scheduler._resources_to_reserve
+
+        def reserve(e, cq, real_reserve=real_reserve, reserved=reserved):
+            if e.assignment.representative_mode() == Mode.PREEMPT:
+                reserved.append(e.info.key)
+            return real_reserve(e, cq)
+        d.scheduler._resources_to_reserve = reserve
+        picks = record_picks(d.scheduler)
+        log = drive(d, clock, [], n_cycles=24)
+        runs.append((picks, log, d, reserved))
+    (ref_picks, ref_log, ref, _), (picks, log, d, reserved) = runs
+    assert len(picks) == len(ref_picks)
+    for cyc, (r, b) in enumerate(zip(ref_picks, picks)):
+        assert r == b, f"{case} cycle {cyc}:\nscalar={r}\nkept={b}"
+    assert log == ref_log
+    assert any(c["admitted"] for c in log)
+    stats = d.scheduler.solver.stats
+    # a root's own pick is always made again: a tree of depth 2 has no
+    # other cohort to keep one of
+    assert (stats["fs_picks_reused"] > 0) == (spec["depth"] > 2)
+    assert ref.scheduler.solver.stats["fs_picks_reused"] == 0
+    assert stats["fs_drs_entries"] < ref.scheduler.solver.stats[
+        "fs_drs_entries"]
+    if case == "stale_mid_cycle":
+        assert d.scheduler.fs_stats["scalar_drs_rounds"] > 0
+    else:
+        assert d.scheduler.fs_stats["scalar_drs_rounds"] == 0
+    if case == "preempt_reserve":
+        assert reserved
+    if case == "nofit_and_skipped":
+        assert any(c["skipped"] or c["inadmissible"] for c in log)
+    if case == "carry_stops_below_team":
+        # an admission whose usage its own queue's guaranteed quota held
+        assert any(len(n) == 1 for n in notes)
 
 
 @pytest.mark.parametrize("seed", [51, 52, 53])
