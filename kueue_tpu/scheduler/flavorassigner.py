@@ -177,6 +177,17 @@ class _NeverReclaimOracle:
         return False
 
 
+@dataclass
+class _FlavorMode:
+    """A flavor's mode in one walk, the worst of its resources'.
+    RECLAIM stands first for "Preempt, and Reclaim if the reclaim oracle
+    says yes to each of ``questions``" ((FlavorResource, val), in the
+    walk's order): ``FlavorAssigner._settle`` puts them to it only where
+    a comparison reads the answer."""
+    mode: GranularMode
+    questions: list[tuple[FlavorResource, int]] = field(default_factory=list)
+
+
 def rg_by_resource(cq: CQState, resource: str):
     for rg in cq.spec.resource_groups:
         if resource in rg.covered_resources:
@@ -203,6 +214,8 @@ class FlavorAssigner:
         self.flavor_fungibility_enabled = flavor_fungibility_enabled
         self.tas_enabled = tas_enabled
         self._tas_only: Optional[bool] = None
+        # the oracle questions the walks met and never put to it
+        self.oracle_skips = 0
 
     def _is_tas_only(self) -> bool:
         """Every flavor of the CQ is a TAS flavor (reference
@@ -304,7 +317,7 @@ class FlavorAssigner:
                         for k in self.resource_flavors.get(fq.name, ResourceFlavor(fq.name)).node_labels}
 
         best: dict[str, FlavorAssignmentDecision] = {}
-        best_mode = GranularMode.NO_FIT
+        best_mode = _FlavorMode(GranularMode.NO_FIT)
         attempted_idx = -1
         last = self.wl.last_assignment
         idx = last.next_flavor_to_try(ps_idx, res_name) if last is not None else 0
@@ -336,35 +349,37 @@ class FlavorAssigner:
 
             needs_borrowing = False
             assignments: dict[str, FlavorAssignmentDecision] = {}
-            representative = GranularMode.FIT
+            representative = _FlavorMode(GranularMode.FIT)
             for r_name in sorted(reqs):
-                val = reqs[r_name]
                 fr = FlavorResource(f_name, r_name)
-                mode, borrow, reason = self._fits_resource_quota(
-                    fr, val + assignment_usage.get(fr, 0))
+                val = reqs[r_name] + assignment_usage.get(fr, 0)
+                mode, borrow, reason = self._fits_resource_quota(fr, val)
                 if reason:
                     reasons.append(reason)
-                if mode < representative:
-                    representative = mode
+                if mode == GranularMode.RECLAIM:
+                    representative.questions.append((fr, val))
+                    self.oracle_skips += 1
+                representative.mode = min(representative.mode, mode)
                 needs_borrowing = needs_borrowing or borrow
-                if representative == GranularMode.NO_FIT:
+                if representative.mode == GranularMode.NO_FIT:
                     break
                 assignments[r_name] = FlavorAssignmentDecision(
                     name=f_name, mode=mode.public(), borrow=borrow)
 
             if self.flavor_fungibility_enabled:
-                if not self._should_try_next_flavor(representative, needs_borrowing):
+                if not self._should_try_next_flavor(representative.mode,
+                                                    needs_borrowing):
                     best = assignments
                     best_mode = representative
                     break
-                if representative > best_mode:
+                if self._beats(representative, best_mode):
                     best = assignments
                     best_mode = representative
             else:
-                if representative > best_mode:
+                if self._beats(representative, best_mode):
                     best = assignments
                     best_mode = representative
-                    if best_mode == GranularMode.FIT:
+                    if best_mode.mode == GranularMode.FIT:
                         return best, [], None
             idx += 1
 
@@ -372,9 +387,33 @@ class FlavorAssigner:
             for fa in best.values():
                 fa.tried_flavor_idx = (-1 if attempted_idx == len(flavor_names) - 1
                                        else attempted_idx)
-            if best_mode == GranularMode.FIT:
+            if best_mode.mode == GranularMode.FIT:
                 return best, [], None
         return best, reasons, None
+
+    def _beats(self, cand: _FlavorMode, best: _FlavorMode) -> bool:
+        """``cand`` > ``best`` in the granular lattice.  Reclaim over
+        Preempt is read only between two preempt-capable flavors, so
+        only there are their questions put to the oracle, ``best``'s
+        first as the walk met them; a policy stop, a lone
+        preempt-capable flavor or one that lost to a Fit asks nothing,
+        as on the device path (ops/cycle.py ``oracle_groups``)."""
+        if cand.mode == GranularMode.RECLAIM and best.mode.is_preempt:
+            self._settle(best)
+            if best.mode == GranularMode.PREEMPT:
+                self._settle(cand)
+        return cand.mode > best.mode
+
+    def _settle(self, fm: _FlavorMode) -> None:
+        """Put ``fm``'s questions to the oracle in order; the first no
+        makes it PREEMPT and leaves the rest unasked.  The oracle is
+        pure (each search puts the snapshot back), so when it is asked
+        changes no answer."""
+        while fm.questions and fm.mode == GranularMode.RECLAIM:
+            fr, val = fm.questions.pop(0)
+            self.oracle_skips -= 1
+            if not self.oracle.is_reclaim_possible(self.cq, self.wl, fr, val):
+                fm.mode = GranularMode.PREEMPT
 
     def _should_try_next_flavor(self, mode: GranularMode,
                                 needs_borrowing: bool) -> bool:
@@ -429,7 +468,9 @@ class FlavorAssigner:
 
     def _fits_resource_quota(self, fr: FlavorResource, val: int
                              ) -> tuple[GranularMode, bool, Optional[str]]:
-        """reference flavorassigner.go:692 fitsResourceQuota."""
+        """reference flavorassigner.go:692 fitsResourceQuota, with the
+        reclaim oracle's question left to the walk: RECLAIM here is
+        "within nominal, Reclaim if the oracle says so" (``_settle``)."""
         cq = self.cq
         borrow = cq.borrowing_with(fr, val) and cq.has_parent()
         available = cq.available(fr)
@@ -446,9 +487,7 @@ class FlavorAssigner:
         nominal = quota.nominal if quota else 0
         mode = GranularMode.NO_FIT
         if val <= nominal:
-            mode = GranularMode.PREEMPT
-            if self.oracle.is_reclaim_possible(cq, self.wl, fr, val):
-                mode = GranularMode.RECLAIM
+            mode = GranularMode.RECLAIM
         elif self._can_preempt_while_borrowing():
             mode = GranularMode.PREEMPT
         return (mode, borrow,

@@ -255,6 +255,9 @@ class Preemptor:
                       # searches it asked for, and those answered Reclaim
                       "oracle_specs": 0,
                       "oracle_reclaims": 0,
+                      # the host flavor walks' oracle questions never
+                      # put to it: no comparison read their answer
+                      "walk_oracle_skips": 0,
                       # fair sharing (_fair_preemptions): the searches,
                       # a head's or the oracle's on its behalf; those
                       # only the second strategy fitted; the candidates

@@ -1009,6 +1009,16 @@ class Scheduler:
             enable_fair_sharing=self.fair_sharing, oracle=oracle,
             tas_flavors=snapshot.tas_flavors,
             tas_enabled=features.enabled("TopologyAwareScheduling"))
+        found = self._assign_and_search(wl, assigner, snapshot)
+        self.preemptor.stats["walk_oracle_skips"] += assigner.oracle_skips
+        return found
+
+    def _assign_and_search(self, wl: Info, assigner: FlavorAssigner,
+                           snapshot: Snapshot
+                           ) -> tuple[Assignment, list[Target]]:
+        """The walk's assignment and its targets at the full counts, or
+        else at the largest partial counts that fit."""
+        from .. import features
         full = assigner.assign(None)
         mode = full.representative_mode()
         if mode == Mode.FIT:

@@ -370,3 +370,26 @@ def test_the_toy_tree_on_every_engine(seed):
     host, d = assert_engines_agree(toy_tree(seed), cycles=12)
     assert any(e for _, _, e in host)
     assert d._burst_solver.stats["burst_dispatches"] >= 1
+
+
+@pytest.mark.parametrize("seed", range(2))
+def test_the_toy_tree_puts_no_question_to_the_oracle(seed, monkeypatch):
+    """The cell's group holds one flavor, so a preempting head's walk
+    meets one preempt-capable flavor and no comparison reads the reclaim
+    oracle's answer: its questions are met (``walk_oracle_skips``) and
+    never put, and the engines still agree cycle by cycle."""
+    from kueue_tpu.scheduler.preemption import PreemptionOracle
+    asked = []
+    real = PreemptionOracle.is_reclaim_possible
+
+    def spy(self, *question):
+        asked.append(question)
+        return real(self, *question)
+
+    monkeypatch.setattr(PreemptionOracle, "is_reclaim_possible", spy)
+    host, d = assert_engines_agree(toy_tree(seed), cycles=12)
+    assert any(e for _, _, e in host)
+    assert asked == []
+    pre = d.scheduler.preemptor.stats
+    assert pre["walk_oracle_skips"] > 0, pre
+    assert pre["fs_preempt_heads"] > 0, pre

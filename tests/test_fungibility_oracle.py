@@ -17,19 +17,36 @@ from __future__ import annotations
 import pytest
 
 from kueue_tpu.api.types import (
+    BorrowWithinCohort,
+    BorrowWithinCohortPolicy,
     ClusterQueue,
     FlavorFungibility,
     FlavorFungibilityPolicy,
     FlavorQuotas,
     LocalQueue,
+    PodSet,
     PreemptionPolicy,
     ReclaimWithinCohort,
     ResourceFlavor,
     ResourceGroup,
     ResourceQuota,
     WithinClusterQueue,
+    Workload,
 )
 from kueue_tpu.controller.driver import Driver
+from kueue_tpu.resources import (
+    FlavorResource,
+    FlavorResourceQuantities,
+    Requests,
+)
+from kueue_tpu.scheduler.flavorassigner import (
+    Assignment,
+    FlavorAssigner,
+    FlavorAssignmentDecision,
+    Mode,
+    PodSetAssignmentResult,
+)
+from kueue_tpu.workload import Info
 from tests.conftest import FakeClock
 from tests.test_conformance_preemption import admit, incoming
 
@@ -306,3 +323,194 @@ def test_lattice_picks_the_first_slot_of_the_best_mode(fit, reclaim, want):
     # a slot that is not preempt-capable is never picked, whatever it reads
     capable[0, want] = False
     assert pick_preempt_slot_np(capable, fit, reclaim)[0] != want
+
+
+# ---------------------------------------------------------------------------
+# The host walk asks the oracle only where two preempt-capable flavors meet
+# ---------------------------------------------------------------------------
+
+class ScriptedOracle:
+    """Answers from a table keyed (flavor, resource, quantity), every
+    question written down; a question not in the table fails the case."""
+
+    def __init__(self, answers):
+        self.answers = answers
+        self.asked = []
+
+    def is_reclaim_possible(self, cq, wl, fr, quantity) -> bool:
+        self.asked.append((fr.flavor, fr.resource, quantity))
+        return self.answers[(fr.flavor, fr.resource, quantity)]
+
+
+def walk_cluster(d, a, b, fungibility, borrow_within):
+    """Queues ``a`` and ``b`` of one cohort over one resource group;
+    ``a`` / ``b`` = {flavor: {resource: nominal}}, in walk order."""
+    for f in FLAVORS:
+        d.apply_resource_flavor(ResourceFlavor(name=f))
+    for name, nominal in (("a", a), ("b", b)):
+        d.apply_cluster_queue(ClusterQueue(
+            name=name, cohort="co", preemption=PreemptionPolicy(
+                within_cluster_queue=WithinClusterQueue.LOWER_PRIORITY,
+                reclaim_within_cohort=ReclaimWithinCohort.ANY,
+                borrow_within_cohort=BorrowWithinCohort(policy=borrow_within)),
+            flavor_fungibility=fungibility,
+            resource_groups=[ResourceGroup(
+                covered_resources=sorted(next(iter(a.values()))),
+                flavors=[FlavorQuotas(name=f, resources={
+                    r: ResourceQuota(nominal=q) for r, q in quota.items()})
+                    for f, quota in nominal.items()])]))
+
+
+def _walk_case(a, b, running, pod_sets, answers, want, asked, skips,
+               wcp=TRY_NEXT, wcb=FlavorFungibilityPolicy.BORROW,
+               borrow_within="Never"):
+    return dict(a=a, b=b, running=running, pod_sets=pod_sets,
+                answers=answers, want=want, asked=asked, skips=skips,
+                wcp=wcp, wcb=wcb, borrow_within=borrow_within)
+
+
+def _short(flavor, res, more):
+    return (f"insufficient unused quota for {res} in flavor {flavor}, "
+            f"{more} more needed")
+
+
+CPU4 = {"cpu": 4 * K}
+BOTH = {"cpu": 4 * K, "memory": 4 * GI}
+# queue a holds its own quota of f1 and f2 to the full
+FULL = [("a", "f1", CPU4), ("a", "f2", CPU4)]
+FULL_BOTH = [("a", "f1", BOTH), ("a", "f2", BOTH)]
+# ``want``: per PodSet ({resource: (flavor, borrow)}, tried, reasons), the
+# eager walk's answer, worked out by hand (every pick here is Preempt
+# but where the case says Fit); ``asked``: the oracle's questions
+WALKS = {
+    "one_flavor_asks_nothing": _walk_case(
+        a={"f1": CPU4}, b={"f1": {"cpu": 0}}, running=[("a", "f1", CPU4)],
+        pod_sets=[CPU4], answers={},
+        want=[({"cpu": ("f1", True)}, -1, [_short("f1", "cpu", 4 * K)])],
+        asked=[], skips=1),
+    "reclaim_second_beats_preempt": _walk_case(
+        a={"f1": CPU4, "f2": CPU4}, b={"f1": {"cpu": 0}, "f2": {"cpu": 0}},
+        running=FULL, pod_sets=[CPU4],
+        answers={("f1", "cpu", 4 * K): False, ("f2", "cpu", 4 * K): True},
+        want=[({"cpu": ("f2", True)}, -1, [_short("f1", "cpu", 4 * K),
+                                           _short("f2", "cpu", 4 * K)])],
+        asked=[("f1", "cpu", 4 * K), ("f2", "cpu", 4 * K)], skips=0),
+    "reclaim_first_beats_preempt": _walk_case(
+        a={"f1": CPU4, "f2": CPU4}, b={"f1": {"cpu": 0}, "f2": {"cpu": 0}},
+        running=FULL, pod_sets=[CPU4],
+        answers={("f1", "cpu", 4 * K): True, ("f2", "cpu", 4 * K): False},
+        want=[({"cpu": ("f1", True)}, -1, [_short("f1", "cpu", 4 * K),
+                                           _short("f2", "cpu", 4 * K)])],
+        asked=[("f1", "cpu", 4 * K)], skips=1),
+    "preempt_ties_keep_the_first": _walk_case(
+        a={"f1": CPU4, "f2": CPU4}, b={"f1": {"cpu": 0}, "f2": {"cpu": 0}},
+        running=FULL, pod_sets=[CPU4],
+        answers={("f1", "cpu", 4 * K): False, ("f2", "cpu", 4 * K): False},
+        want=[({"cpu": ("f1", True)}, -1, [_short("f1", "cpu", 4 * K),
+                                           _short("f2", "cpu", 4 * K)])],
+        asked=[("f1", "cpu", 4 * K), ("f2", "cpu", 4 * K)], skips=0),
+    "policy_stop_asks_nothing": _walk_case(
+        a={"f1": CPU4, "f2": CPU4}, b={"f1": {"cpu": 0}, "f2": {"cpu": 0}},
+        running=FULL, pod_sets=[CPU4], answers={}, wcp=PREEMPT,
+        want=[({"cpu": ("f1", True)}, 0, [_short("f1", "cpu", 4 * K)])],
+        asked=[], skips=1),
+    "fit_borrowing_then_preempt": _walk_case(
+        a={"f1": {"cpu": 0}, "f2": CPU4}, b={"f1": CPU4, "f2": {"cpu": 0}},
+        running=[("a", "f2", CPU4)], pod_sets=[CPU4], answers={},
+        wcb=TRY_NEXT, want=[({"cpu": ("f1", True)}, -1, "fit")],
+        asked=[], skips=1),
+    "definite_preempt_beside_undecided": _walk_case(
+        a={"f1": {"cpu": 2 * K, "memory": 4 * GI}, "f2": BOTH},
+        b={"f1": {"cpu": 4 * K, "memory": 0}, "f2": {"cpu": 0, "memory": 0}},
+        running=[("a", "f1", {"cpu": 2 * K, "memory": 4 * GI}),
+                 ("b", "f1", {"cpu": 4 * K}), ("a", "f2", BOTH)],
+        pod_sets=[BOTH], borrow_within="LowerPriority",
+        answers={("f2", "cpu", 4 * K): True, ("f2", "memory", 4 * GI): True},
+        want=[({"cpu": ("f2", True), "memory": ("f2", True)}, -1, [
+            _short("f1", "cpu", 4 * K), _short("f1", "memory", 4 * GI),
+            _short("f2", "cpu", 4 * K), _short("f2", "memory", 4 * GI)])],
+        asked=[("f2", "cpu", 4 * K), ("f2", "memory", 4 * GI)], skips=1),
+    "first_no_leaves_the_second_unasked": _walk_case(
+        a={"f1": BOTH, "f2": BOTH},
+        b={"f1": {"cpu": 0, "memory": 0}, "f2": {"cpu": 0, "memory": 0}},
+        running=FULL_BOTH, pod_sets=[BOTH],
+        answers={("f1", "cpu", 4 * K): False, ("f2", "cpu", 4 * K): True,
+                 ("f2", "memory", 4 * GI): True},
+        want=[({"cpu": ("f2", True), "memory": ("f2", True)}, -1, [
+            _short("f1", "cpu", 4 * K), _short("f1", "memory", 4 * GI),
+            _short("f2", "cpu", 4 * K), _short("f2", "memory", 4 * GI)])],
+        asked=[("f1", "cpu", 4 * K), ("f2", "cpu", 4 * K),
+               ("f2", "memory", 4 * GI)], skips=1),
+    "nofit_after_preempt_capable": _walk_case(
+        a={"f1": BOTH, "f2": {"cpu": 4 * K, "memory": 8 * GI}},
+        b={"f1": {"cpu": 0, "memory": 0}, "f2": {"cpu": 0, "memory": 0}},
+        running=[("a", "f1", CPU4),
+                 ("a", "f2", {"cpu": 4 * K, "memory": 8 * GI})],
+        pod_sets=[{"cpu": 4 * K, "memory": 8 * GI}], answers={},
+        want=[({"cpu": ("f2", True), "memory": ("f2", True)}, -1, [
+            _short("f1", "cpu", 4 * K),
+            "insufficient quota for memory in flavor f1, request > maximum "
+            f"capacity ({8 * GI} > {4 * GI})",
+            _short("f2", "cpu", 4 * K), _short("f2", "memory", 8 * GI)])],
+        asked=[], skips=3),
+    "gang_second_podset_at_its_charged_val": _walk_case(
+        a={"f1": CPU4, "f2": CPU4}, b={"f1": {"cpu": 0}, "f2": {"cpu": 0}},
+        running=FULL, pod_sets=[{"cpu": 2 * K}, {"cpu": 2 * K}],
+        answers={("f1", "cpu", 2 * K): False, ("f2", "cpu", 2 * K): True,
+                 ("f2", "cpu", 4 * K): True},
+        want=[({"cpu": ("f2", True)}, -1, [_short("f1", "cpu", 2 * K),
+                                           _short("f2", "cpu", 2 * K)]),
+              ({"cpu": ("f2", True)}, -1, [_short("f1", "cpu", 2 * K),
+                                           _short("f2", "cpu", 4 * K)])],
+        asked=[("f1", "cpu", 2 * K), ("f2", "cpu", 2 * K),
+               ("f1", "cpu", 2 * K), ("f2", "cpu", 4 * K)], skips=0),
+}
+
+
+@pytest.mark.parametrize("case", sorted(WALKS))
+def test_the_host_walk_asks_only_where_preempt_flavors_meet(case):
+    """The whole ``Assignment`` of the host walk, its reasons and resume
+    state, is the eager walk's, while the oracle is asked only where a
+    comparison of two preempt-capable flavors reads its answer, in the
+    walk's order and no further than the first no; ``oracle_skips``
+    counts the questions met and never put."""
+    c = WALKS[case]
+    d = Driver(clock=FakeClock())
+    walk_cluster(d, c["a"], c["b"], FlavorFungibility(
+        when_can_borrow=c["wcb"], when_can_preempt=c["wcp"]),
+        BorrowWithinCohortPolicy(c["borrow_within"]))
+    for i, (q, f, usage) in enumerate(c["running"]):
+        admit(d, f"run-{i}", q, {r: (f, v) for r, v in usage.items()},
+              priority=-10)
+    snap = d.cache.snapshot()
+    cq = snap.cq("a")
+    head = Info(Workload(name="head", namespace="default", queue_name="lq-a",
+                         pod_sets=[PodSet(name=f"ps{p}", count=1,
+                                          requests=dict(reqs))
+                                   for p, reqs in enumerate(c["pod_sets"])]))
+    head.cluster_queue = "a"
+    oracle = ScriptedOracle(c["answers"])
+    assigner = FlavorAssigner(head, cq, snap.resource_flavors, oracle=oracle)
+    got = assigner.assign()
+
+    want = Assignment()
+    want.last_state.cluster_queue_generation = cq.allocatable_generation
+    for p, (reqs, (pick, tried, reasons)) in enumerate(
+            zip(c["pod_sets"], c["want"], strict=True)):
+        mode = Mode.FIT if reasons == "fit" else Mode.PREEMPT
+        want.pod_sets.append(PodSetAssignmentResult(
+            name=f"ps{p}", count=1, requests=Requests(reqs),
+            reasons=[] if reasons == "fit" else reasons,
+            flavors={r: FlavorAssignmentDecision(
+                name=f, mode=mode, tried_flavor_idx=tried, borrow=borrow)
+                for r, (f, borrow) in pick.items()}))
+        want.last_state.last_tried_flavor_idx.append(
+            {r: tried for r in pick})
+        want.borrowing |= any(borrow for _, borrow in pick.values())
+        for r, (f, _) in pick.items():
+            fr = FlavorResource(f, r)
+            want.usage[fr] = want.usage.get(fr, 0) + reqs[r]
+    want.usage = FlavorResourceQuantities(want.usage)
+    assert got == want
+    assert oracle.asked == c["asked"]
+    assert assigner.oracle_skips == c["skips"]
