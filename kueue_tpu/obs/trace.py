@@ -80,6 +80,7 @@ HOT_PATH_PHASES = (
     "cycle.nominate.classify.podsets",  # a pass a PodSet of every head
     "cycle.nominate.classify.groups",  # one flavor walk a group, joined
     "cycle.nominate.walk",            # host FlavorAssigner walks
+    "cycle.nominate.partial",         # the count search's lockstep passes
     "cycle.nominate.oracle",          # the reclaim oracle's batched searches
     "cycle.nominate.candidates",      # find/sort candidates, plan searches
     "cycle.nominate.search_pack",     # numpy fill of the [S, K, F] planes

@@ -47,6 +47,7 @@ milli-quanta on TPU is hard part (e) in SURVEY §7).
 
 from __future__ import annotations
 
+import itertools
 import math
 from dataclasses import dataclass, field
 from typing import Optional
@@ -379,6 +380,22 @@ def pack_structure(snapshot: Snapshot, heads: list[Info] = (),
         for psr in h.total_requests:
             for r, v in psr.requests.items():
                 note(r, v)
+    # a PodSet that may run on fewer pods (partial admission) asks, and
+    # once admitted holds, any count of one pod's request: the scale
+    # divides that request, so the count search's probes and the usage
+    # they leave behind stay exact
+    admitted = (info for name in cq_names
+                for info in snapshot.cluster_queues[name].workloads.values())
+    for info in itertools.chain(admitted, heads):
+        for ps, psr in zip(info.obj.pod_sets, info.total_requests):
+            if (ps.min_count is None or ps.min_count >= ps.count
+                    or psr.count <= 0):
+                continue
+            for r, v in psr.requests.items():
+                if r in r_index:
+                    i = r_index[r]
+                    gcd_per_resource[i] = math.gcd(
+                        int(gcd_per_resource[i]), int(v) // psr.count)
 
     scale = np.ones(R, dtype=np.int64)
     exact_static = True

@@ -14,9 +14,12 @@ Per cycle the solver:
    eligibility masks, ``ops.eligibility``, and any fungibility policy
    and the resume state run in the vector walk); the remaining heads
    are marked SCALAR — the scheduler runs the real host FlavorAssigner
-   walk for those few and attaches the resulting assignment, so partial
-   admission, TAS and a gang whose earlier PodSet's pick is the reclaim
-   oracle's all stay inside a device-decided cycle;
+   walk for those few and attaches the resulting assignment, so TAS and
+   a gang whose earlier PodSet's pick is the reclaim oracle's stay
+   inside a device-decided cycle; a partial-admission head that does
+   not fit at its full counts searches them on the vector path, the
+   cycle's searching heads in lockstep passes (``classify_probes``,
+   ``Scheduler._search_counts``);
 3. dispatches the sequential admit scan (``ops.cycle.admit_scan``) as ONE
    jitted program on the solver device (``ops.device.solver_device``: the
    default JAX backend's device, so the TPU on a chip host).  The scan
@@ -51,6 +54,7 @@ from ..scheduler.flavorassigner import (
     FlavorAssignmentDecision,
     Mode,
     PodSetAssignmentResult,
+    can_be_partially_admitted,
 )
 from ..resources import FlavorResource, Requests
 from .packing import (MAX_POD_SETS, PackedCycle, PackedStructure, _bucket,
@@ -217,7 +221,8 @@ class CycleSolver:
             "scalar_heads": 0,        # heads classified by the host walk
             # flavor-walk telemetry (heterogeneous fast path):
             "scalar_reasons": {},     # {reason: count} for scalar heads
-            "resume_heads": 0,        # heads entering the walk mid-list
+            "resume_heads": 0,        # heads (and count-search probes)
+                                      # entering the walk mid-list
             "walk_stop_heads": 0,     # heads whose walk policy-stopped
             "walk_heads": 0,          # heads classified by the vector walk
             "walk_slots": 0,          # flavors their walks visited
@@ -265,6 +270,12 @@ class CycleSolver:
             "tas_host_admit_cycles": 0,  # cycles whose Fit placements
                                          # shared a node: the host admit
                                          # loop decided them in order
+            # partial admission's count search on the vector path
+            # (Scheduler._search_counts, podset_reducer.go):
+            "partial_heads": 0,       # heads whose search it ran
+            "partial_passes": 0,      # its lockstep passes
+            "partial_probes": 0,      # (head, probe) pairs they walked
+            "partial_admitted": 0,    # admissions at counts below full
         }
         self._structure: Optional[PackedStructure] = None
         self._potential0 = None
@@ -601,13 +612,90 @@ class CycleSolver:
 
     # -- phase 1 -------------------------------------------------------
 
-    def classify(self, snapshot: Snapshot,
-                 heads: list[Info]) -> Optional[ClassifiedCycle]:
+    def _walk(self, snapshot: Snapshot, heads: list[Info],
+              packed: PackedCycle):
+        """The vector flavor walks of ``heads`` (classify_np) on the
+        snapshot's usage: (walk outputs, eligibility plane)."""
+        st = packed.structure
+        start = self._start_slots(snapshot, heads, st)
+        if self._potential0 is None or self._potential0.shape != packed.usage0.shape:
+            from .cycle import available_all_np
+            self._potential0 = available_all_np(
+                np.zeros_like(packed.usage0), st.subtree_quota, st.guaranteed,
+                st.borrow_cap, st.has_borrow_limit, st.parent, st.depth)
+        W = packed.wl_cq.shape[0]
+        start_pad = np.zeros((W,) + start.shape[1:], dtype=np.int32)
+        start_pad[:len(heads)] = start
+        with _span("cycle.nominate.classify.eligibility"):
+            eligible = self._eligible_slots(heads, st, W)
+        return classify_np(packed, potential0=self._potential0,
+                           start_slot=start_pad, eligible=eligible), eligible
+
+    @staticmethod
+    def _oracle_chained(out: dict, n: int) -> np.ndarray:
+        """Heads (of the first ``n``) whose earlier PodSet's pick is the
+        reclaim oracle's, in a group a later PodSet walks too: what the
+        later walk is charged with waits for the oracle's answer, so the
+        host walk, which asks as it goes, decides the head."""
+        later = np.flip(np.cumsum(np.flip(out["walked"], 1), axis=1), 1)
+        return (out["oracle_groups"]
+                & (later > out["walked"])).any(axis=(1, 2))[:n]
+
+    @staticmethod
+    def _classified(packed: PackedCycle, heads: list[Info],
+                    snapshot: Snapshot, out: dict,
+                    scalar: np.ndarray) -> ClassifiedCycle:
+        """The cycle as the walks left it, with the rows of the
+        ``scalar`` heads cleared: their decisions come from the host
+        assignments attached to them instead."""
+        W = packed.wl_cq.shape[0]
+        sm = np.zeros(W, dtype=bool)
+        sm[:len(scalar)] = scalar
+        if sm.any():
+            out = dict(out)
+            out["fit0"] = out["fit0"] & ~sm
+            out["slots0"] = np.where(sm[:, None, None], -1, out["slots0"])
+            out["oracle_groups"] = (out["oracle_groups"]
+                                    & ~sm[:, None, None])
+            out["borrows0"] = out["borrows0"] & ~sm
+            out["preempt0"] = out["preempt0"] & ~sm
+            out["preempt_borrows0"] = out["preempt_borrows0"] & ~sm
+            out["preempt_stopped0"] = out["preempt_stopped0"] & ~sm
+        return ClassifiedCycle(
+            packed=packed, heads=heads, snapshot=snapshot,
+            borrows0=out["borrows0"],
+            preempt0=out["preempt0"],
+            preempt_borrows0=out["preempt_borrows0"],
+            preempt_res_fit=out["preempt_res_fit"],
+            preempt_stopped0=out["preempt_stopped0"],
+            preempt_slots=out.get("preempt_slots"),
+            slot_res_fit=out.get("slot_res_fit"),
+            slot_borrows=out.get("slot_borrows"),
+            oracle_ask=out.get("oracle_ask"),
+            fit0=out["fit0"], slots0=out["slots0"], tried=out["tried"],
+            oracle_groups=out["oracle_groups"],
+            scalar_mask=sm, host_assignments={}, host_pairs={})
+
+    def _note_scalar(self, mask: np.ndarray, reason: str) -> int:
+        n = int(mask.sum())
+        if n:
+            reasons = self.stats["scalar_reasons"]
+            reasons[reason] = reasons.get(reason, 0) + n
+        return n
+
+    def classify(self, snapshot: Snapshot, heads: list[Info],
+                 elastic_on_host: bool = False
+                 ) -> Optional[ClassifiedCycle]:
         """Pack + vectorized nominate.  None → run the host path.
 
         Heads the vector math can't cover are flagged in ``scalar_mask``
         (their vector rows are cleared); the scheduler host-walks those
-        and attaches the assignments via ``attach_host_assignment``."""
+        and attaches the assignments via ``attach_host_assignment``.
+        A partial-admission head that does not fit at its full counts
+        is left to the scheduler's count search on the vector path
+        (``classify_probes``), but for one on a queue with a TAS flavor,
+        and every one where ``elastic_on_host`` (fair sharing): the
+        host walk's PodSetReducer decides those."""
         if not heads:
             return None
         st = self._structure_for(snapshot, heads)
@@ -624,62 +712,24 @@ class CycleSolver:
             # lossy int32 scaling could deny fits the host grants
             return None
         scalar = self._scalar_mask(snapshot, heads, st)
-        start = self._start_slots(snapshot, heads, st)
-        if self._potential0 is None or self._potential0.shape != packed.usage0.shape:
-            from .cycle import available_all_np
-            self._potential0 = available_all_np(
-                np.zeros_like(packed.usage0), st.subtree_quota, st.guaranteed,
-                st.borrow_cap, st.has_borrow_limit, st.parent, st.depth)
-
-        W = packed.wl_cq.shape[0]
-        start_pad = np.zeros((W,) + start.shape[1:], dtype=np.int32)
-        start_pad[:len(heads)] = start
-        with _span("cycle.nominate.classify.eligibility"):
-            eligible = self._eligible_slots(heads, st, W)
-        out = classify_np(packed, potential0=self._potential0,
-                          start_slot=start_pad, eligible=eligible)
+        out, eligible = self._walk(snapshot, heads, packed)
         n = packed.wl_count
-        # partial admission: a min_count head whose FULL counts fit is
-        # decision-identical to a plain head; otherwise the host runs the
-        # PodSetReducer binary search (podset_reducer.go) — scalar walk
-        for wi in range(n):
-            if scalar[wi] or out["fit0"][wi]:
-                continue
-            if any(ps.min_count is not None and ps.min_count < ps.count
-                   for ps in heads[wi].obj.pod_sets):
-                scalar[wi] = True
-        # a gang whose earlier PodSet's pick is the reclaim oracle's,
-        # in a group a later PodSet walks too: what the later walk is
-        # charged with waits for the oracle's answer, so the host walk,
-        # which asks as it goes, decides the head
-        later = np.flip(np.cumsum(np.flip(out["walked"], 1), axis=1), 1)
-        chained = (out["oracle_groups"]
-                   & (later > out["walked"])).any(axis=(1, 2))[:n] & ~scalar
-        if chained.any():
-            scalar |= chained
-            reasons = self.stats["scalar_reasons"]
-            reasons["podset_oracle_order"] = (
-                reasons.get("podset_oracle_order", 0) + int(chained.sum()))
-            self.stats["podset_scalar_heads"] += int(chained.sum())
-        if scalar.any():
-            # clear the vector rows for scalar heads: their decisions come
-            # from the attached host assignments instead
-            sm = np.zeros(W, dtype=bool)
-            sm[:n] = scalar
-            out = dict(out)
-            out["fit0"] = out["fit0"] & ~sm
-            out["slots0"] = np.where(sm[:, None, None], -1, out["slots0"])
-            out["oracle_groups"] = (out["oracle_groups"]
-                                    & ~sm[:, None, None])
-            out["borrows0"] = out["borrows0"] & ~sm
-            out["preempt0"] = out["preempt0"] & ~sm
-            out["preempt_borrows0"] = out["preempt_borrows0"] & ~sm
-            out["preempt_stopped0"] = out["preempt_stopped0"] & ~sm
-            self.stats["scalar_heads"] += int(scalar.sum())
-        else:
-            sm = np.zeros(W, dtype=bool)
+        unfit = ~scalar & ~out["fit0"][:n]
+        if unfit.any() and (elastic_on_host or st.cq_tas.any()):
+            elastic = unfit & np.array(
+                [can_be_partially_admitted(h) for h in heads])
+            if not elastic_on_host:
+                elastic &= st.cq_tas[np.maximum(packed.wl_cq[:n], 0)]
+            self._note_scalar(elastic, "partial_fair_sharing"
+                              if elastic_on_host else "partial_tas")
+            scalar |= elastic
+        chained = self._oracle_chained(out, n) & ~scalar
+        self.stats["podset_scalar_heads"] += self._note_scalar(
+            chained, "podset_oracle_order")
+        scalar |= chained
+        self.stats["scalar_heads"] += int(scalar.sum())
         self.stats["walk_stop_heads"] += int(
-            np.count_nonzero(out["preempt_stopped0"][:n]))
+            np.count_nonzero(out["preempt_stopped0"][:n] & ~scalar))
         self.stats["walk_heads"] += int(n - scalar.sum())
         self.stats["walk_slots"] += int(out["walk_slots"][:n][~scalar].sum())
         self.stats["walk_ineligible_slots"] += int(
@@ -699,20 +749,23 @@ class CycleSolver:
         self.stats["constrained_heads"] += int(np.count_nonzero(
             (st.slot_valid[np.maximum(packed.wl_cq[:n], 0)][:, None]
              & ~eligible[:n]).any(axis=(1, 2, 3)) & ~scalar))
-        return ClassifiedCycle(
-            packed=packed, heads=heads, snapshot=snapshot,
-            borrows0=out["borrows0"],
-            preempt0=out["preempt0"],
-            preempt_borrows0=out["preempt_borrows0"],
-            preempt_res_fit=out["preempt_res_fit"],
-            preempt_stopped0=out["preempt_stopped0"],
-            preempt_slots=out.get("preempt_slots"),
-            slot_res_fit=out.get("slot_res_fit"),
-            slot_borrows=out.get("slot_borrows"),
-            oracle_ask=out.get("oracle_ask"),
-            fit0=out["fit0"], slots0=out["slots0"], tried=out["tried"],
-            oracle_groups=out["oracle_groups"],
-            scalar_mask=sm, host_assignments={}, host_pairs={})
+        return self._classified(packed, heads, snapshot, out, scalar)
+
+    def classify_probes(self, cls: ClassifiedCycle,
+                        probes: list[Info]) -> Optional[ClassifiedCycle]:
+        """The vector walks of one pass of the count search: ``probes``
+        are heads of ``cls`` at reduced counts (``probe_info``), walked
+        on ``cls``'s snapshot and structure.  None when their requests
+        do not pack exactly; a probe whose gang walk waits on the
+        reclaim oracle (``_oracle_chained``) is flagged in
+        ``scalar_mask``, for the host walk to search its head."""
+        packed = pack_cycle(cls.snapshot, probes, self.ordering,
+                            structure=cls.packed.structure)
+        if packed is None or not packed.exact:
+            return None
+        out, _ = self._walk(cls.snapshot, probes, packed)
+        return self._classified(packed, probes, cls.snapshot, out,
+                                self._oracle_chained(out, len(probes)))
 
     # -- the reclaim oracle's part of the walk --------------------------
 
@@ -1456,29 +1509,6 @@ class CycleSolver:
                         f"{fr.flavor}, {val - avail} more needed")
             ps.reasons = reasons
         return assignment, assignment.message()
-
-    # -- back-compat one-shot API (tests/probes) -----------------------
-
-    def try_solve(self, snapshot: Snapshot, heads: list[Info]
-                  ) -> Optional[dict[str, Assignment]]:
-        """Classify-only: {workload_key: Fit Assignment} for heads that fit
-        at snapshot usage, or None when the host path must run (any
-        preempt-capable head, or unsupported semantics)."""
-        cls = self.classify(snapshot, heads)
-        if cls is None:
-            self.stats["host_cycles"] += 1
-            return None
-        if self.tas_active(cls):
-            self.tas_stage(cls)
-        if cls.preempt0[:cls.n].any() or cls.scalar_mask[:cls.n].any():
-            self.stats["host_cycles"] += 1
-            return None
-        self.stats["classify_cycles"] += 1
-        out: dict[str, Assignment] = {}
-        for wi in range(cls.n):
-            if cls.fit0[wi]:
-                out[cls.heads[wi].key] = self.build_fit_assignment(cls, wi)
-        return out
 
 
 def build_slot_assignment(info: Info, cq, slots, tried, mode: Mode,

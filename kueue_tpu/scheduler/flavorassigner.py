@@ -15,6 +15,7 @@ this module.
 
 from __future__ import annotations
 
+import copy
 import enum
 from dataclasses import dataclass, field
 from typing import Callable, Optional, Protocol
@@ -570,35 +571,69 @@ class FlavorAssigner:
 # Partial admission (reference podset_reducer.go, KEP 420)
 # ---------------------------------------------------------------------------
 
+def can_be_partially_admitted(wl: Info) -> bool:
+    """A PodSet of the workload may run on fewer pods than it asks
+    (``min_count`` under ``count``: KEP 420)."""
+    return any(ps.min_count is not None and ps.min_count < ps.count
+               for ps in wl.obj.pod_sets)
+
+
+def scaled_info(wl: Info, counts: list[int]) -> Info:
+    """``wl`` at ``counts`` pods a PodSet: a shallow copy whose requests
+    are the ones ``FlavorAssigner.assign(counts)`` walks."""
+    probe = copy.copy(wl)
+    probe.total_requests = [psr.scaled_to(c)
+                            for psr, c in zip(wl.total_requests, counts)]
+    return probe
+
+
 class PodSetReducer:
-    """Binary search over reduced pod counts (reference podset_reducer.go:37)."""
+    """Binary search over reduced pod counts (reference podset_reducer.go:37).
+
+    ``search`` runs it with ``fits`` to the end; a caller that answers
+    many workloads' probes together steps it instead: ``probe`` gives
+    the next reduction index and its counts, ``record`` takes the
+    answer, while ``searching``."""
 
     def __init__(self, pod_sets: list[PodSet],
-                 fits: Callable[[list[int]], tuple[object, bool]]):
+                 fits: Optional[Callable[[list[int]],
+                                         tuple[object, bool]]] = None):
         self.pod_sets = pod_sets
         self.fits = fits
         self.full_counts = [ps.count for ps in pod_sets]
         self.deltas = [ps.count - (ps.min_count if ps.min_count is not None else ps.count)
                        for ps in pod_sets]
         self.total_delta = sum(self.deltas)
+        # the smallest index in [0, totalDelta] that fits
+        self.lo, self.hi = 0, self.total_delta
+        self.last_good = None
+        self.last_good_idx = -1
 
     def _counts_for(self, up: int) -> list[int]:
         return [full - (d * up) // self.total_delta
                 for full, d in zip(self.full_counts, self.deltas)]
 
+    @property
+    def searching(self) -> bool:
+        return self.total_delta > 0 and self.lo <= self.hi
+
+    def probe(self) -> tuple[int, list[int]]:
+        mid = (self.lo + self.hi) // 2
+        return mid, self._counts_for(mid)
+
+    def record(self, mid: int, result, ok: bool) -> None:
+        if ok:
+            self.last_good, self.last_good_idx = result, mid
+            self.hi = mid - 1
+        else:
+            self.lo = mid + 1
+
+    def found(self) -> bool:
+        return self.last_good_idx == self.lo and self.last_good is not None
+
     def search(self) -> tuple[object, bool]:
         """Find the largest counts that fit (smallest reduction index)."""
-        if self.total_delta == 0:
-            return None, False
-        last_good = None
-        last_good_idx = -1
-        lo, hi = 0, self.total_delta  # search smallest i in [0, totalDelta] that fits
-        while lo <= hi:
-            mid = (lo + hi) // 2
-            result, ok = self.fits(self._counts_for(mid))
-            if ok:
-                last_good, last_good_idx = result, mid
-                hi = mid - 1
-            else:
-                lo = mid + 1
-        return last_good, last_good_idx == lo and last_good is not None
+        while self.searching:
+            mid, counts = self.probe()
+            self.record(mid, *self.fits(counts))
+        return self.last_good, self.found()

@@ -363,9 +363,13 @@ class Preemptor:
         return [(same_queue, True, None)], False
 
     def get_targets_batch(self, requests: list[tuple[Info, Assignment]],
-                          snapshot: Snapshot) -> list[list[Target]]:
+                          snapshot: Snapshot, empty: Optional[list] = None
+                          ) -> list[list[Target]]:
         """Target searches for ALL of a cycle's preempt heads in the
-        batched device dispatch (``_search_batch``)."""
+        batched device dispatch (``_search_batch``).  ``empty``, where
+        given, is filled with whether each request's planned searches
+        had no candidate to search (its answer ``[]`` found without a
+        search)."""
         return self._search_batch([_PreemptionCtx(
             preemptor=wl,
             preemptor_cq=snapshot.cq(wl.cluster_queue),
@@ -373,7 +377,7 @@ class Preemptor:
             frs_need_preemption=flavor_resources_need_preemption(
                 assignment),
             workload_usage=assignment.total_requests_for(wl))
-            for wl, assignment in requests], snapshot)
+            for wl, assignment in requests], snapshot, empty)
 
     def reclaim_possible_batch(self, queries: list[tuple],
                                snapshot: Snapshot) -> list[bool]:
@@ -400,8 +404,8 @@ class Preemptor:
         self.stats["oracle_reclaims"] += sum(answers)
         return answers
 
-    def _search_batch(self, ctxs: list[_PreemptionCtx],
-                      snapshot: Snapshot) -> list[list[Target]]:
+    def _search_batch(self, ctxs: list[_PreemptionCtx], snapshot: Snapshot,
+                      empty: Optional[list] = None) -> list[list[Target]]:
         """One target search a context, all in batched device dispatches
         (ops/preemption_kernel minimal_preemptions_batch) — candidate
         discovery and ordering stay host-side, the greedy+fillback
@@ -422,6 +426,8 @@ class Preemptor:
         pack, or an unpackable spec in any launch (decision-identical
         either way)."""
         packed = self._pack_for(snapshot)
+        if empty is not None:
+            empty[:] = [False] * len(ctxs)     # not known: a search each
 
         def each_head():
             """One search (and one candidate discovery) a context."""
@@ -446,6 +452,9 @@ class Preemptor:
                     idxs.append(len(flat_specs))
                     flat_specs.append((ctx, cands, ab, thr))
                 plans.append((idxs, staged))
+        if empty is not None:
+            empty[:] = [all(not flat_specs[i][1] for i in idxs)
+                        for idxs, _ in plans]
 
         # the launch plan follows each spec's size: one with no
         # candidate is answered here, one over the K ladder's top rung

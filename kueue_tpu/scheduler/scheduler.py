@@ -20,6 +20,7 @@ from contextlib import nullcontext
 from dataclasses import dataclass, field
 from typing import Callable, Optional
 
+from .. import features
 from ..api.types import (
     Admission,
     AdmissionCheckState,
@@ -44,8 +45,11 @@ from .flavorassigner import (
     FlavorAssigner,
     Mode,
     PodSetReducer,
+    can_be_partially_admitted,
+    scaled_info,
 )
-from .preemption import Preemptor, PreemptionOracle, Target
+from .preemption import (Preemptor, PreemptionOracle, Target,
+                         flavor_resources_need_preemption)
 
 
 class EntryStatus:
@@ -454,7 +458,8 @@ class Scheduler:
             return None
         solver = self.solver
         with _span("cycle.nominate.classify"):
-            cls = (solver.classify(snapshot, [e.info for e in deferred])
+            cls = (solver.classify(snapshot, [e.info for e in deferred],
+                                   elastic_on_host=self.fair_sharing)
                    if solver is not None else None)
         if cls is None:
             if solver is not None:
@@ -545,28 +550,31 @@ class Scheduler:
         walked: set[int] = set()
         self.preemptor.set_cycle_pack(snapshot, cls.packed)
 
-        def scalar_walk(wi: int) -> bool:
-            """Host FlavorAssigner walk for one head (nominate-time,
-            snapshot state) — partial-admission/TAS heads, and gangs
-            the vector walk turns away, stay inside the device-decided
-            cycle this way."""
+        def settle(wi: int, a: Assignment, targets: list) -> bool:
+            """Head ``wi``'s decided assignment and targets, as the host
+            walk gives them, inside the device-decided cycle."""
             e = deferred[wi]
-            e.inadmissible_msg = ""
-            self._assign_entry(e, snapshot)
+            e.assignment, e.preemption_targets = a, targets
+            e.inadmissible_msg = a.message()
+            e.info.last_assignment = a.last_state
             walked.add(wi)
-            a = e.assignment
             mode = a.representative_mode()
+            reserve[wi] = mode == Mode.PREEMPT and not targets
             if mode == Mode.NO_FIT:
                 return True
             if not solver.attach_host_assignment(cls, wi, a):
                 return False
-            if mode == Mode.PREEMPT:
-                if e.preemption_targets:
-                    targets_by_wi[wi] = e.preemption_targets
-                    assignments_by_wi[wi] = a
-                else:
-                    reserve[wi] = True
+            if targets:
+                targets_by_wi[wi] = targets
+                assignments_by_wi[wi] = a
             return True
+
+        def scalar_walk(wi: int) -> bool:
+            """Host FlavorAssigner walk for one head (nominate-time,
+            snapshot state) — TAS heads, and gangs the vector walk
+            turns away, stay inside the device-decided cycle this way."""
+            e = deferred[wi]
+            return settle(wi, *self._get_assignments(e.info, snapshot))
 
         batch_reqs: list[tuple[int, Assignment]] = []
         with _span("cycle.nominate.walk"):
@@ -597,19 +605,42 @@ class Scheduler:
                     (int(wi), solver.build_preempt_assignment(cls, int(wi)))
                     for wi in pre]
 
+        # the flavor-resources short of quota of the heads whose
+        # planned searches had no candidate: the count search's
+        # shortcut (_search_counts)
+        nothing_to_search: dict[int, set] = {}
         if full_ok and batch_reqs:
             # all preempt heads' target searches in ONE batched
             # dispatch (preemption.go:127-191; candidate discovery
             # host-side, greedy+fillback searches vmapped)
+            empty: list = []
             results = self.preemptor.get_targets_batch(
                 [(deferred[wi].info, a) for wi, a in batch_reqs],
-                snapshot)
-            for (wi, assignment), targets in zip(batch_reqs, results):
+                snapshot, empty)
+            for (wi, assignment), targets, none in zip(batch_reqs, results,
+                                                       empty):
                 if targets:
                     targets_by_wi[wi] = targets
                     assignments_by_wi[wi] = assignment
                 else:
                     reserve[wi] = True
+                if none:
+                    nothing_to_search[wi] = (
+                        flavor_resources_need_preemption(assignment))
+
+        if full_ok:
+            elastic = [int(wi) for wi in np.nonzero(~cls.fit0[:n]
+                                                    & ~cls.scalar_mask[:n])[0]
+                       if int(wi) not in targets_by_wi
+                       and can_be_partially_admitted(deferred[wi].info)]
+            if elastic and features.enabled("PartialAdmission"):
+                with _span("cycle.nominate.partial"):
+                    for wi, (a, targets) in self._search_counts(
+                            deferred, cls, snapshot, elastic,
+                            nothing_to_search).items():
+                        if not settle(wi, a, targets):
+                            full_ok = False
+                            break
 
         if full_ok and (cls.tas_overlap or any(
                 ps.topology_assignment is not None
@@ -644,6 +675,107 @@ class Scheduler:
         solver.stats["full_cycles"] += 1
         return (deferred, cls, handle, assignments_by_wi, targets_by_wi,
                 walked)
+
+    def _search_counts(self, deferred: list[Entry], cls, snapshot: Snapshot,
+                       heads: list[int], nothing_to_search: dict) -> dict:
+        """Partial admission's count search (podset_reducer.go) for the
+        cycle's vector heads that neither fit nor found targets at their
+        full counts, all at once, in lockstep passes: a pass walks every
+        head still searching at its next probe's counts in one vector
+        classify (``CycleSolver.classify_probes``) and sends the pass's
+        Preempt-mode probes to one batched target search.  Returns
+        {head: (assignment, targets)} as ``_get_assignments`` gives
+        them: at the largest counts that fit, or else, for a head that
+        was NoFit at its full counts, the full-count walk's; a
+        Preempt-mode head no count fits keeps its full-count decision.
+        A head one of whose probes the vector walk cannot decide is
+        searched by the host walk instead.
+
+        ``nothing_to_search`` holds, a head, the flavor-resources short
+        of quota of a search of its whose plan had no candidate to
+        search (``Preemptor.plan_searches``): the plan reads nothing of
+        the head's request but those, so a probe short of quota in the
+        same ones plans the same empty searches, and is answered
+        without one."""
+        import numpy as np
+        solver = self.solver
+        stats = solver.stats
+        stats["partial_heads"] += len(heads)
+        search = {wi: PodSetReducer(deferred[wi].info.obj.pod_sets)
+                  for wi in heads}
+        host: list[int] = []
+        while True:
+            live = []
+            for wi, reducer in search.items():
+                while reducer.searching:
+                    mid, counts = reducer.probe()
+                    if counts != [psr.count for psr in
+                                  deferred[wi].info.total_requests]:
+                        live.append((wi, mid, counts))
+                        break
+                    # the full counts: neither fit nor found targets
+                    reducer.record(mid, None, False)
+            if not live:
+                break
+            stats["partial_passes"] += 1
+            stats["partial_probes"] += len(live)
+            sub = solver.classify_probes(cls, [
+                scaled_info(deferred[wi].info, counts)
+                for wi, _, counts in live])
+            lost = (np.ones(len(live), dtype=bool) if sub is None
+                    else sub.scalar_mask[:len(live)])
+            for j in np.nonzero(lost)[0]:
+                wi = live[j][0]
+                host.append(wi)
+                del search[wi]
+            if sub is None:
+                continue
+            pre = np.nonzero(sub.preempt0[:len(live)])[0]
+            self._pick_by_oracle(
+                sub, pre[sub.oracle_groups[pre].any(axis=(1, 2))], snapshot)
+            asks = []
+            for j, (wi, mid, _) in enumerate(live):
+                if lost[j]:
+                    continue
+                if sub.fit0[j]:
+                    search[wi].record(mid, (sub, j), True)
+                    continue
+                if sub.preempt0[j]:
+                    a = solver.build_preempt_assignment(sub, j)
+                    frs = flavor_resources_need_preemption(a)
+                    if frs != nothing_to_search.get(wi):
+                        asks.append((j, a, frs))
+                        continue
+                search[wi].record(mid, None, False)
+            if asks:
+                empty: list = []
+                found = self.preemptor.get_targets_batch(
+                    [(deferred[live[j][0]].info, a) for j, a, _ in asks],
+                    snapshot, empty)
+                for (j, a, frs), targets, none in zip(asks, found, empty):
+                    wi, mid, _ = live[j]
+                    search[wi].record(mid, (a, targets), bool(targets))
+                    if none:
+                        nothing_to_search[wi] = frs
+        out = {}
+        for wi, reducer in search.items():
+            if reducer.found():
+                got = reducer.last_good
+                if isinstance(got[0], Assignment):
+                    out[wi] = got
+                else:
+                    out[wi] = (solver.build_fit_assignment(*got), [])
+            elif not cls.preempt0[wi]:
+                out[wi] = self._get_assignments(deferred[wi].info, snapshot,
+                                                partial=False)
+        if host:
+            reasons = stats["scalar_reasons"]
+            reasons["partial_probe"] = (reasons.get("partial_probe", 0)
+                                        + len(host))
+            stats["scalar_heads"] += len(host)
+            for wi in host:
+                out[wi] = self._get_assignments(deferred[wi].info, snapshot)
+        return out
 
     def _pick_by_oracle(self, cls, heads, snapshot: Snapshot) -> None:
         """The preempt slot of the heads' walks (one a PodSet and
@@ -998,27 +1130,27 @@ class Scheduler:
                     return False
         return True
 
-    def _get_assignments(self, wl: Info, snapshot: Snapshot
+    def _get_assignments(self, wl: Info, snapshot: Snapshot,
+                         partial: bool = True
                          ) -> tuple[Assignment, list[Target]]:
-        """reference scheduler.go:415 getAssignments."""
+        """reference scheduler.go:415 getAssignments; ``partial`` False
+        leaves out the count search (a head the vector path searched)."""
         cq = snapshot.cq(wl.cluster_queue)
         oracle = PreemptionOracle(self.preemptor, snapshot)
-        from .. import features
         assigner = FlavorAssigner(
             wl, cq, snapshot.resource_flavors,
             enable_fair_sharing=self.fair_sharing, oracle=oracle,
             tas_flavors=snapshot.tas_flavors,
             tas_enabled=features.enabled("TopologyAwareScheduling"))
-        found = self._assign_and_search(wl, assigner, snapshot)
+        found = self._assign_and_search(wl, assigner, snapshot, partial)
         self.preemptor.stats["walk_oracle_skips"] += assigner.oracle_skips
         return found
 
     def _assign_and_search(self, wl: Info, assigner: FlavorAssigner,
-                           snapshot: Snapshot
+                           snapshot: Snapshot, partial: bool = True
                            ) -> tuple[Assignment, list[Target]]:
         """The walk's assignment and its targets at the full counts, or
         else at the largest partial counts that fit."""
-        from .. import features
         full = assigner.assign(None)
         mode = full.representative_mode()
         if mode == Mode.FIT:
@@ -1027,8 +1159,8 @@ class Scheduler:
             targets = self.preemptor.get_targets(wl, full, snapshot)
             if targets:
                 return full, targets
-        if (features.enabled("PartialAdmission")
-                and self._can_be_partially_admitted(wl)):
+        if (partial and features.enabled("PartialAdmission")
+                and can_be_partially_admitted(wl)):
             def fits(counts: list[int]):
                 assignment = assigner.assign(counts)
                 m = assignment.representative_mode()
@@ -1044,11 +1176,6 @@ class Scheduler:
             if found and result is not None:
                 return result
         return full, []
-
-    @staticmethod
-    def _can_be_partially_admitted(wl: Info) -> bool:
-        return any(ps.min_count is not None and ps.min_count < ps.count
-                   for ps in wl.obj.pod_sets)
 
     # ------------------------------------------------------------------
     # Iterators — reference scheduler.go:567-600 + fair_sharing_iterator.go
@@ -1350,6 +1477,10 @@ class Scheduler:
             self.cache.forget_workload(new_info)
             self._requeue_and_update(e)
             return False
+        if self.solver is not None and any(
+                ps.count < psr.count for ps, psr in
+                zip(e.assignment.pod_sets, e.info.total_requests)):
+            self.solver.stats["partial_admitted"] += 1
         return True
 
     def _checks_for(self, cq: CQState, assignment: Assignment) -> list[str]:

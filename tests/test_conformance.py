@@ -424,6 +424,18 @@ def test_partial_admission_single_pod_set(use_device):
     adm = d.workload("sales/new").admission
     assert adm.pod_set_assignments[0].count == 25
     assert adm.pod_set_assignments[0].flavors == {"cpu": "default"}
+    assert_partial_on_vector_path(d, searched=use_device)
+
+
+def assert_partial_on_vector_path(d, searched):
+    """The device engine decides a partial-admission head on the vector
+    path: no host walk, and a count search where the full counts
+    neither fit nor find targets."""
+    if d.scheduler.solver is None:
+        return
+    solver = d.scheduler.solver.stats
+    assert solver["scalar_heads"] == 0, solver
+    assert (solver["partial_heads"] > 0) == searched, solver
 
 
 # --- :1251/:1286/:1321 same-cycle borrowing trio ------------------------
@@ -1088,6 +1100,8 @@ def test_partial_admission_preempt_first(use_device):
     assert "eng-beta/new" not in stats.admitted
     heap, parked = queue_state(d, "eng-beta")
     assert "eng-beta/new" in heap | parked
+    # the full counts found their targets: no count search
+    assert_partial_on_vector_path(d, searched=False)
 
 
 def test_partial_admission_preempt_with_reduction(use_device):
@@ -1108,6 +1122,7 @@ def test_partial_admission_preempt_with_reduction(use_device):
     assert "eng-beta/new" not in stats.admitted
     heap, parked = queue_state(d, "eng-beta")
     assert "eng-beta/new" in heap | parked
+    assert_partial_on_vector_path(d, searched=use_device)
 
 
 # --- :2716/:2779 flavor preference among preemption kinds ---------------

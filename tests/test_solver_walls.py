@@ -384,7 +384,12 @@ def test_partial_admission_in_device_cycle():
                                  requests={"cpu": 1000})]))
         return out
 
-    hlog, stats = assert_parity(build, n_cycles=20)
+    hlog, stats = assert_parity(build, expect_scalar=False, n_cycles=20)
+    # the count search runs on the vector path, and no head is walked
+    # on the host for it
+    assert stats["scalar_heads"] == 0, stats
+    assert stats["partial_heads"] > 0, stats
+    assert stats["partial_admitted"] > 0, stats
     # reduced-count admissions must actually happen
     counts = {cnt for c in hlog for _, fl in c["admitted"]
               for _, cnt, _ in fl}
@@ -436,7 +441,13 @@ def test_resume_state_heads_stay_in_device_cycle():
 # Mixed cycles: vector and scalar heads together
 # ---------------------------------------------------------------------------
 
-def test_mixed_vector_and_scalar_heads():
+def test_mixed_vector_and_scalar_heads(monkeypatch):
+    from kueue_tpu.ops import burst, packing, solver
+    # planes of two PodSets (upstream's eight, which the webhook
+    # enforces), so that a three-PodSet head is the host walk's
+    for mod in (burst, packing, solver):
+        monkeypatch.setattr(mod, "MAX_POD_SETS", 2)
+
     def build(d):
         d.apply_resource_flavor(ResourceFlavor(name="default"))
         d.apply_resource_flavor(ResourceFlavor(name="gpu-x"))
@@ -447,10 +458,10 @@ def test_mixed_vector_and_scalar_heads():
                 FlavorQuotas(name="default", resources={
                     "cpu": ResourceQuota(nominal=4000,
                                          borrowing_limit=4000)})])]))
-        # cq-1: two resource groups (vector heads since PR 37); its
-        # two-PodSet workloads are vector heads since PR 41, and the
-        # one with a partial admission that does not fit whole is the
-        # scalar head
+        # cq-1: two resource groups (vector heads); its two-PodSet
+        # workloads are vector heads, and so are those with a partial
+        # admission, whose count search runs on the vector path; one of
+        # more PodSets than the planes hold is the scalar head
         d.apply_cluster_queue(ClusterQueue(
             name="cq-1", cohort="team",
             resource_groups=[
@@ -483,6 +494,13 @@ def test_mixed_vector_and_scalar_heads():
                 name=f"wl-{i}", queue_name=f"lq-{q}",
                 priority=rng.choice([10, 50]), creation_time=float(i + 1),
                 pod_sets=pod_sets))
+        out.append(Workload(
+            name="wl-gang", queue_name="lq-1", priority=50,
+            creation_time=2.5,
+            pod_sets=[PodSet(name=f"p{p}", count=1, requests={"cpu": 500})
+                      for p in range(3)]))
         return out
 
-    assert_parity(build)
+    _, stats = assert_parity(build)
+    assert stats["partial_heads"] > 0, stats
+    assert set(stats["scalar_reasons"]) == {"multi_podset"}, stats
